@@ -372,24 +372,12 @@ def build_from_plan(
         donate_argnums=0,
     )
 
-    from dlrover_tpu.parallel.mesh import (
-        activation_constraint_mesh,
-    )
+    from dlrover_tpu.parallel.mesh import scoped_to_mesh
 
-    def train_step(state, batch):
-        # activation-layout constraints are scoped to THIS mesh for
-        # the duration of the call (tracing happens inside it), so a
-        # model traced later under another mesh never inherits them
-        with activation_constraint_mesh(mesh):
-            return jitted(state, batch)
-
-    def lower(state, batch):
-        # the dry-runner cost model lowers without executing; same
-        # constraint scope applies during ITS tracing
-        with activation_constraint_mesh(mesh):
-            return jitted.lower(state, batch)
-
-    train_step.lower = lower
+    # the model's activation constraints and the map around its
+    # attention kernel see THIS mesh while the step is traced (a
+    # call, or the dry-runner's lower), and only then
+    train_step = scoped_to_mesh(jitted, mesh)
     state = jax.device_put(state, shardings)
     return BuiltPlan(
         mesh=mesh, train_step=train_step, state=state, plan=plan,

@@ -107,26 +107,28 @@ class LogCollector(DataCollector):
 
 
 class ChipMetricsCollector(DataCollector):
-    """Device memory stats from jax when this process owns chips
+    """Device memory stats of the chips the TRAINER owns, read from
+    the metrics file it writes (``record["chip_metrics"]``, see
+    ``ElasticTrainer.report_step``).  The agent never asks jax for
+    devices itself: a chip belongs to one process at a time, and an
+    agent that initialised a backend would take the chip from its own
+    worker — at the latest in the gap after a worker was killed
     (reference: metrics_collector.py chip metrics)."""
 
     data_type = "chip_metrics"
 
-    def collect(self) -> str:
-        try:
-            import jax
+    def __init__(self, metrics_path: Optional[str] = None):
+        from dlrover_tpu.agent.monitor import TrainingMonitor
 
-            lines = []
-            for dev in jax.local_devices():
-                stats = getattr(dev, "memory_stats", lambda: None)()
-                if stats:
-                    lines.append(
-                        f"{dev}: in_use={stats.get('bytes_in_use', 0)} "
-                        f"limit={stats.get('bytes_limit', 0)}"
-                    )
-            return "\n".join(lines)
-        except Exception as e:  # noqa: BLE001
-            return f"chip metrics unavailable: {e}"
+        self._path = (
+            metrics_path or TrainingMonitor.default_metrics_path()
+        )
+
+    def collect(self) -> str:
+        from dlrover_tpu.agent.monitor import read_metrics_record
+
+        record = read_metrics_record(self._path) or {}
+        return str(record.get("chip_metrics", ""))
 
 
 class StepTimeCollector(DataCollector):

@@ -32,6 +32,7 @@ import time
 from typing import Dict, List, Optional
 
 from dlrover_tpu import chaos as _chaos
+from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.log import default_logger as logger
 
 DEFAULT_PRELOAD = "jax,jax.numpy,flax,optax,numpy"
@@ -69,12 +70,20 @@ def _sync_jax_config_from_env():
 
     for env_key, (cfg_key, cast) in _JAX_ENV_CONFIG.items():
         val = os.environ.get(env_key)
-        if val is None:
-            continue
-        try:
+        if val is not None:
             jax.config.update(cfg_key, cast(val))
-        except Exception:  # noqa: BLE001 - unknown option on old jax
-            pass
+
+
+def _assert_no_backend(when: str):
+    """The template may import jax but must never create a backend:
+    its XLA client would not survive the fork, and on a TPU host the
+    template would hold the chip every forked worker needs."""
+    backends = env_utils.initialized_jax_backends()
+    if backends:
+        raise RuntimeError(
+            f"forkserver template has jax backend(s) {backends} "
+            f"{when}: a preloaded module ran device code at import"
+        )
 
 
 def _flush_and_exit(code: int):
@@ -133,6 +142,7 @@ def _template_main(req_fd: int, ev_fd: int):
         except Exception:  # noqa: BLE001 - preload is best-effort
             pass
     _aot_preload()
+    _assert_no_backend("after the preload")
     req = os.fdopen(req_fd, "r")
     ev = os.fdopen(ev_fd, "w")
     children: Dict[int, bool] = {}
@@ -182,10 +192,14 @@ def _template_main(req_fd: int, ev_fd: int):
         # pick up AOT entries written since the last fork (a cold
         # first incarnation's trace) so THIS fork inherits them
         _aot_preload()
+        _assert_no_backend("before a fork")
         pid = os.fork()
         if pid == 0:
             # ---- child: become the worker
             try:
+                # lead a process group of its own, as a cold-spawned
+                # worker does: the agent reaps the group after a death
+                os.setpgid(0, 0)
                 boost = spec.get("nice_boost")
                 if boost:
                     # recovery boost: the respawned worker's restore +

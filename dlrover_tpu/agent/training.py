@@ -31,7 +31,7 @@ from dlrover_tpu.agent.monitor import (
     ResourceMonitor,
     TrainingMonitor,
 )
-from dlrover_tpu.agent.node_check import run_node_check
+from dlrover_tpu.agent.node_check import run_node_check_in_child
 from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.constants import (
     MasterAction,
@@ -66,6 +66,30 @@ _RESTARTS_TOTAL = _REG.counter(
     "dlrover_agent_worker_restarts_total",
     "Worker restart rounds this agent performed",
 )
+
+
+def reap_process_group(pgid: int, timeout: float = 10.0):
+    """SIGKILL whatever is left of a worker's process group and wait
+    until no live member remains.  A worker leads its own group, so
+    its descendants (loader workers, helper subprocesses) are found
+    even after the worker died and they were re-parented.  Raises
+    when a member survives: the replacement worker must not be
+    started next to a process that may still hold the chip."""
+    try:
+        os.killpg(pgid, signal.SIGKILL)
+    except ProcessLookupError:
+        return  # nobody left, zombies included
+    deadline = time.monotonic() + timeout
+    while True:
+        members = env_utils.live_pids(pgid=pgid)
+        if not members:
+            return
+        if time.monotonic() > deadline:
+            raise RuntimeError(
+                f"process group {pgid} still has live members "
+                f"{members} {timeout:.0f}s after SIGKILL"
+            )
+        time.sleep(0.02)
 
 
 class WorkerState(Enum):
@@ -313,26 +337,21 @@ class ElasticTrainingAgent:
 
     @staticmethod
     def _compile_cache_env() -> Dict[str, str]:
-        """Persistent-compile-cache env every incarnation shares:
-        keyed off the JOB (not the uid) so a replacement host resolves
-        the same directory and the first incarnation's compile
-        pre-populates every later one's retrace (see
-        :mod:`dlrover_tpu.common.compile_cache`); the directory is
-        created HERE so the first worker's jax import finds it armed
-        rather than silently disabling the cache."""
+        """Persistent-compile-cache env every incarnation shares
+        (``JAX_COMPILATION_CACHE_DIR`` when the user set it, else the
+        fixed in-checkout directory — see
+        :mod:`dlrover_tpu.common.compile_cache`); the directory and
+        the AOT executable cache beneath it are created HERE so the
+        first worker's jax import finds the cache armed and its first
+        entry write never races the mkdir."""
         from dlrover_tpu.common.aot_cache import aot_cache_dir
         from dlrover_tpu.common.compile_cache import cache_env
 
         env = cache_env()
         try:
-            os.makedirs(env["JAX_COMPILATION_CACHE_DIR"], exist_ok=True)
-            # the AOT executable cache rides the same sharing
-            # contract (aot/ under the job cache dir unless
-            # DLROVER_AOT_CACHE_DIR overrides); created here so the
-            # first incarnation's entry write never races the mkdir
             os.makedirs(aot_cache_dir(), exist_ok=True)
-        except OSError:
-            pass
+        except OSError as e:
+            logger.warning("compile cache dir not creatable: %s", e)
         return env
 
     def _worker_env(
@@ -343,15 +362,7 @@ class ElasticTrainingAgent:
         env.update(self._spec.env)
         # make the framework importable in workers even when not
         # pip-installed (script-mode sys.path only has the script dir)
-        import dlrover_tpu
-
-        pkg_root = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
-        pythonpath = env.get("PYTHONPATH", "")
-        if pkg_root not in pythonpath.split(os.pathsep):
-            env["PYTHONPATH"] = (
-                f"{pkg_root}{os.pathsep}{pythonpath}" if pythonpath
-                else pkg_root
-            )
+        env_utils.with_package_on_pythonpath(env)
         # persistent XLA compilation cache shared across worker
         # incarnations: a restarted worker re-traces its jitted step
         # but hits the cache instead of recompiling — measured as THE
@@ -401,6 +412,14 @@ class ElasticTrainingAgent:
             return None
         return argv
 
+    def _cold_spawn(self, env: Dict[str, str]) -> subprocess.Popen:
+        # the worker leads its own process group, so that the agent
+        # can find and reap its descendants after it died (see
+        # reap_process_group); the forkserver's children do the same
+        return subprocess.Popen(  # noqa: S603 - entrypoint
+            self._spec.entrypoint, env=env, process_group=0
+        )
+
     def _start_workers(self, outcome: RendezvousOutcome):
         self._procs = []
         forked_argv = (
@@ -447,13 +466,9 @@ class ElasticTrainingAgent:
                     )
                     self._forkserver.close()
                     forked_argv = None
-                    proc = subprocess.Popen(  # noqa: S603
-                        self._spec.entrypoint, env=env
-                    )
+                    proc = self._cold_spawn(env)
             else:
-                proc = subprocess.Popen(  # noqa: S603 - entrypoint
-                    self._spec.entrypoint, env=env
-                )
+                proc = self._cold_spawn(env)
             self._procs.append(proc)
         logger.info(
             "started %s worker process(es)%s: %s",
@@ -474,6 +489,10 @@ class ElasticTrainingAgent:
             except subprocess.TimeoutExpired:
                 p.kill()
                 p.wait()
+        # every worker is dead and reaped; now its descendants: the
+        # next incarnation opens the chip, and must be alone with it
+        for p in self._procs:
+            reap_process_group(p.pid)
         self._procs = []
 
     def _monitor_workers(self) -> Tuple[WorkerState, Dict[int, int]]:
@@ -578,8 +597,12 @@ class ElasticTrainingAgent:
             outcome = handler.next_rendezvous()
             normal, elapsed = True, 0.0
             try:
-                elapsed = run_node_check(
-                    client=self._client,
+                # in a child that exits before any worker is
+                # spawned: the agent itself never opens the chip
+                elapsed = run_node_check_in_child(
+                    self._client.master_addr,
+                    self._client.node_id,
+                    self._node_rank,
                     world_size=outcome.num_nodes,
                     round_id=outcome.round,
                 )
@@ -627,6 +650,12 @@ class ElasticTrainingAgent:
         try:
             return self._invoke_run()
         finally:
+            # an agent that exits leaves no worker behind: they lead
+            # their own process groups, so no signal aimed at the
+            # agent's group reaches them.  tpurun turns SIGTERM and
+            # SIGHUP into an exit through here (run._stop_on_signals);
+            # only a SIGKILL of the agent can leave a worker running
+            self._stop_workers()
             # an overlapped breakpoint persist must finish before the
             # saver (and its shm handlers) are torn down
             self._join_save_thread()

@@ -2726,10 +2726,11 @@ def run_scenario(
     if opts.get("compile_cache"):
         # workdir-scoped persistent compile cache: incarnation 0's
         # compile deterministically pre-populates the replacement's
-        # retrace, with no cross-run pollution from a tmpdir default
-        env["DLROVER_COMPILE_CACHE_DIR"] = os.path.join(
-            workdir, "jax_cache"
-        )
+        # retrace (unless the caller's environment already chose the
+        # cache directory: then every process keeps that one)
+        env["JAX_COMPILATION_CACHE_DIR"] = os.environ.get(
+            "JAX_COMPILATION_CACHE_DIR"
+        ) or os.path.join(workdir, "jax_cache")
     if opts.get("journal_mirror"):
         # storage-tier journal mirror under the run's workdir; the
         # master (and its respawns) read this env at construction

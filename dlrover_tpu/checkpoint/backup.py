@@ -15,7 +15,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.common.jax_compat import shard_map
+from jax import shard_map
 
 
 def _to_u8(payload: bytes, size: int) -> np.ndarray:

@@ -292,9 +292,8 @@ def restore_to_template(template, restored, device_put: bool = True):
     leaves = []
     # BATCHED placement: one device_put over all sharded leaves and
     # one over the default-placed ones — a per-leaf asarray+put chain
-    # pays one dispatch (and, through a remote device link, one round
-    # trip) per leaf, which is the measured ``state_build`` residual
-    # of the recovery budget
+    # pays one dispatch per leaf, which is the measured
+    # ``state_build`` residual of the recovery budget
     put_default: list = []   # (leaf_index, host_value)
     put_sharded: list = []   # (leaf_index, host_value, sharding)
     for path, tleaf in flat:

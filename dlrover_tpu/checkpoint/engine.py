@@ -1320,9 +1320,9 @@ class CheckpointEngine:
 
             refill()
             # batched H2D: plain host leaves accumulate and ship in one
-            # device_put call per ~budget bytes — through a remote
-            # device link the per-call dispatch overhead dominates
-            # small leaves, and a batch issues all transfers at once
+            # device_put call per ~budget bytes — the per-call
+            # dispatch overhead dominates small leaves, and a batch
+            # issues all transfers at once
             budget = chunk_bytes()
             pending: list = []
             pending_bytes = 0

@@ -278,8 +278,8 @@ class SharedMemoryHandler:
         Layout (metas) is computed from array avals BEFORE any
         transfer, then device leaves are fetched in ~256 MB batched
         chunks (``jax.device_get`` issues a chunk's transfers
-        concurrently — per-leaf waits pay a transport round trip per
-        leaf, measured 3x slower over a high-latency device link)
+        concurrently — per-leaf waits pay a transfer round trip per
+        leaf)
         and memcpy'd chunk-by-chunk into shm, bounding extra host RAM
         to one chunk instead of a full second state copy.  The engine
         issues ``copy_to_host_async`` on the snapshot up front as a
@@ -338,9 +338,8 @@ class SharedMemoryHandler:
             buf = self._shm.buf
             # device leaves are fetched in BATCHED chunks:
             # ``jax.device_get`` on a group issues all transfers
-            # concurrently (per-leaf waits would pay one transport
-            # round trip per leaf — measured 2x slower through a
-            # high-latency device link), while ~256 MB chunks bound
+            # concurrently (per-leaf waits would pay one transfer
+            # round trip per leaf), while ~256 MB chunks bound
             # the extra host RAM and let the shm memcpy of chunk k
             # overlap nothing worse than chunk k+1's issue
             CHUNK = 256 * 2**20

@@ -6,9 +6,7 @@ persistent XLA compile cache HIT, the respawned trainer still pays
 cache can even answer.  This module removes tracing from the critical
 path: the first incarnation serializes its compiled step executable
 (``jax.jit(...).lower(...).compile()`` through the
-``jax.experimental.serialize_executable`` pair — capability-probed in
-:func:`dlrover_tpu.common.jax_compat.executable_serialization`), and
-every later incarnation *deserializes* it — no trace, no lowering, no
+``jax.experimental.serialize_executable`` pair), and every later incarnation *deserializes* it — no trace, no lowering, no
 XLA compile, ~10 ms instead of seconds.
 
 Keyed like the persistent compile cache (same sharing contract: every
@@ -53,10 +51,9 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from dlrover_tpu.common import env_utils, jax_compat
+from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.log import default_logger as logger
 
-AOT_CACHE_DIR_ENV = "DLROVER_AOT_CACHE_DIR"
 AOT_PRETRACE_ENV = "DLROVER_AOT_PRETRACE"
 ENTRY_SUFFIX = ".aotx"
 # pickle framing of one entry file; bumped when the layout changes so
@@ -71,13 +68,10 @@ _PRELOADED: Dict[str, bytes] = {}
 
 def aot_cache_dir() -> str:
     """The AOT entry directory every incarnation of this job shares:
-    ``DLROVER_AOT_CACHE_DIR`` when the operator chose, else ``aot/``
-    under the persistent compile cache's job-keyed directory (so the
-    two caches ride the same sharing contract, including the
-    cross-host case where both point at job-shared storage)."""
-    explicit = os.getenv(AOT_CACHE_DIR_ENV, "").strip()
-    if explicit:
-        return explicit
+    ``aot/`` under the persistent compile cache's directory, so the
+    two caches ride the same sharing contract (including the
+    cross-host case where ``JAX_COMPILATION_CACHE_DIR`` points at
+    job-shared storage)."""
     from dlrover_tpu.common.compile_cache import job_cache_dir
 
     return os.path.join(job_cache_dir(), "aot")
@@ -302,9 +296,10 @@ def save_entry(
     """Serialize ``compiled`` (a ``Lowered.compile()`` result) under
     ``key``.  Atomic (tmp + rename) and non-fatal: any failure logs
     and returns False — the next incarnation traces, nothing worse."""
-    serialize, _ = jax_compat.executable_serialization()
-    if serialize is None:
-        return False
+    # imported here, not at the top: the agent and the forkserver
+    # template import this module for its paths and byte preloads
+    from jax.experimental.serialize_executable import serialize
+
     cache_dir = cache_dir or aot_cache_dir()
     path = entry_path(key, cache_dir)
     try:
@@ -347,9 +342,10 @@ def load_entry(
     descriptor mismatch, unknown pytree nodes, deserializer error) —
     the caller falls back to tracing.  ``timings`` (optional dict)
     receives the read/unpickle/deserialize breakdown."""
-    _, deserialize_and_load = jax_compat.executable_serialization()
-    if deserialize_and_load is None:
-        return None
+    from jax.experimental.serialize_executable import (
+        deserialize_and_load,
+    )
+
     cache_dir = cache_dir or aot_cache_dir()
     name = key + ENTRY_SUFFIX
     t0 = time.perf_counter()
@@ -454,7 +450,7 @@ class Resolution:
     is ``"aot"`` (deserialized executable — no trace anywhere),
     ``"trace"`` (traced+compiled, either eagerly inside the resolve
     when ``deferred`` is False, or at first call when True) or
-    ``"off"`` (serialization unavailable — plain jit semantics)."""
+    ``"off"`` (the resolve could not run — plain jit semantics)."""
 
     fn: Any
     source: str
@@ -528,12 +524,6 @@ def resolve_step(
     Off/error: returns ``fn`` untouched with ``deferred=True`` — the
     first call traces exactly as without this module."""
     cache_dir = cache_dir or aot_cache_dir()
-    serialize, _ = jax_compat.executable_serialization()
-    if serialize is None:
-        return Resolution(
-            fn=fn, source="off", deferred=True, dir=cache_dir,
-            reason="jax has no serialize_executable",
-        )
     if callable(example_args) and not isinstance(
         example_args, (list, tuple)
     ):

@@ -3,7 +3,8 @@ dlrover/python/common/env_utils.py), plus the shared /proc/<pid>/stat
 field parser the process-supervision paths rely on."""
 
 import os
-from typing import List, Optional
+import sys
+from typing import Dict, List, Optional
 
 from dlrover_tpu.common.constants import NodeEnv
 
@@ -36,6 +37,56 @@ def proc_stat_fields(pid: int) -> Optional[List[bytes]]:
         return data.rsplit(b")", 1)[1].split()
     except (OSError, IndexError):
         return None
+
+
+def live_pids(
+    pgid: Optional[int] = None, session: Optional[int] = None
+) -> List[int]:
+    """Pids of the not-yet-dead members of process group ``pgid``
+    and/or of ``session`` (zombies are dead: they hold no chip, file
+    or socket)."""
+    out = []
+    for name in os.listdir("/proc"):
+        if not name.isdigit():
+            continue
+        # state, ppid, pgrp, session
+        fields = proc_stat_fields(int(name))
+        if fields is None or len(fields) < 4 or fields[0] == b"Z":
+            continue
+        if pgid is not None and int(fields[2]) != pgid:
+            continue
+        if session is not None and int(fields[3]) != session:
+            continue
+        out.append(int(name))
+    return out
+
+
+def with_package_on_pythonpath(env: Dict[str, str]) -> Dict[str, str]:
+    """``env`` with the directory that holds ``dlrover_tpu`` first on
+    ``PYTHONPATH``: children are importable even when the framework is
+    not pip-installed (script mode puts only the script's directory on
+    ``sys.path``)."""
+    import dlrover_tpu
+
+    pkg_root = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
+    rest = [
+        p for p in env.get("PYTHONPATH", "").split(os.pathsep)
+        if p and p != pkg_root
+    ]
+    env["PYTHONPATH"] = os.pathsep.join([pkg_root] + rest)
+    return env
+
+
+def initialized_jax_backends() -> List[str]:
+    """Platforms this process has a live jax backend for (empty when
+    jax was never imported or never asked for devices).  A process
+    with a TPU backend OWNS the chip; the agent and the forkserver
+    template must never be one."""
+    if "jax" not in sys.modules:
+        return []
+    from jax._src import xla_bridge
+
+    return sorted(xla_bridge._backends)
 
 
 def get_node_id() -> int:

@@ -157,12 +157,36 @@ def get_attention_fn(impl: str) -> AttentionFn:
 
     ring/ulysses run over the global mesh's ``sequence`` axis
     (registered by auto_accelerate); activations must be
-    sequence-sharded by the batch placement.
+    sequence-sharded by the batch placement.  flash runs per shard of
+    the mesh its train step was built for (batch over the data axes,
+    heads over ``tensor``): GSPMD cannot split the kernel itself.
     """
     if impl == "flash":
         from dlrover_tpu.ops.flash_attention import flash_attention
+        from dlrover_tpu.parallel.mesh import (
+            get_activation_constraint_mesh,
+        )
+        from dlrover_tpu.parallel.sequence import (
+            shard_local_attention,
+        )
 
-        return flash_attention
+        def flash(q, k, v, dtype=None):
+            # the mesh the enclosing train step was built for (scoped
+            # around its trace by accelerate / make_train_step); in a
+            # manual region (ulysses, pipeline) q/k/v are one shard's
+            # already
+            mesh = get_activation_constraint_mesh()
+            if (
+                mesh is None or mesh.size == 1
+                or jax.sharding.get_abstract_mesh().manual_axes
+            ):
+                return flash_attention(q, k, v, dtype=dtype)
+            return shard_local_attention(
+                flash_attention, q, k, v, mesh, dtype=dtype
+            )
+
+        flash.gqa_aware = flash_attention.gqa_aware
+        return flash
     if impl == "ring":
         from dlrover_tpu.parallel.mesh import get_global_mesh
         from dlrover_tpu.parallel.sequence import ring_attention

@@ -9,6 +9,7 @@ ctypes.
 
 import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import List, Optional
@@ -17,6 +18,25 @@ from dlrover_tpu.common.log import default_logger as logger
 
 _SRC_DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD_LOCK = threading.Lock()
+_FLAGS = [
+    "-O3", "-shared", "-fPIC", "-std=c++17", "-march=native",
+]
+
+
+def _machine_id() -> bytes:
+    """What ``-march=native`` resolves against: the architecture and
+    the first CPU's model and feature flags."""
+    lines = [platform.machine().encode()]
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"model name", b"flags")):
+                    lines.append(line.strip())
+                elif not line.strip():
+                    break  # end of the first CPU's block
+    except OSError:
+        pass
+    return b"\n".join(lines)
 
 
 def build_library(
@@ -33,7 +53,13 @@ def build_library(
     build_dir = os.path.join(_SRC_DIR, "_build")
     os.makedirs(build_dir, exist_ok=True)
 
-    digest = hashlib.sha256()
+    # -march=native ties the binary to this CPU: flags and machine
+    # are part of the key, so a _build/ carried to another machine by
+    # a copy of the tree is rebuilt there, never loaded
+    digest = hashlib.sha256(
+        " ".join(_FLAGS + (extra_flags or [])).encode()
+        + _machine_id()
+    )
     for src in sources:
         with open(src, "rb") as f:
             digest.update(f.read())
@@ -45,10 +71,9 @@ def build_library(
     with _BUILD_LOCK:
         if os.path.exists(lib_path):
             return lib_path
-        cmd = [
-            "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-            "-march=native", *sources, "-o", lib_path + ".tmp",
-        ] + (extra_flags or [])
+        cmd = ["g++", *_FLAGS, *sources, "-o", lib_path + ".tmp"] + (
+            extra_flags or []
+        )
         logger.info("building native lib: %s", " ".join(cmd))
         result = subprocess.run(  # noqa: S603
             cmd, capture_output=True, text=True

@@ -5,8 +5,9 @@ array->shm memcpy through the tiny native helper keeps the trainer's
 other threads (heartbeats, IPC replies, monitors) responsive while a
 multi-GB snapshot streams — the reference gets this for free from
 torch's C++ copy (ckpt_saver.py:174); numpy's ``copyto`` holds the
-GIL the whole time.  Falls back to numpy when the toolchain is
-unavailable.
+GIL the whole time.  Without the native library (no ``g++``) the
+copies still work through numpy, and say so with a warning: callers
+that need the GIL-free path check :func:`native_available`.
 """
 
 import ctypes
@@ -49,10 +50,19 @@ def _load() -> Optional[ctypes.CDLL]:
         ]
         lib.dlrover_fastcopy.restype = ctypes.c_size_t
         _lib = lib
-    except Exception as e:  # noqa: BLE001 - no toolchain etc.
-        logger.info("fastcopy unavailable (%s); using numpy", e)
+    except (OSError, RuntimeError) as e:  # no g++ / build failed
+        logger.warning(
+            "native fastcopy is MISSING (%s): checkpoint copies hold "
+            "the GIL through numpy", e,
+        )
         _lib = None
     return _lib
+
+
+def native_available() -> bool:
+    """Whether the GIL-free native copy is loaded (builds it on first
+    use)."""
+    return _load() is not None
 
 
 def copy_into(dst: np.ndarray, src: np.ndarray) -> None:

@@ -21,14 +21,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30
 # v5e-measured fwd+bwd block sweep (bq x bk in {256,512,1024}^2, seq

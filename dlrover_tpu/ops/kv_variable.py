@@ -583,12 +583,12 @@ class KvVariable:
         dense [n, dim] f32 array on device.
 
         Platform note: host callbacks require the runtime to call
-        back into THIS process mid-program.  A tunneled remote
-        device (device server on the far side of a network link)
-        cannot — the call hangs.  There, run the gather host-side
-        and ``device_put`` the dense batch instead (the embedding
-        lookup is host-resident by design, like the reference's CPU
-        parameter-server tables).
+        back into THIS process mid-program, which serializes the
+        device step with the host table.  The split step of
+        :mod:`dlrover_tpu.trainer.sparse_pipeline` runs the gather
+        host-side and ``device_put``s the dense batch instead (the
+        embedding lookup is host-resident by design, like the
+        reference's CPU parameter-server tables).
 
         The default gather mutates the table (inserts missing rows and
         bumps frequency counters), so it runs through

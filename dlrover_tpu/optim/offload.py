@@ -19,11 +19,11 @@ model fits but Adam states don't.
 import jax
 import optax
 
-from dlrover_tpu.common.jax_compat import memory_placement
+from jax.memory import Space
 
 
 def _to(kind: str):
-    space = memory_placement(kind)
+    space = Space.Host if kind == "pinned_host" else Space.Device
 
     def move(x):
         # Scalars (step counts) stay put: offloading them saves

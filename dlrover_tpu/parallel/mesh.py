@@ -257,7 +257,8 @@ _GLOBAL_MESH = None
 # mesh whose ACTIVATION-layout constraints are currently in force —
 # scoped (not global) so a computation traced under a different mesh
 # (e.g. the RL rollout layout swap) never inherits the training
-# mesh's constraints.  Set by the accelerate train-step wrapper.
+# mesh's constraints.  Set by every train step built for a mesh
+# (scoped_to_mesh: accelerate and make_train_step(mesh=)).
 _ACTIVATION_MESH = threading.local()
 
 
@@ -277,6 +278,25 @@ def activation_constraint_mesh(mesh):
 
 def get_activation_constraint_mesh():
     return getattr(_ACTIVATION_MESH, "mesh", None)
+
+
+def scoped_to_mesh(jitted, mesh):
+    """``jitted`` with ``mesh`` in force around every call and every
+    ``.lower`` (jax traces inside both): what a train step built for
+    a mesh returns, so that the model under it finds that mesh — its
+    activation constraints, the map around an attention kernel — and
+    a computation traced later under another mesh does not."""
+
+    def step(*args):
+        with activation_constraint_mesh(mesh):
+            return jitted(*args)
+
+    def lower(*args):
+        with activation_constraint_mesh(mesh):
+            return jitted.lower(*args)
+
+    step.lower = lower
+    return step
 
 
 def mesh_is_permuted(mesh) -> bool:

@@ -31,7 +31,7 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from dlrover_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -17,15 +17,21 @@ Two schemes:
    ``lax.ppermute`` while each device accumulates online-softmax
    partials for its local queries, so sequence length scales with the
    number of devices without ever materializing full K/V on one chip.
+
+Also :func:`shard_local_attention`, the map that data/tensor
+parallelism needs around an attention kernel GSPMD cannot split.
 """
 
 import functools
+import math
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from dlrover_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
+
+from dlrover_tpu.parallel.mesh import batch_axes
 
 
 def _check_divisible(name, value, by):
@@ -79,6 +85,45 @@ def ulysses_attention(
     return shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec, check_vma=False,
+    )(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# Per-shard attention (data / tensor parallel)
+# ---------------------------------------------------------------------------
+
+
+def shard_local_attention(
+    attn_fn: Callable,
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mesh,
+    **attn_kwargs,
+):
+    """Run ``attn_fn`` once per shard of ``mesh``: batch over the data
+    axes, heads over ``tensor``.
+
+    For an ``attn_fn`` GSPMD cannot split by itself — a Mosaic kernel
+    ("cannot be automatically partitioned").  Every shard is a whole
+    attention problem, so no collective is needed.  A dimension its
+    axes do not divide stays replicated (every device then computes
+    all of it).  ``k``/``v`` may carry fewer
+    heads than ``q`` (GQA, kv-head-major): contiguous head blocks keep
+    each q head with its kv head.
+    """
+    over_batch = tuple(a for a in batch_axes() if mesh.shape[a] > 1)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in over_batch):
+        over_batch = ()
+    tensor = mesh.shape["tensor"]
+    over_heads = (
+        "tensor" if tensor > 1 and q.shape[2] % tensor == 0
+        and k.shape[2] % tensor == 0 else None
+    )
+    spec = P(over_batch or None, None, over_heads, None)
+    return shard_map(
+        functools.partial(attn_fn, **attn_kwargs), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
     )(q, k, v)
 
 

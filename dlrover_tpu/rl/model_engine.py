@@ -56,6 +56,9 @@ class RoleSpec:
     search: bool = False
     rank_mode: str = "cost_model"   # chip-free default for searches
     cost_budget: int = 0
+    # passed to auto_accelerate(extra=...): e.g. the chip the cost
+    # model ranks for when the search runs off the chip
+    extra: Dict[str, Any] = field(default_factory=dict)
     # frozen roles: explicit inference layout (mesh + partition
     # rules); None = replicated jit (single-chip shape)
     mesh: Any = None
@@ -96,6 +99,7 @@ class RLModelEngine:
                         dry_run_candidates=True,
                         rank_mode=spec.rank_mode,
                         cost_budget=spec.cost_budget,
+                        extra=spec.extra,
                     )
                 else:
                     self._accel[name] = auto_accelerate(

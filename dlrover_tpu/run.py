@@ -16,7 +16,9 @@ Usage::
 """
 
 import argparse
+import contextlib
 import os
+import signal
 import subprocess
 import sys
 import tempfile
@@ -352,9 +354,41 @@ def run(args) -> int:
                 shutil.rmtree(journal_dir_created, ignore_errors=True)
 
 
+@contextlib.contextmanager
+def _stop_on_signals():
+    """A SIGTERM or SIGHUP ends the run as an exception would.
+
+    Python's default action kills this process where it stands, and
+    whatever it started lives on: the workers lead process groups of
+    their own (out of reach of a signal aimed at this group) and keep
+    the chip, the local master keeps its port.  Raised into the main
+    thread instead, the signal unwinds through the ``finally`` blocks
+    that stop workers, saver and master.  A second signal does not
+    interrupt that clean-up."""
+    if threading.current_thread() is not threading.main_thread():
+        yield  # signal.signal is the main thread's alone
+        return
+
+    signums = (signal.SIGTERM, signal.SIGHUP)
+
+    def stop(signum, frame):
+        for s in signums:
+            signal.signal(s, signal.SIG_IGN)
+        logger.warning("signal %s: stopping the job", signum)
+        raise SystemExit(128 + signum)
+
+    previous = {s: signal.signal(s, stop) for s in signums}
+    try:
+        yield
+    finally:
+        for s, handler in previous.items():
+            signal.signal(s, handler)
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     args = parse_args(argv)
-    return run(args)
+    with _stop_on_signals():
+        return run(args)
 
 
 if __name__ == "__main__":

@@ -94,6 +94,12 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
+        # which devices the trainer process owns (its own
+        # jax.local_devices()): the agent never opens the chip, so
+        # this is the job's only first-hand device report
+        _s("worker_backend",
+           ["platform", "kind", "count", "restart_count",
+            "node_rank"]),
         # per-step phase breakdown from the always-on profiler
         # (open dict: data_wait / h2d / compute / checkpoint /
         # report / other_s / total_s, arbitrary user phases allowed)

@@ -27,9 +27,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from dlrover_tpu import chaos as _chaos
-from dlrover_tpu.common import env_utils, jax_compat
+from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.parallel.mesh import dp_world_size
+from dlrover_tpu.parallel.mesh import dp_world_size, scoped_to_mesh
 from dlrover_tpu.parallel.sharding import (
     PartitionRules,
     batch_spec,
@@ -115,6 +115,23 @@ class StepPhaseProfiler:
         return phases
 
 
+def _chip_metrics() -> str:
+    """Memory stats of the devices THIS process owns, one line each —
+    written into the metrics file so the agent's diagnosis collector
+    can report them without ever opening the chip itself.  Empty on
+    backends that report no memory stats (the CPU backend)."""
+    lines = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats()
+        if stats:
+            lines.append(
+                f"{dev}: in_use={stats.get('bytes_in_use', 0)} "
+                f"peak={stats.get('peak_bytes_in_use', 0)} "
+                f"limit={stats.get('bytes_limit', 0)}"
+            )
+    return "\n".join(lines)
+
+
 class PhaseHandle:
     """Yielded by :meth:`StepPhaseProfiler.phase`; ``block(x)`` marks
     ``x`` to be ``jax.block_until_ready``-ed before the phase closes,
@@ -198,7 +215,8 @@ def make_train_step(
     batch's leading dim must be ``grad_accum * micro``; the scan keeps
     the accumulation inside the compiled program.  When a mesh is
     given, in/out shardings pin state to the rule-derived placement and
-    the batch to the data axes — GSPMD inserts all collectives.
+    the batch to the data axes — GSPMD inserts all collectives — and
+    the step is traced with that mesh in scope (``scoped_to_mesh``).
     """
 
     def grads_of(params, batch):
@@ -254,11 +272,14 @@ def make_train_step(
     def jit_with_shardings(state_example):
         state_sh = sharding_tree(state_example, mesh, rules)
         batch_sh = NamedSharding(mesh, batch_spec())
-        return jax.jit(
-            step_fn,
-            in_shardings=(state_sh, batch_sh),
-            out_shardings=(state_sh, None),
-            donate_argnums=0,
+        return scoped_to_mesh(
+            jax.jit(
+                step_fn,
+                in_shardings=(state_sh, batch_sh),
+                out_shardings=(state_sh, None),
+                donate_argnums=0,
+            ),
+            mesh,
         )
 
     return step_fn, jit_with_shardings
@@ -364,10 +385,20 @@ class ElasticTrainer:
         self.profiler = StepPhaseProfiler()
         self.last_step_phases: Dict[str, float] = {}
         _GRAD_ACCUM_GAUGE.set(self.grad_accum)
+        devices = jax.local_devices()
+        emit_event(
+            "worker_backend",
+            platform=devices[0].platform,
+            kind=devices[0].device_kind,
+            count=len(devices),
+            restart_count=self._restart_count,
+            node_rank=env_utils.get_node_rank(),
+        )
         logger.info(
-            "elastic trainer: global_batch=%s micro=%s dp=%s accum=%s",
+            "elastic trainer: global_batch=%s micro=%s dp=%s accum=%s "
+            "on %d x %s",
             global_batch_size, micro_batch_size, self.dp_size,
-            self.grad_accum,
+            self.grad_accum, len(devices), devices[0].device_kind,
         )
 
     @property
@@ -444,6 +475,9 @@ class ElasticTrainer:
             # master's diagnosis chain (data-starved detection)
             "phases": phases,
         }
+        chip_metrics = _chip_metrics()
+        if chip_metrics:
+            record["chip_metrics"] = chip_metrics
         if metrics:
             record.update(
                 {
@@ -482,7 +516,6 @@ def init_jax_distributed():
     if not coordinator or num_processes <= 1:
         return False
     process_id = int(os.getenv("DLROVER_PROCESS_ID", "0"))
-    jax_compat.ensure_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,

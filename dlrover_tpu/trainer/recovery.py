@@ -39,7 +39,6 @@ from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.compile_cache import (
     cache_entries,
     enable_persistent_cache,
-    job_cache_dir,
 )
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry.events import emit_event
@@ -113,7 +112,7 @@ class RecoveryProfiler:
         self.phases: Dict[str, float] = {}
         self.cache_hit: Optional[bool] = None
         self.aot_hit: Optional[bool] = None
-        self.cache_dir = enable_persistent_cache() or job_cache_dir()
+        self.cache_dir = enable_persistent_cache()
         try:
             self.t0 = float(os.getenv(RECOVERY_T0_ENV, "") or 0.0)
         except ValueError:

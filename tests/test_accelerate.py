@@ -329,6 +329,7 @@ def test_estimate_plan_cost_model():
     context = ModelContext(
         model=model, optim_factory=lambda: optax.adamw(1e-3),
         loss_fn=loss_fn, sample_batch=batch,
+        extra={"target_chip": "TPU v5e"},
     )
     lib = OptimizationLibrary()
     plan = lib.apply_strategy(
@@ -352,6 +353,24 @@ def test_estimate_plan_cost_model():
     assert r2.flops > 1.1 * r1.flops, (r1.flops, r2.flops)
 
 
+def test_cost_model_refuses_an_unknown_chip():
+    """The roofline needs a chip: a device kind the peak table does
+    not know (the CPU mesh, with no target named) is an error, never
+    a v5e by default."""
+    from dlrover_tpu.accel.dry_runner import chip_spec, estimate_plan
+    from dlrover_tpu.accel.model_context import ModelContext
+
+    assert chip_spec("TPU v5 lite") == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no peak spec"):
+        chip_spec("cpu")
+    context = ModelContext(
+        model=None, optim_factory=None, loss_fn=None,
+        sample_batch=None,
+    )
+    with pytest.raises(ValueError, match="target_chip"):
+        estimate_plan(None, context, devices=jax.devices()[:1])
+
+
 def test_search_strategy_cost_model_mode():
     from dlrover_tpu.accel.model_context import ModelContext
     from dlrover_tpu.accel.strategy_search import search_strategy
@@ -370,6 +389,7 @@ def test_search_strategy_cost_model_mode():
     context = ModelContext(
         model=model, optim_factory=lambda: optax.adamw(1e-3),
         loss_fn=loss_fn, sample_batch=batch,
+        extra={"target_chip": "TPU v5e"},
     )
     result = search_strategy(
         context, num_devices=4, devices=jax.devices()[:4],
@@ -404,6 +424,7 @@ def test_search_strategy_hybrid_profiles_top_k_only():
     context = ModelContext(
         model=model, optim_factory=lambda: optax.adamw(1e-3),
         loss_fn=loss_fn, sample_batch=batch,
+        extra={"target_chip": "TPU v5e"},
     )
     result = search_strategy(
         context, num_devices=2, devices=jax.devices()[:2],

@@ -19,6 +19,7 @@ from dlrover_tpu.agent.training import (
     MasterRendezvousHandler,
     WorkerSpec,
 )
+from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.constants import NodeEnv, RendezvousName
 from dlrover_tpu.master.master import JobMaster
 
@@ -329,3 +330,186 @@ def test_agent_preemption_notice_saves_ckpt_and_reports(
             m.stop()
         agent.stop()
         meta.close()
+
+
+_AGENT_NO_BACKEND = r"""
+import json, sys
+from dlrover_tpu.agent.diagnosis import DiagnosisMonitor
+from dlrover_tpu.common.env_utils import initialized_jax_backends
+from dlrover_tpu.agent.master_client import MasterClient
+from dlrover_tpu.agent.training import ElasticTrainingAgent, WorkerSpec
+from dlrover_tpu.master.master import JobMaster
+
+master = JobMaster(port=0, node_num=1, job_name="agent-no-backend")
+master.prepare()
+client = MasterClient(
+    f"127.0.0.1:{master.port}", node_id=0, node_type="worker"
+)
+try:
+    agent = ElasticTrainingAgent(
+        WorkerSpec(entrypoint=[sys.executable, "-c", "pass"],
+                   network_check=True),
+        client=client, node_rank=0, start_monitors=False,
+    )
+    healthy = agent.node_health_check()   # a --network-check round
+    monitor = DiagnosisMonitor(client=client)  # default collectors
+    monitor.report_once()
+    kinds = sorted(c.data_type for c in monitor._collectors)
+finally:
+    client.close()
+    master.stop()
+print(json.dumps({
+    "healthy": healthy, "collectors": kinds,
+    "backends": initialized_jax_backends(),
+}))
+"""
+
+
+def test_agent_process_never_initializes_a_jax_backend(tmp_path):
+    """One process per chip: an agent that ran its diagnosis
+    collectors and a --network-check round has NO jax backend of its
+    own (the node check ran in a child that exited; chip metrics come
+    from the trainer's metrics file).  In a process of its own: this
+    one has had a CPU backend since conftest."""
+    import json
+    import subprocess
+
+    metrics = tmp_path / "metrics.json"
+    metrics.write_text(json.dumps({
+        "global_step": 3, "timestamp": time.time(),
+        "chip_metrics": "TPU_0: in_use=1 peak=2 limit=3",
+    }))
+    env = dict(
+        os.environ,
+        PYTHONPATH=os.getcwd(),
+        DLROVER_METRICS_FILE=str(metrics),
+        DLROVER_EVENT_LOG=str(tmp_path / "events.jsonl"),
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", _AGENT_NO_BACKEND], env=env,
+        capture_output=True, text=True, timeout=240,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    report = json.loads(done.stdout.strip().splitlines()[-1])
+    assert report["healthy"] is True
+    assert "chip_metrics" in report["collectors"]
+    assert report["backends"] == []
+    # the check really ran, in ANOTHER process
+    from dlrover_tpu.telemetry.events import read_events
+
+    checks = [
+        e for e in read_events(str(tmp_path / "events.jsonl"))
+        if e["type"] == "node_check"
+    ]
+    assert checks
+
+
+def test_chip_metrics_collector_reads_the_trainers_file(tmp_path):
+    import json
+
+    from dlrover_tpu.agent.diagnosis import ChipMetricsCollector
+
+    path = tmp_path / "metrics.json"
+    collector = ChipMetricsCollector(str(path))
+    assert collector.collect() == ""  # no trainer yet
+    path.write_text(json.dumps({
+        "global_step": 1,
+        "chip_metrics": "TPU_0: in_use=1 peak=2 limit=3",
+    }))
+    assert collector.collect() == "TPU_0: in_use=1 peak=2 limit=3"
+
+
+def test_reap_process_group_kills_orphaned_descendants(tmp_path):
+    """The respawn gate: after a worker died, whatever it left behind
+    in its process group is killed and waited for before the
+    replacement may open the chip."""
+    import signal
+    import subprocess
+
+    from dlrover_tpu.agent.training import reap_process_group
+
+    pid_file = tmp_path / "child.pid"
+    # a worker that leaves a grandchild behind and dies
+    worker = subprocess.Popen(
+        [sys.executable, "-c",
+         "import subprocess, sys\n"
+         "c = subprocess.Popen([sys.executable, '-c', "
+         "'import time; time.sleep(120)'])\n"
+         f"open({str(pid_file)!r}, 'w').write(str(c.pid))\n"
+         "import time; time.sleep(120)\n"],
+        process_group=0,
+    )
+    deadline = time.time() + 30
+    while time.time() < deadline and not pid_file.exists():
+        time.sleep(0.05)
+    orphan = int(pid_file.read_text())
+    worker.send_signal(signal.SIGKILL)
+    worker.wait(timeout=10)
+    assert orphan in env_utils.live_pids(pgid=worker.pid)
+    reap_process_group(worker.pid)
+    assert env_utils.live_pids(pgid=worker.pid) == []
+    reap_process_group(worker.pid)  # nobody left: a no-op
+
+
+_WORKER_WITH_A_CHILD = """
+import os, subprocess, sys, time
+child = subprocess.Popen(
+    [sys.executable, "-c", "import time; time.sleep(300)"]
+)
+with open(sys.argv[1] + ".tmp", "w") as f:
+    f.write(f"{os.getpid()} {child.pid}")
+os.replace(sys.argv[1] + ".tmp", sys.argv[1])
+time.sleep(300)
+"""
+
+
+@pytest.mark.parametrize("signame", ["SIGTERM", "SIGHUP"])
+def test_a_signalled_tpurun_leaves_no_worker_behind(tmp_path, signame):
+    """Workers lead process groups of their own, so a signal aimed at
+    tpurun (or at its group) does not reach them: tpurun itself must
+    stop them — and their descendants, and the local master — on its
+    way out, or the next job cannot open the chip."""
+    import signal
+    import subprocess
+
+    script = tmp_path / "worker.py"
+    script.write_text(_WORKER_WITH_A_CHILD)
+    pids = tmp_path / "pids"
+    env = dict(
+        os.environ,
+        PYTHONPATH=os.getcwd(),
+        DLROVER_SHARED_DIR=str(tmp_path / "sock"),
+        DLROVER_JOB_NAME=f"sig{os.getpid()}",
+    )
+    tpurun = subprocess.Popen(
+        [sys.executable, "-m", "dlrover_tpu.run",
+         "--nproc_per_node=1", "--monitor_interval=0.2",
+         str(script), str(pids)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+        text=True, start_new_session=True,
+    )
+    try:
+        deadline = time.time() + 120
+        while time.time() < deadline and not pids.exists():
+            assert tpurun.poll() is None, tpurun.stdout.read()[-2000:]
+            time.sleep(0.05)
+        worker, child = map(int, pids.read_text().split())
+        # the worker is out of the reach of a signal to tpurun's group
+        assert os.getpgid(worker) == worker != os.getpgid(tpurun.pid)
+        assert sorted(env_utils.live_pids(pgid=worker)) == sorted(
+            [worker, child]
+        )
+        tpurun.send_signal(getattr(signal, signame))
+        out, _ = tpurun.communicate(timeout=60)
+        assert tpurun.returncode == 128 + getattr(signal, signame), out
+        assert env_utils.live_pids(pgid=worker) == []
+        # nor anything else of the job: the local master, a template
+        assert env_utils.live_pids(session=tpurun.pid) == []
+    finally:
+        for pgid in (tpurun.pid, locals().get("worker")):
+            if pgid:
+                try:
+                    os.killpg(pgid, signal.SIGKILL)
+                except (ProcessLookupError, PermissionError):
+                    pass
+

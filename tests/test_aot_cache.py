@@ -344,18 +344,18 @@ def test_forkserver_pretrace_inherits_entries(tmp_path):
     env = dict(
         os.environ,
         DLROVER_AOT_PRETRACE="1",
-        DLROVER_AOT_CACHE_DIR=str(cache_dir),
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path),
         DLROVER_PRELOAD="json",
         PYTHONPATH=os.getcwd(),
     )
     old = {
         k: os.environ.get(k)
-        for k in ("DLROVER_AOT_PRETRACE", "DLROVER_AOT_CACHE_DIR",
+        for k in ("DLROVER_AOT_PRETRACE", "JAX_COMPILATION_CACHE_DIR",
                   "DLROVER_PRELOAD")
     }
     os.environ.update({
         "DLROVER_AOT_PRETRACE": "1",
-        "DLROVER_AOT_CACHE_DIR": str(cache_dir),
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
         "DLROVER_PRELOAD": "json",
     })
     fs = WorkerForkServer()
@@ -393,7 +393,7 @@ def test_profiler_resolve_books_phases_and_events(
     log = tmp_path / "events.jsonl"
     monkeypatch.setenv("DLROVER_EVENT_LOG", str(log))
     monkeypatch.setenv(
-        "DLROVER_AOT_CACHE_DIR", str(tmp_path / "aot")
+        "JAX_COMPILATION_CACHE_DIR", str(tmp_path)
     )
     step_fn, state, batch = _fresh()
     p0 = RecoveryProfiler(restart_count=0, node_rank=0)
@@ -433,7 +433,7 @@ def test_profiler_resolve_books_phases_and_events(
 
 def test_resolve_train_step_helper_without_profiler(tmp_path):
     cache_dir = str(tmp_path / "aot")
-    os.environ["DLROVER_AOT_CACHE_DIR"] = cache_dir
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
     try:
         step_fn, state, batch = _fresh()
         step = resolve_train_step(
@@ -443,7 +443,7 @@ def test_resolve_train_step_helper_without_profiler(tmp_path):
         assert np.isfinite(float(m["loss"]))
         assert aot_cache.aot_entries(cache_dir) == 1
     finally:
-        os.environ.pop("DLROVER_AOT_CACHE_DIR", None)
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 
 def test_resolve_step_async_join(tmp_path, monkeypatch):
@@ -453,7 +453,7 @@ def test_resolve_step_async_join(tmp_path, monkeypatch):
     from dlrover_tpu.trainer.recovery import RecoveryProfiler
 
     monkeypatch.setenv(
-        "DLROVER_AOT_CACHE_DIR", str(tmp_path / "aot")
+        "JAX_COMPILATION_CACHE_DIR", str(tmp_path)
     )
     monkeypatch.setenv(
         "DLROVER_EVENT_LOG", str(tmp_path / "ev.jsonl")

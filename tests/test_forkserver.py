@@ -256,3 +256,37 @@ def test_proc_start_time_none_for_dead_pid(srv, tmp_path):
             break
         time.sleep(0.05)  # template may not have reaped the zombie yet
     assert srv._proc_start_time(h.pid) is None
+
+
+def test_template_has_no_jax_backend_after_trainer_preload(tmp_path):
+    """The template imports the trainer's whole module set (jax
+    included) but must never create a backend: on a TPU host it would
+    hold the chip every forked worker needs.  The forked probe
+    inherits the template's state and reports it."""
+    import json
+
+    from dlrover_tpu.agent.forkserver import TRAINER_PRELOAD
+
+    out = tmp_path / "probe.json"
+    script = tmp_path / "probe.py"
+    script.write_text(
+        "import json, sys\n"
+        "from dlrover_tpu.common.env_utils import "
+        "initialized_jax_backends\n"
+        f"open({str(out)!r} + '.tmp', 'w').write(json.dumps({{\n"
+        "    'jax_imported': 'jax' in sys.modules,\n"
+        "    'trainer_imported': "
+        "'dlrover_tpu.trainer.elastic_trainer' in sys.modules,\n"
+        "    'backends': initialized_jax_backends()}))\n"
+        f"import os; os.replace({str(out)!r} + '.tmp', {str(out)!r})\n"
+    )
+    fs = WorkerForkServer(preload=TRAINER_PRELOAD)
+    try:
+        env = dict(os.environ, PYTHONPATH=os.getcwd())
+        fs.spawn([str(script)], env, timeout=120.0)
+        assert _wait_file(str(out), timeout=60.0)
+    finally:
+        fs.close()
+    probe = json.loads(out.read_text())
+    assert probe["jax_imported"] and probe["trainer_imported"]
+    assert probe["backends"] == []

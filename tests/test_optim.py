@@ -7,7 +7,6 @@ import numpy as np
 import optax
 import pytest
 
-from dlrover_tpu.common import jax_compat
 from dlrover_tpu.ops.quantization import (
     dequantize_blockwise,
     quantize_blockwise,
@@ -286,14 +285,6 @@ def test_q_adafactor_relative_step_runs():
     assert np.isfinite(np.asarray(final["w"])).all()
 
 
-needs_pinned_host = pytest.mark.skipif(
-    not jax_compat.supports_memory_kind("pinned_host"),
-    reason="backend has no pinned_host memory kind "
-           "(older-jax cpu backend)",
-)
-
-
-@needs_pinned_host
 def test_offload_state_lives_on_host():
     from dlrover_tpu.optim import adamw_offload
 
@@ -312,7 +303,6 @@ def test_offload_state_lives_on_host():
     )
 
 
-@needs_pinned_host
 def test_offload_sharded_state_host_roundtrip_eager():
     """Sharded (mesh) opt state round-trips host<->device with its
     sharding preserved.  Eager-mode: the CPU backend's SPMD

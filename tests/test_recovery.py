@@ -24,31 +24,46 @@ def event_log(tmp_path, monkeypatch):
 # -- compile cache ------------------------------------------------------
 
 
-def test_job_cache_dir_resolution_order(tmp_path, monkeypatch):
-    monkeypatch.delenv(cc.DLROVER_CACHE_DIR_ENV, raising=False)
-    monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
-    monkeypatch.delenv("DLROVER_JOB_NAME", raising=False)
-    # 3) job-keyed default (namespace rule shared with shm segments)
-    default = cc.job_cache_dir()
-    assert "dlrover_jax_cache_" in default
-    # two jobs (different socket dirs) resolve different dirs; the
-    # same job resolves the same one (that IS the sharing contract)
-    monkeypatch.setenv("DLROVER_SHARED_DIR", str(tmp_path / "a"))
-    a1, a2 = cc.job_cache_dir(), cc.job_cache_dir()
-    monkeypatch.setenv("DLROVER_SHARED_DIR", str(tmp_path / "b"))
-    b = cc.job_cache_dir()
-    assert a1 == a2 and a1 != b
-    # 2) ambient JAX_COMPILATION_CACHE_DIR wins over the default
+@pytest.mark.parametrize("other", [
+    {},
+    {"DLROVER_COMPILE_CACHE_DIR": "/operator"},
+    {"DLROVER_JOB_NAME": "some-job", "TMPDIR": "/elsewhere"},
+])
+def test_job_cache_dir_is_the_jax_env_when_set(monkeypatch, other):
+    """``JAX_COMPILATION_CACHE_DIR`` wins whatever else is set — no
+    code of the job may set another directory."""
+    for key, val in other.items():
+        monkeypatch.setenv(key, val)
     monkeypatch.setenv(cc.CACHE_DIR_ENV, "/ambient")
     assert cc.job_cache_dir() == "/ambient"
-    # 1) the explicit operator knob wins over everything
-    monkeypatch.setenv(cc.DLROVER_CACHE_DIR_ENV, "/explicit")
-    assert cc.job_cache_dir() == "/explicit"
+    assert cc.cache_env()[cc.CACHE_DIR_ENV] == "/ambient"
+    from dlrover_tpu.common.aot_cache import aot_cache_dir
+
+    assert aot_cache_dir() == "/ambient/aot"
+
+
+def test_job_cache_dir_default_is_fixed_in_checkout(
+    tmp_path, monkeypatch
+):
+    """Without the env var: ONE directory inside the checkout, the
+    same under two socket dirs, job names and temp dirs (a cache
+    directory that moves never hits)."""
+    import dlrover_tpu
+
+    monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+    monkeypatch.setenv("DLROVER_SHARED_DIR", str(tmp_path / "a"))
+    a = cc.job_cache_dir()
+    monkeypatch.setenv("DLROVER_SHARED_DIR", str(tmp_path / "b"))
+    monkeypatch.setenv("DLROVER_JOB_NAME", "another-job")
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    b = cc.job_cache_dir()
+    checkout = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
+    assert a == b == os.path.join(checkout, ".jax_cache")
 
 
 def test_cache_env_and_entry_count(tmp_path, monkeypatch):
     cache = tmp_path / "cache"
-    monkeypatch.setenv(cc.DLROVER_CACHE_DIR_ENV, str(cache))
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(cache))
     env = cc.cache_env()
     assert env[cc.CACHE_DIR_ENV] == str(cache)
     assert env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] == "0"
@@ -67,7 +82,7 @@ def test_cache_env_and_entry_count(tmp_path, monkeypatch):
 
 def test_profiler_phases_and_events(tmp_path, monkeypatch, event_log):
     monkeypatch.setenv(
-        cc.DLROVER_CACHE_DIR_ENV, str(tmp_path / "cache")
+        cc.CACHE_DIR_ENV, str(tmp_path / "cache")
     )
     monkeypatch.setenv("DLROVER_RESTART_COUNT", "2")
     monkeypatch.setenv("DLROVER_NODE_RANK", "0")
@@ -98,7 +113,7 @@ def test_retrace_hit_vs_miss_witness(tmp_path, monkeypatch,
     """The cache-hit rule: no NEW *-cache entries across the bracket
     over a WARM dir = HIT; new entries (or an empty dir) = MISS."""
     cache = tmp_path / "cache"
-    monkeypatch.setenv(cc.DLROVER_CACHE_DIR_ENV, str(cache))
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(cache))
     monkeypatch.setenv("DLROVER_RESTART_COUNT", "1")
     prof = rec.RecoveryProfiler()
 

@@ -565,6 +565,7 @@ def test_per_role_strategies_and_reshard_accounting():
             loss_fn=make_critic_loss(critic_model, prompt_len),
             optim_factory=lambda: _optax.adam(1e-3),
             search=True, rank_mode="cost_model", cost_budget=3,
+            extra={"target_chip": "TPU v5e"},
         ),
         ModelRole.REF: RoleSpec(
             model=ref_model, params=actor_params,

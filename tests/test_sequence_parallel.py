@@ -116,3 +116,36 @@ def test_long_context_ring_runs(sp_mesh):
     out = ring_attention(qs, ks, vs, sp_mesh)
     assert out.shape == q.shape
     assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("kv_heads,batch", [(4, 4), (2, 4), (4, 3)])
+def test_flash_runs_per_shard_of_the_steps_mesh(kv_heads, batch):
+    """The flash kernel under a step built for a mesh: batch over
+    data x fsdp, heads over tensor (GQA keeps each q head with its kv
+    head; a batch the axes do not divide stays replicated), forward
+    and gradient equal to the kernel on the whole arrays."""
+    from dlrover_tpu.models.gpt import get_attention_fn
+    from dlrover_tpu.ops.flash_attention import flash_attention
+    from dlrover_tpu.parallel.mesh import scoped_to_mesh
+
+    mesh = build_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
+    q, _, _ = _qkv(b=batch, s=128, h=4, d=16, seed=5)
+    _, k, v = _qkv(b=batch, s=128, h=kv_heads, d=16, seed=6)
+
+    def loss(attn):
+        def f(q, k, v):
+            return (attn(q, k, v, dtype=jnp.float32) ** 2).sum()
+
+        return jax.value_and_grad(f, argnums=(0, 1, 2))
+
+    ref, g_ref = loss(flash_attention)(q, k, v)
+    step = scoped_to_mesh(
+        jax.jit(loss(get_attention_fn("flash"))), mesh
+    )
+    assert "sdy.manual_computation" in step.lower(q, k, v).as_text()
+    out, g = step(q, k, v)
+    np.testing.assert_allclose(out, ref, rtol=1e-5)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
+        )
