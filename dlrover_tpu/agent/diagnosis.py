@@ -27,6 +27,7 @@ from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
+from dlrover_tpu.telemetry.tracing import span as _span
 
 # agent-side no-step-progress threshold (seconds) before the watchdog
 # captures hang flight data and ships it to the master; production
@@ -467,17 +468,21 @@ class DiagnosisMonitor:
         self._stop.set()
 
     def report_once(self):
-        for collector in self._collectors:
-            try:
-                content = collector.collect()
-                if content:
-                    self._client.report_diagnosis_data(
-                        collector.data_type, content
+        with _span(
+            "agent.diagnosis_collect", collectors=len(self._collectors)
+        ):
+            for collector in self._collectors:
+                try:
+                    content = collector.collect()
+                    if content:
+                        self._client.report_diagnosis_data(
+                            collector.data_type, content
+                        )
+                except Exception as e:  # noqa: BLE001
+                    logger.warning(
+                        "collector %s failed: %s",
+                        collector.data_type, e,
                     )
-            except Exception as e:  # noqa: BLE001
-                logger.warning(
-                    "collector %s failed: %s", collector.data_type, e
-                )
 
     def _run(self):
         while not self._stop.wait(self._interval):

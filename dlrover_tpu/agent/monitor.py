@@ -18,6 +18,7 @@ from typing import Dict, List, Optional
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry.metrics import get_registry
+from dlrover_tpu.telemetry.tracing import span as _span
 
 try:
     import psutil
@@ -110,7 +111,10 @@ class ResourceMonitor:
     def _run(self):
         while not self._stopped.wait(self._interval):
             try:
-                with _REPORT_SECONDS.time(monitor="resource"):
+                # one span a tick (as every periodic service beside
+                # the worker): a slow step can be laid against it
+                with _span("agent.resource_monitor"), \
+                        _REPORT_SECONDS.time(monitor="resource"):
                     stats = get_host_stats()
                     _HOST_CPU_GAUGE.set(stats["cpu_percent"])
                     _HOST_MEM_GAUGE.set(stats["memory_mb"])
@@ -169,7 +173,8 @@ class TrainingMonitor:
         try:
             if not os.path.exists(self._path):
                 return
-            with _REPORT_SECONDS.time(monitor="training"):
+            with _span("agent.training_monitor"), \
+                    _REPORT_SECONDS.time(monitor="training"):
                 with open(self._path) as f:
                     record = json.load(f)
                 step = int(record.get("global_step", -1))
@@ -210,7 +215,8 @@ class HeartbeatReporter:
     def _run(self):
         while not self._stopped.wait(self._interval):
             try:
-                with _REPORT_SECONDS.time(monitor="heartbeat"):
+                with _span("agent.heartbeat"), \
+                        _REPORT_SECONDS.time(monitor="heartbeat"):
                     action = self._client.report_heartbeat()
                 # the master delivers an action exactly once (popped
                 # from its queue on this ack): an empty later ack

@@ -13,6 +13,7 @@ from enum import Enum
 from typing import Any, Optional, Tuple
 
 from dlrover_tpu.checkpoint.engine import CheckpointEngine
+from dlrover_tpu.telemetry.tracing import span as _span
 
 
 class RestoreHandle:
@@ -124,6 +125,19 @@ class Checkpointer:
         path: str = "",
         storage_type: StorageType = StorageType.DISK,
     ) -> bool:
+        """One ``ckpt.save`` span per call: what the call itself
+        blocks on is its children (``ckpt.save.<part>``); a DISK
+        save's shm write runs later on the writer thread, as
+        ``ckpt.save.write`` under the same trace id."""
+        with _span(
+            "ckpt.save", step=step, storage=storage_type.name.lower()
+        ) as sp:
+            ok = self._save(step, state_dict, path, storage_type)
+            sp.set_attribute("ok", bool(ok))
+            sp.set_attribute("bytes", self._engine.last_save_bytes)
+        return ok
+
+    def _save(self, step, state_dict, path, storage_type) -> bool:
         if storage_type == StorageType.MEMORY:
             return self._engine.save_to_memory(step, state_dict, path)
         ok = self._engine.save_to_storage(step, state_dict, path)

@@ -41,6 +41,11 @@ from dlrover_tpu.common.multi_process import SharedLock, SharedQueue
 from dlrover_tpu.common.storage import get_checkpoint_storage
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
+from dlrover_tpu.telemetry.tracing import (
+    attach_context,
+    inject_context,
+    span as _span,
+)
 
 _REG = get_registry()
 _SHM_SAVE_SECONDS = _REG.histogram(
@@ -118,6 +123,9 @@ class CheckpointEngine:
         # device->host fetch, memcpy) — surfaced so benches report the
         # dominant term instead of burying it in logs (VERDICT r2)
         self.last_save_phases: Dict[str, float] = {}
+        # bytes of the last save this engine accepted (the shm
+        # layout's total, or the snapshot's leaves for an async save)
+        self.last_save_bytes = 0
         # stage breakdown of the last restore (tier + read/assemble/
         # h2d seconds) — same surfacing contract as the save phases
         self.last_restore_phases: Dict[str, Any] = {}
@@ -325,7 +333,9 @@ class CheckpointEngine:
         thread passes ``block_lock=True`` — it is off the training
         path, so waiting for the agent is free and the save must not
         be silently dropped."""
-        self._notify_agent_to_create_saver()
+        if not self._notified_agent:
+            with _span("ckpt.save.notify_agent"):
+                self._notify_agent_to_create_saver()
         from dlrover_tpu.checkpoint.shm_handler import paged_enabled
 
         # paged hot saves (DLROVER_SHM_PAGED): write only what
@@ -348,8 +358,11 @@ class CheckpointEngine:
             and isinstance(state_dict, dict)
             and KV_STATE_KEY not in state_dict
         )
-        if not use_paged:
-            state_dict = self._merge_sparse(state_dict, step, durable)
+        if not use_paged and self._sparse is not None:
+            with _span("ckpt.save.sparse_merge", step=step):
+                state_dict = self._merge_sparse(
+                    state_dict, step, durable
+                )
         # every rank locks its shard: the agent's breakpoint save reads
         # all local shards, so an unlocked write can be torn even for
         # ranks that never persist to storage; without an agent there
@@ -358,9 +371,17 @@ class CheckpointEngine:
         lock_wait = 0.0
         if self._agent_lock_available():
             t0 = time.perf_counter()
-            if not self._shm_lock.acquire(
-                blocking=block_lock, timeout=600.0
-            ):
+            # held_by = what the holder said it was doing when this
+            # acquire found the lock taken ("persist:<step>")
+            with _span("ckpt.save.lock_wait", step=step) as sp:
+                got = self._shm_lock.acquire(
+                    blocking=block_lock, timeout=600.0
+                )
+                sp.set_attribute("acquired", got)
+                sp.set_attribute(
+                    "held_by", self._shm_lock.contended_with
+                )
+            if not got:
                 logger.info(
                     "step %s: saver busy persisting; skipping shm save",
                     step,
@@ -389,6 +410,7 @@ class CheckpointEngine:
                 self._shm_handler.save_state_dict(state_dict, config)
             self._cached_step = step
             phases = dict(self._shm_handler.last_save_phases)
+            self.last_save_bytes = phases.get("bytes", 0)
             phases["lock_wait_s"] = round(lock_wait, 3)
             phases["total_s"] = round(time.time() - start + lock_wait, 3)
             self.last_save_phases = phases
@@ -520,18 +542,18 @@ class CheckpointEngine:
             item = self._writer_queue.get()
             if item is None:
                 return
-            step, snap, path, enqueue = item
+            step, snap, path, enqueue, trace_ctx = item
             try:
-                with _ASYNC_WRITE_SECONDS.time():
-                    ok = self.save_to_memory(
-                        step, snap, path, block_lock=True
-                    )
-                if ok and enqueue and self._event_queue is not None:
-                    self._event_queue.put(
-                        CheckpointEvent(
-                            event_type=CheckpointEventType.SAVE, step=step
+                # the save call's trace continues on this thread
+                with attach_context(trace_ctx), _span(
+                    "ckpt.save.write", step=step
+                ):
+                    with _ASYNC_WRITE_SECONDS.time():
+                        ok = self.save_to_memory(
+                            step, snap, path, block_lock=True
                         )
-                    )
+                    if ok and enqueue:
+                        self._enqueue_persist(step)
             except Exception as e:  # noqa: BLE001
                 self._last_async_error = e
                 _SAVE_ERRORS_TOTAL.inc()
@@ -577,30 +599,50 @@ class CheckpointEngine:
                 )
                 _SAVE_SKIPPED_TOTAL.inc(reason="writer_busy")
                 return False
-            snap = self._device_snapshot(state_dict)
+            with _span("ckpt.save.snapshot", step=step):
+                snap = self._device_snapshot(state_dict)
             # sparse export joins the snapshot NOW — synchronous with
             # respect to table mutation, like the on-device copy is
             # for the dense leaves; the writer thread must not read a
             # table the next train step is already scattering into
-            snap = self._merge_sparse(snap, step, durable=True)
+            if self._sparse is not None:
+                with _span("ckpt.save.sparse_merge", step=step):
+                    snap = self._merge_sparse(snap, step, durable=True)
             # kick off the device->host transfers without blocking
-            for leaf in jax.tree_util.tree_leaves(snap):
-                if isinstance(leaf, jax.Array):
-                    try:
-                        leaf.copy_to_host_async()
-                    except Exception:  # noqa: BLE001
-                        break
-            self._ensure_writer()
-            self._writer_queue.put((step, snap, path, True))
+            with _span("ckpt.save.d2h_kickoff", step=step):
+                nbytes = 0
+                for leaf in jax.tree_util.tree_leaves(snap):
+                    if isinstance(leaf, jax.Array):
+                        nbytes += leaf.nbytes
+                        try:
+                            leaf.copy_to_host_async()
+                        except Exception:  # noqa: BLE001
+                            break
+                self.last_save_bytes = nbytes
+            # the writer thread continues THIS call's span
+            trace_ctx = inject_context()
+            with _span("ckpt.save.enqueue", step=step):
+                self._ensure_writer()
+                self._writer_queue.put(
+                    (step, snap, path, True, trace_ctx)
+                )
             return True
         ok = self.save_to_memory(step, state_dict, path, durable=True)
-        if ok and self._event_queue is not None:
+        if ok:
+            self._enqueue_persist(step)
+        return ok
+
+    def _enqueue_persist(self, step: int):
+        """Ask the agent to persist ``step`` (this node's lead process
+        only); the event carries the save's trace context, so the
+        agent's ``ckpt.persist`` span joins the same trace."""
+        if self._event_queue is not None:
             self._event_queue.put(
                 CheckpointEvent(
-                    event_type=CheckpointEventType.SAVE, step=step
+                    event_type=CheckpointEventType.SAVE, step=step,
+                    trace=inject_context(),
                 )
             )
-        return ok
 
     # -- load ---------------------------------------------------------------
 
@@ -643,8 +685,6 @@ class CheckpointEngine:
         lands in ``last_restore_phases``, the ``ckpt.restore`` span
         and the ``checkpoint_restore`` event."""
         from dlrover_tpu.checkpoint.restore import RestoreStats
-        from dlrover_tpu.telemetry.tracing import span as _span
-
         with _span("ckpt.restore") as sp:
             stats = RestoreStats()
             t0 = time.perf_counter()
@@ -1035,8 +1075,6 @@ class CheckpointEngine:
         k is in flight to the device.
         """
         from dlrover_tpu.checkpoint.restore import RestoreStats
-        from dlrover_tpu.telemetry.tracing import span as _span
-
         with _span("ckpt.restore") as sp:
             sp.set_attribute("sharded", True)
             stats = RestoreStats()

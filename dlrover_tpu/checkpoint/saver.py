@@ -12,6 +12,7 @@ handlers persist the shm snapshot on SIGTERM
 (``register_signal_handler:472``).
 """
 
+import contextvars
 import os
 import pickle
 import queue
@@ -35,6 +36,11 @@ from dlrover_tpu.common.storage import (
 )
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
+from dlrover_tpu.telemetry.tracing import (
+    attach_context,
+    record_span,
+    span as _span,
+)
 
 _REG = get_registry()
 _PERSIST_SECONDS = _REG.histogram(
@@ -71,6 +77,9 @@ class CheckpointEvent:
     event_type: str = CheckpointEventType.SAVE
     step: int = 0
     global_shard_num: int = 1
+    # wire form of the trainer's ``ckpt.save`` trace context, so the
+    # agent's ``ckpt.persist`` span joins that save's trace
+    trace: Optional[Dict[str, str]] = None
 
 
 @dataclass
@@ -298,7 +307,8 @@ class AsyncCheckpointSaver:
                 continue
             if event.event_type == CheckpointEventType.SAVE:
                 try:
-                    self.save_step_checkpoint(event.step)
+                    with attach_context(getattr(event, "trace", None)):
+                        self.save_step_checkpoint(event.step)
                 except Exception:  # noqa: BLE001
                     logger.exception(
                         "persisting checkpoint step %s failed", event.step
@@ -311,6 +321,13 @@ class AsyncCheckpointSaver:
     ):
         """Persist every local shard of ``step`` then commit
         (reference: save_step_checkpoint, ckpt_saver.py:795)."""
+        with _span("ckpt.persist", step=step) as sp:
+            ok = self._persist_step(step, commit_timeout)
+            sp.set_attribute("ok", ok)
+
+    def _persist_step(
+        self, step: int, commit_timeout: Optional[float],
+    ) -> bool:
         start = time.time()
         step_dir = os.path.join(
             self.config.checkpoint_dir, step_dirname(step)
@@ -318,9 +335,11 @@ class AsyncCheckpointSaver:
         self.storage.safe_makedirs(step_dir)
         futures = []
         for local_rank, handler in enumerate(self._shm_handlers):
+            # each shard's thread continues this span's trace
             futures.append(
                 self._executor.submit(
-                    self._save_shard, step, local_rank, handler, step_dir
+                    contextvars.copy_context().run,
+                    self._save_shard, step, local_rank, handler, step_dir,
                 )
             )
         # a shard whose storage write RAISES (IO fault, chaos
@@ -343,15 +362,16 @@ class AsyncCheckpointSaver:
                 "checkpoint_persist", step=step, ok=False,
                 seconds=round(time.time() - start, 3),
             )
-            return
+            return False
         if self.config.node_rank == 0:
-            self.commit_checkpoint(
-                step, step_dir,
-                timeout=(
-                    commit_timeout if commit_timeout is not None
-                    else CheckpointConstant.SAVE_TIMEOUT
-                ),
-            )
+            with _span("ckpt.persist.commit", step=step):
+                self.commit_checkpoint(
+                    step, step_dir,
+                    timeout=(
+                        commit_timeout if commit_timeout is not None
+                        else CheckpointConstant.SAVE_TIMEOUT
+                    ),
+                )
         self._last_persisted_step = step
         elapsed = time.time() - start
         _PERSIST_SECONDS.observe(elapsed)
@@ -362,6 +382,7 @@ class AsyncCheckpointSaver:
         logger.info(
             "persisted checkpoint step %s in %.2fs", step, elapsed,
         )
+        return True
 
     def _save_shard(
         self, step: int, local_rank: int,
@@ -382,20 +403,26 @@ class AsyncCheckpointSaver:
         # next snapshot for ~10 s/GB on slow hosts.  A lock-free
         # read-only touch is safe — the data read is discarded; only
         # the page mappings persist.
-        try:
-            meta = handler.metadata()
-            if meta:
-                total = meta["scalar_offset"] + meta["scalar_nbytes"]
-                shm = handler._attach(min_size=total)
-                if shm is not None:
-                    import numpy as _np
+        with _span("ckpt.persist.prefault", step=step, shard=local_rank):
+            try:
+                meta = handler.metadata()
+                if meta:
+                    total = (
+                        meta["scalar_offset"] + meta["scalar_nbytes"]
+                    )
+                    shm = handler._attach(min_size=total)
+                    if shm is not None:
+                        import numpy as _np
 
-                    _np.frombuffer(
-                        shm.buf, dtype=_np.uint8, count=total
-                    )[::4096].sum()
-        except Exception:  # noqa: BLE001 - best-effort warmup
-            pass
-        acquired = lock.acquire(timeout=60.0)
+                        _np.frombuffer(
+                            shm.buf, dtype=_np.uint8, count=total
+                        )[::4096].sum()
+            except Exception:  # noqa: BLE001 - best-effort warmup
+                pass
+        # the note is what a trainer that finds the lock taken reads
+        # back into its ``ckpt.save.lock_wait`` span
+        t_wait = time.time()
+        acquired = lock.acquire(timeout=60.0, note=f"persist:{step}")
         if not acquired:
             # reading shm unlocked races the trainer's next save; a torn
             # shard must never reach storage (reference aborts too,
@@ -405,6 +432,14 @@ class AsyncCheckpointSaver:
                 "persist of step %s", local_rank, step,
             )
             return False
+        # No tracing code between here and the release: the copy holds
+        # the GIL, a trainer's non-blocking acquire may be queued
+        # behind it, and whatever runs before the release decides
+        # whether that save is taken or skipped (my chip run, PR 25:
+        # one span closed in here, and the save that met the persist
+        # was skipped).  Two clock readings; the spans come after.
+        t_held = time.time()
+        raw = b""
         try:
             config, raw, meta = handler.read_raw()
             if config is None:
@@ -430,22 +465,33 @@ class AsyncCheckpointSaver:
                 return False
         finally:
             lock.release(force=True)
+            t_released = time.time()
+            # the in-RAM copy is all the lock is held for
+            record_span(
+                "ckpt.persist.lock_hold", t_held, t_released,
+                step=step, shard=local_rank, bytes=len(raw),
+                wait_s=round(t_held - t_wait, 6),
+            )
         # storage IO runs lock-free on the private copy
         global_rank = config.rank
-        self.storage.write(
-            raw, os.path.join(step_dir, shard_file(global_rank))
-        )
-        self.storage.write(
-            pickle.dumps(meta),
-            os.path.join(step_dir, meta_file(global_rank)),
-        )
-        # done file marks this shard committed
-        self.storage.write(
-            b"", os.path.join(
-                step_dir,
-                f"{CheckpointConstant.DONE_FILE_PREFIX}{global_rank}",
-            ),
-        )
+        with _span(
+            "ckpt.persist.write_storage", step=step, shard=local_rank,
+            bytes=len(raw),
+        ):
+            self.storage.write(
+                raw, os.path.join(step_dir, shard_file(global_rank))
+            )
+            self.storage.write(
+                pickle.dumps(meta),
+                os.path.join(step_dir, meta_file(global_rank)),
+            )
+            # done file marks this shard committed
+            self.storage.write(
+                b"", os.path.join(
+                    step_dir,
+                    f"{CheckpointConstant.DONE_FILE_PREFIX}{global_rank}",
+                ),
+            )
         return True
 
     def commit_checkpoint(
@@ -455,7 +501,8 @@ class AsyncCheckpointSaver:
         """Poll done files == global_shard_num then atomically update
         the tracker file (reference: commit_checkpoint,
         ckpt_saver.py:860)."""
-        deadline = time.time() + timeout
+        start = time.time()
+        deadline = start + timeout
         expected = self.config.global_shard_num
         done: List[str] = []
         # adaptive poll: single-node commits find every done file on
@@ -488,7 +535,10 @@ class AsyncCheckpointSaver:
                 self.storage.commit(step, True)
                 self._clean_old_checkpoints(step)
                 _COMMITTED_STEP.set(step)
-                emit_event("checkpoint_commit", step=step)
+                emit_event(
+                    "checkpoint_commit", step=step, start_ts=start,
+                    seconds=round(time.time() - start, 6),
+                )
                 return
             time.sleep(poll)
             poll = min(0.5, poll * 1.7)

@@ -33,6 +33,7 @@ from dlrover_tpu.common.multi_process import (
     SharedDict,
     get_or_create_shm,
 )
+from dlrover_tpu.telemetry.tracing import span as _span
 
 
 @dataclass
@@ -295,100 +296,122 @@ class SharedMemoryHandler:
         """
         import time as _time
 
-        entries, scalars, shard_info = _extract_entries(state_dict)
-        scalar_blob = pickle.dumps(scalars)
-        # a flat write clobbers any paged epoch in this segment; the
-        # next paged save must start a fresh one
-        self._paged_dir = None
+        step = config.step
+        with _span("ckpt.save.layout", step=step):
+            entries, scalars, shard_info = _extract_entries(state_dict)
+            scalar_blob = pickle.dumps(scalars)
+            # a flat write clobbers any paged epoch in this segment;
+            # the next paged save must start a fresh one
+            self._paged_dir = None
 
-        # layout from shapes/dtypes only — no transfer needed yet
-        metas: Dict[str, TensorMeta] = {}
-        offset = 0
-        for key, arr in entries:
-            gshape, ranges = shard_info.get(key, (None, None))
-            dt = np.dtype(arr.dtype)
-            count = int(np.prod(arr.shape, dtype=np.int64)) if (
-                arr.shape
-            ) else 1
-            nbytes = count * dt.itemsize
-            metas[key] = TensorMeta(
-                shape=tuple(arr.shape),
-                dtype=str(dt),
-                offset=offset,
-                nbytes=nbytes,
-                global_shape=gshape,
-                index=ranges,
-            )
-            offset += nbytes
-        total = offset + len(scalar_blob)
+            # layout from shapes/dtypes only — no transfer needed yet
+            metas: Dict[str, TensorMeta] = {}
+            offset = 0
+            for key, arr in entries:
+                gshape, ranges = shard_info.get(key, (None, None))
+                dt = np.dtype(arr.dtype)
+                count = int(np.prod(arr.shape, dtype=np.int64)) if (
+                    arr.shape
+                ) else 1
+                nbytes = count * dt.itemsize
+                metas[key] = TensorMeta(
+                    shape=tuple(arr.shape),
+                    dtype=str(dt),
+                    offset=offset,
+                    nbytes=nbytes,
+                    global_shape=gshape,
+                    index=ranges,
+                )
+                offset += nbytes
+            total = offset + len(scalar_blob)
 
         t_fetch = 0.0
         t_memcpy = 0.0
         with self._write_lock:
             if self._shm is None or self._shm.size < total:
-                if self._shm is not None:
-                    self._shm.close()
-                    self._shm.unlink()
-                    self._shm = None
-                self._shm = get_or_create_shm(self._shm_name, total)
+                with _span("ckpt.save.segment", step=step, bytes=total):
+                    if self._shm is not None:
+                        self._shm.close()
+                        self._shm.unlink()
+                        self._shm = None
+                    self._shm = get_or_create_shm(self._shm_name, total)
             config.writing = True
-            self._publish_meta(metas, config, offset, len(scalar_blob))
+            with _span("ckpt.save.publish_meta", step=step):
+                self._publish_meta(
+                    metas, config, offset, len(scalar_blob)
+                )
+            import jax
+
             from dlrover_tpu.ops.fastcopy import copy_into
 
             buf = self._shm.buf
-            # device leaves are fetched in BATCHED chunks:
-            # ``jax.device_get`` on a group issues all transfers
-            # concurrently (per-leaf waits would pay one transfer
-            # round trip per leaf), while ~256 MB chunks bound
-            # the extra host RAM and let the shm memcpy of chunk k
-            # overlap nothing worse than chunk k+1's issue
+            # leaves are fetched in BATCHED chunks: ``jax.device_get``
+            # on a group issues all transfers concurrently (per-leaf
+            # waits would pay one transfer round trip per leaf; a
+            # host array passes through it untouched), while ~256 MB
+            # chunks bound the extra host RAM and let the shm memcpy
+            # of chunk k overlap nothing worse than chunk k+1's issue.
+            # One ``fetch`` and one ``memcpy`` span a chunk.
             CHUNK = 256 * 2**20
             chunk: list = []
             chunk_bytes = 0
 
-            def flush(chunk):
+            def flush(chunk, chunk_bytes):
                 nonlocal t_fetch, t_memcpy
                 if not chunk:
                     return
-                t0 = _time.perf_counter()
-                import jax
-
-                fetched = jax.device_get([a for _, a in chunk])
-                t_fetch += _time.perf_counter() - t0
-                for (key, _), host in zip(chunk, fetched):
-                    m = metas[key]
-                    host = np.ascontiguousarray(host)
-                    dst = np.frombuffer(
-                        buf, dtype=np.dtype(m.dtype),
-                        count=host.size, offset=m.offset,
-                    ).reshape(m.shape)
-                    # GIL released during the memcpy: a multi-GB
-                    # snapshot must not starve heartbeat/IPC threads
-                    t0 = _time.perf_counter()
-                    copy_into(dst, host)
-                    t_memcpy += _time.perf_counter() - t0
+                with _span(
+                    "ckpt.save.fetch", step=step, bytes=chunk_bytes,
+                    leaves=len(chunk),
+                ) as sp:
+                    fetched = jax.device_get([a for _, a in chunk])
+                t_fetch += sp.duration
+                with _span(
+                    "ckpt.save.memcpy", step=step, bytes=chunk_bytes,
+                    leaves=len(chunk),
+                ) as sp:
+                    # the span is the whole loop; its two parts are
+                    # its attributes: making a fetched array C-
+                    # contiguous (a full copy where it is not), and
+                    # the native copy into the segment
+                    t_contiguous = t_copy = 0.0
+                    for (key, _), host in zip(chunk, fetched):
+                        m = metas[key]
+                        t0 = _time.perf_counter()
+                        host = np.ascontiguousarray(host)
+                        t_contiguous += _time.perf_counter() - t0
+                        dst = np.frombuffer(
+                            buf, dtype=np.dtype(m.dtype),
+                            count=host.size, offset=m.offset,
+                        ).reshape(m.shape)
+                        # GIL released during the memcpy: a multi-GB
+                        # snapshot must not starve heartbeat/IPC
+                        # threads
+                        t0 = _time.perf_counter()
+                        copy_into(dst, host)
+                        t_copy += _time.perf_counter() - t0
+                    sp.set_attribute("copy_s", round(t_copy, 6))
+                    sp.set_attribute(
+                        "contiguous_s", round(t_contiguous, 6)
+                    )
+                t_memcpy += t_copy
+                chunk.clear()
 
             for i, (key, arr) in enumerate(entries):
-                if isinstance(arr, np.ndarray):
-                    m = metas[key]
-                    dst = np.frombuffer(
-                        buf, dtype=np.dtype(m.dtype),
-                        count=arr.size, offset=m.offset,
-                    ).reshape(m.shape)
-                    t0 = _time.perf_counter()
-                    copy_into(dst, arr)
-                    t_memcpy += _time.perf_counter() - t0
-                else:
-                    chunk.append((key, arr))
-                    chunk_bytes += metas[key].nbytes
-                    if chunk_bytes >= CHUNK:
-                        flush(chunk)
-                        chunk, chunk_bytes = [], 0
+                chunk.append((key, arr))
+                chunk_bytes += metas[key].nbytes
                 entries[i] = (key, None)  # free eagerly
-            flush(chunk)
-            buf[offset:offset + len(scalar_blob)] = scalar_blob
+                if chunk_bytes >= CHUNK:
+                    flush(chunk, chunk_bytes)
+                    chunk_bytes = 0
+            flush(chunk, chunk_bytes)
+            with _span("ckpt.save.scalars", step=step):
+                buf[offset:offset + len(scalar_blob)] = scalar_blob
             config.writing = False
-            self._publish_meta(metas, config, offset, len(scalar_blob))
+            with _span("ckpt.save.publish_meta", step=step):
+                self._publish_meta(
+                    metas, config, offset, len(scalar_blob)
+                )
         self.last_save_phases = {
             "fetch_s": round(t_fetch, 3),
             "memcpy_s": round(t_memcpy, 3),

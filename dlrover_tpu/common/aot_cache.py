@@ -46,6 +46,7 @@ import hashlib
 import json
 import os
 import pickle
+import re
 import tempfile
 import time
 from dataclasses import dataclass, field
@@ -212,6 +213,61 @@ def key_of(desc: Dict) -> str:
 def entry_path(key: str, cache_dir: Optional[str] = None) -> str:
     cache_dir = cache_dir or aot_cache_dir()
     return os.path.join(cache_dir, key + ENTRY_SUFFIX)
+
+
+OP_NAMES_SUFFIX = ".opnames.json"
+_MODULE = re.compile(r"^HloModule ([\w\-.]+)")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%?[\w\-.]+) = ")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def op_names_path(key: str, cache_dir: Optional[str] = None) -> str:
+    cache_dir = cache_dir or aot_cache_dir()
+    return os.path.join(cache_dir, key + OP_NAMES_SUFFIX)
+
+
+def op_names(hlo_text: str) -> Dict[str, Any]:
+    """``{"module": name, "op_names": {instruction: jax name
+    stack}}`` of an optimized HLO module's text: each instruction's
+    ``op_name`` metadata, which holds the ``jax.named_scope`` names
+    it was lowered under (``jit(step_fn)/optimizer/mul``)."""
+    module = _MODULE.match(hlo_text)
+    names = {}
+    for line in hlo_text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        # the metadata follows the operands; a custom call's
+        # backend_config (a kernel's whole body) comes after it
+        stack = _OP_NAME.search(line)
+        if stack:
+            names[found.group(1)] = stack.group(1)
+    return {
+        "module": module.group(1) if module else None,
+        "op_names": names,
+    }
+
+
+def save_op_names(
+    key: str, compiled: Any, cache_dir: Optional[str] = None,
+) -> bool:
+    """Write the executable's instruction -> name-stack map beside
+    its entry.  A device trace names an operation by its instruction
+    (``%fusion.13``) and, taken without the HLO proto, carries no
+    name stack: with this map a reader can sum device time by the
+    program's own scope names.  Written once, with the entry, on the
+    cold path; optional like the entry itself."""
+    path = op_names_path(key, cache_dir)
+    try:
+        names = op_names(compiled.as_text())
+        tmp = f"{path}.{os.getpid()}.tmp"
+        with open(tmp, "w") as f:
+            json.dump(names, f)
+        os.replace(tmp, path)
+        return True
+    except Exception as e:  # noqa: BLE001 - the map is optional
+        logger.warning("op-name map not written (%s): %s", path, e)
+        return False
 
 
 def aot_entries(cache_dir: Optional[str] = None) -> int:
@@ -576,6 +632,7 @@ def resolve_step(
     wrote = save_entry(key, desc, compiled, cache_dir)
     if wrote:
         _write_index(label, key, desc, cache_dir)
+        save_op_names(key, compiled, cache_dir)
     save_s = time.perf_counter() - t0
     return Resolution(
         # guarded like the hit path: the compile ran against the

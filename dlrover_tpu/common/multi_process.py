@@ -157,16 +157,26 @@ class SharedLock(LocalSocketComm):
     def __init__(self, name: str, create: bool):
         self._lock = threading.Lock() if create else None
         self._owner: Optional[str] = None
+        # what the holder said it holds the lock for ("persist:120")
+        self._note = ""
+        # after acquire(): the holder's note if the first try found
+        # the lock taken, else None — who a wait was spent behind
+        self.contended_with: Optional[str] = None
         super().__init__(name, create)
 
     def _handle(self, request):
         verb = request[0]
         if verb == "try_acquire":
-            (_, owner) = request
+            (_, owner, *note) = request
             ok = self._lock.acquire(blocking=False)
             if ok:
                 self._owner = owner
+                self._note = note[0] if note else ""
             return ok
+        if verb == "holder":
+            if not self._lock.locked():
+                return None
+            return self._note or self._owner
         if verb == "release":
             (_, owner) = request
             # only the holder (or a force-release, e.g. agent cleanup
@@ -182,18 +192,33 @@ class SharedLock(LocalSocketComm):
             return self._lock.locked()
         raise ValueError(f"unknown lock verb {verb}")
 
-    def _try_acquire(self, owner: str) -> bool:
+    def _try_acquire(self, owner: str, note: str = "") -> bool:
         if self._create:
-            return self._handle(("try_acquire", owner))
-        return self._request("try_acquire", owner)
+            return self._handle(("try_acquire", owner, note))
+        return self._request("try_acquire", owner, note)
 
-    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+    def holder(self) -> Optional[str]:
+        """The current holder's note (its owner tag if it gave none),
+        None when the lock is free."""
+        if self._create:
+            return self._handle(("holder",))
+        return self._request("holder")
+
+    def acquire(
+        self, blocking: bool = True, timeout: float = -1,
+        note: str = "",
+    ) -> bool:
         owner = f"pid-{os.getpid()}"
+        self.contended_with = None
+        if self._try_acquire(owner, note):
+            return True
+        # one more round trip, on contention only
+        self.contended_with = self.holder()
         if not blocking:
-            return self._try_acquire(owner)
+            return False
         deadline = None if timeout < 0 else time.monotonic() + timeout
         while True:
-            if self._try_acquire(owner):
+            if self._try_acquire(owner, note):
                 return True
             if deadline is not None and time.monotonic() >= deadline:
                 return False

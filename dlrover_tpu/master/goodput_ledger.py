@@ -22,6 +22,7 @@ import time
 from typing import Dict, List, Optional
 
 from dlrover_tpu.telemetry.events import collect_events, emit_event
+from dlrover_tpu.telemetry.tracing import span as _span
 from dlrover_tpu.telemetry.metrics import (
     MetricsRegistry,
     get_registry,
@@ -73,13 +74,23 @@ class GoodputLedgerService:
     def tick(self, now: Optional[float] = None) -> bool:
         """Assemble + publish once.  Returns True when a ledger was
         built (False = no events yet)."""
-        from dlrover_tpu.telemetry import goodput as _goodput
         from dlrover_tpu.telemetry.timeline import default_sources
 
         self._last_tick = now or time.time()
-        events = collect_events(self._sources or default_sources())
-        if not events:
-            return False
+        # one span a tick: it re-reads every event log, beside the
+        # worker on a one-host job
+        with _span("master.goodput_ledger_tick") as sp:
+            events = collect_events(
+                self._sources or default_sources()
+            )
+            sp.set_attribute("events", len(events))
+            if not events:
+                return False
+            return self._publish(events)
+
+    def _publish(self, events) -> bool:
+        from dlrover_tpu.telemetry import goodput as _goodput
+
         ledger = _goodput.build_ledger(events)
         for cat in _goodput.CATEGORIES:
             total = ledger.totals.get(cat, 0.0)

@@ -50,6 +50,7 @@ from dlrover_tpu.telemetry.gcp_monitoring import (
 from dlrover_tpu.telemetry.metrics import get_registry
 from dlrover_tpu.telemetry.otlp import maybe_from_env as otlp_from_env
 from dlrover_tpu.telemetry.slo import SloChecker
+from dlrover_tpu.telemetry.tracing import span as _span
 
 _RECOVERIES_TOTAL = get_registry().counter(
     "dlrover_master_recoveries_total",
@@ -335,7 +336,8 @@ class JobMaster:
         re-applied (idempotently) at replay — raced mutations may be
         double-applied, never lost."""
         seq = self.journal.last_seq
-        self.journal.snapshot(capture_snapshot(self), seq=seq)
+        with _span("master.journal_snapshot", seq=seq):
+            self.journal.snapshot(capture_snapshot(self), seq=seq)
 
     def _journal_rdzv_round(self, name, round_, participants):
         if self.journal is not None:
@@ -494,7 +496,8 @@ class JobMaster:
                 # control-plane SLOs: hold the per-verb RPC latency
                 # histograms to their declared bounds every poll
                 try:
-                    self.slo_checker.check()
+                    with _span("master.slo_check"):
+                        self.slo_checker.check()
                 except Exception:  # noqa: BLE001 - policing must
                     logger.exception("SLO check failed")  # not kill
                 # elastic world-resize: capacity changes (node loss,

@@ -434,14 +434,18 @@ class GPT(nn.Module):
                 name="value_head",
             )(x.astype(cfg.dtype))
             return v[..., 0]
-        if cfg.tie_embeddings:
-            logits = wte.attend(x.astype(cfg.dtype))
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="lm_head",
-            )(x)
-        return logits.astype(jnp.float32)
+        # device scope "loss_head": the logits projection here and
+        # the reduction in cross_entropy_loss, forward, backward and
+        # remat copies alike
+        with jax.named_scope("loss_head"):
+            if cfg.tie_embeddings:
+                logits = wte.attend(x.astype(cfg.dtype))
+            else:
+                logits = nn.Dense(
+                    cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name="lm_head",
+                )(x)
+            return logits.astype(jnp.float32)
 
     def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
         seq_len = seq_len or min(self.config.max_seq_len, 128)
@@ -451,9 +455,10 @@ class GPT(nn.Module):
 
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Mean next-token cross entropy; fp32 for the reduction."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-    return nll.mean()
+    with jax.named_scope("loss_head"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+        return nll.mean()
 
 
 def count_params(params) -> int:

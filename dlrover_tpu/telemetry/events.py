@@ -90,10 +90,19 @@ class TrainingEventExporter:
     def emit(self, event_type: str, **fields) -> bool:
         """Append one event; returns False when unconfigured or the
         write failed (never raises into the training path)."""
+        return self.emit_many(event_type, [fields])
+
+    def emit_many(self, event_type: str, records) -> bool:
+        """Append several events of one type with ONE write: each
+        open / append / close is a system call, and on a sandboxed
+        host a single event costs ~0.6 ms (my chip run, PR 25), so
+        the ~35 spans of a flash save go out together.  A record's
+        own ``ts`` stands (an event written after it happened);
+        otherwise it is now.  Same contract as :meth:`emit`."""
         path = self.path
         if not path:
             return False
-        record = {
+        envelope = {
             "schema": EVENT_SCHEMA_VERSION,
             "ts": time.time(),
             "pid": os.getpid(),
@@ -107,9 +116,11 @@ class TrainingEventExporter:
             ),
             "type": event_type,
         }
-        record.update(fields)
         try:
-            line = json.dumps(record, default=str)
+            line = "\n".join(
+                json.dumps({**envelope, **fields}, default=str)
+                for fields in records
+            )
         except (TypeError, ValueError):
             return False
         with self._lock:
@@ -343,6 +354,12 @@ def get_exporter() -> TrainingEventExporter:
 def emit_event(event_type: str, **fields) -> bool:
     """Process-global convenience used by instrumented subsystems."""
     return get_exporter().emit(event_type, **fields)
+
+
+def emit_many(event_type: str, records) -> bool:
+    """:meth:`TrainingEventExporter.emit_many` on the global
+    exporter."""
+    return get_exporter().emit_many(event_type, records)
 
 
 def set_event_source(source: str):

@@ -26,7 +26,7 @@ import os
 import sys
 from typing import Dict, List, Optional, Tuple
 
-from dlrover_tpu.telemetry.schema import EVENT_SCHEMAS
+from dlrover_tpu.telemetry.schema import EVENT_SCHEMAS, SPAN_SCHEMAS
 
 # a string constant is considered an embedded script when it is at
 # least this long and mentions an emit call — short docstrings that
@@ -41,14 +41,15 @@ ALLOWED_UNEMITTED: Tuple[str, ...] = ()
 
 def _emit_name(node: ast.Call) -> Optional[str]:
     """The emitted event-type literal, for calls shaped like
-    ``emit_event("x", ...)`` / ``something.emit("x", ...)``."""
+    ``emit_event("x", ...)`` / ``something.emit("x", ...)`` /
+    ``emit_many("x", records)``."""
     func = node.func
     name = ""
     if isinstance(func, ast.Name):
         name = func.id
     elif isinstance(func, ast.Attribute):
         name = func.attr
-    if name not in ("emit_event", "emit"):
+    if name not in ("emit_event", "emit", "emit_many"):
         return None
     if not node.args:
         return None
@@ -89,14 +90,11 @@ def _collect_from_tree(
                 )
 
 
-def collect_emitted_types(
-    package_dir: Optional[str] = None,
-) -> Dict[str, List[str]]:
-    """Map every statically-visible emitted event type to the call
-    sites (``relpath:line``) that emit it."""
+def _package_trees(package_dir: Optional[str]):
+    """``(relpath, ast or None, error)`` of every ``.py`` under the
+    package (default: dlrover_tpu)."""
     if package_dir is None:
         package_dir = os.path.dirname(os.path.dirname(__file__))
-    emitted: Dict[str, List[str]] = {}
     for root, dirs, files in os.walk(package_dir):
         dirs[:] = [d for d in dirs if d != "__pycache__"]
         for fname in sorted(files):
@@ -106,13 +104,24 @@ def collect_emitted_types(
             rel = os.path.relpath(path, package_dir)
             try:
                 with open(path, "r", encoding="utf-8") as f:
-                    tree = ast.parse(f.read(), filename=rel)
+                    yield rel, ast.parse(f.read(), filename=rel), None
             except (OSError, SyntaxError) as exc:
-                emitted.setdefault("<unparseable>", []).append(
-                    f"{rel}: {exc}"
-                )
-                continue
-            _collect_from_tree(tree, rel, emitted)
+                yield rel, None, exc
+
+
+def collect_emitted_types(
+    package_dir: Optional[str] = None,
+) -> Dict[str, List[str]]:
+    """Map every statically-visible emitted event type to the call
+    sites (``relpath:line``) that emit it."""
+    emitted: Dict[str, List[str]] = {}
+    for rel, tree, exc in _package_trees(package_dir):
+        if tree is None:
+            emitted.setdefault("<unparseable>", []).append(
+                f"{rel}: {exc}"
+            )
+            continue
+        _collect_from_tree(tree, rel, emitted)
     return emitted
 
 
@@ -140,10 +149,57 @@ def lint(package_dir: Optional[str] = None) -> List[str]:
     return problems
 
 
+def collect_span_names(
+    package_dir: Optional[str] = None,
+) -> Dict[str, List[str]]:
+    """Every literal span name (``span("x", ...)``, ``_span("x")``,
+    ``trace.span("x")``, ``record_span("x", t0, t1)``) mapped to its
+    call sites."""
+    names: Dict[str, List[str]] = {}
+    for rel, tree, _ in _package_trees(package_dir):
+        if tree is None:
+            continue  # lint() reports unparseable sources
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call) or not node.args:
+                continue
+            func = node.func
+            called = (
+                func.id if isinstance(func, ast.Name)
+                else getattr(func, "attr", "")
+            )
+            first = node.args[0]
+            if called in (
+                "span", "_span", "record_span"
+            ) and isinstance(first, ast.Constant) and isinstance(
+                first.value, str
+            ):
+                names.setdefault(first.value, []).append(
+                    f"{rel}:{node.lineno}"
+                )
+    return names
+
+
+def lint_spans(package_dir: Optional[str] = None) -> List[str]:
+    """Span names used but not in ``schema.SPAN_SCHEMAS``, and
+    registered names no call site opens."""
+    used = collect_span_names(package_dir)
+    problems = [
+        f"span name {name!r} is not registered in "
+        f"schema.SPAN_SCHEMAS ({', '.join(sites[:3])})"
+        for name, sites in sorted(used.items())
+        if name not in SPAN_SCHEMAS
+    ]
+    problems += [
+        f"span schema entry {name!r} has no call site"
+        for name in sorted(SPAN_SCHEMAS) if name not in used
+    ]
+    return problems
+
+
 def main(argv=None) -> int:
     args = list(sys.argv[1:] if argv is None else argv)
     package_dir = args[0] if args else None
-    problems = lint(package_dir)
+    problems = lint(package_dir) + lint_spans(package_dir)
     for p in problems:
         print(p)
     if problems:

@@ -54,10 +54,13 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
     s.type: s
     for s in (
         # -- telemetry core ------------------------------------------
+        # start_ts = the wall clock at the span's entry, the same
+        # reading its profiler annotation carries as ``wall_ns``
+        # (optional: logs recorded before it existed stay valid)
         _s("span", [
             "name", "trace_id", "span_id", "parent_id",
             "duration_s", "status", "attributes",
-        ]),
+        ], ["start_ts"]),
         # -- master lifecycle ----------------------------------------
         _s("master_start", ["job", "port", "node_num", "metrics_port"]),
         _s("master_exit", [
@@ -126,7 +129,8 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         _s("checkpoint_restore", ["step", "tier", "rank"],
            allow_extra=True),
         _s("checkpoint_persist", ["step", "ok", "seconds"]),
-        _s("checkpoint_commit", ["step"]),
+        # start_ts / seconds: the commit poll (done files -> tracker)
+        _s("checkpoint_commit", ["step"], ["start_ts", "seconds"]),
         # sparse (KvVariable) state riding the flash checkpoint:
         # stage=export on every save, stage=restore on every import;
         # resharded restores carry exactly-once accounting
@@ -319,6 +323,127 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
            ["max_sustained_agents"],
            ["rps_at_capacity", "levels", "search_s",
             "first_breach_agents"]),
+    )
+}
+
+
+@dataclass(frozen=True)
+class SpanSchema:
+    """One span name: the process that records it and what an
+    operator reads from it (README "Telemetry" prints this table)."""
+
+    name: str
+    process: str
+    reads: str
+
+
+# every literal span name of the package (``lint_events.lint_spans``
+# holds the two in step).  In a process that has imported jax (the
+# trainer) a span is also the profiler annotation ``dlrover.<name>``.
+SPAN_SCHEMAS: Dict[str, SpanSchema] = {
+    s.name: s
+    for s in (
+        SpanSchema(
+            "rdzv.join", "agent + master",
+            "one rendezvous join, agent side linked to the master's "
+            "handler by the RPC's trace context"),
+        SpanSchema(
+            "node_check", "agent",
+            "one node-check round before workers start"),
+        SpanSchema(
+            "journal.replay", "master",
+            "replay of the state journal at master start"),
+        SpanSchema(
+            "ckpt.restore", "trainer",
+            "one restore; tier and stage seconds in its attributes"),
+        # -- a flash save, trainer side (one trace id a save) --------
+        SpanSchema(
+            "ckpt.save", "trainer",
+            "one save_checkpoint call: how long it blocked the loop "
+            "(step, storage, bytes, ok); its children say where"),
+        SpanSchema(
+            "ckpt.save.notify_agent", "trainer",
+            "shipping the saver config to the agent (first save)"),
+        SpanSchema(
+            "ckpt.save.sparse_merge", "trainer",
+            "exporting KvVariable tables into the state"),
+        SpanSchema(
+            "ckpt.save.lock_wait", "trainer",
+            "waiting for the shard's shm lock; held_by names the "
+            "holder (persist:<step> = the agent's in-RAM copy)"),
+        SpanSchema(
+            "ckpt.save.layout", "trainer",
+            "flattening the pytree and laying out the segment"),
+        SpanSchema(
+            "ckpt.save.segment", "trainer",
+            "creating (or growing) the shm segment"),
+        SpanSchema(
+            "ckpt.save.publish_meta", "trainer",
+            "publishing the layout to the agent's meta dict (twice "
+            "a save: writing=True, then False)"),
+        SpanSchema(
+            "ckpt.save.fetch", "trainer",
+            "device->host transfer of one ~256 MB chunk (bytes)"),
+        SpanSchema(
+            "ckpt.save.memcpy", "trainer",
+            "native copy of one chunk into the segment (bytes)"),
+        SpanSchema(
+            "ckpt.save.scalars", "trainer",
+            "writing the pickled non-array leaves"),
+        SpanSchema(
+            "ckpt.save.snapshot", "trainer",
+            "DISK save: on-device copy of the state"),
+        SpanSchema(
+            "ckpt.save.d2h_kickoff", "trainer",
+            "DISK save: starting the async device->host copies"),
+        SpanSchema(
+            "ckpt.save.enqueue", "trainer",
+            "DISK save: handing the snapshot to the writer thread "
+            "(or the persist request to the agent)"),
+        SpanSchema(
+            "ckpt.save.write", "trainer (writer thread)",
+            "DISK save: the snapshot's shm write, off the loop, same "
+            "trace id as its ckpt.save"),
+        # -- a persist, agent side -----------------------------------
+        SpanSchema(
+            "ckpt.persist", "agent",
+            "one persist of a step: shm -> storage -> commit"),
+        SpanSchema(
+            "ckpt.persist.prefault", "agent",
+            "touching the segment's pages before taking the lock"),
+        SpanSchema(
+            "ckpt.persist.lock_hold", "agent",
+            "how long a shard's shm lock was held: the in-RAM copy "
+            "of the segment (bytes; wait_s = how long the lock took "
+            "to get)"),
+        SpanSchema(
+            "ckpt.persist.write_storage", "agent",
+            "writing the copy, its meta and the done file"),
+        SpanSchema(
+            "ckpt.persist.commit", "agent",
+            "polling the done files and moving the tracker"),
+        # -- periodic services beside the worker (one span a tick) ---
+        SpanSchema(
+            "master.goodput_ledger_tick", "master",
+            "re-reading every event log into the goodput ledger"),
+        SpanSchema(
+            "master.slo_check", "master",
+            "one pass over the RPC latency SLOs"),
+        SpanSchema(
+            "master.journal_snapshot", "master",
+            "folding the journal into a snapshot"),
+        SpanSchema(
+            "agent.training_monitor", "agent",
+            "reading the metrics file, reporting the global step"),
+        SpanSchema(
+            "agent.resource_monitor", "agent",
+            "host CPU and memory, reported to the master"),
+        SpanSchema(
+            "agent.diagnosis_collect", "agent",
+            "one round of the diagnosis collectors"),
+        SpanSchema(
+            "agent.heartbeat", "agent",
+            "one heartbeat RPC"),
     )
 }
 

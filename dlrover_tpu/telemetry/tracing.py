@@ -14,12 +14,21 @@ in-process consumers/tests, (2) observed into the
 and (3) emitted as a ``span`` training event when an event log is
 configured — which is how cross-process parent/child linkage is
 verified end to end.
+
+One clock: in a process that has imported jax, every span also enters
+a ``jax.profiler.TraceAnnotation`` named ``dlrover.<span name>``
+(:func:`annotation`).  It reaches a device trace only while a
+profiler session is active (a flag check otherwise) and carries the
+wall clock at entry (``wall_ns``), so a reducer can place the event
+log's ``ts`` / ``start_ts`` of EVERY process (agent and master
+included) on the profiler's clock from any one span.
 """
 
 import contextvars
+import os
+import sys
 import threading
 import time
-import uuid
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -43,8 +52,46 @@ _current_span: "contextvars.ContextVar[Optional[SpanContext]]" = (
 )
 
 
+# Where the spans that finish INSIDE another span of this process
+# wait for it: the outermost span writes them with itself, in one
+# append (a flash save is ~35 spans; one write, not 35).  None outside
+# any span.  A thread that continues a trace through
+# ``attach_context`` starts a buffer of its own; a thread handed a
+# COPY of the context shares the outer span's, which must outlive it.
+_span_buffer: "contextvars.ContextVar[Optional[list]]" = (
+    contextvars.ContextVar("dlrover_span_buffer", default=None)
+)
+
+
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return os.urandom(8).hex()
+
+
+ANNOTATION_PREFIX = "dlrover."
+
+
+def annotation(name: str, wall_ns: int, **stats):
+    """An entered ``jax.profiler.TraceAnnotation`` named
+    ``dlrover.<name>`` with ``wall_ns`` (``time.time_ns()`` at entry)
+    and ``stats`` as its stats, or None.
+
+    jax is looked up in ``sys.modules`` and never imported: the agent
+    and the master never touch jax (a process that did would hold the
+    chip), so their spans reach the event log only.  Close it with
+    ``__exit__(None, None, None)``.  Tracing must never raise into
+    the operation it measures."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ann = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + name, wall_ns=wall_ns,
+            **{k: v for k, v in stats.items() if v is not None},
+        )
+        ann.__enter__()
+        return ann
+    except Exception:  # noqa: BLE001 - a half-imported jax, an
+        return None  # older profiler: the event log still has it
 
 
 @dataclass
@@ -102,15 +149,25 @@ class Tracer:
     def span(self, name: str, **attributes):
         parent = _current_span.get()
         trace_id = parent.trace_id if parent else _new_id()
+        wall_ns = time.time_ns()
         s = Span(
             name=name,
             trace_id=trace_id,
             span_id=_new_id(),
             parent_id=parent.span_id if parent else None,
-            start_time=time.time(),
+            start_time=wall_ns / 1e9,
             attributes=dict(attributes),
         )
+        # (the profiler reads a stat that looks like a number as one:
+        # an all-digit hex id would come back an int, its leading
+        # zeros gone; the "s" keeps the join key a string)
+        ann = annotation(
+            name, wall_ns, step=attributes.get("step"),
+            span_id="s" + s.span_id,
+        )
         token = _current_span.set(s.context)
+        outer = _span_buffer.get()
+        inner = _span_buffer.set([]) if outer is None else None
         try:
             yield s
         except BaseException as e:
@@ -120,9 +177,42 @@ class Tracer:
         finally:
             _current_span.reset(token)
             s.end_time = time.time()
-            self._record(s)
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if inner is None:
+                self._record(s, outer)
+            else:
+                finished = _span_buffer.get()
+                _span_buffer.reset(inner)
+                self._record(s, None, finished)
 
-    def _record(self, s: Span):
+    def record_span(
+        self, name: str, start_time: float, end_time: float,
+        **attributes,
+    ) -> Span:
+        """Record a span whose two clock readings (``time.time()``)
+        the caller took itself, as a child of the current span: for
+        a stretch that must run no tracing code at all (the agent's
+        in-RAM copy under the shm lock, where a trainer is waiting
+        for the release).  No profiler annotation."""
+        parent = _current_span.get()
+        s = Span(
+            name=name,
+            trace_id=parent.trace_id if parent else _new_id(),
+            span_id=_new_id(),
+            parent_id=parent.span_id if parent else None,
+            start_time=start_time,
+            end_time=end_time,
+            attributes=dict(attributes),
+        )
+        self._record(s, _span_buffer.get())
+        return s
+
+    def _record(self, s: Span, buffer=None, finished=()):
+        """Keep, observe and export one finished span.  Its event
+        waits in ``buffer`` (the enclosing span's) when there is one;
+        otherwise it is written now, after the ``finished`` spans it
+        enclosed."""
         with self._lock:
             self._finished.append(s)
             listeners = list(self._listeners)
@@ -135,16 +225,22 @@ class Tracer:
             self._duration_hist.observe(s.duration, name=s.name)
         except Exception:  # noqa: BLE001 - telemetry must not raise
             pass
-        _events.emit_event(
-            "span",
+        event = dict(
+            # when the span ended, whenever it is written
+            ts=s.end_time,
             name=s.name,
             trace_id=s.trace_id,
             span_id=s.span_id,
             parent_id=s.parent_id,
+            start_ts=s.start_time,
             duration_s=round(s.duration, 6),
             status=s.status,
             attributes=s.attributes,
         )
+        if buffer is not None:
+            buffer.append(event)
+        else:
+            _events.emit_many("span", [*finished, event])
 
     def finished_spans(self, name: Optional[str] = None) -> List[Span]:
         with self._lock:
@@ -178,6 +274,15 @@ def span(name: str, **attributes):
         yield s
 
 
+def record_span(
+    name: str, start_time: float, end_time: float, **attributes
+) -> Span:
+    """:meth:`Tracer.record_span` on the global tracer."""
+    return get_tracer().record_span(
+        name, start_time, end_time, **attributes
+    )
+
+
 def current_context() -> Optional[SpanContext]:
     return _current_span.get()
 
@@ -206,7 +311,11 @@ def attach_context(wire_ctx: Optional[Dict[str, str]]):
         yield
         return
     token = _current_span.set(SpanContext(trace_id, span_id))
+    # the caller's span lives elsewhere: spans opened here are
+    # written when the outermost of them ends
+    buffer = _span_buffer.set(None)
     try:
         yield
     finally:
+        _span_buffer.reset(buffer)
         _current_span.reset(token)

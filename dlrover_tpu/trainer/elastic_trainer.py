@@ -14,6 +14,7 @@ Sharding: params/opt-state placed by partition rules, batch split over
 the data axes; XLA inserts the gradient psum.
 """
 
+import gc
 import json
 import os
 import time
@@ -37,6 +38,7 @@ from dlrover_tpu.parallel.sharding import (
 )
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
+from dlrover_tpu.telemetry.tracing import annotation
 
 _REG = get_registry()
 _REPORTED_STEP = _REG.gauge(
@@ -54,6 +56,36 @@ _STEP_PHASE_SECONDS = _REG.histogram(
 )
 
 
+class _GcClock:
+    """Seconds this process has spent in garbage collections, from
+    ``gc.callbacks``: two clock reads a collection.  One callback for
+    the process, whatever number of profilers read it; each
+    collection is also a ``dlrover.step.gc`` profiler annotation."""
+
+    def __init__(self):
+        self.total = 0.0
+        self._started = 0.0
+        self._ann = None
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase, info):
+        if phase == "start":
+            self._ann = annotation(
+                "step.gc", time.time_ns(),
+                generation=info.get("generation"),
+            )
+            self._started = time.perf_counter()
+        elif self._started:
+            self.total += time.perf_counter() - self._started
+            self._started = 0.0
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+
+
+_GC_CLOCK = _GcClock()
+
+
 class StepPhaseProfiler:
     """Always-on phase breakdown of one training step.
 
@@ -61,9 +93,9 @@ class StepPhaseProfiler:
     pipeline dominates) from a *slow* one (compute dominates) from a
     *hung* one (nothing progresses), which requires real per-phase
     durations — a bare step time cannot distinguish them.  Cost per
-    phase is two ``perf_counter`` reads and a dict add (~1 µs), so
-    this stays on in production; the event emission is a no-op unless
-    an event log is configured.
+    phase is two ``perf_counter`` reads, a dict add and one inactive
+    profiler annotation (~2 µs), so this stays on in production; the
+    event emission is a no-op unless an event log is configured.
 
     The canonical phases are ``data_wait`` (blocking on the input
     pipeline), ``h2d`` (host-to-device transfer), ``compute`` (the
@@ -71,6 +103,13 @@ class StepPhaseProfiler:
     dispatch doesn't leak compute time into the next data wait),
     ``checkpoint`` and ``report``; arbitrary names are accepted.
     Un-profiled remainder of the step lands in ``other``.
+
+    Two kinds of entry stand BESIDE the phases and are not summed
+    into them: sub-phases (``report.events``, a name with a dot: a
+    part of the phase before the dot) and ``gc`` (seconds of garbage
+    collection during the step, inside whichever phase it
+    interrupted).  Every phase and sub-phase is also a
+    ``dlrover.step.<name>`` profiler annotation carrying the step.
     """
 
     KNOWN_PHASES = (
@@ -79,11 +118,22 @@ class StepPhaseProfiler:
 
     def __init__(self):
         self._acc: Dict[str, float] = {}
+        self._sub: Dict[str, float] = {}
+        self._open: Dict[str, float] = {}
         self._step_started = time.perf_counter()
+        self._gc_seen = _GC_CLOCK.total
+        # the step in progress (the trainer sets it): a stat of every
+        # phase's profiler annotation
+        self.step = 0
 
     @contextmanager
     def phase(self, name: str):
+        """One phase of the step; a name with a dot (``report.
+        metrics_file``) is a sub-phase, recorded beside the phases."""
+        into = self._sub if "." in name else self._acc
+        ann = annotation("step." + name, time.time_ns(), step=self.step)
         start = time.perf_counter()
+        self._open[name] = start
         handle = PhaseHandle()
         try:
             yield handle
@@ -94,24 +144,47 @@ class StepPhaseProfiler:
                 except Exception:  # noqa: BLE001 - profiling must
                     pass  # never break the step it measures
             dt = time.perf_counter() - start
-            self._acc[name] = self._acc.get(name, 0.0) + dt
+            self._open.pop(name, None)
+            into[name] = into.get(name, 0.0) + dt
+            if ann is not None:
+                ann.__exit__(None, None, None)
 
     def add(self, name: str, seconds: float):
         """Record an externally-timed phase (e.g. the checkpoint
-        engine's own stall measurement)."""
-        self._acc[name] = self._acc.get(name, 0.0) + float(seconds)
+        engine's own stall measurement) or sub-phase."""
+        into = self._sub if "." in name else self._acc
+        into[name] = into.get(name, 0.0) + float(seconds)
+
+    def _phases(self, now: float) -> Dict[str, float]:
+        acc = dict(self._acc)
+        sub = dict(self._sub)
+        for name, start in self._open.items():
+            into = sub if "." in name else acc
+            into[name] = into.get(name, 0.0) + now - start
+        total = max(0.0, now - self._step_started)
+        phases = {k: round(v, 6) for k, v in acc.items()}
+        phases.update((k, round(v, 6)) for k, v in sub.items())
+        phases["gc"] = round(_GC_CLOCK.total - self._gc_seen, 6)
+        phases["total_s"] = round(total, 6)
+        phases["other_s"] = round(
+            max(0.0, total - sum(acc.values())), 6
+        )
+        return phases
+
+    def peek(self) -> Dict[str, float]:
+        """The step's breakdown so far, phases still open included;
+        nothing is reset."""
+        return self._phases(time.perf_counter())
 
     def finish_step(self) -> Dict[str, float]:
         """Close the step: returns ``{phase: seconds, ...,
         "total_s", "other_s"}`` and resets for the next step."""
         now = time.perf_counter()
-        total = max(0.0, now - self._step_started)
-        phases = {k: round(v, 6) for k, v in self._acc.items()}
-        profiled = sum(self._acc.values())
-        phases["total_s"] = round(total, 6)
-        phases["other_s"] = round(max(0.0, total - profiled), 6)
+        phases = self._phases(now)
         self._acc.clear()
+        self._sub.clear()
         self._step_started = now
+        self._gc_seen = _GC_CLOCK.total
         return phases
 
 
@@ -220,7 +293,8 @@ def make_train_step(
     """
 
     def grads_of(params, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope("forward_backward"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         return loss, grads
 
     def step_fn(state: TrainState, batch):
@@ -248,19 +322,20 @@ def make_train_step(
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
         else:
             loss, grads = grads_of(state.params, batch)
-        updates, new_opt = optimizer.update(
-            grads, state.opt_state, state.params
-        )
         import optax
 
-        new_params = optax.apply_updates(state.params, updates)
+        # device scope: every op of the optimizer pass carries
+        # "optimizer" in its name stack, so a trace can sum them
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1
         )
-        metrics = {
-            "loss": loss,
-            "grad_norm": optax.global_norm(grads),
-        }
+        metrics = {"loss": loss, "grad_norm": grad_norm}
         return new_state, metrics
 
     if mesh is None:
@@ -417,8 +492,45 @@ class ElasticTrainer:
     def report_step(self, metrics: Optional[Dict[str, float]] = None):
         """Advance the step counter and write the metrics file the
         agent monitor tails (reference: trainer.py report to file +
-        monitor/training.py)."""
-        report_start = time.perf_counter()
+        monitor/training.py).  All of it is the step's ``report``
+        phase, in three sub-phases: ``report.events``,
+        ``report.chip_metrics`` and ``report.metrics_file``."""
+        prof = self.profiler
+        with prof.phase("report"):
+            with prof.phase("report.events"):
+                self._emit_train_step(metrics)
+            with prof.phase("report.chip_metrics"):
+                chip_metrics = _chip_metrics()
+            with prof.phase("report.metrics_file"):
+                # the file's ``phases`` are the step's so far: its
+                # own write is still open, so counted up to here
+                self._write_metrics_file(
+                    metrics, chip_metrics, prof.peek()
+                )
+        # close the step's phase breakdown: everything since the last
+        # report (minus profiled phases) is "other"
+        phases = prof.finish_step()
+        self.last_step_phases = phases
+        for name, seconds in phases.items():
+            if name == "total_s":
+                continue
+            _STEP_PHASE_SECONDS.observe(
+                seconds,
+                phase="other" if name == "other_s" else name,
+            )
+        prof.step = self.global_step + 1
+        # the breakdown's own event is the first thing the next step
+        # pays for: booked to its ``report``, not left in ``other``
+        with prof.phase("report"), prof.phase("report.events"):
+            # dict-build instead of kwargs so a user phase named
+            # "step" can never collide with the envelope fields
+            emit_event("step_phases", **{
+                **phases,
+                "step": self.global_step,
+                "node_rank": env_utils.get_node_rank(),
+            })
+
+    def _emit_train_step(self, metrics):
         self.global_step += 1
         _REPORTED_STEP.set(self.global_step)
         # per-step training event: this is what lets the chaos
@@ -445,28 +557,8 @@ class ElasticTrainer:
         # step N's completion in the log before the process dies; a
         # slow rule stretches the observable step time (straggler)
         _chaos.fire("trainer.step", step=self.global_step)
-        # close the step's phase breakdown: everything since the last
-        # report (minus profiled phases) is "other"; the report path
-        # itself (event + chaos hook) is booked as "report"
-        self.profiler.add(
-            "report", time.perf_counter() - report_start
-        )
-        phases = self.profiler.finish_step()
-        self.last_step_phases = phases
-        for name, seconds in phases.items():
-            if name == "total_s":
-                continue
-            _STEP_PHASE_SECONDS.observe(
-                seconds,
-                phase="other" if name == "other_s" else name,
-            )
-        # dict-build instead of kwargs so a user phase named "step"
-        # can never collide with the envelope fields
-        emit_event("step_phases", **{
-            **phases,
-            "step": self.global_step,
-            "node_rank": env_utils.get_node_rank(),
-        })
+
+    def _write_metrics_file(self, metrics, chip_metrics, phases):
         record = {
             "global_step": self.global_step,
             "timestamp": time.time(),
@@ -475,7 +567,6 @@ class ElasticTrainer:
             # master's diagnosis chain (data-starved detection)
             "phases": phases,
         }
-        chip_metrics = _chip_metrics()
         if chip_metrics:
             record["chip_metrics"] = chip_metrics
         if metrics:

@@ -1,24 +1,17 @@
-"""Profiler trace capture + parsing.
+"""Profiler trace capture.
 
-Reference: ATorch's profiler tooling (``utils/parse_trace_json.py``
-parses chrome traces, ``utils/prof.py``/timers).  On TPU the source
-of truth is the XLA profiler: :func:`trace` wraps
-``jax.profiler.trace`` (TensorBoard-compatible output, works on CPU
-too), and :func:`parse_trace_dir` digests the ``*.trace.json.gz``
-events into per-op self-time totals — enough to answer "where did the
-step time go" without TensorBoard.
+:func:`trace` wraps ``jax.profiler`` (TensorBoard-compatible output,
+works on CPU too).  While it is active the program's own spans are in
+the trace beside the device's operations, as ``dlrover.<span name>``
+and ``dlrover.step.<phase>`` annotations that carry the wall clock at
+their entry (:func:`dlrover_tpu.telemetry.tracing.annotation`).
+Reading a trace is the benchmark's business: ``benchmarks/xplane.py``
+(busy and idle time, time per device operation) and
+``benchmarks/scopes.py`` (device time per named scope, the program's
+spans, the clock offset to the event log).
 """
 
-import glob
-import gzip
-import json
-import os
-from collections import defaultdict
 from contextlib import contextmanager
-from dataclasses import dataclass, field
-from typing import Dict, List
-
-from dlrover_tpu.common.log import default_logger as logger
 
 
 @contextmanager
@@ -31,46 +24,3 @@ def trace(logdir: str):
         yield logdir
     finally:
         jax.profiler.stop_trace()
-
-
-@dataclass
-class TraceSummary:
-    total_duration_us: float = 0.0
-    op_self_time_us: Dict[str, float] = field(default_factory=dict)
-
-    def top_ops(self, k: int = 10) -> List:
-        return sorted(
-            self.op_self_time_us.items(),
-            key=lambda kv: -kv[1],
-        )[:k]
-
-
-def parse_trace_dir(logdir: str) -> TraceSummary:
-    """Digest every ``*.trace.json.gz`` under ``logdir`` (the layout
-    ``jax.profiler`` writes: plugins/profile/<run>/*.trace.json.gz``)."""
-    paths = glob.glob(
-        os.path.join(logdir, "**", "*.trace.json.gz"), recursive=True
-    )
-    summary = TraceSummary()
-    per_op = defaultdict(float)
-    t_min, t_max = float("inf"), 0.0
-    for path in paths:
-        try:
-            with gzip.open(path, "rt") as f:
-                data = json.load(f)
-        except (OSError, ValueError) as e:
-            logger.warning("unreadable trace %s: %s", path, e)
-            continue
-        for event in data.get("traceEvents", []):
-            if event.get("ph") != "X":
-                continue
-            dur = float(event.get("dur", 0.0))
-            name = event.get("name", "?")
-            per_op[name] += dur
-            ts = float(event.get("ts", 0.0))
-            t_min = min(t_min, ts)
-            t_max = max(t_max, ts + dur)
-    summary.op_self_time_us = dict(per_op)
-    if t_max > 0 and t_min < float("inf"):
-        summary.total_duration_us = t_max - t_min
-    return summary

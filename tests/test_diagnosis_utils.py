@@ -105,12 +105,18 @@ def test_mup_width_multipliers_and_transfer():
 
 
 def test_profiler_trace_capture_and_parse(tmp_path):
-    """XLA profile of a real computation parses into per-op self
-    times (reference: parse_trace_json.py tooling)."""
+    """A traced block holds the program's own ``dlrover.*``
+    annotations beside the computation: a span and a step phase, each
+    with the wall clock at its entry."""
+    import glob
+
     import jax
     import jax.numpy as jnp
+    from jax.profiler import ProfileData
 
-    from dlrover_tpu.utils.profiler import parse_trace_dir, trace
+    from dlrover_tpu.telemetry.tracing import Tracer
+    from dlrover_tpu.trainer.elastic_trainer import StepPhaseProfiler
+    from dlrover_tpu.utils.profiler import trace
 
     @jax.jit
     def f(x):
@@ -118,12 +124,25 @@ def test_profiler_trace_capture_and_parse(tmp_path):
 
     x = jnp.ones((256, 256))
     float(f(x))  # compile outside the trace
+    phases = StepPhaseProfiler()
+    phases.step = 7
     with trace(str(tmp_path)):
-        float(f(x))
-    summary = parse_trace_dir(str(tmp_path))
-    assert summary.op_self_time_us, "no trace events parsed"
-    assert summary.total_duration_us > 0
-    assert summary.top_ops(3)
+        with Tracer().span("ckpt.save", step=7):
+            with phases.phase("compute") as p:
+                p.block(f(x))
+    (path,) = glob.glob(
+        str(tmp_path / "**" / "*.xplane.pb"), recursive=True
+    )
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("dlrover."):
+                    found[event.name] = dict(event.stats)
+    assert set(found) >= {"dlrover.ckpt.save", "dlrover.step.compute"}
+    for stats in found.values():
+        assert int(stats["step"]) == 7
+        assert int(stats["wall_ns"]) > 1.6e18
 
 
 def test_comm_perf_check_reports_bandwidth():

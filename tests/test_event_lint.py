@@ -71,3 +71,42 @@ def test_unparseable_source_is_a_problem(tmp_path):
     (tmp_path / "broken.py").write_text("def f(:\n")
     problems = lint_events.lint(str(tmp_path))
     assert any("unparseable" in p for p in problems), problems
+
+
+def test_package_span_names_match_the_registry():
+    """Every literal span name of the package is in
+    ``schema.SPAN_SCHEMAS`` (README "Telemetry" prints that table),
+    and every registered name has a call site."""
+    problems = lint_events.lint_spans()
+    assert problems == [], "\n".join(problems)
+
+
+def test_unregistered_span_name_is_reported(tmp_path):
+    (tmp_path / "mod.py").write_text(textwrap.dedent("""
+        from dlrover_tpu.telemetry.tracing import span as _span
+
+        def f():
+            with _span("totally.unregistered_span", step=1):
+                pass
+    """))
+    problems = lint_events.lint_spans(str(tmp_path))
+    assert any(
+        "totally.unregistered_span" in p and "not registered" in p
+        for p in problems
+    ), problems
+    # and a package with no span leaves every registered name dead
+    assert any("'ckpt.save'" in p and "no call site" in p
+               for p in problems)
+
+
+def test_readme_lists_every_span():
+    from dlrover_tpu.telemetry.schema import SPAN_SCHEMAS
+
+    readme = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "README.md",
+    )
+    with open(readme) as f:
+        text = f.read()
+    missing = [n for n in SPAN_SCHEMAS if f"| `{n}` |" not in text]
+    assert missing == []

@@ -165,6 +165,51 @@ def test_xl_width_train_step_compiles(one_chip, on_tpu):
     assert mem.temp_size_in_bytes < 8 * 2**30
 
 
+def test_step_for_the_chip_carries_the_programs_names(one_chip, on_tpu):
+    """What a reader of a device trace joins on, in the step as the
+    chip's compiler leaves it: the three kernels' names in the
+    lowering, the program's scopes in the instructions' ``op_name``,
+    and the custom calls still named after the flax module
+    (``%attn.<n>``), which is how ``benchmarks/kernels.py`` finds
+    them.  A ``name=`` on the ``pl.pallas_call``s would rename them
+    to ``%flash_fwd.<n>`` and the benchmark's pattern would find
+    none (PERF.md, open questions): it stays off until the benchmark
+    matches on something else."""
+    import re
+
+    from dlrover_tpu.common.aot_cache import op_names
+
+    model, loss_fn, batch = _two_layers(param_dtype=jnp.bfloat16)
+    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
+    abs_params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0))
+    )
+    abs_state = jax.eval_shape(
+        lambda p: TrainState.create(p, optimizer), abs_params
+    )
+    lowered = make_train_step(loss_fn, optimizer).lower(
+        _shapes(abs_state, one_chip), _shapes(batch, one_chip)
+    )
+    text = lowered.as_text(debug_info=True)
+    for kernel in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+        assert f'kernel_name = "{kernel}"' in text, kernel
+    for scope in ("optimizer", "loss_head", "forward_backward"):
+        assert f"/{scope}/" in text, scope
+    compiled = lowered.compile().as_text()
+    calls = re.findall(
+        r"^\s*(%[\w\-.]+) = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', compiled, re.M,
+    )
+    assert len(calls) >= 6
+    assert all(re.match(r"^%?attn(\.|$)", name) for name in calls)
+    op_map = op_names(compiled)
+    assert op_map["module"] == "jit_step_fn"
+    stacks = op_map["op_names"]
+    assert all(name in stacks for name in calls)
+    assert any("/optimizer/" in s for s in stacks.values())
+    assert any("/loss_head/" in s for s in stacks.values())
+
+
 # strategy -> mesh axes -> the operand every kernel must see per device
 # (batch x heads folded, seq, head dim); batch is 4 and seq 1024
 MESH_CASES = {
