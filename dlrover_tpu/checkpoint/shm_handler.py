@@ -349,9 +349,9 @@ class SharedMemoryHandler:
             # on a group issues all transfers concurrently (per-leaf
             # waits would pay one transfer round trip per leaf; a
             # host array passes through it untouched), while ~256 MB
-            # chunks bound the extra host RAM and let the shm memcpy
-            # of chunk k overlap nothing worse than chunk k+1's issue.
-            # One ``fetch`` and one ``memcpy`` span a chunk.
+            # chunks bound the extra host RAM.  A chunk is fetched,
+            # then copied, then the next chunk is fetched: nothing
+            # overlaps.  One ``fetch`` and one ``memcpy`` span a chunk.
             CHUNK = 256 * 2**20
             chunk: list = []
             chunk_bytes = 0
@@ -370,30 +370,36 @@ class SharedMemoryHandler:
                     "ckpt.save.memcpy", step=step, bytes=chunk_bytes,
                     leaves=len(chunk),
                 ) as sp:
-                    # the span is the whole loop; its two parts are
-                    # its attributes: making a fetched array C-
-                    # contiguous (a full copy where it is not), and
-                    # the native copy into the segment
-                    t_contiguous = t_copy = 0.0
+                    # a fetched leaf comes in the device buffer's
+                    # dimension order, not always row-major;
+                    # ``copy_into`` writes either kind row-major into
+                    # the segment in one native pass, GIL released: a
+                    # multi-GB snapshot must not starve heartbeat/IPC
+                    # threads.  ``strided_*`` count the leaves that
+                    # were not row-major.
+                    t_copy = t_strided = 0.0
+                    strided_leaves = strided_bytes = 0
                     for (key, _), host in zip(chunk, fetched):
                         m = metas[key]
-                        t0 = _time.perf_counter()
-                        host = np.ascontiguousarray(host)
-                        t_contiguous += _time.perf_counter() - t0
                         dst = np.frombuffer(
                             buf, dtype=np.dtype(m.dtype),
                             count=host.size, offset=m.offset,
                         ).reshape(m.shape)
-                        # GIL released during the memcpy: a multi-GB
-                        # snapshot must not starve heartbeat/IPC
-                        # threads
                         t0 = _time.perf_counter()
-                        copy_into(dst, host)
-                        t_copy += _time.perf_counter() - t0
+                        strided = copy_into(dst, host)
+                        dt = _time.perf_counter() - t0
+                        t_copy += dt
+                        if strided:
+                            t_strided += dt
+                            strided_leaves += 1
+                            strided_bytes += m.nbytes
                     sp.set_attribute("copy_s", round(t_copy, 6))
-                    sp.set_attribute(
-                        "contiguous_s", round(t_contiguous, 6)
-                    )
+                    # seconds in numpy making an array contiguous:
+                    # none since the native pass takes strides
+                    sp.set_attribute("contiguous_s", 0.0)
+                    sp.set_attribute("strided_s", round(t_strided, 6))
+                    sp.set_attribute("strided_leaves", strided_leaves)
+                    sp.set_attribute("strided_bytes", strided_bytes)
                 t_memcpy += t_copy
                 chunk.clear()
 

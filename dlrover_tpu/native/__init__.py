@@ -20,6 +20,7 @@ _SRC_DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD_LOCK = threading.Lock()
 _FLAGS = [
     "-O3", "-shared", "-fPIC", "-std=c++17", "-march=native",
+    "-pthread",
 ]
 
 

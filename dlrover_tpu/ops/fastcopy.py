@@ -49,6 +49,13 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
         ]
         lib.dlrover_fastcopy.restype = ctypes.c_size_t
+        lib.dlrover_fastcopy_strided.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_size_t, ctypes.c_int,
+        ]
+        lib.dlrover_fastcopy_strided.restype = ctypes.c_size_t
         _lib = lib
     except (OSError, RuntimeError) as e:  # no g++ / build failed
         logger.warning(
@@ -65,25 +72,44 @@ def native_available() -> bool:
     return _load() is not None
 
 
-def copy_into(dst: np.ndarray, src: np.ndarray) -> None:
+def copy_into(dst: np.ndarray, src: np.ndarray) -> bool:
     """dst[...] = src with the GIL released during the transfer.
 
-    Both must be C-contiguous with identical dtype/size (the
-    checkpoint path guarantees this); falls back to ``np.copyto``.
+    ``dst`` is C-contiguous with ``src``'s dtype and shape (the
+    checkpoint path guarantees this).  A C-contiguous ``src`` is one
+    native ``memcpy``.  Any other ``src`` (``jax.device_get`` hands a
+    leaf back in the device buffer's dimension order, so a weight the
+    TPU keeps column-major arrives as a transposed view) goes through
+    the native strided pass, row-major into ``dst`` in one go, over
+    :func:`save_workers` threads; returns True for that case alone.
+    Without the library, or on a dtype / shape mismatch, falls back
+    to ``np.copyto``.
     """
     lib = _load()
     if (
         lib is None
         or not dst.flags["C_CONTIGUOUS"]
-        or not src.flags["C_CONTIGUOUS"]
         or dst.dtype != src.dtype
         or dst.size != src.size
     ):
         np.copyto(dst, src)
-        return
-    lib.dlrover_fastcopy(
-        dst.ctypes.data, src.ctypes.data, dst.nbytes
-    )
+        return False
+    if src.flags["C_CONTIGUOUS"]:
+        lib.dlrover_fastcopy(
+            dst.ctypes.data, src.ctypes.data, dst.nbytes
+        )
+        return False
+    if dst.shape == src.shape and dst.size:
+        dims = ctypes.c_int64 * src.ndim
+        # 0 bytes written: an item size or a rank it does not take
+        if lib.dlrover_fastcopy_strided(
+            dst.ctypes.data, src.ctypes.data, src.ndim,
+            dims(*src.shape), dims(*src.strides),
+            src.itemsize, save_workers(),
+        ):
+            return True
+    np.copyto(dst, src)
+    return False
 
 
 def copy_into_chunked(
