@@ -91,6 +91,43 @@ def _leaf_desc(leaf: Any) -> List:
     return [shape, dtype, weak, repr(sharding) if sharding else ""]
 
 
+# the packages a step program is built from beyond its own closure: a
+# flax module's methods, the layers and kernels they call, the
+# optimizer's update
+SOURCE_PACKAGES = ("models", "ops", "parallel", "optim")
+_SOURCE_DIGESTS: Dict[str, str] = {}
+
+
+def source_digest(root: Optional[str] = None) -> str:
+    """A hash over the bytes of every ``.py`` file of
+    ``dlrover_tpu/{models,ops,parallel,optim}`` (``root``: the package
+    directory; this installation's by default), read once a process.
+    :func:`fn_fingerprint` feeds it where the closure holds an object
+    whose class defines ``__call__`` (a flax module): its methods'
+    code, and the layers and kernels they call, are not reachable
+    through the closure, so without this an edit to ``models/olmoe.py``
+    or ``parallel/moe.py`` alone was served a stale executable."""
+    root = root or os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))
+    )
+    if root not in _SOURCE_DIGESTS:
+        h = hashlib.sha256()
+        for package in SOURCE_PACKAGES:
+            for folder, dirs, files in os.walk(
+                os.path.join(root, package)
+            ):
+                dirs.sort()
+                for name in sorted(files):
+                    if not name.endswith(".py"):
+                        continue
+                    path = os.path.join(folder, name)
+                    h.update(os.path.relpath(path, root).encode())
+                    with open(path, "rb") as f:
+                        h.update(f.read())
+        _SOURCE_DIGESTS[root] = h.hexdigest()
+    return _SOURCE_DIGESTS[root]
+
+
 def fn_fingerprint(fn: Any) -> str:
     """Code-identity component of the key: a hash over the function's
     bytecode, literal constants, and (recursively, bounded) the same
@@ -105,6 +142,8 @@ def fn_fingerprint(fn: Any) -> str:
     identical closures hash identically across processes (the
     cross-process hit this cache exists for).  Unhashable oddities
     degrade to a sentinel — a stale-hit risk narrowed, never a crash.
+    An object whose class defines ``__call__`` in Python (a flax
+    module) adds :func:`source_digest`.
     """
     h = hashlib.sha256()
     seen: set = set()
@@ -155,6 +194,8 @@ def fn_fingerprint(fn: Any) -> str:
             for item in list(v.values())[:32]:
                 feed_value(item, depth + 1)
             return
+        if hasattr(getattr(type(v), "__call__", None), "__code__"):
+            h.update(source_digest().encode("utf-8"))
         try:
             r = repr(v)
         except Exception:  # noqa: BLE001 - repr is best-effort

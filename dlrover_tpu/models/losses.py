@@ -45,9 +45,6 @@ def chunked_cross_entropy(
             f"seq {s} not divisible by num_chunks {num_chunks}"
         )
     c = s // num_chunks
-    # scan axis leading: [num_chunks, batch, chunk, hid]
-    hc = hidden.reshape(b, num_chunks, c, h).transpose(1, 0, 2, 3)
-    tc = targets.reshape(b, num_chunks, c).transpose(1, 0, 2)
     spec = "bch,vh->bcv" if transpose else "bch,hv->bcv"
 
     # head matmul in the activation dtype (bf16 on TPU) like the
@@ -68,10 +65,17 @@ def chunked_cross_entropy(
         h_chunk, t_chunk = xs
         return acc + chunk_nll(h_chunk, t_chunk), None
 
-    total, _ = jax.lax.scan(
-        body, jnp.zeros((), jnp.float32), (hc, tc)
-    )
-    return total / (b * s)
+    # device scope "loss_head", as the unchunked head in models/gpt.py:
+    # every operation of the projection and the cross entropy carries
+    # it, forward, backward and recomputed
+    with jax.named_scope("loss_head"):
+        # scan axis leading: [num_chunks, batch, chunk, hid]
+        hc = hidden.reshape(b, num_chunks, c, h).transpose(1, 0, 2, 3)
+        tc = targets.reshape(b, num_chunks, c).transpose(1, 0, 2)
+        total, _ = jax.lax.scan(
+            body, jnp.zeros((), jnp.float32), (hc, tc)
+        )
+        return total / (b * s)
 
 
 def chunked_loss_fn(

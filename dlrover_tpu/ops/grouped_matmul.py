@@ -1,0 +1,319 @@
+"""Grouped matmul over tile-aligned groups, as Pallas TPU kernels.
+
+``rows[i] @ weights[group of row i]`` for rows sorted by group: the
+expert matmuls of a dropless mixture-of-experts layer
+(``parallel/moe.py``).  The caller lays the rows out so that every
+group starts on a multiple of the row tile (:func:`group_layout`:
+each group is padded to whole tiles with zero rows, an empty group
+gets one tile of them), so a tile of rows belongs to exactly one
+group and the kernels need no mask: the tile's group comes from a
+scalar-prefetched ``tile_group`` and picks the weight block in the
+``BlockSpec``'s index map.  Consecutive tiles of one group keep the
+same weight block, which Pallas then does not fetch again.
+
+Why not ``jax.lax.ragged_dot``: the v5e compiler lowers it to a
+Mosaic kernel of its own whose 512-row tiles straddle group
+boundaries, and a straddling tile is computed once per group it
+touches (191 tile visits for 128 tiles of rows at 64 groups of about
+1024: PERF.md, PR 28).  Aligned tiles waste only each group's own
+padding.
+
+Three kernels, named for a trace: ``gmm_fwd`` (rows x weights),
+``gmm_dlhs`` (the gradient to the rows: the same product against the
+transposed weight block), ``gmm_drhs`` (the gradient to the weights:
+per group, rows^T x cotangent summed over the group's tiles in a
+float32 accumulator).  Interpreter mode off the TPU, as
+``flash_attention.py``.
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dlrover_tpu.ops import flash_attention as _flash
+
+# Tiles (v5e sweep at rows 65536, groups 64, 2048 x 1024 and the
+# transposed shape: PERF.md, PR 28): the contraction whole (no
+# accumulator pass) and the output as wide as it comes, so a group's
+# weight block is fetched once and its rows read once.  Row tiles of
+# 512 are 1% faster in the kernels and cost 20% more padded rows in
+# everything around them; 128 is 6% slower.  The row tile is part of
+# the rows' layout, so all three kernels share it.
+ROW_TILE = 256
+K_TILE = 2048
+N_TILE = 2048
+
+
+def _interpret() -> bool:
+    # one answer for both kernels of a step: what steers the flash
+    # kernel (a compile for a described chip) steers this one
+    return _flash._interpret()
+
+
+def group_layout(group_sizes: jax.Array, rows: int, row_tile: int = ROW_TILE):
+    """The tile-aligned layout of ``rows`` sorted rows in groups of
+    ``group_sizes``: ``(tile_group [tiles], tiles_used [1],
+    padded_starts [groups])``.  Group g's rows live at
+    ``padded_starts[g] + (0 .. group_sizes[g])`` of a ``tiles *
+    row_tile``-row array, ``tiles = ceil(rows / row_tile) + groups``
+    whatever the sizes (every group takes ``max(1, ceil(size /
+    row_tile))`` tiles); tiles from ``tiles_used`` on belong to no
+    group and are named after the last one."""
+    groups = group_sizes.shape[0]
+    tiles = pl.cdiv(rows, row_tile) + groups
+    per_group = jnp.maximum(pl.cdiv(group_sizes, row_tile), 1)
+    ends = jnp.cumsum(per_group)
+    tile = jnp.minimum(jnp.arange(tiles), ends[-1] - 1)
+    # a tile's group: how many groups end at or before it
+    tile_group = jnp.sum(tile[:, None] >= ends[None, :], axis=1)
+    return (
+        tile_group.astype(jnp.int32),
+        ends[-1:].astype(jnp.int32),
+        ((ends - per_group) * row_tile).astype(jnp.int32),
+    )
+
+
+def _params(semantics, *block_bytes):
+    # double-buffered blocks + scratch + the compiler's own room
+    need = 2 * sum(block_bytes) + (8 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=int(min(max(need, 32 << 20), 100 << 20)),
+    )
+
+
+def _nbytes(shape, dtype):
+    return math.prod(shape) * jnp.dtype(dtype).itemsize
+
+
+# -- rows x weights (forward, and the gradient to the rows) -------------------
+
+
+def _gmm_kernel(
+    tile_group, tiles_used,     # scalar prefetch
+    lhs_ref,                    # [row_tile, tk]
+    rhs_ref,                    # [1, tk, tn] ([1, tn, tk] transposed)
+    out_ref,                    # [row_tile, tn]
+    *acc,                       # [row_tile, tn] f32 where k is split
+    k_steps: int, transpose_rhs: bool,
+):
+    row, step = pl.program_id(1), pl.program_id(2)
+    active = row < tiles_used[0]
+    contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+
+    @pl.when(active)
+    def _compute():
+        part = jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[0], contract,
+            preferred_element_type=jnp.float32,
+        )
+        if k_steps == 1:
+            out_ref[...] = part.astype(out_ref.dtype)
+            return
+        acc_ref = acc[0]
+
+        @pl.when(step == 0)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(step > 0)
+        def _rest():
+            acc_ref[...] += part
+
+        @pl.when(step == k_steps - 1)
+        def _store():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    # a tile of no group: zeros, so that nothing downstream reads
+    # memory nobody wrote
+    @pl.when(jnp.logical_not(active) & (step == k_steps - 1))
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
+    row_tile, k_tile, n_tile = tiles
+    m, k = rows.shape
+    n = weights.shape[1] if transpose_rhs else weights.shape[2]
+    tk, tn = min(k, k_tile), min(n, n_tile)
+    if m % row_tile or k % tk or n % tn:
+        raise ValueError(
+            f"rows {rows.shape} x weights {weights.shape} do not "
+            f"divide into tiles {(row_tile, tk, tn)}"
+        )
+    k_steps = k // tk
+    if transpose_rhs:
+        rhs_spec = pl.BlockSpec(
+            (1, tn, tk), lambda j, i, s, tg, nu: (tg[i], j, s)
+        )
+    else:
+        rhs_spec = pl.BlockSpec(
+            (1, tk, tn), lambda j, i, s, tg, nu: (tg[i], s, j)
+        )
+    return pl.pallas_call(
+        functools.partial(
+            _gmm_kernel, k_steps=k_steps, transpose_rhs=transpose_rhs
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            # rows innermost of the two: a group's tiles follow each
+            # other and keep its weight block
+            grid=(n // tn, m // row_tile, k_steps),
+            in_specs=[
+                pl.BlockSpec(
+                    (row_tile, tk), lambda j, i, s, tg, nu: (i, s)
+                ),
+                rhs_spec,
+            ],
+            out_specs=pl.BlockSpec(
+                (row_tile, tn), lambda j, i, s, tg, nu: (i, j)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((row_tile, tn), jnp.float32)
+            ] if k_steps > 1 else [],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
+        compiler_params=_params(
+            ("parallel", "parallel", "arbitrary"),
+            _nbytes((row_tile, tk), rows.dtype),
+            _nbytes((tk, tn), weights.dtype),
+            _nbytes((row_tile, tn), rows.dtype),
+            _nbytes((row_tile, tn), jnp.float32),
+        ),
+        interpret=_interpret(),
+        name="gmm_dlhs" if transpose_rhs else "gmm_fwd",
+    )(tile_group, tiles_used, rows, weights)
+
+
+# -- the gradient to the weights ----------------------------------------------------
+
+
+def _tgmm_kernel(
+    tile_group, tiles_used,     # scalar prefetch
+    lhs_ref,                    # [row_tile, tk]
+    cot_ref,                    # [row_tile, tn]
+    out_ref,                    # [1, tk, tn]
+    acc_ref,                    # [tk, tn] f32
+    *, tiles: int,
+):
+    row = pl.program_id(2)
+    used = tiles_used[0]
+    group = tile_group[row]
+    first = (row == 0) | (tile_group[jnp.maximum(row - 1, 0)] != group)
+    last = (row == used - 1) | (
+        tile_group[jnp.minimum(row + 1, tiles - 1)] != group
+    )
+
+    @pl.when(row < used)
+    def _compute():
+        part = jax.lax.dot_general(
+            lhs_ref[...], cot_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+        @pl.when(first)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _rest():
+            acc_ref[...] += part
+
+        @pl.when(last)
+        def _store():
+            out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _tgmm(rows, cotangent, tile_group, tiles_used, *, groups, tiles):
+    row_tile, k_tile, n_tile = tiles
+    m, k = rows.shape
+    n = cotangent.shape[1]
+    tk, tn = min(k, k_tile), min(n, n_tile)
+    if m % row_tile or k % tk or n % tn:
+        raise ValueError(
+            f"rows {rows.shape} and cotangent {cotangent.shape} do "
+            f"not divide into tiles {(row_tile, tk, tn)}"
+        )
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tiles=m // row_tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // tk, n // tn, m // row_tile),
+            in_specs=[
+                pl.BlockSpec(
+                    (row_tile, tk), lambda a, j, i, tg, nu: (i, a)
+                ),
+                pl.BlockSpec(
+                    (row_tile, tn), lambda a, j, i, tg, nu: (i, j)
+                ),
+            ],
+            # every group has a tile, so every block is written; the
+            # tiles of no group are named after the last group and
+            # leave its block as its last tile stored it
+            out_specs=pl.BlockSpec(
+                (1, tk, tn), lambda a, j, i, tg, nu: (tg[i], a, j)
+            ),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((groups, k, n), rows.dtype),
+        compiler_params=_params(
+            ("parallel", "parallel", "arbitrary"),
+            _nbytes((row_tile, tk), rows.dtype),
+            _nbytes((row_tile, tn), rows.dtype),
+            _nbytes((tk, tn), rows.dtype),
+            _nbytes((tk, tn), jnp.float32),
+        ),
+        interpret=_interpret(),
+        name="gmm_drhs",
+    )(tile_group, tiles_used, rows, cotangent)
+
+
+# -- the differentiable product ---------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _grouped_matmul(rows, weights, tile_group, tiles_used, tiles):
+    return _gmm(
+        rows, weights, tile_group, tiles_used, transpose_rhs=False,
+        tiles=tiles,
+    )
+
+
+def _fwd(rows, weights, tile_group, tiles_used, tiles):
+    out = _grouped_matmul(rows, weights, tile_group, tiles_used, tiles)
+    return out, (rows, weights, tile_group, tiles_used)
+
+
+def _bwd(tiles, residuals, cotangent):
+    rows, weights, tile_group, tiles_used = residuals
+    cotangent = cotangent.astype(rows.dtype)
+    d_rows = _gmm(
+        cotangent, weights, tile_group, tiles_used, transpose_rhs=True,
+        tiles=tiles,
+    )
+    d_weights = _tgmm(
+        rows, cotangent, tile_group, tiles_used,
+        groups=weights.shape[0], tiles=tiles,
+    )
+    return d_rows, d_weights.astype(weights.dtype), None, None
+
+
+_grouped_matmul.defvjp(_fwd, _bwd)
+
+
+def grouped_matmul(
+    rows: jax.Array,         # [tiles * row_tile, k], tile-aligned groups
+    weights: jax.Array,      # [groups, k, n]
+    tile_group: jax.Array,   # [tiles] int32   } of group_layout
+    tiles_used: jax.Array,   # [1] int32       }
+    tiles=(ROW_TILE, K_TILE, N_TILE),
+) -> jax.Array:
+    """``rows[i] @ weights[tile_group[i // row_tile]]`` -> ``[rows,
+    n]``, differentiable in ``rows`` and ``weights``.  Rows of a
+    group's padding are zero in and zero out; rows of the tiles
+    beyond ``tiles_used`` come out zero."""
+    return _grouped_matmul(rows, weights, tile_group, tiles_used, tiles)

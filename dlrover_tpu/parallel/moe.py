@@ -13,6 +13,14 @@ stays jit/remat/scan-compatible.
 
 Gating: top-1 (Switch) and top-2 (GShard) with capacity dropping and
 the standard load-balancing auxiliary loss.
+
+Beside it, for a chip that holds every expert of its layers:
+:func:`dropless_moe` / :class:`DroplessMoE` (OLMoE's recipe).  The
+token-to-expert assignments are sorted by expert and the experts run
+as grouped matmuls over the sorted rows: no capacity, no dropped
+token, no ``[t, e, c]`` tensor.  Across chips a dropless layer needs a
+ragged all-to-all (ROADMAP R3); the ``expert``-mesh path stays with
+:class:`MoEMLP`.
 """
 
 from typing import Any, Optional, Tuple
@@ -22,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.ops import grouped_matmul as gmm
 
 
 def top_k_gating(
@@ -204,3 +213,178 @@ def collect_moe_aux_loss(intermediates) -> jax.Array:
     for leaf in leaves:
         total = total + jnp.asarray(leaf, jnp.float32).sum()
     return total
+
+
+# -- dropless routing over grouped matmuls ------------------------------------
+
+
+@jax.custom_vjp
+def _dispatch_rows(tokens, source, slot):
+    """The rows of ``tokens [t, d]`` in the experts' tile-aligned
+    order, ``[padded rows, d]``: ``source[p]`` is the flat assignment
+    (token * k + choice) that lives at padded row ``p``, or ``t * k``
+    for a row of padding, which reads the zero row appended to the
+    tokens; ``slot[t, k]`` is its inverse.  The gradient is a gather
+    through ``slot`` and a sum over the k choices, not a scatter-add
+    over unsorted rows."""
+    zero_row = jnp.zeros((1, tokens.shape[1]), tokens.dtype)
+    return jnp.concatenate([tokens, zero_row])[source // slot.shape[1]]
+
+
+def _dispatch_fwd(tokens, source, slot):
+    return _dispatch_rows(tokens, source, slot), (source, slot)
+
+
+def _dispatch_bwd(res, g):
+    _, slot = res
+    with jax.named_scope("moe_dispatch"):
+        return g[slot].sum(axis=1).astype(g.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _collect_rows(rows, source, slot):
+    """``rows[slot]``: the experts' outputs back in token order, ``[t,
+    k, d]``; the gradient is the gather through ``source`` (the
+    scatter-add of the combine, read from the other side: every
+    padded row holds at most one assignment).  A row of padding gets
+    the cotangent of the last assignment instead of a zero (no
+    masking pass over the rows): it meets only that row's own
+    activations, which are zero because its input was, so no weight's
+    gradient sees it."""
+    return rows[slot]
+
+
+def _collect_fwd(rows, source, slot):
+    return rows[slot], (source, slot)
+
+
+def _collect_bwd(res, g):
+    source, _ = res
+    with jax.named_scope("moe_combine"):
+        flat = g.reshape((-1, g.shape[-1]))
+        return flat.at[source].get(mode="clip"), None, None
+
+
+_collect_rows.defvjp(_collect_fwd, _collect_bwd)
+
+
+def dropless_moe(
+    tokens: jax.Array,         # [t, d]
+    router_kernel: jax.Array,  # [d, e]
+    w_gate: jax.Array,         # [e, d, m]
+    w_up: jax.Array,           # [e, d, m]
+    w_down: jax.Array,         # [e, m, d]
+    top_k: int,
+    dtype: Any = jnp.bfloat16,
+):
+    """Top-k routing without capacity: ``(out [t, d], stats)``.
+
+    Router logits and softmax in float32; the top-k probabilities
+    weight the experts' outputs as they are, NOT renormalised
+    (``norm_topk_prob: false``).  The ``t * k`` assignments are
+    stable-sorted by expert, the rows gathered in that order with each
+    expert's rows starting on a row tile of the grouped-matmul kernel
+    (``ops/grouped_matmul.py``), and each expert computes
+    ``down(silu(gate(x)) * up(x))`` on its own rows as three grouped
+    matmuls.  Every shape is static (``t * k`` rows and one tile of
+    padding an expert, whatever the routing); an expert without a
+    token is one tile of zero rows.  ``stats`` carries what the
+    auxiliary losses and the counters need: ``counts [e]``
+    (assignments per expert, no gradient), ``prob_sum [e]`` (sum over
+    tokens of the router probabilities), ``z_loss`` (mean over tokens
+    of ``logsumexp(logits) ** 2``)."""
+    t, _ = tokens.shape
+    e = router_kernel.shape[-1]
+    assignments = t * top_k
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(
+            tokens.astype(jnp.float32),
+            router_kernel.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, expert_ids = jax.lax.top_k(probs, top_k)  # [t, k]
+        flat_ids = expert_ids.reshape(-1)
+        group_sizes = jnp.bincount(flat_ids, length=e).astype(jnp.int32)
+        stats = {
+            "counts": group_sizes.astype(jnp.float32),
+            "prob_sum": probs.sum(axis=0),
+            "z_loss": jnp.mean(
+                jax.nn.logsumexp(logits, axis=-1) ** 2
+            ),
+        }
+    with jax.named_scope("moe_dispatch"):
+        tile_group, tiles_used, padded_starts = gmm.group_layout(
+            group_sizes, assignments
+        )
+        order = jnp.argsort(flat_ids, stable=True).astype(jnp.int32)
+        sorted_ids = flat_ids[order]
+        starts = jnp.cumsum(group_sizes) - group_sizes
+        # the padded row of the assignment at sorted position i
+        row = (
+            padded_starts[sorted_ids] - starts[sorted_ids]
+            + jnp.arange(assignments, dtype=jnp.int32)
+        )
+        slot = jnp.zeros_like(order).at[order].set(
+            row, unique_indices=True
+        ).reshape(t, top_k)
+        source = jnp.full(
+            (tile_group.shape[0] * gmm.ROW_TILE,), assignments, jnp.int32
+        ).at[row].set(order, unique_indices=True)
+        rows = _dispatch_rows(tokens.astype(dtype), source, slot)
+    with jax.named_scope("moe_experts"):
+        def expert(x, w):
+            return gmm.grouped_matmul(
+                x, w.astype(dtype), tile_group, tiles_used
+            )
+
+        rows = expert(
+            nn.silu(expert(rows, w_gate)) * expert(rows, w_up), w_down
+        )
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum(
+            "tkd,tk->td", _collect_rows(rows, source, slot), gate,
+            preferred_element_type=jnp.float32,
+        )
+    return out.astype(dtype), stats
+
+
+class DroplessMoE(nn.Module):
+    """:func:`dropless_moe` as a layer: ``x [b, s, d] -> (out, stats)``.
+    Parameter names as :class:`MoEMLP`'s gated experts (``router``,
+    ``experts_w_gate`` / ``experts_w_in`` / ``experts_w_out``, leading
+    expert dim)."""
+
+    num_experts: int
+    mlp_dim: int
+    top_k: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    kernel_init: Any = nn.initializers.normal(0.02)
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        b, s, d = x.shape
+        e, m = self.num_experts, self.mlp_dim
+        router = self.param(
+            "router", self.kernel_init, (d, e), self.param_dtype
+        )
+        w_gate = self.param(
+            "experts_w_gate", self.kernel_init, (e, d, m),
+            self.param_dtype,
+        )
+        w_up = self.param(
+            "experts_w_in", self.kernel_init, (e, d, m), self.param_dtype
+        )
+        w_down = self.param(
+            "experts_w_out", self.kernel_init, (e, m, d),
+            self.param_dtype,
+        )
+        out, stats = dropless_moe(
+            x.reshape(b * s, d), router, w_gate, w_up, w_down,
+            self.top_k, self.dtype,
+        )
+        return out.reshape(b, s, d), stats

@@ -94,8 +94,11 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # loss rides along when the step loop reported it: the
         # elastic-resize loss-trajectory invariant compares same-step
         # losses across incarnations/world sizes from the log alone
+        # moe.*: a sparse model's per-step routing counters (the
+        # ``aux`` of a ``has_aux`` loss; models/olmoe.py)
         _s("train_step", ["step", "restart_count", "node_rank"],
-           ["loss"]),
+           ["loss", "moe.load_max_over_mean", "moe.lb_loss",
+            "moe.z_loss"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

@@ -281,6 +281,7 @@ def make_train_step(
     grad_accum: int = 1,
     mesh=None,
     rules: Optional[PartitionRules] = None,
+    has_aux: Optional[bool] = None,
 ):
     """Build the jitted (state, batch) -> (state, metrics) step.
 
@@ -290,11 +291,24 @@ def make_train_step(
     given, in/out shardings pin state to the rule-derived placement and
     the batch to the data axes — GSPMD inserts all collectives — and
     the step is traced with that mesh in scope (``scoped_to_mesh``).
+
+    With ``has_aux``, ``loss_fn(params, batch) -> (loss, aux)`` and
+    ``aux``'s scalars (a model's own counters: ``moe.load_max_over_mean``)
+    join ``metrics`` (with ``grad_accum``, their mean over the micro
+    batches).  Left at None it is read from ``loss_fn.has_aux``, so a
+    loss that carries counters says so itself and a training script
+    written for scalar losses runs it unchanged.
     """
+    if has_aux is None:
+        has_aux = bool(getattr(loss_fn, "has_aux", False))
 
     def grads_of(params, batch):
+        # (with has_aux, ``loss`` is the pair (loss, aux) down to
+        # where step_fn splits it)
         with jax.named_scope("forward_backward"):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            loss, grads = jax.value_and_grad(
+                loss_fn, has_aux=has_aux
+            )(params, batch)
         return loss, grads
 
     def step_fn(state: TrainState, batch):
@@ -310,18 +324,30 @@ def make_train_step(
                 loss_sum, grads_sum = carry
                 loss, grads = grads_of(state.params, mb)
                 return (
-                    loss_sum + loss,
+                    jax.tree.map(jnp.add, loss_sum, loss),
                     jax.tree.map(jnp.add, grads_sum, grads),
                 ), None
 
             zeros = jax.tree.map(jnp.zeros_like, state.params)
+            loss_zero = jnp.zeros((), jnp.float32)
+            if has_aux:
+                loss_zero = jax.tree.map(
+                    lambda x: jnp.zeros(x.shape, x.dtype),
+                    jax.eval_shape(
+                        loss_fn, state.params,
+                        jax.tree.map(lambda x: x[0], micro),
+                    ),
+                )
             (loss_sum, grads), _ = jax.lax.scan(
-                accum, (jnp.zeros((), jnp.float32), zeros), micro
+                accum, (loss_zero, zeros), micro
             )
-            loss = loss_sum / grad_accum
+            loss = jax.tree.map(lambda x: x / grad_accum, loss_sum)
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
         else:
             loss, grads = grads_of(state.params, batch)
+        aux = {}
+        if has_aux:
+            loss, aux = loss
         import optax
 
         # device scope: every op of the optimizer pass carries
@@ -335,7 +361,7 @@ def make_train_step(
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1
         )
-        metrics = {"loss": loss, "grad_norm": grad_norm}
+        metrics = {**aux, "loss": loss, "grad_norm": grad_norm}
         return new_state, metrics
 
     if mesh is None:
@@ -552,6 +578,12 @@ class ElasticTrainer:
                 step_event["loss"] = float(metrics["loss"])
             except (TypeError, ValueError):
                 pass
+        for name, value in (metrics or {}).items():
+            # a sparse model's routing counters (``has_aux`` of
+            # make_train_step) ride on this event: one more event
+            # would cost the loop 0.65 ms a step (PERF.md, PR 25)
+            if name.startswith("moe."):
+                step_event[name] = float(value)
         emit_event("train_step", **step_event)
         # chaos hook AFTER the event: a kill rule at step N must leave
         # step N's completion in the log before the process dies; a

@@ -511,3 +511,82 @@ def test_code_change_invalidates_entry(tmp_path):
         cache_dir=cache_dir,
     )
     assert not r3.hit  # hyperparameter captured in a closure
+
+
+# -- the sources a flax module's step is built from ------------------------------
+
+
+def _copy_sources(tmp_path):
+    import shutil
+
+    package = os.path.dirname(os.path.dirname(aot_cache.__file__))
+    root = tmp_path / "dlrover_tpu"
+    for name in aot_cache.SOURCE_PACKAGES:
+        shutil.copytree(
+            os.path.join(package, name), root / name,
+            ignore=shutil.ignore_patterns("__pycache__"),
+        )
+    return str(root)
+
+
+def test_editing_a_module_file_changes_the_source_digest(tmp_path):
+    """The digest covers the bytes of models/, ops/, parallel/ and
+    optim/: an edit to a copied ``parallel/moe.py`` or
+    ``models/olmoe.py`` alone changes it (and so the key of every
+    step whose closure holds a flax module)."""
+    root = _copy_sources(tmp_path)
+    assert aot_cache.source_digest(root) == aot_cache.source_digest()
+    for edited in ("parallel/moe.py", "models/olmoe.py"):
+        before = aot_cache.source_digest(root)
+        with open(os.path.join(root, edited), "a") as f:
+            f.write("\n# an edit\n")
+        aot_cache._SOURCE_DIGESTS.pop(root)
+        assert aot_cache.source_digest(root) != before
+
+
+def test_a_flax_module_in_the_closure_feeds_the_sources(monkeypatch):
+    """A loss over a flax module (its class defines ``__call__``): the
+    fingerprint changes with the source digest; a loss over plain
+    arrays does not read the sources at all."""
+    from dlrover_tpu.models.gpt import GPT, GPTConfig
+
+    model = GPT(GPTConfig.tiny())
+
+    def over_module(p, b):
+        return model.apply({"params": p}, b["x"]).sum()
+
+    before = aot_cache.fn_fingerprint(over_module)
+    plain = aot_cache.fn_fingerprint(_loss)
+    monkeypatch.setattr(aot_cache, "source_digest", lambda: "edited")
+    assert aot_cache.fn_fingerprint(over_module) != before
+    assert aot_cache.fn_fingerprint(_loss) == plain
+
+
+def test_fingerprint_over_a_module_is_stable_across_processes():
+    script = textwrap.dedent("""
+        import jax
+        jax.config.update("jax_platforms", "cpu")
+        from dlrover_tpu.common import aot_cache
+        from dlrover_tpu.models.olmoe import (
+            Olmoe, OlmoeConfig, make_olmoe_loss,
+        )
+        from dlrover_tpu.optim import adamw_bf16
+        from dlrover_tpu.trainer.elastic_trainer import make_train_step
+        step = make_train_step(
+            make_olmoe_loss(Olmoe(OlmoeConfig.tiny())),
+            adamw_bf16(learning_rate=3e-4, weight_decay=0.1),
+        )
+        print(aot_cache.fn_fingerprint(step), aot_cache.source_digest())
+    """)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu")
+    outs = [
+        subprocess.run(
+            [sys.executable, "-c", script], env=env, cwd=repo,
+            capture_output=True, text=True, check=True,
+        ).stdout.split()
+        for _ in range(2)
+    ]
+    assert outs[0] == outs[1] and len(outs[0]) == 2
+    assert outs[0][0] != "unhashable"
+    assert outs[0][1] == aot_cache.source_digest()

@@ -11,12 +11,21 @@ import time
 import pytest
 
 from dlrover_tpu import run as tpurun
-from dlrover_tpu.checkpoint.saver import read_last_checkpoint
+from dlrover_tpu.checkpoint.saver import (
+    AsyncCheckpointSaver,
+    read_last_checkpoint,
+)
 
 from bench import ELASTIC_TRAIN_SCRIPT as TRAIN_SCRIPT
 
 
 def test_tpurun_crash_restart_restore(tmp_path, monkeypatch):
+    # the agent runs in THIS process: a saver factory that an earlier
+    # test file of the same xdist worker left behind listens under
+    # that file's socket directory, ``start_async_saving_ckpt`` would
+    # keep it, and every save of this job would wait 300 s for an IPC
+    # server that is not there (seen under ``--dist loadfile``, PR 28)
+    AsyncCheckpointSaver.reset()
     monkeypatch.setenv("DLROVER_SHARED_DIR", str(tmp_path / "sock"))
     # one JSONL event log collects the whole job: the master
     # subprocess, this (agent) process and the trainer workers all

@@ -150,3 +150,23 @@ def test_chunked_loss_rejects_pipelined_model():
     # first trace of the step, not at build time
     with pytest.raises(ValueError, match="pipelined"):
         result.train_step(result.state, result.place_batch(batch))
+
+
+def test_chunked_ce_carries_the_loss_head_scope():
+    """Every operation of the chunked head, forward and backward, is
+    lowered under the device scope ``loss_head`` (what
+    ``losshead.ms_per_step`` sums in a trace), as the unchunked
+    ``cross_entropy_loss`` is."""
+    from dlrover_tpu.common.aot_cache import op_names
+
+    hidden = jnp.ones((2, 16, 8), jnp.float32)
+    kernel = jnp.ones((8, 32), jnp.float32)
+    targets = jnp.zeros((2, 16), jnp.int32)
+    compiled = jax.jit(jax.grad(
+        lambda h, k: chunked_cross_entropy(h, k, targets, num_chunks=4),
+        argnums=(0, 1),
+    )).lower(hidden, kernel).compile()
+    stacks = op_names(compiled.as_text())["op_names"].values()
+    dots = [s for s in stacks if "dot_general" in s]
+    assert dots and all("loss_head" in s for s in dots)
+    assert any("transpose(" in s for s in dots)
