@@ -5,17 +5,45 @@ The reference binds a prebuilt CUDA FMHA library
 ATorch's module swaps in ``atorch/modules/transformer/layers.py``);
 the TPU rebuild implements the kernel itself in Pallas: online-softmax
 tiling so the [seq, seq] score matrix never materializes in HBM, MXU
-matmuls in bf16 with fp32 accumulators, causal block skipping.
+matmuls in bf16 with fp32 accumulators.
 
 Layout: q, k, v are [batch, seq, heads, head_dim] (the model's bqhd).
-Internally folded to [batch*heads, seq, head_dim]; the grid walks
-(batch*heads, q_block, k_block) with the k_block axis innermost so the
-running max/denominator scratch carries across k steps.
+Internally folded to [batch*heads, seq, head_dim].
+
+The walk.  Forward and dq: the grid is (batch*heads, q tiles, kv-major
+blocks).  A grid step holds one q tile of ``block_q`` rows and the
+head's K and V, whole while they fit ``_RESIDENT_BYTES`` (then the
+last grid axis is 1 and K / V are fetched once a head); inside, a
+``fori_loop`` walks kv sub-blocks of ``block_k`` rows.  With ``causal``
+the loop stops at the last sub-block that touches the q tile's rows
+(``_kv_walk``); sub-blocks wholly below the diagonal take the body
+without the mask, only the ones the diagonal crosses are masked.  dkv
+is the mirror image: the grid is (batch*heads, kv tiles of ``block_k``
+rows, q-major blocks), Q / dO / lse / delta are resident, the loop
+walks q sub-blocks of ``block_q`` rows and STARTS at the first one that
+reaches the kv tile (``_q_walk``); it works on the transposed
+sub-block, where lse and delta are rows.  Past the residency budget
+the resident extent becomes a major block on the grid with the same
+loop inside it; its index map is clamped at the diagonal, so a major
+block the walk skips is not fetched.
+
+Inside a sub-block.  Where ``block_q == block_k`` (the default) the
+one sub-block the diagonal crosses is the tile's own, so its shape is
+known when the kernel is traced: it is taken as a triangle of
+``_CHUNK``-column passes, each over only the rows that see those
+columns, the mask on one square chunk a pass and nothing above the
+diagonal computed (10 of 16 chunk pairs at 1024 x 1024).  Other
+sub-blocks are one pass (or a few, ``_PASS_SCORES``).  ``m``, ``l`` and
+the accumulator live in VMEM scratch, the statistics lane-dense
+(``[block_q, 128]``); lse and delta cross between that form and the
+``[1, seq]`` rows they travel as by 128 x 128 transposes, once a grid
+step.  ``block_schedule`` counts the walk.
 
 On CPU (tests / virtual mesh) the kernel runs in interpreter mode.
 """
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -23,38 +51,245 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_VMEM = pltpu.VMEM
-
 NEG_INF = -1e30
-# v5e-measured fwd+bwd block sweep (bq x bk in {256,512,1024}^2, seq
-# 1k/2k/4k, head_dim 64/128, constant token count): 1024x1024 wins or
-# ties everywhere — e.g. seq 2048/d64: 10.6 ms vs 15.7 ms at the old
-# 512x512 default (1.48x).  The table keeps the per-shape winners;
-# unlisted shapes fall back to min(1024, seq).
-DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 1024
-_TUNED_BLOCKS = {
-    # (seq, head_dim) -> (block_q, block_k)
-    (1024, 64): (512, 1024),
-    (2048, 64): (1024, 1024),
-    (4096, 64): (1024, 1024),
-    (1024, 128): (1024, 1024),
-    (2048, 128): (1024, 1024),
-    (4096, 128): (1024, 1024),
-}
+_LANES = 128
+# What a grid step may keep of the operands its loop walks (K and V in
+# forward and dq, Q and dO in dkv), as the pipeline holds them: two
+# arrays x two buffers x rows x head_dim x itemsize.  4 MB is the
+# whole sequence at 4096 x 128 in bf16 (8 x seq x d bytes), 0.5 MB at
+# 1024 x 64.  Beside it a step holds its own tile and its output
+# (double-buffered, 1 MB at 1024 x 128), dkv's lse and delta rows (an
+# eighth more) and a pass's float32 score temporaries (``_PASS_SCORES``
+# below): what the v5e's compiler allows a kernel, as
+# ``tests/test_tpu_compile.py`` checks for both cells' shapes and one
+# past the budget.
+_RESIDENT_BYTES = 4 * 2**20
 
 
-def tuned_blocks(seq: int, head_dim: int):
-    """Measured-best (block_q, block_k) for this shape (v5e sweep);
-    min(1024, seq) when unmeasured."""
-    if (seq, head_dim) in _TUNED_BLOCKS:
-        return _TUNED_BLOCKS[(seq, head_dim)]
-    b = min(1024, seq)
-    return b, b
+def resident_rows(
+    seq: int, sub_block: int, head_dim: int, itemsize: int
+) -> int:
+    """Rows of the walked operands one grid step holds: the largest
+    divisor of ``seq`` that is a whole number of loop sub-blocks and
+    fits ``_RESIDENT_BYTES``; one sub-block if none does."""
+    for parts in range(1, seq // sub_block + 1):
+        rows, rest = divmod(seq, parts)
+        if rest or rows % sub_block:
+            continue
+        if 4 * rows * head_dim * itemsize <= _RESIDENT_BYTES:
+            return rows
+    return sub_block
+
+
+# Columns of the score sub-block that one pass of the loop body takes
+# where the sub-block the diagonal crosses is the tile's own (block_q
+# == block_k): a static loop over chunks of columns, each with only the
+# rows that see it, so the triangle above the diagonal is not computed
+# and the mask falls on one square chunk a pass.  256: at 128 the
+# forward ran 18% slower (more passes than the scores they save), at
+# 512 the backward 8% slower (PERF.md, PR 29).
+_CHUNK = 256
+# Scores one pass may hold as float32 temporaries ([rows, columns];
+# dkv holds four such, forward and dq three): a sub-block larger than
+# this is taken in passes of fewer columns.
+_PASS_SCORES = 1024 * 1024
+_PASS_SCORES_DKV = 512 * 1024
+
+
+def _chunk(block: int) -> int:
+    return _CHUNK if block % _CHUNK == 0 else block
+
+
+def _passes(rows: int, cols: int, triangle: bool, scores: int):
+    """``(first column, columns, first row)`` of each pass over a
+    ``[rows, cols]`` score sub-block whose rows belong to the grid
+    step's own tile.  A triangle (rows == cols, the diagonal running
+    through it) goes by chunks, each from its own first row down."""
+    if triangle:
+        width = _chunk(cols)
+        return [(c, width, c) for c in range(0, cols, width)]
+    width = cols
+    while rows * width > scores and width % 2 == 0 and width > _LANES:
+        width //= 2
+    return [(c, width, 0) for c in range(0, cols, width)]
+
+
+def _kv_walk(q_start, block_q: int, block_k: int):
+    """Causal walk of the q tile that starts at row ``q_start``: kv
+    sub-blocks ``[0, full)`` lie wholly below the diagonal, ``[full,
+    end)`` are crossed by it, the rest is never visited."""
+    full = (q_start + 1) // block_k
+    end = (q_start + block_q - 1) // block_k + 1
+    return full, end
+
+
+def _q_walk(k_start, block_q: int, block_k: int):
+    """The mirror image for the kv tile that starts at ``k_start``: q
+    sub-blocks ``[start, full)`` are crossed by the diagonal, ``[full,
+    seq // block_q)`` lie wholly below it, ``[0, start)`` are never
+    visited."""
+    start = k_start // block_q
+    full = (k_start + block_k + block_q - 2) // block_q
+    return start, full
+
+
+def block_schedule(
+    seq: int, block_q: int, block_k: int, causal: bool = True
+) -> dict:
+    """What one head's walk costs: how many ``[block_q, block_k]``
+    sub-blocks the kernels visit, how many of those take the masked
+    body, the square's total, and ``computed``, the share of the
+    square's scores that are computed at all (a masked sub-block that
+    is the tile's own is walked as a triangle of chunks).  The three
+    kernels walk the same set (dkv by kv tile, the other two by q
+    tile); their loop bounds are ``_kv_walk`` / ``_q_walk``, summed
+    here."""
+    total = (seq // block_q) * (seq // block_k)
+    if not causal:
+        return {
+            "visited": total, "masked": 0, "total": total,
+            "computed": 1.0,
+        }
+    visited = masked = 0
+    for q_start in range(0, seq, block_q):
+        full, end = _kv_walk(q_start, block_q, block_k)
+        visited += end
+        masked += end - full
+    chunks = block_q // _chunk(block_q) if block_q == block_k else 1
+    triangle = (chunks + 1) / (2 * chunks)
+    return {
+        "visited": visited, "masked": masked, "total": total,
+        "computed": (visited - masked + masked * triangle) / total,
+    }
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _scratch(shape, dtype):
+    return pltpu.VMEM(shape, dtype)
+
+
+def _scale_is_exact(scale: float) -> bool:
+    """A power of two (head 64: 0.125) multiplies a bf16 tile without
+    rounding, so it goes onto the resident tile once a grid step; any
+    other scale stays a float32 multiply of the scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _lanes(x, n: int):
+    """Lane-dense ``[rows, 128]`` statistics (every lane the same) as
+    ``[rows, n]``."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _rows_to_lanes(x):
+    """Lane-dense ``[rows, 128]`` statistics as the ``[1, rows]`` row
+    that lse and delta travel as.  By 128 x 128 transposes: a lane
+    reduction a row group (what ``jnp.max(x, axis=1)`` lowers to) took
+    0.13 of the forward's 0.44 ms a call at 100 x 1024 x 64 (my chip
+    run, PR 29)."""
+    rows = x.shape[0]
+    if rows % _LANES:
+        return jnp.max(x, axis=1)[None, :]
+    blocks = [x[i:i + _LANES].T[:1] for i in range(0, rows, _LANES)]
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(
+        blocks, axis=1
+    )
+
+
+def _lanes_to_rows(ref):
+    """The way back: a ``[1, 1, rows]`` block of lse or delta as a
+    ``[rows, 1]`` column."""
+    rows = ref.shape[2]
+    if rows % _LANES:
+        return ref[0, 0][:, None]
+    blocks = [
+        jnp.broadcast_to(ref[0, :, i:i + _LANES], (_LANES, _LANES)).T
+        for i in range(0, rows, _LANES)
+    ]
+    column = blocks[0] if len(blocks) == 1 else jnp.concatenate(
+        blocks, axis=0
+    )
+    return column[:, :1]
+
+
+def _nt(a, b):
+    """``a @ b.T`` with a float32 result."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _mask(s, q_axis: int, offset):
+    """Scores of a sub-block the diagonal crosses; ``offset`` is its
+    first kv position less its first q position."""
+    q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q_pos - k_pos >= offset, s, NEG_INF)
+
+
+def _mask_corner(s, q_axis: int, first: int):
+    """One pass over the triangle: the square chunk the diagonal
+    crosses (the sub-block's columns by as many of its rows, from row
+    ``first``) is masked, what lies below or above it is whole."""
+    size = s.shape[1]
+    parts = [
+        s[:first], _mask(s[first:first + size], q_axis, 0),
+        s[first + size:],
+    ]
+    parts = [part for part in parts if part.shape[0]]
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate(parts, axis=0)
+
+
+def _clip(lo, x, hi):
+    if all(isinstance(n, int) for n in (lo, x, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _walk(step, lo, hi, plain, masked=None):
+    """``step(index, masked)`` over the sub-blocks of ``[lo, hi)`` (what
+    this grid step holds) that the walk visits: without the mask over
+    ``plain``, with it over ``masked``, both ``(first, end)``."""
+    def loop(bounds, with_mask):
+        jax.lax.fori_loop(
+            _clip(lo, bounds[0], hi), _clip(lo, bounds[1], hi),
+            lambda i, _: step(i, with_mask), None,
+        )
+
+    loop(plain, False)
+    if masked is not None:
+        loop(masked, True)
+
+
+def _bracket(major, num_major: int, init, walk, final):
+    """One grid step of a walk whose last grid axis has ``num_major``
+    steps: the accumulators start on its first and leave on its last
+    (both at once, with no condition, when the operands are resident)."""
+    if num_major == 1:
+        init()
+        walk()
+        final()
+        return
+    pl.when(major == 0)(init)
+    walk()
+    pl.when(major == num_major - 1)(final)
 
 
 # ---------------------------------------------------------------------------
@@ -63,70 +298,73 @@ def _interpret() -> bool:
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref,      # [1, block_q, d], [1, block_k, d] x2
+    q_ref, k_ref, v_ref,      # [1, block_q, d], [1, major rows, d] x2
     o_ref,                    # [1, block_q, d]
-    lse_ref,                  # [1, block_q]
-    m_scr, l_scr, acc_scr,    # VMEM scratch
+    lse_ref,                  # [1, 1, block_q]
+    m_scr, l_scr, acc_scr,    # [block_q, 128] x2, [block_q, d]
     *, scale: float, block_q: int, block_k: int, causal: bool,
+    num_major: int,
 ):
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
-    num_kv = pl.num_programs(2)
+    q_start = pl.program_id(1) * block_q
+    major = 0 if num_major == 1 else pl.program_id(2)
+    subs = k_ref.shape[1] // block_k
+    first = major * subs
+    d = q_ref.shape[2]
+    fold = _scale_is_exact(scale)
 
-    @pl.when(kv_idx == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: process only blocks with kv_start <= q_end
-    run = True
-    if causal:
-        run = kv_idx * block_k <= q_idx * block_q + (block_q - 1)
-
-    @pl.when(run)
-    def _body():
+    def walk():
         q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        logits = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
+        if fold:
+            q = q * scale
+
+        def step(j, masked):
+            base = pl.multiple_of((j - first) * block_k, block_k)
+            triangle = masked and block_q == block_k
+            for col, width, top in _passes(
+                block_q, block_k, triangle, _PASS_SCORES
+            ):
+                mine = slice(top, block_q)
+                k = k_ref[0, pl.ds(base + col, width), :]
+                v = v_ref[0, pl.ds(base + col, width), :]
+                s = _nt(q[mine], k)
+                if not fold:
+                    s = s * scale
+                if triangle:
+                    s = _mask_corner(s, 0, 0)
+                elif masked:
+                    s = _mask(s, 0, j * block_k + col - q_start)
+                m_prev = m_scr[mine, :]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=1, keepdims=True)
+                )
+                p = jnp.exp(s - _lanes(m_new, width))
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[mine, :] = alpha * l_scr[mine, :] + jnp.sum(
+                    p, axis=1, keepdims=True
+                )
+                acc_scr[mine, :] = acc_scr[mine, :] * _lanes(
+                    alpha, d
+                ) + _nn(p.astype(v.dtype), v)
+                m_scr[mine, :] = m_new
+
         if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+            full, end = _kv_walk(q_start, block_q, block_k)
+            _walk(step, first, first + subs, (0, full), (full, end))
+        else:
+            _walk(step, first, first + subs, (0, num_major * subs))
 
-        m_prev = m_scr[:]
-        l_prev = l_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
-        p = jnp.exp(logits - m_new[:, None])
-        correction = jnp.exp(m_prev - m_new)
-        l_new = l_prev * correction + jnp.sum(p, axis=1)
-        acc_scr[:] = (
-            acc_scr[:] * correction[:, None]
-            + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        )
-        m_scr[:] = m_new
-        l_scr[:] = l_new
-
-    @pl.when(kv_idx == num_kv - 1)
-    def _final():
-        l = m_scr[:] * 0.0 + l_scr[:]  # keep shapes aligned
+    def final():
+        l = l_scr[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:] + jnp.log(safe_l)
+        o_ref[0] = (acc_scr[...] / _lanes(safe_l, d)).astype(o_ref.dtype)
+        lse_ref[0] = _rows_to_lanes(m_scr[...] + jnp.log(safe_l))
+
+    _bracket(major, num_major, init, walk, final)
 
 
 def _fwd(
@@ -134,32 +372,35 @@ def _fwd(
     group: int = 1,
 ):
     bh, seq, d = q.shape
-    num_q = seq // block_q
-    num_kv = seq // block_k
-    grid = (bh, num_q, num_kv)
+    rows = resident_rows(seq, block_k, d, k.dtype.itemsize)
+    num_major = seq // rows
 
     # GQA: k/v carry bh//group rows; `group` consecutive q heads read
-    # the same kv row through the index map — the repeated kv tensor
-    # never materializes in HBM
+    # the same kv row through the index map - the repeated kv tensor
+    # never materializes in HBM.  Causal: a major block above the q
+    # tile's diagonal maps to the last one below it, which is already
+    # there, so nothing is fetched for it.
+    def kv_block(b, i, j):
+        if causal and num_major > 1:
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // rows)
+        return (b // group, j, 0)
+
+    def q_block(b, i, j):
+        return (b, i, 0)
+
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, block_q=block_q,
-            block_k=block_k, causal=causal,
+            block_k=block_k, causal=causal, num_major=num_major,
         ),
-        grid=grid,
+        grid=(bh, seq // block_q, num_major),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda b, i, j: (b // group, j, 0),
-            ),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda b, i, j: (b // group, j, 0),
-            ),
+            pl.BlockSpec((1, block_q, d), q_block),
+            pl.BlockSpec((1, rows, d), kv_block),
+            pl.BlockSpec((1, rows, d), kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), q_block),
             # lse carried as [bh, 1, seq]: (1, 1, block_q) blocks satisfy
             # the TPU (8, 128) tiling rule on the last two dims
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
@@ -169,17 +410,13 @@ def _fwd(
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
         scratch_shapes=[
-            _scratch((block_q,), jnp.float32),
-            _scratch((block_q,), jnp.float32),
+            _scratch((block_q, _LANES), jnp.float32),
+            _scratch((block_q, _LANES), jnp.float32),
             _scratch((block_q, d), jnp.float32),
         ],
         interpret=_interpret(),
     )(q, k, v)
     return out, lse
-
-
-def _scratch(shape, dtype):
-    return pltpu.VMEM(shape, dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -192,56 +429,63 @@ def _bwd_dq_kernel(
     dq_ref,
     dq_scr,
     *, scale: float, block_q: int, block_k: int, causal: bool,
+    num_major: int,
 ):
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
-    num_kv = pl.num_programs(2)
+    q_start = pl.program_id(1) * block_q
+    major = 0 if num_major == 1 else pl.program_id(2)
+    subs = k_ref.shape[1] // block_k
+    first = major * subs
+    fold = _scale_is_exact(scale)
 
-    @pl.when(kv_idx == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+    def init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = True
-    if causal:
-        run = kv_idx * block_k <= q_idx * block_q + (block_q - 1)
-
-    @pl.when(run)
-    def _body():
+    def walk():
         q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        if fold:
+            q = q * scale
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        logits = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-        p = jnp.exp(logits - lse[:, None])
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        # the statistics arrive along the lanes; turned into columns
+        # once a grid step, not once a sub-block
+        lse = _lanes_to_rows(lse_ref)
+        delta = _lanes_to_rows(delta_ref)
 
-    @pl.when(kv_idx == num_kv - 1)
-    def _final():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        def step(j, masked):
+            base = pl.multiple_of((j - first) * block_k, block_k)
+            triangle = masked and block_q == block_k
+            for col, width, top in _passes(
+                block_q, block_k, triangle, _PASS_SCORES
+            ):
+                mine = slice(top, block_q)
+                k = k_ref[0, pl.ds(base + col, width), :]
+                v = v_ref[0, pl.ds(base + col, width), :]
+                s = _nt(q[mine], k)
+                if not fold:
+                    s = s * scale
+                if triangle:
+                    s = _mask_corner(s, 0, 0)
+                elif masked:
+                    s = _mask(s, 0, j * block_k + col - q_start)
+                p = jnp.exp(s - lse[mine])
+                dp = _nt(do[mine], v.astype(jnp.float32))
+                ds = p * (dp - delta[mine])
+                if not fold:
+                    ds = ds * scale
+                dq_scr[mine, :] += _nn(ds.astype(k.dtype), k)
+
+        if causal:
+            full, end = _kv_walk(q_start, block_q, block_k)
+            _walk(step, first, first + subs, (0, full), (full, end))
+        else:
+            _walk(step, first, first + subs, (0, num_major * subs))
+
+    def final():
+        dq = dq_scr[...]
+        if fold:
+            dq = dq * scale
+        dq_ref[0] = dq.astype(dq_ref.dtype)
+
+    _bracket(major, num_major, init, walk, final)
 
 
 def _bwd_dkv_kernel(
@@ -249,130 +493,161 @@ def _bwd_dkv_kernel(
     dk_ref, dv_ref,
     dk_scr, dv_scr,
     *, scale: float, block_q: int, block_k: int, causal: bool,
+    num_major: int,
 ):
-    q_idx = pl.program_id(2)
-    kv_idx = pl.program_id(1)
-    num_q = pl.num_programs(2)
+    """Works on the TRANSPOSED sub-block, ``[block_k, block_q]``: the
+    statistics are rows there and broadcast down the sublanes, and
+    both accumulating matmuls contract over its last axis."""
+    k_start = pl.program_id(1) * block_k
+    major = 0 if num_major == 1 else pl.program_id(2)
+    subs = q_ref.shape[1] // block_q
+    first = major * subs
+    fold = _scale_is_exact(scale)
 
-    @pl.when(q_idx == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    def init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    run = True
-    if causal:
-        # q block must reach at least the kv block start
-        run = q_idx * block_q + (block_q - 1) >= kv_idx * block_k
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
+    def walk():
         k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        logits = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
+        if fold:
+            k = k * scale
+        v = v_ref[0].astype(jnp.float32)
+
+        def step(i, masked):
+            base = pl.multiple_of((i - first) * block_q, block_q)
+            triangle = masked and block_q == block_k
+            for col, width, _ in _passes(
+                block_k, block_q, triangle, _PASS_SCORES_DKV
+            ):
+                # here the tile's rows are kv positions: a chunk of q
+                # columns is seen by the rows down to its own last one
+                mine = slice(0, col + width if triangle else block_k)
+                cols = pl.ds(base + col, width)
+                q = q_ref[0, cols, :]
+                do = do_ref[0, cols, :].astype(jnp.float32)
+                s = _nt(k[mine], q)
+                if not fold:
+                    s = s * scale
+                if triangle:
+                    s = _mask_corner(s, 1, col)
+                elif masked:
+                    s = _mask(s, 1, k_start - i * block_q - col)
+                p = jnp.exp(s - lse_ref[0, :, cols])
+                dv_scr[mine, :] += _nn(p, do)
+                dp = _nt(v[mine], do)
+                ds = p * (dp - delta_ref[0, :, cols])
+                if not fold:
+                    ds = ds * scale
+                dk_scr[mine, :] += _nn(ds, q.astype(jnp.float32))
+
+        total = num_major * subs
         if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-        p = jnp.exp(logits - lse[:, None])
-        # dv += p^T @ do
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        # dk += ds^T @ q
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            start, full = _q_walk(k_start, block_q, block_k)
+            _walk(step, first, first + subs, (full, total), (start, full))
+        else:
+            _walk(step, first, first + subs, (0, total))
 
-    @pl.when(q_idx == num_q - 1)
-    def _final():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    def final():
+        dk = dk_scr[...]
+        if fold:
+            dk = dk * scale
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    _bracket(major, num_major, init, walk, final)
 
 
-def _bwd(
-    scale, causal, block_q, block_k, group, residuals, dout
-):
-    q, k, v, out, lse = residuals
-    bh, seq, d = q.shape
-    delta = jnp.sum(
+def _delta(out, dout):
+    """sum(out * dout) over the head dim, [bh, 1, seq] like lse."""
+    return jnp.sum(
         out.astype(jnp.float32) * dout.astype(jnp.float32), axis=-1
-    )[:, None, :]  # [bh, 1, seq] to match the lse tiling layout
+    )[:, None, :]
 
-    num_q = seq // block_q
-    num_kv = seq // block_k
 
-    dq = pl.pallas_call(
+def _bwd_dq(
+    q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group
+):
+    bh, seq, d = q.shape
+    rows = resident_rows(seq, block_k, d, k.dtype.itemsize)
+    num_major = seq // rows
+
+    def kv_block(b, i, j):
+        if causal and num_major > 1:
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // rows)
+        return (b // group, j, 0)
+
+    def q_block(b, i, j):
+        return (b, i, 0)
+
+    def stat_block(b, i, j):
+        return (b, 0, i)
+
+    return pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, block_q=block_q,
-            block_k=block_k, causal=causal,
+            block_k=block_k, causal=causal, num_major=num_major,
         ),
-        grid=(bh, num_q, num_kv),
+        grid=(bh, seq // block_q, num_major),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda b, i, j: (b // group, j, 0),
-            ),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda b, i, j: (b // group, j, 0),
-            ),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, block_q, d), q_block),
+            pl.BlockSpec((1, rows, d), kv_block),
+            pl.BlockSpec((1, rows, d), kv_block),
+            pl.BlockSpec((1, block_q, d), q_block),
+            pl.BlockSpec((1, 1, block_q), stat_block),
+            pl.BlockSpec((1, 1, block_q), stat_block),
         ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, d), lambda b, i, j: (b, i, 0)
-        ),
+        out_specs=pl.BlockSpec((1, block_q, d), q_block),
         out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
         scratch_shapes=[_scratch((block_q, d), jnp.float32)],
         interpret=_interpret(),
     )(q, k, v, dout, lse, delta)
 
+
+def _bwd_dkv(
+    q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group
+):
+    bh, seq, d = q.shape
+    rows = resident_rows(seq, block_q, d, q.dtype.itemsize)
+    num_major = seq // rows
+
+    # causal: a q-major block above the kv tile maps to the first one
+    # that reaches it, so the pipeline fetches that one early and
+    # nothing for the ones the walk skips
+    def major(j, m):
+        if causal and num_major > 1:
+            m = jnp.maximum(m, (j * block_k) // rows)
+        return m
+
+    def q_block(b, j, m):
+        return (b, major(j, m), 0)
+
+    def stat_block(b, j, m):
+        return (b, 0, major(j, m))
+
+    def kv_block(b, j, m):
+        return (b // group, j, 0)
+
+    def out_block(b, j, m):
+        return (b, j, 0)
+
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, block_q=block_q,
-            block_k=block_k, causal=causal,
+            block_k=block_k, causal=causal, num_major=num_major,
         ),
-        grid=(bh, num_kv, num_q),
+        grid=(bh, seq // block_k, num_major),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda b, j, i: (b // group, j, 0),
-            ),
-            pl.BlockSpec(
-                (1, block_k, d),
-                lambda b, j, i: (b // group, j, 0),
-            ),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, rows, d), q_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, rows, d), q_block),
+            pl.BlockSpec((1, 1, rows), stat_block),
+            pl.BlockSpec((1, 1, rows), stat_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), out_block),
+            pl.BlockSpec((1, block_k, d), out_block),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq, d), k.dtype),
@@ -393,7 +668,18 @@ def _bwd(
         dv = dv.reshape(bh // group, group, seq, d).astype(
             jnp.float32
         ).sum(axis=1).astype(v.dtype)
-    return dq, dk, dv
+    return dk, dv
+
+
+def _bwd(
+    scale, causal, block_q, block_k, group, residuals, dout
+):
+    q, k, v, out, lse = residuals
+    args = (
+        q, k, v, dout, lse, _delta(out, dout), scale, causal,
+        block_q, block_k, group,
+    )
+    return (_bwd_dq(*args), *_bwd_dkv(*args))
 
 
 # ---------------------------------------------------------------------------
@@ -425,6 +711,20 @@ def _flash_mha_bwd(scale, causal, block_q, block_k, group,
 _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 
 
+def default_blocks(seq: int, itemsize: int):
+    """(block_q, block_k) for a call that names none, from the call's
+    own shape: one square tile of up to 1024 rows (512 for 4-byte
+    operands, whose tiles are twice the bytes).  Square, so that the
+    sub-block the diagonal crosses is the tile's own and is walked as
+    a triangle; large, because what a tile costs beyond its scores
+    (statistics, accumulators, the pipeline's fill) is paid once a
+    tile: at 1024 x 64 and at 4096 x 128 alike the three kernels
+    together ran 10% slower at 512 and 50-85% slower at 256 (PERF.md,
+    PR 29), so neither the head dim nor ``causal`` moves the choice."""
+    block = min(seq, 2048 // itemsize)
+    return block, block
+
+
 def _fit_block(s: int, requested: int) -> int:
     """Largest divisor of ``s`` that is <= requested — so a seq that
     is a multiple of 128 but not of the (large) default block still
@@ -453,6 +753,14 @@ def flash_attention(
     Sequence length must be divisible by the block sizes (the caller
     pads; GPT training shapes are powers of two).
 
+    ``block_q`` is the q rows a grid step of the forward and of dq
+    holds, ``block_k`` the kv rows one iteration of their loop takes
+    (dkv holds ``block_k`` kv rows and walks ``block_q`` q rows at a
+    time).  Left out, both come from the call's own shape
+    (``default_blocks``); with ``causal`` the loop runs only to the
+    diagonal, and equal blocks let the sub-block on it be walked as a
+    triangle (the module docstring; ``block_schedule`` counts it).
+
     GQA: ``k``/``v`` may carry fewer heads than ``q`` (``kv_heads``
     dividing ``heads``, kv-head-major q layout as in the Llama
     family); the forward and dq kernels read each kv head once per
@@ -474,7 +782,7 @@ def flash_attention(
     group = h // kvh
     scale = scale if scale is not None else d**-0.5
     if block_q is None or block_k is None:
-        tq, tk = tuned_blocks(s, d)
+        tq, tk = default_blocks(s, q.dtype.itemsize)
         block_q = tq if block_q is None else block_q
         block_k = tk if block_k is None else block_k
     block_q = _fit_block(s, block_q)

@@ -12,7 +12,10 @@ import sys
 import pytest
 
 from dlrover_tpu.chaos import harness, scenarios
-from dlrover_tpu.checkpoint.saver import read_last_checkpoint
+from dlrover_tpu.checkpoint.saver import (
+    AsyncCheckpointSaver,
+    read_last_checkpoint,
+)
 
 pytestmark = pytest.mark.chaos
 
@@ -21,6 +24,13 @@ CKPT_EVERY = 2
 
 
 def _run(tmp_path, scenario, **kwargs):
+    # the agent runs in THIS process: a saver factory that an earlier
+    # test file of the same xdist worker left behind would be kept, and
+    # every save of this job would wait 300 s for an IPC server under
+    # that file's socket directory (``tests/test_e2e_elastic.py`` has
+    # the same cure; seen here under ``--dist loadfile`` in PR 29, when
+    # new tests moved this file behind another on its worker)
+    AsyncCheckpointSaver.reset()
     return harness.run_scenario(
         scenario,
         workdir=str(tmp_path / "run"),

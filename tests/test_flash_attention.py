@@ -8,19 +8,27 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models.gpt import xla_causal_attention
+from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.ops.flash_attention import flash_attention
 
 
-def _rand_qkv(b=2, s=128, h=4, d=32, dtype=jnp.float32, seed=0):
+def _rand_qkv(
+    b=2, s=128, h=4, d=32, dtype=jnp.float32, seed=0, kv_heads=None
+):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    shape = (b, s, h, d)
+    heads = (h, kv_heads or h, kv_heads or h)
     return tuple(
-        jax.random.normal(k, shape, dtype=dtype) * 0.3 for k in ks
+        jax.random.normal(k, (b, s, hh, d), dtype=dtype) * 0.3
+        for k, hh in zip(ks, heads)
     )
 
 
 def _reference(q, k, v, causal=True):
     scale = q.shape[-1] ** -0.5
+    group = q.shape[2] // k.shape[2]
+    if group > 1:  # kv-head-major, as the Llama family lays q out
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
@@ -54,6 +62,223 @@ def test_forward_uneven_blocks():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
     )
+
+
+# what the walk inside the kernel can meet: (shape, blocks, causal,
+# residency budget in bytes or None for the module's own, rows a chunk
+# of the loop body takes or None for the module's own)
+WALKS = {
+    # 4 x 4 sub-blocks: tiles on the diagonal (masked), below it
+    # (plain) and above it (never visited)
+    "on-and-off-the-diagonal": (dict(s=256), (64, 64), True, None, None),
+    # the diagonal crosses two kv sub-blocks of every q tile
+    "q-tile-wider": (dict(s=256), (128, 64), True, None, None),
+    # and two q tiles share every kv sub-block
+    "kv-sub-block-wider": (dict(s=256), (64, 128), True, None, None),
+    "one-sub-block": (dict(s=64), (64, 64), True, None, None),
+    "not-causal": (dict(s=256), (64, 128), False, None, None),
+    "group-4": (dict(s=128, h=8, kv_heads=2), (64, 32), True, None, None),
+    "head-64": (dict(s=128, h=2, d=64), (64, 64), True, None, None),
+    # 0.0884 is no power of two: the scale stays on the scores
+    "head-128": (dict(b=1, s=256, h=2, d=128), (128, 128), True, None, None),
+    # float32 at 4096 x 128 is 8 MB of K and V: two kv-major blocks
+    # of 2048 on the grid, each walked by the same loop
+    "past-the-budget": (
+        dict(b=1, s=4096, h=1, d=128), (512, 512), True, None, None,
+    ),
+    # the same branch at a size the gradients' tolerances were set at:
+    # 4 major blocks of 2 sub-blocks, group 2, both block orders
+    "major-blocks-q-wider": (
+        dict(s=512, h=4, kv_heads=2), (128, 64), True,
+        4 * 128 * 32 * 4, 32,
+    ),
+    "major-blocks-kv-wider": (
+        dict(s=512, h=4, kv_heads=2), (64, 128), True,
+        4 * 256 * 32 * 4, None,
+    ),
+    "major-blocks-not-causal": (
+        dict(s=256), (64, 64), False, 4 * 128 * 32 * 4, None,
+    ),
+    # nothing fits: one sub-block a grid step
+    "major-block-is-the-sub-block": (
+        dict(s=256), (64, 64), True, 1, None,
+    ),
+    # block_q == block_k: the diagonal block is the tile's own, walked
+    # as a triangle of chunks (4 chunks: 10 of 16 chunk pairs, the
+    # mask on 4 of them); tiles below it take whole chunks
+    "triangle-of-chunks": (dict(s=256), (128, 128), True, None, 32),
+    "triangle-is-the-whole-walk": (
+        dict(s=128, h=2, d=64), (128, 128), True, None, 32,
+    ),
+    "triangle-in-major-blocks": (
+        dict(s=512, h=4, kv_heads=2), (128, 128), True,
+        4 * 256 * 32 * 4, 64,
+    ),
+    # chunks without a triangle: the mask's offset moves with the chunk
+    "chunks-q-tile-wider": (dict(s=256), (128, 64), True, None, 32),
+    "chunks-kv-sub-block-wider": (
+        dict(s=256), (64, 128), True, None, 32,
+    ),
+    "chunks-not-causal": (dict(s=256), (128, 128), False, None, 32),
+}
+
+
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_walk_matches_reference(walk, monkeypatch):
+    """Forward and all three gradients against the plain reference,
+    over the cases the loop bounds inside the kernels create."""
+    shape, (block_q, block_k), causal, budget, chunk = WALKS[walk]
+    if budget is not None:
+        monkeypatch.setattr(fa, "_RESIDENT_BYTES", budget)
+    if chunk is not None:
+        monkeypatch.setattr(fa, "_CHUNK", chunk)
+    q, k, v = _rand_qkv(**shape)
+    if budget is not None or walk.startswith("past"):
+        rows = fa.resident_rows(
+            q.shape[1], block_k, q.shape[3], q.dtype.itemsize
+        )
+        assert rows < q.shape[1]
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k
+        )
+
+    def ref(q, k, v):
+        return _reference(q, k, v, causal=causal)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)),
+        atol=2e-5, rtol=2e-5,
+    )
+    g_flash = jax.grad(
+        lambda *a: flash(*a).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
+    g_ref = jax.grad(
+        lambda *a: ref(*a).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-5, rtol=5e-4,
+            err_msg=f"grad mismatch for {name}",
+        )
+
+
+@pytest.mark.parametrize(
+    "seq,block_q,block_k,visited,masked,total,computed",
+    [
+        # the old table's: the whole square, all of it masked
+        (1024, 512, 1024, 2, 2, 2, 1.0),
+        # one tile a head, a triangle of 4 chunks of 256: 10 of 16
+        (1024, 1024, 1024, 1, 1, 1, 10 / 16),
+        (1024, 512, 512, 3, 2, 4, (1 + 2 * 3 / 4) / 4),
+        (1024, 256, 256, 10, 4, 16, 10 / 16),
+        (1024, 128, 512, 12, 8, 16, 12 / 16),
+        (4096, 1024, 1024, 10, 4, 16, (6 + 4 * 10 / 16) / 16),
+        (4096, 512, 512, 36, 8, 64, (28 + 8 * 3 / 4) / 64),
+        (4096, 256, 1024, 40, 16, 64, 40 / 64),
+    ],
+)
+def test_block_schedule_counts_the_walk(
+    seq, block_q, block_k, visited, masked, total, computed
+):
+    assert fa._CHUNK == 256
+    assert fa.block_schedule(seq, block_q, block_k, True) == {
+        "visited": visited, "masked": masked, "total": total,
+        "computed": pytest.approx(computed),
+    }
+    assert fa.block_schedule(seq, block_q, block_k, False) == {
+        "visited": total, "masked": 0, "total": total, "computed": 1.0,
+    }
+    # dkv walks the same set from the other side
+    by_kv_tile = [
+        fa._q_walk(k_start, block_q, block_k)
+        for k_start in range(0, seq, block_k)
+    ]
+    num_q = seq // block_q
+    assert sum(num_q - start for start, _ in by_kv_tile) == visited
+    assert sum(full - start for start, full in by_kv_tile) == masked
+    # and each sub-block is masked exactly when the diagonal crosses it
+    for qi in range(num_q):
+        full, end = fa._kv_walk(qi * block_q, block_q, block_k)
+        for kj in range(seq // block_k):
+            first_q, last_q = qi * block_q, (qi + 1) * block_q - 1
+            first_k, last_k = kj * block_k, (kj + 1) * block_k - 1
+            assert (kj < end) == (first_k <= last_q)
+            assert (kj < full) == (last_k <= first_q)
+
+
+@pytest.mark.parametrize(
+    "seq,head_dim,itemsize,blocks,computed",
+    [
+        # GPT-2-XL's call: one tile a head, 4 passes over its triangle
+        (1024, 64, 2, (1024, 1024), 10 / 16),
+        # OLMoE's: 4 x 4 tiles, 6 below the diagonal and 4 triangles
+        (4096, 128, 2, (1024, 1024), (6 + 4 * 10 / 16) / 16),
+        # 4-byte operands take half the rows
+        (4096, 128, 4, (512, 512), (28 + 8 * 3 / 4) / 64),
+        (256, 64, 2, (256, 256), 1.0),
+    ],
+)
+def test_default_blocks_read_the_calls_shape(
+    seq, head_dim, itemsize, blocks, computed
+):
+    """The rule behind a call that names no blocks, pinned for the
+    benchmark's two shapes with the walk it makes there."""
+    assert fa.default_blocks(seq, itemsize) == blocks
+    assert fa.block_schedule(seq, *blocks)["computed"] == (
+        pytest.approx(computed)
+    )
+    # K and V of the whole sequence stay with the grid step up to the
+    # budget: both cells' shapes do, float32 at 4096 x 128 does not
+    rows = fa.resident_rows(seq, blocks[1], head_dim, itemsize)
+    assert rows == (seq if itemsize == 2 else min(seq, 2048))
+
+
+@pytest.mark.parametrize(
+    "rows,cols,triangle,scores,passes",
+    [
+        # one pass while the scores fit
+        (1024, 1024, False, 1024 * 1024, [(0, 1024, 0)]),
+        # else passes of fewer columns, all rows each
+        (1024, 1024, False, 512 * 1024, [(0, 512, 0), (512, 512, 0)]),
+        (128, 64, False, 1, [(0, 64, 0)]),
+        # the triangle: chunk c of the columns, from row c down
+        (1024, 1024, True, 1,
+         [(0, 256, 0), (256, 256, 256), (512, 256, 512),
+          (768, 256, 768)]),
+        (64, 64, True, 1, [(0, 64, 0)]),
+    ],
+)
+def test_passes_over_a_sub_block(rows, cols, triangle, scores, passes):
+    assert fa._passes(rows, cols, triangle, scores) == passes
+
+
+def test_sub_block_in_several_passes_matches_reference(monkeypatch):
+    """A sub-block too large for one pass: fewer columns a pass, the
+    online softmax carried across them."""
+    monkeypatch.setattr(fa, "_PASS_SCORES", 256 * 128)
+    monkeypatch.setattr(fa, "_PASS_SCORES_DKV", 256 * 128)
+    q, k, v = _rand_qkv(b=1, s=512, h=2, d=32)
+    assert len(fa._passes(256, 256, False, fa._PASS_SCORES)) == 2
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=256, block_k=256)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(_reference(q, k, v)),
+        atol=2e-5, rtol=2e-5,
+    )
+    g_flash = jax.grad(
+        lambda *a: flash(*a).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
+    g_ref = jax.grad(
+        lambda *a: _reference(*a).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
+    for gf, gr in zip(g_flash, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), atol=5e-5, rtol=5e-4
+        )
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -31,7 +31,13 @@ from dlrover_tpu.trainer.elastic_trainer import (
 # GPT-2-XL widths; the largest XL leaf is the fused MLP kernel
 XL = dict(num_heads=25, hidden_dim=1600, max_seq_len=1024)
 XL_LEAF = (1600, 6400)
-ATTN_SHAPES = [(4, 1024, 25, 64), (4, 2048, 32, 128)]
+# GPT-2-XL's and OLMoE's own (K and V resident for the whole sequence)
+# and one past the residency budget (8 MB of K and V at 8192 x 128:
+# two kv-major blocks on the grid, their index map clamped)
+ATTN_SHAPES = [
+    (4, 1024, 25, 64), (4, 2048, 32, 128), (2, 4096, 16, 128),
+    (1, 8192, 8, 128),
+]
 
 
 @pytest.fixture(scope="module")
