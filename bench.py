@@ -1421,7 +1421,6 @@ def bench_flash_ckpt(jax, results: dict, workdir: str):
     f_sync_post, _ = sync_save()
     f_sync = (f_sync_pre + f_sync_post) / 2
     d2h_mbps = state_bytes / 2**20 / max(t_d2h, 1e-9)
-    paged = _bench_paged_hot_save(workdir)
     # raw host memcpy bandwidth on THIS box, measured the moment the
     # restore ran: the shm restore's assemble stage copies each byte
     # exactly once, so assemble_s ~= bytes / this number means the
@@ -1467,116 +1466,8 @@ def bench_flash_ckpt(jax, results: dict, workdir: str):
         "num_params": count_params(params),
         "committed_step": committed,
         "saver": "separate-process agent",
-        "paged": paged,
-        # headline pair of the paged tier: effective hot-save
-        # throughput (state bytes the save COVERS per second of
-        # stall, copy-skips included) and how many x fewer bytes the
-        # ~1% delta moved vs the full base write
-        "shm_hot_save_MBps": paged["hot_save_MBps"],
-        "shm_delta_ratio": paged["delta_ratio_x"],
     }
     return f_sync / max(f_flash, 1e-9)
-
-
-def _bench_paged_hot_save(workdir: str) -> dict:
-    """Paged hot-save leg (ISSUE 18): base+delta pages vs the flat
-    full-segment write at ~1% sparse touch.  Host-side only — the
-    tier is a host shm structure, so no device transfer belongs in
-    the measurement."""
-    import numpy as np
-
-    from dlrover_tpu.checkpoint.shm_handler import (
-        CheckpointConfig,
-        SharedMemoryHandler,
-    )
-    from dlrover_tpu.checkpoint.sparse import (
-        KV_STATE_KEY,
-        SparseStateAdapter,
-    )
-    from dlrover_tpu.ops.kv_variable import (
-        GroupAdamOptimizer,
-        KvVariable,
-    )
-
-    smoke = bool(os.getenv("BENCH_SMOKE"))
-    rows = 2_000 if smoke else 200_000
-    dense_mb = 1 if smoke else 64
-    table = KvVariable(dim=16, seed=3, name="emb")
-    opt = GroupAdamOptimizer(table, learning_rate=1e-2)
-    adapter = SparseStateAdapter()
-    adapter.register_optimizer(opt)
-    keys = np.arange(rows, dtype=np.int64)
-    opt.apply_gradients(
-        keys, np.tanh(table.gather(keys)) * 0.1
-    )
-    rng = np.random.default_rng(0)
-    dense = {
-        "w": rng.standard_normal(
-            dense_mb * 2**20 // 4
-        ).astype(np.float32),
-        "step": 0,
-    }
-    state_bytes = dense["w"].nbytes + sum(
-        a.nbytes
-        for tb in adapter.export_state().values()
-        if isinstance(tb, dict)
-        for a in tb.values()
-        if isinstance(a, np.ndarray)
-    )
-    h_paged = SharedMemoryHandler(0, host=True, job_name="benchpg")
-    h_flat = SharedMemoryHandler(0, host=True, job_name="benchfl")
-    try:
-        kind, kv = adapter.export_for_shm(step=1, rank=0)
-        t0 = time.perf_counter()
-        base_phases = h_paged.save_state_dict_paged(
-            dense, CheckpointConfig(step=1), kv_payload=(kind, kv)
-        )
-        base_s = time.perf_counter() - t0
-
-        touched = keys[::100]  # ~1% of the rows
-        opt.apply_gradients(
-            touched, np.tanh(table.gather(touched)) * 0.1
-        )
-        kind, kv = adapter.export_for_shm(step=2, rank=0)
-        t0 = time.perf_counter()
-        delta_phases = h_paged.save_state_dict_paged(
-            dense, CheckpointConfig(step=2), kv_payload=(kind, kv)
-        )
-        delta_s = time.perf_counter() - t0
-        assert delta_phases["kind"] == "delta"
-
-        # flat control: what the same hot save costs full-segment
-        state = dict(dense)
-        state[KV_STATE_KEY] = adapter.export_state(step=2, rank=0)
-        t0 = time.perf_counter()
-        h_flat.save_state_dict(state, CheckpointConfig(step=2))
-        flat_s = time.perf_counter() - t0
-    finally:
-        h_paged.unlink()
-        h_flat.unlink()
-    return {
-        "rows": rows,
-        "touched_rows": int(len(touched)),
-        "state_mb": round(state_bytes / 2**20, 1),
-        "base_save_s": round(base_s, 4),
-        "delta_save_s": round(delta_s, 4),
-        "flat_save_s": round(flat_s, 4),
-        "base_bytes": int(base_phases["bytes"]),
-        "delta_bytes": int(delta_phases["bytes"]),
-        "delta_bytes_skipped": int(delta_phases["bytes_skipped"]),
-        "delta_phases": delta_phases,
-        # bytes the save makes restorable per second of stall — the
-        # copy-skipped dense leaves count, which is the whole point
-        "hot_save_MBps": round(
-            state_bytes / 2**20 / max(delta_s, 1e-9), 1
-        ),
-        "delta_ratio_x": round(
-            base_phases["bytes"] / max(delta_phases["bytes"], 1), 1
-        ),
-        "paged_vs_flat_stall_x": round(
-            flat_s / max(delta_s, 1e-9), 2
-        ),
-    }
 
 
 # One elastic train script for the recovery bench AND the e2e tests
@@ -3162,16 +3053,6 @@ def _headline(snapshot: dict) -> dict:
     put(
         "flash_ckpt_restore_s",
         _dig(snapshot, "flash_ckpt", "restore_shm_s"),
-    )
-    # paged shm tier: effective hot-save throughput (copy-skips
-    # included) and the base-vs-delta byte reduction at ~1% touch
-    put(
-        "shm_hot_save_MBps",
-        _dig(snapshot, "flash_ckpt", "shm_hot_save_MBps"),
-    )
-    put(
-        "shm_delta_ratio",
-        _dig(snapshot, "flash_ckpt", "shm_delta_ratio"),
     )
     speedup = snapshot.get("_speedup")
     put(

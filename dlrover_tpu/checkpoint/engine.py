@@ -73,11 +73,6 @@ _RESTORE_STAGE_SECONDS = _REG.histogram(
     "Per-stage restore pipeline time (labels: tier, stage = "
     "read / assemble / h2d)",
 )
-_SAVE_STAGE_SECONDS = _REG.histogram(
-    "dlrover_checkpoint_save_stage_seconds",
-    "Per-stage save pipeline time (labels: mode = flat / paged, "
-    "stage = fetch / compare / memcpy / kv / publish)",
-)
 
 
 class CheckpointEngine:
@@ -98,22 +93,9 @@ class CheckpointEngine:
         global_rank: Optional[int] = None,
         world_size: Optional[int] = None,
         deletion_keep_latest: int = 0,
-        async_snapshot: bool = True,
     ):
         self.checkpoint_dir = checkpoint_dir
         self.replicated = replicated
-        # Async-snapshot mode exploits jax.Array immutability: the
-        # training stall of a flash save is only a cheap on-device copy
-        # (guarding against buffer donation invalidating the refs); the
-        # device->host fetch, shm write and persist enqueue all happen
-        # on a background writer thread.  The reference must copy
-        # synchronously because torch tensors mutate in place
-        # (ckpt_saver.py:174 _traverse_copy_to_shm); JAX does not.
-        # Trade-off: a crash between ``save_to_storage`` returning and
-        # the background shm write completing loses that snapshot (the
-        # previous one remains) — same exposure as the reference's
-        # async persist window.
-        self._async_snapshot = async_snapshot
         self._writer_queue: "queue.Queue" = queue.Queue(maxsize=1)
         self._writer_thread: Optional[threading.Thread] = None
         self._writer_lock = threading.Lock()
@@ -336,33 +318,16 @@ class CheckpointEngine:
         if not self._notified_agent:
             with _span("ckpt.save.notify_agent"):
                 self._notify_agent_to_create_saver()
-        from dlrover_tpu.checkpoint.shm_handler import paged_enabled
-
-        # paged hot saves (DLROVER_SHM_PAGED): write only what
-        # changed — dense leaves copy-skipped, sparse rows as delta
-        # pages via the shm dirty-consumer slot.  Sparse DURABLE
-        # saves stay flat: their delta chain belongs to the storage
-        # consumer and replays from committed step dirs, not shm.
-        use_paged = (
-            paged_enabled()
-            and not durable
-            and isinstance(state_dict, dict)
-            and KV_STATE_KEY not in state_dict
-        )
         # sparse tables export here on the SYNC path (MEMORY saves /
         # no-device-array states); the async path already merged a
-        # consistent export before queueing, which the key guard skips
-        merged_here = (
-            not use_paged
-            and self._sparse is not None
-            and isinstance(state_dict, dict)
-            and KV_STATE_KEY not in state_dict
-        )
-        if not use_paged and self._sparse is not None:
+        # consistent export before queueing, which ``_merge_sparse``'s
+        # key guard hands back untouched
+        merged_here = False
+        if self._sparse is not None:
             with _span("ckpt.save.sparse_merge", step=step):
-                state_dict = self._merge_sparse(
-                    state_dict, step, durable
-                )
+                merged = self._merge_sparse(state_dict, step, durable)
+            merged_here = merged is not state_dict
+            state_dict = merged
         # every rank locks its shard: the agent's breakpoint save reads
         # all local shards, so an unlocked write can be torn even for
         # ranks that never persist to storage; without an agent there
@@ -404,10 +369,7 @@ class CheckpointEngine:
                 global_shard_num=self.global_shard_num,
             )
             start = time.time()
-            if use_paged:
-                self._save_paged(step, state_dict, config)
-            else:
-                self._shm_handler.save_state_dict(state_dict, config)
+            self._shm_handler.save_state_dict(state_dict, config)
             self._cached_step = step
             phases = dict(self._shm_handler.last_save_phases)
             self.last_save_bytes = phases.get("bytes", 0)
@@ -415,13 +377,6 @@ class CheckpointEngine:
             phases["total_s"] = round(time.time() - start + lock_wait, 3)
             self.last_save_phases = phases
             _SHM_SAVE_SECONDS.observe(phases["total_s"])
-            mode = "paged" if phases.get("paged") else "flat"
-            for stage in ("fetch", "compare", "memcpy", "kv", "publish"):
-                sec = phases.get(f"{stage}_s")
-                if sec is not None:
-                    _SAVE_STAGE_SECONDS.observe(
-                        float(sec), mode=mode, stage=stage
-                    )
             emit_event(
                 "checkpoint_shm_save",
                 step=step,
@@ -439,49 +394,6 @@ class CheckpointEngine:
         finally:
             if locked:
                 self._shm_lock.release()
-
-    def _save_paged(self, step: int, state_dict, config) -> None:
-        """One paged hot save under the shm lock: export the sparse
-        delta on the shm consumer slot, hand it to the handler as a
-        delta page; when the handler cannot take a delta (fresh/
-        invalid epoch, arena overflow) poison the shm chain,
-        re-export a full base and retry once.  Any failure after the
-        delta drained its baseline also poisons — those rows must
-        ride the next base, not vanish."""
-        from dlrover_tpu.checkpoint.shm_handler import (
-            PagedNeedBase,
-            shm_full_every,
-        )
-
-        kv_payload = None
-        if self._sparse is not None:
-            kv_payload = self._sparse.export_for_shm(
-                step=step, rank=self._rank,
-                full_every=shm_full_every(),
-            )
-        try:
-            try:
-                self._shm_handler.save_state_dict_paged(
-                    state_dict, config, kv_payload=kv_payload
-                )
-                return
-            except PagedNeedBase as e:
-                logger.info(
-                    "paged save of step %s re-basing: %s", step, e
-                )
-                if self._sparse is not None:
-                    self._sparse.shm_chain_poison()
-                    kv_payload = self._sparse.export_for_shm(
-                        step=step, rank=self._rank,
-                        full_every=shm_full_every(),
-                    )
-                self._shm_handler.save_state_dict_paged(
-                    state_dict, config, kv_payload=kv_payload
-                )
-        except Exception:
-            if self._sparse is not None:
-                self._sparse.shm_chain_poison()
-            raise
 
     def _agent_lock_available(self) -> bool:
         """Whether an agent-side lock server exists for this shard
@@ -582,16 +494,25 @@ class CheckpointEngine:
         """Flash save: shm write + async persist by the agent
         (reference: save_to_storage in full_ckpt_engine.py).
 
-        With ``async_snapshot`` (default) the training stall is only
-        the on-device copy; the host fetch + shm write happen on the
-        writer thread, which then enqueues the agent persist."""
+        A state that holds a ``jax.Array`` takes the snapshot route,
+        which exploits jax.Array immutability: the training stall is
+        only a cheap on-device copy (guarding against buffer donation
+        invalidating the refs); the device->host fetch, shm write and
+        persist enqueue all happen on the writer thread.  The
+        reference must copy synchronously because torch tensors mutate
+        in place (ckpt_saver.py:174 _traverse_copy_to_shm); JAX does
+        not.  Trade-off: a crash between this call returning and the
+        background shm write completing loses that snapshot (the
+        previous one remains) — same exposure as the reference's async
+        persist window.  A state of host leaves alone has nothing to
+        snapshot and is written synchronously."""
         import jax
 
         has_device_arrays = any(
             isinstance(leaf, jax.Array)
             for leaf in jax.tree_util.tree_leaves(state_dict)
         )
-        if self._async_snapshot and has_device_arrays:
+        if has_device_arrays:
             if self._writer_queue.unfinished_tasks:
                 logger.info(
                     "step %s: previous snapshot still writing; "
@@ -1259,9 +1180,9 @@ class CheckpointEngine:
         import jax
 
         from dlrover_tpu.checkpoint.restore import (
+            CHUNK_BYTES,
             RestoreStats,
             StagedRestore,
-            chunk_bytes,
             detach_for_device_put,
         )
         from dlrover_tpu.checkpoint.sharded import (
@@ -1361,7 +1282,7 @@ class CheckpointEngine:
             # device_put call per ~budget bytes — the per-call
             # dispatch overhead dominates small leaves, and a batch
             # issues all transfers at once
-            budget = chunk_bytes()
+            budget = CHUNK_BYTES
             pending: list = []
             pending_bytes = 0
 

@@ -39,10 +39,12 @@ import numpy as np
 from dlrover_tpu.ops.fastcopy import copy_into_chunked
 
 RESTORE_WORKERS_ENV = "DLROVER_RESTORE_WORKERS"
-RESTORE_CHUNK_MB_ENV = "DLROVER_RESTORE_CHUNK_MB"
-RESTORE_ZERO_COPY_ENV = "DLROVER_RESTORE_ZERO_COPY"
 
-_DEFAULT_CHUNK_MB = 64
+# Piece size of the chunked detach copies and byte budget of one batched
+# ``device_put``: large enough that the pool's per-piece dispatch is
+# noise beside the memcpy, small enough that a multi-GB leaf still
+# splits across every worker (its page faults are what parallelise).
+CHUNK_BYTES = 64 * 2**20
 
 
 def restore_workers() -> int:
@@ -59,25 +61,13 @@ def restore_workers() -> int:
     return min(8, max(2, (os.cpu_count() or 4) // 2))
 
 
-def chunk_bytes() -> int:
-    try:
-        mb = int(os.getenv(RESTORE_CHUNK_MB_ENV, str(_DEFAULT_CHUNK_MB)))
-    except ValueError:
-        mb = _DEFAULT_CHUNK_MB
-    return max(1, mb) * 2**20
-
-
 def zero_copy_device_put() -> bool:
     """Whether ``np.frombuffer`` views of shm/mmap may be fed straight
     to ``device_put``.  On a real accelerator H2D always copies, so
     views are safe and save one host memcpy per leaf.  On the CPU
     backend jax may alias a suitably-aligned host buffer instead of
     copying — a restored param aliased to shm would be silently
-    corrupted by the next snapshot — so views are detached first.
-    ``DLROVER_RESTORE_ZERO_COPY=1/0`` overrides the probe."""
-    val = os.getenv(RESTORE_ZERO_COPY_ENV, "").strip().lower()
-    if val:
-        return val not in ("0", "false", "no", "off")
+    corrupted by the next snapshot — so views are detached first."""
     try:
         import jax
 
@@ -205,12 +195,12 @@ class StagedRestore:
     # -- chunked detach ----------------------------------------------------
 
     def copy_chunked(self, dst: np.ndarray, src: np.ndarray) -> List:
-        """``dst[...] = src`` split into ~chunk_bytes pieces, each a
+        """``dst[...] = src`` split into ~CHUNK_BYTES pieces, each a
         GIL-released :func:`fastcopy.copy_into`; returns the futures
         (already done when serial).  Splitting a single large leaf is
         what parallelizes the page faults of a cold shm mapping."""
         return copy_into_chunked(
-            dst, src, submit=self.submit, chunk_bytes=chunk_bytes()
+            dst, src, submit=self.submit, chunk_bytes=CHUNK_BYTES
         )
 
     def detach_flat(

@@ -225,22 +225,19 @@ class AsyncCheckpointSaver:
         daemon thread the moment a death is witnessed — the page-ins
         overlap the breakpoint save, the worker stop AND the new
         trainer's interpreter + jax import.  Read-only strided
-        touches on a PINNED thread budget (``prefault_workers``): the
-        prefetch exists to hide latency from the respawn, so it must
-        never out-compete the respawn for cores.  Returns bytes
-        touched."""
+        touches on a PINNED thread budget (``PREFAULT_WORKERS`` of
+        ``shm_handler``): the prefetch exists to hide latency from the
+        respawn, so it must never out-compete the respawn for cores.
+        Returns bytes touched."""
         saver = cls._instance
         if saver is None:
             return 0
-        from dlrover_tpu.checkpoint.shm_handler import prefault_workers
-
         t0 = time.time()
         touched = 0
         segments = 0
-        workers = prefault_workers()
         for handler in saver._shm_handlers:
             try:
-                nbytes = handler.prefault(workers=workers)
+                nbytes = handler.prefault()
                 if nbytes:
                     touched += nbytes
                     segments += 1

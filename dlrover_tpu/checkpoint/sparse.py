@@ -51,7 +51,6 @@ from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.ops.kv_variable import (
     DIRTY_CONSUMER_CHECKPOINT,
     DIRTY_CONSUMER_SERVING,
-    DIRTY_CONSUMER_SHM,
 )
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
@@ -171,61 +170,6 @@ def keys_digest(keys: np.ndarray) -> int:
         return int(np.sum(_hash64(keys), dtype=np.uint64))
 
 
-def merge_kv_states(
-    base: Dict[str, Any], deltas: List[Dict[str, Any]],
-) -> Dict[str, Any]:
-    """Replay a base + delta chain in numpy-land WITHOUT tables: the
-    paged shm tier stores kv pages as pickled export blobs and a
-    restore (or the agent's flat materialization) must flatten the
-    chain back to one full export bit-equal to what the live tables
-    would produce.  Per delta: tombstones delete first, then touched
-    rows last-write-win (the exact :meth:`SparseStateAdapter.
-    apply_delta` ordering).  Optimizer scalars ride whole per link —
-    the newest link's copy wins."""
-    names = [k for k in base.keys() if k != SCALARS_KEY]
-    merged: Dict[str, Any] = {}
-    for name in names:
-        sub = base[name]
-        keys = np.ascontiguousarray(sub["keys"], dtype=np.int64)
-        values = np.ascontiguousarray(sub["values"], dtype=np.float32)
-        freq = np.ascontiguousarray(sub["freq"], dtype=np.uint64)
-        for d in deltas:
-            dsub = d.get(name)
-            if not isinstance(dsub, dict):
-                continue
-            dead = np.ascontiguousarray(
-                dsub.get("dead", ()), dtype=np.int64
-            )
-            if dead.size:
-                live = ~np.isin(keys, dead)
-                keys, values, freq = (
-                    keys[live], values[live], freq[live]
-                )
-            dkeys = np.ascontiguousarray(dsub["keys"], dtype=np.int64)
-            if dkeys.size:
-                keep = ~np.isin(keys, dkeys)
-                keys = np.concatenate([keys[keep], dkeys])
-                values = np.concatenate([
-                    values[keep],
-                    np.ascontiguousarray(
-                        dsub["values"], dtype=np.float32
-                    ),
-                ])
-                freq = np.concatenate([
-                    freq[keep],
-                    np.ascontiguousarray(
-                        dsub["freq"], dtype=np.uint64
-                    ),
-                ])
-        merged[name] = {"keys": keys, "values": values, "freq": freq}
-    scalars = base.get(SCALARS_KEY)
-    for d in deltas:
-        scalars = d.get(SCALARS_KEY, scalars)
-    if scalars:
-        merged[SCALARS_KEY] = scalars
-    return merged
-
-
 def _digest_enabled() -> bool:
     return os.environ.get(
         "DLROVER_KV_DIGEST", ""
@@ -263,13 +207,6 @@ class SparseStateAdapter:
         self._delta_every: Optional[int] = None
         self._ckpt_chain: List[int] = []
         self._ckpt_poisoned = True
-        # paged shm tier (consumer 2): its base+delta pages live in
-        # the shm segment itself, so the chain here is only a length
-        # counter for the full-base cadence; poisoned forces the next
-        # shm export to re-base (fresh adapter, any restore, or a
-        # paged save that failed after draining the baseline)
-        self._shm_chain_len = 0
-        self._shm_poisoned = True
 
     # -- registration -------------------------------------------------------
 
@@ -663,62 +600,6 @@ class SparseStateAdapter:
         self._ckpt_chain.append(step_i)
         return out
 
-    # -- paged shm tier (consumer 2) ---------------------------------------
-
-    def shm_chain_poison(self) -> None:
-        """Force the next paged shm export to re-base — same
-        discipline as :meth:`checkpoint_chain_poison`, for the shm
-        consumer slot: a paged save that failed or was skipped AFTER
-        the delta drained its baseline would otherwise silently drop
-        those rows from the segment."""
-        self._shm_poisoned = True
-
-    def export_for_shm(
-        self, step: Optional[int] = None, rank: Optional[int] = None,
-        full_every: int = 0,
-    ) -> Tuple[str, Dict[str, Any]]:
-        """The paged shm tier's export entry: ``("base", state)`` on
-        the first save / after any poison / every ``full_every``-th
-        save, else ``("delta", state)`` holding only the consumer-2
-        dirty rows.  Unlike the storage chain there is no
-        :data:`KV_META_KEY` link metadata — the shm page directory
-        itself records the chain."""
-        untracked = any(
-            not t.dirty_tracking_enabled(DIRTY_CONSUMER_SHM)
-            for t in self._tables.values()
-        )
-        self.enable_dirty_tracking(DIRTY_CONSUMER_SHM)
-        cadence = int(full_every or 0)
-        if (
-            untracked
-            or self._shm_poisoned
-            or self._shm_chain_len <= 0
-            or (cadence > 0 and self._shm_chain_len >= cadence)
-        ):
-            # baseline BEFORE the export (the publisher's ordering):
-            # a racing mutation lands in the base AND the next delta
-            for table in self._tables.values():
-                table.clear_dirty(DIRTY_CONSUMER_SHM)
-            out = self.export_state(
-                step=step, rank=rank,
-                extra_event={"kind": "base",
-                             "consumer": DIRTY_CONSUMER_SHM},
-            )
-            self._shm_chain_len = 1
-            self._shm_poisoned = False
-            return "base", out
-        out = self.export_delta(
-            step=step, rank=rank, clear=True,
-            consumer=DIRTY_CONSUMER_SHM,
-            extra_event={
-                "kind": "delta",
-                "consumer": DIRTY_CONSUMER_SHM,
-                "chain_len": self._shm_chain_len + 1,
-            },
-        )
-        self._shm_chain_len += 1
-        return "delta", out
-
     @staticmethod
     def chain_steps(meta: Dict[str, Any]) -> List[int]:
         """The storage steps a delta link's restore must replay
@@ -764,7 +645,6 @@ class SparseStateAdapter:
         # import re-marks every row dirty anyway, and a delta chained
         # onto pre-restore history would be wrong — next export bases
         self._ckpt_poisoned = True
-        self._shm_poisoned = True
         with_digest = self.digest_enabled()
         rows = nbytes = 0
         digests: Dict[str, Dict[str, Any]] = {}
@@ -999,7 +879,6 @@ class SparseStateAdapter:
             if est:
                 table.reserve(est // max(1, world_size) + 64)
         self._ckpt_poisoned = True
-        self._shm_poisoned = True
 
         rows = nbytes = total_rows = chunks = 0
         import_sums: Dict[str, int] = {}

@@ -37,13 +37,12 @@ _SPILL_DISK_ROWS_GAUGE = _REG.gauge(
 
 _lib = None
 
-# Dirty-baseline consumer slots: the serving publisher, the delta
-# flash checkpointer and the paged shm tier drain deltas on
-# independent cadences — each owns its own dirty/dead baseline on the
-# C++ table so no plane can clear rows out of another's next delta.
+# Dirty-baseline consumer slots: the serving publisher and the delta
+# flash checkpointer drain deltas on independent cadences — each owns
+# its own dirty/dead baseline on the C++ table so no plane can clear
+# rows out of another's next delta.
 DIRTY_CONSUMER_SERVING = 0
 DIRTY_CONSUMER_CHECKPOINT = 1
-DIRTY_CONSUMER_SHM = 2
 
 
 def _load():

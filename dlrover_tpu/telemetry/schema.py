@@ -118,16 +118,8 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
            ["leases", "rollout_s", "score_s", "gae_s", "train_s",
             "actor_loss", "critic_loss"]),
         # -- checkpoint (open phase dicts: stage timings vary) -------
-        # paged shm tier (DLROVER_SHM_PAGED): paged=True saves carry
-        # kind (base/delta), the published generation, pages_written,
-        # bytes moved vs bytes_skipped (copy-skip) vs bytes_total,
-        # kv_bytes (the sparse page blob), and the compare/kv/publish
-        # stage seconds next to the flat path's fetch/memcpy ones
         _s("checkpoint_shm_save", ["step", "rank"],
-           ["paged", "kind", "generation", "pages_written",
-            "bytes", "bytes_skipped", "bytes_total", "kv_bytes",
-            "fetch_s", "compare_s", "memcpy_s", "kv_s", "publish_s",
-            "lock_wait_s", "total_s"],
+           ["bytes", "fetch_s", "memcpy_s", "lock_wait_s", "total_s"],
            allow_extra=True),
         _s("checkpoint_restore", ["step", "tier", "rank"],
            allow_extra=True),

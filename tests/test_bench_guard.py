@@ -55,20 +55,6 @@ def _fat_snapshot() -> dict:
                 "h2d_s": 0.345678, "bytes": 402653184, "workers": 8,
             },
             "memcpy_baseline_MBps": 1234.567,
-            # paged shm tier (ISSUE 18): the headline pair plus the
-            # full sub-dict (which must NOT leak into the headline)
-            "shm_hot_save_MBps": 12345.678901,
-            "shm_delta_ratio": 1234.512345,
-            "paged": {
-                "rows": 200000, "touched_rows": 2000,
-                "base_save_s": 0.912345, "delta_save_s": 0.012345,
-                "flat_save_s": 0.812345, "base_bytes": 123456789,
-                "delta_bytes": 123456,
-                "delta_bytes_skipped": 67108864,
-                "hot_save_MBps": 12345.678901,
-                "delta_ratio_x": 1234.512345,
-                "paged_vs_flat_stall_x": 66.123456,
-            },
         },
         "auto_config": {"searched_vs_hand": 0.9661234},
         "sparse_kv": {

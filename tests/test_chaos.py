@@ -484,9 +484,21 @@ def test_kill_worker_primitive_signals_supervised_proc():
             proc.wait()
 
 
-def test_corrupt_shm_torn_snapshot_refused(tmp_path, monkeypatch):
+@pytest.mark.parametrize(
+    "reader,refused",
+    [
+        ("load_state_dict", (None, {})),
+        ("load_flat", (None, {}, {})),
+        ("read_raw", (None, b"", {})),
+    ],
+    ids=["load_state_dict", "load_flat", "read_raw"],
+)
+def test_corrupt_shm_torn_snapshot_refused(
+    tmp_path, monkeypatch, reader, refused
+):
     """A torn shm snapshot (chaos republished writing=True) must be
-    refused by the restore path rather than loaded as garbage."""
+    refused by every reader of the segment (the restore paths and the
+    agent's persist) rather than loaded as garbage."""
     from dlrover_tpu.checkpoint.shm_handler import (
         CheckpointConfig,
         SharedMemoryHandler,
@@ -505,14 +517,15 @@ def test_corrupt_shm_torn_snapshot_refused(tmp_path, monkeypatch):
         handler.save_state_dict(
             state, CheckpointConfig(step=3, rank=0)
         )
-        config, loaded = handler.load_state_dict()
-        assert config is None and loaded == {}
+        assert getattr(handler, reader)() == refused
         # an intact later snapshot loads again (rule exhausted)
         handler.save_state_dict(
             state, CheckpointConfig(step=4, rank=0)
         )
-        config, loaded = handler.load_state_dict()
+        config, loaded = getattr(handler, reader)()[:2]
         assert config is not None and config.step == 4
+        if reader == "read_raw":
+            loaded = {"w": np.frombuffer(loaded, np.float32, count=8)}
         np.testing.assert_array_equal(loaded["w"], state["w"])
     finally:
         handler.unlink()

@@ -162,8 +162,7 @@ def test_c_contiguous_source_is_the_plain_native_memcpy(spy):
 
 
 def test_chunked_copy_stays_on_the_plain_native_memcpy(spy):
-    """The restore pipeline and the paged save pass C-contiguous
-    pieces."""
+    """The restore pipeline passes C-contiguous pieces."""
     src = _random((64, 1024), np.float32)
     dst = np.empty_like(src)
     fastcopy.copy_into_chunked(dst, src, chunk_bytes=64 * 1024)

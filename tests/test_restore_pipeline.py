@@ -122,7 +122,7 @@ def test_detach_flat_bit_identical_serial_vs_parallel(monkeypatch):
     serial = detach_flat(dict(views))
     monkeypatch.setenv(restore_mod.RESTORE_WORKERS_ENV, "4")
     # tiny chunks force many parallel pieces over each leaf
-    monkeypatch.setenv(restore_mod.RESTORE_CHUNK_MB_ENV, "1")
+    monkeypatch.setattr(restore_mod, "CHUNK_BYTES", 2**20)
     parallel = detach_flat(dict(views))
     assert set(serial) == set(parallel)
     for key in views:
@@ -145,7 +145,7 @@ def test_shm_restore_equivalence_and_phases(saver, tmp_path,
     monkeypatch.setenv(restore_mod.RESTORE_WORKERS_ENV, "1")
     step1, serial = engine.load()
     monkeypatch.setenv(restore_mod.RESTORE_WORKERS_ENV, "4")
-    monkeypatch.setenv(restore_mod.RESTORE_CHUNK_MB_ENV, "1")
+    monkeypatch.setattr(restore_mod, "CHUNK_BYTES", 2**20)
     step2, pipelined = engine.load()
     assert step1 == step2 == 3
     assert _leaf_bytes(serial) == _leaf_bytes(pipelined)
@@ -172,7 +172,7 @@ def test_storage_restore_equivalence_and_disk_phases(
     monkeypatch.setenv(restore_mod.RESTORE_WORKERS_ENV, "1")
     step1, serial = engine.load_from_storage()
     monkeypatch.setenv(restore_mod.RESTORE_WORKERS_ENV, "4")
-    monkeypatch.setenv(restore_mod.RESTORE_CHUNK_MB_ENV, "1")
+    monkeypatch.setattr(restore_mod, "CHUNK_BYTES", 2**20)
     step2, pipelined = engine.load_from_storage()
     assert step1 == step2 == 9
     assert _leaf_bytes(serial) == _leaf_bytes(pipelined)
@@ -549,10 +549,10 @@ def test_prefault_touches_whole_snapshot(saver, tmp_path):
     """handler.prefault returns the snapshot's full byte size (every
     page visited) and tolerates an absent snapshot."""
     from dlrover_tpu.checkpoint.shm_handler import (
-        SharedMemoryHandler, prefault_workers,
+        PREFAULT_WORKERS, SharedMemoryHandler,
     )
 
-    assert prefault_workers() >= 1
+    assert PREFAULT_WORKERS >= 1
     eng = _engine(tmp_path)
     try:
         h = SharedMemoryHandler(0, host=False)
