@@ -1364,7 +1364,7 @@ def bench_flash_ckpt(jax, results: dict, workdir: str):
     try:
         # warm up (jit of the on-device copy, shm allocation, saver
         # handshake) — pays one full snapshot
-        assert engine.save_to_storage(1, state_dict)
+        assert engine.save(1, state_dict, persist=True)
         assert engine.wait_async(timeout=240.0)
         tracker = os.path.join(ckpt_dir, CheckpointConstant.TRACKER_FILE)
 
@@ -1377,7 +1377,7 @@ def bench_flash_ckpt(jax, results: dict, workdir: str):
         # timed save: stall (training-thread block), snapshot e2e
         # (crash-restorable in shm), persist e2e (committed on disk)
         t0 = time.perf_counter()
-        ok = engine.save_to_storage(2, state_dict)
+        ok = engine.save(2, state_dict, persist=True)
         stalls.append(time.perf_counter() - t0)
         assert ok, "flash save of step 2 was skipped"
         assert engine.wait_async(timeout=240.0)
@@ -1568,6 +1568,7 @@ for i in range(start_step, 5):
         {"state": state, "trainer": trainer.state_dict()},
         storage_type=StorageType.MEMORY,
     )
+    ckpt.wait()  # the crash below comes AFTER the commit to shm
     if start_step > 0 and not os.path.exists(restored_flag):
         open(restored_flag, "w").close()  # first step after restore
     if trainer.global_step == 3 and not os.path.exists(crash_flag):

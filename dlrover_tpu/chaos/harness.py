@@ -39,6 +39,7 @@ from dlrover_tpu.chaos.scenarios import (
     CHAOS_TRAIN_SCRIPT,
     CKPT_EVERY_ENV,
     DISK_EVERY_ENV,
+    NO_COMMIT_WAIT_TRAIN_SCRIPT,
     RESIZE_TRAIN_SCRIPT,
     RL_TRAIN_SCRIPT,
     RUN_OPTIONS,
@@ -70,6 +71,7 @@ CHAOS_EVENT = "chaos_inject"
 # the GSPMD resize loop)
 TRAIN_SCRIPTS = {
     "default": CHAOS_TRAIN_SCRIPT,
+    "no_commit_wait": NO_COMMIT_WAIT_TRAIN_SCRIPT,
     "sparse": SPARSE_TRAIN_SCRIPT,
     "resize": RESIZE_TRAIN_SCRIPT,
     "sparse_resize": SPARSE_RESIZE_TRAIN_SCRIPT,
@@ -2482,7 +2484,8 @@ def invariants_for_scenario(
             GoodputAtLeast(0.90),
             NoOrphanProcesses(marker=workdir),
         ]
-    if name == "shm-corrupt-storage-fallback":
+    if name in ("shm-corrupt-storage-fallback",
+                "kill-between-accept-and-commit"):
         # full recovery trail PLUS the tier assertion; step loss is
         # bounded by the DISK interval (the shm interval's snapshot
         # was deliberately torn).  ``disk_every`` is the interval the

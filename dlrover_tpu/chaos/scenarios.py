@@ -177,6 +177,9 @@ def after_step():
         ckpt.save_checkpoint(
             trainer.global_step, sd, storage_type=StorageType.MEMORY,
         )
+        # accepted is not committed: a kill rule a step later
+        # must find THIS step in shm
+        ckpt.wait()
 
 if SHARD_DATASET:
     # master-driven dynamic sharding: one step per shard task.  The
@@ -258,6 +261,19 @@ else:
     ckpt.wait()
 ckpt.close()
 '''
+
+# The same loop WITHOUT the commit-wait after a MEMORY save: what a
+# production loop does.  The loop steps on while the writer thread
+# copies the snapshot, so a kill can land between a save's accept and
+# its commit (kill_between_accept_and_commit).
+_COMMIT_WAIT = """\
+        # accepted is not committed: a kill rule a step later
+        # must find THIS step in shm
+        ckpt.wait()
+"""
+assert CHAOS_TRAIN_SCRIPT.count(_COMMIT_WAIT) == 1
+NO_COMMIT_WAIT_TRAIN_SCRIPT = CHAOS_TRAIN_SCRIPT.replace(_COMMIT_WAIT, "")
+
 
 
 # Elastic world-resize train loop (ISSUE 8): a GLOBAL param sharded
@@ -414,6 +430,9 @@ for k in range(start_step, TOTAL_STEPS):
                 trainer.global_step, {"w": w},
                 storage_type=StorageType.MEMORY,
             )
+            # accepted is not committed: a kill rule a step later
+            # must find THIS step in shm
+            ckpt.wait()
 
 # final durable save: every rank persists its shard; the lead rank
 # waits for the commit (needs every surviving rank's done file)
@@ -565,6 +584,9 @@ for k in range(start_step, TOTAL_STEPS):
                 trainer.global_step, sd,
                 storage_type=StorageType.MEMORY,
             )
+            # accepted is not committed: a kill rule a step later
+            # must find THIS step in shm
+            ckpt.wait()
 
 final_sd = {"dense": state, "trainer": trainer.state_dict()}
 deadline = time.time() + 60
@@ -1135,6 +1157,9 @@ for k in range(start_step, TOTAL_STEPS):
                 {"dense": state, "trainer": trainer.state_dict()},
                 storage_type=StorageType.MEMORY,
             )
+            # accepted is not committed: a kill rule a step later
+            # must find THIS step in shm
+            ckpt.wait()
     if trainer.global_step % PUB_EVERY == 0:
         publisher.publish(step=trainer.global_step)
 
@@ -1286,6 +1311,9 @@ for k in range(start_step, TOTAL_STEPS):
                 trainer.global_step, {"w": w},
                 storage_type=StorageType.MEMORY,
             )
+            # accepted is not committed: a kill rule a step later
+            # must find THIS step in shm
+            ckpt.wait()
 
 final_sd = {"w": w}
 if RANK == 0:
@@ -1486,6 +1514,29 @@ def shm_corrupt_storage_fallback(seed: int = 23) -> Scenario:
                 "only_first_incarnation": True,
             },
         ],
+    })
+
+
+def kill_between_accept_and_commit(seed: int = 29) -> Scenario:
+    """A save's ``True`` means accepted, not committed: SIGKILL the
+    worker on its writer thread inside the copy of the step-6 MEMORY
+    save — after the call returned and the loop went on, for this
+    scenario's train script has no ``ckpt.wait()`` behind a MEMORY
+    save.  The segment's meta says ``writing`` over half of step 6,
+    and step 4's snapshot is gone with it: the respawned trainer must
+    refuse the shm tier and restore the committed DISK step 4 from
+    storage (``disk_every=4``), as after a kill inside a synchronous
+    copy."""
+    return Scenario.from_dict({
+        "name": "kill-between-accept-and-commit",
+        "seed": seed,
+        "rules": [{
+            "name": "kill-mid-write",
+            "point": "ckpt.shm_write",
+            "action": "kill",
+            "at_step": 6,
+            "only_first_incarnation": True,
+        }],
     })
 
 
@@ -2043,6 +2094,7 @@ SCENARIOS: Dict[str, Callable[[int], Scenario]] = {
     "preemption_notice": preemption_notice,
     "shm_corruption": shm_corruption,
     "shm_corrupt_storage_fallback": shm_corrupt_storage_fallback,
+    "kill_between_accept_and_commit": kill_between_accept_and_commit,
     "ckpt_brownout_during_preemption": ckpt_brownout_during_preemption,
     "master_kill_restart_midround": master_kill_restart_midround,
     "multinode_rpc_partition": multinode_rpc_partition,
@@ -2076,6 +2128,9 @@ SCENARIOS: Dict[str, Callable[[int], Scenario]] = {
 # mid-run
 RUN_OPTIONS: Dict[str, Dict] = {
     "shm-corrupt-storage-fallback": {"disk_every": 4},
+    "kill-between-accept-and-commit": {
+        "disk_every": 4, "train_script": "no_commit_wait",
+    },
     "ckpt-brownout-during-preemption": {
         "step_sleep": 1.0,
         "extra_env": {

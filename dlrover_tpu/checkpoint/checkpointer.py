@@ -56,6 +56,10 @@ class RestoreHandle:
 
 
 class StorageType(Enum):
+    """What a save asks for beyond shared memory: ``MEMORY`` nothing,
+    ``DISK`` the agent's persist to storage after the commit.  Both
+    take the same route into shared memory."""
+
     MEMORY = 0
     DISK = 1
 
@@ -69,6 +73,8 @@ class Checkpointer:
         ckpt.save_checkpoint(step, {"params": params, "opt": opt_state},
                              storage_type=StorageType.DISK)
         step, state = ckpt.load_checkpoint()
+        ...
+        ckpt.wait()   # before exit: the last save is committed
     """
 
     def __init__(
@@ -125,9 +131,21 @@ class Checkpointer:
         path: str = "",
         storage_type: StorageType = StorageType.DISK,
     ) -> bool:
-        """One ``ckpt.save`` span per call: what the call itself
-        blocks on is its children (``ckpt.save.<part>``); a DISK
-        save's shm write runs later on the writer thread, as
+        """Three stages, for either storage type.  ACCEPTED: this
+        call returns True; a state of device arrays is then held in an
+        on-device snapshot (the call blocks the loop for that copy,
+        and first for a still-running previous write).  COMMITTED: the
+        writer thread has copied it into shared memory
+        (``checkpoint_shm_save``); :meth:`wait` waits for that, and
+        :meth:`load_checkpoint` and :meth:`close` do so themselves.
+        PERSISTED (DISK only): the agent has written the step to
+        storage (``checkpoint_persist``, the tracker file); nothing in
+        this process waits for it.  False: the save was skipped.
+
+        One ``ckpt.save`` span per call (``route``: ``snapshot``, or
+        ``caller`` where the state was written on this thread): what
+        the call blocks on is its children (``ckpt.save.<part>``); a
+        snapshot's shm write runs later on the writer thread, as
         ``ckpt.save.write`` under the same trace id."""
         with _span(
             "ckpt.save", step=step, storage=storage_type.name.lower()
@@ -135,12 +153,14 @@ class Checkpointer:
             ok = self._save(step, state_dict, path, storage_type)
             sp.set_attribute("ok", bool(ok))
             sp.set_attribute("bytes", self._engine.last_save_bytes)
+            sp.set_attribute("route", self._engine.last_save_route)
         return ok
 
     def _save(self, step, state_dict, path, storage_type) -> bool:
-        if storage_type == StorageType.MEMORY:
-            return self._engine.save_to_memory(step, state_dict, path)
-        ok = self._engine.save_to_storage(step, state_dict, path)
+        persist = storage_type == StorageType.DISK
+        ok = self._engine.save(step, state_dict, path, persist=persist)
+        if not persist:
+            return ok
         # the durable tier is independent of the flash tier: a flash
         # save skipped as busy must not starve the orbax cadence, and
         # the cadence counts SAVES (not raw step numbers, which may
@@ -225,11 +245,13 @@ class Checkpointer:
         )
 
     def wait(self, timeout: float = 600.0) -> bool:
-        """Block until in-flight async snapshot writes reach shared
-        memory AND in-flight orbax tier writes complete (call before
-        process exit so the last save is restorable).  The timeout
-        bounds the whole call — a hung remote store cannot block a
-        preemption grace period."""
+        """Block until every accepted save, MEMORY or DISK, is
+        committed to shared memory AND in-flight orbax tier writes
+        complete (call before process exit, or before anything outside
+        this process is to find the last save there).  It does not
+        wait for the agent's persist.  The timeout bounds the whole
+        call — a hung remote store cannot block a preemption grace
+        period."""
         import threading
         import time as _time
 

@@ -2,18 +2,22 @@
 
 Reference: ``CheckpointEngine`` / ``FullCheckpointEngine``
 (``dlrover/trainer/torch/flash_checkpoint/engine.py:135,291``,
-``full_ckpt_engine.py``): ``save_to_memory`` copies the state dict
-into agent-owned shared memory under the shm lock (sub-second,
-blocking the train step only for the device->host copy);
-``save_to_storage`` additionally enqueues a SAVE event the agent
-persists asynchronously; ``load`` prefers the shm snapshot (process
+``full_ckpt_engine.py``).  ``save`` copies the state dict into
+agent-owned shared memory under the shm lock — for a state of device
+arrays from an on-device snapshot, on a writer thread beside the
+steps — and with ``persist`` enqueues a SAVE event the agent persists
+asynchronously (the reference's ``save_to_memory`` /
+``save_to_storage``); ``load`` prefers the shm snapshot (process
 restart with agent alive) and falls back to storage.
 """
 
+import atexit
+import functools
 import os
 import queue
 import threading
 import time
+import weakref
 from typing import Any, Dict, Optional, Tuple
 
 from dlrover_tpu.checkpoint.saver import (
@@ -47,6 +51,11 @@ from dlrover_tpu.telemetry.tracing import (
     span as _span,
 )
 
+# how long a save waits for the writer thread to finish the previous
+# snapshot before it is skipped: many times a write (seconds) behind
+# the agent's in-RAM copy (seconds), short of a hung agent's 600 s
+_WRITER_WAIT_BOUND_S = 60.0
+
 _REG = get_registry()
 _SHM_SAVE_SECONDS = _REG.histogram(
     "dlrover_checkpoint_shm_save_seconds",
@@ -58,7 +67,9 @@ _ASYNC_WRITE_SECONDS = _REG.histogram(
 )
 _SAVE_SKIPPED_TOTAL = _REG.counter(
     "dlrover_checkpoint_save_skipped_total",
-    "Flash saves skipped because the saver/writer was busy",
+    "Flash saves skipped: the saver held the shard lock "
+    "(saver_busy), or the writer thread was still busy after the "
+    "bounded wait (writer_busy)",
 )
 _SAVE_ERRORS_TOTAL = _REG.counter(
     "dlrover_checkpoint_save_errors_total",
@@ -73,6 +84,21 @@ _RESTORE_STAGE_SECONDS = _REG.histogram(
     "Per-stage restore pipeline time (labels: tier, stage = "
     "read / assemble / h2d)",
 )
+
+
+def _memory_stats(dev) -> Dict[str, int]:
+    """What the runtime says of ``dev``'s memory ({} on the CPU)."""
+    return dev.memory_stats() or {}
+
+
+def _drain_at_exit(engine_ref):
+    """``atexit``: a normal exit commits what was accepted."""
+    engine = engine_ref()
+    if engine is not None and not engine.wait_async(_WRITER_WAIT_BOUND_S):
+        logger.warning(
+            "exit with a snapshot still being written after %.0f s; "
+            "the shm segment is left torn", _WRITER_WAIT_BOUND_S,
+        )
 
 
 class CheckpointEngine:
@@ -100,6 +126,14 @@ class CheckpointEngine:
         self._writer_thread: Optional[threading.Thread] = None
         self._writer_lock = threading.Lock()
         self._jit_copy = None
+        self._exit_drain = None
+        # the route of a save (:meth:`_why_no_snapshot`): whether a
+        # device has reported its memory, and whether a snapshot of
+        # this engine has yet lived beside the caller's loop
+        self._device_reports = False
+        self._loop_unseen = True
+        # "snapshot" | "caller": the route the last save took
+        self.last_save_route = ""
         self._last_async_error: Optional[Exception] = None
         # phase breakdown of the last completed shm save (lock wait,
         # device->host fetch, memcpy) — surfaced so benches report the
@@ -303,31 +337,143 @@ class CheckpointEngine:
 
     # -- save ---------------------------------------------------------------
 
-    def save_to_memory(
+    def save(
         self, step: int, state_dict, path: str = "",
-        block_lock: bool = False, durable: bool = False,
+        persist: bool = False,
     ) -> bool:
-        """Synchronous part of a flash save: device->host copy into
-        shm under the shm lock.  Non-blocking lock by default: if the
-        agent is still persisting the previous snapshot, skip this
-        save rather than stall training (reference:
-        save_state_dict_to_memory, engine.py:291).  The async writer
-        thread passes ``block_lock=True`` — it is off the training
-        path, so waiting for the agent is free and the save must not
-        be silently dropped."""
+        """One flash save.  ``True`` means ACCEPTED: the state is in
+        shared memory already, or in an on-device snapshot the writer
+        thread is copying there.  The ``checkpoint_shm_save`` event is
+        the commit (:meth:`wait_async` waits for it, every read
+        through this engine does so first); with ``persist`` the agent
+        is then asked to write the step to storage.
+
+        ``route="snapshot"``, a state that holds a ``jax.Array``: the
+        loop is blocked for an on-device copy alone.  A ``jax.Array``
+        is immutable, so only buffer donation by the caller's next
+        step has to be guarded against, where the reference copies
+        synchronously because torch tensors mutate in place
+        (ckpt_saver.py:174 _traverse_copy_to_shm); the device->host
+        fetch and the copy into shm run on the writer thread beside
+        the steps.  At most one snapshot is alive: while the previous
+        one is still being written the call waits for the writer, and
+        skips the save only past ``_WRITER_WAIT_BOUND_S``; and an
+        engine's first snapshot on a device that reports its memory
+        is committed before the call returns.  A crash between the
+        call and the commit leaves a segment whose meta says
+        ``writing``, and the restore falls to the next tier, as after
+        a crash inside a synchronous copy.
+
+        ``route="caller"``, written on the caller's thread and skipped
+        if the agent holds the shard lock: a state of host leaves
+        alone, and a state whose snapshot would not fit beside the
+        next step.  That is decided anew at every save from what the
+        device reports (:meth:`_why_no_snapshot`); a snapshot that
+        raises RESOURCE_EXHAUSTED all the same sends this one save
+        the same way."""
+        import jax
+
         if not self._notified_agent:
             with _span("ckpt.save.notify_agent"):
                 self._notify_agent_to_create_saver()
-        # sparse tables export here on the SYNC path (MEMORY saves /
-        # no-device-array states); the async path already merged a
-        # consistent export before queueing, which ``_merge_sparse``'s
-        # key guard hands back untouched
-        merged_here = False
+        # before the route is chosen: a save written on this thread
+        # must not be overtaken by an older snapshot either
+        if self._writer_queue.unfinished_tasks and not (
+            self._wait_for_writer(step)
+        ):
+            logger.warning(
+                "step %s: previous snapshot still writing after "
+                "%.0f s; skipping save", step, _WRITER_WAIT_BOUND_S,
+            )
+            _SAVE_SKIPPED_TOTAL.inc(reason="writer_busy")
+            return False
+        snap = None
+        on_device = any(
+            isinstance(leaf, jax.Array)
+            for leaf in jax.tree_util.tree_leaves(state_dict)
+        )
+        why = self._why_no_snapshot(state_dict) if on_device else ""
+        if on_device and not why:
+            try:
+                with _span("ckpt.save.snapshot", step=step):
+                    snap = self._device_snapshot(state_dict)
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                why = str(e).splitlines()[0][:200]
+        if why and self.last_save_route != "caller":
+            logger.warning(
+                "step %s: no snapshot, the state is written on the "
+                "caller's thread: %s", step, why,
+            )
+        self.last_save_route = "caller" if snap is None else "snapshot"
+        state = state_dict if snap is None else snap
+        # the sparse export joins the state NOW, on the caller's
+        # thread: synchronous with respect to table mutation, like
+        # the on-device copy is for the dense leaves; the writer
+        # thread must not read a table the next step scatters into
+        exported = False
         if self._sparse is not None:
             with _span("ckpt.save.sparse_merge", step=step):
-                merged = self._merge_sparse(state_dict, step, durable)
-            merged_here = merged is not state_dict
-            state_dict = merged
+                merged = self._merge_sparse(state, step, durable=persist)
+            exported = merged is not state
+            state = merged
+        if snap is None:
+            ok = self._write_shm(step, state, path, block_lock=False)
+            if ok and persist:
+                self._enqueue_persist(step)
+            if not ok and exported and persist:
+                # a delta export already DRAINED its baseline; the
+                # skipped save means those rows never became durable
+                # — the next export must re-base
+                self._sparse.checkpoint_chain_poison()
+            return ok
+        # kick off the device->host transfers without blocking
+        with _span("ckpt.save.d2h_kickoff", step=step):
+            nbytes = 0
+            for leaf in jax.tree_util.tree_leaves(state):
+                if isinstance(leaf, jax.Array):
+                    nbytes += leaf.nbytes
+                    try:
+                        leaf.copy_to_host_async()
+                    except Exception:  # noqa: BLE001
+                        break
+            self.last_save_bytes = nbytes
+        # the writer thread continues THIS call's span
+        trace_ctx = inject_context()
+        with _span("ckpt.save.enqueue", step=step):
+            self._ensure_writer()
+            self._writer_queue.put(
+                (step, state, path, persist, trace_ctx)
+            )
+        if self._loop_unseen and self._device_reports:
+            # the first snapshot of this engine's life is not left
+            # alive beside the loop: what the device has reserved so
+            # far says nothing yet of the steps between two saves
+            self._wait_for_writer(step)
+        self._loop_unseen = False
+        return True
+
+    def _wait_for_writer(self, step: int) -> bool:
+        """Stall until the writer thread is idle, up to the bound."""
+        with _span("ckpt.save.writer_wait", step=step) as sp:
+            t0 = time.perf_counter()
+            idle = self.wait_async(_WRITER_WAIT_BOUND_S)
+            sp.set_attribute(
+                "waited_s", round(time.perf_counter() - t0, 6)
+            )
+        return idle
+
+    def _write_shm(
+        self, step: int, state_dict, path: str, block_lock: bool,
+    ) -> bool:
+        """Device->host fetch and copy into shm under the shard's shm
+        lock; the ``checkpoint_shm_save`` event is the commit.  On the
+        caller's thread the lock is tried once (``block_lock=False``):
+        if the agent is still persisting the previous snapshot the
+        save is skipped rather than stall training.  The writer
+        thread waits for it — it is off the training path, and an
+        accepted save must not be dropped."""
         # every rank locks its shard: the agent's breakpoint save reads
         # all local shards, so an unlocked write can be torn even for
         # ranks that never persist to storage; without an agent there
@@ -352,11 +498,6 @@ class CheckpointEngine:
                     step,
                 )
                 _SAVE_SKIPPED_TOTAL.inc(reason="saver_busy")
-                if merged_here and durable:
-                    # a delta export already DRAINED its baseline;
-                    # the skipped save means those rows never became
-                    # durable — the next export must re-base
-                    self._sparse.checkpoint_chain_poison()
                 return False
             lock_wait = time.perf_counter() - t0
             locked = True
@@ -397,7 +538,7 @@ class CheckpointEngine:
 
     def _agent_lock_available(self) -> bool:
         """Whether an agent-side lock server exists for this shard
-        (absent in standalone/no-agent mode, where save_to_memory has
+        (absent in standalone/no-agent mode, where a shm write has
         no concurrent reader to guard against)."""
         from dlrover_tpu.common.multi_process import _socket_path
 
@@ -405,7 +546,47 @@ class CheckpointEngine:
             _socket_path(f"{LOCK_PREFIX}_{self._local_rank}")
         )
 
-    # -- async snapshot path -------------------------------------------------
+    # -- the snapshot and its writer thread ------------------------------------
+
+    def _why_no_snapshot(self, state_dict) -> str:
+        """Empty if every device that holds the state reports room for
+        its shards a second time BESIDE the largest scratch a program
+        has reserved there, else the reason.  A snapshot stays alive
+        through the next steps: where ``bytes_in_use`` + the snapshot
+        + ``peak_bytes_reserved`` pass ``bytes_limit`` the snapshot
+        itself would succeed and the step after it would fail to load
+        (TPU v5e, 34-layer GPT-2-XL: "Attempting to reserve 7.25G at
+        the bottom of memory").  A backend that reports no such
+        figures (the CPU's) is left to the allocation itself."""
+        import math
+
+        import jax
+
+        snapshot: Dict[Any, int] = {}
+        for leaf in jax.tree_util.tree_leaves(state_dict):
+            if not isinstance(leaf, jax.Array):
+                continue
+            nbytes = leaf.dtype.itemsize * math.prod(
+                leaf.sharding.shard_shape(leaf.shape)
+            )
+            for dev in leaf.sharding.addressable_devices:
+                snapshot[dev] = snapshot.get(dev, 0) + nbytes
+        for dev, nbytes in snapshot.items():
+            stats = _memory_stats(dev)
+            limit = stats.get("bytes_limit")
+            reserved = stats.get("peak_bytes_reserved")
+            if limit is None or reserved is None:
+                continue
+            self._device_reports = True
+            in_use = stats.get("bytes_in_use", 0)
+            if in_use + nbytes + reserved > limit:
+                return (
+                    f"{dev}: {in_use / 2**30:.2f} GB in use + "
+                    f"{nbytes / 2**30:.2f} GB of snapshot + "
+                    f"{reserved / 2**30:.2f} GB of program scratch "
+                    f"pass the device's {limit / 2**30:.2f} GB"
+                )
+        return ""
 
     def _device_snapshot(self, state_dict):
         """Copy every device-array leaf to a fresh on-device buffer.
@@ -440,6 +621,15 @@ class CheckpointEngine:
 
     def _ensure_writer(self):
         with self._writer_lock:
+            if self._exit_drain is None:
+                # the writer is a daemon thread: without this a script
+                # that ends right after an accepted save would leave
+                # the segment ``writing`` and lose the previous
+                # snapshot with it
+                self._exit_drain = functools.partial(
+                    _drain_at_exit, weakref.ref(self)
+                )
+                atexit.register(self._exit_drain)
             if self._writer_thread is None or (
                 not self._writer_thread.is_alive()
             ):
@@ -454,20 +644,25 @@ class CheckpointEngine:
             item = self._writer_queue.get()
             if item is None:
                 return
-            step, snap, path, enqueue, trace_ctx = item
+            step, snap, path, persist, trace_ctx = item
             try:
                 # the save call's trace continues on this thread
                 with attach_context(trace_ctx), _span(
                     "ckpt.save.write", step=step
                 ):
                     with _ASYNC_WRITE_SECONDS.time():
-                        ok = self.save_to_memory(
+                        ok = self._write_shm(
                             step, snap, path, block_lock=True
                         )
-                    if ok and enqueue:
+                    if ok and persist:
                         self._enqueue_persist(step)
+                if not ok:
+                    raise TimeoutError(
+                        "the shard's shm lock was not free in 600 s"
+                    )
             except Exception as e:  # noqa: BLE001
-                self._last_async_error = e
+                # without the traceback, whose frames hold the snapshot
+                self._last_async_error = e.with_traceback(None)
                 _SAVE_ERRORS_TOTAL.inc()
                 if self._sparse is not None:
                     # the queued snapshot may hold a drained delta
@@ -477,81 +672,21 @@ class CheckpointEngine:
                     "async snapshot of step %s failed", step
                 )
             finally:
+                # the snapshot dies BEFORE the waiter wakes: the next
+                # save takes its own right after, and the device holds
+                # the state twice, never three times
+                del item, snap
                 self._writer_queue.task_done()
 
     def wait_async(self, timeout: float = 600.0) -> bool:
-        """Block until in-flight async snapshots are written to shm
-        (tests / shutdown); returns False on timeout.
-        ``unfinished_tasks`` counts queued and in-progress items."""
-        deadline = time.monotonic() + timeout
-        while self._writer_queue.unfinished_tasks:
-            if time.monotonic() > deadline:
-                return False
-            time.sleep(0.02)
-        return True
-
-    def save_to_storage(self, step: int, state_dict, path: str = "") -> bool:
-        """Flash save: shm write + async persist by the agent
-        (reference: save_to_storage in full_ckpt_engine.py).
-
-        A state that holds a ``jax.Array`` takes the snapshot route,
-        which exploits jax.Array immutability: the training stall is
-        only a cheap on-device copy (guarding against buffer donation
-        invalidating the refs); the device->host fetch, shm write and
-        persist enqueue all happen on the writer thread.  The
-        reference must copy synchronously because torch tensors mutate
-        in place (ckpt_saver.py:174 _traverse_copy_to_shm); JAX does
-        not.  Trade-off: a crash between this call returning and the
-        background shm write completing loses that snapshot (the
-        previous one remains) — same exposure as the reference's async
-        persist window.  A state of host leaves alone has nothing to
-        snapshot and is written synchronously."""
-        import jax
-
-        has_device_arrays = any(
-            isinstance(leaf, jax.Array)
-            for leaf in jax.tree_util.tree_leaves(state_dict)
-        )
-        if has_device_arrays:
-            if self._writer_queue.unfinished_tasks:
-                logger.info(
-                    "step %s: previous snapshot still writing; "
-                    "skipping save", step,
-                )
-                _SAVE_SKIPPED_TOTAL.inc(reason="writer_busy")
-                return False
-            with _span("ckpt.save.snapshot", step=step):
-                snap = self._device_snapshot(state_dict)
-            # sparse export joins the snapshot NOW — synchronous with
-            # respect to table mutation, like the on-device copy is
-            # for the dense leaves; the writer thread must not read a
-            # table the next train step is already scattering into
-            if self._sparse is not None:
-                with _span("ckpt.save.sparse_merge", step=step):
-                    snap = self._merge_sparse(snap, step, durable=True)
-            # kick off the device->host transfers without blocking
-            with _span("ckpt.save.d2h_kickoff", step=step):
-                nbytes = 0
-                for leaf in jax.tree_util.tree_leaves(snap):
-                    if isinstance(leaf, jax.Array):
-                        nbytes += leaf.nbytes
-                        try:
-                            leaf.copy_to_host_async()
-                        except Exception:  # noqa: BLE001
-                            break
-                self.last_save_bytes = nbytes
-            # the writer thread continues THIS call's span
-            trace_ctx = inject_context()
-            with _span("ckpt.save.enqueue", step=step):
-                self._ensure_writer()
-                self._writer_queue.put(
-                    (step, snap, path, True, trace_ctx)
-                )
-            return True
-        ok = self.save_to_memory(step, state_dict, path, durable=True)
-        if ok:
-            self._enqueue_persist(step)
-        return ok
+        """Block until every accepted save is committed to shm;
+        returns False on timeout.  ``unfinished_tasks`` counts queued
+        and in-progress items."""
+        q = self._writer_queue
+        with q.all_tasks_done:
+            return q.all_tasks_done.wait_for(
+                lambda: not q.unfinished_tasks, timeout
+            )
 
     def _enqueue_persist(self, step: int):
         """Ask the agent to persist ``step`` (this node's lead process
@@ -664,6 +799,8 @@ class CheckpointEngine:
         own = stats is None
         if own:
             stats = RestoreStats()
+        # a process reads its own writes: accepted saves commit first
+        self.wait_async()
         t0 = time.perf_counter()
         try:
             config, state = self._shm_handler.load_state_dict(
@@ -998,6 +1135,7 @@ class CheckpointEngine:
         from dlrover_tpu.checkpoint.restore import RestoreStats
         with _span("ckpt.restore") as sp:
             sp.set_attribute("sharded", True)
+            self.wait_async()  # as get_state_dict_from_memory does
             stats = RestoreStats()
             t0 = time.perf_counter()
             config, flat, metas = self._shm_handler.load_flat(
@@ -1376,7 +1514,10 @@ class CheckpointEngine:
         return jax.tree_util.tree_unflatten(treedef, ordered)
 
     def close(self):
-        self.wait_async(timeout=60.0)
+        self.wait_async(timeout=_WRITER_WAIT_BOUND_S)
+        if self._exit_drain is not None:
+            atexit.unregister(self._exit_drain)
+            self._exit_drain = None
         if self._writer_thread is not None and self._writer_thread.is_alive():
             self._writer_queue.put(None)
             self._writer_thread.join(timeout=5.0)

@@ -247,7 +247,12 @@ class SharedMemoryHandler:
                 )
             import jax
 
+            from dlrover_tpu import chaos as _chaos
             from dlrover_tpu.ops.fastcopy import copy_into
+
+            # chaos hook: a kill rule here ends the process between a
+            # save's accept and its commit, the meta saying ``writing``
+            _chaos.fire("ckpt.shm_write", step=config.step)
 
             buf = self._shm.buf
             # leaves are fetched in BATCHED chunks: ``jax.device_get``
@@ -331,8 +336,6 @@ class SharedMemoryHandler:
         # chaos hook: a corrupt_shm rule flips bytes of (or tears) the
         # snapshot that was just published, so restore/persist paths
         # must prove they reject or survive a damaged segment
-        from dlrover_tpu import chaos as _chaos
-
         _chaos.fire("ckpt.shm_save", step=config.step, handler=self)
         logger.debug(
             "rank %s wrote %.1f MB checkpoint step %s to shm "

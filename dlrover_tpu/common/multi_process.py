@@ -26,6 +26,7 @@ from dlrover_tpu.common.comm import (
     _recv_frame,
     _send_frame,
 )
+from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.log import default_logger as logger
 
 
@@ -156,6 +157,8 @@ class SharedLock(LocalSocketComm):
 
     def __init__(self, name: str, create: bool):
         self._lock = threading.Lock() if create else None
+        # serialises try-acquires (a connection has its own thread)
+        self._handover = threading.Lock() if create else None
         self._owner: Optional[str] = None
         # what the holder said it holds the lock for ("persist:120")
         self._note = ""
@@ -168,10 +171,16 @@ class SharedLock(LocalSocketComm):
         verb = request[0]
         if verb == "try_acquire":
             (_, owner, *note) = request
-            ok = self._lock.acquire(blocking=False)
-            if ok:
-                self._owner = owner
-                self._note = note[0] if note else ""
+            # a holder that died with the lock (a trainer killed
+            # inside a save's copy) hands it on: nobody else would
+            # ever release it
+            with self._handover:
+                ok = self._lock.acquire(blocking=False) or (
+                    self._holder_is_dead()
+                )
+                if ok:
+                    self._owner = owner
+                    self._note = note[0] if note else ""
             return ok
         if verb == "holder":
             if not self._lock.locked():
@@ -191,6 +200,23 @@ class SharedLock(LocalSocketComm):
         if verb == "locked":
             return self._lock.locked()
         raise ValueError(f"unknown lock verb {verb}")
+
+    def _holder_is_dead(self) -> bool:
+        """Whether the lock is held under a ``pid-<n>`` tag whose
+        process is gone or a zombie (server side: the clients of a
+        local socket share this host's pids)."""
+        owner = self._owner or ""
+        if not owner.startswith("pid-"):
+            return False
+        pid = int(owner[4:])
+        try:
+            os.kill(pid, 0)
+        except ProcessLookupError:
+            return True
+        except PermissionError:
+            return False
+        fields = env_utils.proc_stat_fields(pid)
+        return fields is not None and fields[0] == b"Z"
 
     def _try_acquire(self, owner: str, note: str = "") -> bool:
         if self._create:

@@ -355,7 +355,9 @@ SPAN_SCHEMAS: Dict[str, SpanSchema] = {
         SpanSchema(
             "ckpt.save", "trainer",
             "one save_checkpoint call: how long it blocked the loop "
-            "(step, storage, bytes, ok); its children say where"),
+            "(step, storage, bytes, ok; route = snapshot, or caller "
+            "where the state was written on the calling thread); its "
+            "children say where"),
         SpanSchema(
             "ckpt.save.notify_agent", "trainer",
             "shipping the saver config to the agent (first save)"),
@@ -386,19 +388,25 @@ SPAN_SCHEMAS: Dict[str, SpanSchema] = {
             "ckpt.save.scalars", "trainer",
             "writing the pickled non-array leaves"),
         SpanSchema(
+            "ckpt.save.writer_wait", "trainer",
+            "waiting for the writer thread: to commit the previous "
+            "snapshot before taking the next (past the bound the "
+            "save is skipped), or this engine's first snapshot before "
+            "the call returns (waited_s)"),
+        SpanSchema(
             "ckpt.save.snapshot", "trainer",
-            "DISK save: on-device copy of the state"),
+            "on-device copy of the state"),
         SpanSchema(
             "ckpt.save.d2h_kickoff", "trainer",
-            "DISK save: starting the async device->host copies"),
+            "starting the async device->host copies"),
         SpanSchema(
             "ckpt.save.enqueue", "trainer",
-            "DISK save: handing the snapshot to the writer thread "
-            "(or the persist request to the agent)"),
+            "handing the snapshot to the writer thread"),
         SpanSchema(
             "ckpt.save.write", "trainer (writer thread)",
-            "DISK save: the snapshot's shm write, off the loop, same "
-            "trace id as its ckpt.save"),
+            "the snapshot's shm write (lock_wait, fetch, memcpy ... "
+            "beneath it), off the loop, same trace id as its "
+            "ckpt.save"),
         # -- a persist, agent side -----------------------------------
         SpanSchema(
             "ckpt.persist", "agent",

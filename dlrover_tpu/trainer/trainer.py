@@ -223,11 +223,9 @@ class Trainer:
                 except StopIteration:
                     data_iter = iter(self.train_data)
                     batch = next(data_iter)
-        # final storage save; flush in-flight snapshots first so the
-        # save cannot be skipped as busy, then flush it too so a
-        # process exit right after train() cannot lose it
-        if self._checkpointer is not None:
-            self._checkpointer.wait()
+        # final storage save (it waits for an in-flight snapshot
+        # itself); then its commit, so a process exit right after
+        # train() cannot lose it
         self._save(step, True)
         if self._checkpointer is not None:
             self._checkpointer.wait()

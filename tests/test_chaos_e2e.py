@@ -155,6 +155,43 @@ def test_shm_corruption_falls_back_to_storage_tier(tmp_path):
     assert final_step == TOTAL_STEPS and 0 in shards
 
 
+def test_kill_between_accept_and_commit_falls_to_storage(tmp_path):
+    """A MEMORY save returned True, the loop went on with no
+    ``wait()``, and the worker is SIGKILLed on its writer thread
+    inside that save's copy: the segment says ``writing`` (step 4's
+    snapshot is gone under half of step 6), the respawned trainer
+    refuses it and restores the committed DISK step 4 from storage;
+    an accepted save costs at most ``disk_every`` steps."""
+    report = _run(
+        tmp_path, scenarios.kill_between_accept_and_commit(seed=29)
+    )
+    assert report.ok, report.summary()
+    (fault,) = report.timeline
+    _seq, point, _rule, action, step = fault
+    assert (point, action, step) == ("ckpt.shm_write", "kill", 6)
+    # the train script really has no commit-wait behind a MEMORY save
+    assert scenarios.NO_COMMIT_WAIT_TRAIN_SCRIPT.count(
+        "ckpt.wait()"
+    ) == scenarios.CHAOS_TRAIN_SCRIPT.count("ckpt.wait()") - 1
+    restores = [
+        e for e in report.events
+        if e.get("type") == "checkpoint_restore"
+    ]
+    assert restores and restores[0]["tier"] == "storage", restores
+    assert restores[0]["step"] == 4, restores
+    # step 6 was accepted and never committed in the first incarnation
+    commits = [
+        e["step"] for e in report.events
+        if e.get("type") == "checkpoint_shm_save"
+        and e["ts"] < restores[0]["ts"]
+    ]
+    assert commits == [2, 4], commits
+    final_step, shards = read_last_checkpoint(
+        str(tmp_path / "run" / "ckpt")
+    )
+    assert final_step == TOTAL_STEPS and 0 in shards
+
+
 def test_master_kill_restart_midround(tmp_path):
     """ISSUE 4 acceptance (tier-1): SIGKILL the MASTER on its 3rd
     shard dispatch mid-rendezvous-round.  tpurun's watchdog respawns

@@ -4,6 +4,8 @@ test_multi_process.py / test_grpc_utils.py (in-process client+server)."""
 
 import os
 import queue
+import subprocess
+import sys
 import threading
 import time
 
@@ -101,6 +103,48 @@ def test_shared_lock():
     assert client_lock.release()
     assert not server_lock.locked()
     server_lock.close()
+
+
+_LOCK_HOLDER = """
+import os, sys, time
+from dlrover_tpu.common.multi_process import SharedLock
+lock = SharedLock(sys.argv[1], create=False)
+assert lock.acquire(note="save:6")
+print(os.getpid(), flush=True)
+time.sleep(60)
+"""
+
+
+def test_shared_lock_of_a_dead_holder_is_handed_on():
+    """A trainer killed inside a save dies with its shard's lock:
+    the next acquire takes it over, a live holder's it does not."""
+    import dlrover_tpu
+
+    name = f"lock-dead-{os.getpid()}"
+    server = SharedLock(name, create=True)
+    client = SharedLock(name, create=False)
+    pkg_root = os.path.dirname(os.path.dirname(dlrover_tpu.__file__))
+    child = subprocess.Popen(  # noqa: S603
+        [sys.executable, "-c", _LOCK_HOLDER, name],
+        stdout=subprocess.PIPE, text=True,
+        env=dict(os.environ, PYTHONPATH=pkg_root),
+    )
+    try:
+        assert int(child.stdout.readline()) == child.pid
+        assert client.holder() == "save:6"
+        assert not client.acquire(blocking=False)  # the holder lives
+        assert not server.acquire(blocking=False)
+        child.kill()
+        child.wait()
+        assert client.acquire(blocking=False, note="save:7")
+        assert client.holder() == "save:7"
+        assert not server.acquire(blocking=False)  # held by a live pid
+        assert client.release()
+        assert not server.locked()
+    finally:
+        child.kill()
+        client.close()
+        server.close()
 
 
 def test_shared_queue():

@@ -72,7 +72,7 @@ def test_flash_ckpt_cycle_through_object_store(memfs):
             world_size=1,
         )
         sd = {"w": np.arange(8, dtype=np.float32), "step": 3}
-        assert engine.save_to_storage(3, sd)
+        assert engine.save(3, sd, persist=True)
         assert engine.wait_async(timeout=30.0)
         tracker = f"{ckpt_dir}/{CheckpointConstant.TRACKER_FILE}"
         deadline = time.time() + 30

@@ -62,6 +62,17 @@ def covered(root, children):
     return total
 
 
+def uncovered(root, children):
+    return root["duration_s"] - covered(root, children)
+
+
+def slack(root):
+    """What a save's children may leave unnamed: a tenth of the span,
+    and 50 ms for the lock probe, the config, the event and the log
+    line between them, which other threads stretch on a busy host."""
+    return 0.1 * root["duration_s"] + 0.05
+
+
 # -- A. one clock ------------------------------------------------------------
 
 
@@ -204,8 +215,11 @@ def test_annotations_and_span_events_share_one_clock(
 
 
 def test_ckpt_save_children_cover_the_save(tmp_path, event_log):
-    """MEMORY save: the children name >= 90% of the call.  DISK save:
-    the writer thread's shm write runs under the call's trace id."""
+    """MEMORY or DISK, a save's call is the snapshot, the kick-off and
+    the hand-over, and its children name all of it but a bounded
+    remainder (``slack``); the shm write
+    (``fetch``, ``memcpy`` ...) runs on the writer thread under the
+    call's trace id, and ends after the call returned."""
     state = {
         "w": jnp.ones((64, 1024, 1024), jnp.float32),  # 256 MB
         "b": jnp.arange(8, dtype=jnp.int32),
@@ -232,6 +246,7 @@ def test_ckpt_save_children_cover_the_save(tmp_path, event_log):
         ):
             time.sleep(0.05)
     finally:
+        ckpt._engine._shm_handler.unlink()  # 256 MB of /dev/shm
         ckpt.close()
     spans = spans_of(event_log)
     assert all(validate_event(e) == [] for e in spans)
@@ -239,42 +254,56 @@ def test_ckpt_save_children_cover_the_save(tmp_path, event_log):
     assert [r["attributes"]["storage"] for r in roots] == [
         "memory", "memory", "disk"
     ]
-    # the second MEMORY save (the first creates the segment)
-    root = roots[1]
-    assert root["attributes"]["bytes"] >= 256 * 2**20
-    children = [
-        e for e in spans
-        if e["trace_id"] == root["trace_id"] and e is not root
-    ]
-    names = {e["name"] for e in children}
-    assert names >= {
-        "ckpt.save.layout", "ckpt.save.publish_meta",
-        "ckpt.save.fetch", "ckpt.save.memcpy", "ckpt.save.scalars",
-    }
-    assert all(e["parent_id"] == root["span_id"] for e in children)
-    assert covered(root, children) >= 0.9 * root["duration_s"]
-    (memcpy,) = [e for e in children if e["name"] == "ckpt.save.memcpy"]
-    assert memcpy["attributes"]["bytes"] >= 256 * 2**20
-    assert memcpy["attributes"]["copy_s"] <= memcpy["duration_s"]
-    # DISK: snapshot, kick-off and enqueue on the caller's thread;
-    # the write on the writer thread, same trace
+    assert {r["attributes"]["route"] for r in roots} == {"snapshot"}
+    # the second MEMORY save (the first creates the segment) and the
+    # DISK save: the same tree
+    for root in roots[1:]:
+        assert root["attributes"]["bytes"] >= 256 * 2**20
+        same_trace = [
+            e for e in spans
+            if e["trace_id"] == root["trace_id"] and e is not root
+        ]
+        by_name = {e["name"]: e for e in same_trace}
+        assert set(by_name) >= {
+            "ckpt.save.writer_wait", "ckpt.save.snapshot",
+            "ckpt.save.d2h_kickoff", "ckpt.save.enqueue",
+            "ckpt.save.write", "ckpt.save.layout",
+            "ckpt.save.publish_meta", "ckpt.save.fetch",
+            "ckpt.save.memcpy", "ckpt.save.scalars",
+        }
+        # on the caller's thread: the call's own children
+        called = [
+            e for e in same_trace if e["parent_id"] == root["span_id"]
+            and e["name"] != "ckpt.save.write"
+        ]
+        assert {e["name"] for e in called} == {
+            "ckpt.save.writer_wait", "ckpt.save.snapshot",
+            "ckpt.save.d2h_kickoff", "ckpt.save.enqueue",
+        }
+        assert uncovered(root, called) <= slack(root)
+        # on the writer thread: the write and everything beneath it
+        write = by_name["ckpt.save.write"]
+        assert write["parent_id"] == root["span_id"]
+        written = [
+            e for e in same_trace
+            if e not in called and e is not write
+            and not e["name"].startswith("ckpt.persist")
+        ]
+        assert all(e["parent_id"] == write["span_id"] for e in written)
+        assert uncovered(write, written) <= slack(write)
+        memcpy = by_name["ckpt.save.memcpy"]
+        assert memcpy["attributes"]["bytes"] >= 256 * 2**20
+        assert memcpy["attributes"]["copy_s"] <= memcpy["duration_s"]
+        # the call itself returned before the write ended
+        assert (
+            write["start_ts"] + write["duration_s"]
+            > root["start_ts"] + root["duration_s"]
+        )
     disk = roots[2]
     same_trace = {
         e["name"]: e for e in spans
         if e["trace_id"] == disk["trace_id"]
     }
-    assert set(same_trace) >= {
-        "ckpt.save.snapshot", "ckpt.save.d2h_kickoff",
-        "ckpt.save.enqueue", "ckpt.save.write", "ckpt.save.fetch",
-    }
-    write = same_trace["ckpt.save.write"]
-    assert write["parent_id"] == disk["span_id"]
-    assert same_trace["ckpt.save.fetch"]["parent_id"] == write["span_id"]
-    # the call itself returned before the write ended
-    assert (
-        write["start_ts"] + write["duration_s"]
-        > disk["start_ts"] + disk["duration_s"]
-    )
     # the in-process saver persisted it, under the same trace too
     persist = same_trace.get("ckpt.persist")
     assert persist and persist["attributes"]["step"] == 2

@@ -408,8 +408,8 @@ def test_engine_skips_shm_tier_across_world_change(saver, tmp_path):
         str(tmp_path), replicated=False, local_rank=0, global_rank=0,
         world_size=2,
     )
-    assert engine2.save_to_memory(6, state)
-    assert engine2.save_to_storage(6, state)
+    assert engine2.save(6, state)
+    assert engine2.save(6, state, persist=True)
     assert engine2.wait_async(timeout=30.0)
     tracker = tmp_path / "latest_checkpointed_iteration.txt"
     deadline = time.time() + 30
@@ -459,7 +459,7 @@ def test_saver_prefetch_touches_snapshot(saver, tmp_path,
         world_size=1,
     )
     state = {"w": np.arange(4096, dtype=np.float32)}
-    assert engine.save_to_memory(3, state)
+    assert engine.save(3, state)
     touched = AsyncCheckpointSaver.prefetch_shm_snapshots(
         restart_count=1
     )

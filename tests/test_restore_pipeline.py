@@ -140,7 +140,7 @@ def test_shm_restore_equivalence_and_phases(saver, tmp_path,
     the stage breakdown."""
     engine = _engine(tmp_path)
     sd = _state_dict()
-    assert engine.save_to_memory(3, sd)
+    assert engine.save(3, sd)
 
     monkeypatch.setenv(restore_mod.RESTORE_WORKERS_ENV, "1")
     step1, serial = engine.load()
@@ -165,7 +165,7 @@ def test_storage_restore_equivalence_and_disk_phases(
 ):
     engine = _engine(tmp_path)
     sd = _state_dict()
-    assert engine.save_to_storage(9, sd)
+    assert engine.save(9, sd, persist=True)
     assert engine.wait_async(timeout=30.0)
     _wait_tracker(tmp_path)
 
@@ -190,7 +190,7 @@ def test_read_last_checkpoint_mmap_views_match_eager_read(
     """The lazy read_view path must hand back the same bytes the old
     eager read did (and tolerate workers=1)."""
     engine = _engine(tmp_path)
-    engine.save_to_storage(5, _state_dict())
+    engine.save(5, _state_dict(), persist=True)
     engine.wait_async(timeout=30.0)
     _wait_tracker(tmp_path)
     step_a, shards_a = read_last_checkpoint(str(tmp_path), workers=1)
@@ -253,7 +253,7 @@ def test_load_sharded_pipeline_reshard_bit_identical(
     }
     engine = _engine(tmp_path)
     engine.replicated = False
-    assert engine.save_to_memory(5, state)
+    assert engine.save(5, state)
 
     mesh2 = _mesh((2, 4), ("data", "fsdp"))
     target = {
@@ -328,7 +328,7 @@ def test_reshard_save_restore_grid_bit_identical(
     }
     engine = _engine(tmp_path)
     engine.replicated = False
-    assert engine.save_to_memory(3, state)
+    assert engine.save(3, state)
 
     m2 = _mesh(restore_mesh, axes_of[len(restore_mesh)])
     spec2 = (
@@ -391,7 +391,7 @@ def test_reshard_round_trip_2_1_2(saver, tmp_path):
         raise AssertionError(f"step {step} never committed")
 
     e2 = engine_for(2)
-    assert e2.save_to_storage(1, {"w": sharded(2, src)})
+    assert e2.save(1, {"w": sharded(2, src)}, persist=True)
     assert e2.wait_async(timeout=30)
     wait_commit(1)
 
@@ -400,7 +400,7 @@ def test_reshard_round_trip_2_1_2(saver, tmp_path):
     assert step == 1
     assert e1.last_restore_phases["tier"] == "storage"
     assert np.asarray(got["w"]).tobytes() == src.tobytes()
-    assert e1.save_to_storage(2, {"w": got["w"]})
+    assert e1.save(2, {"w": got["w"]}, persist=True)
     assert e1.wait_async(timeout=30)
     wait_commit(2)
 
@@ -429,7 +429,7 @@ def test_restore_span_and_event_carry_stage_breakdown(
     tracer = get_tracer()
     tracer.clear()
     engine = _engine(tmp_path)
-    assert engine.save_to_memory(4, _state_dict())
+    assert engine.save(4, _state_dict())
     step, _state = engine.load()
     assert step == 4
     spans = tracer.finished_spans("ckpt.restore")
@@ -454,7 +454,7 @@ def test_restore_stage_histogram_observed(saver, tmp_path):
     from dlrover_tpu.telemetry.metrics import get_registry
 
     engine = _engine(tmp_path)
-    assert engine.save_to_memory(6, _state_dict())
+    assert engine.save(6, _state_dict())
     hist = get_registry().get(
         "dlrover_checkpoint_restore_stage_seconds"
     )
@@ -520,7 +520,7 @@ def test_engine_prefault_thread_on_respawn(saver, tmp_path,
     state = _state_dict()
     eng = _engine(tmp_path)
     try:
-        assert eng.save_to_memory(3, state)
+        assert eng.save(3, state)
     finally:
         eng.close()
     monkeypatch.setenv("DLROVER_RESTART_COUNT", "1")
@@ -557,7 +557,8 @@ def test_prefault_touches_whole_snapshot(saver, tmp_path):
     try:
         h = SharedMemoryHandler(0, host=False)
         assert h.prefault() == 0  # nothing saved yet
-        assert eng.save_to_memory(9, _state_dict())
+        assert eng.save(9, _state_dict())
+        assert eng.wait_async(timeout=30)  # h reads the commit
         meta = h.metadata()
         expect = meta["scalar_offset"] + meta["scalar_nbytes"]
         assert h.prefault(workers=2) == expect

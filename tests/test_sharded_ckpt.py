@@ -90,7 +90,7 @@ def test_shm_sharded_roundtrip_same_mesh(saver, tmp_path):
         str(tmp_path), replicated=False, local_rank=0, global_rank=0,
         world_size=1,
     )
-    assert engine.save_to_memory(5, state)
+    assert engine.save(5, state)
     target = jax.tree.map(
         lambda x: jnp.zeros_like(x) if isinstance(x, jax.Array) else x,
         state,
@@ -116,7 +116,7 @@ def test_storage_sharded_restore_at_different_mesh(saver, tmp_path):
         str(tmp_path), replicated=False, local_rank=0, global_rank=0,
         world_size=1,
     )
-    assert engine.save_to_storage(5, state)
+    assert engine.save(5, state, persist=True)
     assert engine.wait_async(timeout=60.0)
     tracker = os.path.join(str(tmp_path), CheckpointConstant.TRACKER_FILE)
     deadline = time.time() + 30

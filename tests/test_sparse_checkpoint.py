@@ -537,7 +537,7 @@ def test_engine_shm_round_trip_strips_kv(saver, tmp_path):
     engine = _engine(tmp_path)
     engine.register_sparse(adapter)
     dense = {"w": np.arange(6, dtype=np.float32), "step": 5}
-    assert engine.save_to_memory(5, dense)
+    assert engine.save(5, dense)
     snapshot = {"emb": _sorted_export(t)}
     _train(t, opt, steps=5, n_keys=300, seed=77)  # diverge the table
     step, state = engine.load()
@@ -556,7 +556,7 @@ def test_engine_storage_round_trip_fresh_process(saver, tmp_path):
     t, opt, adapter = _adapter_with_state()
     engine = _engine(tmp_path)
     engine.register_sparse(adapter)
-    assert engine.save_to_storage(3, {"w": np.ones(4, np.float32)})
+    assert engine.save(3, {"w": np.ones(4, np.float32)}, persist=True)
     assert engine.wait_async(timeout=30)
     _wait_commit(tmp_path, 3)
     engine.close()
@@ -604,11 +604,11 @@ def test_engine_cross_world_reshards_and_refuses_shm(tmp_path):
             ranks[rank] = (t, opt, e, mine)
         # local rank 0 notifies the agent; its persist reads ALL
         # local shards, so rank 1's shm snapshot must exist first
-        assert ranks[1][2].save_to_storage(
-            1, {"w": np.full(2, 1.0, np.float32)}
+        assert ranks[1][2].save(
+            1, {"w": np.full(2, 1.0, np.float32)}, persist=True
         )
-        assert ranks[0][2].save_to_storage(
-            1, {"w": np.full(2, 0.0, np.float32)}
+        assert ranks[0][2].save(
+            1, {"w": np.full(2, 0.0, np.float32)}, persist=True
         )
         assert ranks[0][2].wait_async(timeout=30)
         _wait_commit(tmp_path, 1)

@@ -614,8 +614,8 @@ def test_engine_delta_chain_storage_round_trip(tmp_path):
             opt.apply_gradients(
                 keys, np.tanh(t.gather(keys)) * 0.1
             )
-            assert e.save_to_storage(
-                step, {"w": np.ones(3, np.float32) * step}
+            assert e.save(
+                step, {"w": np.ones(3, np.float32) * step}, persist=True
             )
             assert e.wait_async(timeout=30)
             wait_commit(step)
