@@ -428,17 +428,10 @@ class CheckpointEngine:
                 # — the next export must re-base
                 self._sparse.checkpoint_chain_poison()
             return ok
-        # kick off the device->host transfers without blocking
-        with _span("ckpt.save.d2h_kickoff", step=step):
-            nbytes = 0
-            for leaf in jax.tree_util.tree_leaves(state):
-                if isinstance(leaf, jax.Array):
-                    nbytes += leaf.nbytes
-                    try:
-                        leaf.copy_to_host_async()
-                    except Exception:  # noqa: BLE001
-                        break
-            self.last_save_bytes = nbytes
+        self.last_save_bytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(state)
+            if isinstance(leaf, jax.Array)
+        )
         # the writer thread continues THIS call's span
         trace_ctx = inject_context()
         with _span("ckpt.save.enqueue", step=step):
@@ -619,6 +612,24 @@ class CheckpointEngine:
                 leaves[i] = c
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
+    @staticmethod
+    def _kick_off_fetch(step: int, snap) -> None:
+        """Start every leaf's device->host transfer, so that they run
+        beside the wait for the shard's lock and the copies of the
+        leaves before them.  On the writer thread: issued from the
+        loop's thread they cost a 2.7 GB state 0.2 s of every call
+        (0.02 s here), a time that varied from save to save, and
+        slowed the steps after them (PERF.md, PR 32)."""
+        import jax
+
+        with _span("ckpt.save.d2h_kickoff", step=step):
+            for leaf in jax.tree_util.tree_leaves(snap):
+                if isinstance(leaf, jax.Array):
+                    try:
+                        leaf.copy_to_host_async()
+                    except Exception:  # noqa: BLE001
+                        break
+
     def _ensure_writer(self):
         with self._writer_lock:
             if self._exit_drain is None:
@@ -650,6 +661,7 @@ class CheckpointEngine:
                 with attach_context(trace_ctx), _span(
                     "ckpt.save.write", step=step
                 ):
+                    self._kick_off_fetch(step, snap)
                     with _ASYNC_WRITE_SECONDS.time():
                         ok = self._write_shm(
                             step, snap, path, block_lock=True

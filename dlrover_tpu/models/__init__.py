@@ -3,6 +3,7 @@ strategy engine's dry-runner, and the benchmarks."""
 
 from dlrover_tpu.models.gpt import GPT, GPTConfig
 from dlrover_tpu.models.llama import Llama, LlamaConfig
+from dlrover_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from dlrover_tpu.models.losses import (
     chunked_cross_entropy,
     chunked_loss_fn,
@@ -13,6 +14,8 @@ __all__ = [
     "GPTConfig",
     "Llama",
     "LlamaConfig",
+    "OlmoHybrid",
+    "OlmoHybridConfig",
     "chunked_cross_entropy",
     "chunked_loss_fn",
 ]

@@ -96,9 +96,11 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # losses across incarnations/world sizes from the log alone
         # moe.*: a sparse model's per-step routing counters (the
         # ``aux`` of a ``has_aux`` loss; models/olmoe.py)
+        # gdn.state_rms_max: the largest rms of a linear-attention
+        # layer's final state (models/olmo_hybrid.py)
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
-            "moe.z_loss"]),
+            "moe.z_loss", "gdn.state_rms_max"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so
@@ -397,8 +399,9 @@ SPAN_SCHEMAS: Dict[str, SpanSchema] = {
             "ckpt.save.snapshot", "trainer",
             "on-device copy of the state"),
         SpanSchema(
-            "ckpt.save.d2h_kickoff", "trainer",
-            "starting the async device->host copies"),
+            "ckpt.save.d2h_kickoff", "trainer (writer thread)",
+            "starting the async device->host copies, first thing "
+            "beneath ckpt.save.write"),
         SpanSchema(
             "ckpt.save.enqueue", "trainer",
             "handing the snapshot to the writer thread"),

@@ -293,7 +293,8 @@ def make_train_step(
     the step is traced with that mesh in scope (``scoped_to_mesh``).
 
     With ``has_aux``, ``loss_fn(params, batch) -> (loss, aux)`` and
-    ``aux``'s scalars (a model's own counters: ``moe.load_max_over_mean``)
+    ``aux``'s scalars (a model's own counters: ``moe.load_max_over_mean``,
+    ``gdn.state_rms_max``)
     join ``metrics`` (with ``grad_accum``, their mean over the micro
     batches).  Left at None it is read from ``loss_fn.has_aux``, so a
     loss that carries counters says so itself and a training script
@@ -579,10 +580,11 @@ class ElasticTrainer:
             except (TypeError, ValueError):
                 pass
         for name, value in (metrics or {}).items():
-            # a sparse model's routing counters (``has_aux`` of
-            # make_train_step) ride on this event: one more event
-            # would cost the loop 0.65 ms a step (PERF.md, PR 25)
-            if name.startswith("moe."):
+            # a model's own counters (``has_aux`` of make_train_step:
+            # a sparse model's routing, a linear-attention model's
+            # state) ride on this event: one more event would cost
+            # the loop 0.65 ms a step (PERF.md, PR 25)
+            if name.startswith(("moe.", "gdn.")):
                 step_event[name] = float(value)
         emit_event("train_step", **step_event)
         # chaos hook AFTER the event: a kill rule at step N must leave

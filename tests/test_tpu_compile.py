@@ -331,3 +331,120 @@ def test_olmoe_block_at_published_widths_compiles(one_chip, on_tpu):
         "moe_experts" in stacks[c] for c in calls if "gmm_" in c
     )
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+# -- Olmo-Hybrid: the rule, flash attention at its shapes, the one-period step ----
+
+HYBRID_ATTN = (1, 8192, 30, 128)
+HYBRID_RULE = dict(batch=1, seq=8192, heads=30, dk=96, dv=192)
+
+
+def _rule_operands(one_chip):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, t, h = (HYBRID_RULE[k] for k in ("batch", "seq", "heads"))
+    keys = s((b, t, h, HYBRID_RULE["dk"]), jnp.bfloat16)
+    values = s((b, t, h, HYBRID_RULE["dv"]), jnp.bfloat16)
+    gate = s((b, t, h), jnp.float32)
+    return keys, keys, values, gate, gate
+
+
+def test_flash_attention_compiles_at_olmo_hybrids_shape(one_chip, on_tpu):
+    """30 heads of 128 over 8192 tokens, forward and backward: the
+    full-attention layer of ``olmo_hybrid_steady_8k``."""
+    x = jax.ShapeDtypeStruct(HYBRID_ATTN, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    forward = jax.jit(fa.flash_attention).lower(x, x, x).compile()
+    assert _kernels(forward) >= 1
+    backward = jax.jit(
+        jax.grad(loss, argnums=(0, 1, 2))
+    ).lower(x, x, x).compile()
+    assert _kernels(backward) >= 3
+
+
+def test_gated_delta_rule_compiles_at_published_sizes(one_chip):
+    """The chunk-wise rule at (1, 8192, 30, 96 / 192), forward and
+    backward, for the described chip: no kernel of the repo's own in
+    it (XLA's matmuls and one ``while`` for the hand-over), which
+    emits 8192 / CHUNK states."""
+    from dlrover_tpu.ops import gated_delta_rule as gdr
+
+    operands = _rule_operands(one_chip)
+    forward = jax.jit(gdr.gated_delta_rule).lower(*operands).compile()
+    out, state = forward.out_info
+    assert out.shape == (1, 8192, 30, 192) and out.dtype == jnp.bfloat16
+    assert state.shape == (1, 30, 96, 192) and state.dtype == jnp.float32
+    assert _kernels(forward) == 0
+    text = forward.as_text()
+    assert text.count(" while(") == 1
+    # the states the loop emits, one a chunk
+    assert f"[{8192 // gdr.CHUNK},1,30,96,192]" in text
+
+    def loss(*a):
+        return gdr.gated_delta_rule(*a)[0].astype(jnp.float32).sum()
+
+    backward = jax.jit(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    ).lower(*operands).compile()
+    # one layer's rule and its gradient: 3.8 GB of temporaries (a
+    # 64-wide float32 minor dimension is padded to 128 lanes)
+    assert backward.memory_analysis().temp_size_in_bytes < 4.5 * 2**30
+
+
+def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
+    """The cell's step (``olmo_hybrid_7b_cut``: one period at the
+    published widths, the whole vocabulary, bf16 state, flash
+    attention, per-block remat, 1 x 8192 tokens): state + temporaries
+    under the chip's 15.75 GB, the four flash kernels under the module
+    ``attn`` and none of them under ``gdn``."""
+    import re
+
+    from dlrover_tpu.common.aot_cache import op_names
+    from dlrover_tpu.models.olmo_hybrid import (
+        PERIOD,
+        OlmoHybrid,
+        OlmoHybridConfig,
+        make_olmo_hybrid_loss,
+    )
+
+    model = OlmoHybrid(OlmoHybridConfig(
+        layer_types=PERIOD, attention_impl="flash", remat=True,
+        param_dtype=jnp.bfloat16,
+    ))
+    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
+    abs_state = jax.eval_shape(
+        lambda: TrainState.create(
+            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
+            optimizer,
+        )
+    )
+    tokens = np.zeros((1, 8192), np.int32)
+    compiled = make_train_step(
+        make_olmo_hybrid_loss(model, num_chunks=8), optimizer
+    ).lower(
+        _shapes(abs_state, one_chip),
+        _shapes({"x": tokens, "y": tokens}, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    # 1.603 B parameters x 6 bytes
+    assert round(mem.argument_size_in_bytes / 1e9, 1) == 9.6
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        < 15.75 * 2**30
+    )
+    text = compiled.as_text()
+    calls = re.findall(
+        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', text, re.M,
+    )
+    # forward, its remat copy, dq, dkv: one layer of four
+    assert len(calls) == 4
+    assert all(re.match(r"^%?attn(\.|$)", name) for name in calls)
+    stacks = op_names(text)["op_names"]
+    assert all("/block_3/attn/" in stacks[c] for c in calls)
+    for scope in ("gdn_conv", "gdn_gates", "gdn_rule", "gdn_norm"):
+        assert any(f"/gdn/{scope}/" in s for s in stacks.values()), scope
