@@ -239,7 +239,8 @@ def test_the_whole_model_is_causal():
 
 def test_the_layers_scopes_are_in_the_compiled_step():
     """Each of the layer's four device scopes names operations of the
-    compiled step, forward and backward; the rule's hand-over is a
+    compiled step, forward and backward (the loss head's are all
+    forward: its gradients are formed there); the rule's hand-over is a
     ``while`` under ``gdn_rule`` whose body's operations carry
     ``gdn_rule/../while/body`` (what ``gdn_flops.py`` tells a scan's
     two appearances in a trace by)."""
@@ -248,12 +249,14 @@ def test_the_layers_scopes_are_in_the_compiled_step():
     _, step, state, batch = toy_step()
     compiled = step.lower(state, batch).compile()
     stacks = list(op_names(compiled.as_text())["op_names"].values())
-    for scope in (
-        "gdn_conv", "gdn_gates", "gdn_rule", "gdn_norm", "loss_head",
-    ):
+    for scope in ("gdn_conv", "gdn_gates", "gdn_rule", "gdn_norm"):
         named = [s for s in stacks if scope in s]
         assert named, scope
         assert any("transpose(" in s for s in named), scope
+    head = [s for s in stacks if "loss_head" in s]
+    assert sum(s.endswith("/dot_general") for s in set(head)) == 3
+    assert all("jvp(loss_head)" in s for s in head)
+    assert not any("rematted_computation" in s for s in head)
     rule = [s for s in stacks if "gdn_rule" in s]
     assert any("/while/body/" in s for s in rule)
     # the full-attention mixer is the module ``attn``, the linear one

@@ -304,7 +304,8 @@ def test_no_tokens_by_experts_by_capacity_tensor_in_the_step():
 def test_the_layers_scopes_are_in_the_compiled_step():
     """What the benchmark's readers join on: each of the layer's four
     device scopes names operations of the compiled step, forward
-    (``jvp(..)``) and backward (``transpose(jvp(..))``)."""
+    (``jvp(..)``) and backward (``transpose(jvp(..))``); the loss
+    head's are all forward, its gradients are formed there."""
     from dlrover_tpu.common.aot_cache import op_names
 
     _, step, state, batch = toy_step()
@@ -312,11 +313,14 @@ def test_the_layers_scopes_are_in_the_compiled_step():
     stacks = list(op_names(compiled.as_text())["op_names"].values())
     for scope in (
         "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
-        "loss_head",
     ):
         named = [s for s in stacks if scope in s]
         assert named, scope
         assert any("transpose(" in s for s in named), scope
+    head = [s for s in stacks if "loss_head" in s]
+    assert sum(s.endswith("/dot_general") for s in set(head)) == 3
+    assert all("jvp(loss_head)" in s for s in head)
+    assert not any("rematted_computation" in s for s in head)
 
 
 # -- the step that carries the counters ----------------------------------------

@@ -11,6 +11,7 @@ monkeypatching, and the compile cache is off around them (an entry
 compiled for a described chip cannot be read back without one)."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +84,20 @@ def _shapes(tree, sharding):
 
 def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
+
+
+def _head_matmul_shapes(text):
+    """The result shapes, sorted, of the matmuls (``convolution`` on
+    the TPU) that the compiled program holds under the scope
+    ``loss_head``.  The chunked head's all sit in its scan's body, so
+    each runs once a chunk, and none is jax's recomputation."""
+    found = re.findall(
+        r"^\s*%[\w.\-]+ = (\w+\[[\d,]*\])[^\n]*? convolution\("
+        r'[^\n]*op_name="([^"]*loss_head[^"]*)"', text, re.M,
+    )
+    assert all("/while/body/" in stack for _, stack in found)
+    assert not any("rematted_computation" in stack for _, stack in found)
+    return sorted(shape for shape, _ in found)
 
 
 @pytest.mark.parametrize("shape", ATTN_SHAPES, ids=str)
@@ -399,10 +414,9 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
     """The cell's step (``olmo_hybrid_7b_cut``: one period at the
     published widths, the whole vocabulary, bf16 state, flash
     attention, per-block remat, 1 x 8192 tokens): state + temporaries
-    under the chip's 15.75 GB, the four flash kernels under the module
-    ``attn`` and none of them under ``gdn``."""
-    import re
-
+    under the chip's 15.75 GB, the loss head's three matmuls a chunk,
+    the four flash kernels under the module ``attn`` and none of them
+    under ``gdn``."""
     from dlrover_tpu.common.aot_cache import op_names
     from dlrover_tpu.models.olmo_hybrid import (
         PERIOD,
@@ -436,7 +450,16 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
         mem.argument_size_in_bytes + mem.temp_size_in_bytes
         < 15.75 * 2**30
     )
+    # no more scratch than the checkpointed head of PR 32 asked for
+    # (offline compile of 2da395f, this very program)
+    assert mem.temp_size_in_bytes <= 5_059_906_048
     text = compiled.as_text()
+    # the head: 3 vocabulary-sized matmuls a chunk of 8192 / 8 tokens
+    # (logits, d_hidden, d_kernel: 3 x 8 a step, where the
+    # checkpointed head made 4 x 8), none of them a recomputation
+    assert _head_matmul_shapes(text) == [
+        "bf16[1024,3840]", "f32[1024,100352]", "f32[3840,100352]",
+    ]
     calls = re.findall(
         r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
         r'"tpu_custom_call"', text, re.M,
@@ -448,3 +471,32 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
     assert all("/block_3/attn/" in stacks[c] for c in calls)
     for scope in ("gdn_conv", "gdn_gates", "gdn_rule", "gdn_norm"):
         assert any(f"/gdn/{scope}/" in s for s in stacks.values()), scope
+
+
+def test_chunked_head_compiles_at_olmoes_shapes(one_chip):
+    """The head alone at ``olmoe_steady_4k``'s shapes (2 x 4096 rows of
+    2048 against a 50304-word vocabulary, bf16, 8 chunks): value and
+    both gradients hold three vocabulary-sized matmuls a chunk, all in
+    the scan's body, none recomputed, and one chunk's float32 logits
+    (206 MB) and their gradient are the vocabulary-sized
+    temporaries."""
+    from dlrover_tpu.models.losses import chunked_cross_entropy
+
+    hidden = jax.ShapeDtypeStruct(
+        (2, 4096, 2048), jnp.bfloat16, sharding=one_chip
+    )
+    kernel = jax.ShapeDtypeStruct(
+        (2048, 50304), jnp.bfloat16, sharding=one_chip
+    )
+    targets = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda h, k, t: chunked_cross_entropy(h, k, t, num_chunks=8),
+        argnums=(0, 1),
+    )).lower(hidden, kernel, targets).compile()
+    assert _head_matmul_shapes(compiled.as_text()) == [
+        "bf16[1024,2048]", "f32[1024,50304]", "f32[2048,50304]",
+    ]
+    # one chunk's float32 logits, their bf16 gradient and the
+    # chunk-major copies of hidden and d_hidden: under two chunks of
+    # logits
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 206_045_184
