@@ -56,6 +56,14 @@ from dlrover_tpu.telemetry.tracing import (
 # the agent's in-RAM copy (seconds), short of a hung agent's 600 s
 _WRITER_WAIT_BOUND_S = 60.0
 
+# the writer thread's kick-off pauses after this many leaves, for this
+# long: each ``copy_to_host_async`` keeps the interpreter's lock, and a
+# thread that waits for that lock is handed it only every 5 ms, so an
+# unbroken loop over 446 leaves (14-50 ms) held the loop's thread in
+# the save call until it ended (PERF.md, PR 35)
+_KICKOFF_TURN = 8
+_KICKOFF_PAUSE_S = 1e-4
+
 _REG = get_registry()
 _SHM_SAVE_SECONDS = _REG.histogram(
     "dlrover_checkpoint_shm_save_seconds",
@@ -619,16 +627,25 @@ class CheckpointEngine:
         leaves before them.  On the writer thread: issued from the
         loop's thread they cost a 2.7 GB state 0.2 s of every call
         (0.02 s here), a time that varied from save to save, and
-        slowed the steps after them (PERF.md, PR 32)."""
+        slowed the steps after them (PERF.md, PR 32).  Every
+        ``_KICKOFF_TURN`` leaves it sleeps ``_KICKOFF_PAUSE_S``: a
+        real sleep, because the loop's thread, on its way out of the
+        save call (the span's events are a file append), must wake
+        and take the interpreter's lock before this thread asks for
+        it again."""
         import jax
 
         with _span("ckpt.save.d2h_kickoff", step=step):
+            started = 0
             for leaf in jax.tree_util.tree_leaves(snap):
                 if isinstance(leaf, jax.Array):
                     try:
                         leaf.copy_to_host_async()
                     except Exception:  # noqa: BLE001
                         break
+                    started += 1
+                    if started % _KICKOFF_TURN == 0:
+                        time.sleep(_KICKOFF_PAUSE_S)
 
     def _ensure_writer(self):
         with self._writer_lock:

@@ -135,14 +135,18 @@ def _remat_policy(name: str):
 
 
 def xla_causal_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, dtype=jnp.bfloat16
+    q: jax.Array, k: jax.Array, v: jax.Array, dtype=jnp.bfloat16,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Plain causal attention; XLA fuses softmax chains well on TPU.
 
-    q,k,v: [batch, seq, heads, head_dim] -> same shape out.
+    q,k,v: [batch, seq, heads, head_dim] -> v's shape out (v's head
+    size may differ from q's and k's); ``scale`` defaults to q's
+    ``head_dim ** -0.5``.
     """
     seq = q.shape[1]
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale

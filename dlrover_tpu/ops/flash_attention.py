@@ -8,7 +8,10 @@ tiling so the [seq, seq] score matrix never materializes in HBM, MXU
 matmuls in bf16 with fp32 accumulators.
 
 Layout: q, k, v are [batch, seq, heads, head_dim] (the model's bqhd).
-Internally folded to [batch*heads, seq, head_dim].
+Internally folded to [batch*heads, seq, head_dim].  q and k share one
+head size, ``d_qk``; v (and so the output, dO and dv) may have another,
+``d_v`` (latent attention: 192 | 128).  Scores, dq and dk are ``d_qk``
+wide, the accumulator ``d_v``; nothing is padded to the larger.
 
 The walk.  Forward and dq: the grid is (batch*heads, q tiles, kv-major
 blocks).  A grid step holds one q tile of ``block_q`` rows and the
@@ -54,11 +57,13 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 _LANES = 128
 # What a grid step may keep of the operands its loop walks (K and V in
-# forward and dq, Q and dO in dkv), as the pipeline holds them: two
-# arrays x two buffers x rows x head_dim x itemsize.  4 MB is the
-# whole sequence at 4096 x 128 in bf16 (8 x seq x d bytes), 0.5 MB at
-# 1024 x 64.  Beside it a step holds its own tile and its output
-# (double-buffered, 1 MB at 1024 x 128), dkv's lse and delta rows (an
+# forward and dq, Q and dO in dkv: one of each pair is d_qk wide, the
+# other d_v), as the pipeline holds them: two buffers x rows x (d_qk +
+# d_v) x itemsize.  4 MB is the whole sequence at 4096 x 128 in bf16
+# (8 x seq x d bytes), 0.5 MB at 1024 x 64; at 8192 x (192 | 128) it
+# is a quarter of the sequence.  Beside it a step holds its own tile
+# and its output (double-buffered, 1 MB at 1024 x 128), dkv's lse and
+# delta rows (an
 # eighth more) and a pass's float32 score temporaries (``_PASS_SCORES``
 # below): what the v5e's compiler allows a kernel, as
 # ``tests/test_tpu_compile.py`` checks for both cells' shapes and one
@@ -67,16 +72,24 @@ _RESIDENT_BYTES = 4 * 2**20
 
 
 def resident_rows(
-    seq: int, sub_block: int, head_dim: int, itemsize: int
+    seq: int, sub_block: int, head_dim: int, itemsize: int,
+    v_head_dim: int | None = None,
 ) -> int:
     """Rows of the walked operands one grid step holds: the largest
     divisor of ``seq`` that is a whole number of loop sub-blocks and
-    fits ``_RESIDENT_BYTES``; one sub-block if none does."""
+    fits ``_RESIDENT_BYTES``; one sub-block if none does.  The walked
+    pair is one operand of ``head_dim`` (= ``d_qk``: K, or Q in dkv)
+    and one of ``v_head_dim`` (``d_v``: V, or dO; left out, the
+    same), so the count takes their SUM."""
+    if v_head_dim is None:
+        v_head_dim = head_dim
     for parts in range(1, seq // sub_block + 1):
         rows, rest = divmod(seq, parts)
         if rest or rows % sub_block:
             continue
-        if 4 * rows * head_dim * itemsize <= _RESIDENT_BYTES:
+        if 2 * rows * (head_dim + v_head_dim) * itemsize <= (
+            _RESIDENT_BYTES
+        ):
             return rows
     return sub_block
 
@@ -137,7 +150,8 @@ def block_schedule(
     seq: int, block_q: int, block_k: int, causal: bool = True
 ) -> dict:
     """What one head's walk costs: how many ``[block_q, block_k]``
-    sub-blocks the kernels visit, how many of those take the masked
+    sub-blocks the kernels visit (a count of score tiles: neither
+    ``d_qk`` nor ``d_v`` enters it), how many of those take the masked
     body, the square's total, and ``computed``, the share of the
     square's scores that are computed at all (a masked sub-block that
     is the tile's own is walked as a triangle of chunks).  The three
@@ -298,10 +312,10 @@ def _bracket(major, num_major: int, init, walk, final):
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref,      # [1, block_q, d], [1, major rows, d] x2
-    o_ref,                    # [1, block_q, d]
+    q_ref, k_ref, v_ref,      # [1, block_q, d_qk], [1, major rows, d_qk | d_v]
+    o_ref,                    # [1, block_q, d_v]
     lse_ref,                  # [1, 1, block_q]
-    m_scr, l_scr, acc_scr,    # [block_q, 128] x2, [block_q, d]
+    m_scr, l_scr, acc_scr,    # [block_q, 128] x2, [block_q, d_v]
     *, scale: float, block_q: int, block_k: int, causal: bool,
     num_major: int,
 ):
@@ -309,7 +323,7 @@ def _fwd_kernel(
     major = 0 if num_major == 1 else pl.program_id(2)
     subs = k_ref.shape[1] // block_k
     first = major * subs
-    d = q_ref.shape[2]
+    d = v_ref.shape[2]
     fold = _scale_is_exact(scale)
 
     def init():
@@ -372,7 +386,8 @@ def _fwd(
     group: int = 1,
 ):
     bh, seq, d = q.shape
-    rows = resident_rows(seq, block_k, d, k.dtype.itemsize)
+    d_v = v.shape[2]
+    rows = resident_rows(seq, block_k, d, k.dtype.itemsize, d_v)
     num_major = seq // rows
 
     # GQA: k/v carry bh//group rows; `group` consecutive q heads read
@@ -397,22 +412,22 @@ def _fwd(
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_block),
             pl.BlockSpec((1, rows, d), kv_block),
-            pl.BlockSpec((1, rows, d), kv_block),
+            pl.BlockSpec((1, rows, d_v), kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), q_block),
+            pl.BlockSpec((1, block_q, d_v), q_block),
             # lse carried as [bh, 1, seq]: (1, 1, block_q) blocks satisfy
             # the TPU (8, 128) tiling rule on the last two dims
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
         scratch_shapes=[
             _scratch((block_q, _LANES), jnp.float32),
             _scratch((block_q, _LANES), jnp.float32),
-            _scratch((block_q, d), jnp.float32),
+            _scratch((block_q, d_v), jnp.float32),
         ],
         interpret=_interpret(),
     )(q, k, v)
@@ -569,7 +584,8 @@ def _bwd_dq(
     q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group
 ):
     bh, seq, d = q.shape
-    rows = resident_rows(seq, block_k, d, k.dtype.itemsize)
+    d_v = v.shape[2]
+    rows = resident_rows(seq, block_k, d, k.dtype.itemsize, d_v)
     num_major = seq // rows
 
     def kv_block(b, i, j):
@@ -592,8 +608,8 @@ def _bwd_dq(
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_block),
             pl.BlockSpec((1, rows, d), kv_block),
-            pl.BlockSpec((1, rows, d), kv_block),
-            pl.BlockSpec((1, block_q, d), q_block),
+            pl.BlockSpec((1, rows, d_v), kv_block),
+            pl.BlockSpec((1, block_q, d_v), q_block),
             pl.BlockSpec((1, 1, block_q), stat_block),
             pl.BlockSpec((1, 1, block_q), stat_block),
         ],
@@ -608,7 +624,8 @@ def _bwd_dkv(
     q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group
 ):
     bh, seq, d = q.shape
-    rows = resident_rows(seq, block_q, d, q.dtype.itemsize)
+    d_v = v.shape[2]
+    rows = resident_rows(seq, block_q, d, q.dtype.itemsize, d_v)
     num_major = seq // rows
 
     # causal: a q-major block above the kv tile maps to the first one
@@ -640,22 +657,22 @@ def _bwd_dkv(
         in_specs=[
             pl.BlockSpec((1, rows, d), q_block),
             pl.BlockSpec((1, block_k, d), kv_block),
-            pl.BlockSpec((1, block_k, d), kv_block),
-            pl.BlockSpec((1, rows, d), q_block),
+            pl.BlockSpec((1, block_k, d_v), kv_block),
+            pl.BlockSpec((1, rows, d_v), q_block),
             pl.BlockSpec((1, 1, rows), stat_block),
             pl.BlockSpec((1, 1, rows), stat_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), out_block),
-            pl.BlockSpec((1, block_k, d), out_block),
+            pl.BlockSpec((1, block_k, d_v), out_block),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d_v), v.dtype),
         ],
         scratch_shapes=[
             _scratch((block_k, d), jnp.float32),
-            _scratch((block_k, d), jnp.float32),
+            _scratch((block_k, d_v), jnp.float32),
         ],
         interpret=_interpret(),
     )(q, k, v, dout, lse, delta)
@@ -665,7 +682,7 @@ def _bwd_dkv(
         dk = dk.reshape(bh // group, group, seq, d).astype(
             jnp.float32
         ).sum(axis=1).astype(k.dtype)
-        dv = dv.reshape(bh // group, group, seq, d).astype(
+        dv = dv.reshape(bh // group, group, seq, d_v).astype(
             jnp.float32
         ).sum(axis=1).astype(v.dtype)
     return dk, dv
@@ -720,7 +737,8 @@ def default_blocks(seq: int, itemsize: int):
     (statistics, accumulators, the pipeline's fill) is paid once a
     tile: at 1024 x 64 and at 4096 x 128 alike the three kernels
     together ran 10% slower at 512 and 50-85% slower at 256 (PERF.md,
-    PR 29), so neither the head dim nor ``causal`` moves the choice."""
+    PR 29), so neither head size (``d_qk``, ``d_v``) nor ``causal``
+    moves the choice."""
     block = min(seq, 2048 // itemsize)
     return block, block
 
@@ -753,6 +771,12 @@ def flash_attention(
     Sequence length must be divisible by the block sizes (the caller
     pads; GPT training shapes are powers of two).
 
+    ``q`` and ``k`` share their head size ``d_qk``; ``v`` may have
+    another, ``d_v`` (latent attention: 192 | 128).  The output and
+    ``dv`` are ``d_v`` wide, ``dq`` and ``dk`` ``d_qk``; the default
+    ``scale`` is ``d_qk ** -0.5``.  With ``d_v == d_qk`` every shape
+    and block choice is what a call with one head size always had.
+
     ``block_q`` is the q rows a grid step of the forward and of dq
     holds, ``block_k`` the kv rows one iteration of their loop takes
     (dkv holds ``block_k`` kv rows and walks ``block_q`` q rows at a
@@ -771,6 +795,10 @@ def flash_attention(
     """
     b, s, h, d = q.shape
     kvh = k.shape[2]
+    if k.shape[3] != d:
+        raise ValueError(
+            f"q has head size {d} but k has {k.shape[3]}"
+        )
     if v.shape[2] != kvh:
         raise ValueError(
             f"k has {kvh} heads but v has {v.shape[2]}"
@@ -794,14 +822,14 @@ def flash_attention(
         )
 
     def fold(x):
-        hh = x.shape[2]
-        return x.transpose(0, 2, 1, 3).reshape(b * hh, s, d)
+        hh, width = x.shape[2:]
+        return x.transpose(0, 2, 1, 3).reshape(b * hh, s, width)
 
     out = _flash_mha(
         fold(q), fold(k), fold(v), scale, causal, block_q, block_k,
         group,
     )
-    out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
     if dtype is not None:
         out = out.astype(dtype)
     return out

@@ -18,11 +18,14 @@ Beside it, for a chip that holds every expert of its layers:
 :func:`dropless_moe` / :class:`DroplessMoE` (OLMoE's recipe).  The
 token-to-expert assignments are sorted by expert and the experts run
 as grouped matmuls over the sorted rows: no capacity, no dropped
-token, no ``[t, e, c]`` tensor.  Across chips a dropless layer needs a
-ragged all-to-all (ROADMAP R3); the ``expert``-mesh path stays with
-:class:`MoEMLP`.
+token, no ``[t, e, c]`` tensor.  A chip that holds only a range of a
+layer's experts tells the layer so (``held``): it routes over all of
+them and computes its own.  Across chips a dropless layer needs a
+ragged all-to-all (ROADMAP R3), which is not here; the
+``expert``-mesh path stays with :class:`MoEMLP`.
 """
 
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -218,8 +221,17 @@ def collect_moe_aux_loss(intermediates) -> jax.Array:
 # -- dropless routing over grouped matmuls ------------------------------------
 
 
-@jax.custom_vjp
-def _dispatch_rows(tokens, source, slot):
+def _rows_at(rows, slot, some_absent: bool):
+    """``rows[slot]``; with ``some_absent`` a slot past the last row
+    (an assignment to an expert this chip does not hold) reads
+    zeros."""
+    if some_absent:
+        return rows.at[slot].get(mode="fill", fill_value=0)
+    return rows[slot]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(tokens, source, slot, some_absent=False):
     """The rows of ``tokens [t, d]`` in the experts' tile-aligned
     order, ``[padded rows, d]``: ``source[p]`` is the flat assignment
     (token * k + choice) that lives at padded row ``p``, or ``t * k``
@@ -231,21 +243,23 @@ def _dispatch_rows(tokens, source, slot):
     return jnp.concatenate([tokens, zero_row])[source // slot.shape[1]]
 
 
-def _dispatch_fwd(tokens, source, slot):
-    return _dispatch_rows(tokens, source, slot), (source, slot)
+def _dispatch_fwd(tokens, source, slot, some_absent):
+    return _dispatch_rows(tokens, source, slot, some_absent), slot
 
 
-def _dispatch_bwd(res, g):
-    _, slot = res
+def _dispatch_bwd(some_absent, slot, g):
     with jax.named_scope("moe_dispatch"):
-        return g[slot].sum(axis=1).astype(g.dtype), None, None
+        return (
+            _rows_at(g, slot, some_absent).sum(axis=1).astype(g.dtype),
+            None, None,
+        )
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _collect_rows(rows, source, slot):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _collect_rows(rows, source, slot, some_absent=False):
     """``rows[slot]``: the experts' outputs back in token order, ``[t,
     k, d]``; the gradient is the gather through ``source`` (the
     scatter-add of the combine, read from the other side: every
@@ -254,15 +268,14 @@ def _collect_rows(rows, source, slot):
     masking pass over the rows): it meets only that row's own
     activations, which are zero because its input was, so no weight's
     gradient sees it."""
-    return rows[slot]
+    return _rows_at(rows, slot, some_absent)
 
 
-def _collect_fwd(rows, source, slot):
-    return rows[slot], (source, slot)
+def _collect_fwd(rows, source, slot, some_absent):
+    return _rows_at(rows, slot, some_absent), source
 
 
-def _collect_bwd(res, g):
-    source, _ = res
+def _collect_bwd(some_absent, source, g):
     with jax.named_scope("moe_combine"):
         flat = g.reshape((-1, g.shape[-1]))
         return flat.at[source].get(mode="clip"), None, None
@@ -274,30 +287,62 @@ _collect_rows.defvjp(_collect_fwd, _collect_bwd)
 def dropless_moe(
     tokens: jax.Array,         # [t, d]
     router_kernel: jax.Array,  # [d, e]
-    w_gate: jax.Array,         # [e, d, m]
-    w_up: jax.Array,           # [e, d, m]
-    w_down: jax.Array,         # [e, m, d]
+    w_gate: jax.Array,         # [held experts, d, m]
+    w_up: jax.Array,           # [held experts, d, m]
+    w_down: jax.Array,         # [held experts, m, d]
     top_k: int,
     dtype: Any = jnp.bfloat16,
+    *,
+    held: Optional[Tuple[int, int]] = None,
+    score: str = "softmax",
+    select_bias: Optional[jax.Array] = None,  # [e], no gradient
+    renormalise: bool = False,
+    scale: float = 1.0,
 ):
     """Top-k routing without capacity: ``(out [t, d], stats)``.
 
-    Router logits and softmax in float32; the top-k probabilities
-    weight the experts' outputs as they are, NOT renormalised
-    (``norm_topk_prob: false``).  The ``t * k`` assignments are
-    stable-sorted by expert, the rows gathered in that order with each
-    expert's rows starting on a row tile of the grouped-matmul kernel
-    (``ops/grouped_matmul.py``), and each expert computes
-    ``down(silu(gate(x)) * up(x))`` on its own rows as three grouped
-    matmuls.  Every shape is static (``t * k`` rows and one tile of
-    padding an expert, whatever the routing); an expert without a
-    token is one tile of zero rows.  ``stats`` carries what the
+    The defaults are OLMoE's router: logits and softmax in float32,
+    the top-k probabilities weight the experts' outputs as they are,
+    NOT renormalised (``norm_topk_prob: false``), and the chip holds
+    all ``e`` experts.  ``score="sigmoid"`` scores each expert on its
+    own; with ``select_bias`` the k experts are chosen by ``score +
+    bias`` and weighted by the score alone (the bias only steers the
+    load and takes no gradient); ``renormalise`` divides the k weights
+    by their sum, ``scale`` multiplies them.
+
+    **Held experts.**  ``held=(lo, count)`` says that this chip holds
+    experts ``[lo, lo + count)`` of the layer's ``e`` (the weights are
+    ``[count, ...]``).  The router keeps its ``e`` outputs and its
+    top-k over all of them; only assignments to a held expert get a
+    row and are computed; what the other experts would have added is
+    LEFT OUT of ``out``: nothing here stands in for the absent chips
+    or their exchange.  The weights (and a renormalisation) are over
+    all k choices, held or not, so the shares of all the chips that
+    hold a layer add up to the whole layer.
+
+    The ``t * k`` assignments are stable-sorted by expert (those to
+    experts held elsewhere behind the rest), the rows gathered in that
+    order with each expert's rows starting on a row tile of the
+    grouped-matmul kernel (``ops/grouped_matmul.py``), and each expert
+    computes ``down(silu(gate(x)) * up(x))`` on its own rows as three
+    grouped matmuls.  Every shape is static (``t * k`` rows and one
+    tile of padding an expert held, whatever the routing: a batch may
+    send every assignment here); an expert without a token is one tile
+    of zero rows, and the kernels skip the tiles past the last used
+    one; the sort, the gathers and the combine run at the static size
+    whatever share of it has a row.  ``stats`` carries what the
     auxiliary losses and the counters need: ``counts [e]``
-    (assignments per expert, no gradient), ``prob_sum [e]`` (sum over
-    tokens of the router probabilities), ``z_loss`` (mean over tokens
-    of ``logsumexp(logits) ** 2``)."""
+    (assignments per expert over ALL experts, no gradient),
+    ``held_rows`` (assignments that reached a held expert),
+    ``prob_sum [e]`` (sum over tokens of the router's scores),
+    ``z_loss`` (mean over tokens of ``logsumexp(logits) ** 2``)."""
     t, _ = tokens.shape
     e = router_kernel.shape[-1]
+    lo, count = (0, e) if held is None else held
+    if w_gate.shape[0] != count:
+        raise ValueError(
+            f"{w_gate.shape[0]} experts' weights for {count} held"
+        )
     assignments = t * top_k
     with jax.named_scope("moe_router"):
         logits = jnp.dot(
@@ -305,36 +350,74 @@ def dropless_moe(
             router_kernel.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate, expert_ids = jax.lax.top_k(probs, top_k)  # [t, k]
+        if score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        elif score == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown router score {score!r}")
+        if select_bias is None:
+            gate, expert_ids = jax.lax.top_k(probs, top_k)  # [t, k]
+        else:
+            _, expert_ids = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(select_bias), top_k
+            )
+            gate = jnp.take_along_axis(probs, expert_ids, axis=-1)
+        if renormalise:
+            gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
+        if scale != 1.0:
+            gate = gate * scale
         flat_ids = expert_ids.reshape(-1)
-        group_sizes = jnp.bincount(flat_ids, length=e).astype(jnp.int32)
+        counts = jnp.bincount(flat_ids, length=e).astype(jnp.int32)
+        group_sizes = counts if held is None else counts[lo:lo + count]
         stats = {
-            "counts": group_sizes.astype(jnp.float32),
+            "counts": counts.astype(jnp.float32),
+            "held_rows": group_sizes.sum().astype(jnp.float32),
             "prob_sum": probs.sum(axis=0),
             "z_loss": jnp.mean(
                 jax.nn.logsumexp(logits, axis=-1) ** 2
             ),
         }
+    some_absent = held is not None
     with jax.named_scope("moe_dispatch"):
         tile_group, tiles_used, padded_starts = gmm.group_layout(
             group_sizes, assignments
         )
-        order = jnp.argsort(flat_ids, stable=True).astype(jnp.int32)
-        sorted_ids = flat_ids[order]
+        padded_rows = tile_group.shape[0] * gmm.ROW_TILE
+        if held is None:
+            order = jnp.argsort(flat_ids, stable=True).astype(jnp.int32)
+            sorted_ids = flat_ids[order]
+        else:
+            # an expert held elsewhere sorts as group ``count``
+            local = flat_ids - lo
+            local = jnp.where((local >= 0) & (local < count), local, count)
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)
+            here = local[order] < count
+            sorted_ids = jnp.minimum(local[order], count - 1)
         starts = jnp.cumsum(group_sizes) - group_sizes
         # the padded row of the assignment at sorted position i
         row = (
             padded_starts[sorted_ids] - starts[sorted_ids]
             + jnp.arange(assignments, dtype=jnp.int32)
         )
+        if held is not None:
+            # no row: a slot past the last one, each its own
+            row = jnp.where(
+                here, row,
+                padded_rows + jnp.arange(assignments, dtype=jnp.int32),
+            )
         slot = jnp.zeros_like(order).at[order].set(
             row, unique_indices=True
         ).reshape(t, top_k)
         source = jnp.full(
-            (tile_group.shape[0] * gmm.ROW_TILE,), assignments, jnp.int32
-        ).at[row].set(order, unique_indices=True)
-        rows = _dispatch_rows(tokens.astype(dtype), source, slot)
+            (padded_rows,), assignments, jnp.int32
+        ).at[row].set(
+            order, unique_indices=True,
+            mode=None if held is None else "drop",
+        )
+        rows = _dispatch_rows(
+            tokens.astype(dtype), source, slot, some_absent
+        )
     with jax.named_scope("moe_experts"):
         def expert(x, w):
             return gmm.grouped_matmul(
@@ -346,7 +429,8 @@ def dropless_moe(
         )
     with jax.named_scope("moe_combine"):
         out = jnp.einsum(
-            "tkd,tk->td", _collect_rows(rows, source, slot), gate,
+            "tkd,tk->td",
+            _collect_rows(rows, source, slot, some_absent), gate,
             preferred_element_type=jnp.float32,
         )
     return out.astype(dtype), stats
@@ -356,7 +440,14 @@ class DroplessMoE(nn.Module):
     """:func:`dropless_moe` as a layer: ``x [b, s, d] -> (out, stats)``.
     Parameter names as :class:`MoEMLP`'s gated experts (``router``,
     ``experts_w_gate`` / ``experts_w_in`` / ``experts_w_out``, leading
-    expert dim)."""
+    expert dim).  The defaults are OLMoE's layer.  With ``held`` the
+    router keeps ``num_experts`` outputs and the expert weights have
+    ``held[1]`` leading entries; ``select_bias`` adds the parameter of
+    that name (``[num_experts]`` float32 zeros: no gradient reaches
+    it, the train step moves it by the loss's ``state_updates``);
+    ``shared_dim`` adds a SwiGLU of that width that every token takes
+    (``shared_gate`` / ``shared_up`` / ``shared_down``), under the
+    device scope ``moe_shared``."""
 
     num_experts: int
     mlp_dim: int
@@ -364,27 +455,56 @@ class DroplessMoE(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     kernel_init: Any = nn.initializers.normal(0.02)
+    held: Optional[Tuple[int, int]] = None
+    score: str = "softmax"
+    select_bias: bool = False
+    renormalise: bool = False
+    scale: float = 1.0
+    shared_dim: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array):
         b, s, d = x.shape
         e, m = self.num_experts, self.mlp_dim
+        count = e if self.held is None else self.held[1]
         router = self.param(
             "router", self.kernel_init, (d, e), self.param_dtype
         )
         w_gate = self.param(
-            "experts_w_gate", self.kernel_init, (e, d, m),
+            "experts_w_gate", self.kernel_init, (count, d, m),
             self.param_dtype,
         )
         w_up = self.param(
-            "experts_w_in", self.kernel_init, (e, d, m), self.param_dtype
-        )
-        w_down = self.param(
-            "experts_w_out", self.kernel_init, (e, m, d),
+            "experts_w_in", self.kernel_init, (count, d, m),
             self.param_dtype,
         )
+        w_down = self.param(
+            "experts_w_out", self.kernel_init, (count, m, d),
+            self.param_dtype,
+        )
+        bias = None
+        if self.select_bias:
+            bias = self.param(
+                "select_bias", nn.initializers.zeros, (e,), jnp.float32
+            )
         out, stats = dropless_moe(
             x.reshape(b * s, d), router, w_gate, w_up, w_down,
-            self.top_k, self.dtype,
+            self.top_k, self.dtype, held=self.held, score=self.score,
+            select_bias=bias, renormalise=self.renormalise,
+            scale=self.scale,
         )
-        return out.reshape(b, s, d), stats
+        out = out.reshape(b, s, d)
+        if self.shared_dim:
+            def dense(features, name):
+                return nn.Dense(
+                    features, use_bias=False, dtype=self.dtype,
+                    param_dtype=self.param_dtype,
+                    kernel_init=self.kernel_init, name=name,
+                )
+
+            with jax.named_scope("moe_shared"):
+                hidden = nn.silu(
+                    dense(self.shared_dim, "shared_gate")(x)
+                ) * dense(self.shared_dim, "shared_up")(x)
+                out = out + dense(d, "shared_down")(hidden)
+        return out, stats

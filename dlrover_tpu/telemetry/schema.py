@@ -95,12 +95,16 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # elastic-resize loss-trajectory invariant compares same-step
         # losses across incarnations/world sizes from the log alone
         # moe.*: a sparse model's per-step routing counters (the
-        # ``aux`` of a ``has_aux`` loss; models/olmoe.py)
+        # ``aux`` of a ``has_aux`` loss; models/olmoe.py); for a layer
+        # that holds a range of its experts (models/sarvam_mla.py):
+        # the share of the step's assignments that reached a held
+        # expert and the router bias's size
         # gdn.state_rms_max: the largest rms of a linear-attention
         # layer's final state (models/olmo_hybrid.py)
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
-            "moe.z_loss", "gdn.state_rms_max"]),
+            "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
+            "moe.bias_abs_max"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

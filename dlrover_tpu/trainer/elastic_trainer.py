@@ -275,6 +275,26 @@ def restore_train_state(optimizer, restored) -> TrainState:
     return restore_to_template(template, restored)
 
 
+# the key of a loss's ``aux`` under which it hands the step deltas
+# for leaves of the parameters that it moves itself (a loss writes the
+# literal: the models' side imports nothing from the trainer's)
+STATE_UPDATES = "state_updates"
+
+
+def _add_at(new, old, deltas):
+    """``new`` with ``old + delta`` at every leaf that ``deltas`` (a
+    nested dict over part of the parameters' tree) names."""
+    if not isinstance(deltas, dict):
+        return (old + deltas).astype(old.dtype)
+    return {
+        **new,
+        **{
+            key: _add_at(new[key], old[key], sub)
+            for key, sub in deltas.items()
+        },
+    }
+
+
 def make_train_step(
     loss_fn: Callable,
     optimizer,
@@ -299,6 +319,15 @@ def make_train_step(
     batches).  Left at None it is read from ``loss_fn.has_aux``, so a
     loss that carries counters says so itself and a training script
     written for scalar losses runs it unchanged.
+
+    ``aux`` may also carry ``"state_updates"`` (``STATE_UPDATES``): a
+    pytree of deltas, laid out as the part of ``params`` it names
+    (``{"block_1": {"moe": {"select_bias": delta}}}``), for leaves
+    that no gradient reaches and that the loss moves by a rule of its
+    own (a router's load bias).  The step sets each such leaf to ``old + delta`` after the
+    optimizer, whatever the optimizer made of it: no Adam, no weight
+    decay on it.  It is not a metric.  (With ``grad_accum`` the
+    deltas, like the counters, are the micro batches' mean.)
     """
     if has_aux is None:
         has_aux = bool(getattr(loss_fn, "has_aux", False))
@@ -359,6 +388,11 @@ def make_train_step(
             )
             new_params = optax.apply_updates(state.params, updates)
             grad_norm = optax.global_norm(grads)
+            if STATE_UPDATES in aux:
+                aux = dict(aux)
+                new_params = _add_at(
+                    new_params, state.params, aux.pop(STATE_UPDATES)
+                )
         new_state = TrainState(
             params=new_params, opt_state=new_opt, step=state.step + 1
         )

@@ -647,6 +647,25 @@ def test_sparse_export_is_durable_only_for_a_persisted_save(
         engine.close()
 
 
+def test_kick_off_lets_the_loop_thread_in_every_few_leaves(monkeypatch):
+    """The writer thread's kick-off sleeps after every
+    ``_KICKOFF_TURN`` transfers it starts (host leaves start none):
+    unbroken, it kept the interpreter's lock, and the loop's thread
+    inside the save call, until its last leaf."""
+    from dlrover_tpu.checkpoint import engine as engine_mod
+
+    pauses = []
+    monkeypatch.setattr(engine_mod.time, "sleep", pauses.append)
+    leaves = 2 * engine_mod._KICKOFF_TURN + 3
+    snap = {
+        "device": [jnp.full((4,), i) for i in range(leaves)],
+        "host": [np.zeros(4)] * engine_mod._KICKOFF_TURN,
+    }
+    CheckpointEngine._kick_off_fetch(7, snap)
+    assert pauses == [engine_mod._KICKOFF_PAUSE_S] * 2
+    assert engine_mod._KICKOFF_PAUSE_S > 0  # a real sleep, not a yield
+
+
 def test_fastcopy_gil_release_and_correctness():
     """The native copy matches numpy and keeps other threads running
     during a large transfer (the GIL-starvation fix)."""

@@ -404,3 +404,123 @@ def test_flash_gqa_rejects_nondivisible_heads():
     v8 = jnp.zeros((2, 128, 8, 64))
     with pytest.raises(ValueError, match="heads"):
         flash_attention(q8, k2, v8)
+
+
+# -- two head sizes: q and k of d_qk, v of d_v (latent attention) -------------
+
+
+def _rand_two_sizes(s, h, d_qk, d_v, kv_heads=None, seed=3):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    kvh = kv_heads or h
+    q = jax.random.normal(ks[0], (1, s, h, d_qk)) * 0.3
+    k = jax.random.normal(ks[1], (1, s, kvh, d_qk)) * 0.3
+    v = jax.random.normal(ks[2], (1, s, kvh, d_v)) * 0.3
+    do = jax.random.normal(ks[3], (1, s, h, d_v))
+    return q, k, v, do
+
+
+def _forward_and_grads(fn, q, k, v, do):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out, *vjp(do.astype(out.dtype)))
+
+
+TWO_SIZES = {
+    "24|16": (dict(s=256, h=2, d_qk=24, d_v=16), (64, 64), None),
+    # latent attention's own: one and a half lane tiles beside one
+    "192|128": (dict(s=256, h=2, d_qk=192, d_v=128), (128, 128), None),
+    "192|128-default-blocks": (
+        dict(s=256, h=2, d_qk=192, d_v=128), (None, None), None,
+    ),
+    # v the wider one, a group of 2, and major blocks on the grid
+    "16|48-group-2-major-blocks": (
+        dict(s=512, h=4, d_qk=16, d_v=48, kv_heads=2), (128, 64),
+        2 * 128 * (16 + 48) * 4,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(TWO_SIZES))
+def test_two_head_sizes_match_reference(case, monkeypatch):
+    """``v.shape[-1] != q.shape[-1]``: the output and dv have v's
+    size, dq and dk q's; forward and all three gradients against XLA
+    attention, the default scale being ``d_qk ** -0.5``."""
+    shape, (block_q, block_k), budget = TWO_SIZES[case]
+    if budget is not None:
+        monkeypatch.setattr(fa, "_RESIDENT_BYTES", budget)
+    q, k, v, do = _rand_two_sizes(**shape)
+    got = _forward_and_grads(
+        lambda *a: flash_attention(
+            *a, block_q=block_q, block_k=block_k
+        ), q, k, v, do,
+    )
+    want = _forward_and_grads(_reference, q, k, v, do)
+    assert got[0].shape == do.shape
+    assert [g.shape for g in got[1:]] == [q.shape, k.shape, v.shape]
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=3e-5, rtol=3e-4
+        )
+
+
+def test_two_head_sizes_are_the_one_size_path_on_a_padded_v():
+    """What ties the new path to the one every accepted cell runs: v
+    of 16 beside q and k of 24 gives, BIT FOR BIT, what the one-size
+    call gives on v padded with zero lanes to 24 (the same scores and
+    probabilities; a zero lane adds exact zeros to dP)."""
+    q, k, v, do = _rand_two_sizes(s=256, h=2, d_qk=24, d_v=16)
+    pad = ((0, 0), (0, 0), (0, 0), (0, 8))
+    narrow = _forward_and_grads(
+        lambda *a: flash_attention(*a, block_q=64, block_k=64),
+        q, k, v, do,
+    )
+    wide = _forward_and_grads(
+        lambda *a: flash_attention(*a, block_q=64, block_k=64),
+        q, k, jnp.pad(v, pad), jnp.pad(do, pad),
+    )
+    for n, w in zip(narrow, wide):
+        w = w[..., :n.shape[-1]]
+        assert np.array_equal(np.asarray(n), np.asarray(w))
+
+
+def test_one_head_size_lowers_as_it_always_did():
+    """With ``d_v == d_qk`` nothing of a call depends on the second
+    size: ``resident_rows`` counts ``2 x (d + d)`` where it counted
+    ``4 x d``, and the lowered call is the same text whether v's size
+    is read from v or, as before, taken to be q's."""
+    for seq, sub, d, itemsize in [
+        (1024, 1024, 64, 2), (4096, 1024, 128, 2), (8192, 1024, 128, 2),
+        (4096, 512, 128, 4),
+    ]:
+        assert fa.resident_rows(seq, sub, d, itemsize) == (
+            fa.resident_rows(seq, sub, d, itemsize, d)
+        )
+    # 8192 x (192 | 128) in bf16: a quarter of the sequence resident
+    assert fa.resident_rows(8192, 1024, 192, 2, 128) == 2048
+    assert fa.resident_rows(8192, 1024, 128, 2) == 4096
+
+
+@pytest.mark.parametrize("cell, seq, d, blocks, rows", [
+    ("xl48_steady", 1024, 64, (1024, 1024), 1024),
+    ("xl12_flash_save", 1024, 64, (1024, 1024), 1024),
+    ("olmoe_steady_4k", 4096, 128, (1024, 1024), 4096),
+    ("olmo_hybrid_steady_8k", 8192, 128, (1024, 1024), 4096),
+])
+def test_the_accepted_cells_keep_their_blocks_and_resident_rows(
+    cell, seq, d, blocks, rows
+):
+    """At each accepted cell's sequence and head size (bf16) the block
+    choice and the resident rows are the numbers the rule gave before
+    it knew of a second head size (``4 x rows x d x itemsize`` within 4
+    MiB, worked by hand): their kernels' static shapes did not move."""
+    assert fa.default_blocks(seq, 2) == blocks
+    assert fa.resident_rows(seq, blocks[1], d, 2) == rows
+    assert fa.resident_rows(seq, blocks[1], d, 2, d) == rows
+    assert 4 * rows * d * 2 <= 4 * 2**20 < 4 * (2 * rows) * d * 2 or (
+        rows == seq
+    )
+
+
+def test_k_must_have_qs_head_size():
+    q, k, v, _ = _rand_two_sizes(s=64, h=2, d_qk=24, d_v=16)
+    with pytest.raises(ValueError, match="head size"):
+        flash_attention(q, v, v)

@@ -143,3 +143,38 @@ def test_sizes_that_do_not_divide_into_tiles_are_refused():
         gmm.grouped_matmul(
             a.pad(a.x)[:-1], a.w, a.layout[0], a.layout[1], TILES
         )
+
+
+@pytest.mark.parametrize("k, n", [(2048, 1024), (1024, 2048)])
+def test_olmoes_widths_trace_to_the_kernels_they_had(k, n):
+    """``K_TILE`` went from 2048 to 4096 for a contraction of 4096 (PR
+    35).  At ``olmoe_steady_4k``'s real shapes (65536 rows + a tile an
+    expert, 64 experts, hidden 2048 x width 1024 and back) no
+    contraction passes 2048, so the three kernels of each matmul
+    (forward, the gradient to the rows, the gradient to the weights)
+    trace to the same grids, blocks and bodies under the old tiles and
+    the new; a tile that does cut a contraction traces to others."""
+    groups, assignments = 64, 2 * 4096 * 8
+    tiles = assignments // gmm.ROW_TILE + groups
+    operands = (
+        jax.ShapeDtypeStruct((tiles * gmm.ROW_TILE, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16),
+        jax.ShapeDtypeStruct((tiles,), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32),
+    )
+
+    def traced(tile_sizes):
+        def loss(r, w, tg, nu):
+            return gmm.grouped_matmul(
+                r, w, tg, nu, tiles=tile_sizes
+            ).astype(jnp.float32).sum()
+
+        return str(jax.make_jaxpr(
+            jax.value_and_grad(loss, argnums=(0, 1))
+        )(*operands))
+
+    assert (gmm.ROW_TILE, gmm.K_TILE, gmm.N_TILE) == (256, 4096, 2048)
+    before = traced((256, 2048, 2048))
+    assert before.count("pallas_call") == 3
+    assert traced((gmm.ROW_TILE, gmm.K_TILE, gmm.N_TILE)) == before
+    assert traced((256, 512, 2048)) != before

@@ -473,6 +473,96 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
         assert any(f"/gdn/{scope}/" in s for s in stacks.values()), scope
 
 
+def test_flash_attention_compiles_at_two_head_sizes(one_chip, on_tpu):
+    """Latent attention's shape in the cell: 16 heads x 8192 tokens, q
+    and k of 192 (one and a half lane tiles), v of 128, bf16, a scale
+    that is no power of two: forward, dq and dkv compile for the v5e,
+    a quarter of the sequence resident a grid step."""
+    q = jax.ShapeDtypeStruct(
+        (1, 8192, 16, 192), jnp.bfloat16, sharding=one_chip
+    )
+    v = jax.ShapeDtypeStruct(
+        (1, 8192, 16, 128), jnp.bfloat16, sharding=one_chip
+    )
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, scale=0.1352
+        ).astype(jnp.float32).sum()
+
+    compiled = jax.jit(
+        jax.grad(loss, argnums=(0, 1, 2))
+    ).lower(q, q, v).compile()
+    assert _kernels(compiled) == 3
+    assert fa.resident_rows(8192, 1024, 192, 2, 128) == 2048
+
+
+def test_sarvam_one_dense_four_expert_step_fits_the_chip(one_chip, on_tpu):
+    """The cell's step (``sarvam_105b_cut``: the leading dense block
+    and four expert blocks at the published widths, 16 heads and 8 of
+    128 experts held, an eighth of the vocabulary, bf16 state, flash
+    attention at 192 | 128, per-block remat, 1 x 8192 tokens): state +
+    temporaries under the chip's 15.75 GB, the flash kernels under the
+    module ``attn``, the grouped matmuls under ``moe_experts``, and
+    every scope the benchmark's readers join on in the op-name map."""
+    from dlrover_tpu.common.aot_cache import op_names
+    from dlrover_tpu.models.sarvam_mla import (
+        SarvamMla,
+        SarvamMlaConfig,
+        make_sarvam_mla_loss,
+    )
+
+    model = SarvamMla(SarvamMlaConfig(
+        vocab_size=32768, num_layers=5, num_heads_held=16,
+        experts_held=(0, 8), attention_impl="flash", remat=True,
+        param_dtype=jnp.bfloat16,
+    ))
+    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
+    abs_state = jax.eval_shape(
+        lambda: TrainState.create(
+            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
+            optimizer,
+        )
+    )
+    tokens = np.zeros((1, 8192), np.int32)
+    compiled = make_train_step(
+        make_sarvam_mla_loss(model, num_chunks=8), optimizer
+    ).lower(
+        _shapes(abs_state, one_chip),
+        _shapes({"x": tokens, "y": tokens}, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    # 1.505 B parameters x 6 bytes
+    assert round(mem.argument_size_in_bytes / 1e9, 2) == 9.03
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        < 15.75 * 2**30
+    )
+    # the block's remat holds (left to the compiler's CSE the step
+    # asked for 7.8 GB and did not fit: offline compile, PR 35)
+    assert mem.temp_size_in_bytes < 5 * 2**30
+    text = compiled.as_text()
+    calls = re.findall(
+        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', text, re.M,
+    )
+    flash = [c for c in calls if re.match(r"^%?attn(\.|$)", c)]
+    # forward, its remat copy, dq, dkv in each of five blocks
+    assert len(flash) == 4 * 5
+    stacks = op_names(text)["op_names"]
+    grouped = [c for c in calls if c not in flash]
+    # gate, up, down x (forward, remat copy, dlhs, drhs) x 4 layers
+    assert len(grouped) == 3 * 4 * 4
+    assert all("/moe_experts/" in stacks[c] for c in grouped)
+    assert not any("/block_0/moe" in s for s in stacks.values())
+    for scope in (
+        "mla_q", "mla_kv_down", "mla_kv_up", "mla_rope", "mla_out",
+        "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+        "moe_shared",
+    ):
+        assert any(f"/{scope}/" in s for s in stacks.values()), scope
+
+
 def test_chunked_head_compiles_at_olmoes_shapes(one_chip):
     """The head alone at ``olmoe_steady_4k``'s shapes (2 x 4096 rows of
     2048 against a 50304-word vocabulary, bf16, 8 chunks): value and
