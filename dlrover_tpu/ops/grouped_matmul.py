@@ -11,6 +11,13 @@ scalar-prefetched ``tile_group`` and picks the weight block in the
 ``BlockSpec``'s index map.  Consecutive tiles of one group keep the
 same weight block, which Pallas then does not fetch again.
 
+The arrays have a static size (the worst routing), so they end in
+tiles of no group, from ``tiles_used`` on.  Such a tile costs an empty
+grid step and nothing else: every index map names the last used
+tile's blocks for it, so the pipeline fetches nothing and writes
+nothing, and the bodies do not run.  Its rows of the result are NOT
+WRITTEN (:func:`grouped_matmul`).
+
 Why not ``jax.lax.ragged_dot``: the v5e compiler lowers it to a
 Mosaic kernel of its own whose 512-row tiles straddle group
 boundaries, and a straddling tile is computed once per group it
@@ -94,6 +101,14 @@ def _nbytes(shape, dtype):
     return math.prod(shape) * jnp.dtype(dtype).itemsize
 
 
+def _held_tile(i, tiles_used):
+    # the row tile whose blocks grid step ``i`` names: its own, or for
+    # a tile of no group the last used one's (every group takes a
+    # tile, so there is one).  A block index that does not change
+    # between grid steps is neither fetched nor written back
+    return jnp.minimum(i, tiles_used[0] - 1)
+
+
 # -- rows x weights (forward, and the gradient to the rows) -------------------
 
 
@@ -106,10 +121,12 @@ def _gmm_kernel(
     k_steps: int, transpose_rhs: bool,
 ):
     row, step = pl.program_id(1), pl.program_id(2)
-    active = row < tiles_used[0]
     contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
 
-    @pl.when(active)
+    # a tile of no group holds the last used tile's blocks (_gmm):
+    # ``out_ref`` is that tile's result, not yet written back, and
+    # must not be touched
+    @pl.when(row < tiles_used[0])
     def _compute():
         part = jax.lax.dot_general(
             lhs_ref[...], rhs_ref[0], contract,
@@ -132,12 +149,6 @@ def _gmm_kernel(
         def _store():
             out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
-    # a tile of no group: zeros, so that nothing downstream reads
-    # memory nobody wrote
-    @pl.when(jnp.logical_not(active) & (step == k_steps - 1))
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
 
 def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
     row_tile, k_tile, n_tile = tiles
@@ -150,13 +161,21 @@ def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
             f"divide into tiles {(row_tile, tk, tn)}"
         )
     k_steps = k // tk
+
+    def held_step(i, s, nu):
+        # where the contraction is split, a tile of no group also
+        # stays on the last used tile's last step, rows and weights
+        return jnp.where(i < nu[0], s, k_steps - 1)
+
     if transpose_rhs:
         rhs_spec = pl.BlockSpec(
-            (1, tn, tk), lambda j, i, s, tg, nu: (tg[i], j, s)
+            (1, tn, tk),
+            lambda j, i, s, tg, nu: (tg[i], j, held_step(i, s, nu)),
         )
     else:
         rhs_spec = pl.BlockSpec(
-            (1, tk, tn), lambda j, i, s, tg, nu: (tg[i], s, j)
+            (1, tk, tn),
+            lambda j, i, s, tg, nu: (tg[i], held_step(i, s, nu), j),
         )
     return pl.pallas_call(
         functools.partial(
@@ -169,20 +188,33 @@ def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
             grid=(n // tn, m // row_tile, k_steps),
             in_specs=[
                 pl.BlockSpec(
-                    (row_tile, tk), lambda j, i, s, tg, nu: (i, s)
+                    (row_tile, tk),
+                    lambda j, i, s, tg, nu: (
+                        _held_tile(i, nu), held_step(i, s, nu)
+                    ),
                 ),
                 rhs_spec,
             ],
+            # held over the tiles of no group, the last used tile's
+            # result stays in VMEM and is written back once, when the
+            # sweep over the rows ends
             out_specs=pl.BlockSpec(
-                (row_tile, tn), lambda j, i, s, tg, nu: (i, j)
+                (row_tile, tn),
+                lambda j, i, s, tg, nu: (_held_tile(i, nu), j),
             ),
             scratch_shapes=[
                 pltpu.VMEM((row_tile, tn), jnp.float32)
             ] if k_steps > 1 else [],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
+        # the rows are NOT "parallel": several grid steps share one
+        # output block, which is right only on one walk in order.  A
+        # chip that splits a parallel dimension over two cores would
+        # hand one of them only tiles of no group, and it would write
+        # a buffer it never filled over the last used tile (v5e has
+        # one core a chip: the order costs nothing there)
         compiler_params=_params(
-            ("parallel", "parallel", "arbitrary"),
+            ("parallel", "arbitrary", "arbitrary"),
             _nbytes((row_tile, tk), rows.dtype),
             _nbytes((tk, tn), weights.dtype),
             _nbytes((row_tile, tn), rows.dtype),
@@ -251,10 +283,12 @@ def _tgmm(rows, cotangent, tile_group, tiles_used, *, groups, tiles):
             grid=(k // tk, n // tn, m // row_tile),
             in_specs=[
                 pl.BlockSpec(
-                    (row_tile, tk), lambda a, j, i, tg, nu: (i, a)
+                    (row_tile, tk),
+                    lambda a, j, i, tg, nu: (_held_tile(i, nu), a),
                 ),
                 pl.BlockSpec(
-                    (row_tile, tn), lambda a, j, i, tg, nu: (i, j)
+                    (row_tile, tn),
+                    lambda a, j, i, tg, nu: (_held_tile(i, nu), j),
                 ),
             ],
             # every group has a tile, so every block is written; the
@@ -320,6 +354,10 @@ def grouped_matmul(
 ) -> jax.Array:
     """``rows[i] @ weights[tile_group[i // row_tile]]`` -> ``[rows,
     n]``, differentiable in ``rows`` and ``weights``.  Rows of a
-    group's padding are zero in and zero out; rows of the tiles
-    beyond ``tiles_used`` come out zero."""
+    group's padding are zero in and zero out.  The rows of the tiles
+    from ``tiles_used`` on are NOT READ and NOT WRITTEN, here and in
+    both gradients: the result holds whatever the memory held there,
+    and the caller reads no such row (``parallel/moe.py`` gathers
+    through indices that name only rows of a group;
+    ``tests/test_sarvam_mla.py`` overwrites the others with NaN)."""
     return _grouped_matmul(rows, weights, tile_group, tiles_used, tiles)

@@ -329,8 +329,14 @@ def dropless_moe(
     tile of padding an expert held, whatever the routing: a batch may
     send every assignment here); an expert without a token is one tile
     of zero rows, and the kernels skip the tiles past the last used
-    one; the sort, the gathers and the combine run at the static size
-    whatever share of it has a row.  ``stats`` carries what the
+    one: no product, no fetch, no store, so those rows of each
+    matmul's result are NOT WRITTEN, forward or backward.  Nothing
+    here reads them: the activation works row by row, the gathers back
+    go through ``slot``, which names only rows of an expert, and the
+    next matmul skips the same tiles (a reduction over the padded rows
+    would: ``tests/test_sarvam_mla.py`` fills them with NaN).  The
+    sort, the gathers and the combine run at the static size whatever
+    share of it has a row.  ``stats`` carries what the
     auxiliary losses and the counters need: ``counts [e]``
     (assignments per expert over ALL experts, no gradient),
     ``held_rows`` (assignments that reached a held expert),

@@ -1,7 +1,10 @@
 """The grouped-matmul kernels (``ops/grouped_matmul.py``, interpreter
 mode here) against a per-group loop and against ``jax.lax.ragged_dot``:
 ragged, empty and single-group sizes, forward and both gradients, the
-tile-aligned layout itself."""
+tile-aligned layout itself, and what a tile of no group costs: no
+block moves for it, and nothing it holds reaches a result."""
+
+import itertools
 
 import pytest
 
@@ -22,7 +25,12 @@ SIZES = {
     "single_group": [64],
     "one_group_takes_all": [0, 0, 64, 0],
     "whole_tiles": [32, 64, 32, 32],
+    "most_tiles_empty": [40, 0, 24],
 }
+# the arrays' static row count where it is not the sum of the sizes: a
+# layer that holds a range of the experts sizes its rows for every
+# assignment and gets a share of them
+STATIC_ROWS = {"most_tiles_empty": 256}
 
 
 def by_loop(rows, weights, sizes):
@@ -37,11 +45,12 @@ def by_loop(rows, weights, sizes):
 class Aligned:
     """Sorted rows laid out as the kernels take them, and back."""
 
-    def __init__(self, sizes, seed=0, dtype=jnp.float32):
-        self.sizes = sizes
+    def __init__(self, case, seed=0, dtype=jnp.float32):
+        self.sizes = sizes = SIZES[case]
         self.rows = sum(sizes)
         self.layout = gmm.group_layout(
-            jnp.asarray(sizes, jnp.int32), self.rows, TILES[0]
+            jnp.asarray(sizes, jnp.int32),
+            STATIC_ROWS.get(case, self.rows), TILES[0],
         )
         self.padded = self.layout[0].shape[0] * TILES[0]
         starts = np.asarray(self.layout[2])
@@ -70,14 +79,15 @@ class Aligned:
 def test_layout_gives_every_group_whole_tiles_of_its_own(case):
     sizes = SIZES[case]
     tile = TILES[0]
+    rows = STATIC_ROWS.get(case, sum(sizes))
     tile_group, used, starts = (
         np.asarray(a) for a in gmm.group_layout(
-            jnp.asarray(sizes, jnp.int32), sum(sizes), tile
+            jnp.asarray(sizes, jnp.int32), rows, tile
         )
     )
     per_group = [max(1, -(-size // tile)) for size in sizes]
     assert used[0] == sum(per_group)
-    assert len(tile_group) == -(-sum(sizes) // tile) + len(sizes)
+    assert len(tile_group) == -(-rows // tile) + len(sizes)
     assert list(tile_group[:used[0]]) == [
         g for g, n in enumerate(per_group) for _ in range(n)
     ]
@@ -90,7 +100,7 @@ def test_layout_gives_every_group_whole_tiles_of_its_own(case):
 
 @pytest.mark.parametrize("case", sorted(SIZES))
 def test_forward_equals_the_per_group_loop(case):
-    a = Aligned(SIZES[case])
+    a = Aligned(case)
     padded, got = a.product(a.x, a.w)
     want = by_loop(a.x, a.w, a.sizes)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
@@ -98,15 +108,151 @@ def test_forward_equals_the_per_group_loop(case):
         got, jax.lax.ragged_dot(a.x, a.w, jnp.asarray(a.sizes, jnp.int32)),
         rtol=1e-5, atol=1e-4,
     )
-    # padding rows and the tiles of no group come out zero
-    rest = np.ones(a.padded, bool)
-    rest[a.index] = False
-    assert not np.asarray(padded)[rest].any()
+
+
+@pytest.mark.parametrize("case", sorted(SIZES))
+def test_padding_rows_inside_a_used_tile_come_out_zero(case):
+    """Zero in, zero out for a group's own padding (``parallel/moe.py
+    ::_collect_bwd`` rests on it).  The tiles past ``tiles_used`` are
+    another matter: their rows are not written."""
+    a = Aligned(case)
+    padded, _ = a.product(a.x, a.w)
+    padding = np.ones(a.padded, bool)
+    padding[a.index] = False
+    padding[int(a.layout[1][0]) * TILES[0]:] = False
+    assert padding.any() == any(
+        size == 0 or size % TILES[0] for size in a.sizes
+    )
+    assert not np.asarray(padded)[padding].any()
+
+
+@pytest.mark.parametrize("case", sorted(SIZES))
+def test_nothing_past_tiles_used_reaches_a_result(case):
+    """The contract from the reading side: with the rows of the
+    operand and of the cotangent past ``tiles_used`` set to NaN, the
+    product on the used tiles, the gradient to their rows and the
+    gradient to the weights (an empty group's zeros among them) are
+    finite and bit-equal to what zeros there give."""
+    a = Aligned(case, seed=2)
+    used = int(a.layout[1][0]) * TILES[0]
+    assert used < a.padded
+
+    def results(fill):
+        def past(sorted_rows):
+            return a.pad(sorted_rows).at[used:].set(fill)
+
+        out, vjp = jax.vjp(
+            lambda x, w: gmm.grouped_matmul(
+                x, w, a.layout[0], a.layout[1], TILES
+            ),
+            past(a.x), a.w,
+        )
+        d_rows, d_weights = vjp(past(a.cot))
+        return [np.asarray(r) for r in (out[:used], d_rows[:used], d_weights)]
+
+    got, want = results(jnp.nan), results(0.0)
+    for g, w in zip(got, want):
+        assert np.isfinite(g).all()
+        np.testing.assert_array_equal(g, w)
+    for group, size in enumerate(a.sizes):
+        if size == 0:
+            assert not got[2][group].any()
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+# the two cells' real tile counts (``sarvam_steady_8k``: 65536
+# assignments + 8 tiles, about 20 with a row; ``olmoe_steady_4k``:
+# 65536 + 64 tiles, about 288), and a contraction split in two
+REAL_TILES = (gmm.ROW_TILE, gmm.K_TILE, gmm.N_TILE)
+INDEX_CASES = {
+    "sarvam_264_tiles_20_used": dict(
+        sizes=[600, 520, 0, 700, 512, 300, 900, 500], rows=65536,
+        k=4096, n=2048, tiles=REAL_TILES, want=(264, 20),
+    ),
+    "olmoe_320_tiles_288_used": dict(
+        sizes=[1040, 1008] * 32, rows=65536, k=2048, n=1024,
+        tiles=REAL_TILES, want=(320, 288),
+    ),
+    "split_contraction": dict(
+        sizes=[40, 0, 24], rows=256, k=K, n=N, tiles=TILES,
+        want=(11, 4),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INDEX_CASES))
+def test_a_tile_of_no_group_moves_no_block(case):
+    """The index rule itself, read from the traced kernels: walking
+    each grid in its order, a used tile names its own rows (operand,
+    cotangent, output) and its group's weights; on a tile past
+    ``tiles_used`` NO block's index differs from the grid step before,
+    so the pipeline neither fetches nor writes back for it."""
+    c = INDEX_CASES[case]
+    tile_sizes = c["tiles"]
+    layout = gmm.group_layout(
+        jnp.asarray(c["sizes"], jnp.int32), c["rows"], tile_sizes[0]
+    )
+    tile_group, used = np.asarray(layout[0]), int(layout[1][0])
+    tiles = len(tile_group)
+    assert (tiles, used) == c["want"]
+    dtype = jnp.bfloat16
+
+    def loss(r, w):
+        return gmm.grouped_matmul(
+            r, w, layout[0], layout[1], tile_sizes
+        ).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+        jax.ShapeDtypeStruct((tiles * tile_sizes[0], c["k"]), dtype),
+        jax.ShapeDtypeStruct((len(c["sizes"]), c["k"], c["n"]), dtype),
+    )
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert [e.params["name"] for e in calls] == [
+        "gmm_fwd", "gmm_dlhs", "gmm_drhs"
+    ]
+    for call in calls:
+        mapping = call.params["grid_mapping"]
+        row_axis = 2 if call.params["name"] == "gmm_drhs" else 1
+        assert mapping.grid[row_axis] == tiles
+        steps = np.array(
+            list(itertools.product(*map(range, mapping.grid))), np.int32
+        )
+        row = steps[:, row_axis]
+        assert len(mapping.block_mappings) == 3
+        for block in mapping.block_mappings:
+            index_map = block.index_map_jaxpr
+
+            def at(step, index_map=index_map):
+                return jnp.stack(jax.core.eval_jaxpr(
+                    index_map.jaxpr, index_map.consts, *step,
+                    jax.new_ref(layout[0]), jax.new_ref(layout[1]),
+                ))
+
+            index = np.asarray(
+                jax.jit(lambda s, at=at: jax.lax.map(at, s))(steps)
+            )
+            own = row if index.shape[1] == 2 else tile_group[row]
+            np.testing.assert_array_equal(
+                index[row < used, 0], own[row < used]
+            )
+            empty = np.flatnonzero(row >= used)
+            assert len(empty) and empty[0] > 0
+            np.testing.assert_array_equal(index[empty], index[empty - 1])
 
 
 @pytest.mark.parametrize("case", sorted(SIZES))
 def test_both_gradients_equal_the_per_group_loop(case):
-    a = Aligned(SIZES[case], seed=1)
+    a = Aligned(case, seed=1)
 
     def through(fn):
         return jax.grad(
@@ -127,7 +273,7 @@ def test_bf16_operands_accumulate_in_float32():
     """bf16 in, bf16 out, but the sum over k (two tiles of it here) is
     kept in float32: against the float32 product of the same (already
     rounded) operands the result is within one bf16 rounding."""
-    a = Aligned(SIZES["ragged"], dtype=jnp.bfloat16)
+    a = Aligned("ragged", dtype=jnp.bfloat16)
     _, got = a.product(a.x, a.w)
     assert got.dtype == jnp.bfloat16
     want = by_loop(
@@ -138,7 +284,7 @@ def test_bf16_operands_accumulate_in_float32():
 
 
 def test_sizes_that_do_not_divide_into_tiles_are_refused():
-    a = Aligned(SIZES["single_group"])
+    a = Aligned("single_group")
     with pytest.raises(ValueError, match="do not divide"):
         gmm.grouped_matmul(
             a.pad(a.x)[:-1], a.w, a.layout[0], a.layout[1], TILES

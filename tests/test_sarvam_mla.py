@@ -7,6 +7,7 @@ bias, which no gradient reaches and the train step moves by the
 loss's ``state_updates``; the yarn frequencies against hand-worked
 numbers."""
 
+import functools
 import math
 import os
 import subprocess
@@ -415,6 +416,99 @@ def test_a_share_that_no_token_reaches_and_one_that_all_reach():
     np.testing.assert_allclose(out, want, atol=1e-5)
     with pytest.raises(ValueError, match="held"):
         dropless_moe(*operands, 4, held=(0, 4))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _fill_past(x, tiles_used, fill):
+    """``x`` with ``fill`` in the rows of the tiles from
+    ``tiles_used`` on, and the same done to its cotangent."""
+    past = jnp.arange(x.shape[0]) >= tiles_used[0] * gmm.ROW_TILE
+    return jnp.where(past[:, None], jnp.asarray(fill, x.dtype), x)
+
+
+_fill_past.defvjp(
+    lambda x, tiles_used, fill: (_fill_past(x, tiles_used, fill), tiles_used),
+    lambda fill, tiles_used, g: (_fill_past(g, tiles_used, fill), None),
+)
+
+UNWRITTEN = {
+    # OLMoE's tiny case: softmax, not renormalised, every expert held
+    "every_expert_held": dict(
+        operands=dict(t=128, d=64, m=32, e=8, seed=4), held=None,
+        top_k=2, tiles=(8, 9),
+    ),
+    # 4 of 64 experts held: a sixteenth of 2048 assignments has a row
+    "most_tiles_empty": dict(
+        operands=dict(t=512, e=64, seed=2), held=(8, 4), top_k=4,
+        tiles=(4, 12),
+    ),
+    # ... and the bias keeps every token from held expert 9
+    "an_empty_expert": dict(
+        operands=dict(t=512, e=64, seed=3), held=(8, 4), top_k=4,
+        avoid=9, tiles=(4, 12),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNWRITTEN))
+def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
+    """A grouped matmul does not write the rows of the tiles past
+    ``tiles_used``, forward or backward.  That is safe because the
+    layer reads none of them: with every such row of every grouped
+    matmul's result AND of its gradient to the rows overwritten with
+    NaN, the output and all five gradients are finite and bit-equal
+    to the run with zeros there (the kernels' contract until PR 36)
+    and to the run as it is.  A reduction over the padded rows, or a
+    gather that names one, fails here."""
+    c = UNWRITTEN[case]
+    operands = layer_operands(**c["operands"])
+    bias = None
+    if "avoid" in c:
+        bias = jnp.zeros(operands[1].shape[1:]).at[c["avoid"]].set(-9.0)
+    real = gmm.grouped_matmul
+    seen = []
+
+    def layer(*ops):
+        if c["held"] is None:
+            return dropless_moe(*ops, c["top_k"], jnp.float32)
+        return share(ops, c["held"], c["top_k"], bias=bias)
+
+    def results(fill):
+        def product(rows, weights, tile_group, tiles_used, *tiles):
+            seen.append((tiles_used, tile_group.shape[0]))
+            if fill is None:
+                return real(rows, weights, tile_group, tiles_used, *tiles)
+            # the cotangent's fill first (d_rows), the result's last
+            return _fill_past(
+                real(
+                    _fill_past(rows, tiles_used, fill), weights,
+                    tile_group, tiles_used, *tiles,
+                ),
+                tiles_used, fill,
+            )
+
+        def scored(*ops):
+            out, stats = layer(*ops)
+            return jnp.sum(out * cot), (out, stats)
+
+        monkeypatch.setattr(moe.gmm, "grouped_matmul", product)
+        cot = jax.random.normal(jax.random.PRNGKey(7), operands[0].shape)
+        (_, (out, stats)), grads = jax.value_and_grad(
+            scored, argnums=range(5), has_aux=True
+        )(*operands)
+        return [np.asarray(a) for a in (out, *grads)], stats
+
+    as_it_is, stats = results(None)
+    used, tiles = c["tiles"]
+    assert {(int(u[0]), n) for u, n in seen} == {(used, tiles)}
+    if "avoid" in c:
+        assert float(stats["counts"][c["avoid"]]) == 0
+    with_nan, _ = results(jnp.nan)
+    with_zeros, _ = results(0.0)
+    for got, zeros, plain in zip(with_nan, with_zeros, as_it_is):
+        assert np.isfinite(got).all() and got.any()
+        np.testing.assert_array_equal(got, zeros)
+        np.testing.assert_array_equal(got, plain)
 
 
 def dropless_moe_at_pr_33(
