@@ -173,18 +173,24 @@ class MasterRendezvousHandler:
         self._client = client or MasterClient.singleton()
         self._timeout = timeout
 
-    def next_rendezvous(self) -> RendezvousOutcome:
+    def next_rendezvous(
+        self, restart_count: int = 0
+    ) -> RendezvousOutcome:
         # the span context rides the join RPC frame, so the master's
         # handler-side ``rdzv.join`` span records this span as parent
-        # — the cross-process link tests assert from the event log
+        # — the cross-process link tests assert from the event log.
+        # ``polls`` / ``slept_s``: how much of the span was this
+        # loop's own sleep (a late joiner costs whole intervals)
         with trace.span(
-            "rdzv.join", rdzv=self._name, node_rank=self._node_rank
+            "rdzv.join", rdzv=self._name, node_rank=self._node_rank,
+            restart_count=restart_count, polls=0, slept_s=0.0,
         ) as join_span:
             rdzv_round = self._client.join_rendezvous(
                 self._node_rank, self._local_world_size, self._name
             )
             start = time.time()
             while True:
+                join_span.attributes["polls"] += 1
                 round_, _group, world, coordinator = (
                     self._client.get_comm_world(
                         self._name, self._node_rank
@@ -216,6 +222,9 @@ class MasterRendezvousHandler:
                         f"timed out after {self._timeout}s"
                     )
                 time.sleep(RendezvousConstant.JOIN_INTERVAL)
+                join_span.attributes["slept_s"] += (
+                    RendezvousConstant.JOIN_INTERVAL
+                )
 
 
 class ElasticTrainingAgent:
@@ -378,6 +387,10 @@ class ElasticTrainingAgent:
             env["DLROVER_RECOVERY_T0"] = f"{self._recovery_t0:.6f}"
         else:
             env.pop("DLROVER_RECOVERY_T0", None)
+        # the launch's trace goes on in the worker: its
+        # ``trainer.*`` spans are children of the span this runs in
+        # (``agent.spawn_workers``)
+        trace.export_context(env)
         # tag the worker's training events even when the entrypoint
         # never touches telemetry itself
         env.setdefault(EVENT_SOURCE_ENV, "trainer")
@@ -420,7 +433,9 @@ class ElasticTrainingAgent:
             self._spec.entrypoint, env=env, process_group=0
         )
 
-    def _start_workers(self, outcome: RendezvousOutcome):
+    def _start_workers(self, outcome: RendezvousOutcome) -> bool:
+        """Spawn this node's workers; True where they were forked
+        from the warm template."""
         self._procs = []
         forked_argv = (
             self._forked_argv() if self._forkserver is not None
@@ -476,6 +491,7 @@ class ElasticTrainingAgent:
             " (warm fork)" if forked_argv is not None else "",
             self._spec.entrypoint,
         )
+        return forked_argv is not None
 
     def _stop_workers(self, timeout: float = 30.0):
         for p in self._procs:
@@ -594,7 +610,7 @@ class ElasticTrainingAgent:
                 client=self._client,
                 timeout=NetworkCheckConstant.CHECK_TIMEOUT,
             )
-            outcome = handler.next_rendezvous()
+            outcome = handler.next_rendezvous(self._restart_count)
             normal, elapsed = True, 0.0
             try:
                 # in a child that exits before any worker is
@@ -673,8 +689,16 @@ class ElasticTrainingAgent:
     def _initialize_workers(self):
         if self._spec.network_check:
             self.node_health_check()
-        outcome = self._rdzv.next_rendezvous()
-        self._start_workers(outcome)
+        outcome = self._rdzv.next_rendezvous(self._restart_count)
+        # a launch and a respawn alike: the same span names, told
+        # apart by ``restart_count``
+        with trace.span(
+            "agent.spawn_workers", node_rank=self._node_rank,
+            restart_count=self._restart_count,
+        ) as spawn:
+            warm = self._start_workers(outcome)
+            spawn.set_attribute("workers", len(self._procs))
+            spawn.set_attribute("warm_fork", warm)
 
     def _join_save_thread(self, timeout: float = 600.0):
         """Wait for the previous round's overlapped breakpoint save —
@@ -906,7 +930,13 @@ def launch_agent(
     save_ckpt_hook: Optional[Callable[[], None]] = None,
 ) -> int:
     """Build and run the agent (reference: launch_agent, training.py:734)."""
-    agent = ElasticTrainingAgent(
-        spec, client=client, save_ckpt_hook=save_ckpt_hook
-    )
+    # the constructor's own cost: the monitors and, under
+    # ``--warm_restart``, the forkserver template's start
+    with trace.span(
+        "agent.init", restart_count=0, warm_restart=spec.warm_restart,
+    ) as init:
+        agent = ElasticTrainingAgent(
+            spec, client=client, save_ckpt_hook=save_ckpt_hook
+        )
+        init.set_attribute("node_rank", agent._node_rank)
     return agent.run()

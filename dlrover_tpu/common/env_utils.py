@@ -4,6 +4,7 @@ field parser the process-supervision paths rely on."""
 
 import os
 import sys
+import time
 from typing import Dict, List, Optional
 
 from dlrover_tpu.common.constants import NodeEnv
@@ -37,6 +38,28 @@ def proc_stat_fields(pid: int) -> Optional[List[bytes]]:
         return data.rsplit(b")", 1)[1].split()
     except (OSError, IndexError):
         return None
+
+
+def proc_start_before(now: float) -> float:
+    """Wall-clock time this process started, as the beginning of a
+    span that ends at ``now``: kernel start ticks
+    (``/proc/self/stat`` field 22) against the boot epoch from
+    ``/proc/uptime`` — survives exec, unlike any userland timestamp;
+    a process's interpreter start and imports lie between this and
+    its first line of code.  Never after ``now`` (/proc counts in
+    10 ms ticks), and ``now`` itself where /proc cannot say."""
+    fields = proc_stat_fields(os.getpid())
+    if fields is None:
+        return now
+    try:
+        ticks = int(fields[19])
+        hz = float(os.sysconf("SC_CLK_TCK"))
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+    except (IndexError, ValueError, OSError):
+        return now
+    boot_epoch = time.time() - uptime
+    return min(boot_epoch + ticks / hz, now)
 
 
 def live_pids(

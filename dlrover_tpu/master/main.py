@@ -8,7 +8,9 @@ import argparse
 import os
 import signal
 import sys
+import time
 
+from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.constants import DefaultPorts
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.master.journal import (
@@ -16,6 +18,7 @@ from dlrover_tpu.master.journal import (
     JOURNAL_MIRROR_DIR_ENV,
 )
 from dlrover_tpu.master.master import JobMaster
+from dlrover_tpu.telemetry import tracing as trace
 
 
 def parse_args(argv=None):
@@ -146,6 +149,17 @@ def run(args) -> int:
     except ValueError:  # pragma: no cover - non-main thread (tests)
         pass
     master.prepare()
+    # this process's start -> it serves (``master_start`` is out):
+    # interpreter, imports, construction, the journal's replay (a
+    # span of its own inside this one).  A child of the launcher's
+    # ``tpurun.master_boot`` where tpurun spawned this master
+    serving = time.time()
+    with trace.attach_context(trace.inherited_context()):
+        trace.record_span(
+            "master.boot", env_utils.proc_start_before(serving),
+            serving, restart_count=env_utils.get_restart_count(),
+            node_rank=0,
+        )
     return master.run()
 
 

@@ -28,11 +28,13 @@ from typing import List, Optional, Tuple
 
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.agent.training import WorkerSpec, launch_agent
+from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.comm import addr_connected, find_free_port
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.master.journal import JOURNAL_DIR_ENV
-from dlrover_tpu.telemetry.events import emit_event
+from dlrover_tpu.telemetry import tracing as trace
+from dlrover_tpu.telemetry.events import emit_event, set_event_source
 
 # how many times tpurun respawns a locally-spawned master that died
 # (each respawn replays the state journal and resumes the job)
@@ -115,30 +117,42 @@ def _launch_local_master(
     (from ``--nnodes MIN:MAX``) arms the master's elastic resize
     coordinator."""
     port = port or find_free_port()
-    env = dict(os.environ)
-    if journal_dir:
-        env[JOURNAL_DIR_ENV] = journal_dir
-    env[NodeEnv.RESTART_COUNT] = str(restart_count)
-    argv = [
-        sys.executable, "-m", "dlrover_tpu.master.main",
-        "--port", str(port),
-        "--node_num", str(max_nodes),
-    ]
-    if min_nodes:
-        argv += ["--min_nodes", str(min_nodes)]
-    if node_unit > 1:
-        argv += ["--node_unit", str(node_unit)]
-    proc = subprocess.Popen(argv, env=env)  # noqa: S603
     addr = f"127.0.0.1:{port}"
-    deadline = time.time() + 30
-    while time.time() < deadline:
-        if addr_connected(addr):
-            return proc, addr
-        if proc.poll() is not None:
-            raise RuntimeError("local master exited during startup")
-        time.sleep(0.3)
-    proc.kill()
-    raise RuntimeError("local master did not become reachable")
+    # the whole wait for the master, on the launch's clock: its
+    # process start, imports and journal replay are the master's own
+    # ``master.boot`` inside this span (the trace parent rides the
+    # environment), the rest is this poll's slack
+    with trace.span(
+        "tpurun.master_boot", port=port, restart_count=restart_count,
+        node_rank=0, polls=0, slept_s=0.0,
+    ) as boot:
+        env = trace.export_context(dict(os.environ))
+        if journal_dir:
+            env[JOURNAL_DIR_ENV] = journal_dir
+        env[NodeEnv.RESTART_COUNT] = str(restart_count)
+        argv = [
+            sys.executable, "-m", "dlrover_tpu.master.main",
+            "--port", str(port),
+            "--node_num", str(max_nodes),
+        ]
+        if min_nodes:
+            argv += ["--min_nodes", str(min_nodes)]
+        if node_unit > 1:
+            argv += ["--node_unit", str(node_unit)]
+        proc = subprocess.Popen(argv, env=env)  # noqa: S603
+        deadline = time.time() + 30
+        while time.time() < deadline:
+            boot.attributes["polls"] += 1
+            if addr_connected(addr):
+                return proc, addr
+            if proc.poll() is not None:
+                raise RuntimeError("local master exited during startup")
+            time.sleep(0.3)
+            boot.attributes["slept_s"] = round(
+                boot.attributes["slept_s"] + 0.3, 3
+            )
+        proc.kill()
+        raise RuntimeError("local master did not become reachable")
 
 
 class _MasterSupervisor:
@@ -266,13 +280,38 @@ def apply_auto_config(args):
 
 
 def run(args) -> int:
+    """One node's launch.  A launch is restart 0 of the recovery
+    chain and is traced as one: ``tpurun.boot`` (this process's
+    kernel start -> here: interpreter + imports) opens the trace,
+    and every later stretch of the launch in this process
+    (``tpurun.master_boot``, ``agent.init``, ``rdzv.join``,
+    ``agent.spawn_workers``), in the master (``master.boot``) and in
+    the workers (``trainer.*``) is its descendant: one trace id,
+    one clock.  The agent's respawns run under it too, with their
+    ``restart_count``."""
+    entered = time.time()
+    # this process becomes the agent: its launch spans are the
+    # agent's from the first one
+    set_event_source("agent")
     args = apply_auto_config(args)
-    min_nodes, max_nodes = parse_nnodes(args.nnodes)
     node_rank = (
         args.node_rank
         if args.node_rank is not None
         else int(os.getenv(NodeEnv.NODE_RANK, "0"))
     )
+    boot = trace.record_span(
+        "tpurun.boot", env_utils.proc_start_before(entered), entered,
+        restart_count=0, node_rank=node_rank,
+    )
+    with trace.attach_context({
+        trace.TRACE_ID_KEY: boot.trace_id,
+        trace.SPAN_ID_KEY: boot.span_id,
+    }):
+        return _supervise(args, node_rank)
+
+
+def _supervise(args, node_rank: int) -> int:
+    min_nodes, max_nodes = parse_nnodes(args.nnodes)
     master_addr = args.master_addr or os.getenv(NodeEnv.MASTER_ADDR, "")
     supervisor: Optional[_MasterSupervisor] = None
     journal_dir_created = ""

@@ -17,7 +17,7 @@ categories:
   ``recovery_phase`` restore, ``ckpt.restore`` spans);
 - ``rendezvous`` — rendezvous rounds + node checks;
 - ``drain_resize`` — elastic-resize decide + drain windows;
-- ``respawn_gap`` — spawn/import phases PLUS whatever remains of a
+- ``respawn_gap`` — spawn/import/backend phases PLUS whatever remains of a
   death-witnessed recovery head (death witness → first step) that no
   finer-grained witness claimed;
 - ``checkpoint_stall`` — save/persist/export windows not overlapped
@@ -318,6 +318,10 @@ def _scan(events: List[Dict]):
                 "model_build": COMPILE, "state_build": COMPILE,
                 "first_step": COMPILE,
                 "spawn": RESPAWN, "import": RESPAWN,
+                # the distributed initialize and the backend's
+                # opening (taking the chip): the process is not yet
+                # a worker, and no XLA work has begun
+                "backend": RESPAWN,
                 "loop_setup": RESPAWN,
             }.get(phase)
             if cat is not None:
@@ -483,7 +487,7 @@ def build_ledger(events: Iterable[Dict]) -> GoodputLedger:
                 claimed = _intersect(iv, remaining)
                 rec.intervals[cat] = claimed
                 remaining = _subtract(remaining, claimed)
-            # respawn: the measured spawn/import phases, plus — for a
+            # respawn: the measured spawn/import/backend phases, plus — for a
             # death-witnessed birth — whatever remains of the
             # recovery head (death witness -> first step) that no
             # finer-grained witness claimed

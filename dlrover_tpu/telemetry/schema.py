@@ -226,9 +226,13 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         _s("shm_prefetch", ["bytes", "seconds"],
            ["segments", "restart_count"]),
         # measured death->first-step budget, one event per phase
-        # (spawn / import / restore / aot / retrace / first_step) —
-        # the trainer-side RecoveryProfiler emits them and the
-        # timeline derives the recovery breakdown slices.  `aot` is
+        # (spawn / import / backend / restore / aot / retrace /
+        # first_step) — the trainer-side RecoveryProfiler emits them
+        # and the timeline derives the recovery breakdown slices; a
+        # phase lies at [ts - seconds, ts] (spawn and import carry
+        # the ts at which they ENDED, before there was a profiler).
+        # `backend` is the distributed initialize + the backend's
+        # opening (init_jax_distributed), no part of `import`.  `aot` is
         # the AOT executable cache resolve: deserialize+link on a
         # HIT (retrace collapses to 0), entry write on a MISS
         _s("recovery_phase", ["phase", "seconds", "restart_count"],
@@ -344,10 +348,49 @@ class SpanSchema:
 SPAN_SCHEMAS: Dict[str, SpanSchema] = {
     s.name: s
     for s in (
+        # -- a launch (restart 0) or a respawn: one trace, opened by
+        # tpurun.boot; every one carries restart_count + node_rank --
+        SpanSchema(
+            "tpurun.boot", "agent (tpurun)",
+            "tpurun's process start (the kernel's clock) -> run() "
+            "entered: interpreter + imports; the root of the "
+            "launch's trace"),
+        SpanSchema(
+            "tpurun.master_boot", "agent (tpurun)",
+            "spawning the local master and polling its port until it "
+            "answers (polls; slept_s = the poll's own sleep); "
+            "master.boot lies inside it"),
+        SpanSchema(
+            "master.boot", "master",
+            "the master's process start -> it serves (master_start): "
+            "imports, construction, journal.replay"),
+        SpanSchema(
+            "agent.init", "agent",
+            "the agent's constructor: monitors and, with "
+            "warm_restart, the forkserver template's start"),
         SpanSchema(
             "rdzv.join", "agent + master",
             "one rendezvous join, agent side linked to the master's "
-            "handler by the RPC's trace context"),
+            "handler by the RPC's trace context (agent side: polls; "
+            "slept_s = whole JOIN_INTERVALs slept waiting for the "
+            "world)"),
+        SpanSchema(
+            "agent.spawn_workers", "agent",
+            "starting this node's workers (workers; warm_fork = "
+            "forked from the template); the workers' trainer.* "
+            "spans are its children through the environment"),
+        SpanSchema(
+            "trainer.distributed_init", "trainer",
+            "jax.distributed.initialize from the agent's env "
+            "(initialized false on one process: nothing to join); "
+            "recovery_phase import ends where it starts"),
+        SpanSchema(
+            "trainer.backend_open", "trainer",
+            "the first jax.local_devices(): creating the backend, on "
+            "a TPU host taking the chip (platform, kind, count)"),
+        SpanSchema(
+            "trainer.init", "trainer",
+            "ElasticTrainer's constructor up to worker_backend"),
         SpanSchema(
             "node_check", "agent",
             "one node-check round before workers start"),

@@ -109,7 +109,8 @@ CAT_RECOVERY_PHASE = "recovery_phase"
 # phase order of one recovery budget (mirrors
 # dlrover_recovery_phase_seconds{phase})
 RECOVERY_PHASES = (
-    "spawn", "import", "restore", "aot", "retrace", "first_step",
+    "spawn", "import", "backend", "restore", "aot", "retrace",
+    "first_step",
 )
 
 # how long after master_recovered a session resync still counts as

@@ -39,6 +39,12 @@ from dlrover_tpu.telemetry import metrics as _metrics
 
 TRACE_ID_KEY = "trace_id"
 SPAN_ID_KEY = "span_id"
+# how a launch's trace crosses a process spawn (no RPC exists yet):
+# the parent writes its current context, ``<trace_id>:<span_id>``,
+# into the environment it builds for the child anyway (tpurun for the
+# local master, the agent for every worker, beside
+# ``DLROVER_RECOVERY_T0``).  Never set by a user.
+TRACE_PARENT_ENV = "DLROVER_TRACE_PARENT"
 
 
 @dataclass(frozen=True)
@@ -294,6 +300,29 @@ def inject_context() -> Optional[Dict[str, str]]:
     if ctx is None:
         return None
     return {TRACE_ID_KEY: ctx.trace_id, SPAN_ID_KEY: ctx.span_id}
+
+
+def export_context(env: Dict[str, str]) -> Dict[str, str]:
+    """``env`` (a child process's) with the current span as its
+    trace parent, or without one where no span is active."""
+    ctx = _current_span.get()
+    if ctx is None:
+        env.pop(TRACE_PARENT_ENV, None)
+    else:
+        env[TRACE_PARENT_ENV] = f"{ctx.trace_id}:{ctx.span_id}"
+    return env
+
+
+def inherited_context() -> Optional[Dict[str, str]]:
+    """The trace parent this process was spawned under, in the wire
+    form :func:`attach_context` takes (None: spawned outside any
+    span, or not by this framework)."""
+    trace_id, _, span_id = os.environ.get(
+        TRACE_PARENT_ENV, ""
+    ).partition(":")
+    if not (trace_id and span_id):
+        return None
+    return {TRACE_ID_KEY: trace_id, SPAN_ID_KEY: span_id}
 
 
 @contextmanager

@@ -36,6 +36,7 @@ from dlrover_tpu.parallel.sharding import (
     batch_spec,
     sharding_tree,
 )
+from dlrover_tpu.telemetry import tracing as trace
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
 from dlrover_tpu.telemetry.tracing import annotation
@@ -102,7 +103,12 @@ class StepPhaseProfiler:
     jitted step — bracket with :meth:`PhaseHandle.block` so async
     dispatch doesn't leak compute time into the next data wait),
     ``checkpoint`` and ``report``; arbitrary names are accepted.
-    Un-profiled remainder of the step lands in ``other``.
+    Un-profiled remainder of the step lands in ``other``.  The first
+    step begins at its first phase, not at this object's
+    construction: what a process does before its first step (the
+    state's init, a restore, the step's compile) has names of its own
+    (``recovery_phase``, ``trainer.*`` spans) and is no part of a
+    step; every later step begins where the one before it ended.
 
     Two kinds of entry stand BESIDE the phases and are not summed
     into them: sub-phases (``report.events``, a name with a dot: a
@@ -120,7 +126,7 @@ class StepPhaseProfiler:
         self._acc: Dict[str, float] = {}
         self._sub: Dict[str, float] = {}
         self._open: Dict[str, float] = {}
-        self._step_started = time.perf_counter()
+        self._step_started: Optional[float] = None
         self._gc_seen = _GC_CLOCK.total
         # the step in progress (the trainer sets it): a stat of every
         # phase's profiler annotation
@@ -133,6 +139,7 @@ class StepPhaseProfiler:
         into = self._sub if "." in name else self._acc
         ann = annotation("step." + name, time.time_ns(), step=self.step)
         start = time.perf_counter()
+        self._begin(start)
         self._open[name] = start
         handle = PhaseHandle()
         try:
@@ -152,8 +159,15 @@ class StepPhaseProfiler:
     def add(self, name: str, seconds: float):
         """Record an externally-timed phase (e.g. the checkpoint
         engine's own stall measurement) or sub-phase."""
+        self._begin(time.perf_counter() - float(seconds))
         into = self._sub if "." in name else self._acc
         into[name] = into.get(name, 0.0) + float(seconds)
+
+    def _begin(self, now: float):
+        """The first step starts with its first phase."""
+        if self._step_started is None:
+            self._step_started = now
+            self._gc_seen = _GC_CLOCK.total
 
     def _phases(self, now: float) -> Dict[str, float]:
         acc = dict(self._acc)
@@ -161,7 +175,10 @@ class StepPhaseProfiler:
         for name, start in self._open.items():
             into = sub if "." in name else acc
             into[name] = into.get(name, 0.0) + now - start
-        total = max(0.0, now - self._step_started)
+        started = (
+            now if self._step_started is None else self._step_started
+        )
+        total = max(0.0, now - started)
         phases = {k: round(v, 6) for k, v in acc.items()}
         phases.update((k, round(v, 6)) for k, v in sub.items())
         phases["gc"] = round(_GC_CLOCK.total - self._gc_seen, 6)
@@ -186,23 +203,6 @@ class StepPhaseProfiler:
         self._step_started = now
         self._gc_seen = _GC_CLOCK.total
         return phases
-
-
-def _chip_metrics() -> str:
-    """Memory stats of the devices THIS process owns, one line each —
-    written into the metrics file so the agent's diagnosis collector
-    can report them without ever opening the chip itself.  Empty on
-    backends that report no memory stats (the CPU backend)."""
-    lines = []
-    for dev in jax.local_devices():
-        stats = dev.memory_stats()
-        if stats:
-            lines.append(
-                f"{dev}: in_use={stats.get('bytes_in_use', 0)} "
-                f"peak={stats.get('peak_bytes_in_use', 0)} "
-                f"limit={stats.get('bytes_limit', 0)}"
-            )
-    return "\n".join(lines)
 
 
 class PhaseHandle:
@@ -486,6 +486,23 @@ def resolve_train_step_async(
     )
 
 
+def _chip_metrics() -> str:
+    """Memory stats of the devices THIS process owns, one line each —
+    written into the metrics file so the agent's diagnosis collector
+    can report them without ever opening the chip itself.  Empty on
+    backends that report no memory stats (the CPU backend)."""
+    lines = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats()
+        if stats:
+            lines.append(
+                f"{dev}: in_use={stats.get('bytes_in_use', 0)} "
+                f"peak={stats.get('peak_bytes_in_use', 0)} "
+                f"limit={stats.get('bytes_limit', 0)}"
+            )
+    return "\n".join(lines)
+
+
 class ElasticTrainer:
     """Step/epoch accounting with a fixed global batch across resizes
     (reference: trainer.py GradientState + _ElasticOptimizer)."""
@@ -497,6 +514,7 @@ class ElasticTrainer:
         dp_size: Optional[int] = None,
         metrics_path: Optional[str] = None,
     ):
+        entered = time.time()
         self.global_batch_size = global_batch_size
         self.micro_batch_size = micro_batch_size
         self.dp_size = dp_size or env_utils.get_world_size()
@@ -530,6 +548,16 @@ class ElasticTrainer:
             restart_count=self._restart_count,
             node_rank=env_utils.get_node_rank(),
         )
+        # the launch chain's last link before the step's own phases:
+        # this constructor up to ``worker_backend``.  It holds the
+        # backend's opening where no ``init_jax_distributed()`` ran
+        # before it
+        with trace.attach_context(trace.inherited_context()):
+            trace.record_span(
+                "trainer.init", entered, time.time(),
+                restart_count=self._restart_count,
+                node_rank=env_utils.get_node_rank(),
+            )
         logger.info(
             "elastic trainer: global_batch=%s micro=%s dp=%s accum=%s "
             "on %d x %s",
@@ -667,21 +695,45 @@ class ElasticTrainer:
 def init_jax_distributed():
     """Initialize multi-host JAX from the agent's env contract
     (reference analog: dist.init_process_group with MASTER_ADDR/PORT
-    set by the agent, training.py:430-447)."""
+    set by the agent, training.py:430-447), then open the backend:
+    the first program call of every entrypoint, and two spans of the
+    launch's trace.  ``trainer.distributed_init`` is the connect to
+    the coordinator (``initialized`` false on one process: nothing
+    to join).  ``trainer.backend_open`` is ``jax.local_devices()``,
+    the call that creates the backend and, on a TPU host, takes the
+    chip: every worker makes it next anyway, and it can only come
+    AFTER the distributed initialize.  Before these spans a worker's
+    time is interpreter + imports (``recovery_phase`` ``import``);
+    :class:`~dlrover_tpu.trainer.recovery.RecoveryProfiler` books
+    them as ``backend``."""
     coordinator = env_utils.get_coordinator_addr()
     num_processes = int(
         os.getenv("DLROVER_NUM_PROCESSES", "1")
     )
-    if not coordinator or num_processes <= 1:
-        return False
-    process_id = int(os.getenv("DLROVER_PROCESS_ID", "0"))
-    jax.distributed.initialize(
-        coordinator_address=coordinator,
-        num_processes=num_processes,
-        process_id=process_id,
-    )
-    logger.info(
-        "jax.distributed initialized: process %s/%s via %s",
-        process_id, num_processes, coordinator,
-    )
-    return True
+    distributed = bool(coordinator) and num_processes > 1
+    labels = {
+        "restart_count": env_utils.get_restart_count(),
+        "node_rank": env_utils.get_node_rank(),
+    }
+    with trace.attach_context(trace.inherited_context()):
+        with trace.span(
+            "trainer.distributed_init", initialized=distributed,
+            num_processes=num_processes, **labels,
+        ):
+            if distributed:
+                process_id = int(os.getenv("DLROVER_PROCESS_ID", "0"))
+                jax.distributed.initialize(
+                    coordinator_address=coordinator,
+                    num_processes=num_processes,
+                    process_id=process_id,
+                )
+                logger.info(
+                    "jax.distributed initialized: process %s/%s via %s",
+                    process_id, num_processes, coordinator,
+                )
+        with trace.span("trainer.backend_open", **labels) as opened:
+            devices = jax.local_devices()
+            opened.set_attribute("platform", devices[0].platform)
+            opened.set_attribute("kind", devices[0].device_kind)
+            opened.set_attribute("count", len(devices))
+    return distributed

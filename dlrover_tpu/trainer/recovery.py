@@ -7,8 +7,14 @@ named phases, each measured where it actually runs:
 - **spawn**: the agent witnesses the death → this process exists
   (kernel start time from ``/proc/self/stat``, so the measurement
   covers the fork/exec itself, not just userland);
-- **import**: process start → the trainer constructed this profiler
-  (interpreter + jax/flax imports — near zero under a warm fork);
+- **import**: process start → the program's first call
+  (interpreter + jax/flax imports — near zero under a warm fork):
+  ``init_jax_distributed()`` where the entrypoint made it before
+  constructing this profiler, else the construction itself;
+- **backend**: that call → this profiler's construction: the
+  distributed initialize and the backend's opening (on a TPU host,
+  taking the chip), the spans ``trainer.distributed_init`` and
+  ``trainer.backend_open``;
 - **restore**: the checkpoint restore (the engine's measured
   ``total_s``);
 - **aot**: resolving the step through the AOT executable cache
@@ -18,7 +24,8 @@ named phases, each measured where it actually runs:
 - **retrace**: the first post-restore step's trace+compile, with the
   persistent compilation cache's hit/miss witnessed from the cache
   directory (:mod:`dlrover_tpu.common.compile_cache`);
-- **first_step**: the remainder until the first step completes.
+- **first_step**: the remainder, from the last phase recorded
+  before it, until the first step completes.
 
 Each phase lands as a ``recovery_phase`` event + a
 ``dlrover_recovery_phase_seconds{phase}`` histogram sample, so the
@@ -43,6 +50,7 @@ from dlrover_tpu.common.compile_cache import (
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
+from dlrover_tpu.telemetry.tracing import get_tracer
 
 RECOVERY_T0_ENV = "DLROVER_RECOVERY_T0"
 
@@ -50,26 +58,9 @@ _REG = get_registry()
 _PHASE_SECONDS = _REG.histogram(
     "dlrover_recovery_phase_seconds",
     "Measured death->first-step recovery budget by phase "
-    "(spawn / import / restore / aot / retrace / first_step)",
+    "(spawn / import / backend / restore / aot / retrace / "
+    "first_step)",
 )
-
-
-def _proc_start_epoch() -> Optional[float]:
-    """Absolute wall-clock time this process started: kernel start
-    ticks (``/proc/self/stat`` field 22) against the boot epoch from
-    ``/proc/uptime`` — survives exec, unlike any userland timestamp."""
-    fields = env_utils.proc_stat_fields(os.getpid())
-    if fields is None:
-        return None
-    try:
-        ticks = int(fields[19])
-        hz = float(os.sysconf("SC_CLK_TCK"))
-        with open("/proc/uptime") as f:
-            uptime = float(f.read().split()[0])
-        boot_epoch = time.time() - uptime
-        return boot_epoch + ticks / hz
-    except (IndexError, ValueError, OSError):
-        return None
 
 
 class _Phase:
@@ -118,26 +109,47 @@ class RecoveryProfiler:
         except ValueError:
             self.t0 = 0.0
         now = time.time()
-        start = _proc_start_epoch()
-        self._proc_start = start if start is not None else now
+        self._proc_start = env_utils.proc_start_before(now)
         if self.t0 > 0 and self._proc_start >= self.t0:
-            self.record("spawn", self._proc_start - self.t0)
-        self.record("import", max(0.0, now - self._proc_start))
-        self._first_step_t0 = time.perf_counter()
+            self.record(
+                "spawn", self._proc_start - self.t0,
+                end_ts=self._proc_start,
+            )
+        # where ``init_jax_distributed()`` ran before this
+        # construction, the imports ended as it began
+        opened = get_tracer().finished_spans("trainer.distributed_init")
+        imported = opened[-1].start_time if opened else now
+        self.record(
+            "import", imported - self._proc_start, end_ts=imported
+        )
+        if opened:
+            self.record("backend", now - imported)
 
     # -- recording ---------------------------------------------------------
 
-    def record(self, phase: str, seconds: float):
+    def record(
+        self, phase: str, seconds: float,
+        end_ts: Optional[float] = None,
+    ):
+        """Book ``seconds`` to ``phase``, as having ended now, or at
+        the wall clock ``end_ts`` (a phase that ended before there
+        was a profiler to see it): readers lay a phase on the
+        timeline as ``[ts - seconds, ts]``."""
         seconds = max(0.0, float(seconds))
         self.phases[phase] = round(seconds, 4)
+        # where the phase recorded last ended: ``first_step`` is the
+        # remainder since
+        self._boundary = time.perf_counter()
         _PHASE_SECONDS.observe(seconds, phase=phase)
-        emit_event(
-            "recovery_phase",
-            phase=phase,
-            seconds=round(seconds, 4),
-            restart_count=self.restart_count,
-            node_rank=self.node_rank,
-        )
+        event = {
+            "phase": phase,
+            "seconds": round(seconds, 4),
+            "restart_count": self.restart_count,
+            "node_rank": self.node_rank,
+        }
+        if end_ts is not None:
+            event["ts"] = end_ts
+        emit_event("recovery_phase", **event)
 
     def phase(self, name: str) -> _Phase:
         """``with profiler.phase("restore"): step, state = load()``"""
@@ -395,15 +407,14 @@ class RecoveryProfiler:
         return _Retrace(self)
 
     def record_first_step(self):
-        """Close the budget: remainder since the last recorded phase
-        boundary (profiler construction → now, minus restore+retrace,
-        which were measured inside it)."""
-        elapsed = time.perf_counter() - self._first_step_t0
-        inner = sum(
-            self.phases.get(p, 0.0)
-            for p in ("restore", "retrace", "aot")
+        """Close the budget: the remainder since the last recorded
+        phase boundary (the end of ``aot`` / ``retrace`` /
+        ``restore`` or of a phase of the entrypoint's own, whichever
+        came last).  What lies before that boundary has its own
+        names: the phases, and the ``trainer.*`` spans."""
+        self.record(
+            "first_step", time.perf_counter() - self._boundary
         )
-        self.record("first_step", max(0.0, elapsed - inner))
         if self.t0 > 0:
             total = time.time() - self.t0
             logger.info(

@@ -482,3 +482,229 @@ def test_goodput_ledger_tick_is_a_span(tmp_path, event_log):
     service.tick()
     (tick,) = spans_of(event_log, "master.goodput_ledger_tick")
     assert tick["attributes"]["events"] >= 1
+
+
+# -- E. a launch on one clock (PR 37) -------------------------------------------------
+
+LAUNCH_SPANS = (
+    "tpurun.boot", "tpurun.master_boot", "master.boot", "agent.init",
+    "rdzv.join", "agent.spawn_workers", "trainer.distributed_init",
+    "trainer.backend_open", "trainer.init",
+)
+
+# a worker as small as a worker gets: the entrypoint's three first
+# calls, a set-up that is no part of any step, then steps.  Its first
+# incarnation kills itself after three steps.
+TOY_WORKER = '''
+import os, signal, time
+from dlrover_tpu.trainer.elastic_trainer import (
+    ElasticTrainer, init_jax_distributed,
+)
+from dlrover_tpu.trainer.recovery import RecoveryProfiler
+
+init_jax_distributed()
+prof = RecoveryProfiler()
+trainer = ElasticTrainer(4, 4, dp_size=1)
+time.sleep(1.0)
+for step in range(1, 26):
+    with trainer.profile("compute"):
+        time.sleep(0.005)
+    trainer.report_step({"loss": 1.0})
+    if step == 1:
+        prof.record_first_step()
+    if step == 3 and prof.restart_count == 0:
+        os.kill(os.getpid(), signal.SIGKILL)
+'''
+
+
+@pytest.fixture(scope="module")
+def launch_log(tmp_path_factory):
+    """The event log of one ``tpurun`` job on the CPU backend: a
+    launch, a SIGKILL of the worker, a respawn that runs to its end.
+    tpurun is a process of its own, as a user starts it."""
+    tmp = tmp_path_factory.mktemp("launch")
+    script = tmp / "worker.py"
+    script.write_text(TOY_WORKER)
+    log = str(tmp / "events.jsonl")
+    env = dict(
+        os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+        DLROVER_EVENT_LOG=log,
+        DLROVER_SHARED_DIR=str(tmp / "sock"),
+        DLROVER_JOB_NAME=f"launch{os.getpid()}",
+        DLROVER_METRICS_FILE=str(tmp / "metrics.json"),
+    )
+    env.pop("DLROVER_TRACE_PARENT", None)
+    done = subprocess.run(  # noqa: S603
+        [sys.executable, "-m", "dlrover_tpu.run", "--nproc_per_node=1",
+         "--max_restarts=1", "--monitor_interval=0.3", str(script)],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
+    return list(read_events(log))
+
+
+def launch_spans(events, restart_count):
+    """``{name: span}`` of the launch chain's spans of one
+    incarnation (the agent's side of ``rdzv.join``)."""
+    return {
+        e["name"]: e for e in events
+        if e["type"] == "span" and e["name"] in LAUNCH_SPANS
+        and e["attributes"].get("restart_count") == restart_count
+    }
+
+
+def test_a_launch_is_nine_spans_on_one_clock_under_one_trace(launch_log):
+    spans = launch_spans(launch_log, 0)
+    assert set(spans) == set(LAUNCH_SPANS)
+    assert all(validate_event(e) == [] for e in spans.values())
+    assert {e["trace_id"] for e in spans.values()} == {
+        spans["tpurun.boot"]["trace_id"]
+    }
+    assert all(e["attributes"]["node_rank"] == 0 for e in spans.values())
+    # the chain's links: tpurun.boot is the root, the master's boot
+    # hangs under the launcher's wait for it, the worker's spans
+    # under the agent's spawn (both through the environment)
+    root = spans["tpurun.boot"]
+    assert root["parent_id"] is None
+    for name in ("tpurun.master_boot", "agent.init", "rdzv.join",
+                 "agent.spawn_workers"):
+        assert spans[name]["parent_id"] == root["span_id"], name
+    assert (spans["master.boot"]["parent_id"]
+            == spans["tpurun.master_boot"]["span_id"])
+    for name in ("trainer.distributed_init", "trainer.backend_open",
+                 "trainer.init"):
+        assert (spans[name]["parent_id"]
+                == spans["agent.spawn_workers"]["span_id"]), name
+    # in order on one clock (/proc's process start counts in 10 ms)
+    order = [n for n in LAUNCH_SPANS if n != "master.boot"]
+    starts = [spans[n]["start_ts"] for n in order]
+    assert starts == sorted(starts)
+    ends = [spans[n]["start_ts"] + spans[n]["duration_s"] for n in order]
+    assert all(e <= s + 0.02 for e, s in zip(ends, starts[1:]))
+    inside, around = spans["master.boot"], spans["tpurun.master_boot"]
+    assert around["start_ts"] - 0.02 <= inside["start_ts"]
+    assert (inside["start_ts"] + inside["duration_s"]
+            <= around["start_ts"] + around["duration_s"] + 0.02)
+    # what the polls cost is on the spans that poll
+    assert around["attributes"]["polls"] >= 1
+    assert around["attributes"]["slept_s"] == pytest.approx(
+        0.3 * (around["attributes"]["polls"] - 1)
+    )
+    join = spans["rdzv.join"]["attributes"]
+    assert join["polls"] >= 1 and join["slept_s"] >= 0.0
+    spawn = spans["agent.spawn_workers"]["attributes"]
+    assert spawn["workers"] == 1 and spawn["warm_fork"] is False
+    assert spans["trainer.backend_open"]["attributes"]["platform"] == "cpu"
+    # the worker's process exists after the agent began to spawn it,
+    # and its imports end where the program's first call begins
+    (imported,) = [
+        e for e in launch_log if e["type"] == "recovery_phase"
+        and e["phase"] == "import" and e["restart_count"] == 0
+    ]
+    assert (imported["ts"] - imported["seconds"]
+            >= spans["agent.spawn_workers"]["start_ts"] - 0.02)
+    assert imported["ts"] == pytest.approx(
+        spans["trainer.distributed_init"]["start_ts"], abs=1e-3
+    )
+
+
+def test_a_respawn_writes_the_launchs_spans_again(launch_log):
+    """After the SIGKILL the second incarnation runs the same chain
+    under the same names and the same trace, ``restart_count`` 1,
+    with a ``spawn`` phase from the death's witness."""
+    first, again = launch_spans(launch_log, 0), launch_spans(launch_log, 1)
+    assert set(again) == {
+        "rdzv.join", "agent.spawn_workers", "trainer.distributed_init",
+        "trainer.backend_open", "trainer.init",
+    }
+    assert {e["trace_id"] for e in again.values()} == {
+        first["tpurun.boot"]["trace_id"]
+    }
+    phases = {
+        e["phase"]: e for e in launch_log
+        if e["type"] == "recovery_phase" and e["restart_count"] == 1
+    }
+    assert {"spawn", "import", "backend", "first_step"} <= set(phases)
+    (restart,) = [e for e in launch_log if e["type"] == "worker_restart"]
+    spawn = phases["spawn"]
+    assert spawn["ts"] - spawn["seconds"] == pytest.approx(
+        restart["ts"], abs=0.05
+    )
+    # the phases tile: each begins where the one before it ended
+    assert phases["import"]["ts"] - phases["import"]["seconds"] == (
+        pytest.approx(spawn["ts"], abs=0.02)
+    )
+    assert phases["backend"]["ts"] - phases["backend"]["seconds"] == (
+        pytest.approx(phases["import"]["ts"], abs=1e-3)
+    )
+
+
+def test_step_one_is_the_first_step_and_not_the_setup(launch_log):
+    """The worker sleeps a second between the trainer's construction
+    and its first step: no part of step 1."""
+    for restart_count in (0, 1):
+        first = next(
+            e for e in launch_log if e["type"] == "step_phases"
+            and e["step"] == 1 and e["ts"] > launch_spans(
+                launch_log, restart_count
+            )["trainer.init"]["ts"]
+        )
+        own = first["compute"] + first["report"]
+        assert own <= first["total_s"] <= own + 0.05
+        assert first["other_s"] <= 0.05
+
+
+def test_a_steady_step_writes_the_parents_two_events(launch_log):
+    """Nothing of the launch's tracing landed in the step loop: over
+    20 steady steps the worker writes ``train_step`` and
+    ``step_phases``, one each a step, as the parent of PR 37 does."""
+    respawned = next(
+        e["pid"] for e in launch_log
+        if e["type"] == "train_step" and e["restart_count"] == 1
+    )
+    steady = [e for e in launch_log if e["pid"] == respawned]
+    at = {e["step"]: i for i, e in enumerate(steady)
+          if e["type"] == "train_step"}
+    between = steady[at[4]:at[24]]
+    assert len(between) == 2 * 20
+    assert {e["type"] for e in between} == {"train_step", "step_phases"}
+
+
+def test_the_harness_rehearses_the_launch_readers(tmp_path):
+    """``benchmarks/run.py`` on the toy configuration with the five
+    ``launch.*`` readers beside ``agent.start_s`` and
+    ``cache.load_s``: each finds a value, and the set-up splits into
+    program + harness + uncovered with little left uncovered."""
+    import re
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path))
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run(  # noqa: S603
+        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+         "--cells", os.path.join(REPO, "benchmarks",
+                                 "rehearsal_launch.json"),
+         "--workload", "toy_steady", "--seed", "3700000011",
+         "--seconds", "1", "--trace", "1"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
+    )
+    out = done.stdout
+    assert done.returncode == 3, out[-3000:] + done.stderr[-3000:]
+    assert '"correct": true' in out
+    (found,) = re.findall(r"readers that found a value: (\[.*\])", out)
+    assert set(json.loads(found.replace("'", '"'))) == {
+        "agent.start_s", "cache.load_s", "launch.tpurun_boot_s",
+        "launch.worker_import_s", "launch.backend_open_s",
+        "launch.first_step_s", "launch.unattributed_pct",
+    }
+    ((setup, program, harness, uncovered, share),) = re.findall(
+        r"launch: setup_s ([\d.]+) = program ([\d.]+) \+ harness "
+        r"([\d.]+) \+ uncovered ([\d.]+) \(([\d.]+)%\)", out,
+    )
+    setup, share = float(setup), float(share)
+    assert float(program) + float(harness) + float(uncovered) == (
+        pytest.approx(setup, rel=0.01)
+    )
+    assert share == pytest.approx(
+        100 * float(uncovered) / setup, abs=0.1
+    )
+    assert share < 25

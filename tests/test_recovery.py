@@ -231,3 +231,93 @@ def test_worker_env_exports_recovery_t0(monkeypatch):
     assert float(env["DLROVER_RECOVERY_T0"]) == pytest.approx(
         agent._recovery_t0, abs=1e-3
     )
+
+
+# -- the launch's phases (PR 37) ---------------------------------------
+
+
+def test_import_ends_where_the_backend_phase_begins(
+    tmp_path, monkeypatch, event_log
+):
+    """``init_jax_distributed()`` before the profiler: ``import`` is
+    the process's start to that call, ``backend`` the call to the
+    profiler's construction; together what ``import`` used to be
+    (process start -> construction), and each event lies where its
+    phase ended."""
+    from dlrover_tpu.common import env_utils
+    from dlrover_tpu.trainer.elastic_trainer import init_jax_distributed
+
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path / "cache"))
+    monkeypatch.delenv(rec.RECOVERY_T0_ENV, raising=False)
+    called = time.time()
+    assert init_jax_distributed() is False   # one process
+    time.sleep(0.2)
+    prof = rec.RecoveryProfiler()
+    built = time.time()
+    assert prof.phases["backend"] == pytest.approx(
+        built - called, abs=0.02
+    )
+    assert prof.phases["backend"] >= 0.2
+    assert prof.phases["import"] + prof.phases["backend"] == (
+        pytest.approx(built - env_utils.proc_start_before(built),
+                      abs=0.02)
+    )
+    by_phase = {
+        e["phase"]: e for e in read_events(event_log)
+        if e["type"] == "recovery_phase"
+    }
+    assert by_phase["import"]["ts"] == pytest.approx(called, abs=0.02)
+    assert by_phase["backend"]["ts"] == pytest.approx(built, abs=0.02)
+    spans = [
+        e["name"] for e in read_events(event_log) if e["type"] == "span"
+    ]
+    assert spans == ["trainer.distributed_init", "trainer.backend_open"]
+
+
+def test_first_step_is_the_remainder_since_the_last_phase(
+    tmp_path, monkeypatch
+):
+    """Not everything since the profiler's construction: what an
+    entrypoint does before the resolve (the trainer's constructor,
+    the backend's opening where the profiler came first) has its own
+    names and is no part of ``first_step``."""
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path / "cache"))
+    prof = rec.RecoveryProfiler()
+    time.sleep(0.3)          # stands for the trainer's init
+    with prof.phase("aot"):
+        time.sleep(0.01)
+    time.sleep(0.05)         # the first step itself
+    prof.record_first_step()
+    assert 0.05 <= prof.phases["first_step"] < 0.25
+
+
+def test_ledger_books_the_backend_phase_to_the_respawn_gap():
+    """The chip's opening is neither XLA work nor idle: beside
+    ``import`` under ``respawn_gap``."""
+    from dlrover_tpu.telemetry import goodput
+
+    t = 5000.0
+
+    def phase(ts, name, seconds):
+        return {"type": "recovery_phase", "ts": ts, "phase": name,
+                "seconds": seconds, "restart_count": 0, "node_rank": 0,
+                "source": "trainer"}
+
+    events = [
+        phase(t, "import", 5.0),
+        phase(t + 8.0, "backend", 8.0),
+        phase(t + 9.0, "aot", 1.0),
+    ] + [
+        {"type": "train_step", "ts": t + 10.0 + 0.1 * i, "step": i + 1,
+         "restart_count": 0, "node_rank": 0, "source": "trainer"}
+        for i in range(20)
+    ]
+    ledger = goodput.build_ledger(events)
+    assert ledger.conservation_errors() == []
+    assert ledger.totals[goodput.RESPAWN] == pytest.approx(8.0)
+    assert ledger.totals[goodput.COMPILE] == pytest.approx(1.0)
+    assert "backend" in flight.RECOVERY_PHASES
+    slices = flight.assemble(events).slices_by_cat(
+        flight.CAT_RECOVERY_PHASE
+    )
+    assert "backend" in {s.meta["phase"] for s in slices}
