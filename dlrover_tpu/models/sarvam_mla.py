@@ -355,6 +355,9 @@ def make_sarvam_mla_loss(model: SarvamMla, num_chunks: int = 8):
             "moe.held_rows_share": jnp.mean(
                 stats["held_rows"] / counts.sum(axis=1)
             ),
+            "moe.held_tiles_share": jnp.mean(
+                stats["tiles_used"] / stats["tiles"]
+            ),
             "moe.bias_abs_max": jnp.max(jnp.abs(biases)),
             "state_updates": {
                 f"block_{i}": {"moe": {"select_bias": deltas[j]}}

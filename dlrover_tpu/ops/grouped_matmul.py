@@ -31,6 +31,13 @@ transposed weight block), ``gmm_drhs`` (the gradient to the weights:
 per group, rows^T x cotangent summed over the group's tiles in a
 float32 accumulator).  Interpreter mode off the TPU, as
 ``flash_attention.py``.
+
+Beside them, for a layer most of whose tiles hold no row (a chip that
+holds a range of the experts, PR 38): ``gmm_tokens_from_rows``
+(:func:`tokens_from_rows`: the rows of the used tiles added back to
+their tokens, what XLA would run as a scatter-add) and
+``gmm_unwritten`` (:func:`unwritten`: a buffer for a walk over the
+used tiles to fill, which nothing has zeroed).
 """
 
 import functools
@@ -107,6 +114,24 @@ def _held_tile(i, tiles_used):
     # tile, so there is one).  A block index that does not change
     # between grid steps is neither fetched nor written back
     return jnp.minimum(i, tiles_used[0] - 1)
+
+
+def unwritten(shape, dtype, after: jax.Array) -> jax.Array:
+    """An array that nothing has written: it holds whatever its
+    memory held (NaN off the TPU).  What a walk over the used tiles
+    fills, where a ``jnp.zeros`` would be a pass over every row of
+    the static size for the sake of rows that nobody reads.  It
+    exists no sooner than ``after`` (an operand nobody reads): with
+    no operand the compiler allocates every such array of a step as
+    the step begins."""
+    return pl.pallas_call(
+        lambda after_ref, out_ref: None,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        interpret=_interpret(),
+        name="gmm_unwritten",
+    )(after)
 
 
 # -- rows x weights (forward, and the gradient to the rows) -------------------
@@ -361,3 +386,125 @@ def grouped_matmul(
     through indices that name only rows of a group;
     ``tests/test_sarvam_mla.py`` overwrites the others with NaN)."""
     return _grouped_matmul(rows, weights, tile_group, tiles_used, tiles)
+
+
+# -- the rows back to their tokens --------------------------------------------
+
+
+# rows of one tile are accumulated this many at a time: within a tile
+# the tokens are distinct, so the reads of a batch may all come before
+# its writes
+_ROW_BATCH = 4
+
+
+def _tokens_from_rows_kernel(
+    tiles_used, token_of_row, *refs,    # scalar prefetch, then blocks
+    tokens: int, tiles: int, weighted: bool,
+):
+    if weighted:
+        weight_ref, rows_ref, out_ref, acc_ref, part_ref = refs
+    else:
+        rows_ref, out_ref, acc_ref, part_ref = refs
+    tile = pl.program_id(1)
+    first_row = tile * rows_ref.shape[0]
+
+    @pl.when(tile == 0)
+    def _clear():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(tile < tiles_used[0])
+    def _accumulate():
+        part_ref[...] = rows_ref[...].astype(jnp.float32)
+
+        def batch(b, carry):
+            at = [b * _ROW_BATCH + u for u in range(_ROW_BATCH)]
+            # a row of padding names a token past the last one: it
+            # lands in the spare row behind them, which nobody reads
+            to = [
+                jnp.minimum(token_of_row[first_row + r], tokens)
+                for r in at
+            ]
+            parts = [part_ref[pl.ds(r, 1), :] for r in at]
+            if weighted:
+                parts = [
+                    part * weight_ref[first_row + r]
+                    for part, r in zip(parts, at)
+                ]
+            sums = [
+                acc_ref[pl.ds(row, 1), :] + part
+                for row, part in zip(to, parts)
+            ]
+            for row, total in zip(to, sums):
+                acc_ref[pl.ds(row, 1), :] = total
+            return carry
+
+        jax.lax.fori_loop(0, rows_ref.shape[0] // _ROW_BATCH, batch, 0)
+
+    @pl.when(tile == tiles - 1)
+    def _store():
+        out_ref[...] = acc_ref[pl.ds(0, tokens), :].astype(out_ref.dtype)
+
+
+def tokens_from_rows(
+    rows: jax.Array,          # [tiles * row_tile, d], tile-aligned groups
+    token_of_row: jax.Array,  # [tiles * row_tile] int32
+    tiles_used: jax.Array,    # [1] int32, of group_layout
+    tokens: int,
+    weight: jax.Array = None,  # [tiles * row_tile] float32
+) -> jax.Array:
+    """``out[token_of_row[p]] += weight[p] * rows[p]`` over the rows of
+    the tiles before ``tiles_used``, accumulated in float32 and cast
+    once to the rows' type: ``[tokens, d]``.  A row whose token is
+    ``tokens`` or more (a group's padding) is dropped; the rows of the
+    tiles from ``tiles_used`` on are NOT READ.  Within one tile the
+    tokens must be distinct.  A ``[tokens, column block]`` float32
+    accumulator stays in VMEM while the used tiles go by; each row is
+    one read-add-write of it (XLA's scatter-add takes 2.8 us a row
+    of 4096 on a v5e, this 0.07: PERF.md, PR 38)."""
+    m, d = rows.shape
+    if m % ROW_TILE:
+        raise ValueError(f"{m} rows do not divide into tiles of {ROW_TILE}")
+    tiles = m // ROW_TILE
+    # the widest column block whose accumulator and double-buffered
+    # result fit beside the row tiles
+    tn = d
+    while (tokens + 8) * tn * (4 + 2 * rows.dtype.itemsize) > (72 << 20):
+        if tn % 256:
+            raise ValueError(
+                f"no column block of {d} keeps {tokens} tokens in VMEM"
+            )
+        tn //= 2
+    weighted = weight is not None
+    return pl.pallas_call(
+        functools.partial(
+            _tokens_from_rows_kernel, tokens=tokens, tiles=tiles,
+            weighted=weighted,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3 if weighted else 2,
+            grid=(d // tn, tiles),
+            in_specs=[pl.BlockSpec(
+                (ROW_TILE, tn), lambda j, i, nu, *_: (_held_tile(i, nu), j)
+            )],
+            out_specs=pl.BlockSpec(
+                (tokens, tn), lambda j, i, *_: (0, j)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((tokens + 8, tn), jnp.float32),
+                pltpu.VMEM((ROW_TILE, tn), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), rows.dtype),
+        compiler_params=_params(
+            ("parallel", "arbitrary"),
+            _nbytes((ROW_TILE, tn), rows.dtype),
+            _nbytes((tokens, tn), rows.dtype),
+            _nbytes((tokens + 8, tn), jnp.float32) // 2,
+            _nbytes((ROW_TILE, tn), jnp.float32) // 2,
+        ),
+        interpret=_interpret(),
+        name="gmm_tokens_from_rows",
+    )(
+        tiles_used, token_of_row,
+        *((weight,) if weighted else ()), rows,
+    )

@@ -20,12 +20,12 @@ token-to-expert assignments are sorted by expert and the experts run
 as grouped matmuls over the sorted rows: no capacity, no dropped
 token, no ``[t, e, c]`` tensor.  A chip that holds only a range of a
 layer's experts tells the layer so (``held``): it routes over all of
-them and computes its own.  Across chips a dropless layer needs a
+them, computes its own and moves only the rows that exist (the last
+section of this file).  Across chips a dropless layer needs a
 ragged all-to-all (ROADMAP R3), which is not here; the
 ``expert``-mesh path stays with :class:`MoEMLP`.
 """
 
-import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -221,17 +221,8 @@ def collect_moe_aux_loss(intermediates) -> jax.Array:
 # -- dropless routing over grouped matmuls ------------------------------------
 
 
-def _rows_at(rows, slot, some_absent: bool):
-    """``rows[slot]``; with ``some_absent`` a slot past the last row
-    (an assignment to an expert this chip does not hold) reads
-    zeros."""
-    if some_absent:
-        return rows.at[slot].get(mode="fill", fill_value=0)
-    return rows[slot]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_rows(tokens, source, slot, some_absent=False):
+@jax.custom_vjp
+def _dispatch_rows(tokens, source, slot):
     """The rows of ``tokens [t, d]`` in the experts' tile-aligned
     order, ``[padded rows, d]``: ``source[p]`` is the flat assignment
     (token * k + choice) that lives at padded row ``p``, or ``t * k``
@@ -243,23 +234,20 @@ def _dispatch_rows(tokens, source, slot, some_absent=False):
     return jnp.concatenate([tokens, zero_row])[source // slot.shape[1]]
 
 
-def _dispatch_fwd(tokens, source, slot, some_absent):
-    return _dispatch_rows(tokens, source, slot, some_absent), slot
+def _dispatch_fwd(tokens, source, slot):
+    return _dispatch_rows(tokens, source, slot), slot
 
 
-def _dispatch_bwd(some_absent, slot, g):
+def _dispatch_bwd(slot, g):
     with jax.named_scope("moe_dispatch"):
-        return (
-            _rows_at(g, slot, some_absent).sum(axis=1).astype(g.dtype),
-            None, None,
-        )
+        return g[slot].sum(axis=1).astype(g.dtype), None, None
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _collect_rows(rows, source, slot, some_absent=False):
+@jax.custom_vjp
+def _collect_rows(rows, source, slot):
     """``rows[slot]``: the experts' outputs back in token order, ``[t,
     k, d]``; the gradient is the gather through ``source`` (the
     scatter-add of the combine, read from the other side: every
@@ -268,14 +256,14 @@ def _collect_rows(rows, source, slot, some_absent=False):
     masking pass over the rows): it meets only that row's own
     activations, which are zero because its input was, so no weight's
     gradient sees it."""
-    return _rows_at(rows, slot, some_absent)
+    return rows[slot]
 
 
-def _collect_fwd(rows, source, slot, some_absent):
-    return _rows_at(rows, slot, some_absent), source
+def _collect_fwd(rows, source, slot):
+    return rows[slot], source
 
 
-def _collect_bwd(some_absent, source, g):
+def _collect_bwd(source, g):
     with jax.named_scope("moe_combine"):
         flat = g.reshape((-1, g.shape[-1]))
         return flat.at[source].get(mode="clip"), None, None
@@ -334,14 +322,26 @@ def dropless_moe(
     here reads them: the activation works row by row, the gathers back
     go through ``slot``, which names only rows of an expert, and the
     next matmul skips the same tiles (a reduction over the padded rows
-    would: ``tests/test_sarvam_mla.py`` fills them with NaN).  The
-    sort, the gathers and the combine run at the static size whatever
-    share of it has a row.  ``stats`` carries what the
-    auxiliary losses and the counters need: ``counts [e]``
-    (assignments per expert over ALL experts, no gradient),
-    ``held_rows`` (assignments that reached a held expert),
-    ``prob_sum [e]`` (sum over tokens of the router's scores),
-    ``z_loss`` (mean over tokens of ``logsumexp(logits) ** 2``)."""
+    would: ``tests/test_sarvam_mla.py`` fills them with NaN).
+
+    **What runs at which size.**  The router and the index work (the
+    sort, ``slot``, ``source``: ``[t * k]`` and ``[padded rows]``
+    int32) run at the static size.  With every expert held, so do the
+    two row gathers and the weighting, over ``[t, k, d]``: nearly
+    every tile has rows.  With a held range most tiles have none, and
+    the rows move from the row side (below the layer in this file):
+    dispatch and combine walk the ``tiles_used`` tiles that hold a
+    row, forward and backward; the token side keeps ``[t, d]`` arrays
+    and nothing of ``[t, k, d]`` is made.  Their ``[padded rows, d]``
+    results are, as the kernels', NOT WRITTEN past ``tiles_used``.
+
+    ``stats`` carries what the auxiliary losses and the counters
+    need: ``counts [e]`` (assignments per expert over ALL experts, no
+    gradient), ``held_rows`` (assignments that reached a held
+    expert), ``prob_sum [e]`` (sum over tokens of the router's
+    scores), ``z_loss`` (mean over tokens of ``logsumexp(logits) **
+    2``) and, with ``held``, ``tiles_used`` of the layout's ``tiles``
+    row tiles."""
     t, _ = tokens.shape
     e = router_kernel.shape[-1]
     lo, count = (0, e) if held is None else held
@@ -384,12 +384,14 @@ def dropless_moe(
                 jax.nn.logsumexp(logits, axis=-1) ** 2
             ),
         }
-    some_absent = held is not None
     with jax.named_scope("moe_dispatch"):
         tile_group, tiles_used, padded_starts = gmm.group_layout(
             group_sizes, assignments
         )
         padded_rows = tile_group.shape[0] * gmm.ROW_TILE
+        if held is not None:
+            stats["tiles_used"] = tiles_used[0].astype(jnp.float32)
+            stats["tiles"] = jnp.float32(tile_group.shape[0])
         if held is None:
             order = jnp.argsort(flat_ids, stable=True).astype(jnp.int32)
             sorted_ids = flat_ids[order]
@@ -421,9 +423,12 @@ def dropless_moe(
             order, unique_indices=True,
             mode=None if held is None else "drop",
         )
-        rows = _dispatch_rows(
-            tokens.astype(dtype), source, slot, some_absent
-        )
+        if held is None:
+            rows = _dispatch_rows(tokens.astype(dtype), source, slot)
+        else:
+            rows = _held_dispatch(
+                tokens.astype(dtype), source, slot, tiles_used
+            )
     with jax.named_scope("moe_experts"):
         def expert(x, w):
             return gmm.grouped_matmul(
@@ -434,11 +439,13 @@ def dropless_moe(
             nn.silu(expert(rows, w_gate)) * expert(rows, w_up), w_down
         )
     with jax.named_scope("moe_combine"):
-        out = jnp.einsum(
-            "tkd,tk->td",
-            _collect_rows(rows, source, slot, some_absent), gate,
-            preferred_element_type=jnp.float32,
-        )
+        if held is None:
+            out = jnp.einsum(
+                "tkd,tk->td", _collect_rows(rows, source, slot), gate,
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            out = _held_combine(rows, gate, source, slot, tiles_used)
     return out.astype(dtype), stats
 
 
@@ -514,3 +521,153 @@ class DroplessMoE(nn.Module):
                 ) * dense(self.shared_dim, "shared_up")(x)
                 out = out + dense(d, "shared_down")(hidden)
         return out, stats
+
+
+# -- a held range: the rows move from the row side ----------------------------
+#
+# A chip that holds ``count`` of a layer's ``e`` experts gives a row to
+# ``count / e`` of the assignments, so most of the static layout's row
+# tiles hold none.  The movements below walk the used tiles, up to
+# ``tiles_used`` (a trip count read on the device), and keep only ``[t,
+# d]`` arrays on the token side: no ``[t, k, d]`` array is made and the
+# rows past ``tiles_used`` are neither read nor written.  Rows from
+# tokens is a loop of one tile's gather a trip; its transpose, the
+# rows back to their tokens, is ``gmm.tokens_from_rows`` (a kernel:
+# XLA's scatter-add walks its rows one by one).  Both live inside
+# ``custom_vjp`` rules, so autodiff never meets a loop.
+
+
+def _token_of_row(source, slot):
+    """``[padded rows]``: the token whose assignment lives at each
+    padded row.  A row of padding names a token past the last one,
+    each its own and ascending, so that a gather fills it with zeros
+    and a scatter drops it, and the indices of one tile (one expert's
+    rows in token order, then its padding) are sorted and distinct."""
+    t, k = slot.shape
+    padding = t + jnp.arange(source.shape[0], dtype=jnp.int32)
+    return jnp.where(source < t * k, source // k, padding)
+
+
+def _tile(x, i):
+    return jax.lax.dynamic_slice_in_dim(
+        x, i * gmm.ROW_TILE, gmm.ROW_TILE
+    )
+
+
+def _rows_of(x, index):
+    # ``x[index]`` for one tile's tokens; a row of padding reads zeros
+    return x.at[index].get(
+        mode="fill", fill_value=0, indices_are_sorted=True,
+        unique_indices=True,
+    )
+
+
+def _rows_from_tokens(x, token_of_row, tiles_used, weight=None):
+    """``weight[p] * x[token_of_row[p]]`` for the rows of the used
+    tiles, ``[padded rows, d]``: a gather of one tile's rows a trip;
+    the product in float32."""
+
+    def move(i, rows):
+        tile = _rows_of(x, _tile(token_of_row, i))
+        if weight is not None:
+            tile = tile.astype(jnp.float32) * _tile(weight, i)[:, None]
+        return jax.lax.dynamic_update_slice_in_dim(
+            rows, tile.astype(x.dtype), i * gmm.ROW_TILE, axis=0
+        )
+
+    return jax.lax.fori_loop(
+        0, tiles_used[0], move,
+        gmm.unwritten((token_of_row.shape[0], x.shape[1]), x.dtype, x),
+    )
+
+
+def _row_dots(rows, x, token_of_row, tiles_used):
+    """``<rows[p], x[token_of_row[p]]>`` in float32 for the rows of
+    the used tiles, ``[padded rows]``; 0 for a row of padding."""
+
+    def move(i, dots):
+        tile = jnp.sum(
+            _tile(rows, i).astype(jnp.float32)
+            * _rows_of(x, _tile(token_of_row, i)).astype(jnp.float32),
+            axis=-1,
+        )
+        return jax.lax.dynamic_update_slice_in_dim(
+            dots, tile, i * gmm.ROW_TILE, axis=0
+        )
+
+    return jax.lax.fori_loop(
+        0, tiles_used[0], move,
+        jnp.zeros(token_of_row.shape, jnp.float32),
+    )
+
+
+@jax.custom_vjp
+def _held_dispatch(tokens, source, slot, tiles_used):
+    """:func:`_dispatch_rows` where only some assignments have a row
+    (``slot`` names a row past the last one for the others)."""
+    return _rows_from_tokens(
+        tokens, _token_of_row(source, slot), tiles_used
+    )
+
+
+def _held_dispatch_fwd(tokens, source, slot, tiles_used):
+    return (
+        _held_dispatch(tokens, source, slot, tiles_used),
+        (source, slot, tiles_used),
+    )
+
+
+def _held_dispatch_bwd(res, g):
+    source, slot, tiles_used = res
+    with jax.named_scope("moe_dispatch"):
+        d_tokens = gmm.tokens_from_rows(
+            g, _token_of_row(source, slot), tiles_used, slot.shape[0]
+        )
+        return d_tokens, None, None, None
+
+
+_held_dispatch.defvjp(_held_dispatch_fwd, _held_dispatch_bwd)
+
+
+def _gate_of_row(gate, source):
+    return gate.reshape(-1).at[source].get(mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _held_combine(rows, gate, source, slot, tiles_used):
+    """``sum over a token's held choices of gate x row``, accumulated
+    in float32 and cast once, ``[t, d]``: :func:`_collect_rows` and
+    the weighting in one, from the row side.  Choices held elsewhere
+    add nothing."""
+    return gmm.tokens_from_rows(
+        rows, _token_of_row(source, slot), tiles_used, slot.shape[0],
+        _gate_of_row(gate, source),
+    )
+
+
+def _held_combine_fwd(rows, gate, source, slot, tiles_used):
+    return (
+        _held_combine(rows, gate, source, slot, tiles_used),
+        (rows, gate, source, slot, tiles_used),
+    )
+
+
+def _held_combine_bwd(res, g):
+    rows, gate, source, slot, tiles_used = res
+    with jax.named_scope("moe_combine"):
+        token_of_row = _token_of_row(source, slot)
+        # a row's gradient is its gate x its token's; the gate's is
+        # the row's dot product with it, back in ``[t, k]`` through
+        # ``slot`` (0 for a choice held elsewhere).  Two walks, so
+        # that the first does not wait for ``rows``, which the
+        # backward pass has to make again
+        d_rows = _rows_from_tokens(
+            g, token_of_row, tiles_used, _gate_of_row(gate, source)
+        )
+        d_gate = _row_dots(rows, g, token_of_row, tiles_used).at[
+            slot
+        ].get(mode="fill", fill_value=0)
+        return d_rows, d_gate.astype(gate.dtype), None, None, None
+
+
+_held_combine.defvjp(_held_combine_fwd, _held_combine_bwd)

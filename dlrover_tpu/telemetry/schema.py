@@ -98,13 +98,15 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # ``aux`` of a ``has_aux`` loss; models/olmoe.py); for a layer
         # that holds a range of its experts (models/sarvam_mla.py):
         # the share of the step's assignments that reached a held
-        # expert and the router bias's size
+        # expert, the share of the layout's row tiles they fill (what
+        # the layer's row movement and kernels walk) and the router
+        # bias's size
         # gdn.state_rms_max: the largest rms of a linear-attention
         # layer's final state (models/olmo_hybrid.py)
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
-            "moe.bias_abs_max"]),
+            "moe.held_tiles_share", "moe.bias_abs_max"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

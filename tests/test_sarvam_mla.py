@@ -124,13 +124,23 @@ def test_float32_loss_and_logits_equal_the_reference(attention):
         logits, jnp.stack(ref_logits), rtol=0, atol=1e-4
     )
     assert set(aux) == {
-        "moe.held_rows_share", "moe.bias_abs_max", STATE_UPDATES,
+        "moe.held_rows_share", "moe.held_tiles_share",
+        "moe.bias_abs_max", STATE_UPDATES,
     }
     # the counter is the reference's count of what reached experts
     # 4..7 of 16, over both layers' 2 x 64 x 4 assignments each
     held = sum(float(n[4:8].sum()) for n in counts)
     assert float(aux["moe.held_rows_share"]) == pytest.approx(
         held / (2 * 2 * 64 * 4)
+    )
+    # ... and each held expert fills ceil(n / tile) row tiles of the
+    # layout's 2 x 64 x 4 / tile + 4, an expert without a token one
+    tiles = [
+        sum(max(1, math.ceil(float(c) / gmm.ROW_TILE)) for c in n[4:8])
+        for n in counts
+    ]
+    assert float(aux["moe.held_tiles_share"]) == pytest.approx(
+        np.mean(tiles) / (2 * 64 * 4 / gmm.ROW_TILE + 4)
     )
     assert float(aux["moe.bias_abs_max"]) == pytest.approx(max(
         float(jnp.abs(params[f"block_{i}"]["moe"]["select_bias"]).max())
@@ -458,14 +468,17 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
     matmul's result AND of its gradient to the rows overwritten with
     NaN, the output and all five gradients are finite and bit-equal
     to the run with zeros there (the kernels' contract until PR 36)
-    and to the run as it is.  A reduction over the padded rows, or a
-    gather that names one, fails here."""
+    and to the run as it is.  Where the chip holds a range, the
+    dispatch's output and the combine's gradient are not written
+    there either (PR 38) and are overwritten alike.  A reduction over
+    the padded rows, or a gather that names one, fails here."""
     c = UNWRITTEN[case]
     operands = layer_operands(**c["operands"])
     bias = None
     if "avoid" in c:
         bias = jnp.zeros(operands[1].shape[1:]).at[c["avoid"]].set(-9.0)
     real = gmm.grouped_matmul
+    held_dispatch, held_combine = moe._held_dispatch, moe._held_combine
     seen = []
 
     def layer(*ops):
@@ -491,7 +504,23 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
             out, stats = layer(*ops)
             return jnp.sum(out * cot), (out, stats)
 
+        def dispatch(tokens, source, slot, tiles_used):
+            return _fill_past(
+                held_dispatch(tokens, source, slot, tiles_used),
+                tiles_used, fill,
+            )
+
+        def combine(rows, gate, source, slot, tiles_used):
+            # the fill of ``rows`` is the fill of their gradient
+            return held_combine(
+                _fill_past(rows, tiles_used, fill), gate, source, slot,
+                tiles_used,
+            )
+
         monkeypatch.setattr(moe.gmm, "grouped_matmul", product)
+        if fill is not None:
+            monkeypatch.setattr(moe, "_held_dispatch", dispatch)
+            monkeypatch.setattr(moe, "_held_combine", combine)
         cot = jax.random.normal(jax.random.PRNGKey(7), operands[0].shape)
         (_, (out, stats)), grads = jax.value_and_grad(
             scored, argnums=range(5), has_aux=True
@@ -509,6 +538,220 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
         assert np.isfinite(got).all() and got.any()
         np.testing.assert_array_equal(got, zeros)
         np.testing.assert_array_equal(got, plain)
+
+
+# The held layer's routing as it stood until PR 38, word for word: the
+# plain reference of the row-side movements.  Every array has the
+# static size: the dispatch gathers ``[padded rows, d]``, the combine
+# gathers ``[t, k, d]`` (a choice held elsewhere reads zeros) and
+# weights it.
+
+
+def _rows_at(rows, slot, some_absent: bool):
+    if some_absent:
+        return rows.at[slot].get(mode="fill", fill_value=0)
+    return rows[slot]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows_at_pr_37(tokens, source, slot, some_absent=False):
+    zero_row = jnp.zeros((1, tokens.shape[1]), tokens.dtype)
+    return jnp.concatenate([tokens, zero_row])[source // slot.shape[1]]
+
+
+def _dispatch_fwd(tokens, source, slot, some_absent):
+    return _dispatch_rows_at_pr_37(tokens, source, slot, some_absent), slot
+
+
+def _dispatch_bwd(some_absent, slot, g):
+    return (
+        _rows_at(g, slot, some_absent).sum(axis=1).astype(g.dtype),
+        None, None,
+    )
+
+
+_dispatch_rows_at_pr_37.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _collect_rows_at_pr_37(rows, source, slot, some_absent=False):
+    return _rows_at(rows, slot, some_absent)
+
+
+def _collect_fwd(rows, source, slot, some_absent):
+    return _rows_at(rows, slot, some_absent), source
+
+
+def _collect_bwd(some_absent, source, g):
+    flat = g.reshape((-1, g.shape[-1]))
+    return flat.at[source].get(mode="clip"), None, None
+
+
+_collect_rows_at_pr_37.defvjp(_collect_fwd, _collect_bwd)
+
+
+def plain_dispatch(tokens, source, slot, tiles_used):
+    return _dispatch_rows_at_pr_37(tokens, source, slot, True)
+
+
+def plain_combine(rows, gate, source, slot, tiles_used):
+    return jnp.einsum(
+        "tkd,tk->td", _collect_rows_at_pr_37(rows, source, slot, True),
+        gate, preferred_element_type=jnp.float32,
+    ).astype(rows.dtype)
+
+
+HELD = {
+    **{k: v for k, v in UNWRITTEN.items() if v["held"] is not None},
+    # the bias sends every token's four choices to experts 0..3: all
+    # 2048 rows land here, two tiles an expert, the 4 spare ones empty
+    "every_assignment_lands_here": dict(
+        operands=dict(t=512, e=16, seed=5), held=(0, 4), top_k=4,
+        towards=slice(0, 4), tiles=(8, 12),
+    ),
+    # ... and to a range held elsewhere: one empty tile an expert
+    "no_token_reaches_the_range": dict(
+        operands=dict(t=512, e=16, seed=5), held=(8, 4), top_k=4,
+        towards=slice(0, 4), tiles=(4, 12),
+    ),
+}
+
+
+def held_case(case):
+    c = HELD[case]
+    operands = layer_operands(**c["operands"])
+    bias = jnp.zeros(operands[1].shape[1:])
+    if "avoid" in c:
+        bias = bias.at[c["avoid"]].set(-9.0)
+    if "towards" in c:
+        bias = bias.at[c["towards"]].set(9.0)
+    return c, operands, bias
+
+
+@pytest.mark.parametrize("case", sorted(HELD))
+def test_the_row_side_is_the_plain_routing(case, monkeypatch):
+    """Where a chip holds a range, dispatch and combine walk the used
+    row tiles (PR 38).  Against the routing as it stood, at the static
+    size: the same output and the same five gradients, to 1e-6 of
+    float32 where a token's held terms are summed in another order
+    (by expert, no longer by choice) and BIT-equal where nothing is
+    summed differently (the gradients to the experts' weights: the
+    rows and the rows' gradients are the same numbers)."""
+    c, operands, bias = held_case(case)
+    cot = jax.random.normal(jax.random.PRNGKey(7), operands[0].shape)
+
+    def results():
+        def scored(*ops):
+            out, stats = share(ops, c["held"], c["top_k"], bias=bias)
+            return jnp.sum(out * cot), (out, stats)
+
+        (_, (out, stats)), grads = jax.value_and_grad(
+            scored, argnums=range(5), has_aux=True
+        )(*operands)
+        return [np.asarray(a) for a in (out, *grads)], stats
+
+    got, stats = results()
+    assert (int(stats["tiles_used"]), int(stats["tiles"])) == c["tiles"]
+    monkeypatch.setattr(moe, "_held_dispatch", plain_dispatch)
+    monkeypatch.setattr(moe, "_held_combine", plain_combine)
+    want, _ = results()
+    for name, a, b in zip(
+        ("out", "tokens", "router", "w_gate", "w_up", "w_down"), got, want
+    ):
+        assert np.isfinite(a).all(), name
+        if name.startswith("w_"):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:
+            assert relative(a, b) < 1e-6, name
+    if case == "no_token_reaches_the_range":
+        assert not any(a.any() for a in got)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_the_two_movements_are_each_others_transpose(weighted, dtype):
+    """``_rows_from_tokens`` and ``tokens_from_rows`` against numpy's
+    loops: 5 row tiles of which 3 are used, a tile's tokens ascending
+    and distinct, its padding named past the last token.  The rows of
+    the tiles past ``tiles_used`` are neither written by the one nor
+    read by the other (NaN there), a row of padding reads zeros and
+    adds nothing (NaN there too)."""
+    t, d, tile = 300, 128, gmm.ROW_TILE
+    rng = np.random.default_rng(3)
+    token_of_row = np.full((5 * tile,), -1)
+    for i, n in enumerate((tile, 41, 0)):
+        token_of_row[i * tile:i * tile + n] = np.sort(
+            rng.choice(t, n, replace=False)
+        )
+    real = token_of_row >= 0
+    token_of_row = np.where(real, token_of_row, t + np.arange(5 * tile))
+    tiles_used = jnp.array([3], jnp.int32)
+    x = jnp.asarray(rng.normal(size=(t, d)), dtype)
+    rows = np.asarray(moe._rows_from_tokens(
+        x, jnp.asarray(token_of_row, jnp.int32), tiles_used
+    ))
+    assert np.isnan(rows[3 * tile:].astype(np.float32)).all()
+    want = np.where(
+        real[:, None], np.asarray(x)[np.minimum(token_of_row, t - 1)], 0
+    )
+    np.testing.assert_array_equal(rows[:3 * tile], want[:3 * tile])
+
+    y = rng.normal(size=(5 * tile, d)).astype(np.float32)
+    y[~real] = np.nan
+    y = np.asarray(jnp.asarray(y, dtype))
+    weight = rng.uniform(0.5, 2, size=(5 * tile,)).astype(np.float32)
+    got = gmm.tokens_from_rows(
+        jnp.asarray(y), jnp.asarray(token_of_row, jnp.int32), tiles_used,
+        t, jnp.asarray(weight) if weighted else None,
+    )
+    assert got.dtype == dtype
+    want = np.zeros((t, d), np.float32)
+    for p in np.flatnonzero(real):
+        want[token_of_row[p]] += (
+            weight[p] if weighted else 1.0
+        ) * y[p].astype(np.float32)
+    want = np.asarray(jnp.asarray(want, dtype))
+    if weighted:
+        # a compiler may fuse the product into the sum
+        assert relative(got, want) < (1e-6 if dtype == jnp.float32 else 8e-3)
+    else:
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_no_tokens_by_choices_by_width_array_in_the_step():
+    """The toy's lowered step, forward and backward, holds no array of
+    ``tokens x k`` rows of the model's width: no ``[t, k, d]`` and no
+    ``[t * k, d]`` (what a gather or scatter of every assignment's row
+    would make).  The layer with the plain routing does, so the search
+    would find one."""
+    from test_olmoe import shapes_in
+
+    model, step, state, batch = toy_step()
+    cfg = model.config
+    t, k, d = batch["x"].size, cfg.top_k, cfg.hidden_dim
+
+    def every_assignment(text):
+        return [
+            s for s in shapes_in(text)
+            if s[-1] == d and math.prod(s) == t * k * d
+        ]
+
+    assert not every_assignment(step.lower(state, batch).as_text())
+    c, operands, bias = held_case("most_tiles_empty")
+    t, d = operands[0].shape
+    k = c["top_k"]
+
+    def lowered():
+        return jax.jit(jax.grad(
+            lambda *ops: share(ops, c["held"], k, bias=bias)[0].sum(),
+            argnums=range(5),
+        )).lower(*operands).as_text()
+
+    assert not every_assignment(lowered())
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "_held_dispatch", plain_dispatch)
+        patch.setattr(moe, "_held_combine", plain_combine)
+        assert (t, k, d) in every_assignment(lowered())
 
 
 def dropless_moe_at_pr_33(
@@ -621,7 +864,8 @@ def test_the_step_moves_the_bias_by_its_rule_and_nothing_else_does():
     deltas = jax.tree.map(np.asarray, aux[STATE_UPDATES])
     new_state, metrics = step(state, batch)
     assert set(metrics) == {
-        "loss", "grad_norm", "moe.held_rows_share", "moe.bias_abs_max",
+        "loss", "grad_norm", "moe.held_rows_share",
+        "moe.held_tiles_share", "moe.bias_abs_max",
     }
     for i in EXPERT_LAYERS:
         old = before[f"block_{i}"]["moe"]["select_bias"]
@@ -675,10 +919,12 @@ def test_the_counters_ride_on_the_train_step_event(tmp_path, monkeypatch):
     trainer.report_step({
         "loss": jnp.float32(1.5), "grad_norm": jnp.float32(0.1),
         "moe.held_rows_share": jnp.float32(0.0625),
+        "moe.held_tiles_share": jnp.float32(0.078125),
         "moe.bias_abs_max": jnp.float32(0.003),
     })
     (event,) = [e for e in read_events(path) if e["type"] == "train_step"]
     assert event["moe.held_rows_share"] == 0.0625
+    assert event["moe.held_tiles_share"] == 0.078125
     assert event["moe.bias_abs_max"] == pytest.approx(0.003)
     assert not validate_event(event)
 
