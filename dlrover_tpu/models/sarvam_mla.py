@@ -118,33 +118,41 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def yarn_correction_range(cfg: SarvamMlaConfig) -> Tuple[int, int]:
-    """``(low, high)``: the rope pairs between which the frequencies
-    blend from extrapolated to interpolated."""
-    dim = cfg.qk_rope_dim
+def yarn_correction_range(
+    dim: int, theta: float, original_len: int, beta_fast: float,
+    beta_slow: float,
+) -> Tuple[int, int]:
+    """``(low, high)``: the rope pairs (of ``dim`` rotated lanes)
+    between which the frequencies blend from extrapolated to
+    interpolated."""
 
     def pair_of(rotations):
         return dim * math.log(
-            cfg.rope_original_len / (rotations * 2 * math.pi)
-        ) / (2 * math.log(cfg.rope_theta))
+            original_len / (rotations * 2 * math.pi)
+        ) / (2 * math.log(theta))
 
-    low = math.floor(pair_of(cfg.rope_beta_fast))
-    high = math.ceil(pair_of(cfg.rope_beta_slow))
+    low = math.floor(pair_of(beta_fast))
+    high = math.ceil(pair_of(beta_slow))
     return max(low, 0), min(high, dim - 1)
 
 
-def yarn_inv_freq(cfg: SarvamMlaConfig) -> np.ndarray:
-    """``deepseek_yarn``: pair ``i`` keeps ``theta^(-2i/dim)`` below
-    ``low``, takes it over ``factor`` above ``high``, a linear blend
-    between.  A constant of the configuration, worked in float64 (at
-    position 8191 a float32 rounding of the frequency is 5e-4 rad)."""
-    dim = cfg.qk_rope_dim
-    freq = cfg.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    low, high = yarn_correction_range(cfg)
+def yarn_inv_freq(
+    dim: int, theta: float, factor: float, original_len: int,
+    beta_fast: float, beta_slow: float,
+) -> np.ndarray:
+    """yarn (``deepseek_yarn``, HF ``_compute_yarn_parameters``): pair
+    ``i`` keeps ``theta^(-2i/dim)`` below ``low``, takes it over
+    ``factor`` above ``high``, a linear blend between.  A constant of
+    the configuration, worked in float64 (at position 8191 a float32
+    rounding of the frequency is 5e-4 rad)."""
+    freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_correction_range(
+        dim, theta, original_len, beta_fast, beta_slow
+    )
     ramp = np.clip(
         (np.arange(dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0
     )
-    return freq / cfg.rope_factor * ramp + freq * (1.0 - ramp)
+    return freq / factor * ramp + freq * (1.0 - ramp)
 
 
 def softmax_scale(cfg: SarvamMlaConfig) -> float:
@@ -199,7 +207,11 @@ class LatentAttention(nn.Module):
         with jax.named_scope("mla_rope"):
             angles = (
                 jnp.arange(s, dtype=jnp.float32)[:, None]
-                * jnp.asarray(yarn_inv_freq(cfg), jnp.float32)[None, :]
+                * jnp.asarray(yarn_inv_freq(
+                    rope, cfg.rope_theta, cfg.rope_factor,
+                    cfg.rope_original_len, cfg.rope_beta_fast,
+                    cfg.rope_beta_slow,
+                ), jnp.float32)[None, :]
             )
             m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / (
                 yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)

@@ -36,7 +36,21 @@ known when the kernel is traced: it is taken as a triangle of
 ``_CHUNK``-column passes, each over only the rows that see those
 columns, the mask on one square chunk a pass and nothing above the
 diagonal computed (10 of 16 chunk pairs at 1024 x 1024).  Other
-sub-blocks are one pass (or a few, ``_PASS_SCORES``).  ``m``, ``l`` and
+sub-blocks are one pass (or a few, ``_PASS_SCORES``).
+
+A window.  With ``window`` a query sees the ``window`` keys up to its
+own, so a tile's walk has a second end and a second masked edge.  Such
+a call takes square tiles, and then everything about a sub-block is
+known when the kernel is traced from how many tiles it lies away from
+the grid step's own: the last grid axis runs over those distances (the
+own tile and the ``ceil((window - 1) / block)`` before it; after it
+in dkv), the walked operands come one tile a grid step (the index map
+clamped at the sequence's ends, so a tile the walk skips is not
+fetched), and a sub-block is taken as passes of ``_CHUNK`` columns,
+each over only the rows the BAND gives those columns, the masks on
+the chunk-square pieces an edge crosses (``_window_passes``; at a
+window of 512 and tiles of 1024, 1.5 x the scores the window
+requires).  ``m``, ``l`` and
 the accumulator live in VMEM scratch, the statistics lane-dense
 (``[block_q, 128]``); lse and delta cross between that form and the
 ``[1, seq]`` rows they travel as by 128 x 128 transposes, once a grid
@@ -73,14 +87,18 @@ _RESIDENT_BYTES = 4 * 2**20
 
 def resident_rows(
     seq: int, sub_block: int, head_dim: int, itemsize: int,
-    v_head_dim: int | None = None,
+    v_head_dim: int | None = None, window: int | None = None,
 ) -> int:
     """Rows of the walked operands one grid step holds: the largest
     divisor of ``seq`` that is a whole number of loop sub-blocks and
     fits ``_RESIDENT_BYTES``; one sub-block if none does.  The walked
     pair is one operand of ``head_dim`` (= ``d_qk``: K, or Q in dkv)
     and one of ``v_head_dim`` (``d_v``: V, or dO; left out, the
-    same), so the count takes their SUM."""
+    same), so the count takes their SUM.  A windowed walk needs
+    ``sub_block + window`` rows at most and takes them one sub-block
+    a grid step."""
+    if window is not None:
+        return sub_block
     if v_head_dim is None:
         v_head_dim = head_dim
     for parts in range(1, seq // sub_block + 1):
@@ -146,8 +164,91 @@ def _q_walk(k_start, block_q: int, block_k: int):
     return start, full
 
 
+def _tiles_back(block: int, window: int) -> int:
+    """Tiles before its own that a q tile's window reaches (after its
+    own, the q tiles that reach a kv tile)."""
+    return -(-(window - 1) // block)
+
+
+def _band_edges(nq: int, nk: int, off: int, window: int):
+    """``(causal, trailing)``: which edges of the band cross a piece
+    of ``nq`` queries by ``nk`` keys whose first key lies ``off``
+    positions after its first query (query ``a`` sees key ``b`` where
+    ``0 <= (a - b) - off < window``)."""
+    return off > -(nk - 1), nq - 1 >= off + window
+
+
+def _window_passes(
+    block: int, window: int, away: int, kv_side: bool, scores: int
+):
+    """``(first column, columns, first row, end row, shift)`` of each
+    pass over the ``[block, block]`` sub-block that lies ``away``
+    tiles from the grid step's own (before it for a q tile, after it
+    for a kv tile, whose rows are kv positions and whose columns are q
+    positions: ``kv_side``).  A chunk of columns meets only the rows
+    the band gives it; ``shift`` is the chunk's first position less
+    the tile's, None where no edge crosses the pass (a sub-block
+    wholly inside the band goes as :func:`_passes` takes it)."""
+    c = _chunk(block)
+    out, whole = [], True
+    for col in range(0, block, c):
+        shift = away * block + col
+        if kv_side:
+            first = max(0, (shift - window + 1) // c * c)
+            end = min(block, shift + c)
+        else:
+            first = max(0, shift)
+            end = min(block, -(-(shift + c + window - 1) // c) * c)
+        if end <= first:
+            whole = False
+            continue
+        crossed = any(
+            any(_band_edges(
+                c, c, row - shift if kv_side else shift - row, window
+            )) for row in range(first, end, c)
+        )
+        whole = whole and not crossed and (first, end) == (0, block)
+        out.append((col, c, first, end, shift if crossed else None))
+    if whole:
+        return [
+            (col, width, 0, block, None)
+            for col, width, _ in _passes(block, block, False, scores)
+        ]
+    return out
+
+
+def _window_walk(tile, major, block, window, kv_side, scores, body):
+    """``body(*pass)`` over the passes of the sub-block this grid step
+    holds: step ``major`` of the last grid axis is the sub-block
+    ``major - back`` tiles from q tile ``tile`` (``+ major`` from kv
+    tile ``tile``), skipped where that lies outside the sequence."""
+    back = _tiles_back(block, window)
+    by_passes = {}
+    for step in range(back + 1):
+        passes = tuple(_window_passes(
+            block, window, step if kv_side else step - back, kv_side,
+            scores,
+        ))
+        by_passes.setdefault(passes, []).append(step)
+    for passes, steps in by_passes.items():
+        here = functools.reduce(
+            jnp.logical_or, [major == step for step in steps]
+        )
+        inside = (
+            tile + major < pl.num_programs(1) if kv_side
+            else tile + major >= back
+        )
+
+        def run(passes=passes):
+            for one in passes:
+                body(*one)
+
+        pl.when(jnp.logical_and(here, inside))(run)
+
+
 def block_schedule(
-    seq: int, block_q: int, block_k: int, causal: bool = True
+    seq: int, block_q: int, block_k: int, causal: bool = True,
+    window: int | None = None,
 ) -> dict:
     """What one head's walk costs: how many ``[block_q, block_k]``
     sub-blocks the kernels visit (a count of score tiles: neither
@@ -157,8 +258,31 @@ def block_schedule(
     is the tile's own is walked as a triangle of chunks).  The three
     kernels walk the same set (dkv by kv tile, the other two by q
     tile); their loop bounds are ``_kv_walk`` / ``_q_walk``, summed
-    here."""
+    here.  With a ``window`` (square tiles) the walk is
+    ``_window_walk``'s: a q tile visits its own tile and those its
+    window reaches, a sub-block is masked where an edge of the band
+    crosses it, and ``computed`` counts the passes' rows by
+    columns."""
     total = (seq // block_q) * (seq // block_k)
+    if window is not None and window < seq:
+        if not causal or block_q != block_k:
+            raise ValueError("a window takes causal, square tiles")
+        visited = masked = scores = 0
+        for tile in range(seq // block_q):
+            for away in range(-min(tile, _tiles_back(block_q, window)), 1):
+                passes = _window_passes(
+                    block_q, window, away, False, _PASS_SCORES
+                )
+                visited += 1
+                masked += any(p[4] is not None for p in passes)
+                scores += sum(
+                    width * (end - first)
+                    for _, width, first, end, _ in passes
+                )
+        return {
+            "visited": visited, "masked": masked, "total": total,
+            "computed": scores / seq**2,
+        }
     if not causal:
         return {
             "visited": total, "masked": 0, "total": total,
@@ -271,6 +395,41 @@ def _mask_corner(s, q_axis: int, first: int):
     return jnp.concatenate(parts, axis=0)
 
 
+def _band_rows(s, q_axis: int, first: int, shift: int, window: int):
+    """One pass of a windowed walk: scores of the tile's rows from
+    ``first`` against a chunk of columns that starts ``shift``
+    positions from the tile's start.  The chunk-square pieces an edge
+    of the band crosses are masked, what lies between them is whole
+    (all of it where ``shift`` is None)."""
+    if shift is None:
+        return s
+    size = s.shape[1]
+    parts, whole_from = [], None
+    for top in range(0, s.shape[0], size):
+        off = shift - first - top if q_axis == 0 else first + top - shift
+        causal, trailing = _band_edges(size, size, off, window)
+        if not (causal or trailing):
+            whole_from = top if whole_from is None else whole_from
+            continue
+        if whole_from is not None:
+            parts.append(s[whole_from:top])
+            whole_from = None
+        part = s[top:top + size]
+        ahead = jax.lax.broadcasted_iota(
+            jnp.int32, part.shape, q_axis
+        ) - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1 - q_axis)
+        if causal and trailing:
+            keep = (ahead >= off) & (ahead < off + window)
+        elif causal:
+            keep = ahead >= off
+        else:
+            keep = ahead < off + window
+        parts.append(jnp.where(keep, part, NEG_INF))
+    if whole_from is not None:
+        parts.append(s[whole_from:])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
 def _clip(lo, x, hi):
     if all(isinstance(n, int) for n in (lo, x, hi)):
         return max(lo, min(x, hi))
@@ -306,6 +465,14 @@ def _bracket(major, num_major: int, init, walk, final):
     pl.when(major == num_major - 1)(final)
 
 
+def _major(num_major: int, window):
+    """This grid step's place on the last grid axis: static where the
+    axis has one step and its place picks no branch."""
+    if num_major == 1 and window is None:
+        return 0
+    return pl.program_id(2)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -317,10 +484,10 @@ def _fwd_kernel(
     lse_ref,                  # [1, 1, block_q]
     m_scr, l_scr, acc_scr,    # [block_q, 128] x2, [block_q, d_v]
     *, scale: float, block_q: int, block_k: int, causal: bool,
-    num_major: int,
+    num_major: int, window: int | None = None,
 ):
     q_start = pl.program_id(1) * block_q
-    major = 0 if num_major == 1 else pl.program_id(2)
+    major = _major(num_major, window)
     subs = k_ref.shape[1] // block_k
     first = major * subs
     d = v_ref.shape[2]
@@ -336,37 +503,60 @@ def _fwd_kernel(
         if fold:
             q = q * scale
 
+        def one_pass(mine, col, width, mask):
+            # ``col()`` once an operand: the causal walk's start is
+            # an addition on the device, and its trace keeps the two
+            # it always had
+            k = k_ref[0, pl.ds(col(), width), :]
+            v = v_ref[0, pl.ds(col(), width), :]
+            s = _nt(q[mine], k)
+            if not fold:
+                s = s * scale
+            s = mask(s)
+            m_prev = m_scr[mine, :]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(s, axis=1, keepdims=True)
+            )
+            p = jnp.exp(s - _lanes(m_new, width))
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[mine, :] = alpha * l_scr[mine, :] + jnp.sum(
+                p, axis=1, keepdims=True
+            )
+            acc_scr[mine, :] = acc_scr[mine, :] * _lanes(
+                alpha, d
+            ) + _nn(p.astype(v.dtype), v)
+            m_scr[mine, :] = m_new
+
         def step(j, masked):
             base = pl.multiple_of((j - first) * block_k, block_k)
             triangle = masked and block_q == block_k
             for col, width, top in _passes(
                 block_q, block_k, triangle, _PASS_SCORES
             ):
-                mine = slice(top, block_q)
-                k = k_ref[0, pl.ds(base + col, width), :]
-                v = v_ref[0, pl.ds(base + col, width), :]
-                s = _nt(q[mine], k)
-                if not fold:
-                    s = s * scale
-                if triangle:
-                    s = _mask_corner(s, 0, 0)
-                elif masked:
-                    s = _mask(s, 0, j * block_k + col - q_start)
-                m_prev = m_scr[mine, :]
-                m_new = jnp.maximum(
-                    m_prev, jnp.max(s, axis=1, keepdims=True)
-                )
-                p = jnp.exp(s - _lanes(m_new, width))
-                alpha = jnp.exp(m_prev - m_new)
-                l_scr[mine, :] = alpha * l_scr[mine, :] + jnp.sum(
-                    p, axis=1, keepdims=True
-                )
-                acc_scr[mine, :] = acc_scr[mine, :] * _lanes(
-                    alpha, d
-                ) + _nn(p.astype(v.dtype), v)
-                m_scr[mine, :] = m_new
+                def mask(s, col=col):
+                    if triangle:
+                        return _mask_corner(s, 0, 0)
+                    if masked:
+                        return _mask(s, 0, j * block_k + col - q_start)
+                    return s
 
-        if causal:
+                one_pass(
+                    slice(top, block_q), lambda col=col: base + col,
+                    width, mask,
+                )
+
+        def band(col, width, top, end, shift):
+            one_pass(
+                slice(top, end), lambda: col, width,
+                lambda s: _band_rows(s, 0, top, shift, window),
+            )
+
+        if window is not None:
+            _window_walk(
+                pl.program_id(1), major, block_q, window, False,
+                _PASS_SCORES, band,
+            )
+        elif causal:
             full, end = _kv_walk(q_start, block_q, block_k)
             _walk(step, first, first + subs, (0, full), (full, end))
         else:
@@ -381,13 +571,22 @@ def _fwd_kernel(
     _bracket(major, num_major, init, walk, final)
 
 
-def _fwd(
-    q, k, v, scale: float, causal: bool, block_q: int, block_k: int,
-    group: int = 1,
-):
-    bh, seq, d = q.shape
-    d_v = v.shape[2]
-    rows = resident_rows(seq, block_k, d, k.dtype.itemsize, d_v)
+def _kv_walked(seq, block_q, block_k, d, d_v, itemsize, causal, group,
+               window):
+    """``(rows, num_major, index map)`` of the K and V blocks a grid
+    step of the forward or of dq holds, on the grid (batch * heads, q
+    tiles, major blocks)."""
+    rows = resident_rows(seq, block_k, d, itemsize, d_v, window)
+    if window is not None:
+        back = _tiles_back(block_q, window)
+
+        # step j is the tile j - back from the q tile's own; before
+        # the sequence's start it maps to the first tile, which the
+        # next step holds too, so nothing is fetched for it
+        def kv_block(b, i, j):
+            return (b // group, jnp.maximum(i + j - back, 0), 0)
+
+        return rows, back + 1, kv_block
     num_major = seq // rows
 
     # GQA: k/v carry bh//group rows; `group` consecutive q heads read
@@ -400,6 +599,20 @@ def _fwd(
             j = jnp.minimum(j, (i * block_q + block_q - 1) // rows)
         return (b // group, j, 0)
 
+    return rows, num_major, kv_block
+
+
+def _fwd(
+    q, k, v, scale: float, causal: bool, block_q: int, block_k: int,
+    group: int = 1, window: int | None = None,
+):
+    bh, seq, d = q.shape
+    d_v = v.shape[2]
+    rows, num_major, kv_block = _kv_walked(
+        seq, block_q, block_k, d, d_v, k.dtype.itemsize, causal, group,
+        window,
+    )
+
     def q_block(b, i, j):
         return (b, i, 0)
 
@@ -407,6 +620,7 @@ def _fwd(
         functools.partial(
             _fwd_kernel, scale=scale, block_q=block_q,
             block_k=block_k, causal=causal, num_major=num_major,
+            window=window,
         ),
         grid=(bh, seq // block_q, num_major),
         in_specs=[
@@ -444,10 +658,10 @@ def _bwd_dq_kernel(
     dq_ref,
     dq_scr,
     *, scale: float, block_q: int, block_k: int, causal: bool,
-    num_major: int,
+    num_major: int, window: int | None = None,
 ):
     q_start = pl.program_id(1) * block_q
-    major = 0 if num_major == 1 else pl.program_id(2)
+    major = _major(num_major, window)
     subs = k_ref.shape[1] // block_k
     first = major * subs
     fold = _scale_is_exact(scale)
@@ -465,30 +679,50 @@ def _bwd_dq_kernel(
         lse = _lanes_to_rows(lse_ref)
         delta = _lanes_to_rows(delta_ref)
 
+        def one_pass(mine, col, width, mask):
+            k = k_ref[0, pl.ds(col(), width), :]
+            v = v_ref[0, pl.ds(col(), width), :]
+            s = _nt(q[mine], k)
+            if not fold:
+                s = s * scale
+            s = mask(s)
+            p = jnp.exp(s - lse[mine])
+            dp = _nt(do[mine], v.astype(jnp.float32))
+            ds = p * (dp - delta[mine])
+            if not fold:
+                ds = ds * scale
+            dq_scr[mine, :] += _nn(ds.astype(k.dtype), k)
+
         def step(j, masked):
             base = pl.multiple_of((j - first) * block_k, block_k)
             triangle = masked and block_q == block_k
             for col, width, top in _passes(
                 block_q, block_k, triangle, _PASS_SCORES
             ):
-                mine = slice(top, block_q)
-                k = k_ref[0, pl.ds(base + col, width), :]
-                v = v_ref[0, pl.ds(base + col, width), :]
-                s = _nt(q[mine], k)
-                if not fold:
-                    s = s * scale
-                if triangle:
-                    s = _mask_corner(s, 0, 0)
-                elif masked:
-                    s = _mask(s, 0, j * block_k + col - q_start)
-                p = jnp.exp(s - lse[mine])
-                dp = _nt(do[mine], v.astype(jnp.float32))
-                ds = p * (dp - delta[mine])
-                if not fold:
-                    ds = ds * scale
-                dq_scr[mine, :] += _nn(ds.astype(k.dtype), k)
+                def mask(s, col=col):
+                    if triangle:
+                        return _mask_corner(s, 0, 0)
+                    if masked:
+                        return _mask(s, 0, j * block_k + col - q_start)
+                    return s
 
-        if causal:
+                one_pass(
+                    slice(top, block_q), lambda col=col: base + col,
+                    width, mask,
+                )
+
+        def band(col, width, top, end, shift):
+            one_pass(
+                slice(top, end), lambda: col, width,
+                lambda s: _band_rows(s, 0, top, shift, window),
+            )
+
+        if window is not None:
+            _window_walk(
+                pl.program_id(1), major, block_q, window, False,
+                _PASS_SCORES, band,
+            )
+        elif causal:
             full, end = _kv_walk(q_start, block_q, block_k)
             _walk(step, first, first + subs, (0, full), (full, end))
         else:
@@ -508,13 +742,13 @@ def _bwd_dkv_kernel(
     dk_ref, dv_ref,
     dk_scr, dv_scr,
     *, scale: float, block_q: int, block_k: int, causal: bool,
-    num_major: int,
+    num_major: int, window: int | None = None,
 ):
     """Works on the TRANSPOSED sub-block, ``[block_k, block_q]``: the
     statistics are rows there and broadcast down the sublanes, and
     both accumulating matmuls contract over its last axis."""
     k_start = pl.program_id(1) * block_k
-    major = 0 if num_major == 1 else pl.program_id(2)
+    major = _major(num_major, window)
     subs = q_ref.shape[1] // block_q
     first = major * subs
     fold = _scale_is_exact(scale)
@@ -529,35 +763,54 @@ def _bwd_dkv_kernel(
             k = k * scale
         v = v_ref[0].astype(jnp.float32)
 
+        def one_pass(mine, cols, mask):
+            q = q_ref[0, cols, :]
+            do = do_ref[0, cols, :].astype(jnp.float32)
+            s = _nt(k[mine], q)
+            if not fold:
+                s = s * scale
+            s = mask(s)
+            p = jnp.exp(s - lse_ref[0, :, cols])
+            dv_scr[mine, :] += _nn(p, do)
+            dp = _nt(v[mine], do)
+            ds = p * (dp - delta_ref[0, :, cols])
+            if not fold:
+                ds = ds * scale
+            dk_scr[mine, :] += _nn(ds, q.astype(jnp.float32))
+
         def step(i, masked):
             base = pl.multiple_of((i - first) * block_q, block_q)
             triangle = masked and block_q == block_k
             for col, width, _ in _passes(
                 block_k, block_q, triangle, _PASS_SCORES_DKV
             ):
+                def mask(s, col=col):
+                    if triangle:
+                        return _mask_corner(s, 1, col)
+                    if masked:
+                        return _mask(s, 1, k_start - i * block_q - col)
+                    return s
+
                 # here the tile's rows are kv positions: a chunk of q
                 # columns is seen by the rows down to its own last one
-                mine = slice(0, col + width if triangle else block_k)
-                cols = pl.ds(base + col, width)
-                q = q_ref[0, cols, :]
-                do = do_ref[0, cols, :].astype(jnp.float32)
-                s = _nt(k[mine], q)
-                if not fold:
-                    s = s * scale
-                if triangle:
-                    s = _mask_corner(s, 1, col)
-                elif masked:
-                    s = _mask(s, 1, k_start - i * block_q - col)
-                p = jnp.exp(s - lse_ref[0, :, cols])
-                dv_scr[mine, :] += _nn(p, do)
-                dp = _nt(v[mine], do)
-                ds = p * (dp - delta_ref[0, :, cols])
-                if not fold:
-                    ds = ds * scale
-                dk_scr[mine, :] += _nn(ds, q.astype(jnp.float32))
+                one_pass(
+                    slice(0, col + width if triangle else block_k),
+                    pl.ds(base + col, width), mask,
+                )
+
+        def band(col, width, top, end, shift):
+            one_pass(
+                slice(top, end), pl.ds(col, width),
+                lambda s: _band_rows(s, 1, top, shift, window),
+            )
 
         total = num_major * subs
-        if causal:
+        if window is not None:
+            _window_walk(
+                pl.program_id(1), major, block_k, window, True,
+                _PASS_SCORES_DKV, band,
+            )
+        elif causal:
             start, full = _q_walk(k_start, block_q, block_k)
             _walk(step, first, first + subs, (full, total), (start, full))
         else:
@@ -581,17 +834,15 @@ def _delta(out, dout):
 
 
 def _bwd_dq(
-    q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group
+    q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group,
+    window=None,
 ):
     bh, seq, d = q.shape
     d_v = v.shape[2]
-    rows = resident_rows(seq, block_k, d, k.dtype.itemsize, d_v)
-    num_major = seq // rows
-
-    def kv_block(b, i, j):
-        if causal and num_major > 1:
-            j = jnp.minimum(j, (i * block_q + block_q - 1) // rows)
-        return (b // group, j, 0)
+    rows, num_major, kv_block = _kv_walked(
+        seq, block_q, block_k, d, d_v, k.dtype.itemsize, causal, group,
+        window,
+    )
 
     def q_block(b, i, j):
         return (b, i, 0)
@@ -603,6 +854,7 @@ def _bwd_dq(
         functools.partial(
             _bwd_dq_kernel, scale=scale, block_q=block_q,
             block_k=block_k, causal=causal, num_major=num_major,
+            window=window,
         ),
         grid=(bh, seq // block_q, num_major),
         in_specs=[
@@ -621,17 +873,23 @@ def _bwd_dq(
 
 
 def _bwd_dkv(
-    q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group
+    q, k, v, dout, lse, delta, scale, causal, block_q, block_k, group,
+    window=None,
 ):
     bh, seq, d = q.shape
     d_v = v.shape[2]
-    rows = resident_rows(seq, block_q, d, q.dtype.itemsize, d_v)
+    rows = resident_rows(seq, block_q, d, q.dtype.itemsize, d_v, window)
     num_major = seq // rows
+    if window is not None:
+        num_major = _tiles_back(block_k, window) + 1
 
     # causal: a q-major block above the kv tile maps to the first one
     # that reaches it, so the pipeline fetches that one early and
-    # nothing for the ones the walk skips
+    # nothing for the ones the walk skips.  A window: step m is the q
+    # tile m after the kv tile's own, past the sequence's end the last
     def major(j, m):
+        if window is not None:
+            return jnp.minimum(j + m, seq // block_q - 1)
         if causal and num_major > 1:
             m = jnp.maximum(m, (j * block_k) // rows)
         return m
@@ -652,6 +910,7 @@ def _bwd_dkv(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, block_q=block_q,
             block_k=block_k, causal=causal, num_major=num_major,
+            window=window,
         ),
         grid=(bh, seq // block_k, num_major),
         in_specs=[
@@ -689,12 +948,12 @@ def _bwd_dkv(
 
 
 def _bwd(
-    scale, causal, block_q, block_k, group, residuals, dout
+    scale, causal, block_q, block_k, group, window, residuals, dout
 ):
     q, k, v, out, lse = residuals
     args = (
         q, k, v, dout, lse, _delta(out, dout), scale, causal,
-        block_q, block_k, group,
+        block_q, block_k, group, window,
     )
     return (_bwd_dq(*args), *_bwd_dkv(*args))
 
@@ -705,23 +964,28 @@ def _bwd(
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
-def _flash_mha(q, k, v, scale, causal, block_q, block_k, group=1):
-    out, _ = _fwd(q, k, v, scale, causal, block_q, block_k, group)
+def _flash_mha(q, k, v, scale, causal, block_q, block_k, group=1,
+               window=None):
+    out, _ = _fwd(
+        q, k, v, scale, causal, block_q, block_k, group, window
+    )
     return out
 
 
 def _flash_mha_fwd(q, k, v, scale, causal, block_q, block_k,
-                   group=1):
-    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, group)
+                   group=1, window=None):
+    out, lse = _fwd(
+        q, k, v, scale, causal, block_q, block_k, group, window
+    )
     return out, (q, k, v, out, lse)
 
 
-def _flash_mha_bwd(scale, causal, block_q, block_k, group,
+def _flash_mha_bwd(scale, causal, block_q, block_k, group, window,
                    residuals, dout):
     return _bwd(
-        scale, causal, block_q, block_k, group, residuals, dout
+        scale, causal, block_q, block_k, group, window, residuals, dout
     )
 
 
@@ -764,6 +1028,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     dtype: Any = None,  # accepted for model-pluggability; output dtype
+    window: int | None = None,
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] tensors.
 
@@ -784,6 +1049,12 @@ def flash_attention(
     (``default_blocks``); with ``causal`` the loop runs only to the
     diagonal, and equal blocks let the sub-block on it be walked as a
     triangle (the module docstring; ``block_schedule`` counts it).
+
+    ``window`` (with ``causal``): query ``i`` sees keys ``(i - window,
+    i]``.  The three kernels walk only the tiles the band touches and
+    mask only the pieces an edge crosses (the module docstring).  A
+    windowed call takes square tiles (``block_k`` is ``block_q``); a
+    window that covers the sequence is the plain causal call.
 
     GQA: ``k``/``v`` may carry fewer heads than ``q`` (``kv_heads``
     dividing ``heads``, kv-head-major q layout as in the Llama
@@ -809,6 +1080,19 @@ def flash_attention(
         )
     group = h // kvh
     scale = scale if scale is not None else d**-0.5
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"a window ({window}) is at least 1 and needs causal"
+            )
+        if block_k is not None and block_k != (block_q or block_k):
+            raise ValueError(
+                "a windowed call takes square tiles, not blocks "
+                f"({block_q},{block_k})"
+            )
+        block_q = block_k = block_q or block_k
+        if window >= s:
+            window = None
     if block_q is None or block_k is None:
         tq, tk = default_blocks(s, q.dtype.itemsize)
         block_q = tq if block_q is None else block_q
@@ -827,7 +1111,7 @@ def flash_attention(
 
     out = _flash_mha(
         fold(q), fold(k), fold(v), scale, causal, block_q, block_k,
-        group,
+        group, window,
     )
     out = out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
     if dtype is not None:

@@ -175,11 +175,25 @@ def _gmm_kernel(
             out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
+def _fit_tile(dim: int, tile: int) -> int:
+    """A dim's tile: the dim whole up to ``tile``, else its largest
+    divisor that is a whole number of lanes and at most ``tile`` (a
+    hidden size of 3072 under a tile of 2048 goes in halves of 1536;
+    2048 and 4096 take the tile itself).  Where there is none the
+    tile, which the caller's check then refuses."""
+    if dim <= tile:
+        return dim
+    for parts in range(-(-dim // tile), dim // 128 + 1):
+        if dim % parts == 0 and (dim // parts) % 128 == 0:
+            return dim // parts
+    return tile
+
+
 def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
     row_tile, k_tile, n_tile = tiles
     m, k = rows.shape
     n = weights.shape[1] if transpose_rhs else weights.shape[2]
-    tk, tn = min(k, k_tile), min(n, n_tile)
+    tk, tn = _fit_tile(k, k_tile), _fit_tile(n, n_tile)
     if m % row_tile or k % tk or n % tn:
         raise ValueError(
             f"rows {rows.shape} x weights {weights.shape} do not "
@@ -295,7 +309,7 @@ def _tgmm(rows, cotangent, tile_group, tiles_used, *, groups, tiles):
     n = cotangent.shape[1]
     # both sides of the weight block are OUTPUT dims here (its float32
     # accumulator lives in VMEM), so both take the output's tile
-    tk, tn = min(k, n_tile), min(n, n_tile)
+    tk, tn = _fit_tile(k, n_tile), _fit_tile(n, n_tile)
     if m % row_tile or k % tk or n % tn:
         raise ValueError(
             f"rows {rows.shape} and cotangent {cotangent.shape} do "

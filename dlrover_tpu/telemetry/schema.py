@@ -103,10 +103,14 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # bias's size
         # gdn.state_rms_max: the largest rms of a linear-attention
         # layer's final state (models/olmo_hybrid.py)
+        # attn.window_tiles_share: the sub-blocks the sliding layers'
+        # flash kernels walk over those a causal walk would
+        # (models/laguna.py)
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
-            "moe.held_tiles_share", "moe.bias_abs_max"]),
+            "moe.held_tiles_share", "moe.bias_abs_max",
+            "attn.window_tiles_share"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

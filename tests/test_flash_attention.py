@@ -23,7 +23,7 @@ def _rand_qkv(
     )
 
 
-def _reference(q, k, v, causal=True):
+def _reference(q, k, v, causal=True, window=None):
     scale = q.shape[-1] ** -0.5
     group = q.shape[2] // k.shape[2]
     if group > 1:  # kv-head-major, as the Llama family lays q out
@@ -35,6 +35,9 @@ def _reference(q, k, v, causal=True):
     if causal:
         s = q.shape[1]
         mask = jnp.tril(jnp.ones((s, s), dtype=bool))
+        if window is not None:
+            # query i sees keys (i - window, i]
+            mask = mask & ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
         logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum(
@@ -120,6 +123,41 @@ WALKS = {
         dict(s=256), (64, 128), True, None, 32,
     ),
     "chunks-not-causal": (dict(s=256), (128, 128), False, None, 32),
+    # a window (a sixth entry): query i sees keys (i - window, i].
+    # Below the block: the band lies in a tile's own sub-block and
+    # the one before it, both edges in the own one
+    "window-below-the-block": (
+        dict(s=256), (64, 64), True, None, 32, 24,
+    ),
+    # the trailing edge runs along the diagonal of the tile before
+    "window-is-the-block": (dict(s=256), (64, 64), True, None, 32, 64),
+    # two tiles back, the trailing edge through chunk-square pieces
+    "window-above-the-block-not-a-multiple": (
+        dict(s=256), (64, 64), True, None, 32, 150,
+    ),
+    # a sub-block wholly inside the band goes in plain passes
+    "window-of-three-blocks": (
+        dict(s=512), (64, 64), True, None, 32, 192,
+    ),
+    "window-one-chunk-a-block": (
+        dict(s=256), (64, 64), True, None, None, 40,
+    ),
+    "window-of-one-key": (dict(s=128), (64, 64), True, None, 32, 1),
+    "window-one-tile-a-head": (
+        dict(s=128, h=2, d=64), (128, 128), True, None, 32, 50,
+    ),
+    # Laguna's two groups: 6 query heads a kv head in a full layer, 9
+    # in a sliding one
+    "window-group-9": (
+        dict(b=1, s=256, h=9, kv_heads=1), (64, 64), True, None, 32, 72,
+    ),
+    "group-6": (
+        dict(b=1, s=128, h=12, kv_heads=2), (64, 64), True, None, 32,
+    ),
+    "window-group-6-head-128": (
+        dict(b=1, s=256, h=6, kv_heads=1, d=128), (128, 128), True,
+        None, 64, 96,
+    ),
 }
 
 
@@ -127,7 +165,8 @@ WALKS = {
 def test_walk_matches_reference(walk, monkeypatch):
     """Forward and all three gradients against the plain reference,
     over the cases the loop bounds inside the kernels create."""
-    shape, (block_q, block_k), causal, budget, chunk = WALKS[walk]
+    shape, (block_q, block_k), causal, budget, chunk, *more = WALKS[walk]
+    window = more[0] if more else None
     if budget is not None:
         monkeypatch.setattr(fa, "_RESIDENT_BYTES", budget)
     if chunk is not None:
@@ -141,21 +180,24 @@ def test_walk_matches_reference(walk, monkeypatch):
 
     def flash(q, k, v):
         return flash_attention(
-            q, k, v, causal=causal, block_q=block_q, block_k=block_k
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+            window=window,
         )
 
     def ref(q, k, v):
-        return _reference(q, k, v, causal=causal)
+        return _reference(q, k, v, causal=causal, window=window)
 
     np.testing.assert_allclose(
         np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)),
         atol=2e-5, rtol=2e-5,
     )
+    # a random cotangent (all ones would weigh every row alike)
+    do = jax.random.normal(jax.random.PRNGKey(9), q.shape[:3] + v.shape[3:])
     g_flash = jax.grad(
-        lambda *a: flash(*a).sum(), argnums=(0, 1, 2)
+        lambda *a: (flash(*a) * do).sum(), argnums=(0, 1, 2)
     )(q, k, v)
     g_ref = jax.grad(
-        lambda *a: ref(*a).sum(), argnums=(0, 1, 2)
+        lambda *a: (ref(*a) * do).sum(), argnums=(0, 1, 2)
     )(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
@@ -436,6 +478,14 @@ TWO_SIZES = {
         dict(s=512, h=4, d_qk=16, d_v=48, kv_heads=2), (128, 64),
         2 * 128 * (16 + 48) * 4,
     ),
+    # a window (a fourth entry) beside two head sizes
+    "24|16-window": (
+        dict(s=256, h=2, d_qk=24, d_v=16), (64, 64), None, 100,
+    ),
+    "192|128-window-group-3": (
+        dict(s=256, h=3, d_qk=192, d_v=128, kv_heads=1), (128, 128),
+        None, 128,
+    ),
 }
 
 
@@ -444,16 +494,19 @@ def test_two_head_sizes_match_reference(case, monkeypatch):
     """``v.shape[-1] != q.shape[-1]``: the output and dv have v's
     size, dq and dk q's; forward and all three gradients against XLA
     attention, the default scale being ``d_qk ** -0.5``."""
-    shape, (block_q, block_k), budget = TWO_SIZES[case]
+    shape, (block_q, block_k), budget, *more = TWO_SIZES[case]
+    window = more[0] if more else None
     if budget is not None:
         monkeypatch.setattr(fa, "_RESIDENT_BYTES", budget)
     q, k, v, do = _rand_two_sizes(**shape)
     got = _forward_and_grads(
         lambda *a: flash_attention(
-            *a, block_q=block_q, block_k=block_k
+            *a, block_q=block_q, block_k=block_k, window=window
         ), q, k, v, do,
     )
-    want = _forward_and_grads(_reference, q, k, v, do)
+    want = _forward_and_grads(
+        lambda *a: _reference(*a, window=window), q, k, v, do
+    )
     assert got[0].shape == do.shape
     assert [g.shape for g in got[1:]] == [q.shape, k.shape, v.shape]
     for g, w in zip(got, want):
@@ -524,3 +577,174 @@ def test_k_must_have_qs_head_size():
     q, k, v, _ = _rand_two_sizes(s=64, h=2, d_qk=24, d_v=16)
     with pytest.raises(ValueError, match="head size"):
         flash_attention(q, v, v)
+
+
+# -- a window: query i sees keys (i - window, i] ------------------------------
+
+
+@pytest.mark.parametrize("window", [256, 300])
+def test_a_window_that_covers_the_sequence_is_the_plain_causal_call(
+    window,
+):
+    """``window >= seq`` hides nothing: the call IS the causal one
+    (the same program, so the same bits), forward and gradients."""
+    q, k, v, do = _rand_two_sizes(s=256, h=4, d_qk=32, d_v=32, kv_heads=2)
+    plain = _forward_and_grads(
+        lambda *a: flash_attention(*a, block_q=64, block_k=64),
+        q, k, v, do,
+    )
+    windowed = _forward_and_grads(
+        lambda *a: flash_attention(
+            *a, block_q=64, block_k=64, window=window
+        ), q, k, v, do,
+    )
+    for a, b in zip(plain, windowed):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    text = [
+        str(jax.make_jaxpr(lambda *a: flash_attention(*a, window=w))(
+            q, k, v
+        )) for w in (None, window)
+    ]
+    assert text[0] == text[1]
+
+
+def _band(seq, window):
+    ahead = np.arange(seq)[:, None] - np.arange(seq)[None, :]
+    return (ahead >= 0) & (ahead < window)
+
+
+@pytest.mark.parametrize("seq, block, window, chunk", [
+    (8192, 1024, 512, 256), (8192, 512, 512, 256), (8192, 1024, 500, 256),
+    (4096, 1024, 2048, 256), (2048, 256, 700, 256), (1024, 256, 1, 256),
+    (1024, 128, 100, 32), (2048, 512, 1536, 128), (512, 512, 200, 128),
+])
+def test_block_schedule_counts_a_windowed_walk(
+    seq, block, window, chunk, monkeypatch
+):
+    """``block_schedule(window=)`` against a brute-force count over
+    the band itself: a sub-block is visited iff the band touches it,
+    masked iff it is visited and not wholly inside, and ``computed``
+    counts the chunk-square pieces the band touches.  The kv side (dkv
+    walks q sub-blocks for a kv tile) visits the same sub-blocks and
+    computes the same pieces."""
+    monkeypatch.setattr(fa, "_CHUNK", chunk)
+    band = _band(seq, window)
+    tiles = seq // block
+
+    def pieces(size):
+        n = seq // size
+        cut = band.reshape(n, size, n, size)
+        return cut.any(axis=(1, 3)), cut.all(axis=(1, 3))
+
+    touched, inside = pieces(block)
+    got = fa.block_schedule(seq, block, block, True, window=window)
+    assert got["visited"] == touched.sum()
+    assert got["masked"] == (touched & ~inside).sum()
+    assert got["total"] == tiles * tiles
+    small = chunk if block % chunk == 0 else block
+    assert got["computed"] == pytest.approx(
+        pieces(small)[0].sum() * small * small / seq**2
+    )
+    back = fa._tiles_back(block, window)
+    for side in (False, True):
+        seen = np.zeros((tiles, tiles), bool)
+        scores = 0
+        for tile in range(tiles):
+            for away in range(back + 1):
+                other = tile + away if side else tile - away
+                if not 0 <= other < tiles:
+                    continue
+                passes = fa._window_passes(
+                    block, window, away if side else -away, side,
+                    fa._PASS_SCORES,
+                )
+                assert passes
+                seen[(other, tile) if side else (tile, other)] = True
+                scores += sum(
+                    width * (end - first)
+                    for _, width, first, end, _ in passes
+                )
+        assert np.array_equal(seen, touched)
+        assert scores == got["computed"] * seq**2
+    # 15 of the causal walk's 36 tiles at Laguna's shape
+    if (seq, block, window) == (8192, 1024, 512):
+        assert got["visited"] == 15
+        assert fa.block_schedule(seq, block, block)["visited"] == 36
+        assert got["computed"] == pytest.approx(1.5 * band.sum() / seq**2,
+                                                rel=0.01)
+    assert fa.resident_rows(seq, block, 128, 2, window=window) == block
+
+
+def test_a_window_takes_causal_square_tiles():
+    q, k, v = _rand_qkv(s=128)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=32)
+    with pytest.raises(ValueError, match="at least 1"):
+        flash_attention(q, k, v, window=0)
+    with pytest.raises(ValueError, match="square"):
+        flash_attention(q, k, v, block_q=64, block_k=32, window=32)
+    with pytest.raises(ValueError, match="square"):
+        fa.block_schedule(128, 64, 32, True, window=32)
+    # one block named: the other follows it
+    out = flash_attention(q, k, v, block_q=64, window=32)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_reference(q, k, v, window=32)),
+        atol=2e-5, rtol=2e-5,
+    )
+
+
+# sha256 of ``str(jax.make_jaxpr(...))`` of value and gradients of a
+# bf16 call at each accepted cell's attention shape (and one with a
+# group), taken at the commit BEFORE the kernels knew of a window
+# (f583308).  The jaxpr holds each ``pallas_call``'s grid, block
+# shapes, index maps and kernel body, and no source location, so equal
+# text is an equal Mosaic program.
+BEFORE_THE_WINDOW = {
+    "xl48_steady": (
+        (4, 1024, 25, 64), 25, 64, None,
+        "24844a32805f3c616aef922d62af1c31b045e17e8f3ac04e06a4d11160c60f31",
+    ),
+    "olmoe_steady_4k": (
+        (2, 4096, 16, 128), 16, 128, None,
+        "e6be4ca052eff05c148850af394658f734ecb8a6ff68ea6679fa3bff5e524a54",
+    ),
+    "olmo_hybrid_steady_8k": (
+        (1, 8192, 30, 128), 30, 128, None,
+        "bab0ad587671634c914ed4775bd4f59de66b7666534e24801d4e18a4c201fe06",
+    ),
+    "sarvam_steady_8k": (
+        (1, 8192, 16, 192), 16, 128, 0.1352,
+        "803f3b69f0a5dab6e1643876a74f19021d2babaf7528e508fa82f30845f6c6b6",
+    ),
+    "a-group-of-4": (
+        (1, 2048, 8, 128), 2, 128, None,
+        "40f920f70b9fa174266bd48e11a903d01f5fc4444b30cc8e7efcfb837ae46847",
+    ),
+}
+
+
+@pytest.mark.parametrize("cell", list(BEFORE_THE_WINDOW))
+def test_without_a_window_the_kernels_are_the_programs_they_were(
+    cell, monkeypatch
+):
+    """``window=None`` keeps every accepted cell's attention: the
+    three kernels trace, for the TPU, to the text they traced to
+    before this file knew of a window."""
+    import hashlib
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    q_shape, kv_heads, d_v, scale, before = BEFORE_THE_WINDOW[cell]
+    b, s, _, d = q_shape
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, s, kv_heads, d_v), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(
+            q, k, v, scale=scale
+        ).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))
+    )(q, k, v))
+    assert hashlib.sha256(text.encode()).hexdigest() == before

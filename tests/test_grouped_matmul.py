@@ -324,3 +324,56 @@ def test_olmoes_widths_trace_to_the_kernels_they_had(k, n):
     assert before.count("pallas_call") == 3
     assert traced((gmm.ROW_TILE, gmm.K_TILE, gmm.N_TILE)) == before
     assert traced((256, 512, 2048)) != before
+
+
+@pytest.mark.parametrize("dim, tile, fitted", [
+    # a hidden size of 3072 (Laguna) goes in halves under 2048
+    (3072, 2048, 1536), (3072, 4096, 3072), (6144, 2048, 2048),
+    # every accepted cell's dims take the tile they took
+    (4096, 2048, 2048), (4096, 4096, 4096), (2048, 2048, 2048),
+    (1024, 2048, 1024), (384, 256, 128), (96, 64, 64),
+])
+def test_a_dim_takes_its_largest_whole_lane_divisor_under_the_tile(
+    dim, tile, fitted
+):
+    assert gmm._fit_tile(dim, tile) == fitted
+
+
+@pytest.mark.parametrize("k, n", [(384, 128), (128, 384), (384, 384)])
+def test_a_dim_the_tile_does_not_divide_goes_in_fitted_tiles(k, n):
+    """384 under tiles of 256: three blocks of 128, as an output dim
+    and as the contraction, forward and both gradients against the
+    per-group loop."""
+    sizes = SIZES["ragged"]
+    tiles = (32, 256, 256)
+    layout = gmm.group_layout(
+        jnp.asarray(sizes, jnp.int32), sum(sizes), tiles[0]
+    )
+    starts = np.asarray(layout[2])
+    index = np.concatenate([
+        starts[g] + np.arange(size) for g, size in enumerate(sizes)
+    ]).astype(np.int32)
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(keys[0], (sum(sizes), k))
+    w = jax.random.normal(keys[1], (len(sizes), k, n))
+    cot = jax.random.normal(keys[2], (sum(sizes), n))
+
+    def product(x, w):
+        padded = jnp.zeros(
+            (layout[0].shape[0] * tiles[0], k), x.dtype
+        ).at[index].set(x)
+        return gmm.grouped_matmul(
+            padded, w, layout[0], layout[1], tiles
+        )[index]
+
+    np.testing.assert_allclose(
+        product(x, w), by_loop(x, w, sizes), rtol=1e-5, atol=1e-4
+    )
+    got = jax.grad(
+        lambda x, w: jnp.sum(product(x, w) * cot), argnums=(0, 1)
+    )(x, w)
+    want = jax.grad(
+        lambda x, w: jnp.sum(by_loop(x, w, sizes) * cot), argnums=(0, 1)
+    )(x, w)
+    for g, wanted in zip(got, want):
+        np.testing.assert_allclose(g, wanted, rtol=1e-5, atol=1e-4)

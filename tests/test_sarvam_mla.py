@@ -256,8 +256,8 @@ def test_yarn_frequencies_against_hand_worked_numbers():
     it over 40, pair 16 blends at (16 - 10) / 13; the softmax scale is
     192^-1/2 x (0.1 ln 40 + 1)^2."""
     cfg = SarvamMlaConfig()
-    assert yarn_correction_range(cfg) == (10, 23)
-    got = yarn_inv_freq(cfg)
+    assert yarn_correction_range(64, 10000.0, 4096, 32.0, 1.0) == (10, 23)
+    got = yarn_inv_freq(64, 10000.0, 40.0, 4096, 32.0, 1.0)
     f = 10000.0 ** (-np.arange(32) / 32.0)
     assert got[:11] == pytest.approx(f[:11], rel=1e-12)
     assert got[23:] == pytest.approx(f[23:] / 40.0, rel=1e-12)

@@ -579,6 +579,120 @@ def test_sarvam_one_dense_four_expert_step_fits_the_chip(one_chip, on_tpu):
         assert any(f"/{scope}/" in s for s in stacks.values()), scope
 
 
+@pytest.mark.parametrize("heads, window", [(72, 512), (48, None)])
+def test_flash_attention_compiles_at_lagunas_two_kinds_of_layer(
+    one_chip, on_tpu, heads, window
+):
+    """Laguna's attention in the cell: 8192 tokens, 8 kv heads of 128,
+    72 query heads under a window of 512 (a group of 9) and 48 without
+    (a group of 6): forward, dq and dkv compile within the v5e's
+    scoped VMEM, the windowed ones one tile of K and V a grid step
+    over a last grid axis of two (the own tile and the one before)."""
+    q = jax.ShapeDtypeStruct(
+        (1, 8192, heads, 128), jnp.bfloat16, sharding=one_chip
+    )
+    kv = jax.ShapeDtypeStruct(
+        (1, 8192, 8, 128), jnp.bfloat16, sharding=one_chip
+    )
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, window=window
+        ).astype(jnp.float32).sum()
+
+    compiled = jax.jit(
+        jax.grad(loss, argnums=(0, 1, 2))
+    ).lower(q, kv, kv).compile()
+    assert _kernels(compiled) == 3
+    assert fa.resident_rows(8192, 1024, 128, 2, window=window) == (
+        4096 if window is None else 1024
+    )
+    if window is not None:
+        assert fa._tiles_back(1024, window) == 1
+
+
+def test_laguna_one_dense_four_sparse_step_fits_the_chip(one_chip, on_tpu):
+    """The cell's step (``laguna_s_2_1_cut``: a full dense block, three
+    sliding sparse blocks and a full sparse one at the published
+    widths, 16 of 256 experts held, an eighth of the vocabulary, bf16
+    state, flash attention, per-block remat, 1 x 8192 tokens): state +
+    temporaries under the chip's 15.75 GB, the flash kernels under the
+    module ``attn`` inside ``swa`` or ``full_attn``, the grouped
+    matmuls (hidden 3072 in tiles of 1536) under ``moe_experts``, and
+    every scope the benchmark's readers join on in the op-name map."""
+    from dlrover_tpu.common.aot_cache import op_names
+    from dlrover_tpu.models.laguna import (
+        FULL,
+        SLIDING,
+        Laguna,
+        LagunaConfig,
+        make_laguna_loss,
+    )
+
+    model = Laguna(LagunaConfig(
+        vocab_size=12544,
+        layer_types=(FULL, SLIDING, SLIDING, SLIDING, FULL),
+        heads_per_layer=(48, 72, 72, 72, 48),
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        experts_held=(0, 16), attention_impl="flash", remat=True,
+        param_dtype=jnp.bfloat16,
+    ))
+    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
+    abs_state = jax.eval_shape(
+        lambda: TrainState.create(
+            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
+            optimizer,
+        )
+    )
+    tokens = np.zeros((1, 8192), np.int32)
+    compiled = make_train_step(
+        make_laguna_loss(model, num_chunks=8), optimizer
+    ).lower(
+        _shapes(abs_state, one_chip),
+        _shapes({"x": tokens, "y": tokens}, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    # 1.113 B parameters x 6 bytes
+    assert round(mem.argument_size_in_bytes / 1e9, 2) == 6.68
+    assert mem.temp_size_in_bytes < 4 * 2**30
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        < 15.75 * 2**30
+    )
+    text = compiled.as_text()
+    calls = re.findall(
+        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', text, re.M,
+    )
+    stacks = op_names(text)["op_names"]
+    flash = [c for c in calls if re.match(r"^%?attn(\.|$)", c)]
+    # forward, its remat copy, dq, dkv in each of five blocks: 12 in
+    # the sliding layers, 8 in the full ones
+    assert len(flash) == 4 * 5
+    assert sum("/swa/attn/" in stacks[c] for c in flash) == 4 * 3
+    assert sum("/full_attn/attn/" in stacks[c] for c in flash) == 4 * 2
+    for block, scope in enumerate(
+        ("full_attn", "swa", "swa", "swa", "full_attn")
+    ):
+        assert sum(
+            f"/block_{block}/{scope}/attn/" in stacks[c] for c in flash
+        ) == 4
+    kinds = [
+        re.sub(r"^%|\.\d+$", "", c) for c in calls if c not in flash
+    ]
+    assert {kind: kinds.count(kind) for kind in kinds} == {
+        "gmm_fwd": 3 * 2 * 4, "gmm_dlhs": 3 * 4, "gmm_drhs": 3 * 4,
+        "gmm_tokens_from_rows": 2 * 4, "gmm_unwritten": 3 * 4,
+    }
+    # no array of every assignment's row, forward or backward
+    assert not re.search(r"\[8192,10,3072\]|\[81920,3072\]", text)
+    for scope in (
+        "attn_rope", "attn_gate", "moe_router", "moe_dispatch",
+        "moe_experts", "moe_combine", "moe_shared",
+    ):
+        assert any(f"/{scope}/" in s for s in stacks.values()), scope
+
+
 def test_chunked_head_compiles_at_olmoes_shapes(one_chip):
     """The head alone at ``olmoe_steady_4k``'s shapes (2 x 4096 rows of
     2048 against a 50304-word vocabulary, bf16, 8 chunks): value and
