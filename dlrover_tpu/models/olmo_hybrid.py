@@ -203,9 +203,8 @@ class GatedDeltaNet(nn.Module):
                 beta = 2.0 * beta
             g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
         with jax.named_scope("gdn_rule"):
-            # (its own remat: the rule's temporaries live only while
-            # its backward runs, not beside the block's)
-            o, state = jax.checkpoint(gated_delta_rule)(
+            # (the kernels' custom_vjp says what the backward keeps)
+            o, state = gated_delta_rule(
                 q.reshape(b, s, heads, dk), k.reshape(b, s, heads, dk),
                 v.reshape(b, s, heads, dv), g, beta,
             )

@@ -1,7 +1,8 @@
-"""The chunk-wise gated delta rule (``ops/gated_delta_rule.py``)
-against the recurrence it stands for, token by token: outputs, the
-final state, all five gradients, and the state at every chunk
-boundary."""
+"""The chunk-wise gated delta rule (``ops/gated_delta_rule.py``: the
+``gdn_fwd`` / ``gdn_bwd`` kernels, in interpreter mode here) against
+the recurrence it stands for, token by token: outputs, the final
+state, all five gradients through the ``custom_vjp``, and the state at
+every chunk boundary."""
 
 import numpy as np
 import pytest
@@ -62,6 +63,12 @@ def relative(got, want):
 
 # a multiple of the chunk, one chunk and a bit, a ragged tail
 LENGTHS = [gdr.CHUNK, 2 * gdr.CHUNK, gdr.CHUNK + 7, 3 * gdr.CHUNK + 41]
+# the published head sizes (neither a multiple of 128), two heads and
+# a ragged length; the small sizes of the other cases
+SIZES = {
+    "8x16": dict(),
+    "96x192": dict(b=1, h=2, dk=96, dv=192),
+}
 # write strength near 1, near 2 and near 0; decay mild, none, strong
 REGIMES = {
     "plain": (0.0, 1.0),
@@ -71,10 +78,25 @@ REGIMES = {
 }
 
 
-@pytest.mark.parametrize("regime", list(REGIMES))
-@pytest.mark.parametrize("length", LENGTHS)
-def test_outputs_and_final_state_equal_the_recurrence(length, regime):
-    x = operands(length, *REGIMES[regime])
+def _cases(lengths, published):
+    """Every regime at every length at the small sizes, and the
+    published sizes at ``published`` (length, regime) pairs."""
+    return [
+        pytest.param(n, regime, "8x16", id=f"{n}-{regime}")
+        for n in lengths for regime in REGIMES
+    ] + [
+        pytest.param(n, regime, "96x192", id=f"{n}-{regime}-96x192")
+        for n, regime in published
+    ]
+
+
+@pytest.mark.parametrize("length,regime,sizes", _cases(LENGTHS, [
+    (gdr.CHUNK + 7, "plain"), (2 * gdr.CHUNK + 41, "beta2-strong"),
+]))
+def test_outputs_and_final_state_equal_the_recurrence(
+    length, regime, sizes
+):
+    x = operands(length, *REGIMES[regime], **SIZES[sizes])
     o, state = gated_delta_rule(*x)
     want_o, want_state, _ = recurrence(*x)
     assert o.shape == want_o.shape and o.dtype == jnp.float32
@@ -82,10 +104,14 @@ def test_outputs_and_final_state_equal_the_recurrence(length, regime):
     assert relative(state, want_state) < 2e-5
 
 
-@pytest.mark.parametrize("regime", list(REGIMES))
-@pytest.mark.parametrize("length", [gdr.CHUNK, 2 * gdr.CHUNK + 9])
-def test_all_five_gradients_equal_the_recurrences(length, regime):
-    x = operands(length, *REGIMES[regime])
+@pytest.mark.parametrize("length,regime,sizes", _cases(
+    [gdr.CHUNK, 2 * gdr.CHUNK + 9],
+    [(gdr.CHUNK + 9, "plain"), (gdr.CHUNK + 9, "beta2-nodecay")],
+))
+def test_all_five_gradients_equal_the_recurrences(length, regime, sizes):
+    """Through the ``custom_vjp`` (``gdn_bwd``), write strengths near
+    2 included (``beta2-*``: what breaks a Neumann product)."""
+    x = operands(length, *REGIMES[regime], **SIZES[sizes])
     weights = jax.random.normal(
         jax.random.PRNGKey(9), x[2].shape
     )
@@ -103,18 +129,90 @@ def test_all_five_gradients_equal_the_recurrences(length, regime):
         assert relative(a, b) < 5e-5, name
 
 
-def test_state_handed_over_equals_the_recurrences_at_every_boundary():
-    """The states the scan emits (what each chunk STARTS from) are the
-    recurrence's after ``CHUNK, 2 CHUNK, ..`` tokens."""
+@pytest.mark.parametrize("regime", ["plain", "beta2-strong"])
+def test_state_handed_over_equals_the_recurrences_at_every_boundary(
+    regime,
+):
+    """The states the forward saves for the backward (what each chunk
+    STARTS from: zero, then the recurrence's after ``CHUNK, 2 CHUNK,
+    ..`` tokens), float32 for float32 operands, and the final state
+    of every prefix of whole chunks."""
     chunks = 4
-    x = operands(chunks * gdr.CHUNK)
+    x = operands(chunks * gdr.CHUNK, *REGIMES[regime])
     _, final, at = recurrence(*x, every=gdr.CHUNK)
     assert at.shape[0] == chunks
+    b, _, h, dk = x[0].shape
+    (_, state), (_, starts, t) = gdr._rule_fwd(*x)
+    assert starts.dtype == t.dtype == jnp.float32
+    assert t.shape == (b * h, chunks, gdr.CHUNK, gdr.CHUNK)
+    starts = starts.reshape(b, h, chunks, dk, -1)
+    assert not np.asarray(starts[:, :, 0]).any()
+    for n in range(1, chunks):
+        assert relative(starts[:, :, n], at[n - 1]) < 2e-5, n
+    assert relative(state, at[-1]) < 2e-5
     for n in range(1, chunks + 1):
         cut = tuple(a[:, :n * gdr.CHUNK] for a in x)
         _, state = gated_delta_rule(*cut)
         assert relative(state, at[n - 1]) < 2e-5, n
     np.testing.assert_allclose(at[-1], final)
+
+
+@pytest.mark.parametrize("regime", ["plain", "beta2-strong"])
+def test_gradients_under_an_enclosing_remat_are_the_same(regime):
+    """``jax.grad`` of the rule inside a ``jax.checkpoint`` of the
+    function around it (the model's per-block remat: the forward runs
+    again, then the ``custom_vjp``'s backward) equals the same
+    without."""
+    x = operands(gdr.CHUNK + 9, *REGIMES[regime])
+    weights = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)
+
+    def block(*a):
+        o, state = gated_delta_rule(*(2.0 * y for y in a[:3]), *a[3:])
+        return jnp.sum(jnp.tanh(o) * weights) + jnp.sum(state ** 2)
+
+    want = jax.grad(block, argnums=range(5))(*x)
+    got = jax.grad(jax.checkpoint(block), argnums=range(5))(*x)
+    for name, a, b in zip(NAMES, got, want):
+        assert np.abs(np.asarray(b)).max() > 0, name
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7, err_msg=name)
+
+
+def test_state_decays_and_inverse_are_float32_in_the_kernels():
+    """With bf16 operands: the state's and ``dS``'s scratch are
+    float32, the chunk-start states and the inverse travel to the
+    backward in the operands' type (what the matmuls read), the final
+    state and the gates' gradients come out float32, and the inverse
+    of a tile is float32 whichever way its matmuls run."""
+    x = operands(gdr.CHUNK + 5, dtype=jnp.bfloat16)
+    operands_ = gdr._operands(*x)
+    assert [a.dtype for a in operands_] == [jnp.bfloat16] * 3 + [
+        jnp.float32
+    ] * 2
+    def scratch_type(jaxpr):
+        """Of the one kernel in a jitted wrapper's jaxpr."""
+        (jitted,) = jaxpr.eqns
+        (call,) = [
+            e for e in jitted.params["jaxpr"].eqns
+            if e.primitive.name == "pallas_call"
+        ]
+        return call.params["jaxpr"].invars[-1].aval.dtype
+
+    forward = jax.make_jaxpr(gdr._forward)(*operands_)
+    assert scratch_type(forward) == jnp.float32
+    o, final, starts, t = gdr._forward(*operands_)
+    assert (o.dtype, final.dtype, starts.dtype, t.dtype) == (
+        jnp.bfloat16, jnp.float32, jnp.bfloat16, jnp.bfloat16
+    )
+    grads = jax.make_jaxpr(gdr._backward)(
+        *operands_, starts, t, o, final
+    )
+    assert scratch_type(grads) == jnp.float32
+    assert [v.aval.dtype for v in grads.jaxpr.outvars] == [
+        jnp.bfloat16
+    ] * 3 + [jnp.float32] * 2
+    a = jnp.tril(jnp.full((gdr.CHUNK,) * 2, 0.25), -1)
+    for exact in (True, False):
+        assert gdr._inverse_unit_lower(a, exact).dtype == jnp.float32
 
 
 def test_padding_neither_decays_nor_writes():
@@ -140,28 +238,35 @@ def test_inverse_of_unit_lower_is_exact_where_the_series_is_not():
     c = gdr.CHUNK
     a = 2.0 * np.tril(np.ones((c, c)), -1)
     want = np.linalg.inv(np.eye(c) + a)
-    got = gdr._inverse_unit_lower(jnp.asarray(a, jnp.float32)[None])[0]
+    got = gdr._inverse_unit_lower(jnp.asarray(a, jnp.float32))
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-4)
     assert np.abs(want).max() == pytest.approx(2.0)
     assert np.abs(np.linalg.matrix_power(a, 12)).max() > 1e12
 
 
 def test_inverses_gradient_is_the_closed_form():
+    """``X = (I + A)^-1 B`` as the backward kernel differentiates it
+    (``_solve_bwd``: ``dB = T^T dX``, ``dA = -T^T (dX B^T) T^T = -dB
+    X^T`` below the diagonal) against autodiff through a solve."""
     key = jax.random.PRNGKey(1)
-    a = jnp.tril(jax.random.normal(key, (2, 16, 16)) * 0.5, -1)
-    weights = jax.random.normal(jax.random.PRNGKey(2), a.shape)
+    a = jnp.tril(jax.random.normal(key, (16, 16)) * 0.5, -1)
+    b = jax.random.normal(jax.random.PRNGKey(3), (16, 24))
+    weights = jax.random.normal(jax.random.PRNGKey(2), b.shape)
 
-    def by_solve(a):
-        eye = jnp.eye(16)
+    def by_solve(a, b):
         with jax.default_matmul_precision("highest"):
-            return jnp.sum(jnp.linalg.inv(eye + a) * weights)
+            return jnp.sum(jnp.linalg.solve(jnp.eye(16) + a, b) * weights)
 
-    got = jax.grad(
-        lambda a: jnp.sum(gdr._inverse_unit_lower(a) * weights)
-    )(a)
-    want = jnp.tril(jax.grad(by_solve)(a), -1)
-    assert relative(got, want) < 1e-5
-    assert not np.asarray(jnp.triu(got)).any()
+    t = gdr._inverse_unit_lower(a)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            t @ (jnp.eye(16) + a), jnp.eye(16), atol=1e-5
+        )
+        db, da = gdr._solve_bwd(t, t @ b, weights, True)
+    want_a, want_b = jax.grad(by_solve, argnums=(0, 1))(a, b)
+    assert relative(da, jnp.tril(want_a, -1)) < 1e-5
+    assert relative(db, want_b) < 1e-5
+    assert not np.asarray(jnp.triu(da)).any()
 
 
 @pytest.mark.parametrize("regime", ["plain", "beta2-strong"])

@@ -356,13 +356,13 @@ HYBRID_ATTN = (1, 8192, 30, 128)
 HYBRID_RULE = dict(batch=1, seq=8192, heads=30, dk=96, dv=192)
 
 
-def _rule_operands(one_chip):
+def _rule_operands(one_chip, dtype=jnp.bfloat16):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     b, t, h = (HYBRID_RULE[k] for k in ("batch", "seq", "heads"))
-    keys = s((b, t, h, HYBRID_RULE["dk"]), jnp.bfloat16)
-    values = s((b, t, h, HYBRID_RULE["dv"]), jnp.bfloat16)
+    keys = s((b, t, h, HYBRID_RULE["dk"]), dtype)
+    values = s((b, t, h, HYBRID_RULE["dv"]), dtype)
     gate = s((b, t, h), jnp.float32)
     return keys, keys, values, gate, gate
 
@@ -383,23 +383,44 @@ def test_flash_attention_compiles_at_olmo_hybrids_shape(one_chip, on_tpu):
     assert _kernels(backward) >= 3
 
 
-def test_gated_delta_rule_compiles_at_published_sizes(one_chip):
-    """The chunk-wise rule at (1, 8192, 30, 96 / 192), forward and
-    backward, for the described chip: no kernel of the repo's own in
-    it (XLA's matmuls and one ``while`` for the hand-over), which
-    emits 8192 / CHUNK states."""
+def _calls(compiled, name) -> int:
+    """The compiled program's Pallas calls whose name holds ``name``
+    (``%jvp_gdn_fwd_.1``, ``%transpose_jvp_gdn_bwd__.1``)."""
+    return len(re.findall(
+        rf"^\s*(?:ROOT )?%[\w.\-]*{name}[\w.\-]* = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', compiled.as_text(), re.M,
+    ))
+
+
+@pytest.mark.parametrize(
+    "dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"]
+)
+def test_gated_delta_rule_compiles_at_published_sizes(
+    one_chip, on_tpu, dtype
+):
+    """The rule at (1, 8192, 30, 96 / 192), forward and backward, for
+    the described chip: the forward is the ``gdn_fwd`` kernel and no
+    ``while`` (the hand-over is the kernel's chunk axis), the gradient
+    adds ``gdn_bwd``, and both stay inside Mosaic's scoped-VMEM limit
+    (no ``vmem_limit_bytes`` is asked for: a kernel over the default
+    16 MB fails to compile).  bf16 is the cell's; float32 operands
+    (every matmul at ``HIGHEST``) are the tests' exact path."""
     from dlrover_tpu.ops import gated_delta_rule as gdr
 
-    operands = _rule_operands(one_chip)
+    operands = _rule_operands(one_chip, dtype)
     forward = jax.jit(gdr.gated_delta_rule).lower(*operands).compile()
     out, state = forward.out_info
-    assert out.shape == (1, 8192, 30, 192) and out.dtype == jnp.bfloat16
+    assert out.shape == (1, 8192, 30, 192) and out.dtype == dtype
     assert state.shape == (1, 30, 96, 192) and state.dtype == jnp.float32
-    assert _kernels(forward) == 0
+    assert _calls(forward, "gdn_fwd") == _kernels(forward) == 1
     text = forward.as_text()
-    assert text.count(" while(") == 1
-    # the states the loop emits, one a chunk
-    assert f"[{8192 // gdr.CHUNK},1,30,96,192]" in text
+    assert " while(" not in text
+    # the states each chunk starts from and its inverse, for the
+    # backward, in the operands' type
+    hlo = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    chunks = f"{hlo}[30,{8192 // gdr.CHUNK}"
+    assert f"{chunks},96,192]" in text
+    assert f"{chunks},{gdr.CHUNK},{gdr.CHUNK}]" in text
 
     def loss(*a):
         return gdr.gated_delta_rule(*a)[0].astype(jnp.float32).sum()
@@ -407,9 +428,16 @@ def test_gated_delta_rule_compiles_at_published_sizes(one_chip):
     backward = jax.jit(
         jax.grad(loss, argnums=(0, 1, 2, 3, 4))
     ).lower(*operands).compile()
-    # one layer's rule and its gradient: 3.8 GB of temporaries (a
-    # 64-wide float32 minor dimension is padded to 128 lanes)
-    assert backward.memory_analysis().temp_size_in_bytes < 4.5 * 2**30
+    assert _calls(backward, "gdn_fwd") == 1
+    assert _calls(backward, "gdn_bwd") == 1
+    assert _kernels(backward) == 2
+    assert " while(" not in backward.as_text()
+    # one layer's rule and its gradient: the XLA form of PR 32 took
+    # 3.8 GB of temporaries under a bound of 4.5 GiB
+    temp = backward.memory_analysis().temp_size_in_bytes
+    print(f"gdn backward temporaries {hlo}: {temp / 2**30:.3f} GiB")
+    # 0.73 GiB in bf16, 1.47 in float32
+    assert temp < 2.0 * 2**30
 
 
 def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
@@ -417,8 +445,9 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
     published widths, the whole vocabulary, bf16 state, flash
     attention, per-block remat, 1 x 8192 tokens): state + temporaries
     under the chip's 15.75 GB, the loss head's three matmuls a chunk,
-    the four flash kernels under the module ``attn`` and none of them
-    under ``gdn``."""
+    the four flash kernels under the module ``attn``, and under each
+    linear layer's ``gdn_rule`` scope two ``gdn_fwd`` (forward, the
+    block's remat copy) and one ``gdn_bwd``."""
     from dlrover_tpu.common.aot_cache import op_names
     from dlrover_tpu.models.olmo_hybrid import (
         PERIOD,
@@ -466,11 +495,26 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
         r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
         r'"tpu_custom_call"', text, re.M,
     )
+    stacks = op_names(text)["op_names"]
+    rule = [c for c in calls if "gdn_" in c]
+    calls = [c for c in calls if c not in rule]
     # forward, its remat copy, dq, dkv: one layer of four
     assert len(calls) == 4
     assert all(re.match(r"^%?attn(\.|$)", name) for name in calls)
-    stacks = op_names(text)["op_names"]
     assert all("/block_3/attn/" in stacks[c] for c in calls)
+    # the other three: the rule's kernels, the backward's too under
+    # the scope the benchmark's readers look for
+    assert sorted(
+        (re.search(r"block_(\d)/gdn/", stacks[c]).group(1),
+         re.search(r"gdn_(fwd|bwd)", c).group(1))
+        for c in rule
+    ) == sorted(
+        (str(i), kind) for i in range(3) for kind in ("fwd", "fwd", "bwd")
+    )
+    # (bare forward, ``transpose(jvp(gdn_rule))`` backward)
+    assert all(
+        re.search(r"(?:^|[/(])gdn_rule(?:[/)]|$)", stacks[c]) for c in rule
+    )
     for scope in ("gdn_conv", "gdn_gates", "gdn_rule", "gdn_norm"):
         assert any(f"/gdn/{scope}/" in s for s in stacks.values()), scope
 
