@@ -4,6 +4,7 @@ strategy engine's dry-runner, and the benchmarks."""
 from dlrover_tpu.models.gpt import GPT, GPTConfig
 from dlrover_tpu.models.llama import Llama, LlamaConfig
 from dlrover_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
+from dlrover_tpu.models.ouro import Ouro, OuroConfig
 from dlrover_tpu.models.sarvam_mla import SarvamMla, SarvamMlaConfig
 from dlrover_tpu.models.losses import (
     chunked_cross_entropy,
@@ -17,6 +18,8 @@ __all__ = [
     "LlamaConfig",
     "OlmoHybrid",
     "OlmoHybridConfig",
+    "Ouro",
+    "OuroConfig",
     "SarvamMla",
     "SarvamMlaConfig",
     "chunked_cross_entropy",

@@ -1,26 +1,42 @@
 """Memory-efficient losses: sequence-chunked fused lm_head + CE.
 
-The classic long-context memory cliff is the logits tensor: a 32k-vocab
-Llama at batch 8 x seq 4096 materializes ``[8, 4096, 32000]`` fp32
-logits (~4.2 GB) plus the same again for the softmax backward — often
-larger than the whole transformer's activations.  (Reference frame:
-ATorch's pipeline/remat memory work targets activations; the vocab
-axis is the TPU-side analog worth the same treatment.)
+The classic long-context memory cliff is the logits tensor: a
+50304-word head at batch 2 x seq 4096 materializes ``[2, 4096, 50304]``
+fp32 logits (1.6 GB) plus the same again for the softmax backward,
+often larger than the whole transformer's activations.
 
-TPU-native fix: never build the full logits.  ``chunked_cross_entropy``
-scans over sequence chunks; each step projects one chunk through the
-head and reduces it to a scalar NLL.  Under ``jax.grad`` the same
-step also forms the chunk's two gradients while its float32 logits
-exist (``d_hidden = d_logits @ W^T``, ``d_W += h^T @ d_logits``), so
-no logits are stored and none are made a second time: three
+TPU-native fix: never build the full logits.  Both heads here scan
+over sequence chunks; each step projects one chunk through the head
+and reduces its float32 logits to cross entropies while they exist.
+Under ``jax.grad`` the same step also forms the chunk's two gradients
+(``d_hidden = d_logits @ W^T``, ``d_W += h^T @ d_logits``), so no
+logits are stored and none are made a second time: three
 vocabulary-sized matmuls a chunk, the number the algorithm requires,
-and the backward pass only scales what the forward pass left.  Peak
-logits memory drops from ``O(S * V)`` to ``O(S/num_chunks * V)``; the
-residuals are the two gradients themselves (``hidden``'s and the
-kernel's shape and dtype).
+and the backward rule only scales what the forward rule left.  Peak
+logits memory drops from ``O(S * V)`` to ``O(S/num_chunks * V)``.
 
-Works with both head layouts in this repo: Llama's untied ``lm_head``
-kernel and GPT's tied ``wte`` embedding (pass ``transpose=True``).
+Two forms, one chunk body (:func:`_chunk_nll`):
+
+``chunked_cross_entropy``
+    the mean over all rows: one scalar out, a scalar cotangent in.
+    The forward rule keeps the two gradients for a cotangent of 1
+    (``hidden``'s and the kernel's shape and dtype).  Called by the
+    GPT family through ``chunked_loss_fn`` (tied ``wte``,
+    ``transpose=True``) and by the ``olmoe``, ``olmo_hybrid``,
+    ``sarvam_mla`` and ``laguna`` losses (untied ``lm_head``): the
+    benchmark's cells ``olmoe_steady_4k``, ``olmo_hybrid_steady_8k``,
+    ``sarvam_steady_8k`` and ``laguna_steady_8k``.  The program it
+    lowers to is pinned (``tests/test_ouro.py``).
+``weighted_chunked_cross_entropy``
+    per-row weights in, ``(sum of weight x nll, the per-row nll)``
+    out: a loss that mixes several exits' cross entropies token by
+    token (``models/ouro.py``: the rows are the exits stacked, the
+    weights each token's exit distribution, and the per-row nll is
+    the gradient with respect to the weights).  ``d_logits`` is scaled
+    row by row inside the chunk; the forward rule keeps the two
+    gradients and the per-row nll.  Cell ``ouro_steady_1x4k``.
+
+Every operation of either head carries the device scope ``loss_head``.
 """
 
 import functools
@@ -30,24 +46,36 @@ import jax
 import jax.numpy as jnp
 
 
+def _split(a, num_chunks):
+    """``[n, seq, ...]`` with the scan axis leading and a chunk's rows
+    of every entry as one axis: ``[num_chunks, n * chunk, ...]``."""
+    n, s = a.shape[:2]
+    c = s // num_chunks
+    return jnp.moveaxis(
+        a.reshape((n, num_chunks, c) + a.shape[2:]), 1, 0
+    ).reshape((num_chunks, n * c) + a.shape[2:])
+
+
+def _join(chunks, n):
+    """:func:`_split` undone: ``[n, seq, ...]``."""
+    num_chunks, rows = chunks.shape[:2]
+    return jnp.moveaxis(
+        chunks.reshape((num_chunks, n, rows // n) + chunks.shape[2:]),
+        0, 1,
+    ).reshape((n, num_chunks * (rows // n)) + chunks.shape[2:])
+
+
 def _chunks(hidden, targets, num_chunks):
     """Scan axis leading, a chunk's rows of every batch entry as one
     axis: ``[num_chunks, batch * chunk, hid]``, so each of the head's
     matmuls is one plain 2-D product."""
-    b, s, h = hidden.shape
-    c = s // num_chunks
-    return (
-        hidden.reshape(b, num_chunks, c, h).transpose(1, 0, 2, 3)
-        .reshape(num_chunks, b * c, h),
-        targets.reshape(b, num_chunks, c).transpose(1, 0, 2)
-        .reshape(num_chunks, b * c),
-    )
+    return _split(hidden, num_chunks), _split(targets, num_chunks)
 
 
 def _chunk_nll(h_chunk, kernel, t_chunk, transpose):
     """One chunk's float32 logits (a product in the activation dtype,
-    accumulated in float32), their log-sum-exp pieces, and the chunk's
-    summed NLL."""
+    accumulated in float32), their log-sum-exp pieces, and each row's
+    NLL."""
     logits = jnp.einsum(
         "th,vh->tv" if transpose else "th,hv->tv", h_chunk, kernel,
         preferred_element_type=jnp.float32,
@@ -60,7 +88,7 @@ def _chunk_nll(h_chunk, kernel, t_chunk, transpose):
         == t_chunk[:, None]
     )
     picked = jnp.where(hit, logits, 0.0).sum(axis=-1)
-    nll = (jnp.log(norm[:, 0]) + top[:, 0] - picked).sum()
+    nll = jnp.log(norm[:, 0]) + top[:, 0] - picked
     return nll, exp, norm, hit
 
 
@@ -74,7 +102,7 @@ def _head(hidden, head_kernel, targets, num_chunks, transpose):
         def body(total, xs):
             h_chunk, t_chunk = xs
             nll, *_ = _chunk_nll(h_chunk, kernel, t_chunk, transpose)
-            return total + nll, None
+            return total + nll.sum(), None
 
         total, _ = jax.lax.scan(
             body, jnp.zeros((), jnp.float32),
@@ -100,6 +128,7 @@ def _head_fwd(hidden, head_kernel, targets, num_chunks, transpose):
             nll, exp, norm, hit = _chunk_nll(
                 h_chunk, kernel, t_chunk, transpose
             )
+            nll = nll.sum()
             # d(mean NLL) / d(logits), rounded once to the matmuls'
             # dtype and WRITTEN once: left to itself the TPU compiler
             # fuses this softmax into the operands of both matmuls
@@ -146,6 +175,91 @@ def _head_bwd(num_chunks, transpose, residuals, ct):
 _head.defvjp(_head_fwd, _head_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_head(hidden, head_kernel, weights, targets, num_chunks):
+    """Value only: ``(sum of weight x nll, the per-row nll [n, seq])``,
+    one matmul a chunk, no gradient formed."""
+    n = hidden.shape[0]
+    with jax.named_scope("loss_head"):
+        kernel = head_kernel.astype(hidden.dtype)
+        h_chunks, t_chunks, w_chunks = (
+            _split(a, num_chunks) for a in (hidden, targets, weights)
+        )
+
+        def body(total, xs):
+            h_chunk, t_chunk, w_chunk = xs
+            nll, *_ = _chunk_nll(h_chunk, kernel, t_chunk, False)
+            return total + (w_chunk * nll).sum(), nll
+
+        total, nll = jax.lax.scan(
+            body, jnp.zeros((), jnp.float32),
+            (h_chunks, t_chunks, w_chunks),
+        )
+        return total, _join(nll, n)
+
+
+def _weighted_head_fwd(hidden, head_kernel, weights, targets, num_chunks):
+    """The two outputs, and both gradients of the weighted sum (for a
+    cotangent of 1) formed where each chunk's logits already are:
+    :func:`_head_fwd` with ``d_logits`` scaled row by row."""
+    n = hidden.shape[0]
+    with jax.named_scope("loss_head"):
+        kernel = head_kernel.astype(hidden.dtype)
+        h_chunks, t_chunks, w_chunks = (
+            _split(a, num_chunks) for a in (hidden, targets, weights)
+        )
+
+        def body(carry, xs):
+            total, d_kernel = carry
+            h_chunk, t_chunk, w_chunk = xs
+            nll, exp, norm, hit = _chunk_nll(
+                h_chunk, kernel, t_chunk, False
+            )
+            # (written once, as in _head_fwd and for its reason)
+            d_logits = jax.lax.optimization_barrier((
+                (exp / norm - hit.astype(jnp.float32))
+                * w_chunk[:, None]
+            ).astype(hidden.dtype))
+            d_chunk = jnp.einsum("tv,hv->th", d_logits, kernel)
+            d_kernel = (
+                d_kernel.astype(jnp.float32) + jnp.einsum(
+                    "th,tv->hv", h_chunk, d_logits,
+                    preferred_element_type=jnp.float32,
+                )
+            ).astype(d_kernel.dtype)
+            return (total + (w_chunk * nll).sum(), d_kernel), (
+                d_chunk, nll
+            )
+
+        (total, d_kernel), (d_chunks, nll) = jax.lax.scan(
+            body,
+            (jnp.zeros((), jnp.float32), jnp.zeros_like(head_kernel)),
+            (h_chunks, t_chunks, w_chunks),
+        )
+        nll = _join(nll, n)
+        return (total, nll), (_join(d_chunks, n), d_kernel, nll)
+
+
+def _weighted_head_bwd(num_chunks, residuals, cts):
+    """Scales what the forward rule left by the weighted sum's
+    cotangent.  The per-row nll's own cotangent is NOT carried back to
+    ``hidden`` and the kernel (that would take the logits again):
+    :func:`weighted_chunked_cross_entropy` hands it out under
+    ``stop_gradient``."""
+    d_hidden, d_kernel, nll = residuals
+    ct, _ = cts
+    with jax.named_scope("loss_head"):
+        return (
+            d_hidden * ct.astype(d_hidden.dtype),
+            d_kernel * ct.astype(d_kernel.dtype),
+            nll * ct,
+            None,
+        )
+
+
+_weighted_head.defvjp(_weighted_head_fwd, _weighted_head_bwd)
+
+
 def chunked_cross_entropy(
     hidden: jax.Array,        # [batch, seq, hid]
     head_kernel: jax.Array,   # [hid, vocab] (or [vocab, hid] tied)
@@ -164,6 +278,11 @@ def chunked_cross_entropy(
     value, in the forward rule that forms the gradients and in the
     backward rule that scales them, carries the device scope
     ``loss_head``, as the unchunked head in models/gpt.py does.
+
+    Four of the benchmark's cells call this form, so the program it
+    lowers to is pinned: ``tests/test_ouro.py`` holds the hash of its
+    lowered value-and-gradient, and a change here that moves it has to
+    be measured in those cells.
     """
     s = hidden.shape[1]
     if s % num_chunks:
@@ -171,6 +290,36 @@ def chunked_cross_entropy(
             f"seq {s} not divisible by num_chunks {num_chunks}"
         )
     return _head(hidden, head_kernel, targets, num_chunks, transpose)
+
+
+def weighted_chunked_cross_entropy(
+    hidden: jax.Array,        # [n, seq, hid]
+    head_kernel: jax.Array,   # [hid, vocab]
+    targets: jax.Array,       # [n, seq] int
+    weights: jax.Array,       # [n, seq] float32
+    num_chunks: int = 8,
+):
+    """``(sum over rows of weight x nll, nll [n, seq])`` through one
+    chunked head, the full logits never materialized.
+
+    The first output is differentiable in ``hidden``, the kernel and
+    ``weights`` (whose gradient is the per-row nll); the second is the
+    per-row cross entropy as a VALUE (``stop_gradient``), for that
+    gradient's sake and for a caller's counters.  A caller that wants a
+    mean puts its ``1 / rows`` into the weights: with every weight
+    ``1 / (n x seq)`` the first output is
+    :func:`chunked_cross_entropy`'s.
+    """
+    s = hidden.shape[1]
+    if s % num_chunks:
+        raise ValueError(
+            f"seq {s} not divisible by num_chunks {num_chunks}"
+        )
+    total, nll = _weighted_head(
+        hidden, head_kernel, weights.astype(jnp.float32), targets,
+        num_chunks,
+    )
+    return total, jax.lax.stop_gradient(nll)
 
 
 def chunked_loss_fn(

@@ -106,11 +106,16 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # attn.window_tiles_share: the sub-blocks the sliding layers'
         # flash kernels walk over those a causal walk would
         # (models/laguna.py)
+        # loop.*: a looped model's exits (models/ouro.py): the mean
+        # exit a token is expected to leave at, the exit
+        # distribution's mean entropy, and the first and the last
+        # exit's mean cross entropy (what a further pass buys)
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
             "moe.held_tiles_share", "moe.bias_abs_max",
-            "attn.window_tiles_share"]),
+            "attn.window_tiles_share", "loop.expected_exit",
+            "loop.exit_entropy", "loop.nll_first", "loop.nll_last"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

@@ -27,6 +27,28 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 
+import pytest  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def checkout(tmp_path):
+    """A directory that stands for the checkout in a test that runs a
+    whole job through ``benchmarks/run.py``: the program and the
+    benchmark symlinked into it.  ``run.py`` fixes its compile cache
+    and its run directories at its own checkout's root
+    (``.jax_cache``, ``.bench_out``), so from here they are the
+    test's own and no entry of another tree or another test is met
+    (ROADMAP B7: four such tests read rc 1 on the driver's machine on
+    a cache an earlier tree had filled)."""
+    root = tmp_path / "checkout"
+    root.mkdir()
+    for name in ("benchmarks", "dlrover_tpu", "BENCHMARK.json"):
+        os.symlink(os.path.join(REPO, name), root / name)
+    return str(root)
+
+
 def pytest_configure(config):
     """Register the suite's custom markers (no pytest.ini in this
     repo): ``chaos`` tags fault-injection tests so they are runnable

@@ -736,7 +736,7 @@ def test_every_leaf_is_judged_by_its_own_limit(
     assert got == (1.5 if inside else float("inf"))
 
 
-def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path):
+def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path, checkout):
     """``benchmarks/run.py`` end to end on the toy configuration:
     ``tpurun`` -> the worker -> the ``has_aux`` step -> the
     reference's loss and gradients -> the readers; exit code 3 (a
@@ -744,7 +744,8 @@ def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path))
     env.pop("XLA_FLAGS", None)
     done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+        # (from a checkout of its own: conftest.py, ROADMAP B7)
+        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
          "--cells", os.path.join(REPO, "benchmarks", "rehearsal_laguna.json"),
          "--workload", "toy_laguna_steady", "--seed", "3500000007",
          "--seconds", "1", "--trace", "1"],

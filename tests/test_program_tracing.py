@@ -532,6 +532,8 @@ def launch_log(tmp_path_factory):
         DLROVER_SHARED_DIR=str(tmp / "sock"),
         DLROVER_JOB_NAME=f"launch{os.getpid()}",
         DLROVER_METRICS_FILE=str(tmp / "metrics.json"),
+        # (a cache of its own: ROADMAP B7)
+        JAX_COMPILATION_CACHE_DIR=str(tmp / "jax_cache"),
     )
     env.pop("DLROVER_TRACE_PARENT", None)
     done = subprocess.run(  # noqa: S603
@@ -670,7 +672,7 @@ def test_a_steady_step_writes_the_parents_two_events(launch_log):
     assert {e["type"] for e in between} == {"train_step", "step_phases"}
 
 
-def test_the_harness_rehearses_the_launch_readers(tmp_path):
+def test_the_harness_rehearses_the_launch_readers(tmp_path, checkout):
     """``benchmarks/run.py`` on the toy configuration with the five
     ``launch.*`` readers beside ``agent.start_s`` and
     ``cache.load_s``: each finds a value, and the set-up splits into
@@ -680,7 +682,8 @@ def test_the_harness_rehearses_the_launch_readers(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path))
     env.pop("XLA_FLAGS", None)
     done = subprocess.run(  # noqa: S603
-        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+        # (from a checkout of its own: conftest.py, ROADMAP B7)
+        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
          "--cells", os.path.join(REPO, "benchmarks",
                                  "rehearsal_launch.json"),
          "--workload", "toy_steady", "--seed", "3700000011",

@@ -1112,7 +1112,7 @@ def test_every_leaf_and_the_bias_are_judged_by_their_own_limit(
     assert got == (1.5 if inside else float("inf"))
 
 
-def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path):
+def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path, checkout):
     """``benchmarks/run.py`` end to end on the toy configuration:
     ``tpurun`` -> the worker -> the ``has_aux`` step with its
     ``state_updates`` -> the reference's loss -> the readers; exit
@@ -1120,7 +1120,8 @@ def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path))
     env.pop("XLA_FLAGS", None)
     done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+        # (from a checkout of its own: conftest.py, ROADMAP B7)
+        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
          "--cells", os.path.join(
              REPO, "benchmarks", "rehearsal_sarvam_mla.json"),
          "--workload", "toy_sarvam_mla_steady", "--seed", "3500000007",
