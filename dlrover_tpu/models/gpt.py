@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 AttentionFn = Callable[..., jax.Array]
+REMAT_POLICIES = ("full", "offload")
 
 
 @dataclass(frozen=True)
@@ -42,10 +43,12 @@ class GPTConfig:
     dtype: Any = jnp.bfloat16       # activation/compute dtype (MXU)
     param_dtype: Any = jnp.float32  # master params
     remat: bool = False
-    # remat policy: "full" recomputes everything; "offload" keeps the
-    # per-block residual checkpoints but parks them in host memory
-    # (pinned_host) between forward and backward — activation HBM
-    # drops to ~one block's working set (reference:
+    # remat policy: "full" recomputes everything but the two arrays a
+    # flash kernel's backward reads of its forward (``_remat_policy``);
+    # "offload" recomputes everything and keeps the per-block residual
+    # checkpoints, parked in host memory (pinned_host) between forward
+    # and backward: activation HBM drops to ~one block's working set
+    # (reference:
     # auto/opt_lib/selective_offloading_checkpoint.py:1).  TPU-only:
     # the cpu backend has no pinned_host placement under jit.
     remat_policy: str = "full"
@@ -73,10 +76,10 @@ class GPTConfig:
         return self.hidden_dim // self.num_heads
 
     def __post_init__(self):
-        if self.remat_policy not in ("full", "offload", "save_attn"):
+        if self.remat_policy not in REMAT_POLICIES:
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r} "
-                "(full | offload | save_attn)"
+                f"({' | '.join(REMAT_POLICIES)})"
             )
         if self.remat_policy != "full" and not self.remat:
             raise ValueError(
@@ -107,31 +110,32 @@ class GPTConfig:
 
 
 def _remat_policy(name: str):
-    """None = recompute everything (plain remat); "offload" parks
-    the named per-block residual checkpoints in pinned_host between
-    forward and backward (selective offloading checkpoint)."""
-    if name in ("full", "", None):
-        return None
-    if name == "offload":
-        import jax
+    """What a rematted block keeps, for every decoder family: the
+    flash kernel's forward results that its own backward kernels read
+    (``out`` and ``lse``, already in HBM for that backward), by the
+    names the kernel's forward rule gives them, and nothing else.
+    With XLA attention the names do not occur and everything is
+    recomputed.  "offload" keeps nothing on the device, the kernel's
+    two arrays neither (a layer's ``out`` is as many bytes as the
+    ``block_in`` it moves off the device), and parks the named
+    per-block residual checkpoints in pinned_host between forward and
+    backward (selective offloading checkpoint)."""
+    from dlrover_tpu.ops.flash_attention import RESIDUAL_NAMES
 
+    if name in ("full", "", None):
+        return jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES
+        )
+    if name == "offload":
         return jax.checkpoint_policies.save_and_offload_only_these_names(
             names_which_can_be_saved=[],
             names_which_can_be_offloaded=["block_in"],
             offload_src="device",
             offload_dst="pinned_host",
         )
-    if name == "save_attn":
-        # selective remat: keep each block's attention output
-        # ([b, s, hidden] bf16 per layer — hundreds of MB, not GB)
-        # so the backward re-runs only layernorm/MLP, never the
-        # flash-attention forward — the priciest recompute
-        import jax
-
-        return jax.checkpoint_policies.save_only_these_names(
-            "attn_out"
-        )
-    raise ValueError(f"unknown remat_policy {name!r}")
+    raise ValueError(
+        f"unknown remat_policy {name!r} ({' | '.join(REMAT_POLICIES)})"
+    )
 
 
 def xla_causal_attention(
@@ -342,13 +346,7 @@ class Block(nn.Module):
         h = nn.LayerNorm(
             epsilon=cfg.ln_eps, dtype=jnp.float32, name="ln_attn"
         )(x)
-        # named so the save_attn remat policy can keep it (the flash
-        # forward is the priciest recompute in a full-remat backward)
-        attn_out = checkpoint_name(
-            Attention(cfg, name="attn")(h.astype(cfg.dtype)),
-            "attn_out",
-        )
-        x = x + attn_out
+        x = x + Attention(cfg, name="attn")(h.astype(cfg.dtype))
         h = nn.LayerNorm(
             epsilon=cfg.ln_eps, dtype=jnp.float32, name="ln_mlp"
         )(x)

@@ -47,7 +47,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.gpt import get_attention_fn
+from dlrover_tpu.models.gpt import _remat_policy, get_attention_fn
 from dlrover_tpu.models.llama import RMSNorm, rope
 from dlrover_tpu.models.losses import weighted_chunked_cross_entropy
 from dlrover_tpu.models.sarvam_mla import DenseMLP, _dense
@@ -159,7 +159,10 @@ class Ouro(nn.Module):
         )
         block = OuroBlock
         if cfg.remat:
-            block = nn.remat(OuroBlock, prevent_cse=True)
+            block = nn.remat(
+                OuroBlock, prevent_cse=True,
+                policy=_remat_policy("full"),
+            )
         # built once, called in every pass: L blocks in the tree
         for i in range(cfg.num_layers):
             setattr(self, f"block_{i}", block(cfg))

@@ -65,11 +65,16 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128
+# the forward kernel's two results that its backward kernels read,
+# under the names a remat policy keeps them by (``out`` [b h, s, d_v],
+# ``lse`` [b h, 1, s] float32)
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 # What a grid step may keep of the operands its loop walks (K and V in
 # forward and dq, Q and dO in dkv: one of each pair is d_qk wide, the
 # other d_v), as the pipeline holds them: two buffers x rows x (d_qk +
@@ -974,11 +979,38 @@ def _flash_mha(q, k, v, scale, causal, block_q, block_k, group=1,
     return out
 
 
+def _named(x, name):
+    """``x`` under ``name`` for a remat policy, named as its BITS.
+    ``jax.checkpoint`` puts a ``reduce_precision`` behind the producer
+    of every floating-point residual it saves, against excess
+    precision in a fused producer; the TPU compiler keeps it as a pass
+    of its own over the array, every layer, also in a program whose
+    remat copy it merges with the forward (``prevent_cse=False``).  A
+    kernel's result in HBM has no excess precision, and an integer
+    residual gets no such pass.  The two bitcasts cancel where the
+    compiler may merge, and the step is then the program it was
+    without a name; behind a rematted block's barrier
+    (``prevent_cse=True``) the forward's is a copy of ``out`` (1.9 of
+    409 ms a step at Laguna's five layers, PERF.md, PR 44) and the
+    backward's fuses into its consumers."""
+    bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    return jax.lax.bitcast_convert_type(
+        checkpoint_name(jax.lax.bitcast_convert_type(x, bits), name),
+        x.dtype,
+    )
+
+
 def _flash_mha_fwd(q, k, v, scale, causal, block_q, block_k,
                    group=1, window=None):
     out, lse = _fwd(
         q, k, v, scale, causal, block_q, block_k, group, window
     )
+    # named, and the NAMED arrays are both the primal output and the
+    # residuals: a rematted block whose policy saves RESIDUAL_NAMES
+    # keeps what this forward has written for its backward, and does
+    # not run the forward kernel a second time to make them again
+    out = _named(out, RESIDUAL_NAMES[0])
+    lse = _named(lse, RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse)
 
 

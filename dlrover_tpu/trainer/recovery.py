@@ -123,7 +123,9 @@ class RecoveryProfiler:
             "import", imported - self._proc_start, end_ts=imported
         )
         if opened:
-            self.record("backend", now - imported)
+            # ended at ``now``, not when the two events before it
+            # have been written
+            self.record("backend", now - imported, end_ts=now)
 
     # -- recording ---------------------------------------------------------
 

@@ -49,6 +49,113 @@ def checkout(tmp_path):
     return str(root)
 
 
+# what ``jax.checkpoint`` binds, as the enclosing equation of a
+# rematted block's second copy; an internal name like those below
+REMAT_PRIMITIVE = "remat2"
+
+
+def jax_internal(module, name):
+    """``jax._src.<module>.<name>``.  The tests of what a rematted
+    block keeps read what jax has no public API for: a jaxpr's
+    sub-jaxprs (``core.jaxprs_in_params``), one equation's text
+    (``core.pp_eqn``), what a ``checkpoint`` saves
+    (``ad_checkpoint.saved_residuals``).  Written against jax 0.9.0:
+    where an upgrade moves one, this says so and the test does not
+    pass on something else."""
+    import importlib
+
+    try:
+        return getattr(importlib.import_module(f"jax._src.{module}"), name)
+    except (ImportError, AttributeError) as missing:
+        pytest.fail(
+            f"jax {jax.__version__} has no jax._src.{module}.{name} "
+            f"({missing}); tests/conftest.py::jax_internal was written "
+            "against jax 0.9.0.  Find its successor, then check that "
+            f"jax.checkpoint still binds {REMAT_PRIMITIVE!r} and that "
+            "test_a_saved_residual_costs_no_pass_over_it's control "
+            "still finds the reduce_precision that "
+            "ops/flash_attention.py::_named exists to avoid."
+        )
+
+
+def pallas_calls(jaxpr, under=()):
+    """``(names of the enclosing equations' primitives, equation)`` of
+    every ``pallas_call`` of a jaxpr, sub-jaxprs included."""
+    jaxprs_in_params = jax_internal("core", "jaxprs_in_params")
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield under, eqn
+        for sub in jaxprs_in_params(eqn.params):
+            yield from pallas_calls(sub, under + (eqn.primitive.name,))
+
+
+def flash_forwards(jaxpr):
+    """Where a jaxpr calls the flash FORWARD kernel: the primitives
+    round each call.  The three flash kernels carry no name; the
+    forward is the one that writes ``lse`` (float32 ``[b h, 1, s]``)
+    beside its output."""
+    return [
+        under for under, eqn in pallas_calls(jaxpr)
+        if eqn.params["name"] is None and len(eqn.outvars) == 2
+        and eqn.outvars[1].aval.shape[1] == 1
+    ]
+
+
+@pytest.fixture
+def remat_keeps_what_flash_reads(monkeypatch):
+    """``check(module, loss, params, forwards)``: ``jax.grad(loss)`` of
+    a rematted model with flash attention, whose blocks take their
+    policy from ``module._remat_policy``, calls the forward kernel
+    ``forwards`` times, never under a ``checkpoint``; with the parent's
+    policy (``None``: keep nothing) each runs a second time there, and
+    loss and every gradient leaf are the same numbers bit for bit."""
+    import numpy as np
+
+    def check(module, loss, params, forwards):
+        grad = jax.value_and_grad(loss)
+        where = flash_forwards(jax.make_jaxpr(grad)(params).jaxpr)
+        assert len(where) == forwards, where
+        assert not any(REMAT_PRIMITIVE in under for under in where), where
+        kept = jax.jit(grad)(params)
+        monkeypatch.setattr(module, "_remat_policy", lambda name: None)
+        # another function: a trace is cached by its function
+        grad = jax.value_and_grad(lambda p: loss(p))
+        where = flash_forwards(jax.make_jaxpr(grad)(params).jaxpr)
+        assert len(where) == 2 * forwards, where
+        assert sum(
+            REMAT_PRIMITIVE in under for under in where
+        ) == forwards, (where, REMAT_PRIMITIVE, jax.__version__)
+        again = jax.jit(grad)(params)
+        for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(again)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    return check
+
+
+@pytest.fixture
+def remat_with_xla_attention_is_the_parents(monkeypatch):
+    """``check(module, loss, params)``: with XLA attention the names
+    do not occur, and ``jax.grad(loss)`` is the jaxpr it is under the
+    parent's policy (``None``), the ``policy=`` parameter apart."""
+    import re
+
+    def text(loss, params):
+        # a function of its own: a trace is cached by its function
+        return re.sub(r"policy=[^\n]*", "policy=", str(
+            jax.make_jaxpr(jax.grad(lambda p: loss(p)))(params)
+        ))
+
+    def check(module, loss, params):
+        ours = text(loss, params)
+        assert "policy=" in ours and "name=flash" not in ours
+        monkeypatch.setattr(module, "_remat_policy", lambda name: None)
+        assert ours == text(loss, params)
+        assert module._remat_policy("full") is None
+
+    return check
+
+
 def pytest_configure(config):
     """Register the suite's custom markers (no pytest.ini in this
     repo): ``chaos`` tags fault-injection tests so they are runnable

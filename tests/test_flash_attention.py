@@ -2,10 +2,20 @@
 gradients vs the XLA reference attention, causal and non-causal,
 multiple block splits."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import (
+    REMAT_PRIMITIVE,
+    flash_forwards,
+    jax_internal,
+    pallas_calls,
+)
+from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.models.gpt import xla_causal_attention
 from dlrover_tpu.ops import flash_attention as fa
@@ -693,34 +703,59 @@ def test_a_window_takes_causal_square_tiles():
     )
 
 
-# sha256 of ``str(jax.make_jaxpr(...))`` of value and gradients of a
-# bf16 call at each accepted cell's attention shape (and one with a
-# group), taken at the commit BEFORE the kernels knew of a window
-# (f583308).  The jaxpr holds each ``pallas_call``'s grid, block
-# shapes, index maps and kernel body, and no source location, so equal
-# text is an equal Mosaic program.
+# sha256 over the three ``pallas_call`` equations (forward, dq, dkv) of
+# value and gradients of a bf16 call at each accepted cell's attention
+# shape (and one with a group): each equation printed on its own, so
+# that no name of the surrounding jaxpr enters, with its index maps'
+# jaxprs after it: grid, block shapes, kernel body, index maps, and no
+# source location, so equal text is an equal Mosaic program.  First
+# pinned as the whole jaxpr's text at the commit BEFORE the kernels
+# knew of a window (f583308); re-pinned on the equations at fad06df
+# (PR 44), where the whole text still read the hashes of f583308: the
+# forward rule's two ``name`` equations are in the outer jaxpr, not in
+# a kernel.
 BEFORE_THE_WINDOW = {
     "xl48_steady": (
         (4, 1024, 25, 64), 25, 64, None,
-        "24844a32805f3c616aef922d62af1c31b045e17e8f3ac04e06a4d11160c60f31",
+        "3a0ce0a08463890b2975f11fc280514408f252078bdd0ea0638107d040d27538",
     ),
     "olmoe_steady_4k": (
         (2, 4096, 16, 128), 16, 128, None,
-        "e6be4ca052eff05c148850af394658f734ecb8a6ff68ea6679fa3bff5e524a54",
+        "7ed94592c0aba16d986a84f005c517a3f22e3ed52c3fea37d61c4f92d34037b3",
     ),
     "olmo_hybrid_steady_8k": (
         (1, 8192, 30, 128), 30, 128, None,
-        "bab0ad587671634c914ed4775bd4f59de66b7666534e24801d4e18a4c201fe06",
+        "f09c01f99925dd0075f560ae8338f9990628a47ed17ef83d0a73af1be991e924",
     ),
     "sarvam_steady_8k": (
         (1, 8192, 16, 192), 16, 128, 0.1352,
-        "803f3b69f0a5dab6e1643876a74f19021d2babaf7528e508fa82f30845f6c6b6",
+        "6212aa5470cdf8125d86276c588828d317323b0ee7df6372ac69a6c8afb566c9",
     ),
     "a-group-of-4": (
         (1, 2048, 8, 128), 2, 128, None,
-        "40f920f70b9fa174266bd48e11a903d01f5fc4444b30cc8e7efcfb837ae46847",
+        "8efcad089ccf1ce9fbe6ac0eacc39bcec83ebf1c8c66989bf0e38be14002c34a",
     ),
 }
+
+
+def kernel_texts(jaxpr):
+    """Each ``pallas_call`` equation of a jaxpr as text of its own
+    (a fresh naming context: the outer jaxpr's variable names do not
+    enter), followed by its index maps."""
+    pp_eqn, context, settings = (
+        jax_internal("core", name)
+        for name in ("pp_eqn", "JaxprPpContext", "JaxprPpSettings")
+    )
+    return [
+        "\n".join([
+            str(pp_eqn(eqn, context(), settings())),
+            *(
+                str(m.index_map_jaxpr)
+                for m in eqn.params["grid_mapping"].block_mappings
+            ),
+        ])
+        for _, eqn in pallas_calls(jaxpr)
+    ]
 
 
 @pytest.mark.parametrize("cell", list(BEFORE_THE_WINDOW))
@@ -744,7 +779,125 @@ def test_without_a_window_the_kernels_are_the_programs_they_were(
             q, k, v, scale=scale
         ).astype(jnp.float32).sum()
 
-    text = str(jax.make_jaxpr(
+    texts = kernel_texts(jax.make_jaxpr(
         jax.value_and_grad(loss, argnums=(0, 1, 2))
-    )(q, k, v))
-    assert hashlib.sha256(text.encode()).hexdigest() == before
+    )(q, k, v).jaxpr)
+    assert len(texts) == 3
+    assert hashlib.sha256(
+        "\n".join(texts).encode()
+    ).hexdigest() == before
+
+
+# -- what a rematted caller keeps (PR 44) ---------------------------------------
+
+
+def _rematted_loss(policy, dtype=jnp.bfloat16):
+    """A block's worth round the kernel under ``jax.checkpoint`` as the
+    newer families wrap theirs (``prevent_cse=True``): projections in,
+    one out, so that a consumer's backward needs the kernel's output."""
+    b, s, h, d = 1, 256, 2, 64
+
+    @functools.partial(jax.checkpoint, prevent_cse=True, policy=policy)
+    def block(x, w_in, w_out):
+        q, k, v = jnp.split(
+            (x @ w_in).reshape(b, s, 3 * h, d), 3, axis=2
+        )
+        return x + flash_attention(q, k, v).reshape(b, s, h * d) @ w_out
+
+    def loss(args):
+        return block(*args).astype(jnp.float32).sum()
+
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    return loss, (
+        jax.random.normal(keys[0], (b, s, h * d), dtype),
+        jax.random.normal(keys[1], (h * d, 3 * h * d), dtype) * 0.1,
+        jax.random.normal(keys[2], (h * d, h * d), dtype) * 0.1,
+    )
+
+
+def test_a_rematted_caller_keeps_out_and_lse_and_runs_no_second_forward():
+    """Under the one remat policy the forward kernel is called once,
+    outside the ``checkpoint``; what is saved into the backward beside
+    the block's inputs is ``out`` and ``lse`` as bits and nothing
+    else; under the parent's ``policy=None`` the kernel is called
+    again inside it, and value and gradients are the same bits."""
+    from dlrover_tpu.models.gpt import _remat_policy
+
+    saved_residuals = jax_internal("ad_checkpoint", "saved_residuals")
+    loss, args = _rematted_loss(_remat_policy("full"))
+    grad = jax.value_and_grad(loss)
+    assert flash_forwards(jax.make_jaxpr(grad)(args).jaxpr) == [()]
+    saved = [
+        (str(aval), where) for aval, where in saved_residuals(loss, args)
+        if "from the argument" not in where
+    ]
+    assert sorted(aval for aval, _ in saved) == [
+        "uint16[2,256,64]", "uint32[2,1,256]"
+    ]
+    assert all(
+        f"named '{name}'" in where
+        for name, (_, where) in zip(fa.RESIDUAL_NAMES, sorted(saved))
+    )
+    parents, _ = _rematted_loss(None)
+    assert flash_forwards(
+        jax.make_jaxpr(jax.value_and_grad(parents))(args).jaxpr
+    ) == [(), (REMAT_PRIMITIVE,)]
+    for ours, theirs in zip(
+        jax.tree.leaves(jax.jit(grad)(args)),
+        jax.tree.leaves(jax.jit(jax.value_and_grad(parents))(args)),
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(ours), np.asarray(theirs)
+        )
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_a_saved_residual_costs_no_pass_over_it(dtype):
+    """``jax.checkpoint`` guards every floating-point residual it saves
+    with a ``reduce_precision`` behind its producer, on the chip a pass
+    over ``out`` and ``lse`` every layer; named as their bits they get
+    none, and a program that is not rematted has no trace of a name:
+    its lowering holds no ``reduce_precision`` either way."""
+    from dlrover_tpu.models.gpt import _remat_policy
+
+    loss, args = _rematted_loss(_remat_policy("full"), dtype)
+    text = str(jax.make_jaxpr(jax.grad(loss))(args))
+    assert "name=flash_out" in text and "name=flash_lse" in text
+    assert "reduce_precision" not in text
+    q, k, v = _rand_qkv(b=1, s=128, h=2, d=32, dtype=dtype)
+    plain = jax.jit(jax.grad(
+        lambda q: flash_attention(q, k, v).astype(jnp.float32).sum()
+    )).lower(q).as_text()
+    assert "reduce_precision" not in plain and "flash_out" not in plain
+
+    # the control: the same name on the number itself gets the pass
+    # (where a jax upgrade drops it, ``_named``'s bitcasts can go)
+    @functools.partial(
+        jax.checkpoint, prevent_cse=True, policy=_remat_policy("full")
+    )
+    def plainly(x):
+        return jnp.sin(checkpoint_name(jnp.cos(x), fa.RESIDUAL_NAMES[0]))
+
+    assert "reduce_precision" in str(jax.make_jaxpr(
+        jax.grad(lambda x: plainly(x).astype(jnp.float32).sum())
+    )(jnp.ones(8, dtype)))
+
+
+@pytest.mark.parametrize(
+    "policy, refused", [("full", None), ("offload", None),
+                        ("save_attn", "full | offload")],
+)
+def test_the_remat_policies_are_full_and_offload(policy, refused):
+    """``save_attn`` is gone: what it promised is what ``full`` does."""
+    from dlrover_tpu.models.gpt import GPTConfig, _remat_policy
+
+    if refused is None:
+        assert GPTConfig.tiny(
+            remat=True, remat_policy=policy
+        ).remat_policy == policy
+        assert callable(_remat_policy(policy))
+        return
+    with pytest.raises(ValueError, match=re.escape(refused)):
+        GPTConfig.tiny(remat=True, remat_policy=policy)
+    with pytest.raises(ValueError, match=re.escape(refused)):
+        _remat_policy(policy)

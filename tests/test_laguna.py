@@ -128,6 +128,28 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
         assert relative(g, w) < 1e-4, name
 
 
+@pytest.mark.parametrize("attention", ["flash", "xla"])
+def test_a_rematted_block_keeps_what_its_flash_backward_reads(
+    attention, remat_keeps_what_flash_reads,
+    remat_with_xla_attention_is_the_parents,
+):
+    """One forward kernel a layer (a full one, two sliding), none of
+    them run again for the backward; loss and gradients the parent
+    policy's bit for bit.  With XLA attention nothing is named and the
+    program is the parent's."""
+    _, cfg, _, loss_fn, params, batch = toy(attention=attention, remat=True)
+
+    def loss(p):
+        return loss_fn(p, batch)[0]
+
+    if attention == "xla":
+        remat_with_xla_attention_is_the_parents(laguna, loss, params)
+    else:
+        remat_keeps_what_flash_reads(
+            laguna, loss, params, len(cfg["layer_types"])
+        )
+
+
 def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
     _, cfg, _, loss_fn, params, batch = toy(
         param_dtype="bfloat16", compute_dtype="bfloat16",
@@ -513,13 +535,20 @@ class TracedRun:
             "%gmm_fwd.6": {"seconds": 0.5, "count": 5, "target": call},
         }} if traced else None
         stack = "jit(step)/jvp(Laguna)/block_{}/{}/attn/{}"
+        # a backward kernel's: under the block's ``checkpoint`` and,
+        # since the block keeps the forward's results (PR 44), none
+        # under ``rematted_computation``
+        back = (
+            "jit(step)/transpose(jvp(Laguna))/jvp(Laguna)/checkpoint/"
+            "block_{}/{}/attn/pallas_call"
+        )
         with open(os.path.join(directory, "k.opnames.json"), "w") as f:
             import json
 
             json.dump({"op_names": {
                 "%attn.1": stack.format(1, "swa", "pallas_call"),
-                "%attn.2": stack.format(2, "swa", "pallas_call"),
-                "%attn.3": stack.format(0, "full_attn", "pallas_call"),
+                "%attn.2": back.format(1, "swa"),
+                "%attn.3": back.format(0, "full_attn"),
                 "%fusion.4": stack.format(1, "swa", "attn_gate/mul"),
                 "%fusion.5": stack.format(0, "full_attn", "attn_rope/cos"),
                 "%gmm_fwd.6": "jit(step)/block_1/moe/moe_experts/gmm",
@@ -572,6 +601,35 @@ def test_a_reader_reads_its_scope_and_is_silent_without_it(name, tmp_path):
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     assert entry["workloads"] == ["laguna_steady_8k"]
     assert entry["layer"] == "window attention"
+    assert (reader.NAME, reader.UNIT, reader.LAYER, reader.MOVES,
+            reader.SOURCE) == tuple(
+        entry[k] for k in ("name", "unit", "layer", "moves", "source")
+    )
+
+
+def test_flash_calls_a_step_are_counted_from_the_trace(tmp_path):
+    """``kernel.flash_calls_per_step`` (PR 44): the flash kernels'
+    executions over the traced steps, the grouped matmuls' left out:
+    3 a layer when no forward runs twice.  Silent without a trace or
+    without a flash kernel in it; no ``workloads`` list (every cell
+    calls the kernels)."""
+    import json
+
+    cut = loader.load_json(os.path.join(CONFIGS, "laguna_s_2_1_cut.json"))
+    reader = loader.load_module("layer_metrics", "kernel.flash_calls_per_step")
+    run = TracedRun(str(tmp_path), cut)
+    assert reader.read(run) == 3.0
+    run.trace["ops"]["%attn.7"] = {
+        "seconds": 0.010, "count": 5, "target": "tpu_custom_call",
+    }
+    assert reader.read(run) == 4.0
+    assert reader.read(TracedRun(str(tmp_path), cut, traced=False)) is None
+    run.trace["ops"] = {"%gmm_fwd.6": run.trace["ops"]["%gmm_fwd.6"]}
+    assert reader.read(run) is None
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert "workloads" not in entry and entry["better"] == "lower"
     assert (reader.NAME, reader.UNIT, reader.LAYER, reader.MOVES,
             reader.SOURCE) == tuple(
         entry[k] for k in ("name", "unit", "layer", "moves", "source")

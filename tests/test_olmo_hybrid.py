@@ -114,6 +114,29 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
         assert relative(g, w) < 1e-4, jax.tree_util.keystr(path)
 
 
+@pytest.mark.parametrize("attention", ["flash", "xla"])
+def test_a_rematted_block_keeps_what_its_flash_backward_reads(
+    attention, remat_keeps_what_flash_reads,
+    remat_with_xla_attention_is_the_parents,
+):
+    """One forward kernel in the period's one full-attention layer, not
+    run again for the backward; loss and gradients the parent policy's
+    bit for bit (the linear layers' rule keeps what it kept).  With XLA
+    attention nothing is named and the program is the parent's."""
+    from dlrover_tpu.models import olmo_hybrid
+
+    model, params, batch = toy(remat=True, attention_impl=attention)
+    loss_fn = make_olmo_hybrid_loss(model, num_chunks=5)
+
+    def loss(p):
+        return loss_fn(p, batch)[0]
+
+    if attention == "xla":
+        remat_with_xla_attention_is_the_parents(olmo_hybrid, loss, params)
+    else:
+        remat_keeps_what_flash_reads(olmo_hybrid, loss, params, 1)
+
+
 def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
     """bf16 compute (float32 accumulation, norms, decays, the rule's
     state and inverse, loss) on bf16-rounded weights against the

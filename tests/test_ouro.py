@@ -143,6 +143,31 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
         assert relative(g, w) < 1e-4, name
 
 
+@pytest.mark.parametrize("attention", ["flash", "xla"])
+def test_a_rematted_block_keeps_what_its_flash_backward_reads(
+    attention, remat_keeps_what_flash_reads,
+    remat_with_xla_attention_is_the_parents,
+):
+    """One forward kernel an application (two in the body of the scan
+    over the passes), none of them run again for the backward; loss
+    and gradients the parent policy's bit for bit.  With XLA attention
+    nothing is named and the program is the parent's.  The block takes
+    its policy where the other families take theirs."""
+    from dlrover_tpu.models import ouro
+
+    _, cfg, _, loss_fn, params, batch = toy(attention=attention, remat=True)
+
+    def loss(p):
+        return loss_fn(p, batch)[0]
+
+    if attention == "xla":
+        remat_with_xla_attention_is_the_parents(ouro, loss, params)
+    else:
+        remat_keeps_what_flash_reads(
+            ouro, loss, params, cfg["num_hidden_layers"]
+        )
+
+
 def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
     _, cfg, _, loss_fn, params, batch = toy(
         param_dtype="bfloat16", compute_dtype="bfloat16",
@@ -605,10 +630,15 @@ class TracedRun:
         ops = {
             "%fusion.1": (0.100, "jvp(Ouro)/while/body/ut/checkpoint/"
                           "block_0/mlp"),
-            "%attn.2": (0.050, "transpose(jvp(Ouro))/while/body/ut/"
-                        "rematted_computation/block_0/attn/pallas_call"),
-            "%fusion.3": (0.050, "transpose(jvp(Ouro))/while/body/ut/"
-                          "block_0/mlp"),
+            # a block's remat copy holds no kernel since it keeps the
+            # forward's ``out`` and ``lse`` (PR 44): projections, norms
+            "%fusion.2": (0.050, "transpose(jvp(Ouro))/while/body/ut/"
+                          "checkpoint/rematted_computation/block_0/"
+                          "attn/q_proj/dot_general"),
+            "%attn.3": (0.020, "transpose(jvp(Ouro))/while/body/ut/"
+                        "checkpoint/block_0/attn/pallas_call"),
+            "%fusion.3": (0.030, "transpose(jvp(Ouro))/while/body/ut/"
+                          "checkpoint/block_0/mlp"),
             "%fusion.4": (0.150, "jvp(Ouro)/while/body/ut/checkpoint/"
                           "block_1/mlp"),
             "%fusion.5": (0.100, "transpose(jvp(Ouro))/while/body/ut/"

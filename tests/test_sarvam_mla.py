@@ -173,6 +173,31 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
         assert relative(g, w) < 1e-4, name
 
 
+@pytest.mark.parametrize("attention", ["flash", "xla"])
+def test_a_rematted_block_keeps_what_its_flash_backward_reads(
+    attention, remat_keeps_what_flash_reads,
+    remat_with_xla_attention_is_the_parents,
+):
+    """One forward kernel a layer (heads of 24 | 16), none of them run
+    again for the backward; loss and gradients the parent policy's bit
+    for bit.  With XLA attention nothing is named and the program is
+    the parent's."""
+    from dlrover_tpu.models import sarvam_mla
+
+    model, params, batch = toy(remat=True, attention_impl=attention)
+    loss_fn = make_sarvam_mla_loss(model, num_chunks=4)
+
+    def loss(p):
+        return loss_fn(p, batch)[0]
+
+    if attention == "xla":
+        remat_with_xla_attention_is_the_parents(sarvam_mla, loss, params)
+    else:
+        remat_keeps_what_flash_reads(
+            sarvam_mla, loss, params, CFG["num_hidden_layers"]
+        )
+
+
 def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
     """bf16 compute (float32 accumulation, norms, router and loss) on
     bf16-rounded weights against the float32 reference on the SAME
