@@ -596,6 +596,30 @@ class _GuardedCall:
             return self._fallback(*args, **kwargs)
 
 
+# XLA orders a WHOLE module by one of three schedulers (list, dfs,
+# post order), whichever ESTIMATES the least peak memory.  The
+# estimate is no buffer assignment (what a ``while`` carries counts in
+# the caller and again in the body; no fragmentation), and dfs and
+# post order leave every weight gradient of a scan's body to the
+# body's end, each holding its block's activations until then.  Every
+# step of the benchmark is ordered by list (OLMoE's by 1.4% of the
+# estimate) but Ouro's, which lost the draw when its passes' scans
+# carried 2.4 GB more: 9.63 GB reserved for 6.99, 27 ms a step
+# (PERF.md section 6, PR 45).  So the order is asked for by name.
+COMPILER_OPTIONS = {"xla_memory_scheduler": "list"}
+
+
+def compile_lowered(lowered: Any) -> Any:
+    """``lowered.compile()`` under :data:`COMPILER_OPTIONS`; a backend
+    that has no such option (the CPU's) compiles as it would."""
+    try:
+        return lowered.compile(compiler_options=COMPILER_OPTIONS)
+    except Exception as e:  # noqa: BLE001 - jax's runtime error type
+        if "No such compile option" not in str(e):
+            raise
+        return lowered.compile()
+
+
 def resolve_step(
     fn: Any,
     example_args,
@@ -661,7 +685,7 @@ def resolve_step(
         )
     try:
         t0 = time.perf_counter()
-        compiled = fn.lower(*example_args).compile()
+        compiled = compile_lowered(fn.lower(*example_args))
         trace_s = time.perf_counter() - t0
     except Exception as e:  # noqa: BLE001 - abstract lowering failed
         return Resolution(

@@ -43,8 +43,8 @@ class GPTConfig:
     dtype: Any = jnp.bfloat16       # activation/compute dtype (MXU)
     param_dtype: Any = jnp.float32  # master params
     remat: bool = False
-    # remat policy: "full" recomputes everything but the two arrays a
-    # flash kernel's backward reads of its forward (``_remat_policy``);
+    # remat policy: "full" recomputes everything but the five arrays a
+    # flash kernel's backward reads (``_remat_policy``);
     # "offload" recomputes everything and keeps the per-block residual
     # checkpoints, parked in host memory (pinned_host) between forward
     # and backward: activation HBM drops to ~one block's working set
@@ -111,15 +111,18 @@ class GPTConfig:
 
 def _remat_policy(name: str):
     """What a rematted block keeps, for every decoder family: the
-    flash kernel's forward results that its own backward kernels read
-    (``out`` and ``lse``, already in HBM for that backward), by the
-    names the kernel's forward rule gives them, and nothing else.
-    With XLA attention the names do not occur and everything is
-    recomputed.  "offload" keeps nothing on the device, the kernel's
-    two arrays neither (a layer's ``out`` is as many bytes as the
-    ``block_in`` it moves off the device), and parks the named
-    per-block residual checkpoints in pinned_host between forward and
-    backward (selective offloading checkpoint)."""
+    five arrays its flash kernel's backward kernels read (``q``, ``k``
+    and ``v`` as the kernel took them, ``out`` and ``lse`` as it wrote
+    them: all in HBM for the forward already), by the names the
+    kernel's forward rule gives them, and nothing else: the backward
+    runs neither the forward kernel again nor the projections, RoPE
+    and layouts that only feed it.  With XLA attention the names do
+    not occur and everything is recomputed.  "offload" keeps nothing
+    on the device, the kernel's five arrays neither (a layer's ``out``
+    alone is as many bytes as the ``block_in`` it moves off the
+    device), and parks the named per-block residual checkpoints in
+    pinned_host between forward and backward (selective offloading
+    checkpoint)."""
     from dlrover_tpu.ops.flash_attention import RESIDUAL_NAMES
 
     if name in ("full", "", None):

@@ -56,6 +56,14 @@ the accumulator live in VMEM scratch, the statistics lane-dense
 ``[1, seq]`` rows they travel as by 128 x 128 transposes, once a grid
 step.  ``block_schedule`` counts the walk.
 
+What a rematted caller keeps.  The backward kernels read the five
+arrays the forward rule hands them: ``q``, ``k``, ``v`` as folded,
+``out`` and ``lse``.  The rule names all five (``RESIDUAL_NAMES``),
+and a ``jax.checkpoint`` whose policy saves those names
+(``models/gpt.py::_remat_policy``) runs in its backward neither the
+forward kernel again nor anything that stands before it only to feed
+it.
+
 On CPU (tests / virtual mesh) the kernel runs in interpreter mode.
 """
 
@@ -71,10 +79,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128
-# the forward kernel's two results that its backward kernels read,
-# under the names a remat policy keeps them by (``out`` [b h, s, d_v],
-# ``lse`` [b h, 1, s] float32)
-RESIDUAL_NAMES = ("flash_out", "flash_lse")
+# the five arrays the backward kernels read, under the names a remat
+# policy keeps them by, in the order of the forward rule's residuals:
+# the operands as the kernels take them (``q`` [b h, s, d_qk], ``k``
+# [b kv_heads, s, d_qk], ``v`` [b kv_heads, s, d_v]: a grouped call
+# keeps no repeated array) and the forward kernel's two results
+# (``out`` [b h, s, d_v], ``lse`` [b h, 1, s] float32)
+RESIDUAL_NAMES = (
+    "flash_q", "flash_k", "flash_v", "flash_out", "flash_lse"
+)
 # What a grid step may keep of the operands its loop walks (K and V in
 # forward and dq, Q and dO in dkv: one of each pair is d_qk wide, the
 # other d_v), as the pipeline holds them: two buffers x rows x (d_qk +
@@ -980,19 +993,20 @@ def _flash_mha(q, k, v, scale, causal, block_q, block_k, group=1,
 
 
 def _named(x, name):
-    """``x`` under ``name`` for a remat policy, named as its BITS.
-    ``jax.checkpoint`` puts a ``reduce_precision`` behind the producer
-    of every floating-point residual it saves, against excess
-    precision in a fused producer; the TPU compiler keeps it as a pass
-    of its own over the array, every layer, also in a program whose
-    remat copy it merges with the forward (``prevent_cse=False``).  A
-    kernel's result in HBM has no excess precision, and an integer
-    residual gets no such pass.  The two bitcasts cancel where the
-    compiler may merge, and the step is then the program it was
-    without a name; behind a rematted block's barrier
-    (``prevent_cse=True``) the forward's is a copy of ``out`` (1.9 of
-    409 ms a step at Laguna's five layers, PERF.md, PR 44) and the
-    backward's fuses into its consumers."""
+    """``x`` under ``name`` for a remat policy, named as its BITS: for
+    an array that the forward goes on to read.  ``jax.checkpoint``
+    puts a ``reduce_precision`` behind the producer of every
+    floating-point residual it saves that the forward also USES,
+    against excess precision in a fused producer; the TPU compiler
+    keeps it as a pass of its own over the array, every layer, also in
+    a program whose remat copy it merges with the forward
+    (``prevent_cse=False``).  A kernel's result in HBM has no excess
+    precision, and an integer residual gets no such pass.  The two
+    bitcasts cancel where the compiler may merge, and the step is then
+    the program it was without a name; behind a rematted block's
+    barrier (``prevent_cse=True``) the forward's is a copy of ``out``
+    (1.9 of 409 ms a step at Laguna's five layers, PERF.md, PR 44) and
+    the backward's fuses into its consumers."""
     bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
     return jax.lax.bitcast_convert_type(
         checkpoint_name(jax.lax.bitcast_convert_type(x, bits), name),
@@ -1002,15 +1016,22 @@ def _named(x, name):
 
 def _flash_mha_fwd(q, k, v, scale, causal, block_q, block_k,
                    group=1, window=None):
+    # q, k and v are named on arrays only the residuals hold: no
+    # ``reduce_precision``, so as the numbers they are (``_named``;
+    # ``test_a_saved_residual_costs_no_pass_over_it`` holds jax to it)
     out, lse = _fwd(
         q, k, v, scale, causal, block_q, block_k, group, window
     )
-    # named, and the NAMED arrays are both the primal output and the
-    # residuals: a rematted block whose policy saves RESIDUAL_NAMES
-    # keeps what this forward has written for its backward, and does
-    # not run the forward kernel a second time to make them again
-    out = _named(out, RESIDUAL_NAMES[0])
-    lse = _named(lse, RESIDUAL_NAMES[1])
+    q_name, k_name, v_name, out_name, lse_name = RESIDUAL_NAMES
+    q, k, v = (
+        checkpoint_name(x, name)
+        for x, name in ((q, q_name), (k, k_name), (v, v_name))
+    )
+    # ``out`` goes on into the block, so it is named as bits (``lse``
+    # as PR 44 left it), and the NAMED arrays are both the primal
+    # output and the residuals
+    out = _named(out, out_name)
+    lse = _named(lse, lse_name)
     return out, (q, k, v, out, lse)
 
 

@@ -78,16 +78,25 @@ def jax_internal(module, name):
         )
 
 
-def pallas_calls(jaxpr, under=()):
+def equations(jaxpr, under=()):
     """``(names of the enclosing equations' primitives, equation)`` of
-    every ``pallas_call`` of a jaxpr, sub-jaxprs included."""
+    every equation of a jaxpr, sub-jaxprs included; a ``pallas_call``
+    is one equation (a kernel's body is not the program's)."""
     jaxprs_in_params = jax_internal("core", "jaxprs_in_params")
 
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield under, eqn
-        for sub in jaxprs_in_params(eqn.params):
-            yield from pallas_calls(sub, under + (eqn.primitive.name,))
+        yield under, eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jaxprs_in_params(eqn.params):
+                yield from equations(sub, under + (eqn.primitive.name,))
+
+
+def pallas_calls(jaxpr):
+    """``(under, equation)`` of every ``pallas_call`` of a jaxpr."""
+    return (
+        (under, eqn) for under, eqn in equations(jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    )
 
 
 def flash_forwards(jaxpr):

@@ -296,6 +296,44 @@ def test_guarded_call_falls_back_on_first_failure():
     assert guarded(3) == "ok"
 
 
+class _Lowered:
+    """A ``Lowered`` that refuses the options with ``error``."""
+
+    def __init__(self, error):
+        self.error, self.asked = error, []
+
+    def compile(self, compiler_options=None):
+        self.asked.append(compiler_options)
+        if compiler_options:
+            raise RuntimeError(self.error)
+        return "compiled"
+
+
+@pytest.mark.parametrize("case", ["cpu", "unknown_option", "other_error"])
+def test_compile_lowered_asks_for_the_list_order_where_it_can(case):
+    """A step is compiled under ``COMPILER_OPTIONS``; a backend that
+    has no such option compiles as it would (the CPU's, for real, and
+    any that says so), and every other failure is the caller's."""
+    assert aot_cache.COMPILER_OPTIONS == {"xla_memory_scheduler": "list"}
+    if case == "cpu":
+        lowered = jax.jit(lambda x: x + 1).lower(jnp.ones(3))
+        out = aot_cache.compile_lowered(lowered)(jnp.ones(3))
+        np.testing.assert_array_equal(np.asarray(out), 2.0)
+        return
+    unknown = case == "unknown_option"
+    lowered = _Lowered(
+        "INVALID_ARGUMENT: No such compile option: 'xla_memory_scheduler'"
+        if unknown else "RESOURCE_EXHAUSTED: out of memory"
+    )
+    if unknown:
+        assert aot_cache.compile_lowered(lowered) == "compiled"
+        assert lowered.asked == [aot_cache.COMPILER_OPTIONS, None]
+    else:
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            aot_cache.compile_lowered(lowered)
+        assert lowered.asked == [aot_cache.COMPILER_OPTIONS]
+
+
 def test_preload_serves_entries_from_memory(tmp_path):
     """preload_entries + file deletion: the executable still loads —
     this is exactly what a forked worker inherits from the template

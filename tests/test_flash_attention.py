@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 from conftest import (
     REMAT_PRIMITIVE,
+    equations,
     flash_forwards,
     jax_internal,
     pallas_calls,
@@ -788,19 +789,22 @@ def test_without_a_window_the_kernels_are_the_programs_they_were(
     ).hexdigest() == before
 
 
-# -- what a rematted caller keeps (PR 44) ---------------------------------------
+# -- what a rematted caller keeps (PR 44, PR 45) ----------------------------
 
 
-def _rematted_loss(policy, dtype=jnp.bfloat16):
+def _rematted_loss(policy, dtype=jnp.bfloat16, kv_heads=2):
     """A block's worth round the kernel under ``jax.checkpoint`` as the
-    newer families wrap theirs (``prevent_cse=True``): projections in,
-    one out, so that a consumer's backward needs the kernel's output."""
+    newer families wrap theirs (``prevent_cse=True``): one projection
+    in (2 query heads, ``kv_heads`` for k and v), one out, so that a
+    consumer's backward needs the kernel's output."""
     b, s, h, d = 1, 256, 2, 64
+    width = (h + 2 * kv_heads) * d
 
     @functools.partial(jax.checkpoint, prevent_cse=True, policy=policy)
     def block(x, w_in, w_out):
         q, k, v = jnp.split(
-            (x @ w_in).reshape(b, s, 3 * h, d), 3, axis=2
+            (x @ w_in).reshape(b, s, h + 2 * kv_heads, d),
+            [h, h + kv_heads], axis=2,
         )
         return x + flash_attention(q, k, v).reshape(b, s, h * d) @ w_out
 
@@ -810,38 +814,65 @@ def _rematted_loss(policy, dtype=jnp.bfloat16):
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     return loss, (
         jax.random.normal(keys[0], (b, s, h * d), dtype),
-        jax.random.normal(keys[1], (h * d, 3 * h * d), dtype) * 0.1,
+        jax.random.normal(keys[1], (h * d, width), dtype) * 0.1,
         jax.random.normal(keys[2], (h * d, h * d), dtype) * 0.1,
     )
 
 
-def test_a_rematted_caller_keeps_out_and_lse_and_runs_no_second_forward():
+def _projections_in(jaxpr):
+    """Where a jaxpr of ``_rematted_loss`` computes ``x @ w_in``: the
+    primitives round each ``dot_general`` that writes ``[b, s,
+    width]`` (no gradient's matmul has that shape)."""
+    return [
+        under for under, eqn in equations(jaxpr)
+        if eqn.primitive.name == "dot_general"
+        and len(eqn.outvars[0].aval.shape) == 3
+        and eqn.outvars[0].aval.shape[2] > 128
+    ]
+
+
+@pytest.mark.parametrize("dtype, bits, kv_heads", [
+    (jnp.bfloat16, "uint16", 2),
+    (jnp.bfloat16, "uint16", 1),  # a group of 2
+    (jnp.float32, "uint32", 1),
+], ids=["one-kv-head-a-query-head", "grouped-kv-heads", "grouped-float32"])
+def test_a_rematted_caller_keeps_the_five_residuals_and_runs_nothing_twice(
+    dtype, bits, kv_heads
+):
     """Under the one remat policy the forward kernel is called once,
-    outside the ``checkpoint``; what is saved into the backward beside
-    the block's inputs is ``out`` and ``lse`` as bits and nothing
-    else; under the parent's ``policy=None`` the kernel is called
-    again inside it, and value and gradients are the same bits."""
+    outside the ``checkpoint``, and so is the projection that feeds
+    it; what is saved into the backward beside the block's inputs is
+    q, k and v as the kernel took them (k and v at the kv heads'
+    count: no repeated array), ``out`` and ``lse`` as bits, each
+    under its name, and nothing else; under the parent's
+    ``policy=None`` kernel and projection run again inside it, and
+    value and gradients are the same bits."""
     from dlrover_tpu.models.gpt import _remat_policy
 
     saved_residuals = jax_internal("ad_checkpoint", "saved_residuals")
-    loss, args = _rematted_loss(_remat_policy("full"))
+    loss, args = _rematted_loss(_remat_policy("full"), dtype, kv_heads)
     grad = jax.value_and_grad(loss)
-    assert flash_forwards(jax.make_jaxpr(grad)(args).jaxpr) == [()]
-    saved = [
-        (str(aval), where) for aval, where in saved_residuals(loss, args)
+    jaxpr = jax.make_jaxpr(grad)(args).jaxpr
+    assert flash_forwards(jaxpr) == [()]
+    assert _projections_in(jaxpr) == [()]
+    kept = [
+        (where, str(aval)) for aval, where in saved_residuals(loss, args)
         if "from the argument" not in where
     ]
-    assert sorted(aval for aval, _ in saved) == [
-        "uint16[2,256,64]", "uint32[2,1,256]"
-    ]
-    assert all(
-        f"named '{name}'" in where
-        for name, (_, where) in zip(fa.RESIDUAL_NAMES, sorted(saved))
-    )
-    parents, _ = _rematted_loss(None)
-    assert flash_forwards(
-        jax.make_jaxpr(jax.value_and_grad(parents))(args).jaxpr
-    ) == [(), (REMAT_PRIMITIVE,)]
+    saved = {where.split("'")[1]: aval for where, aval in kept}
+    number = jnp.dtype(dtype).name
+    assert saved == dict(zip(fa.RESIDUAL_NAMES, (
+        f"{number}[2,256,64]",
+        f"{number}[{kv_heads},256,64]",
+        f"{number}[{kv_heads},256,64]",
+        f"{bits}[2,256,64]",
+        "uint32[2,1,256]",
+    )))
+    assert len(saved) == len(kept)
+    parents, _ = _rematted_loss(None, dtype, kv_heads)
+    theirs = jax.make_jaxpr(jax.value_and_grad(parents))(args).jaxpr
+    assert flash_forwards(theirs) == [(), (REMAT_PRIMITIVE,)]
+    assert _projections_in(theirs) == [(), (REMAT_PRIMITIVE,)]
     for ours, theirs in zip(
         jax.tree.leaves(jax.jit(grad)(args)),
         jax.tree.leaves(jax.jit(jax.value_and_grad(parents))(args)),
@@ -851,32 +882,40 @@ def test_a_rematted_caller_keeps_out_and_lse_and_runs_no_second_forward():
         )
 
 
+@pytest.mark.parametrize("kv_heads", [2, 1])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_a_saved_residual_costs_no_pass_over_it(dtype):
-    """``jax.checkpoint`` guards every floating-point residual it saves
-    with a ``reduce_precision`` behind its producer, on the chip a pass
-    over ``out`` and ``lse`` every layer; named as their bits they get
-    none, and a program that is not rematted has no trace of a name:
-    its lowering holds no ``reduce_precision`` either way."""
+def test_a_saved_residual_costs_no_pass_over_it(dtype, kv_heads):
+    """``jax.checkpoint`` guards a floating-point residual it saves
+    with a ``reduce_precision`` behind its producer where the forward
+    goes on to read it, on the chip a pass over the array every layer.
+    ``out`` and ``lse`` are named as their bits and get none; q, k and
+    v are named on arrays that only the residuals hold (the kernel
+    reads the unnamed ones) and get none as the numbers they are (no
+    bitcast either: the test above reads their types).  A program
+    that is not rematted has no trace of a name: its lowering holds
+    no ``reduce_precision`` either way."""
     from dlrover_tpu.models.gpt import _remat_policy
 
-    loss, args = _rematted_loss(_remat_policy("full"), dtype)
+    loss, args = _rematted_loss(_remat_policy("full"), dtype, kv_heads)
     text = str(jax.make_jaxpr(jax.grad(loss))(args))
-    assert "name=flash_out" in text and "name=flash_lse" in text
+    assert all(f"name={name}" in text for name in fa.RESIDUAL_NAMES)
     assert "reduce_precision" not in text
     q, k, v = _rand_qkv(b=1, s=128, h=2, d=32, dtype=dtype)
     plain = jax.jit(jax.grad(
         lambda q: flash_attention(q, k, v).astype(jnp.float32).sum()
     )).lower(q).as_text()
-    assert "reduce_precision" not in plain and "flash_out" not in plain
+    assert "reduce_precision" not in plain
+    assert not any(name in plain for name in fa.RESIDUAL_NAMES)
 
-    # the control: the same name on the number itself gets the pass
-    # (where a jax upgrade drops it, ``_named``'s bitcasts can go)
+    # the control: the same name on a number the forward goes on to
+    # read gets the pass (where a jax upgrade drops it, ``_named``'s
+    # bitcasts can go; where one guards a residual nothing reads,
+    # q, k and v need ``_named`` too)
     @functools.partial(
         jax.checkpoint, prevent_cse=True, policy=_remat_policy("full")
     )
     def plainly(x):
-        return jnp.sin(checkpoint_name(jnp.cos(x), fa.RESIDUAL_NAMES[0]))
+        return jnp.sin(checkpoint_name(jnp.cos(x), fa.RESIDUAL_NAMES[3]))
 
     assert "reduce_precision" in str(jax.make_jaxpr(
         jax.grad(lambda x: plainly(x).astype(jnp.float32).sum())
