@@ -38,9 +38,7 @@ from dlrover_tpu.common.log import default_logger as logger
 DEFAULT_PRELOAD = "jax,jax.numpy,flax,optax,numpy"
 
 # the warm-restart recovery posture: everything the respawned trainer
-# imports on its critical path, baked into the template ONCE — the
-# single source the chaos scenarios and bench.py share, so the module
-# set they measure cannot silently drift apart
+# imports on its critical path, baked into the template ONCE
 TRAINER_PRELOAD = (
     DEFAULT_PRELOAD
     + ",dlrover_tpu.checkpoint.checkpointer"

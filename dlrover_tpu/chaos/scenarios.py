@@ -30,9 +30,8 @@ STEP_SLEEP_ENV = "DLROVER_CHAOS_STEP_SLEEP"
 # none acked twice" is decidable from shard_dispatch/shard_ack events
 SHARD_DATASET_ENV = "DLROVER_CHAOS_SHARD_DATASET"
 
-# Toy GPT elastic train loop (mirrors bench.py's ELASTIC_TRAIN_SCRIPT
-# shape, minus the self-inflicted crash — faults come exclusively from
-# the chaos schedule).  Flash-checkpoints to shm every CKPT_EVERY
+# Toy GPT elastic train loop (it never crashes itself: faults come
+# exclusively from the chaos schedule).  Flash-checkpoints to shm every CKPT_EVERY
 # steps; a killed incarnation restores from the snapshot the agent
 # kept alive and finishes the fixed step budget; the final step is
 # persisted to disk and committed.  argv: ckpt_dir
@@ -1672,7 +1671,7 @@ def warm_template_midspawn_kill(seed: int = 41) -> Scenario:
 
 
 def goodput_under_scheduled_churn(seed: int = 43) -> Scenario:
-    """bench.py's churn section as a seeded scenario: the worker is
+    """Goodput under churn as a seeded scenario: the worker is
     SIGKILLed at fixed absolute steps, one kill per incarnation (the
     ``incarnation`` trigger keeps a respawn replaying step N from
     being re-killed at N).  The invariant is on the master's own

@@ -26,7 +26,7 @@ faster single stream:
 entirely and reproduces the serial path exactly (the equivalence
 tests pin this).  Stage wall times land in :class:`RestoreStats`
 (``read_s``/``assemble_s``/``h2d_s``), which the engine exports to
-the restore span/event/histograms and bench.py reports.
+the restore span/event/histograms.
 """
 
 import os

@@ -59,9 +59,8 @@ class SpeedMonitor:
         self._samples: Deque[Tuple[float, int]] = deque(maxlen=window)
         self._global_step = 0
         # goodput wall-clock starts at the FIRST step report: master/
-        # agent startup idle is not churn loss, and measuring
-        # [first_step, last_step] matches bench.py's churn-window
-        # accounting (0.0 = no step seen yet)
+        # agent startup idle is not churn loss: the window is
+        # [first_step, last_step] (0.0 = no step seen yet)
         self._start_time = 0.0
         self._last_step_time = time.time()
         self._batch_size = 0

@@ -18,15 +18,24 @@ start:
   ``auto/opt_lib/checkpoint_optimization.py``).
 """
 
-from dataclasses import dataclass, field
-from typing import Any, Callable, Optional
+from dataclasses import dataclass
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-AttentionFn = Callable[..., jax.Array]
-REMAT_POLICIES = ("full", "offload")
+from dlrover_tpu.models import layers
+from dlrover_tpu.ops.attention import cached_decode_attention
+from dlrover_tpu.ops.fp8 import Fp8Dense
+from dlrover_tpu.parallel.mesh import get_global_mesh
+from dlrover_tpu.parallel.moe import MoEMLP
+from dlrover_tpu.parallel.pipeline import (
+    pipeline_apply,
+    pipeline_train_step_1f1b,
+)
+from dlrover_tpu.parallel.sharding import constrain_activation
 
 
 @dataclass(frozen=True)
@@ -44,7 +53,7 @@ class GPTConfig:
     param_dtype: Any = jnp.float32  # master params
     remat: bool = False
     # remat policy: "full" recomputes everything but the five arrays a
-    # flash kernel's backward reads (``_remat_policy``);
+    # flash kernel's backward reads (``layers.remat_policy``);
     # "offload" recomputes everything and keeps the per-block residual
     # checkpoints, parked in host memory (pinned_host) between forward
     # and backward: activation HBM drops to ~one block's working set
@@ -76,10 +85,10 @@ class GPTConfig:
         return self.hidden_dim // self.num_heads
 
     def __post_init__(self):
-        if self.remat_policy not in REMAT_POLICIES:
+        if self.remat_policy not in layers.REMAT_POLICIES:
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r} "
-                f"({' | '.join(REMAT_POLICIES)})"
+                f"({' | '.join(layers.REMAT_POLICIES)})"
             )
         if self.remat_policy != "full" and not self.remat:
             raise ValueError(
@@ -107,153 +116,6 @@ class GPTConfig:
             num_layers=48, num_heads=25, hidden_dim=1600,
             max_seq_len=1024, **kw,
         )
-
-
-def _remat_policy(name: str):
-    """What a rematted block keeps, for every decoder family: the
-    five arrays its flash kernel's backward kernels read (``q``, ``k``
-    and ``v`` as the kernel took them, ``out`` and ``lse`` as it wrote
-    them: all in HBM for the forward already), by the names the
-    kernel's forward rule gives them, and nothing else: the backward
-    runs neither the forward kernel again nor the projections, RoPE
-    and layouts that only feed it.  With XLA attention the names do
-    not occur and everything is recomputed.  "offload" keeps nothing
-    on the device, the kernel's five arrays neither (a layer's ``out``
-    alone is as many bytes as the ``block_in`` it moves off the
-    device), and parks the named per-block residual checkpoints in
-    pinned_host between forward and backward (selective offloading
-    checkpoint)."""
-    from dlrover_tpu.ops.flash_attention import RESIDUAL_NAMES
-
-    if name in ("full", "", None):
-        return jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES
-        )
-    if name == "offload":
-        return jax.checkpoint_policies.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
-            names_which_can_be_offloaded=["block_in"],
-            offload_src="device",
-            offload_dst="pinned_host",
-        )
-    raise ValueError(
-        f"unknown remat_policy {name!r} ({' | '.join(REMAT_POLICIES)})"
-    )
-
-
-def xla_causal_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, dtype=jnp.bfloat16,
-    scale: Optional[float] = None,
-) -> jax.Array:
-    """Plain causal attention; XLA fuses softmax chains well on TPU.
-
-    q,k,v: [batch, seq, heads, head_dim] -> v's shape out (v's head
-    size may differ from q's and k's); ``scale`` defaults to q's
-    ``head_dim ** -0.5``.
-    """
-    seq = q.shape[1]
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
-    logits = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * scale
-    mask = jnp.tril(jnp.ones((seq, seq), dtype=bool))
-    logits = jnp.where(mask[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-
-
-def get_attention_fn(impl: str) -> AttentionFn:
-    """xla | flash | ring | ulysses | ulysses_flash.
-
-    ring/ulysses run over the global mesh's ``sequence`` axis
-    (registered by auto_accelerate); activations must be
-    sequence-sharded by the batch placement.  flash runs per shard of
-    the mesh its train step was built for (batch over the data axes,
-    heads over ``tensor``): GSPMD cannot split the kernel itself.
-    """
-    if impl == "flash":
-        from dlrover_tpu.ops.flash_attention import flash_attention
-        from dlrover_tpu.parallel.mesh import (
-            get_activation_constraint_mesh,
-        )
-        from dlrover_tpu.parallel.sequence import (
-            shard_local_attention,
-        )
-
-        def flash(q, k, v, dtype=None):
-            # the mesh the enclosing train step was built for (scoped
-            # around its trace by accelerate / make_train_step); in a
-            # manual region (ulysses, pipeline) q/k/v are one shard's
-            # already
-            mesh = get_activation_constraint_mesh()
-            if (
-                mesh is None or mesh.size == 1
-                or jax.sharding.get_abstract_mesh().manual_axes
-            ):
-                return flash_attention(q, k, v, dtype=dtype)
-            return shard_local_attention(
-                flash_attention, q, k, v, mesh, dtype=dtype
-            )
-
-        flash.gqa_aware = flash_attention.gqa_aware
-        return flash
-    if impl == "ring":
-        from dlrover_tpu.parallel.mesh import get_global_mesh
-        from dlrover_tpu.parallel.sequence import ring_attention
-
-        def ring(q, k, v, dtype=jnp.bfloat16):
-            return ring_attention(
-                q, k, v, get_global_mesh(), causal=True
-            ).astype(dtype)
-
-        return ring
-    if impl in ("ulysses", "ulysses_flash"):
-        from dlrover_tpu.parallel.mesh import get_global_mesh
-        from dlrover_tpu.parallel.sequence import ulysses_attention
-
-        inner = (
-            get_attention_fn("flash")
-            if impl == "ulysses_flash"
-            else xla_causal_attention
-        )
-
-        def ulysses(q, k, v, dtype=jnp.bfloat16):
-            return ulysses_attention(
-                inner, q, k, v, get_global_mesh(), dtype=dtype
-            )
-
-        return ulysses
-    return xla_causal_attention
-
-
-def cached_decode_attention(
-    q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-    q_pos: jax.Array, dtype=jnp.bfloat16,
-) -> jax.Array:
-    """Chunked decode attention against a KV cache.
-
-    ``q``: [b, s_new, h, d] (prompt prefill or a 1-token step);
-    ``k_cache``/``v_cache``: [b, max_len, kv_heads, d] with this
-    chunk already written (``kv_heads`` may divide ``h`` — GQA);
-    ``q_pos``: [s_new] absolute positions.  Masks both causality
-    inside the chunk and the unfilled cache tail.
-    """
-    b, s, h, d = q.shape
-    kvh = k_cache.shape[2]
-    group = h // kvh
-    qg = q.reshape(b, s, kvh, group, d)
-    scale = d**-0.5
-    logits = jnp.einsum(
-        "bqkgd,bmkd->bkgqm", qg, k_cache,
-        preferred_element_type=jnp.float32,
-    ) * scale
-    k_pos = jnp.arange(k_cache.shape[1])
-    mask = k_pos[None, :] <= q_pos[:, None]  # [s_new, max_len]
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    out = jnp.einsum("bkgqm,bmkd->bqkgd", probs, v_cache)
-    return out.reshape(b, s, h, d)
 
 
 class Attention(nn.Module):
@@ -301,8 +163,9 @@ class Attention(nn.Module):
                 dtype=cfg.dtype,
             )
         else:
-            attn_fn = get_attention_fn(cfg.attention_impl)
-            out = attn_fn(q, k, v, dtype=cfg.dtype)
+            out = layers.attention(
+                cfg.attention_impl, q, k, v, dtype=cfg.dtype
+            )
         out = out.reshape(b, s, d)
         return nn.Dense(
             d, use_bias=True, dtype=cfg.dtype,
@@ -316,12 +179,7 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         cfg = self.config
-        if cfg.fp8:
-            from dlrover_tpu.ops.fp8 import Fp8Dense
-
-            dense = Fp8Dense
-        else:
-            dense = nn.Dense
+        dense = Fp8Dense if cfg.fp8 else nn.Dense
         h = dense(
             cfg.mlp_ratio * cfg.hidden_dim, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="fc_in",
@@ -342,8 +200,6 @@ class Block(nn.Module):
         cfg = self.config
         # named so the offload remat policy can select the residual
         # stream (a no-op under other policies)
-        from jax.ad_checkpoint import checkpoint_name
-
         x = checkpoint_name(x, "block_in")
         # fp32 layernorms on the residual stream for stability
         h = nn.LayerNorm(
@@ -354,8 +210,6 @@ class Block(nn.Module):
             epsilon=cfg.ln_eps, dtype=jnp.float32, name="ln_mlp"
         )(x)
         if self.use_moe:
-            from dlrover_tpu.parallel.moe import MoEMLP
-
             mlp_out = MoEMLP(
                 num_experts=cfg.moe_experts,
                 hidden_dim=cfg.hidden_dim,
@@ -405,16 +259,11 @@ class GPT(nn.Module):
         # active: free propagation invents iota-ordered intermediate
         # shardings that permuted (multi-slice) meshes cannot
         # transition out of efficiently
-        from dlrover_tpu.parallel.sharding import (
-            constrain_activation,
-        )
-
         x = constrain_activation(x)
         block = Block
         if cfg.remat:
-            block = nn.remat(
-                Block, prevent_cse=False,
-                policy=_remat_policy(cfg.remat_policy),
+            block = layers.rematted(
+                Block, prevent_cse=False, policy=cfg.remat_policy
             )
         for i in range(cfg.num_layers):
             use_moe = (
@@ -452,10 +301,7 @@ class GPT(nn.Module):
                 )(x)
             return logits.astype(jnp.float32)
 
-    def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
-        seq_len = seq_len or min(self.config.max_seq_len, 128)
-        tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
-        return self.init(rng, tokens)["params"]
+    init_params = layers.init_params
 
 
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -625,9 +471,6 @@ class PipelinedDecoder:
         )
 
     def apply(self, variables, tokens):
-        from dlrover_tpu.parallel.mesh import get_global_mesh
-        from dlrover_tpu.parallel.pipeline import pipeline_apply
-
         pp = variables["params"]
         mesh = get_global_mesh()
         x = self._embed(pp["embed"], tokens)
@@ -650,11 +493,6 @@ class PipelinedDecoder:
         ``(mean_loss, grads)`` in the stage-stacked layout.  (Fixed
         loss by design: custom losses use the GPipe schedule.)
         """
-        from dlrover_tpu.parallel.mesh import get_global_mesh
-        from dlrover_tpu.parallel.pipeline import (
-            pipeline_train_step_1f1b,
-        )
-
         cfg = self.config
         tied = bool(getattr(cfg, "tie_embeddings", False))
         mesh = get_global_mesh()

@@ -31,6 +31,7 @@ scaling), and the expert layer's ``moe_*``.
 """
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -38,15 +39,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dlrover_tpu.models.gpt import _remat_policy
-from dlrover_tpu.models.llama import RMSNorm
+from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
-from dlrover_tpu.models.sarvam_mla import (
-    DenseMLP,
-    _dense,
-    _rope,
-    yarn_inv_freq,
-)
+from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.parallel.moe import DroplessMoE
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -72,7 +67,7 @@ class RopeRule:
             return self.theta ** (
                 -np.arange(0, dim, 2, dtype=np.float64) / dim
             )
-        return yarn_inv_freq(
+        return layers.yarn_inv_freq(
             dim, self.theta, self.factor, self.original_len,
             self.beta_fast, self.beta_slow,
         )
@@ -118,7 +113,6 @@ class LagunaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    remat_policy: str = "full"
     attention_impl: str = "xla"
 
     def __post_init__(self):
@@ -153,38 +147,6 @@ class LagunaConfig:
         ), **kw})
 
 
-def xla_window_attention(q, k, v, window: Optional[int], dtype):
-    """Plain grouped-query attention with the mask written out:
-    ``[b, s, heads, d]`` queries over ``[b, s, kv heads, d]`` keys and
-    values, query head ``j`` reading kv head ``j // group``."""
-    b, s, heads, d = q.shape
-    kv = k.shape[2]
-    q = q.reshape(b, s, kv, heads // kv, d)
-    logits = jnp.einsum(
-        "bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32
-    ) * d ** -0.5
-    ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
-    seen = ahead >= 0
-    if window is not None:
-        seen = seen & (ahead < window)
-    probs = jax.nn.softmax(
-        jnp.where(seen, logits, -1e30), axis=-1
-    ).astype(dtype)
-    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(
-        b, s, heads, v.shape[-1]
-    )
-
-
-def _attention(cfg: LagunaConfig, q, k, v, window):
-    if cfg.attention_impl == "xla":
-        return xla_window_attention(q, k, v, window, cfg.dtype)
-    if cfg.attention_impl == "flash":
-        from dlrover_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, window=window)
-    raise ValueError(f"no windowed attention through {cfg.attention_impl!r}")
-
-
 class LagunaAttention(nn.Module):
     """``heads``, ``window`` (None: full) and ``rope`` come from the
     layer's kind."""
@@ -199,9 +161,13 @@ class LagunaAttention(nn.Module):
         cfg = self.config
         b, s, _ = x.shape
         heads, kv, d = self.heads, cfg.num_kv_heads, cfg.head_dim
-        q = _dense(cfg, heads * d, "q_proj")(x)
-        k = _dense(cfg, kv * d, "k_proj")(x)
-        v = _dense(cfg, kv * d, "v_proj")(x)
+        proj = partial(
+            layers.dense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            init_std=cfg.init_std,
+        )
+        q = proj(heads * d, "q_proj")(x)
+        k = proj(kv * d, "k_proj")(x)
+        v = proj(kv * d, "v_proj")(x)
         with jax.named_scope("attn_rope"):
             inv_freq = self.rope.inv_freq(d)
             rotated = 2 * len(inv_freq)
@@ -216,20 +182,24 @@ class LagunaAttention(nn.Module):
             def rotate(t, n):
                 t = t.reshape(b, s, n, d)
                 if rotated == d:
-                    return _rope(t, cos, sin)
+                    return layers.rotate_half(t, cos, sin)
                 return jnp.concatenate([
-                    _rope(t[..., :rotated], cos, sin), t[..., rotated:],
+                    layers.rotate_half(t[..., :rotated], cos, sin),
+                    t[..., rotated:],
                 ], axis=-1)
 
             q, k = rotate(q, heads), rotate(k, kv)
             v = v.reshape(b, s, kv, d)
-        out = _attention(cfg, q, k, v, self.window)
+        out = layers.attention(
+            cfg.attention_impl, q, k, v, window=self.window,
+            dtype=cfg.dtype,
+        )
         with jax.named_scope("attn_gate"):
             gate = jax.nn.sigmoid(
-                _dense(cfg, heads, "g_proj")(x).astype(jnp.float32)
+                proj(heads, "g_proj")(x).astype(jnp.float32)
             )
             out = (out * gate[..., None]).astype(cfg.dtype)
-        return _dense(cfg, cfg.hidden_dim, "o_proj")(
+        return proj(cfg.hidden_dim, "o_proj")(
             out.reshape(b, s, heads * d)
         )
 
@@ -254,10 +224,13 @@ class LagunaBlock(nn.Module):
                 cfg.sliding_window if sliding else None,
                 cfg.sliding_rope if sliding else cfg.full_rope,
                 name="attn",
-            )(RMSNorm(cfg.rms_eps, name="ln_attn")(x))
-        h = RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
+            )(layers.RMSNorm(cfg.rms_eps, name="ln_attn")(x))
+        h = layers.RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
         if self.dense:
-            return x + DenseMLP(cfg, name="mlp")(h), None
+            return x + layers.SwiGLU(
+                cfg.dense_dim, cfg.hidden_dim, cfg.dtype,
+                cfg.param_dtype, cfg.init_std, name="mlp",
+            )(h), None
         out, stats = DroplessMoE(
             num_experts=cfg.num_experts, mlp_dim=cfg.expert_dim,
             top_k=cfg.top_k, dtype=cfg.dtype,
@@ -290,12 +263,10 @@ class Laguna(nn.Module):
             embedding_init=nn.initializers.normal(cfg.init_std),
             name="wte",
         )(tokens)
-        block = LagunaBlock
-        if cfg.remat:
-            block = nn.remat(
-                LagunaBlock, prevent_cse=True,
-                policy=_remat_policy(cfg.remat_policy),
-            )
+        block = (
+            layers.rematted(LagunaBlock, prevent_cse=True) if cfg.remat
+            else LagunaBlock
+        )
         per_layer = []
         for i, (kind, heads, mlp) in enumerate(zip(
             cfg.layer_types, cfg.heads_per_layer, cfg.mlp_layer_types
@@ -305,19 +276,17 @@ class Laguna(nn.Module):
             )(x)
             if stats is not None:
                 per_layer.append(stats)
-        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+        x = layers.RMSNorm(cfg.rms_eps, name="ln_f")(x)
         if not return_hidden:
-            x = _dense(cfg, cfg.vocab_size, "lm_head")(x).astype(
-                jnp.float32
-            )
+            x = layers.dense(
+                cfg.vocab_size, "lm_head", cfg.dtype, cfg.param_dtype,
+                cfg.init_std,
+            )(x).astype(jnp.float32)
         if not return_router_stats:
             return x
         return x, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
 
-    def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
-        seq_len = seq_len or min(self.config.max_seq_len, 128)
-        tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
-        return self.init(rng, tokens)["params"]
+    init_params = layers.init_params
 
 
 def window_tiles_share(cfg: LagunaConfig, seq: int, itemsize: int = 2):
@@ -325,8 +294,6 @@ def window_tiles_share(cfg: LagunaConfig, seq: int, itemsize: int = 2):
     walk of the same tiles would (``block_schedule``): what the window
     saves of the walk, a constant of the shapes.  None where no
     sliding layer goes through the kernels."""
-    from dlrover_tpu.ops import flash_attention as fa
-
     if cfg.attention_impl != "flash" or SLIDING not in cfg.layer_types:
         return None
     block = fa._fit_block(seq, fa.default_blocks(seq, itemsize)[0])

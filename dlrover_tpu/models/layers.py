@@ -1,0 +1,248 @@
+"""What the decoder families share: the norm, rope, the dense helper,
+the SwiGLU, the one call that maps ``attention_impl`` to a function,
+and the remat rule.  A family file imports this module, ``losses``,
+``ops`` and ``parallel``, and no sibling; nothing here knows a family
+(layers take widths and dtypes, never a config object).
+
+Two SwiGLUs stay apart because their parameter names are a
+checkpoint's format: ``llama.py::LlamaMLP`` (``gate`` / ``up`` /
+``down``) and the shared expert of ``parallel/moe.py::DroplessMoE``
+(three kernels among the expert layer's own parameters).
+"""
+
+import math
+from functools import partial
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from dlrover_tpu.ops.attention import (
+    xla_causal_attention,
+    xla_window_attention,
+)
+from dlrover_tpu.ops.flash_attention import RESIDUAL_NAMES, flash_attention
+from dlrover_tpu.parallel.mesh import (
+    get_activation_constraint_mesh,
+    get_global_mesh,
+)
+from dlrover_tpu.parallel.sequence import (
+    ring_attention,
+    shard_local_attention,
+    ulysses_attention,
+)
+
+REMAT_POLICIES = ("full", "offload")
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        x32 = x.astype(jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1],), jnp.float32
+        )
+        norm = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
+        )
+        return (norm * scale).astype(x.dtype)
+
+
+def dense(features, name, dtype, param_dtype, init_std):
+    return nn.Dense(
+        features, use_bias=False, dtype=dtype, param_dtype=param_dtype,
+        kernel_init=nn.initializers.normal(init_std), name=name,
+    )
+
+
+class SwiGLU(nn.Module):
+    mlp_dim: int
+    hidden_dim: int
+    dtype: Any
+    param_dtype: Any
+    init_std: float
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        proj = partial(
+            dense, dtype=self.dtype, param_dtype=self.param_dtype,
+            init_std=self.init_std,
+        )
+        gate = proj(self.mlp_dim, "gate_proj")(x)
+        up = proj(self.mlp_dim, "up_proj")(x)
+        return proj(self.hidden_dim, "down_proj")(nn.silu(gate) * up)
+
+
+def rotate_half(x, cos, sin):
+    """``x [b, s, heads, rope]``, half-split pairs, float32 inside."""
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
+    ).astype(x.dtype)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary position embedding on [b, s, h, d]."""
+    d = x.shape[-1]
+    freqs = 1.0 / (
+        theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    )
+    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = jnp.sin(angles)[None, :, None, :]
+    return rotate_half(x, cos, sin)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(
+    dim: int, theta: float, original_len: int, beta_fast: float,
+    beta_slow: float,
+) -> Tuple[int, int]:
+    """``(low, high)``: the rope pairs (of ``dim`` rotated lanes)
+    between which the frequencies blend from extrapolated to
+    interpolated."""
+
+    def pair_of(rotations):
+        return dim * math.log(
+            original_len / (rotations * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = math.floor(pair_of(beta_fast))
+    high = math.ceil(pair_of(beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_inv_freq(
+    dim: int, theta: float, factor: float, original_len: int,
+    beta_fast: float, beta_slow: float,
+) -> np.ndarray:
+    """yarn (``deepseek_yarn``, HF ``_compute_yarn_parameters``): pair
+    ``i`` keeps ``theta^(-2i/dim)`` below ``low``, takes it over
+    ``factor`` above ``high``, a linear blend between.  A constant of
+    the configuration, worked in float64 (at position 8191 a float32
+    rounding of the frequency is 5e-4 rad)."""
+    freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_correction_range(
+        dim, theta, original_len, beta_fast, beta_slow
+    )
+    ramp = np.clip(
+        (np.arange(dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0
+    )
+    return freq / factor * ramp + freq * (1.0 - ramp)
+
+
+def init_params(model, rng, batch_size: int = 2, seq_len: int = 0):
+    """A decoder's ``params`` tree, initialised on a batch of zeros."""
+    seq_len = seq_len or min(model.config.max_seq_len, 128)
+    tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
+    return model.init(rng, tokens)["params"]
+
+
+def _flash(q, k, v, **kw):
+    """The Pallas kernel, per shard of the mesh its train step was
+    built for (batch over the data axes, heads over ``tensor``): GSPMD
+    cannot split the kernel itself."""
+    # the mesh the enclosing train step was built for (scoped around
+    # its trace by accelerate / make_train_step); in a manual region
+    # (ulysses, pipeline) q/k/v are one shard's already
+    mesh = get_activation_constraint_mesh()
+    if (
+        mesh is None or mesh.size == 1
+        or jax.sharding.get_abstract_mesh().manual_axes
+    ):
+        return flash_attention(q, k, v, **kw)
+    return shard_local_attention(flash_attention, q, k, v, mesh, **kw)
+
+
+def _xla(q, k, v, *, scale, window, dtype):
+    """The plain forms: the grouped one, its mask written out, for a
+    window or fewer kv heads than query heads, else the causal one."""
+    if window is None and k.shape[2] == q.shape[2]:
+        return xla_causal_attention(q, k, v, dtype=dtype, scale=scale)
+    if scale is not None:
+        raise ValueError("no scale in the plain grouped or windowed form")
+    return xla_window_attention(q, k, v, window, dtype)
+
+
+def attention(
+    impl: str, q: jax.Array, k: jax.Array, v: jax.Array, *,
+    scale: Optional[float] = None, window: Optional[int] = None,
+    dtype: Any = None,
+) -> jax.Array:
+    """Causal attention through ``impl``: xla | flash | ring | ulysses
+    | ulysses_flash, the only place that maps the name to a function.
+
+    ``[b, s, heads, d]`` queries over ``[b, s, kv heads, d]`` keys and
+    values (``v``'s head size may differ); ``scale`` defaults to
+    ``d ** -0.5``; with ``window`` query ``i`` sees keys ``(i - window,
+    i]``; ``dtype`` (default ``v``'s) is the output's.  ring/ulysses
+    run over the global mesh's ``sequence`` axis (registered by
+    auto_accelerate); activations must be sequence-sharded by the
+    batch placement.
+    """
+    dtype = v.dtype if dtype is None else dtype
+    kw = dict(scale=scale, window=window, dtype=dtype)
+    if impl == "flash":
+        return _flash(q, k, v, **kw)
+    if impl == "xla":
+        return _xla(q, k, v, **kw)
+    if k.shape[2] != q.shape[2]:
+        # only flash and the grouped plain form read each kv head once
+        # per group; the others need the materialized repeat
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    if impl == "ring" and window is None:
+        return ring_attention(
+            q, k, v, get_global_mesh(), causal=True, scale=scale
+        ).astype(dtype)
+    if impl in ("ulysses", "ulysses_flash"):
+        inner = _flash if impl == "ulysses_flash" else _xla
+        return ulysses_attention(inner, q, k, v, get_global_mesh(), **kw)
+    raise ValueError(f"no attention through {impl!r} (window {window})")
+
+
+def remat_policy(name: str):
+    """What a rematted block keeps, for every decoder family: the
+    five arrays its flash kernel's backward kernels read (``q``, ``k``
+    and ``v`` as the kernel took them, ``out`` and ``lse`` as it wrote
+    them: all in HBM for the forward already), by the names the
+    kernel's forward rule gives them
+    (``ops/flash_attention.py::RESIDUAL_NAMES``), and nothing else: the
+    backward runs neither the forward kernel again nor the
+    projections, RoPE and layouts that only feed it.  With XLA
+    attention the names do not occur and everything is recomputed.
+    "offload" keeps nothing on the device, the kernel's five arrays
+    neither (a layer's ``out`` alone is as many bytes as the
+    ``block_in`` it moves off the device), and parks the per-block
+    residual checkpoints that ``gpt.py`` and ``llama.py`` name in
+    pinned_host between forward and backward (selective offloading
+    checkpoint)."""
+    if name in ("full", "", None):
+        return jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES
+        )
+    if name == "offload":
+        return jax.checkpoint_policies.save_and_offload_only_these_names(
+            names_which_can_be_saved=[],
+            names_which_can_be_offloaded=["block_in"],
+            offload_src="device",
+            offload_dst="pinned_host",
+        )
+    raise ValueError(
+        f"unknown remat_policy {name!r} ({' | '.join(REMAT_POLICIES)})"
+    )
+
+
+def rematted(block, prevent_cse: bool, policy: str = "full"):
+    """``block`` (a module class) under ``jax.checkpoint`` with the one
+    rule above."""
+    return nn.remat(
+        block, prevent_cse=prevent_cse, policy=remat_policy(policy)
+    )

@@ -10,17 +10,17 @@ rules (q_proj/k_proj/v_proj/o_proj, gate/up/down).
 """
 
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from dlrover_tpu.models.gpt import (
-    PipelinedDecoder,
-    cached_decode_attention,
-    get_attention_fn,
-)
+from dlrover_tpu.models.gpt import PipelinedDecoder
+from dlrover_tpu.models import layers
+from dlrover_tpu.ops.attention import cached_decode_attention
+from dlrover_tpu.parallel.moe import MoEMLP
 
 
 @dataclass(frozen=True)
@@ -52,7 +52,7 @@ class LlamaConfig:
     moe_capacity_factor: float = 1.25
 
     def __post_init__(self):
-        if self.remat_policy not in ("full", "offload"):
+        if self.remat_policy not in layers.REMAT_POLICIES:
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r} "
                 "(full | offload)"
@@ -97,37 +97,6 @@ class LlamaConfig:
         )
 
 
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        x32 = x.astype(jnp.float32)
-        scale = self.param(
-            "scale", nn.initializers.ones, (x.shape[-1],), jnp.float32
-        )
-        norm = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
-        )
-        return (norm * scale).astype(x.dtype)
-
-
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding on [b, s, h, d]."""
-    d = x.shape[-1]
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    )
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    )
-    return out.astype(x.dtype)
-
-
 class LlamaAttention(nn.Module):
     config: LlamaConfig
 
@@ -167,8 +136,8 @@ class LlamaAttention(nn.Module):
             )
             pos = idx.value
             positions = pos + jnp.arange(s)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+            q = layers.rope(q, positions, cfg.rope_theta)
+            k = layers.rope(k, positions, cfg.rope_theta)
             ck.value = jax.lax.dynamic_update_slice(
                 ck.value, k, (0, pos, 0, 0)
             )
@@ -184,19 +153,11 @@ class LlamaAttention(nn.Module):
             )
         else:
             positions = jnp.arange(s)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-            attn_fn = get_attention_fn(cfg.attention_impl)
-            if cfg.num_kv_heads != cfg.num_heads and not getattr(
-                attn_fn, "gqa_aware", False
-            ):
-                # the Pallas flash kernel is GQA-aware (reads each kv
-                # head once per group via its index maps); other
-                # impls need the materialized repeat
-                group = cfg.num_heads // cfg.num_kv_heads
-                k = jnp.repeat(k, group, axis=2)
-                v = jnp.repeat(v, group, axis=2)
-            out = attn_fn(q, k, v, dtype=cfg.dtype)
+            q = layers.rope(q, positions, cfg.rope_theta)
+            k = layers.rope(k, positions, cfg.rope_theta)
+            out = layers.attention(
+                cfg.attention_impl, q, k, v, dtype=cfg.dtype
+            )
         out = out.reshape(b, s, cfg.num_heads * hd)
         return nn.Dense(
             cfg.hidden_dim, use_bias=False, dtype=cfg.dtype,
@@ -232,15 +193,11 @@ class LlamaBlock(nn.Module):
     def __call__(self, x: jax.Array) -> jax.Array:
         cfg = self.config
         # named for the offload remat policy (no-op otherwise)
-        from jax.ad_checkpoint import checkpoint_name
-
         x = checkpoint_name(x, "block_in")
-        h = RMSNorm(cfg.rms_eps, name="ln_attn")(x)
+        h = layers.RMSNorm(cfg.rms_eps, name="ln_attn")(x)
         x = x + LlamaAttention(cfg, name="attn")(h)
-        h = RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
+        h = layers.RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
         if self.use_moe:
-            from dlrover_tpu.parallel.moe import MoEMLP
-
             mlp_out = MoEMLP(
                 num_experts=cfg.moe_experts,
                 hidden_dim=cfg.hidden_dim,
@@ -273,11 +230,8 @@ class Llama(nn.Module):
         )(tokens)
         block = LlamaBlock
         if cfg.remat:
-            from dlrover_tpu.models.gpt import _remat_policy
-
-            block = nn.remat(
-                LlamaBlock, prevent_cse=False,
-                policy=_remat_policy(cfg.remat_policy),
+            block = layers.rematted(
+                LlamaBlock, prevent_cse=False, policy=cfg.remat_policy
             )
         for i in range(cfg.num_layers):
             # shared convention with GPT: every moe_every-th block,
@@ -288,7 +242,7 @@ class Llama(nn.Module):
                 and (i + 1) % cfg.moe_every == 0
             )
             x = block(cfg, use_moe=use_moe, name=f"block_{i}")(x)
-        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+        x = layers.RMSNorm(cfg.rms_eps, name="ln_f")(x)
         if return_hidden:
             # for chunked/fused losses (models/losses.py)
             return x
@@ -298,10 +252,7 @@ class Llama(nn.Module):
         )(x)
         return logits.astype(jnp.float32)
 
-    def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
-        seq_len = seq_len or min(self.config.max_seq_len, 128)
-        tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
-        return self.init(rng, tokens)["params"]
+    init_params = layers.init_params
 
 
 class PipelinedLlama(PipelinedDecoder):
@@ -322,7 +273,7 @@ class PipelinedLlama(PipelinedDecoder):
 
     def _apply_head(self, head_pp, wte_params, h):
         cfg = self.config
-        h = RMSNorm(cfg.rms_eps).apply(
+        h = layers.RMSNorm(cfg.rms_eps).apply(
             {"params": head_pp["ln_f"]}, h
         )
         logits = nn.Dense(

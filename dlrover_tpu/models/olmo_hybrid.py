@@ -24,21 +24,21 @@ values; :func:`dlrover_tpu.ops.gated_delta_rule.gated_delta_rule`)::
 Full-attention mixer: ``q = RMSNorm(W_q x)``, ``k = RMSNorm(W_k x)``
 over the whole projection (the family's QK-norm, as
 ``models/olmoe.py``), NO positional embedding (the published
-``rope_theta`` is null), causal, through ``get_attention_fn``.  Its
+``rope_theta`` is null), causal, through ``layers.attention``.  Its
 flax module is called ``attn`` (the benchmark finds flash kernels by
 that name); the linear mixer's is ``gdn``.
 """
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.gpt import _remat_policy, get_attention_fn
-from dlrover_tpu.models.llama import RMSNorm
+from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
 
@@ -67,7 +67,6 @@ class OlmoHybridConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    remat_policy: str = "full"
     attention_impl: str = "xla"
 
     @property
@@ -85,14 +84,6 @@ class OlmoHybridConfig:
             num_heads=4, hidden_dim=64, mlp_dim=96, linear_heads=4,
             linear_key_dim=8, linear_value_dim=16,
         ), **kw})
-
-
-def _dense(cfg, features, name):
-    return nn.Dense(
-        features, use_bias=False, dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype,
-        kernel_init=nn.initializers.normal(cfg.init_std), name=name,
-    )
 
 
 def _conv_init(key, shape, dtype):
@@ -169,12 +160,16 @@ class GatedDeltaNet(nn.Module):
         heads, dk, dv = (
             cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
         )
-        q = _dense(cfg, heads * dk, "q_proj")(x)
-        k = _dense(cfg, heads * dk, "k_proj")(x)
-        v = _dense(cfg, heads * dv, "v_proj")(x)
-        z = _dense(cfg, heads * dv, "g_proj")(x)
-        a = _dense(cfg, heads, "a_proj")(x).astype(jnp.float32)
-        bb = _dense(cfg, heads, "b_proj")(x).astype(jnp.float32)
+        proj = partial(
+            layers.dense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            init_std=cfg.init_std,
+        )
+        q = proj(heads * dk, "q_proj")(x)
+        k = proj(heads * dk, "k_proj")(x)
+        v = proj(heads * dv, "v_proj")(x)
+        z = proj(heads * dv, "g_proj")(x)
+        a = proj(heads, "a_proj")(x).astype(jnp.float32)
+        bb = proj(heads, "b_proj")(x).astype(jnp.float32)
         a_log = self.param("A_log", _a_log_init, (heads,), jnp.float32)
         dt_bias = self.param(
             "dt_bias", _dt_bias_init, (heads,), jnp.float32
@@ -219,7 +214,7 @@ class GatedDeltaNet(nn.Module):
             ) * jnp.tile(scale, heads)
             o = (o32 * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
             state_rms = jnp.sqrt(jnp.mean(state * state))
-        return _dense(cfg, cfg.hidden_dim, "o_proj")(o), state_rms
+        return proj(cfg.hidden_dim, "o_proj")(o), state_rms
 
 
 class FullAttention(nn.Module):
@@ -230,32 +225,23 @@ class FullAttention(nn.Module):
         cfg = self.config
         b, s, _ = x.shape
         heads, hd = cfg.num_heads, cfg.head_dim
+
+        def proj(name):
+            return layers.dense(
+                cfg.hidden_dim, name, cfg.dtype, cfg.param_dtype,
+                cfg.init_std,
+            )
+
         # QK-norm over all heads together, before the split; no rope
-        q = RMSNorm(cfg.rms_eps, name="q_norm")(
-            _dense(cfg, cfg.hidden_dim, "q_proj")(x)
+        q = layers.RMSNorm(cfg.rms_eps, name="q_norm")(proj("q_proj")(x))
+        k = layers.RMSNorm(cfg.rms_eps, name="k_norm")(proj("k_proj")(x))
+        v = proj("v_proj")(x)
+        out = layers.attention(
+            cfg.attention_impl, q.reshape(b, s, heads, hd),
+            k.reshape(b, s, heads, hd), v.reshape(b, s, heads, hd),
+            dtype=cfg.dtype,
         )
-        k = RMSNorm(cfg.rms_eps, name="k_norm")(
-            _dense(cfg, cfg.hidden_dim, "k_proj")(x)
-        )
-        v = _dense(cfg, cfg.hidden_dim, "v_proj")(x)
-        out = get_attention_fn(cfg.attention_impl)(
-            q.reshape(b, s, heads, hd), k.reshape(b, s, heads, hd),
-            v.reshape(b, s, heads, hd), dtype=cfg.dtype,
-        )
-        return _dense(cfg, cfg.hidden_dim, "o_proj")(
-            out.reshape(b, s, cfg.hidden_dim)
-        )
-
-
-class SwiGLU(nn.Module):
-    config: OlmoHybridConfig
-
-    @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        cfg = self.config
-        gate = _dense(cfg, cfg.mlp_dim, "gate_proj")(x)
-        up = _dense(cfg, cfg.mlp_dim, "up_proj")(x)
-        return _dense(cfg, cfg.hidden_dim, "down_proj")(nn.silu(gate) * up)
+        return proj("o_proj")(out.reshape(b, s, cfg.hidden_dim))
 
 
 class OlmoHybridBlock(nn.Module):
@@ -276,10 +262,11 @@ class OlmoHybridBlock(nn.Module):
             state_rms = jnp.zeros((), jnp.float32)
         else:
             raise ValueError(f"unknown layer type {self.kind!r}")
-        x = x + RMSNorm(cfg.rms_eps, name="ln_mixer")(mixed)
-        x = x + RMSNorm(cfg.rms_eps, name="ln_mlp")(
-            SwiGLU(cfg, name="mlp")(x)
-        )
+        x = x + layers.RMSNorm(cfg.rms_eps, name="ln_mixer")(mixed)
+        x = x + layers.RMSNorm(cfg.rms_eps, name="ln_mlp")(layers.SwiGLU(
+            cfg.mlp_dim, cfg.hidden_dim, cfg.dtype, cfg.param_dtype,
+            cfg.init_std, name="mlp",
+        )(x))
         return x, state_rms
 
 
@@ -302,29 +289,25 @@ class OlmoHybrid(nn.Module):
             embedding_init=nn.initializers.normal(cfg.init_std),
             name="wte",
         )(tokens)
-        block = OlmoHybridBlock
-        if cfg.remat:
-            block = nn.remat(
-                OlmoHybridBlock, prevent_cse=True,
-                policy=_remat_policy(cfg.remat_policy),
-            )
+        block = (
+            layers.rematted(OlmoHybridBlock, prevent_cse=True) if cfg.remat
+            else OlmoHybridBlock
+        )
         state_rms = jnp.zeros((), jnp.float32)
         for i, kind in enumerate(cfg.layer_types):
             x, rms = block(cfg, kind, name=f"block_{i}")(x)
             state_rms = jnp.maximum(state_rms, rms)
-        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+        x = layers.RMSNorm(cfg.rms_eps, name="ln_f")(x)
         if not return_hidden:
-            x = _dense(cfg, cfg.vocab_size, "lm_head")(x).astype(
-                jnp.float32
-            )
+            x = layers.dense(
+                cfg.vocab_size, "lm_head", cfg.dtype, cfg.param_dtype,
+                cfg.init_std,
+            )(x).astype(jnp.float32)
         if not return_state_rms:
             return x
         return x, state_rms
 
-    def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
-        seq_len = seq_len or min(self.config.max_seq_len, 128)
-        tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
-        return self.init(rng, tokens)["params"]
+    init_params = layers.init_params
 
 
 def make_olmo_hybrid_loss(model: OlmoHybrid, num_chunks: int = 8):

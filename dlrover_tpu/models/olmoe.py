@@ -10,8 +10,8 @@ Per block, as published::
                                     split into heads; then RoPE
 
 No biases, no ``clip_qkv``, no shared expert, top-k weights not
-renormalised.  Shares the repo's blocks with the GPT and Llama
-families (``RMSNorm``, ``rope``, ``get_attention_fn``, remat); the
+renormalised.  The shared pieces (``RMSNorm``, ``rope``, the attention
+call, remat) are ``models/layers.py``'s; the
 expert layer is :class:`dlrover_tpu.parallel.moe.DroplessMoE`: one
 chip holds every expert of its layers.
 """
@@ -23,8 +23,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.gpt import _remat_policy, get_attention_fn
-from dlrover_tpu.models.llama import RMSNorm, rope
+from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.parallel.moe import DroplessMoE
 
@@ -48,7 +47,6 @@ class OlmoeConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    remat_policy: str = "full"
     attention_impl: str = "xla"
 
     @property
@@ -74,22 +72,21 @@ class OlmoeAttention(nn.Module):
         heads, hd = cfg.num_heads, cfg.head_dim
 
         def proj(name):
-            return nn.Dense(
-                cfg.hidden_dim, use_bias=False, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                kernel_init=nn.initializers.normal(cfg.init_std),
-                name=name,
+            return layers.dense(
+                cfg.hidden_dim, name, cfg.dtype, cfg.param_dtype,
+                cfg.init_std,
             )
 
         # QK-norm over all heads together, before the split
-        q = RMSNorm(cfg.rms_eps, name="q_norm")(proj("q_proj")(x))
-        k = RMSNorm(cfg.rms_eps, name="k_norm")(proj("k_proj")(x))
+        q = layers.RMSNorm(cfg.rms_eps, name="q_norm")(proj("q_proj")(x))
+        k = layers.RMSNorm(cfg.rms_eps, name="k_norm")(proj("k_proj")(x))
         v = proj("v_proj")(x)
         positions = jnp.arange(s)
-        q = rope(q.reshape(b, s, heads, hd), positions, cfg.rope_theta)
-        k = rope(k.reshape(b, s, heads, hd), positions, cfg.rope_theta)
-        out = get_attention_fn(cfg.attention_impl)(
-            q, k, v.reshape(b, s, heads, hd), dtype=cfg.dtype
+        q = layers.rope(q.reshape(b, s, heads, hd), positions, cfg.rope_theta)
+        k = layers.rope(k.reshape(b, s, heads, hd), positions, cfg.rope_theta)
+        out = layers.attention(
+            cfg.attention_impl, q, k, v.reshape(b, s, heads, hd),
+            dtype=cfg.dtype,
         )
         return proj("o_proj")(out.reshape(b, s, cfg.hidden_dim))
 
@@ -100,9 +97,9 @@ class OlmoeBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array):
         cfg = self.config
-        h = RMSNorm(cfg.rms_eps, name="ln_attn")(x)
+        h = layers.RMSNorm(cfg.rms_eps, name="ln_attn")(x)
         x = x + OlmoeAttention(cfg, name="attn")(h)
-        h = RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
+        h = layers.RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
         out, stats = DroplessMoE(
             num_experts=cfg.num_experts, mlp_dim=cfg.expert_dim,
             top_k=cfg.top_k, dtype=cfg.dtype,
@@ -132,32 +129,25 @@ class Olmoe(nn.Module):
             embedding_init=nn.initializers.normal(cfg.init_std),
             name="wte",
         )(tokens)
-        block = OlmoeBlock
-        if cfg.remat:
-            block = nn.remat(
-                OlmoeBlock, prevent_cse=False,
-                policy=_remat_policy(cfg.remat_policy),
-            )
+        block = (
+            layers.rematted(OlmoeBlock, prevent_cse=False) if cfg.remat
+            else OlmoeBlock
+        )
         per_layer = []
         for i in range(cfg.num_layers):
             x, stats = block(cfg, name=f"block_{i}")(x)
             per_layer.append(stats)
-        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+        x = layers.RMSNorm(cfg.rms_eps, name="ln_f")(x)
         if not return_hidden:
-            x = nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                kernel_init=nn.initializers.normal(cfg.init_std),
-                name="lm_head",
+            x = layers.dense(
+                cfg.vocab_size, "lm_head", cfg.dtype, cfg.param_dtype,
+                cfg.init_std,
             )(x).astype(jnp.float32)
         if not return_router_stats:
             return x
         return x, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
 
-    def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
-        seq_len = seq_len or min(self.config.max_seq_len, 128)
-        tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
-        return self.init(rng, tokens)["params"]
+    init_params = layers.init_params
 
 
 def router_losses(stats, top_k: int):

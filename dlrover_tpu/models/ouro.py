@@ -41,16 +41,15 @@ computes the last pass's gate logit like the others' and drops it.
 """
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.gpt import _remat_policy, get_attention_fn
-from dlrover_tpu.models.llama import RMSNorm, rope
+from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import weighted_chunked_cross_entropy
-from dlrover_tpu.models.sarvam_mla import DenseMLP, _dense
 
 
 @dataclass(frozen=True)
@@ -93,15 +92,21 @@ class OuroAttention(nn.Module):
         heads, d = cfg.num_heads, cfg.head_dim
         positions = jnp.arange(s)
 
-        def heads_of(name, rotate):
-            t = _dense(cfg, heads * d, name)(x).reshape(b, s, heads, d)
-            return rope(t, positions, cfg.rope_theta) if rotate else t
-
-        out = get_attention_fn(cfg.attention_impl)(
-            heads_of("q_proj", True), heads_of("k_proj", True),
-            heads_of("v_proj", False), dtype=cfg.dtype,
+        proj = partial(
+            layers.dense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            init_std=cfg.init_std,
         )
-        return _dense(cfg, cfg.hidden_dim, "o_proj")(
+
+        def heads_of(name, rotate):
+            t = proj(heads * d, name)(x).reshape(b, s, heads, d)
+            return layers.rope(t, positions, cfg.rope_theta) if rotate else t
+
+        out = layers.attention(
+            cfg.attention_impl, heads_of("q_proj", True),
+            heads_of("k_proj", True), heads_of("v_proj", False),
+            dtype=cfg.dtype,
+        )
+        return proj(cfg.hidden_dim, "o_proj")(
             out.reshape(b, s, heads * d)
         )
 
@@ -116,14 +121,15 @@ class OuroBlock(nn.Module):
         cfg = self.config
 
         def norm(name):
-            return RMSNorm(cfg.rms_eps, name=name)
+            return layers.RMSNorm(cfg.rms_eps, name=name)
 
         x = x + norm("ln_attn_out")(
             OuroAttention(cfg, name="attn")(norm("ln_attn")(x))
         )
-        return x + norm("ln_mlp_out")(
-            DenseMLP(cfg, name="mlp")(norm("ln_mlp")(x))
-        )
+        return x + norm("ln_mlp_out")(layers.SwiGLU(
+            cfg.dense_dim, cfg.hidden_dim, cfg.dtype, cfg.param_dtype,
+            cfg.init_std, name="mlp",
+        )(norm("ln_mlp")(x)))
 
 
 class ExitGate(nn.Module):
@@ -157,19 +163,20 @@ class Ouro(nn.Module):
             param_dtype=cfg.param_dtype,
             embedding_init=nn.initializers.normal(cfg.init_std),
         )
-        block = OuroBlock
-        if cfg.remat:
-            block = nn.remat(
-                OuroBlock, prevent_cse=True,
-                policy=_remat_policy("full"),
-            )
+        block = (
+            layers.rematted(OuroBlock, prevent_cse=True) if cfg.remat
+            else OuroBlock
+        )
         # built once, called in every pass: L blocks in the tree
         for i in range(cfg.num_layers):
             setattr(self, f"block_{i}", block(cfg))
-        self.ln_f = RMSNorm(cfg.rms_eps)
+        self.ln_f = layers.RMSNorm(cfg.rms_eps)
         if cfg.ut_steps > 1:
             self.exit_gate = ExitGate(cfg)
-        self.lm_head = _dense(cfg, cfg.vocab_size, "lm_head")
+        self.lm_head = layers.dense(
+            cfg.vocab_size, "lm_head", cfg.dtype, cfg.param_dtype,
+            cfg.init_std,
+        )
 
     def one_pass(self, x: jax.Array):
         """``(x_t, its gate logit [b, s] float32 or None)``: the stack
@@ -206,10 +213,7 @@ class Ouro(nn.Module):
             return x, jnp.zeros((0,) + tokens.shape, jnp.float32)
         return x, logits[:-1]
 
-    def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
-        seq_len = seq_len or min(self.config.max_seq_len, 128)
-        tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
-        return self.init(rng, tokens)["params"]
+    init_params = layers.init_params
 
 
 def exit_distribution(gate_logits: jax.Array):

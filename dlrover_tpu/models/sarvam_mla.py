@@ -36,17 +36,15 @@ finds flash kernels by that name).  Device scopes: ``mla_q``,
 expert layer's ``moe_*``.
 """
 
-import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from dlrover_tpu.models.gpt import _remat_policy, xla_causal_attention
-from dlrover_tpu.models.llama import RMSNorm
+from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.parallel.moe import DroplessMoE
 
@@ -88,7 +86,6 @@ class SarvamMlaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    remat_policy: str = "full"
     attention_impl: str = "xla"
 
     @property
@@ -106,82 +103,15 @@ class SarvamMlaConfig:
         ), **kw})
 
 
-def _dense(cfg, features, name):
-    return nn.Dense(
-        features, use_bias=False, dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype,
-        kernel_init=nn.initializers.normal(cfg.init_std), name=name,
-    )
-
-
-def yarn_mscale(factor: float, mscale: float) -> float:
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def yarn_correction_range(
-    dim: int, theta: float, original_len: int, beta_fast: float,
-    beta_slow: float,
-) -> Tuple[int, int]:
-    """``(low, high)``: the rope pairs (of ``dim`` rotated lanes)
-    between which the frequencies blend from extrapolated to
-    interpolated."""
-
-    def pair_of(rotations):
-        return dim * math.log(
-            original_len / (rotations * 2 * math.pi)
-        ) / (2 * math.log(theta))
-
-    low = math.floor(pair_of(beta_fast))
-    high = math.ceil(pair_of(beta_slow))
-    return max(low, 0), min(high, dim - 1)
-
-
-def yarn_inv_freq(
-    dim: int, theta: float, factor: float, original_len: int,
-    beta_fast: float, beta_slow: float,
-) -> np.ndarray:
-    """yarn (``deepseek_yarn``, HF ``_compute_yarn_parameters``): pair
-    ``i`` keeps ``theta^(-2i/dim)`` below ``low``, takes it over
-    ``factor`` above ``high``, a linear blend between.  A constant of
-    the configuration, worked in float64 (at position 8191 a float32
-    rounding of the frequency is 5e-4 rad)."""
-    freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    low, high = yarn_correction_range(
-        dim, theta, original_len, beta_fast, beta_slow
-    )
-    ramp = np.clip(
-        (np.arange(dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0
-    )
-    return freq / factor * ramp + freq * (1.0 - ramp)
-
-
 def softmax_scale(cfg: SarvamMlaConfig) -> float:
     """``d_qk^-1/2 x m^2``, ``m`` yarn's attention factor over all
     dims."""
     scale = cfg.qk_head_dim ** -0.5
     if cfg.rope_mscale_all_dim:
-        scale *= yarn_mscale(
+        scale *= layers.yarn_mscale(
             cfg.rope_factor, cfg.rope_mscale_all_dim
         ) ** 2
     return scale
-
-
-def _rope(x, cos, sin):
-    """``x [b, s, heads, rope]``, half-split pairs, float32 inside."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    ).astype(x.dtype)
-
-
-def _attention(impl: str, q, k, v, scale):
-    if impl == "xla":
-        return xla_causal_attention(q, k, v, dtype=v.dtype, scale=scale)
-    if impl == "flash":
-        from dlrover_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, scale=scale)
-    raise ValueError(f"no latent attention through {impl!r}")
 
 
 class LatentAttention(nn.Module):
@@ -195,36 +125,41 @@ class LatentAttention(nn.Module):
             cfg.num_heads_held, cfg.qk_nope_dim, cfg.qk_rope_dim,
             cfg.v_head_dim,
         )
+        proj = partial(
+            layers.dense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            init_std=cfg.init_std,
+        )
         with jax.named_scope("mla_q"):
-            q = _dense(cfg, heads * (nope + rope), "q_proj")(x)
+            q = proj(heads * (nope + rope), "q_proj")(x)
         with jax.named_scope("mla_kv_down"):
-            down = _dense(cfg, cfg.kv_lora_rank + rope, "kv_down")(x)
-            latent = RMSNorm(cfg.rms_eps, name="kv_norm")(
+            down = proj(cfg.kv_lora_rank + rope, "kv_down")(x)
+            latent = layers.RMSNorm(cfg.rms_eps, name="kv_norm")(
                 down[..., :cfg.kv_lora_rank]
             )
         with jax.named_scope("mla_kv_up"):
-            up = _dense(cfg, heads * (nope + dv), "kv_up")(latent)
+            up = proj(heads * (nope + dv), "kv_up")(latent)
         with jax.named_scope("mla_rope"):
             angles = (
                 jnp.arange(s, dtype=jnp.float32)[:, None]
-                * jnp.asarray(yarn_inv_freq(
+                * jnp.asarray(layers.yarn_inv_freq(
                     rope, cfg.rope_theta, cfg.rope_factor,
                     cfg.rope_original_len, cfg.rope_beta_fast,
                     cfg.rope_beta_slow,
                 ), jnp.float32)[None, :]
             )
-            m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / (
-                yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+            m = layers.yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / (
+                layers.yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
             )
             cos = (jnp.cos(angles) * m)[None, :, None, :]
             sin = (jnp.sin(angles) * m)[None, :, None, :]
             q = q.reshape(b, s, heads, nope + rope)
             q = jnp.concatenate(
-                [q[..., :nope], _rope(q[..., nope:], cos, sin)], axis=-1
+                [q[..., :nope], layers.rotate_half(q[..., nope:], cos, sin)],
+                axis=-1,
             )
             up = up.reshape(b, s, heads, nope + dv)
             # the one rope key, broadcast to every head's key
-            k_pe = _rope(
+            k_pe = layers.rotate_half(
                 down[..., None, cfg.kv_lora_rank:], cos, sin
             )
             k = jnp.concatenate([
@@ -232,24 +167,14 @@ class LatentAttention(nn.Module):
                 jnp.broadcast_to(k_pe, (b, s, heads, rope)),
             ], axis=-1)
             v = up[..., nope:]
-        out = _attention(
-            cfg.attention_impl, q, k, v, softmax_scale(cfg)
+        out = layers.attention(
+            cfg.attention_impl, q, k, v, scale=softmax_scale(cfg),
+            dtype=cfg.dtype,
         )
         with jax.named_scope("mla_out"):
-            return _dense(cfg, cfg.hidden_dim, "o_proj")(
+            return proj(cfg.hidden_dim, "o_proj")(
                 out.reshape(b, s, heads * dv)
             )
-
-
-class DenseMLP(nn.Module):
-    config: SarvamMlaConfig
-
-    @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        cfg = self.config
-        gate = _dense(cfg, cfg.dense_dim, "gate_proj")(x)
-        up = _dense(cfg, cfg.dense_dim, "up_proj")(x)
-        return _dense(cfg, cfg.hidden_dim, "down_proj")(nn.silu(gate) * up)
 
 
 class SarvamMlaBlock(nn.Module):
@@ -264,11 +189,14 @@ class SarvamMlaBlock(nn.Module):
     def __call__(self, x: jax.Array):
         cfg = self.config
         x = x + LatentAttention(cfg, name="attn")(
-            RMSNorm(cfg.rms_eps, name="ln_attn")(x)
+            layers.RMSNorm(cfg.rms_eps, name="ln_attn")(x)
         )
-        h = RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
+        h = layers.RMSNorm(cfg.rms_eps, name="ln_mlp")(x)
         if self.dense:
-            return x + DenseMLP(cfg, name="mlp")(h), None
+            return x + layers.SwiGLU(
+                cfg.dense_dim, cfg.hidden_dim, cfg.dtype,
+                cfg.param_dtype, cfg.init_std, name="mlp",
+            )(h), None
         out, stats = DroplessMoE(
             num_experts=cfg.num_experts, mlp_dim=cfg.expert_dim,
             top_k=cfg.top_k, dtype=cfg.dtype,
@@ -301,12 +229,10 @@ class SarvamMla(nn.Module):
             embedding_init=nn.initializers.normal(cfg.init_std),
             name="wte",
         )(tokens)
-        block = SarvamMlaBlock
-        if cfg.remat:
-            block = nn.remat(
-                SarvamMlaBlock, prevent_cse=True,
-                policy=_remat_policy(cfg.remat_policy),
-            )
+        block = (
+            layers.rematted(SarvamMlaBlock, prevent_cse=True) if cfg.remat
+            else SarvamMlaBlock
+        )
         per_layer = []
         for i in range(cfg.num_layers):
             x, stats = block(
@@ -314,19 +240,17 @@ class SarvamMla(nn.Module):
             )(x)
             if stats is not None:
                 per_layer.append(stats)
-        x = RMSNorm(cfg.rms_eps, name="ln_f")(x)
+        x = layers.RMSNorm(cfg.rms_eps, name="ln_f")(x)
         if not return_hidden:
-            x = _dense(cfg, cfg.vocab_size, "lm_head")(x).astype(
-                jnp.float32
-            )
+            x = layers.dense(
+                cfg.vocab_size, "lm_head", cfg.dtype, cfg.param_dtype,
+                cfg.init_std,
+            )(x).astype(jnp.float32)
         if not return_router_stats:
             return x
         return x, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
 
-    def init_params(self, rng, batch_size: int = 2, seq_len: int = 0):
-        seq_len = seq_len or min(self.config.max_seq_len, 128)
-        tokens = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
-        return self.init(rng, tokens)["params"]
+    init_params = layers.init_params
 
 
 def bias_deltas(counts, rate: float):

@@ -60,7 +60,7 @@ What a rematted caller keeps.  The backward kernels read the five
 arrays the forward rule hands them: ``q``, ``k``, ``v`` as folded,
 ``out`` and ``lse``.  The rule names all five (``RESIDUAL_NAMES``),
 and a ``jax.checkpoint`` whose policy saves those names
-(``models/gpt.py::_remat_policy``) runs in its backward neither the
+(``models/layers.py::remat_policy``) runs in its backward neither the
 forward kernel again nor anything that stands before it only to feed
 it.
 
@@ -1085,7 +1085,7 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] tensors.
 
-    Drop-in for :func:`dlrover_tpu.models.gpt.xla_causal_attention`.
+    Drop-in for :func:`dlrover_tpu.ops.attention.xla_causal_attention`.
     Sequence length must be divisible by the block sizes (the caller
     pads; GPT training shapes are powers of two).
 
@@ -1170,9 +1170,3 @@ def flash_attention(
     if dtype is not None:
         out = out.astype(dtype)
     return out
-
-
-# dispatch layers (LlamaAttention) key on this instead of the impl
-# string: only the plain flash path accepts kv_heads < heads
-# (ulysses all-to-alls heads across devices and needs the repeat)
-flash_attention.gqa_aware = True

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from dlrover_tpu.ops.attention import xla_causal_attention
 from dlrover_tpu.parallel.mesh import batch_axes
 
 
@@ -198,8 +199,6 @@ def ring_attention(
         # no ring to rotate (running the ring machinery on one device
         # would only add a no-op scan + self-permute)
         if causal:
-            from dlrover_tpu.models.gpt import xla_causal_attention
-
             return xla_causal_attention(q, k, v, dtype=q.dtype)
         logits = jnp.einsum(
             "bqhd,bkhd->bhqk", q, k,

@@ -530,9 +530,9 @@ def recovery_budgets(
     """Per-incarnation recovery budget from the raw event stream:
     ``{(node_rank, restart_count): {phase: seconds, ...,
     "compile_cache_hit": bool?, "retrace_s": float?}}`` — the single
-    ingestion path the incident report, bench.py and the chaos
-    cache-hit invariants all read, so they can never disagree about
-    what was measured."""
+    ingestion path the incident report and the chaos cache-hit
+    invariants both read, so they can never disagree about what was
+    measured."""
     out: Dict[Tuple[int, int], Dict] = {}
     for e in events:
         etype = e.get("type")

@@ -29,8 +29,8 @@ named phases, each measured where it actually runs:
 
 Each phase lands as a ``recovery_phase`` event + a
 ``dlrover_recovery_phase_seconds{phase}`` histogram sample, so the
-chaos invariants, the timeline's recovery breakdown and bench.py all
-read the same numbers.  The agent exports ``DLROVER_RECOVERY_T0``
+chaos invariants and the timeline's recovery breakdown read the same
+numbers.  The agent exports ``DLROVER_RECOVERY_T0``
 (the wall clock at which it observed the death) into every respawned
 worker's env; without it the profiler still measures import/restore/
 retrace relative to process start (a first incarnation, or a cold

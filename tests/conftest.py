@@ -113,21 +113,23 @@ def flash_forwards(jaxpr):
 
 @pytest.fixture
 def remat_keeps_what_flash_reads(monkeypatch):
-    """``check(module, loss, params, forwards)``: ``jax.grad(loss)`` of
-    a rematted model with flash attention, whose blocks take their
-    policy from ``module._remat_policy``, calls the forward kernel
+    """``check(loss, params, forwards)``: ``jax.grad(loss)`` of a
+    rematted model with flash attention, whose blocks take their
+    policy from ``models/layers.py::remat_policy``, calls the forward kernel
     ``forwards`` times, never under a ``checkpoint``; with the parent's
     policy (``None``: keep nothing) each runs a second time there, and
     loss and every gradient leaf are the same numbers bit for bit."""
     import numpy as np
 
-    def check(module, loss, params, forwards):
+    from dlrover_tpu.models import layers
+
+    def check(loss, params, forwards):
         grad = jax.value_and_grad(loss)
         where = flash_forwards(jax.make_jaxpr(grad)(params).jaxpr)
         assert len(where) == forwards, where
         assert not any(REMAT_PRIMITIVE in under for under in where), where
         kept = jax.jit(grad)(params)
-        monkeypatch.setattr(module, "_remat_policy", lambda name: None)
+        monkeypatch.setattr(layers, "remat_policy", lambda name: None)
         # another function: a trace is cached by its function
         grad = jax.value_and_grad(lambda p: loss(p))
         where = flash_forwards(jax.make_jaxpr(grad)(params).jaxpr)
@@ -144,10 +146,12 @@ def remat_keeps_what_flash_reads(monkeypatch):
 
 @pytest.fixture
 def remat_with_xla_attention_is_the_parents(monkeypatch):
-    """``check(module, loss, params)``: with XLA attention the names
+    """``check(loss, params)``: with XLA attention the names
     do not occur, and ``jax.grad(loss)`` is the jaxpr it is under the
     parent's policy (``None``), the ``policy=`` parameter apart."""
     import re
+
+    from dlrover_tpu.models import layers
 
     def text(loss, params):
         # a function of its own: a trace is cached by its function
@@ -155,12 +159,12 @@ def remat_with_xla_attention_is_the_parents(monkeypatch):
             jax.make_jaxpr(jax.grad(lambda p: loss(p)))(params)
         ))
 
-    def check(module, loss, params):
+    def check(loss, params):
         ours = text(loss, params)
         assert "policy=" in ours and "name=flash" not in ours
-        monkeypatch.setattr(module, "_remat_policy", lambda name: None)
+        monkeypatch.setattr(layers, "remat_policy", lambda name: None)
         assert ours == text(loss, params)
-        assert module._remat_policy("full") is None
+        assert layers.remat_policy("full") is None
 
     return check
 
@@ -194,7 +198,6 @@ def pytest_collection_modifyitems(config, items):
         "test_telemetry.py", "test_otlp.py", "test_timeline.py",
         "test_goodput_ledger.py", "test_event_lint.py",
         "test_deep_diagnosis.py", "test_gcp_monitoring.py",
-        "test_bench_guard.py",
         "test_chaos.py",
         "test_restore_pipeline.py", "test_master_journal.py",
         "test_resize.py", "test_sparse_checkpoint.py",

@@ -589,7 +589,7 @@ def test_warm_restart_template_chaos(tmp_path, factory):
 
 @pytest.mark.slow
 def test_goodput_under_scheduled_churn(tmp_path):
-    """ISSUE 4 satellite: bench.py's churn section as a seeded
+    """ISSUE 4 satellite: goodput under churn as a seeded
     scenario — one SIGKILL per incarnation at fixed absolute steps,
     warm restarts + per-step flash snapshots keeping recovery short.
     The master's own accounting (dlrover_goodput_ratio, stamped on
@@ -657,7 +657,7 @@ def test_warm_recovery_cache_hit(tmp_path):
     )
     assert report.ok, report.summary()
     # the per-cycle budget is also derivable through the shared
-    # ingestion helper (what bench.py and the incident report use)
+    # ingestion helper (what the incident report uses)
     from dlrover_tpu.telemetry.timeline import recovery_budgets
 
     budgets = {

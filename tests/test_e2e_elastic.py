@@ -16,7 +16,124 @@ from dlrover_tpu.checkpoint.saver import (
     read_last_checkpoint,
 )
 
-from bench import ELASTIC_TRAIN_SCRIPT as TRAIN_SCRIPT
+# The job's training script.  Every incarnation runs the
+# RecoveryProfiler: restore overlaps the model/step build via
+# load_checkpoint_async, the first step's trace+compile is bracketed
+# as the retrace phase (compile-cache hit/miss witnessed from the
+# cache dir), and the whole death->first-step budget lands as
+# recovery_phase events.  It crashes itself once.  argv: ckpt_dir
+# crash_flag restored_flag crash_mode(exit|kill)
+TRAIN_SCRIPT = r'''
+import os, sys, time
+import jax
+jax.config.update("jax_platforms", "cpu")
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from dlrover_tpu.checkpoint.checkpointer import Checkpointer, StorageType
+from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+from dlrover_tpu.trainer.elastic_trainer import (
+    ElasticTrainer, TrainState, abstract_like, make_train_step,
+    restore_train_state,
+)
+from dlrover_tpu.trainer.recovery import RecoveryProfiler
+
+ckpt_dir, crash_flag, restored_flag, crash_mode = sys.argv[1:5]
+
+prof = RecoveryProfiler()
+# restore overlap: read/assemble run on a background thread while the
+# model/optimizer/jitted step are built below
+ckpt = Checkpointer(ckpt_dir)
+load_handle = ckpt.load_checkpoint_async()
+
+cfg = GPTConfig.tiny()
+model = GPT(cfg)
+optimizer = optax.adam(1e-3)
+
+def loss_fn(p, batch):
+    logits = model.apply({"params": p}, batch["x"])
+    return cross_entropy_loss(logits, batch["y"])
+
+step_fn = make_train_step(loss_fn, optimizer)
+rng = np.random.default_rng(0)
+data = rng.integers(0, cfg.vocab_size, (8, 17), dtype=np.int32)
+
+# AOT executable cache, resolved while the restore read runs on its
+# own thread: a warm incarnation resolves through the label index
+# and deserializes the compiled step (no eval_shape, no trace); a
+# cold one traces and writes the entry + index the replacement hits
+batch = {"x": jnp.asarray(data[:, :-1]), "y": jnp.asarray(data[:, 1:])}
+
+def _abstract_examples():
+    abs_params = jax.eval_shape(
+        model.init_params, jax.random.PRNGKey(0)
+    )
+    abs_state = jax.eval_shape(
+        lambda p: TrainState.create(p, optimizer), abs_params
+    )
+    return abs_state, abstract_like(batch)
+
+step = prof.resolve_step(
+    step_fn, _abstract_examples,
+    restore_busy=lambda: not load_handle.done(),
+)
+
+start_step, restored = load_handle.result()
+prof.record_restore(ckpt.last_restore_phases)
+if start_step is None:
+    params = model.init_params(jax.random.PRNGKey(0))
+    start_step = 0
+    state = TrainState.create(params, optimizer)
+else:
+    # shaved state_build: batched device_put + deferred optimizer
+    # init (the checkpoint supplies the optax slots)
+    state = restore_train_state(optimizer, restored["state"])
+
+trainer = ElasticTrainer(global_batch_size=8, micro_batch_size=8,
+                         dp_size=1)
+trainer.global_step = start_step
+
+_first_step = True
+for i in range(start_step, 5):
+    with trainer.profile("h2d"):
+        batch = {"x": jnp.asarray(data[:, :-1]),
+                 "y": jnp.asarray(data[:, 1:])}
+    with trainer.profile("compute") as _p:
+        state, metrics = step(state, batch)
+        if _first_step:
+            _first_step = False
+            jax.block_until_ready(metrics)
+            prof.record_first_step()
+        _p.block(metrics)
+    trainer.report_step(metrics)
+    ckpt.save_checkpoint(
+        trainer.global_step,
+        {"state": state, "trainer": trainer.state_dict()},
+        storage_type=StorageType.MEMORY,
+    )
+    ckpt.wait()  # the crash below comes AFTER the commit to shm
+    if start_step > 0 and not os.path.exists(restored_flag):
+        open(restored_flag, "w").close()  # first step after restore
+    if trainer.global_step == 3 and not os.path.exists(crash_flag):
+        open(crash_flag, "w").close()
+        if crash_mode == "kill":
+            os.kill(os.getpid(), 9)  # hard kill AFTER the shm save
+        sys.exit(17)  # simulated crash AFTER the shm save
+
+ckpt.save_checkpoint(
+    5, {"state": state, "trainer": trainer.state_dict()},
+    storage_type=StorageType.DISK,
+)
+# wait for the agent-side async persist to commit before exiting
+ckpt.wait()
+tracker = os.path.join(ckpt_dir, "latest_checkpointed_iteration.txt")
+deadline = time.time() + 60
+while time.time() < deadline and not os.path.exists(tracker):
+    time.sleep(0.2)
+assert os.path.exists(tracker), "checkpoint commit did not land"
+ckpt.close()
+'''
 
 
 def test_tpurun_crash_restart_restore(tmp_path, monkeypatch):

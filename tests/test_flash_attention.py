@@ -18,7 +18,7 @@ from conftest import (
 )
 from jax.ad_checkpoint import checkpoint_name
 
-from dlrover_tpu.models.gpt import xla_causal_attention
+from dlrover_tpu.ops.attention import xla_causal_attention
 from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.ops.flash_attention import flash_attention
 
@@ -386,7 +386,7 @@ def test_model_integration_flash_impl():
 def test_flash_attention_head_dim_128():
     """Llama-7B-class head_dim: kernel tiling must hold at d=128."""
     q, k, v = _rand_qkv(b=1, s=256, h=2, d=128, dtype=jnp.bfloat16)
-    from dlrover_tpu.models.gpt import xla_causal_attention
+    from dlrover_tpu.ops.attention import xla_causal_attention
 
     ref = xla_causal_attention(q, k, v)
     out = flash_attention(q, k, v)
@@ -847,10 +847,10 @@ def test_a_rematted_caller_keeps_the_five_residuals_and_runs_nothing_twice(
     under its name, and nothing else; under the parent's
     ``policy=None`` kernel and projection run again inside it, and
     value and gradients are the same bits."""
-    from dlrover_tpu.models.gpt import _remat_policy
+    from dlrover_tpu.models.layers import remat_policy
 
     saved_residuals = jax_internal("ad_checkpoint", "saved_residuals")
-    loss, args = _rematted_loss(_remat_policy("full"), dtype, kv_heads)
+    loss, args = _rematted_loss(remat_policy("full"), dtype, kv_heads)
     grad = jax.value_and_grad(loss)
     jaxpr = jax.make_jaxpr(grad)(args).jaxpr
     assert flash_forwards(jaxpr) == [()]
@@ -894,9 +894,9 @@ def test_a_saved_residual_costs_no_pass_over_it(dtype, kv_heads):
     bitcast either: the test above reads their types).  A program
     that is not rematted has no trace of a name: its lowering holds
     no ``reduce_precision`` either way."""
-    from dlrover_tpu.models.gpt import _remat_policy
+    from dlrover_tpu.models.layers import remat_policy
 
-    loss, args = _rematted_loss(_remat_policy("full"), dtype, kv_heads)
+    loss, args = _rematted_loss(remat_policy("full"), dtype, kv_heads)
     text = str(jax.make_jaxpr(jax.grad(loss))(args))
     assert all(f"name={name}" in text for name in fa.RESIDUAL_NAMES)
     assert "reduce_precision" not in text
@@ -912,7 +912,7 @@ def test_a_saved_residual_costs_no_pass_over_it(dtype, kv_heads):
     # bitcasts can go; where one guards a residual nothing reads,
     # q, k and v need ``_named`` too)
     @functools.partial(
-        jax.checkpoint, prevent_cse=True, policy=_remat_policy("full")
+        jax.checkpoint, prevent_cse=True, policy=remat_policy("full")
     )
     def plainly(x):
         return jnp.sin(checkpoint_name(jnp.cos(x), fa.RESIDUAL_NAMES[3]))
@@ -928,15 +928,16 @@ def test_a_saved_residual_costs_no_pass_over_it(dtype, kv_heads):
 )
 def test_the_remat_policies_are_full_and_offload(policy, refused):
     """``save_attn`` is gone: what it promised is what ``full`` does."""
-    from dlrover_tpu.models.gpt import GPTConfig, _remat_policy
+    from dlrover_tpu.models.gpt import GPTConfig
+    from dlrover_tpu.models.layers import remat_policy
 
     if refused is None:
         assert GPTConfig.tiny(
             remat=True, remat_policy=policy
         ).remat_policy == policy
-        assert callable(_remat_policy(policy))
+        assert callable(remat_policy(policy))
         return
     with pytest.raises(ValueError, match=re.escape(refused)):
         GPTConfig.tiny(remat=True, remat_policy=policy)
     with pytest.raises(ValueError, match=re.escape(refused)):
-        _remat_policy(policy)
+        remat_policy(policy)

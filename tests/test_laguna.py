@@ -32,9 +32,9 @@ from dlrover_tpu.models.laguna import (  # noqa: E402
     LagunaConfig,
     make_laguna_loss,
     window_tiles_share,
-    xla_window_attention,
 )
-from dlrover_tpu.models.sarvam_mla import yarn_correction_range  # noqa: E402
+from dlrover_tpu.models.layers import yarn_correction_range  # noqa: E402
+from dlrover_tpu.ops.attention import xla_window_attention  # noqa: E402
 from dlrover_tpu.optim import adamw_bf16  # noqa: E402
 from dlrover_tpu.parallel.moe import DroplessMoE, dropless_moe  # noqa: E402
 from dlrover_tpu.telemetry.events import read_events  # noqa: E402
@@ -143,10 +143,10 @@ def test_a_rematted_block_keeps_what_its_flash_backward_reads(
         return loss_fn(p, batch)[0]
 
     if attention == "xla":
-        remat_with_xla_attention_is_the_parents(laguna, loss, params)
+        remat_with_xla_attention_is_the_parents(loss, params)
     else:
         remat_keeps_what_flash_reads(
-            laguna, loss, params, len(cfg["layer_types"])
+            loss, params, len(cfg["layer_types"])
         )
 
 

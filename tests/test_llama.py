@@ -9,7 +9,8 @@ import pytest
 from jax.sharding import NamedSharding
 
 from dlrover_tpu.models.gpt import cross_entropy_loss
-from dlrover_tpu.models.llama import Llama, LlamaConfig, rope
+from dlrover_tpu.models.layers import rope
+from dlrover_tpu.models.llama import Llama, LlamaConfig
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.sharding import (
     batch_spec,

@@ -123,7 +123,6 @@ def test_a_rematted_block_keeps_what_its_flash_backward_reads(
     run again for the backward; loss and gradients the parent policy's
     bit for bit (the linear layers' rule keeps what it kept).  With XLA
     attention nothing is named and the program is the parent's."""
-    from dlrover_tpu.models import olmo_hybrid
 
     model, params, batch = toy(remat=True, attention_impl=attention)
     loss_fn = make_olmo_hybrid_loss(model, num_chunks=5)
@@ -132,9 +131,9 @@ def test_a_rematted_block_keeps_what_its_flash_backward_reads(
         return loss_fn(p, batch)[0]
 
     if attention == "xla":
-        remat_with_xla_attention_is_the_parents(olmo_hybrid, loss, params)
+        remat_with_xla_attention_is_the_parents(loss, params)
     else:
-        remat_keeps_what_flash_reads(olmo_hybrid, loss, params, 1)
+        remat_keeps_what_flash_reads(loss, params, 1)
 
 
 def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():

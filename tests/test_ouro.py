@@ -153,7 +153,6 @@ def test_a_rematted_block_keeps_what_its_flash_backward_reads(
     and gradients the parent policy's bit for bit.  With XLA attention
     nothing is named and the program is the parent's.  The block takes
     its policy where the other families take theirs."""
-    from dlrover_tpu.models import ouro
 
     _, cfg, _, loss_fn, params, batch = toy(attention=attention, remat=True)
 
@@ -161,10 +160,10 @@ def test_a_rematted_block_keeps_what_its_flash_backward_reads(
         return loss_fn(p, batch)[0]
 
     if attention == "xla":
-        remat_with_xla_attention_is_the_parents(ouro, loss, params)
+        remat_with_xla_attention_is_the_parents(loss, params)
     else:
         remat_keeps_what_flash_reads(
-            ouro, loss, params, cfg["num_hidden_layers"]
+            loss, params, cfg["num_hidden_layers"]
         )
 
 

@@ -4,7 +4,6 @@ attention the one remat policy keeps the kernel's five residuals
 (``ops/flash_attention.py::RESIDUAL_NAMES``), so nothing that stands
 before the kernel only to feed it is in the rematted computation."""
 
-import importlib
 import os
 import sys
 
@@ -17,6 +16,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import loader  # noqa: E402  (the benchmark's own)
+
+from dlrover_tpu.models import layers  # noqa: E402
 
 # family -> (attention's matmuls that feed only the kernel, those whose
 # results something else's gradient reads and that are run again)
@@ -91,10 +92,7 @@ def test_a_rematted_block_runs_nothing_again_only_to_feed_its_flash_kernel(
     assert not again & dead and alive <= again, again
     kept = jax.jit(grad)(params)
 
-    monkeypatch.setattr(
-        importlib.import_module(f"dlrover_tpu.models.{family}"),
-        "_remat_policy", lambda name: None,
-    )
+    monkeypatch.setattr(layers, "remat_policy", lambda name: None)
     # another function: a trace is cached by its function
     grad = jax.value_and_grad(lambda p: loss(p))
     again = recomputed_attention_matmuls(jax.make_jaxpr(grad)(params).jaxpr)

@@ -419,8 +419,8 @@ def test_restore_span_and_event_carry_stage_breakdown(
     saver, tmp_path, monkeypatch
 ):
     """The ckpt.restore span and the checkpoint_restore event both
-    carry tier + read_s/assemble_s/h2d_s — what bench.py and the
-    chaos tier invariant consume."""
+    carry tier + read_s/assemble_s/h2d_s — what the chaos tier
+    invariant consumes."""
     from dlrover_tpu.telemetry.events import EVENT_LOG_ENV, read_events
     from dlrover_tpu.telemetry.tracing import get_tracer
 

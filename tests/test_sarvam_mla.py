@@ -35,14 +35,16 @@ from dlrover_tpu.checkpoint.saver import (  # noqa: E402
     AsyncCheckpointSaver,
     SaverConfig,
 )
+from dlrover_tpu.models.layers import (  # noqa: E402
+    yarn_correction_range,
+    yarn_inv_freq,
+)
 from dlrover_tpu.models.sarvam_mla import (  # noqa: E402
     SarvamMla,
     SarvamMlaConfig,
     bias_deltas,
     make_sarvam_mla_loss,
     softmax_scale,
-    yarn_correction_range,
-    yarn_inv_freq,
 )
 from dlrover_tpu.ops import grouped_matmul as gmm  # noqa: E402
 from dlrover_tpu.optim import adamw_bf16  # noqa: E402
@@ -182,7 +184,6 @@ def test_a_rematted_block_keeps_what_its_flash_backward_reads(
     again for the backward; loss and gradients the parent policy's bit
     for bit.  With XLA attention nothing is named and the program is
     the parent's."""
-    from dlrover_tpu.models import sarvam_mla
 
     model, params, batch = toy(remat=True, attention_impl=attention)
     loss_fn = make_sarvam_mla_loss(model, num_chunks=4)
@@ -191,10 +192,10 @@ def test_a_rematted_block_keeps_what_its_flash_backward_reads(
         return loss_fn(p, batch)[0]
 
     if attention == "xla":
-        remat_with_xla_attention_is_the_parents(sarvam_mla, loss, params)
+        remat_with_xla_attention_is_the_parents(loss, params)
     else:
         remat_keeps_what_flash_reads(
-            sarvam_mla, loss, params, CFG["num_hidden_layers"]
+            loss, params, CFG["num_hidden_layers"]
         )
 
 

@@ -2,13 +2,15 @@
 Ulysses all-to-all attention and ring attention match single-device
 full attention, forward and gradient."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models.gpt import xla_causal_attention
+from dlrover_tpu.ops.attention import xla_causal_attention
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.sequence import ring_attention, ulysses_attention
 
@@ -124,7 +126,7 @@ def test_flash_runs_per_shard_of_the_steps_mesh(kv_heads, batch):
     data x fsdp, heads over tensor (GQA keeps each q head with its kv
     head; a batch the axes do not divide stays replicated), forward
     and gradient equal to the kernel on the whole arrays."""
-    from dlrover_tpu.models.gpt import get_attention_fn
+    from dlrover_tpu.models.layers import attention
     from dlrover_tpu.ops.flash_attention import flash_attention
     from dlrover_tpu.parallel.mesh import scoped_to_mesh
 
@@ -140,7 +142,7 @@ def test_flash_runs_per_shard_of_the_steps_mesh(kv_heads, batch):
 
     ref, g_ref = loss(flash_attention)(q, k, v)
     step = scoped_to_mesh(
-        jax.jit(loss(get_attention_fn("flash"))), mesh
+        jax.jit(loss(functools.partial(attention, "flash"))), mesh
     )
     assert "sdy.manual_computation" in step.lower(q, k, v).as_text()
     out, g = step(q, k, v)

@@ -197,7 +197,7 @@ def test_where_the_compiler_may_merge_the_kept_names_cost_nothing(
     and the step asks the bytes it asks under ``policy=None``.  The
     five kept arrays cost memory only behind ``prevent_cse=True``,
     and each of those four families has a cell and a limit here."""
-    from dlrover_tpu.models import gpt
+    from dlrover_tpu.models import layers
     from dlrover_tpu.models.llama import Llama, LlamaConfig
 
     model = Llama(LlamaConfig(
@@ -229,7 +229,7 @@ def test_where_the_compiler_may_merge_the_kept_names_cost_nothing(
         return _kernels(compiled), compiled.memory_analysis()
 
     kernels, kept = step_memory()
-    monkeypatch.setattr(gpt, "_remat_policy", lambda name: None)
+    monkeypatch.setattr(layers, "remat_policy", lambda name: None)
     parents_kernels, parents = step_memory()
     assert parents_kernels == kernels == 6
     assert parents.temp_size_in_bytes == kept.temp_size_in_bytes
