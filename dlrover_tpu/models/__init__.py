@@ -3,6 +3,7 @@ strategy engine's dry-runner, and the benchmarks."""
 
 from dlrover_tpu.models.gpt import GPT, GPTConfig
 from dlrover_tpu.models.llama import Llama, LlamaConfig
+from dlrover_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from dlrover_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from dlrover_tpu.models.ouro import Ouro, OuroConfig
 from dlrover_tpu.models.sarvam_mla import SarvamMla, SarvamMlaConfig
@@ -16,6 +17,8 @@ __all__ = [
     "GPTConfig",
     "Llama",
     "LlamaConfig",
+    "NemotronH",
+    "NemotronHConfig",
     "OlmoHybrid",
     "OlmoHybridConfig",
     "Ouro",

@@ -1,6 +1,7 @@
 """What the decoder families share: the norm, rope, the dense helper,
-the SwiGLU, the one call that maps ``attention_impl`` to a function,
-and the remat rule.  A family file imports this module, ``losses``,
+the SwiGLU, the causal depthwise convolution of the recurrent mixers,
+the one call that maps ``attention_impl`` to a function, and the
+remat rule.  A family file imports this module, ``losses``,
 ``ops`` and ``parallel``, and no sibling; nothing here knows a family
 (layers take widths and dtypes, never a config object).
 
@@ -75,6 +76,23 @@ class SwiGLU(nn.Module):
         gate = proj(self.mlp_dim, "gate_proj")(x)
         up = proj(self.mlp_dim, "up_proj")(x)
         return proj(self.hidden_dim, "down_proj")(nn.silu(gate) * up)
+
+
+def conv_init(key, shape, dtype):
+    """torch ``Conv1d``'s default for a depthwise kernel of ``taps``:
+    uniform in ``+-taps^-1/2``."""
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def causal_conv(x, taps):
+    """Depthwise over the sequence: ``y_t = sum_j taps[j] x_{t-K+1+j}``,
+    ``x [b, s, c]``, ``taps [K, c]``; float32 sum, nothing from after
+    ``t``."""
+    k, s = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    taps = taps.astype(jnp.float32)
+    return sum(padded[:, j:j + s] * taps[j] for j in range(k))
 
 
 def rotate_half(x, cos, sin):

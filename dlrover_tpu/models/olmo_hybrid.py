@@ -86,13 +86,6 @@ class OlmoHybridConfig:
         ), **kw})
 
 
-def _conv_init(key, shape, dtype):
-    """torch ``Conv1d``'s default for a depthwise kernel of ``taps``:
-    uniform in ``+-taps^-1/2``."""
-    bound = 1.0 / math.sqrt(shape[0])
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
 def _a_log_init(key, shape, dtype):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-6, 16.0))
 
@@ -103,16 +96,6 @@ def _dt_bias_init(key, shape, dtype):
         key, shape, dtype, math.log(1e-3), math.log(1e-1)
     ))
     return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def causal_conv(x, taps):
-    """Depthwise over the sequence: ``y_t = sum_j taps[j] x_{t-K+1+j}``,
-    ``x [b, s, c]``, ``taps [K, c]``; float32 sum, nothing from after
-    ``t``."""
-    k, s = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    taps = taps.astype(jnp.float32)
-    return sum(padded[:, j:j + s] * taps[j] for j in range(k))
 
 
 def _indicator(heads, d):
@@ -182,10 +165,11 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gdn_conv"):
             def conv(name, y):
                 taps = self.param(
-                    name, _conv_init, (cfg.conv_kernel, y.shape[-1]),
+                    name, layers.conv_init,
+                    (cfg.conv_kernel, y.shape[-1]),
                     cfg.param_dtype,
                 )
-                return nn.silu(causal_conv(y, taps))
+                return nn.silu(layers.causal_conv(y, taps))
 
             q, k = conv("q_conv", q), conv("k_conv", k)
             v = conv("v_conv", v).astype(cfg.dtype)

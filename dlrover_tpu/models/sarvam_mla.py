@@ -46,7 +46,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
-from dlrover_tpu.parallel.moe import DroplessMoE
+from dlrover_tpu.parallel.moe import DroplessMoE, bias_deltas
 
 
 @dataclass(frozen=True)
@@ -251,14 +251,6 @@ class SarvamMla(nn.Module):
         return x, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
 
     init_params = layers.init_params
-
-
-def bias_deltas(counts, rate: float):
-    """The router bias's rule, a layer a row: ``rate x sign(mean(n) -
-    n_e)``, ``counts [layers, e]`` the step's assignments."""
-    return rate * jnp.sign(
-        counts.mean(axis=1, keepdims=True) - counts
-    )
 
 
 def make_sarvam_mla_loss(model: SarvamMla, num_chunks: int = 8):

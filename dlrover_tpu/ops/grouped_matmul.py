@@ -480,14 +480,21 @@ def tokens_from_rows(
         raise ValueError(f"{m} rows do not divide into tiles of {ROW_TILE}")
     tiles = m // ROW_TILE
     # the widest column block whose accumulator and double-buffered
-    # result fit beside the row tiles
+    # result fit beside the row tiles: the width divided by the least
+    # factor of its lane tiles while it does not (4096 and 3072 go in
+    # halves, 2688 = 21 lane tiles in thirds of 896 and, where
+    # ``init_params`` traces two rows of 8192, on in sevenths)
     tn = d
     while (tokens + 8) * tn * (4 + 2 * rows.dtype.itemsize) > (72 << 20):
-        if tn % 256:
+        lanes, rest = divmod(tn, 128)
+        part = next(
+            (f for f in (2, 3, 5, 7) if not rest and lanes % f == 0), None
+        )
+        if part is None:
             raise ValueError(
                 f"no column block of {d} keeps {tokens} tokens in VMEM"
             )
-        tn //= 2
+        tn //= part
     weighted = weight is not None
     return pl.pallas_call(
         functools.partial(

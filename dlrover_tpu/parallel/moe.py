@@ -272,10 +272,14 @@ def _collect_bwd(source, g):
 _collect_rows.defvjp(_collect_fwd, _collect_bwd)
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(nn.relu(x))
+
+
 def dropless_moe(
     tokens: jax.Array,         # [t, d]
     router_kernel: jax.Array,  # [d, e]
-    w_gate: jax.Array,         # [held experts, d, m]
+    w_gate: Optional[jax.Array],  # [held experts, d, m]; None: ungated
     w_up: jax.Array,           # [held experts, d, m]
     w_down: jax.Array,         # [held experts, m, d]
     top_k: int,
@@ -296,7 +300,10 @@ def dropless_moe(
     own; with ``select_bias`` the k experts are chosen by ``score +
     bias`` and weighted by the score alone (the bias only steers the
     load and takes no gradient); ``renormalise`` divides the k weights
-    by their sum, ``scale`` multiplies them.
+    by their sum, ``scale`` multiplies them.  An expert computes
+    ``down(silu(gate(x)) * up(x))``, three grouped matmuls, or with
+    ``w_gate=None`` ``down(relu(up(x)) ** 2)``, two: an expert that
+    has no gate has no gate matrix.
 
     **Held experts.**  ``held=(lo, count)`` says that this chip holds
     experts ``[lo, lo + count)`` of the layer's ``e`` (the weights are
@@ -312,10 +319,10 @@ def dropless_moe(
     experts held elsewhere behind the rest), the rows gathered in that
     order with each expert's rows starting on a row tile of the
     grouped-matmul kernel (``ops/grouped_matmul.py``), and each expert
-    computes ``down(silu(gate(x)) * up(x))`` on its own rows as three
-    grouped matmuls.  Every shape is static (``t * k`` rows and one
-    tile of padding an expert held, whatever the routing: a batch may
-    send every assignment here); an expert without a token is one tile
+    computes its own rows as grouped matmuls.  Every shape is static
+    (``t * k`` rows and one tile of padding an expert held, whatever
+    the routing: a batch may send every assignment here); an expert
+    without a token is one tile
     of zero rows, and the kernels skip the tiles past the last used
     one: no product, no fetch, no store, so those rows of each
     matmul's result are NOT WRITTEN, forward or backward.  Nothing
@@ -345,9 +352,9 @@ def dropless_moe(
     t, _ = tokens.shape
     e = router_kernel.shape[-1]
     lo, count = (0, e) if held is None else held
-    if w_gate.shape[0] != count:
+    if w_up.shape[0] != count:
         raise ValueError(
-            f"{w_gate.shape[0]} experts' weights for {count} held"
+            f"{w_up.shape[0]} experts' weights for {count} held"
         )
     assignments = t * top_k
     with jax.named_scope("moe_router"):
@@ -435,9 +442,11 @@ def dropless_moe(
                 x, w.astype(dtype), tile_group, tiles_used
             )
 
-        rows = expert(
-            nn.silu(expert(rows, w_gate)) * expert(rows, w_up), w_down
-        )
+        if w_gate is None:
+            hidden = relu2(expert(rows, w_up))
+        else:
+            hidden = nn.silu(expert(rows, w_gate)) * expert(rows, w_up)
+        rows = expert(hidden, w_down)
     with jax.named_scope("moe_combine"):
         if held is None:
             out = jnp.einsum(
@@ -460,7 +469,10 @@ class DroplessMoE(nn.Module):
     it, the train step moves it by the loss's ``state_updates``);
     ``shared_dim`` adds a SwiGLU of that width that every token takes
     (``shared_gate`` / ``shared_up`` / ``shared_down``), under the
-    device scope ``moe_shared``."""
+    device scope ``moe_shared``.  ``expert_form="relu2"`` is a layer
+    of experts WITHOUT a gate matrix, the shared one too
+    (``down(relu(up(x)) ** 2)``): the tree then has no
+    ``experts_w_gate`` and no ``shared_gate``."""
 
     num_experts: int
     mlp_dim: int
@@ -474,19 +486,23 @@ class DroplessMoE(nn.Module):
     renormalise: bool = False
     scale: float = 1.0
     shared_dim: int = 0
+    expert_form: str = "swiglu"  # | "relu2"
 
     @nn.compact
     def __call__(self, x: jax.Array):
         b, s, d = x.shape
         e, m = self.num_experts, self.mlp_dim
         count = e if self.held is None else self.held[1]
+        if self.expert_form not in ("swiglu", "relu2"):
+            raise ValueError(f"no expert form {self.expert_form!r}")
+        gated = self.expert_form == "swiglu"
         router = self.param(
             "router", self.kernel_init, (d, e), self.param_dtype
         )
         w_gate = self.param(
             "experts_w_gate", self.kernel_init, (count, d, m),
             self.param_dtype,
-        )
+        ) if gated else None
         w_up = self.param(
             "experts_w_in", self.kernel_init, (count, d, m),
             self.param_dtype,
@@ -516,11 +532,23 @@ class DroplessMoE(nn.Module):
                 )
 
             with jax.named_scope("moe_shared"):
-                hidden = nn.silu(
-                    dense(self.shared_dim, "shared_gate")(x)
-                ) * dense(self.shared_dim, "shared_up")(x)
+                if gated:
+                    hidden = nn.silu(
+                        dense(self.shared_dim, "shared_gate")(x)
+                    ) * dense(self.shared_dim, "shared_up")(x)
+                else:
+                    hidden = relu2(dense(self.shared_dim, "shared_up")(x))
                 out = out + dense(d, "shared_down")(hidden)
         return out, stats
+
+
+def bias_deltas(counts, rate: float):
+    """The rule of a ``select_bias`` (the loss hands the deltas to the
+    train step as ``state_updates``), a layer a row: ``rate x sign(mean(n) -
+    n_e)``, ``counts [layers, e]`` the step's assignments."""
+    return rate * jnp.sign(
+        counts.mean(axis=1, keepdims=True) - counts
+    )
 
 
 # -- a held range: the rows move from the row side ----------------------------

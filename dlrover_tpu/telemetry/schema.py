@@ -110,12 +110,17 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # exit a token is expected to leave at, the exit
         # distribution's mean entropy, and the first and the last
         # exit's mean cross entropy (what a further pass buys)
+        # ssm.*: a state-space model's layers (models/nemotron_h.py):
+        # the largest rms of a layer's final state, and the mean of
+        # the decay exp(dt A) over tokens, heads and layers (1 / (1 -
+        # a) tokens is how far back a layer remembers)
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
             "moe.held_tiles_share", "moe.bias_abs_max",
             "attn.window_tiles_share", "loop.expected_exit",
-            "loop.exit_entropy", "loop.nll_first", "loop.nll_last"]),
+            "loop.exit_entropy", "loop.nll_first", "loop.nll_last",
+            "ssm.state_rms_max", "ssm.decay_mean"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

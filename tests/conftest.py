@@ -91,6 +91,17 @@ def equations(jaxpr, under=()):
                 yield from equations(sub, under + (eqn.primitive.name,))
 
 
+def primitives_under(jaxpr, scope):
+    """``{primitive: how often}`` of the equations that stand under
+    the named scope ``scope`` of a jaxpr (:func:`equations`' walk)."""
+    found = {}
+    for _, eqn in equations(jaxpr):
+        if scope in str(eqn.source_info.name_stack).split("/"):
+            name = eqn.primitive.name
+            found[name] = found.get(name, 0) + 1
+    return found
+
+
 def pallas_calls(jaxpr):
     """``(under, equation)`` of every ``pallas_call`` of a jaxpr."""
     return (

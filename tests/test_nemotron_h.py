@@ -1,0 +1,740 @@
+"""The Nemotron-H family through the repo's blocks against the plain
+float32 reference (``benchmarks/models/nemotron_h_reference.py``): three
+kinds of mixer in one stack; the chunked state-space scan
+(``ops/ssd.py``) against the recurrence token by token; the ungated
+``relu(.) ** 2`` experts of a held range against their own claims (the
+shares of all chips add up to the whole layer, no gate matrix in the
+tree, no row past the used tiles read) and against the gated layer's
+lowering, which is what it was; an expert width that is no whole
+number of lane tiles through the grouped-matmul kernels; the router's
+bias and the ``ssm.*`` counters through the train step."""
+
+import functools
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+
+import loader  # noqa: E402  (the benchmark's own)
+from conftest import jax_internal, primitives_under  # noqa: E402
+
+from dlrover_tpu.models.gpt import count_params  # noqa: E402
+from dlrover_tpu.models.nemotron_h import (  # noqa: E402
+    NemotronH,
+    NemotronHConfig,
+    make_nemotron_h_loss,
+)
+from dlrover_tpu.ops import grouped_matmul as gmm  # noqa: E402
+from dlrover_tpu.ops.ssd import ssd_scan  # noqa: E402
+from dlrover_tpu.optim import adamw_bf16  # noqa: E402
+from dlrover_tpu.parallel import moe  # noqa: E402
+from dlrover_tpu.parallel.moe import DroplessMoE, dropless_moe  # noqa: E402
+from dlrover_tpu.telemetry.events import read_events  # noqa: E402
+from dlrover_tpu.telemetry.schema import validate_event  # noqa: E402
+from dlrover_tpu.trainer.elastic_trainer import (  # noqa: E402
+    STATE_UPDATES,
+    ElasticTrainer,
+    TrainState,
+    make_train_step,
+)
+
+family = loader.load_module("models", "nemotron_h")
+reference = family.reference
+
+PATTERN = "ME*EM"
+# the HF keys of the tiny configuration, as the reference reads them
+CFG = {
+    "hybrid_override_pattern": PATTERN, "mamba_num_heads": 4,
+    "mamba_head_dim": 8, "n_groups": 2, "ssm_state_size": 16,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "layer_norm_epsilon": 1e-5, "num_experts_per_tok": 3,
+    "first_expert_held": 4, "routed_scaling_factor": 2.5,
+}
+COUNTERS = {
+    "ssm.state_rms_max", "ssm.decay_mean", "moe.held_rows_share",
+    "moe.held_tiles_share", "moe.bias_abs_max",
+}
+
+
+def toy(dtype=jnp.float32, seq=48, **kw):
+    """Three chunks of 16 tokens through ``M E * E M``; 4 of 16
+    experts held."""
+    model = NemotronH(NemotronHConfig.tiny(dtype=dtype, **kw))
+    params = model.init_params(jax.random.PRNGKey(7), seq_len=seq)
+    # weights at 0.02 leave every router near 0.5 and every state near
+    # 0: scale the matrices up so that routing is decided and the
+    # recurrence matters; the biases apart, so that score + bias picks
+    # other experts than the score alone
+    params = jax.tree.map(
+        lambda x: x * (6.0 if x.ndim >= 2 else 1.0), params
+    )
+    for n, i in enumerate(i for i, k in enumerate(PATTERN) if k == "E"):
+        params[f"block_{i}"]["moe"]["select_bias"] = 0.2 * jax.random.normal(
+            jax.random.PRNGKey(20 + n), (16,)
+        )
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (2, seq + 1), 0, 256)
+    return model, params, {"x": tokens[:, :-1], "y": tokens[:, 1:]}
+
+
+def toy_step(**kw):
+    """The toy's jitted train step and its arguments."""
+    model, params, batch = toy(remat=True, **kw)
+    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
+    step = make_train_step(
+        make_nemotron_h_loss(model, num_chunks=4), optimizer
+    )
+    return model, step, TrainState.create(params, optimizer), batch
+
+
+def relative(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.abs(got - want).max() / max(np.abs(want).max(), 1e-30)
+
+
+# -- the family against the reference -----------------------------------------
+
+
+@pytest.mark.parametrize("attention", ["xla", "flash"])
+def test_float32_loss_logits_and_counters_equal_the_reference(attention):
+    model, params, batch = toy(attention_impl=attention)
+    loss, aux = make_nemotron_h_loss(model, num_chunks=4)(params, batch)
+    want, said = reference.loss_and_said(
+        params, batch["x"], batch["y"], CFG
+    )
+    assert abs(float(loss) - float(want)) < 1e-5
+    logits = model.apply({"params": params}, batch["x"])
+    np.testing.assert_allclose(
+        logits, jnp.stack(reference.forward(params, batch["x"], CFG)),
+        rtol=0, atol=2e-4,
+    )
+    assert set(aux) == COUNTERS | {STATE_UPDATES}
+    # the chunked scan's final states are the recurrence's
+    rms = np.sqrt(np.mean(np.square(said["state_rms"]), axis=0).max())
+    assert float(aux["ssm.state_rms_max"]) == pytest.approx(rms, rel=1e-5)
+    assert 0.0 < float(aux["ssm.decay_mean"]) < 1.0
+    # the counter is the reference's count of what reached experts
+    # 4..7 of 16, over both layers' 2 x 48 x 3 assignments each
+    held = float(said["counts"][:, 4:8].sum())
+    assert float(aux["moe.held_rows_share"]) == pytest.approx(
+        held / (2 * 2 * 48 * 3)
+    )
+
+
+def test_float32_gradients_equal_the_reference_leaf_by_leaf():
+    """Every leaf of ``jax.grad`` of the training loss, to 2e-4 of the
+    leaf's largest entry: a state-space layer's eight (``A_log``, ``D``
+    and ``dt_bias`` among them), an expert layer's five that take a
+    gradient, attention's four, the norms, embedding and head.  The
+    bias takes no gradient on either side."""
+    model, params, batch = toy(remat=True, attention_impl="flash")
+    loss_fn = make_nemotron_h_loss(model, num_chunks=4)
+    got = jax.grad(lambda p: loss_fn(p, batch)[0])(params)
+    want = jax.grad(lambda p: reference.loss_and_said(
+        p, batch["x"], batch["y"], CFG
+    )[0])(params)
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    flat_want = jax.tree.leaves(want)
+    # a norm a layer, 8 leaves a state-space mixer, 6 an expert
+    # layer, 4 attention; embedding, final norm, head
+    assert len(flat_got) == len(flat_want) == 5 + 2 * 8 + 2 * 6 + 4 + 3
+    for (path, g), w in zip(flat_got, flat_want):
+        name = jax.tree_util.keystr(path)
+        if "select_bias" in name:
+            assert not np.asarray(g).any() and not np.asarray(w).any()
+            continue
+        assert np.abs(np.asarray(w)).max() > 0, name
+        assert relative(g, w) < 2e-4, name
+
+
+def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
+    model, params, batch = toy(dtype=jnp.bfloat16)
+    loss, _ = make_nemotron_h_loss(model, num_chunks=4)(params, batch)
+    want = reference.loss(params, batch["x"], batch["y"], CFG)
+    assert abs(float(loss) - want) < 0.05
+
+
+def test_the_whole_model_is_causal():
+    """A later token moves no earlier logit: the convolution, the
+    scan across three chunks, attention and the routing."""
+    model, params, batch = toy()
+    x = batch["x"]
+    base = model.apply({"params": params}, x)
+    moved = model.apply(
+        {"params": params}, x.at[:, 30].set((x[:, 30] + 1) % 256)
+    )
+    np.testing.assert_array_equal(base[:, :30], moved[:, :30])
+    assert np.abs(np.asarray(base[:, 30:] - moved[:, 30:])).max() > 1e-3
+
+
+def test_published_sizes_give_the_issues_parameter_counts():
+    """The cut configuration's tree, by shape alone: 38.74 M a
+    state-space layer, 23.40 M an attention layer, 9.978 M an expert
+    (two matrices: NO gate), 1.246 B in all."""
+    cfg = loader.load_json(os.path.join(
+        REPO, "benchmarks", "configs", "nemotron_3_nano_30b_cut.json"
+    ))
+    model, _, _ = family.build(cfg)
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=128)
+    )
+    assert cfg["hybrid_override_pattern"] == "MEMEM*EMEMEM*EMEME"
+    assert count_params(params["block_0"]["ssm"]) == 38_742_208
+    assert count_params(params["block_5"]["attn"]) == 23_396_352
+    experts = params["block_1"]["moe"]
+    assert sorted(experts) == [
+        "experts_w_in", "experts_w_out", "router", "select_bias",
+        "shared_down", "shared_up",
+    ]
+    assert experts["experts_w_in"].shape == (8, 2688, 1856)
+    assert experts["experts_w_out"].shape == (8, 1856, 2688)
+    assert experts["router"].shape == (2688, 128)
+    assert count_params(params) == 1_245_843_840
+    assert params["block_0"]["ssm"]["A_log"].dtype == jnp.float32
+
+
+# -- the chunked scan against the recurrence ----------------------------------
+
+
+def recurrence(x, dt, A, B, C):
+    """One token a step, float32, each head reading its group."""
+    b, _, heads, p = x.shape
+    repeat = heads // B.shape[2]
+    Bh = jnp.repeat(B, repeat, axis=2).astype(jnp.float32)
+    Ch = jnp.repeat(C, repeat, axis=2).astype(jnp.float32)
+
+    def token(state, at):
+        x_t, dt_t, b_t, c_t = at
+        state = (
+            jnp.exp(dt_t * A)[..., None, None] * state
+            + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :]
+        )
+        return state, jnp.sum(state * c_t[:, :, None, :], axis=-1)
+
+    state, y = jax.lax.scan(
+        token, jnp.zeros((b, heads, p, B.shape[-1]), jnp.float32),
+        tuple(a.swapaxes(0, 1) for a in (
+            x.astype(jnp.float32), dt, Bh, Ch
+        )),
+    )
+    return y.swapaxes(0, 1), state
+
+
+def scan_operands(seq, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    b, heads, p, groups, n = 2, 4, 8, 2, 16
+    return (
+        jax.random.normal(ks[0], (b, seq, heads, p)).astype(dtype),
+        0.5 * jax.nn.softplus(jax.random.normal(ks[1], (b, seq, heads))),
+        -jnp.exp(jax.random.normal(ks[2], (heads,))),
+        jax.random.normal(ks[3], (b, seq, groups, n)).astype(dtype),
+        jax.random.normal(ks[4], (b, seq, groups, n)).astype(dtype),
+    )
+
+
+@pytest.mark.parametrize("dtype, seq, limit", [
+    ("float32", 80, 2e-6), ("float32", 70, 2e-6), ("bfloat16", 80, 1e-2),
+])
+def test_the_chunked_scan_is_the_recurrence(dtype, seq, limit):
+    """Five chunks of 16 tokens (and a tail that fills none), two
+    heads a group: the outputs, the final state and all five
+    gradients, of a loss that reads both results, against the
+    recurrence token by token in float32; bf16 operands within their
+    rounding."""
+    operands = scan_operands(seq, jnp.dtype(dtype))
+    weight = jax.random.normal(jax.random.PRNGKey(4), operands[0].shape)
+
+    def scored(rule, *ops):
+        y, state = rule(*ops)
+        return jnp.sum(y.astype(jnp.float32) * weight) + 0.1 * jnp.sum(
+            state ** 2
+        ), (y, state)
+
+    chunked = functools.partial(ssd_scan, chunk=16)
+    (_, got), got_grads = jax.value_and_grad(
+        functools.partial(scored, chunked), argnums=range(5), has_aux=True
+    )(*operands)
+    (_, want), want_grads = jax.value_and_grad(
+        functools.partial(scored, recurrence), argnums=range(5),
+        has_aux=True,
+    )(*operands)
+    assert got[0].dtype == operands[0].dtype
+    assert got[1].dtype == jnp.float32
+    for name, g, w in zip(
+        ("y", "state", "dx", "ddt", "dA", "dB", "dC"),
+        got + got_grads, want + want_grads,
+    ):
+        g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
+        assert np.linalg.norm(g - w) / np.linalg.norm(w) < limit, name
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_a_rematted_block_keeps_nothing_of_the_scan(remat):
+    """The scan is differentiated as written, so what the backward
+    keeps is the block's remat's to say: with ``remat`` no
+    ``[.., chunk, chunk]`` decay or score matrix and no chunk state is
+    a residual of the model's loss (without it they all are: the
+    control)."""
+    model, params, batch = toy(remat=remat)
+    saved = jax_internal("ad_checkpoint", "saved_residuals")(
+        lambda p: make_nemotron_h_loss(model, num_chunks=4)(p, batch)[0],
+        params,
+    )
+    chunk, cfg = model.config.chunk_size, model.config
+    of_the_scan = [
+        a.shape for a, _ in saved if a.ndim >= 5 and (
+            a.shape[-2:] == (chunk, chunk)
+            or a.shape[-2:] == (cfg.ssm_head_dim, cfg.ssm_state)
+        )
+    ]
+    assert bool(of_the_scan) != remat, of_the_scan
+    operands = scan_operands(64, jnp.float32)
+    with pytest.raises(ValueError, match="heads"):
+        ssd_scan(*operands[:3], operands[3][:, :, :1].repeat(3, 2),
+                 operands[4][:, :, :1].repeat(3, 2))
+
+
+# -- the ungated experts ------------------------------------------------------
+
+
+def layer_operands(t=96, d=32, m=24, e=16, seed=1):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (
+        jax.random.normal(ks[0], (t, d)),
+        jax.random.normal(ks[1], (d, e)),
+        jax.random.normal(ks[2], (e, d, m)) * 0.2,
+        jax.random.normal(ks[3], (e, m, d)) * 0.2,
+    )
+
+
+def share(operands, held, top_k=3, bias=None):
+    x, router, w_up, w_down = operands
+    lo, count = held
+    return dropless_moe(
+        x, router, None, w_up[lo:lo + count], w_down[lo:lo + count],
+        top_k, jnp.float32, held=held, score="sigmoid", select_bias=bias,
+        renormalise=True, scale=2.5,
+    )
+
+
+def whole_layer(operands, top_k, bias, scale=2.5):
+    """Every expert on every row."""
+    x, router, w_up, w_down = operands
+    scores = jax.nn.sigmoid(x @ router)
+    _, ids = jax.lax.top_k(scores + bias, top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = scale * chosen / chosen.sum(axis=-1, keepdims=True)
+    out = jnp.zeros_like(x)
+    for e in range(router.shape[1]):
+        w = jnp.sum(jnp.where(ids == e, weights, 0.0), axis=-1)
+        y = jnp.square(jax.nn.relu(x @ w_up[e])) @ w_down[e]
+        out = out + y * w[:, None]
+    return out
+
+
+def test_the_sixteen_shares_add_up_to_the_whole_layer():
+    """Every chip of the group routes over all ``shares x held``
+    experts and computes its own: the routed parts summed, and the
+    shared expert (which every chip computes alike) counted once,
+    equal the uncut layer, every expert on every row: 16 shares of 8
+    experts as the cut configuration's group (128 outputs, top-6).
+    The layer has no gate matrix: not in the tree, not in the shared
+    expert."""
+    shares, held, top_k = 16, 8, 6
+    e = shares * held
+    operands = layer_operands(t=80, e=e, seed=2)
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(5), (e,))
+    parts = [
+        share(operands, (lo, held), top_k, bias)
+        for lo in range(0, e, held)
+    ]
+    want = whole_layer(operands, top_k, bias)
+    np.testing.assert_allclose(
+        sum(out for out, _ in parts), want, atol=2e-5
+    )
+    assert sum(float(s["held_rows"]) for _, s in parts) == 80 * top_k
+    layer = DroplessMoE(
+        num_experts=e, mlp_dim=24, top_k=top_k, dtype=jnp.float32,
+        held=(held, held), score="sigmoid", select_bias=True,
+        renormalise=True, scale=2.5, shared_dim=48,
+        expert_form="relu2",
+    )
+    x = operands[0][None]
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    p = variables["params"]
+    assert sorted(p) == [
+        "experts_w_in", "experts_w_out", "router", "select_bias",
+        "shared_down", "shared_up",
+    ]
+    out, _ = layer.apply(variables, x)
+    routed, _ = dropless_moe(
+        x[0], p["router"], None, p["experts_w_in"], p["experts_w_out"],
+        top_k, jnp.float32, held=(held, held), score="sigmoid",
+        select_bias=p["select_bias"], renormalise=True, scale=2.5,
+    )
+    shared = jnp.square(jax.nn.relu(
+        x[0] @ p["shared_up"]["kernel"]
+    )) @ p["shared_down"]["kernel"]
+    np.testing.assert_allclose(out[0], routed + shared, atol=1e-5)
+
+
+def test_an_ungated_expert_is_two_grouped_matmuls_round_relu2():
+    """The forward of the training loss under ``moe_experts`` and
+    ``moe_shared``, an expert layer: two grouped matmuls (each a
+    ``custom_vjp_call``) and two plain ones, ``relu(.) ** 2`` between;
+    no third matmul, no ``silu``.  (The gated form's count is pinned
+    where its families are tested: ``tests/test_olmoe.py``,
+    ``tests/test_sarvam_mla.py``.)"""
+    model, params, batch = toy()
+    jaxpr = jax.make_jaxpr(make_nemotron_h_loss(model, num_chunks=4))(
+        params, batch
+    ).jaxpr
+    layers = PATTERN.count("E")
+    # (``relu`` is a ``custom_jvp_call``; float32 weights need no cast)
+    assert primitives_under(jaxpr, "moe_experts") == {
+        "custom_vjp_call": 2 * layers, "custom_jvp_call": layers,
+        "square": layers,
+    }
+    assert primitives_under(jaxpr, "moe_shared") == {
+        "dot_general": 2 * layers, "custom_jvp_call": layers,
+        "square": layers, "add": layers,
+    }
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _fill_past(x, tiles_used, fill):
+    """``x`` with ``fill`` in the rows of the tiles from
+    ``tiles_used`` on, and the same done to its cotangent."""
+    past = jnp.arange(x.shape[0]) >= tiles_used[0] * gmm.ROW_TILE
+    return jnp.where(past[:, None], jnp.asarray(fill, x.dtype), x)
+
+
+_fill_past.defvjp(
+    lambda x, tiles_used, fill: (_fill_past(x, tiles_used, fill), tiles_used),
+    lambda fill, tiles_used, g: (_fill_past(g, tiles_used, fill), None),
+)
+
+
+def test_no_row_past_tiles_used_reaches_the_ungated_layer(monkeypatch):
+    """The rows of the tiles past ``tiles_used`` are not written, and
+    ``relu(.) ** 2`` of what the memory held there may be anything:
+    with every such row of both grouped matmuls' results AND of their
+    gradients to the rows, of the dispatch's output and of the
+    combine's gradient overwritten with NaN, the output and all four
+    gradients are finite and bit-equal to the run with zeros there
+    and to the run as it is (``tests/test_sarvam_mla.py`` for the
+    gated form)."""
+    operands = layer_operands(t=512, e=64, seed=2)
+    real = gmm.grouped_matmul
+    held_dispatch, held_combine = moe._held_dispatch, moe._held_combine
+    products = []
+
+    def results(fill):
+        def product(rows, weights, tile_group, tiles_used, *tiles):
+            products.append(int(tiles_used[0]))
+            if fill is None:
+                return real(rows, weights, tile_group, tiles_used, *tiles)
+            return _fill_past(
+                real(
+                    _fill_past(rows, tiles_used, fill), weights,
+                    tile_group, tiles_used, *tiles,
+                ),
+                tiles_used, fill,
+            )
+
+        def dispatch(tokens, source, slot, tiles_used):
+            return _fill_past(
+                held_dispatch(tokens, source, slot, tiles_used),
+                tiles_used, fill,
+            )
+
+        def combine(rows, gate, source, slot, tiles_used):
+            return held_combine(
+                _fill_past(rows, tiles_used, fill), gate, source, slot,
+                tiles_used,
+            )
+
+        monkeypatch.setattr(moe.gmm, "grouped_matmul", product)
+        if fill is not None:
+            monkeypatch.setattr(moe, "_held_dispatch", dispatch)
+            monkeypatch.setattr(moe, "_held_combine", combine)
+        cot = jax.random.normal(jax.random.PRNGKey(7), operands[0].shape)
+        (_, out), grads = jax.value_and_grad(
+            lambda *ops: (lambda out: (jnp.sum(out * cot), out))(
+                share(ops, (8, 4), 4)[0]
+            ), argnums=range(4), has_aux=True,
+        )(*operands)
+        return [np.asarray(a) for a in (out, *grads)]
+
+    as_it_is = results(None)
+    # two products forward, not three; 4 of the layout's 12 tiles used
+    assert products[:2] == [4, 4] and len(products) == 2
+    with_nan, with_zeros = results(jnp.nan), results(0.0)
+    for got, zeros, plain in zip(with_nan, with_zeros, as_it_is):
+        assert np.isfinite(got).all() and got.any()
+        np.testing.assert_array_equal(got, zeros)
+        np.testing.assert_array_equal(got, plain)
+
+
+@pytest.mark.parametrize("k, n", [(96, 232), (232, 96)])
+def test_a_width_of_no_whole_lane_tile_goes_through_every_kernel(k, n):
+    """An expert width of 232 (1856 / 8: one lane tile and 104 lanes)
+    as the output of the up matrix and as the contraction of the down
+    matrix: ``gmm_fwd``, ``gmm_dlhs``, ``gmm_drhs`` against a loop over
+    the groups, and ``gmm_tokens_from_rows`` at that width against a
+    scatter-add."""
+    sizes = [70, 0, 300, 5]
+    layout = gmm.group_layout(jnp.asarray(sizes, jnp.int32), sum(sizes))
+    tile_group, tiles_used, starts = layout
+    padded = tile_group.shape[0] * gmm.ROW_TILE
+    index = np.concatenate([
+        int(starts[g]) + np.arange(size) for g, size in enumerate(sizes)
+    ])
+    ks = jax.random.split(jax.random.PRNGKey(k), 3)
+    dense = jax.random.normal(ks[0], (sum(sizes), k))
+    weights = jax.random.normal(ks[1], (len(sizes), k, n)) * 0.1
+    cot = jax.random.normal(ks[2], (sum(sizes), n))
+
+    def kernels(dense, weights):
+        rows = jnp.zeros((padded, k)).at[index].set(dense)
+        return gmm.grouped_matmul(rows, weights, tile_group, tiles_used)[
+            index
+        ]
+
+    def loop(dense, weights):
+        group = np.repeat(np.arange(len(sizes)), sizes)
+        return jnp.einsum("rk,rkn->rn", dense, weights[group])
+
+    got, pull = jax.vjp(kernels, dense, weights)
+    want, pull_want = jax.vjp(loop, dense, weights)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    for g, w in zip(pull(cot), pull_want(cot)):
+        np.testing.assert_allclose(g, w, atol=1e-4)
+    # the rows back to their tokens at the same width
+    rows = jnp.zeros((padded, n)).at[index].set(cot)
+    token_of_row = jnp.full((padded,), 400, jnp.int32).at[index].set(
+        jnp.asarray(np.concatenate([np.arange(s) for s in sizes]))
+    )
+    back = gmm.tokens_from_rows(rows, token_of_row, tiles_used, 300)
+    np.testing.assert_allclose(
+        back, jnp.zeros((300, n)).at[token_of_row[index]].add(cot),
+        atol=1e-5,
+    )
+
+
+# -- the train step -----------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def compiled_step():
+    model, step, state, batch = toy_step()
+    return model, step.lower(state, batch).compile(), state, batch
+
+
+def test_the_step_moves_the_bias_by_its_rule_and_carries_the_counters(
+    compiled_step,
+):
+    """After one step each expert layer's bias is ``old + u x
+    sign(mean(n) - n)`` EXACTLY (no Adam, no weight decay reached
+    it), every other leaf moved by the optimizer, and the step's
+    metrics are the loss's counters."""
+    model, step, state, batch = compiled_step
+    state = jax.tree.map(jnp.copy, state)   # the step donates it
+    _, aux = make_nemotron_h_loss(model, num_chunks=4)(state.params, batch)
+    before = jax.tree.map(np.asarray, state.params)
+    deltas = jax.tree.map(np.asarray, aux[STATE_UPDATES])
+    new_state, metrics = step(state, batch)
+    assert set(metrics) == COUNTERS | {"loss", "grad_norm"}
+    assert sorted(deltas) == ["block_1", "block_3"]
+    for name, layer in deltas.items():
+        old = before[name]["moe"]["select_bias"]
+        delta = layer["moe"]["select_bias"]
+        assert np.abs(delta).max() == np.float32(0.001)
+        new = np.asarray(new_state.params[name]["moe"]["select_bias"])
+        assert np.array_equal(new, old + delta)
+        assert not np.array_equal(
+            np.asarray(new_state.params[name]["moe"]["router"]),
+            before[name]["moe"]["router"],
+        )
+    moved = np.asarray(new_state.params["block_0"]["ssm"]["A_log"])
+    assert not np.array_equal(moved, before["block_0"]["ssm"]["A_log"])
+
+
+def test_the_layers_scopes_are_in_the_compiled_step(compiled_step):
+    """What the benchmark's readers join on: the state-space mixer's
+    six scopes, the expert layer's five and ``full_attn`` round the
+    module ``attn`` name operations of the compiled step, forward
+    (``jvp(..)``) and backward (``transpose(jvp(..))``): the scan's
+    backward rule among them."""
+    from dlrover_tpu.common.aot_cache import op_names
+
+    _, compiled, _, _ = compiled_step
+    stacks = list(op_names(compiled.as_text())["op_names"].values())
+    for scope in (
+        "ssm_in_proj", "ssm_conv", "ssm_gates", "ssm_scan", "ssm_norm",
+        "ssm_out_proj", "moe_router", "moe_dispatch", "moe_experts",
+        "moe_combine", "moe_shared", "full_attn",
+    ):
+        named = [s for s in stacks if f"({scope})" in s or f"/{scope}/" in s]
+        assert named, scope
+        assert any("transpose(" in s for s in named), scope
+    assert any("full_attn" in s and "/attn/" in s for s in stacks)
+
+
+def test_the_counters_ride_on_the_train_step_event(tmp_path, monkeypatch):
+    path = str(tmp_path / "events.jsonl")
+    monkeypatch.setenv("DLROVER_EVENT_LOG", path)
+    monkeypatch.setenv(
+        "DLROVER_METRICS_FILE", str(tmp_path / "metrics.json")
+    )
+    trainer = ElasticTrainer(4, 4, dp_size=1)
+    trainer.report_step({
+        "loss": jnp.float32(1.5), "grad_norm": jnp.float32(0.1),
+        "ssm.state_rms_max": jnp.float32(0.25),
+        "ssm.decay_mean": jnp.float32(0.875),
+        "moe.held_rows_share": jnp.float32(0.0625),
+    })
+    (event,) = [e for e in read_events(path) if e["type"] == "train_step"]
+    assert event["ssm.state_rms_max"] == 0.25
+    assert event["ssm.decay_mean"] == 0.875
+    assert event["moe.held_rows_share"] == 0.0625
+    assert not validate_event(event)
+
+
+# -- the benchmark's family and harness ---------------------------------------
+
+
+def test_the_compared_leaves_and_their_limits():
+    """Every leaf of the first and the last state-space layer, both
+    attention layers, every norm and router, the last expert layer's
+    held experts; each judged by its own kind's limit."""
+    cfg = loader.load_json(os.path.join(
+        REPO, "benchmarks", "configs", "nemotron_3_nano_30b_cut.json"
+    ))
+    model, _, _ = family.build(cfg)
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=128)
+    )
+    pick = family.compared(cfg)
+    names = [
+        jax.tree_util.keystr(path)
+        for path, _ in jax.tree_util.tree_leaves_with_path(params)
+    ]
+    picked = [name for name in names if pick(name)]
+    kinds = {}
+    for name in picked:
+        kinds.setdefault(family.kind_of(name), []).append(name)
+    assert len(kinds["decay_gradient_tolerance"]) == 2 * 3
+    assert len(kinds["routed_gradient_tolerance"]) == 8 + 2
+    # 2 x 5 further state-space leaves, 2 x 4 attention, 18 + 1 norms
+    assert len(kinds["gradient_tolerance"]) == 10 + 8 + 19
+    assert "['block_16']['ssm']['conv_bias']" in picked
+    assert "['block_2']['ssm']['A_log']" not in picked
+    assert "['block_17']['moe']['experts_w_out']" in picked
+    assert "['block_15']['moe']['experts_w_out']" not in picked
+    assert set(cfg["reference"]) >= set(kinds) | {
+        "loss_tolerance", "router_rms_tolerance", "bias_update_tolerance",
+        "state_rms_tolerance",
+    }
+
+
+@pytest.mark.parametrize("moved, inside", [
+    ({}, True),
+    ({"gradients": {"['block_0']['ssm']['D']": 0.9}}, False),
+    ({"gradients": {"['block_5']['attn']['q_proj']['kernel']": 0.3}}, False),
+    ({"gradients": {"['block_1']['moe']['router']": 0.3}}, True),
+    ({"gradients": {"['block_1']['moe']['router']": float("nan")}}, False),
+    ({"routers_rms": 0.46}, False),
+    ({"bias": 0.5}, False),
+    ({"state_rms": 0.2}, False),
+])
+def test_every_comparison_is_judged_by_its_own_limit(
+    monkeypatch, moved, inside
+):
+    found = {
+        "loss": 9.5, "bias": 0.01, "state_rms": 0.001, "routers_rms": 0.2,
+        "gradients": {
+            "['block_0']['ssm']['D']": 0.1,
+            "['block_5']['attn']['q_proj']['kernel']": 0.05,
+            "['block_1']['moe']['router']": 0.2,
+        },
+    }
+    found = {**found, **moved, "gradients": {
+        **found["gradients"], **moved.get("gradients", {})
+    }}
+    monkeypatch.setattr(family, "comparisons", lambda *a: found)
+    limits = {"reference": {
+        "gradient_tolerance": 0.2, "routed_gradient_tolerance": 0.5,
+        "decay_gradient_tolerance": 0.5, "router_rms_tolerance": 0.45,
+        "bias_update_tolerance": 0.15, "state_rms_tolerance": 0.05,
+    }}
+    loss = family.reference_loss(None, None, None, limits)
+    assert loss == (9.5 if inside else float("inf"))
+
+
+def test_the_flops_keys_count_what_the_family_requires():
+    """``flops.py`` reads GPT-2's key names: on the cut configuration
+    they give the FLOPs a token that ``nemotron_flops.py`` counts
+    layer by layer, to the FLOP."""
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    import flops
+    import nemotron_flops
+
+    cfg = loader.load_json(os.path.join(
+        REPO, "benchmarks", "configs", "nemotron_3_nano_30b_cut.json"
+    ))
+    assert flops.train_flops_per_token(cfg, 8192) == (
+        nemotron_flops.train_flops_per_token(cfg, 8192)
+    ) == 4_022_501_376
+
+
+def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path, checkout):
+    """``benchmarks/run.py`` end to end on the toy configuration:
+    ``tpurun`` -> the worker -> the ``has_aux`` step over three kinds
+    of mixer with its ``state_updates`` -> the reference's loss and
+    the family's own comparisons -> the readers; exit code 3 (a
+    rehearsal, never a result), ``correct`` true."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path))
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run(
+        # (from a checkout of its own: conftest.py, ROADMAP B7)
+        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
+         "--cells", os.path.join(
+             REPO, "benchmarks", "rehearsal_nemotron_h.json"),
+         "--workload", "toy_nemotron_h_steady", "--seed", "4700000007",
+         "--seconds", "1", "--trace", "1"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert done.returncode == 3, done.stdout[-3000:] + done.stderr[-3000:]
+    assert '"correct": true' in done.stdout
+    assert "ssm.state_rms_max" in done.stdout
+
+
+def test_the_benchmark_lists_the_cell_and_its_readers():
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    (cell,) = [
+        w for w in spec["workloads"] if w["name"] == "nemotron_steady_8k"
+    ]
+    assert cell == {**cell, "config": "nemotron_3_nano_30b_cut",
+                    "traffic": "steady_8k", "chips": 1}
+    mine = [
+        m["name"] for m in spec["per_layer"]
+        if m.get("workloads") == ["nemotron_steady_8k"]
+    ]
+    assert mine == [
+        "ssm.scan_ms_per_step", "ssm.scan_roofline_pct",
+        "ssm.mix_ms_per_step", "ssm.proj_ms_per_step",
+        "ssm.state_rms_max", "moe.relu2_expert_roofline_pct",
+    ]
+    for name in mine:
+        reader = loader.load_module("layer_metrics", name)
+        assert reader.NAME == name and reader.MOVES == "tokens_per_s"

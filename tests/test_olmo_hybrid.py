@@ -26,9 +26,9 @@ from dlrover_tpu.models.olmo_hybrid import (  # noqa: E402
     PERIOD,
     OlmoHybrid,
     OlmoHybridConfig,
-    causal_conv,
     make_olmo_hybrid_loss,
 )
+from dlrover_tpu.models.layers import causal_conv  # noqa: E402
 from dlrover_tpu.ops.gated_delta_rule import CHUNK  # noqa: E402
 from dlrover_tpu.optim import adamw_bf16  # noqa: E402
 from dlrover_tpu.telemetry.events import read_events  # noqa: E402

@@ -18,6 +18,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import loader  # noqa: E402  (the benchmark's own)
+from conftest import primitives_under  # noqa: E402
 
 from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss  # noqa: E402
 from dlrover_tpu.models.olmoe import (  # noqa: E402
@@ -299,6 +300,29 @@ def test_no_tokens_by_experts_by_capacity_tensor_in_the_step():
     variables = gshard.init(jax.random.PRNGKey(0), x)
     text = jax.jit(gshard.apply).lower(variables, x).as_text()
     assert (t, cfg.num_experts, capacity) in shapes_in(text)
+
+
+def test_a_gated_expert_is_three_grouped_matmuls_round_one_silu():
+    """The forward of the training loss under ``moe_experts``, a
+    layer: the three grouped matmuls (each a ``custom_vjp_call``)
+    with their weights' casts, one ``silu`` and one product; what it
+    was before ``dropless_moe`` got its ungated form (PR 47: recorded
+    on that PR's parent).  Three cells run this path: a change that
+    moves the count has to be measured in them."""
+    model = Olmoe(OlmoeConfig.tiny())
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=64)
+    )
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    jaxpr = jax.make_jaxpr(make_olmoe_loss(model, num_chunks=4))(
+        params, {"x": tokens, "y": tokens}
+    ).jaxpr
+    layers = model.config.num_layers
+    assert primitives_under(jaxpr, "moe_experts") == {
+        "custom_vjp_call": 3 * layers, "convert_element_type": 3 * layers,
+        "jit": layers, "mul": layers,
+    }
+    assert "experts_w_gate" in params["block_0"]["moe"]
 
 
 def test_the_layers_scopes_are_in_the_compiled_step():

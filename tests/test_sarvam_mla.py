@@ -25,6 +25,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import loader  # noqa: E402  (the benchmark's own)
+from conftest import primitives_under  # noqa: E402
 
 from dlrover_tpu.checkpoint.checkpointer import (  # noqa: E402
     Checkpointer,
@@ -855,6 +856,35 @@ def test_the_defaults_are_the_layer_olmoe_has_always_run(dtype):
 
 
 # -- the step: scopes, counters, the leaf no gradient reaches -----------------
+
+
+def test_a_gated_expert_is_three_grouped_matmuls_round_one_silu():
+    """The forward of the training loss under ``moe_experts`` and
+    ``moe_shared``, an expert layer: three grouped matmuls (each a
+    ``custom_vjp_call``) with their weights' casts, one ``silu`` and
+    one product; the shared expert three plain matmuls, one ``silu``,
+    one product and the sum onto the routed output; what both were
+    before the layer got its ungated form (PR 47: recorded on that
+    PR's parent).  Two cells run this path: a change that moves the
+    count has to be measured in them."""
+    model = SarvamMla(SarvamMlaConfig.tiny(remat=True))
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=64)
+    )
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    jaxpr = jax.make_jaxpr(make_sarvam_mla_loss(model, num_chunks=4))(
+        params, {"x": tokens, "y": tokens}
+    ).jaxpr
+    layers = model.config.num_layers - model.config.first_dense
+    assert primitives_under(jaxpr, "moe_experts") == {
+        "custom_vjp_call": 3 * layers, "convert_element_type": 3 * layers,
+        "jit": layers, "mul": layers,
+    }
+    assert primitives_under(jaxpr, "moe_shared") == {
+        "dot_general": 3 * layers, "convert_element_type": 3 * layers,
+        "jit": layers, "mul": layers, "add": layers,
+    }
+    assert "experts_w_gate" in params["block_1"]["moe"]
 
 
 def test_the_layers_scopes_are_in_the_compiled_step():

@@ -803,6 +803,83 @@ def test_laguna_one_dense_four_sparse_step_fits_the_chip(one_chip, on_tpu):
         assert any(f"/{scope}/" in s for s in stacks.values()), scope
 
 
+def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
+    """The cell's step (``nemotron_3_nano_30b_cut``: ``MEMEM*EMEMEM*EMEME``
+    at the published widths, 8 of 128 experts held, an eighth of the
+    vocabulary, bf16 state, flash attention at 32 heads over 2, per-layer
+    remat, 1 x 8192 tokens): state + temporaries under the chip's 15.75
+    GB, the expert width of 1856 (14.5 lane tiles) WHOLE through the
+    grouped-matmul kernels and the hidden size of 2688 in thirds of 896,
+    two grouped matmuls an expert layer forward, the flash kernels
+    under ``full_attn``, and every scope the benchmark's readers join
+    on in the op-name map."""
+    from dlrover_tpu.common.aot_cache import op_names
+    from dlrover_tpu.models.nemotron_h import (
+        NemotronH,
+        NemotronHConfig,
+        make_nemotron_h_loss,
+    )
+
+    model = NemotronH(NemotronHConfig(
+        vocab_size=16384, pattern="MEMEM*EMEMEM*EMEME",
+        experts_held=(0, 8), attention_impl="flash", remat=True,
+        param_dtype=jnp.bfloat16,
+    ))
+    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
+    abs_state = jax.eval_shape(
+        lambda: TrainState.create(
+            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
+            optimizer,
+        )
+    )
+    tokens = np.zeros((1, 8192), np.int32)
+    compiled = compile_lowered(make_train_step(
+        make_nemotron_h_loss(model, num_chunks=8), optimizer
+    ).lower(
+        _shapes(abs_state, one_chip),
+        _shapes({"x": tokens, "y": tokens}, one_chip),
+    ))
+    mem = compiled.memory_analysis()
+    # 1.2458 B parameters x 6 bytes (the three per-head vectors of a
+    # state-space layer are float32)
+    assert round(mem.argument_size_in_bytes / 1e9, 2) == 7.48
+    # 4.45 GB (offline compile, PR 47): the scan's chunk-square
+    # float32 arrays of one layer at a time
+    assert mem.temp_size_in_bytes < 4.5 * 2**30
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        < 15.75 * 2**30
+    )
+    text = compiled.as_text()
+    calls = re.findall(
+        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', text, re.M,
+    )
+    stacks = op_names(text)["op_names"]
+    flash = [c for c in calls if re.match(r"^%?attn(\.|$)", c)]
+    # forward, dq, dkv in each of the two attention layers
+    assert len(flash) == 3 * 2
+    assert all("/full_attn/attn/" in stacks[c] for c in flash)
+    kinds = [
+        re.sub(r"^%|\.\d+$", "", c) for c in calls if c not in flash
+    ]
+    # an expert layer: up and down forward and in the remat copy, each
+    # with its two gradients: no third matrix
+    assert {kind: kinds.count(kind) for kind in kinds} == {
+        "gmm_fwd": 2 * 2 * 8, "gmm_dlhs": 2 * 8, "gmm_drhs": 2 * 8,
+        "gmm_tokens_from_rows": 2 * 8, "gmm_unwritten": 3 * 8,
+    }
+    # no array of every assignment's row, forward or backward
+    assert not re.search(r"\[8192,6,2688\]|\[49152,2688\]", text)
+    for scope in (
+        "ssm_in_proj", "ssm_conv", "ssm_gates", "ssm_scan", "ssm_norm",
+        "ssm_out_proj", "moe_router", "moe_dispatch", "moe_experts",
+        "moe_combine", "moe_shared",
+    ):
+        named = [s for s in stacks.values() if f"/{scope}/" in s]
+        assert any("transpose(" in s for s in named), scope
+
+
 def test_ouro_twelve_layers_four_passes_step_fits_the_chip(one_chip, on_tpu):
     """The cell's step (``ouro_2_6b_cut``: twelve blocks at the
     published widths run four times over the same weights, the whole
