@@ -1,7 +1,8 @@
 """The Nemotron-H family through the repo's blocks against the plain
 float32 reference (``benchmarks/models/nemotron_h_reference.py``): three
 kinds of mixer in one stack; the chunked state-space scan
-(``ops/ssd.py``) against the recurrence token by token; the ungated
+(``ops/ssd.py``: two Pallas kernels, interpreted here) against the
+recurrence token by token; the ungated
 ``relu(.) ** 2`` experts of a held range against their own claims (the
 shares of all chips add up to the whole layer, no gate matrix in the
 tree, no row past the used tiles read) and against the gated layer's
@@ -229,29 +230,51 @@ def recurrence(x, dt, A, B, C):
     return y.swapaxes(0, 1), state
 
 
-def scan_operands(seq, dtype):
+def scan_operands(
+    seq, dtype, b=2, heads=4, p=8, groups=2, n=16, same_sign=False
+):
+    """``same_sign``: activations mostly positive, steps of 0.3 to 3
+    and ``A`` of -8 to -32, so that a token forgets its neighbour and
+    what two tokens exchange is of one sign."""
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
-    b, heads, p, groups, n = 2, 4, 8, 2, 16
-    return (
-        jax.random.normal(ks[0], (b, seq, heads, p)).astype(dtype),
+    x, dt, A, B, C = (
+        jax.random.normal(ks[0], (b, seq, heads, p)),
         0.5 * jax.nn.softplus(jax.random.normal(ks[1], (b, seq, heads))),
         -jnp.exp(jax.random.normal(ks[2], (heads,))),
-        jax.random.normal(ks[3], (b, seq, groups, n)).astype(dtype),
-        jax.random.normal(ks[4], (b, seq, groups, n)).astype(dtype),
+        jax.random.normal(ks[3], (b, seq, groups, n)),
+        jax.random.normal(ks[4], (b, seq, groups, n)),
     )
+    if same_sign:
+        x, B, C = (jax.nn.silu(0.5 + a) for a in (x, B, C))
+        dt, A = 2.0 * dt + 0.2, -8.0 * jnp.arange(1.0, heads + 1)
+    return x.astype(dtype), dt, A, B.astype(dtype), C.astype(dtype)
 
 
-@pytest.mark.parametrize("dtype, seq, limit", [
-    ("float32", 80, 2e-6), ("float32", 70, 2e-6), ("bfloat16", 80, 1e-2),
+# the cell's group: eight heads of 64 over one B and C of 128, chunks
+# of 128 (two and a tail of 44)
+GROUP = dict(b=1, heads=8, p=64, groups=1, n=128)
+
+
+@pytest.mark.parametrize("dtype, seq, chunk, shape, limit", [
+    ("float32", 80, 16, {}, 2e-6), ("float32", 70, 16, {}, 2e-6),
+    ("bfloat16", 80, 16, {}, 1e-2), ("float32", 300, 128, GROUP, 4e-6),
+    ("bfloat16", 80, 16, {"same_sign": True}, 1e-2),
 ])
-def test_the_chunked_scan_is_the_recurrence(dtype, seq, limit):
+def test_the_chunked_scan_is_the_recurrence(dtype, seq, chunk, shape, limit):
     """Five chunks of 16 tokens (and a tail that fills none), two
-    heads a group: the outputs, the final state and all five
-    gradients, of a loss that reads both results, against the
-    recurrence token by token in float32; bf16 operands within their
-    rounding."""
-    operands = scan_operands(seq, jnp.dtype(dtype))
-    weight = jax.random.normal(jax.random.PRNGKey(4), operands[0].shape)
+    heads a group, and the cell's group shape: the outputs, the final
+    state and all five gradients, of a loss that reads both results,
+    against the recurrence token by token in float32; bf16 operands
+    within their rounding, ALSO where tokens decay fast and exchange
+    terms of one sign: ``dt A`` at token ``t`` takes only the pairs
+    ``i >= t > j``, and the backward has to let every other pair
+    cancel (rows and columns from the same rounded numbers), or ``d
+    dt`` and ``dA`` read noise (0.21 and 1.7 here; PERF.md, PR 48)."""
+    operands = scan_operands(seq, jnp.dtype(dtype), **shape)
+    same_sign = shape.get("same_sign", False)
+    weight = float(same_sign) + (0.3 if same_sign else 1.0) * (
+        jax.random.normal(jax.random.PRNGKey(4), operands[0].shape)
+    )
 
     def scored(rule, *ops):
         y, state = rule(*ops)
@@ -259,7 +282,7 @@ def test_the_chunked_scan_is_the_recurrence(dtype, seq, limit):
             state ** 2
         ), (y, state)
 
-    chunked = functools.partial(ssd_scan, chunk=16)
+    chunked = functools.partial(ssd_scan, chunk=chunk)
     (_, got), got_grads = jax.value_and_grad(
         functools.partial(scored, chunked), argnums=range(5), has_aux=True
     )(*operands)
@@ -279,24 +302,36 @@ def test_the_chunked_scan_is_the_recurrence(dtype, seq, limit):
 
 @pytest.mark.parametrize("remat", [True, False])
 def test_a_rematted_block_keeps_nothing_of_the_scan(remat):
-    """The scan is differentiated as written, so what the backward
-    keeps is the block's remat's to say: with ``remat`` no
-    ``[.., chunk, chunk]`` decay or score matrix and no chunk state is
-    a residual of the model's loss (without it they all are: the
-    control)."""
-    model, params, batch = toy(remat=remat)
+    """What the scan's backward reads is said by its ``custom_vjp``:
+    the caller's five operands and the float32 state each chunk starts
+    from.  Under the block's remat nothing of the scan is a residual
+    of the model's loss (the block's backward runs ``ssd_fwd`` again);
+    without it the chunk-start states of both state-space layers are,
+    beside the operands, and nothing ``chunk x chunk`` either way (six
+    chunks of 8 here, so that no other array has that shape)."""
+    model, params, batch = toy(remat=remat, chunk_size=8)
     saved = jax_internal("ad_checkpoint", "saved_residuals")(
         lambda p: make_nemotron_h_loss(model, num_chunks=4)(p, batch)[0],
         params,
     )
-    chunk, cfg = model.config.chunk_size, model.config
-    of_the_scan = [
-        a.shape for a, _ in saved if a.ndim >= 5 and (
-            a.shape[-2:] == (chunk, chunk)
-            or a.shape[-2:] == (cfg.ssm_head_dim, cfg.ssm_state)
-        )
-    ]
-    assert bool(of_the_scan) != remat, of_the_scan
+    cfg = model.config
+    starts = (
+        2, 48 // 8, cfg.ssm_groups, cfg.ssm_state,
+        cfg.ssm_inner // cfg.ssm_groups,
+    )
+    of_the_scan = [a for a, said in saved if "ops/ssd.py" in said]
+    assert [(a.shape, a.dtype) for a in of_the_scan] == (
+        [] if remat else [(starts, jnp.float32)] * 2
+    )
+    shapes = [a.shape for a, _ in saved]
+    assert not [s for s in shapes if s[-2:] == (8, 8)]
+    # (``A`` is made from a parameter: kept either way)
+    x, dt, B = (
+        (2, 48, cfg.ssm_heads, cfg.ssm_head_dim), (2, 48, cfg.ssm_heads),
+        (2, 48, cfg.ssm_groups, cfg.ssm_state),
+    )
+    for operand, times in ((x, 2), (dt, 2), (B, 4)):
+        assert (shapes.count(operand) >= times) != remat, operand
     operands = scan_operands(64, jnp.float32)
     with pytest.raises(ValueError, match="heads"):
         ssd_scan(*operands[:3], operands[3][:, :, :1].repeat(3, 2),
@@ -734,6 +769,7 @@ def test_the_benchmark_lists_the_cell_and_its_readers():
         "ssm.scan_ms_per_step", "ssm.scan_roofline_pct",
         "ssm.mix_ms_per_step", "ssm.proj_ms_per_step",
         "ssm.state_rms_max", "moe.relu2_expert_roofline_pct",
+        "ssm.kernel_ms_per_step",
     ]
     for name in mine:
         reader = loader.load_module("layer_metrics", name)

@@ -803,6 +803,59 @@ def test_laguna_one_dense_four_sparse_step_fits_the_chip(one_chip, on_tpu):
         assert any(f"/{scope}/" in s for s in stacks.values()), scope
 
 
+@pytest.mark.parametrize(
+    "dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"]
+)
+def test_state_space_scan_compiles_at_published_sizes(
+    one_chip, on_tpu, dtype
+):
+    """The scan at (1, 8192, 64 heads of 64, 8 groups, state 128),
+    forward and backward, for the described chip: the forward is the
+    ``ssd_fwd`` kernel and no ``while`` (the hand-over is the kernel's
+    chunk axis), the gradient adds ``ssd_bwd``, both inside Mosaic's
+    scoped-VMEM limit with eight heads a grid step, and what lives
+    between them is the float32 chunk-start states and nothing
+    ``chunk x chunk``.  bf16 is the cell's; float32 operands (every
+    matmul at ``HIGHEST``) are the tests' exact path."""
+    from dlrover_tpu.ops.ssd import ssd_scan
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    operands = (
+        s((1, 8192, 64, 64), dtype), s((1, 8192, 64), jnp.float32),
+        s((64,), jnp.float32), s((1, 8192, 8, 128), dtype),
+        s((1, 8192, 8, 128), dtype),
+    )
+    forward = jax.jit(ssd_scan).lower(*operands).compile()
+    out, state = forward.out_info
+    assert out.shape == (1, 8192, 64, 64) and out.dtype == dtype
+    assert state.shape == (1, 64, 64, 128) and state.dtype == jnp.float32
+    assert _calls(forward, "ssd_fwd") == _kernels(forward) == 1
+    assert " while(" not in forward.as_text()
+
+    def loss(*a):
+        y, state = ssd_scan(*a)
+        return y.astype(jnp.float32).sum() + state.sum()
+
+    backward = jax.jit(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    ).lower(*operands).compile()
+    assert _calls(backward, "ssd_fwd") == 1
+    assert _calls(backward, "ssd_bwd") == 1
+    assert _kernels(backward) == 2
+    text = backward.as_text()
+    assert " while(" not in text
+    # the states 64 chunks start from, a group's heads side by side
+    assert "f32[1,64,8,128,512]" in text
+    assert not re.search(r"\[[\d,]*,128,128\]", text)
+    # 0.19 GiB in bf16, 0.38 in float32 (the XLA form's 268 MB
+    # chunk-square arrays, several at a time, are gone)
+    temp = backward.memory_analysis().temp_size_in_bytes
+    print(f"ssd backward temporaries {dtype.__name__}: {temp / 2**30:.3f} GiB")
+    assert temp < 0.5 * 2**30
+
+
 def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
     """The cell's step (``nemotron_3_nano_30b_cut``: ``MEMEM*EMEMEM*EMEME``
     at the published widths, 8 of 128 experts held, an eighth of the
@@ -843,9 +896,11 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
     # 1.2458 B parameters x 6 bytes (the three per-head vectors of a
     # state-space layer are float32)
     assert round(mem.argument_size_in_bytes / 1e9, 2) == 7.48
-    # 4.45 GB (offline compile, PR 47): the scan's chunk-square
-    # float32 arrays of one layer at a time
-    assert mem.temp_size_in_bytes < 4.5 * 2**30
+    # 3.972 GiB = 4.26 GB (offline compile, PR 48; 4.45 GB with the
+    # scan as XLA einsums, PR 47): nothing chunk-square is among them
+    temp = mem.temp_size_in_bytes
+    print(f"nemotron step temporaries {temp / 2**30:.3f} GiB")
+    assert temp < 4.0 * 2**30, f"{temp / 2**30:.3f} GiB where 3.972 was read"
     assert (
         mem.argument_size_in_bytes + mem.temp_size_in_bytes
         < 15.75 * 2**30
@@ -860,6 +915,10 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
     # forward, dq, dkv in each of the two attention layers
     assert len(flash) == 3 * 2
     assert all("/full_attn/attn/" in stacks[c] for c in flash)
+    # the state-space scan: eight layers' forward and the block's
+    # remat copy, one backward each, all under the scan's scope
+    scan = [c for c in calls if "ssd_" in c]
+    assert all("/ssm_scan/" in stacks[c] for c in scan)
     kinds = [
         re.sub(r"^%|\.\d+$", "", c) for c in calls if c not in flash
     ]
@@ -868,6 +927,7 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
     assert {kind: kinds.count(kind) for kind in kinds} == {
         "gmm_fwd": 2 * 2 * 8, "gmm_dlhs": 2 * 8, "gmm_drhs": 2 * 8,
         "gmm_tokens_from_rows": 2 * 8, "gmm_unwritten": 3 * 8,
+        "ssd_fwd": 2 * 8, "ssd_bwd": 8,
     }
     # no array of every assignment's row, forward or backward
     assert not re.search(r"\[8192,6,2688\]|\[49152,2688\]", text)
