@@ -85,16 +85,6 @@ def conv_init(key, shape, dtype):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
-def causal_conv(x, taps):
-    """Depthwise over the sequence: ``y_t = sum_j taps[j] x_{t-K+1+j}``,
-    ``x [b, s, c]``, ``taps [K, c]``; float32 sum, nothing from after
-    ``t``."""
-    k, s = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    taps = taps.astype(jnp.float32)
-    return sum(padded[:, j:j + s] * taps[j] for j in range(k))
-
-
 def rotate_half(x, cos, sin):
     """``x [b, s, heads, rope]``, half-split pairs, float32 inside."""
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

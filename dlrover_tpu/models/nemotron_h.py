@@ -10,7 +10,7 @@ attention.  Final RMSNorm, untied head.
 (:func:`dlrover_tpu.ops.ssd.ssd_scan`)::
 
     [z | xBC | dt] = W_in u            [H P | H P + 2 G N | H]
-    xBC <- SiLU(causal depthwise conv(xBC) + b_conv)
+    xBC <- SiLU(causal depthwise conv(xBC) + b_conv)     (ops/causal_conv.py)
     [x | B | C] = xBC                  [H, P | G, N | G, N]
     dt_h = softplus(dt_h + dt_bias_h)  A_h = -exp(A_log_h)
     S_h,t = exp(dt_h,t A_h) S_h,t-1 + (dt_h,t x_h,t) B_g,t^T
@@ -37,7 +37,9 @@ layers carry position), through ``layers.attention``.
 
 Flax module names: ``ssm``, ``moe``, ``attn`` (the benchmark finds
 flash kernels by that name; its scope is ``full_attn``).  Device
-scopes: ``ssm_in_proj``, ``ssm_conv``, ``ssm_gates`` (softplus, the
+scopes: ``ssm_in_proj``, ``ssm_conv`` (three ``conv_fwd`` kernels of
+``ops/causal_conv.py``, one each for ``x``, ``B`` and ``C``; three
+``conv_bwd`` under its transpose), ``ssm_gates`` (softplus, the
 decay's mean, the ``D`` skip), ``ssm_scan``, ``ssm_norm``,
 ``ssm_out_proj``, and the expert layer's ``moe_*``.
 """
@@ -53,6 +55,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
+from dlrover_tpu.ops.causal_conv import causal_conv
 from dlrover_tpu.ops.ssd import ssd_scan
 from dlrover_tpu.parallel.moe import DroplessMoE, bias_deltas
 
@@ -151,23 +154,29 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssm_in_proj"):
             zxbcdt = proj(2 * inner + 2 * bc + heads, "in_proj")(u)
             z = zxbcdt[..., :inner]
-            xbc = zxbcdt[..., inner:2 * inner + 2 * bc]
             dt = zxbcdt[..., 2 * inner + 2 * bc:]
         with jax.named_scope("ssm_conv"):
             taps = self.param(
-                "conv", layers.conv_init, (cfg.conv_kernel, xbc.shape[-1]),
+                "conv", layers.conv_init, (cfg.conv_kernel, inner + 2 * bc),
                 cfg.param_dtype,
             )
             bias = self.param(
-                "conv_bias", nn.initializers.zeros, (xbc.shape[-1],),
+                "conv_bias", nn.initializers.zeros, (inner + 2 * bc,),
                 cfg.param_dtype,
             )
-            xbc = nn.silu(
-                layers.causal_conv(xbc, taps) + bias.astype(jnp.float32)
-            ).astype(cfg.dtype)
-            x = xbc[..., :inner].reshape(b, s, heads, p)
-            B = xbc[..., inner:inner + bc].reshape(b, s, groups, n)
-            C = xbc[..., inner + bc:].reshape(b, s, groups, n)
+            # x, B and C straight out of the projection's lanes, each
+            # its own array: no slice copy before the kernel or after
+            x, B, C = (
+                causal_conv(
+                    zxbcdt, taps[:, lo:hi], bias[lo:hi], first=inner + lo
+                )
+                for lo, hi in (
+                    (0, inner), (inner, inner + bc),
+                    (inner + bc, inner + 2 * bc),
+                )
+            )
+            x = x.reshape(b, s, heads, p)
+            B, C = B.reshape(b, s, groups, n), C.reshape(b, s, groups, n)
         a_log = self.param("A_log", _a_log_init, (heads,), jnp.float32)
         skip = self.param("D", nn.initializers.ones, (heads,), jnp.float32)
         dt_bias = self.param(

@@ -15,6 +15,9 @@ values; :func:`dlrover_tpu.ops.gated_delta_rule.gated_delta_rule`)::
 
     q, k = W_q x, W_k x  [H d_k]       v, z = W_v x, W_z x  [H d_v]
     q, k, v <- SiLU(causal depthwise conv1d over the sequence)
+               (:func:`dlrover_tpu.ops.causal_conv.causal_conv`: a
+               ``conv_fwd`` kernel each under ``gdn_conv``, a
+               ``conv_bwd`` each under its transpose)
     q_h <- q_h / |q_h| * d_k^-1/2      k_h <- k_h / |k_h|
     beta_h = 2 sigmoid(W_b x)_h        (1 sigmoid without neg. eigenvalues)
     g_h = -exp(A_log_h) softplus((W_a x)_h + dt_bias_h)
@@ -40,6 +43,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
+from dlrover_tpu.ops.causal_conv import causal_conv
 from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -163,16 +167,18 @@ class GatedDeltaNet(nn.Module):
         # heads' indicator): a [.., heads, d] view of 96 or 192 lanes
         # costs a relayout each way
         with jax.named_scope("gdn_conv"):
-            def conv(name, y):
+            def conv(name, y, dtype):
                 taps = self.param(
                     name, layers.conv_init,
                     (cfg.conv_kernel, y.shape[-1]),
                     cfg.param_dtype,
                 )
-                return nn.silu(layers.causal_conv(y, taps))
+                return causal_conv(y, taps, dtype=dtype)
 
-            q, k = conv("q_conv", q), conv("k_conv", k)
-            v = conv("v_conv", v).astype(cfg.dtype)
+            # (float32 for the per-head norm that reads q and k next)
+            q = conv("q_conv", q, jnp.float32)
+            k = conv("k_conv", k, jnp.float32)
+            v = conv("v_conv", v, cfg.dtype)
         with jax.named_scope("gdn_gates"):
             q = _head_rsqrt(q, heads, 1e-6) * dk ** -0.5
             k = _head_rsqrt(k, heads, 1e-6)

@@ -28,7 +28,6 @@ from dlrover_tpu.models.olmo_hybrid import (  # noqa: E402
     OlmoHybridConfig,
     make_olmo_hybrid_loss,
 )
-from dlrover_tpu.models.layers import causal_conv  # noqa: E402
 from dlrover_tpu.ops.gated_delta_rule import CHUNK  # noqa: E402
 from dlrover_tpu.optim import adamw_bf16  # noqa: E402
 from dlrover_tpu.telemetry.events import read_events  # noqa: E402
@@ -220,23 +219,6 @@ def test_published_sizes_give_the_published_parameter_counts():
         + 2 * 100352 * 3840                    # embedding, untied head
     )
     assert round(total / 1e9, 3) == 1.603
-
-
-def test_the_convolution_is_causal_and_depthwise():
-    x = jax.random.normal(jax.random.PRNGKey(0), (1, 12, 3))
-    taps = jax.random.normal(jax.random.PRNGKey(1), (4, 3))
-    y = causal_conv(x, taps)
-    # by hand: y_t = sum_j taps[j] x_{t - 3 + j}
-    want = np.zeros((12, 3))
-    for t in range(12):
-        for j in range(4):
-            if t - 3 + j >= 0:
-                want[t] += np.asarray(taps[j]) * np.asarray(x[0, t - 3 + j])
-    np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)
-    moved = causal_conv(x.at[0, 7, 1].add(1.0), taps) - y
-    assert not np.asarray(moved[0, :7]).any()       # nothing before t
-    assert not np.asarray(moved[0, :, [0, 2]]).any()  # its channel only
-    assert np.asarray(moved[0, 7:11, 1]).all()
 
 
 def test_the_whole_model_is_causal():

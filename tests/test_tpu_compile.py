@@ -12,6 +12,7 @@ compiled for a described chip cannot be read back without one)."""
 
 import os
 import re
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,7 @@ import optax
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from dlrover_tpu.common.aot_cache import compile_lowered
+from dlrover_tpu.common.aot_cache import COMPILER_OPTIONS, compile_lowered
 from dlrover_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
 from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.ops import quantization as qz
@@ -85,6 +86,29 @@ def _shapes(tree, sharding):
 
 def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
+
+
+def _compile_and_reserved_hbm(lowered, dump_dir):
+    """``(compiled, bytes)``: the program compiled as the engine does,
+    and the buffer assignment's preallocated temporary in HBM, the one
+    block the chip reserves for the step's temporaries (the TPU
+    compiler's "HLO temp").  ``memory_analysis().temp_size_in_bytes``
+    is NOT that: libtpu reports the block PLUS its fragmentation, the
+    block less the most that is live in it at once (its log says
+    "HLO temp 3.72G (.. Padded (3.31G), 10.9% fragmentation
+    (415.19M))" where the figure reads 3.72 + 0.41 = 4.12 GiB), so a
+    looser packing of a SMALLER block reads as more (PERF.md, PR 49).
+    The block's size comes from the compile's own dump."""
+    compiled = lowered.compile(compiler_options={
+        **COMPILER_OPTIONS, "xla_dump_to": str(dump_dir),
+    })
+    (report,) = dump_dir.glob("*after_optimizations-buffer-assignment.txt")
+    (reserved,) = re.findall(
+        r"^allocation \d+: size (\d+), preallocated-temp:$",
+        report.read_text(), re.M,
+    )
+    shutil.rmtree(dump_dir)
+    return compiled, int(reserved)
 
 
 def _head_matmul_shapes(text):
@@ -536,8 +560,11 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
     # (offline compile of 2da395f, this very program).  4.44 GB now
     # (4,441,295,360 B; 4,442,198,528 before the one full-attention
     # layer kept its q, k and v, 3 x 62.9 MB: the peak is not in that
-    # layer's backward, so 0.19 GB kept shows as nothing)
-    assert mem.temp_size_in_bytes <= 5_059_906_048
+    # layer's backward, so 0.19 GB kept shows as nothing); 4.07 GB
+    # with the convolutions as kernels (4,069,591,040 B, PR 49: the
+    # padded float32 copies of q, k and v are gone): the limit is
+    # what stood before them
+    assert mem.temp_size_in_bytes <= 4_441_295_360
     text = compiled.as_text()
     # the head: 3 vocabulary-sized matmuls a chunk of 8192 / 8 tokens
     # (logits, d_hidden, d_kernel: 3 x 8 a step, where the
@@ -551,7 +578,22 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
     )
     stacks = op_names(text)["op_names"]
     rule = [c for c in calls if "gdn_" in c]
-    calls = [c for c in calls if c not in rule]
+    conv = [c for c in calls if "conv_" in c]
+    calls = [c for c in calls if c not in rule + conv]
+    # the convolutions of q, k and v in each linear layer: forward,
+    # the block's remat copy and one backward each, under the scope
+    # the mix's reader sums
+    assert sorted(
+        (re.search(r"block_(\d)/gdn/", stacks[c]).group(1),
+         re.search(r"conv_(fwd|bwd)", c).group(1))
+        for c in conv
+    ) == sorted(
+        (str(i), kind) for i in range(3)
+        for kind in ("fwd", "fwd", "bwd") * 3
+    )
+    assert all(
+        re.search(r"(?:^|[/(])gdn_conv(?:[/)]|$)", stacks[c]) for c in conv
+    )
     # forward, dq, dkv: one layer of four
     assert len(calls) == 3
     assert all(re.match(r"^%?attn(\.|$)", name) for name in calls)
@@ -856,7 +898,65 @@ def test_state_space_scan_compiles_at_published_sizes(
     assert temp < 0.5 * 2**30
 
 
-def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
+# (operand's lanes, first lane, width, bias, output): the state-space
+# mixer's x and C out of its projection, the hybrid's q / k and v
+CONV_SHAPES = {
+    "ssm-x": (10304, 4096, 4096, True, jnp.bfloat16),
+    "ssm-C": (10304, 9216, 1024, True, jnp.bfloat16),
+    "gdn-qk": (2880, 0, 2880, False, jnp.float32),
+    "gdn-v": (5760, 0, 5760, False, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize(
+    "shape", CONV_SHAPES.values(), ids=CONV_SHAPES.keys()
+)
+def test_causal_conv_compiles_at_both_cells_shapes(one_chip, on_tpu, shape):
+    """``conv_fwd`` and ``conv_bwd`` at 1 x 8192 tokens for the
+    described chip: a window of 1024-lane blocks read in place out of
+    ``[.., 10304]`` (80.5 lane tiles), a whole width of 22.5 lane
+    tiles in one block with float32 out, 45 lane tiles in blocks of
+    five; the gradient is ONE kernel (the residuals are the operands)
+    and the only large temporary is the window's gradient laid into
+    the operand's lanes."""
+    from dlrover_tpu.ops.causal_conv import causal_conv
+
+    total, first, c, bias, out = shape
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    operands = [s((1, 8192, total), jnp.bfloat16), s((4, c), jnp.float32)]
+    if bias:
+        operands.append(s((c,), jnp.float32))
+
+    def conv(x, taps, bias=None):
+        return causal_conv(x, taps, bias, first=first, dtype=out)
+
+    forward = jax.jit(conv).lower(*operands).compile()
+    (y,) = jax.tree.leaves(forward.out_info)
+    assert y.shape == (1, 8192, c) and y.dtype == out
+    assert _calls(forward, "conv_fwd") == _kernels(forward) == 1
+    assert not re.search(r" (pad|slice)\(", forward.as_text())
+
+    backward = jax.jit(jax.grad(
+        lambda *a: conv(*a).astype(jnp.float32).sum(),
+        argnums=tuple(range(len(operands))),
+    )).lower(*operands).compile()
+    assert _calls(backward, "conv_bwd") == _kernels(backward) == 1
+    grads = jax.tree.leaves(backward.out_info)
+    assert [g.dtype for g in grads] == [o.dtype for o in operands]
+    assert f"f32[8,{c}]" in backward.as_text()
+    # nothing float32 of the sequence's size, nothing padded by rows
+    assert not re.search(r"f32\[1,81\d\d,", backward.as_text()) or (
+        out == jnp.float32
+    )
+    assert not re.search(r"\[1,8195,", backward.as_text())
+
+
+def test_nemotron_eighteen_layer_step_fits_the_chip(
+    one_chip, on_tpu, tmp_path
+):
     """The cell's step (``nemotron_3_nano_30b_cut``: ``MEMEM*EMEMEM*EMEME``
     at the published widths, 8 of 128 experts held, an eighth of the
     vocabulary, bf16 state, flash attention at 32 heads over 2, per-layer
@@ -886,21 +986,39 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
         )
     )
     tokens = np.zeros((1, 8192), np.int32)
-    compiled = compile_lowered(make_train_step(
+    compiled, reserved = _compile_and_reserved_hbm(make_train_step(
         make_nemotron_h_loss(model, num_chunks=8), optimizer
     ).lower(
         _shapes(abs_state, one_chip),
         _shapes({"x": tokens, "y": tokens}, one_chip),
-    ))
+    ), tmp_path)
     mem = compiled.memory_analysis()
     # 1.2458 B parameters x 6 bytes (the three per-head vectors of a
     # state-space layer are float32)
     assert round(mem.argument_size_in_bytes / 1e9, 2) == 7.48
-    # 3.972 GiB = 4.26 GB (offline compile, PR 48; 4.45 GB with the
-    # scan as XLA einsums, PR 47): nothing chunk-square is among them
+    # What the chip reserves for the step's temporaries: 3.718 GiB
+    # (3,991,798,272 B, offline compile, PR 49; 3.866 GiB at PR 48,
+    # 4,150,641,152 B: the padded float32 xBC and its transposes are
+    # gone), and the most that is live in it at once 3.312 GiB (3.759
+    # at PR 48): both DOWN.  ``temp_size_in_bytes`` is the block plus
+    # its fragmentation (``_compile_and_reserved_hbm``): 3.718 + 0.405
+    # = 4.123 GiB where PR 48 read 3.866 + 0.106 = 3.972, a smaller
+    # block packed looser, so the limit that stood on that figure
+    # (4.0 GiB) is held on the two it is made of, each under PR 48's.
+    # Nothing chunk-square is among them (4.45 GB with the scan as
+    # XLA einsums, PR 47)
     temp = mem.temp_size_in_bytes
-    print(f"nemotron step temporaries {temp / 2**30:.3f} GiB")
-    assert temp < 4.0 * 2**30, f"{temp / 2**30:.3f} GiB where 3.972 was read"
+    live = 2 * reserved - temp
+    print(
+        f"nemotron step temporaries: {reserved / 2**30:.3f} GiB reserved, "
+        f"{live / 2**30:.3f} live at once, {temp / 2**30:.3f} reported"
+    )
+    assert reserved < 3.75 * 2**30, (
+        f"{reserved / 2**30:.3f} GiB reserved where 3.718 was read"
+    )
+    assert live < 3.4 * 2**30, (
+        f"{live / 2**30:.3f} GiB live at once where 3.312 was read"
+    )
     assert (
         mem.argument_size_in_bytes + mem.temp_size_in_bytes
         < 15.75 * 2**30
@@ -928,7 +1046,15 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(one_chip, on_tpu):
         "gmm_fwd": 2 * 2 * 8, "gmm_dlhs": 2 * 8, "gmm_drhs": 2 * 8,
         "gmm_tokens_from_rows": 2 * 8, "gmm_unwritten": 3 * 8,
         "ssd_fwd": 2 * 8, "ssd_bwd": 8,
+        # x, B and C, each a window of the projection's lanes
+        "conv_fwd": 3 * 2 * 8, "conv_bwd": 3 * 8,
     }
+    conv = [c for c in calls if "conv_" in c]
+    assert all(
+        re.search(r"(?:^|[/(])ssm_conv(?:[/)]|$)", stacks[c]) for c in conv
+    )
+    # read where the projection wrote them: no copy of its lanes
+    assert not re.search(r"bf16\[1,8192,6144\]", text)
     # no array of every assignment's row, forward or backward
     assert not re.search(r"\[8192,6,2688\]|\[49152,2688\]", text)
     for scope in (
