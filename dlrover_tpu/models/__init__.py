@@ -3,6 +3,7 @@ strategy engine's dry-runner, and the benchmarks."""
 
 from dlrover_tpu.models.gpt import GPT, GPTConfig
 from dlrover_tpu.models.llama import Llama, LlamaConfig
+from dlrover_tpu.models.mimo_v2 import MiMoV2, MiMoV2Config
 from dlrover_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from dlrover_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from dlrover_tpu.models.ouro import Ouro, OuroConfig
@@ -17,6 +18,8 @@ __all__ = [
     "GPTConfig",
     "Llama",
     "LlamaConfig",
+    "MiMoV2",
+    "MiMoV2Config",
     "NemotronH",
     "NemotronHConfig",
     "OlmoHybrid",

@@ -8,7 +8,7 @@ Attention is grouped-query (``num_kv_heads`` kv heads, heads of
 (``heads_per_layer[l]``: 48 in a full layer, 72 in a sliding one), its
 mask (causal; a sliding layer also hides keys ``sliding_window`` or
 more behind the query: ``ops/flash_attention.py``'s ``window``) and
-its rope rule (:class:`RopeRule`: a full layer rotates the first half
+its rope rule (:class:`layers.RopeRule`: a full layer rotates the first half
 of each head with yarn frequencies and scales cos and sin, a sliding
 layer rotates the whole head with the default rule).  Rope pairs lane
 ``i`` with lane ``i + rotated / 2`` (half-split).  Each head's output
@@ -37,41 +37,16 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
-from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.parallel.moe import DroplessMoE
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 SCOPE_OF = {FULL: "full_attn", SLIDING: "swa"}
 
 
-@dataclass(frozen=True)
-class RopeRule:
-    """One layer kind's rotary rule (HF ``rope_parameters[kind]``).
-    ``factor`` 1 is the default rule; above it yarn's."""
-
-    theta: float = 10000.0            # rope_theta
-    rotated: float = 1.0              # partial_rotary_factor
-    factor: float = 1.0               # factor (yarn)
-    original_len: int = 8192          # original_max_position_embeddings
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    attention_factor: float = 1.0     # scales cos and sin
-
-    def inv_freq(self, head_dim: int) -> np.ndarray:
-        dim = int(head_dim * self.rotated)
-        if self.factor <= 1:
-            return self.theta ** (
-                -np.arange(0, dim, 2, dtype=np.float64) / dim
-            )
-        return layers.yarn_inv_freq(
-            dim, self.theta, self.factor, self.original_len,
-            self.beta_fast, self.beta_slow,
-        )
-
+RopeRule = layers.RopeRule
 
 # Laguna-S-2.1's two rules
 FULL_ROPE = RopeRule(
@@ -169,26 +144,9 @@ class LagunaAttention(nn.Module):
         k = proj(kv * d, "k_proj")(x)
         v = proj(kv * d, "v_proj")(x)
         with jax.named_scope("attn_rope"):
-            inv_freq = self.rope.inv_freq(d)
-            rotated = 2 * len(inv_freq)
-            angles = (
-                jnp.arange(s, dtype=jnp.float32)[:, None]
-                * jnp.asarray(inv_freq, jnp.float32)[None, :]
-            )
-            m = self.rope.attention_factor
-            cos = (jnp.cos(angles) * m)[None, :, None, :]
-            sin = (jnp.sin(angles) * m)[None, :, None, :]
-
-            def rotate(t, n):
-                t = t.reshape(b, s, n, d)
-                if rotated == d:
-                    return layers.rotate_half(t, cos, sin)
-                return jnp.concatenate([
-                    layers.rotate_half(t[..., :rotated], cos, sin),
-                    t[..., rotated:],
-                ], axis=-1)
-
-            q, k = rotate(q, heads), rotate(k, kv)
+            cos, sin = self.rope.tables(s, d)
+            q = layers.rotate_partial(q.reshape(b, s, heads, d), cos, sin)
+            k = layers.rotate_partial(k.reshape(b, s, kv, d), cos, sin)
             v = v.reshape(b, s, kv, d)
         out = layers.attention(
             cfg.attention_impl, q, k, v, window=self.window,
@@ -296,11 +254,7 @@ def window_tiles_share(cfg: LagunaConfig, seq: int, itemsize: int = 2):
     sliding layer goes through the kernels."""
     if cfg.attention_impl != "flash" or SLIDING not in cfg.layer_types:
         return None
-    block = fa._fit_block(seq, fa.default_blocks(seq, itemsize)[0])
-    walked = fa.block_schedule(
-        seq, block, block, window=cfg.sliding_window
-    )["visited"]
-    return walked / fa.block_schedule(seq, block, block)["visited"]
+    return layers.window_tiles_share(seq, cfg.sliding_window, itemsize)
 
 
 def make_laguna_loss(model: Laguna, num_chunks: int = 8):

@@ -1,4 +1,5 @@
-"""What the decoder families share: the norm, rope, the dense helper,
+"""What the decoder families share: the norm, rope (also by a layer
+kind's :class:`RopeRule`, rotating part of a head), the dense helper,
 the SwiGLU, the causal depthwise convolution of the recurrent mixers,
 the one call that maps ``attention_impl`` to a function, and the
 remat rule.  A family file imports this module, ``losses``,
@@ -12,6 +13,7 @@ checkpoint's format: ``llama.py::LlamaMLP`` (``gate`` / ``up`` /
 """
 
 import math
+from dataclasses import dataclass
 from functools import partial
 from typing import Any, Optional, Tuple
 
@@ -24,7 +26,12 @@ from dlrover_tpu.ops.attention import (
     xla_causal_attention,
     xla_window_attention,
 )
-from dlrover_tpu.ops.flash_attention import RESIDUAL_NAMES, flash_attention
+from dlrover_tpu.ops import flash_attention as fa
+from dlrover_tpu.ops.flash_attention import (
+    RESIDUAL_NAMES,
+    SINK_SCOPE,
+    flash_attention,
+)
 from dlrover_tpu.parallel.mesh import (
     get_activation_constraint_mesh,
     get_global_mesh,
@@ -146,6 +153,56 @@ def yarn_inv_freq(
     return freq / factor * ramp + freq * (1.0 - ramp)
 
 
+@dataclass(frozen=True)
+class RopeRule:
+    """One layer kind's rotary rule (HF ``rope_parameters[kind]``),
+    for the families whose rope comes from the layer's kind.
+    ``factor`` 1 is the default rule; above it yarn's."""
+
+    theta: float = 10000.0            # rope_theta
+    rotated: float = 1.0              # partial_rotary_factor
+    factor: float = 1.0               # factor (yarn)
+    original_len: int = 8192          # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0     # scales cos and sin
+
+    def inv_freq(self, head_dim: int) -> np.ndarray:
+        dim = int(head_dim * self.rotated)
+        if self.factor <= 1:
+            return self.theta ** (
+                -np.arange(0, dim, 2, dtype=np.float64) / dim
+            )
+        return yarn_inv_freq(
+            dim, self.theta, self.factor, self.original_len,
+            self.beta_fast, self.beta_slow,
+        )
+
+    def tables(self, seq: int, head_dim: int):
+        """``(cos, sin)`` ``[1, seq, 1, rotated lanes / 2]`` float32,
+        both times ``attention_factor``."""
+        angles = (
+            jnp.arange(seq, dtype=jnp.float32)[:, None]
+            * jnp.asarray(self.inv_freq(head_dim), jnp.float32)[None, :]
+        )
+        m = self.attention_factor
+        return (
+            (jnp.cos(angles) * m)[None, :, None, :],
+            (jnp.sin(angles) * m)[None, :, None, :],
+        )
+
+
+def rotate_partial(x, cos, sin):
+    """``x [b, s, heads, d]``: the first ``2 x cos.shape[-1]`` lanes of
+    every head rotate (:func:`rotate_half`), the rest pass through."""
+    rotated = 2 * cos.shape[-1]
+    if rotated == x.shape[-1]:
+        return rotate_half(x, cos, sin)
+    return jnp.concatenate([
+        rotate_half(x[..., :rotated], cos, sin), x[..., rotated:],
+    ], axis=-1)
+
+
 def init_params(model, rng, batch_size: int = 2, seq_len: int = 0):
     """A decoder's ``params`` tree, initialised on a batch of zeros."""
     seq_len = seq_len or min(model.config.max_seq_len, 128)
@@ -169,21 +226,45 @@ def _flash(q, k, v, **kw):
     return shard_local_attention(flash_attention, q, k, v, mesh, **kw)
 
 
-def _xla(q, k, v, *, scale, window, dtype):
+def _xla(q, k, v, *, scale, window, dtype, **sinked):
     """The plain forms: the grouped one, its mask written out, for a
-    window or fewer kv heads than query heads, else the causal one."""
-    if window is None and k.shape[2] == q.shape[2]:
+    window, a sink or fewer kv heads than query heads, else the causal
+    one."""
+    if window is None and k.shape[2] == q.shape[2] and not sinked:
         return xla_causal_attention(q, k, v, dtype=dtype, scale=scale)
     if scale is not None:
         raise ValueError("no scale in the plain grouped or windowed form")
-    return xla_window_attention(q, k, v, window, dtype)
+    return xla_window_attention(q, k, v, window, dtype, **sinked)
+
+
+def _with_sink(impl, q, k, v, sink, kw):
+    """``(out, sink mass [heads])`` through the two forms that take a
+    sink; the mass's reduction goes under the sink's device scope."""
+    mesh = get_activation_constraint_mesh()
+    if impl not in ("flash", "xla") or (
+        impl == "flash" and mesh is not None and mesh.size > 1
+    ):
+        raise ValueError(
+            f"no attention sink through {impl!r} over a mesh: xla, or "
+            "flash on one device"
+        )
+    form = flash_attention if impl == "flash" else _xla
+    out, lse = form(q, k, v, sink=sink, return_lse=True, **kw)
+    with jax.named_scope(SINK_SCOPE):
+        mass = jnp.mean(
+            jnp.exp(jax.lax.stop_gradient(
+                sink.astype(jnp.float32)
+            )[None, :, None] - lse),
+            axis=(0, 2),
+        )
+    return out, mass
 
 
 def attention(
     impl: str, q: jax.Array, k: jax.Array, v: jax.Array, *,
     scale: Optional[float] = None, window: Optional[int] = None,
-    dtype: Any = None,
-) -> jax.Array:
+    dtype: Any = None, sink: Optional[jax.Array] = None,
+):
     """Causal attention through ``impl``: xla | flash | ring | ulysses
     | ulysses_flash, the only place that maps the name to a function.
 
@@ -194,9 +275,18 @@ def attention(
     run over the global mesh's ``sequence`` axis (registered by
     auto_accelerate); activations must be sequence-sharded by the
     batch placement.
+
+    ``sink`` (``[heads]`` float32; xla | flash on one device): a
+    learned score a head that joins every row's softmax denominator
+    and nothing else (``ops/flash_attention.py``).  The call then
+    returns ``(out, sink mass)``: the share of the softmax the sink
+    takes, ``exp(sink - lse)``, mean over batch and rows, ``[heads]``
+    float32 with no gradient, for the caller's counter.
     """
     dtype = v.dtype if dtype is None else dtype
     kw = dict(scale=scale, window=window, dtype=dtype)
+    if sink is not None:
+        return _with_sink(impl, q, k, v, sink, kw)
     if impl == "flash":
         return _flash(q, k, v, **kw)
     if impl == "xla":
@@ -214,6 +304,18 @@ def attention(
         inner = _flash if impl == "ulysses_flash" else _xla
         return ulysses_attention(inner, q, k, v, get_global_mesh(), **kw)
     raise ValueError(f"no attention through {impl!r} (window {window})")
+
+
+def window_tiles_share(seq: int, window: int, itemsize: int = 2) -> float:
+    """Sub-blocks the flash kernels walk for a window over those a
+    causal walk of the same tiles would (``block_schedule``, at the
+    tiles a call that names none takes): what the window saves of the
+    walk, a constant of the shapes."""
+    block = fa._fit_block(seq, fa.default_blocks(seq, itemsize)[0])
+    walked = fa.block_schedule(seq, block, block, window=window)
+    return walked["visited"] / fa.block_schedule(seq, block, block)[
+        "visited"
+    ]
 
 
 def remat_policy(name: str):

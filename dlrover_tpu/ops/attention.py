@@ -30,10 +30,17 @@ def xla_causal_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def xla_window_attention(q, k, v, window: Optional[int], dtype):
+def xla_window_attention(
+    q, k, v, window: Optional[int], dtype, sink=None,
+    return_lse: bool = False,
+):
     """Plain grouped-query attention with the mask written out:
     ``[b, s, heads, d]`` queries over ``[b, s, kv heads, d]`` keys and
-    values, query head ``j`` reading kv head ``j // group``."""
+    values (``v``'s head size may differ), query head ``j`` reading kv
+    head ``j // group``.  ``sink`` (``[heads]`` float32) is one more
+    column of every row's scores, dropped after the softmax;
+    ``return_lse`` also gives the rows' log-sum-exp ``[b, heads, s]``,
+    the sink in it."""
     b, s, heads, d = q.shape
     kv = k.shape[2]
     q = q.reshape(b, s, kv, heads // kv, d)
@@ -44,12 +51,23 @@ def xla_window_attention(q, k, v, window: Optional[int], dtype):
     seen = ahead >= 0
     if window is not None:
         seen = seen & (ahead < window)
-    probs = jax.nn.softmax(
-        jnp.where(seen, logits, -1e30), axis=-1
-    ).astype(dtype)
-    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(
-        b, s, heads, v.shape[-1]
-    )
+    logits = jnp.where(seen, logits, -1e30)
+    if sink is not None:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, kv, heads // kv, 1, 1),
+            logits.shape[:-1] + (1,),
+        )
+        logits = jnp.concatenate([logits, column], axis=-1)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if sink is not None:
+        probs = probs[..., :s]
+    out = jnp.einsum(
+        "bhgqk,bkhd->bqhgd", probs.astype(dtype), v
+    ).reshape(b, s, heads, v.shape[-1])
+    if not return_lse:
+        return out
+    lse = jax.nn.logsumexp(jax.lax.stop_gradient(logits), axis=-1)
+    return out, lse.reshape(b, heads, s)
 
 
 def cached_decode_attention(

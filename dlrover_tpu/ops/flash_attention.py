@@ -56,6 +56,16 @@ the accumulator live in VMEM scratch, the statistics lane-dense
 ``[1, seq]`` rows they travel as by 128 x 128 transposes, once a grid
 step.  ``block_schedule`` counts the walk.
 
+A sink.  With ``sink`` (a learned float32 scalar a head) every row's
+softmax has one more column whose value is dropped: the forward adds
+``exp(sink - m)`` to ``l`` where it closes the statistics (and takes
+``max(m, sink)``), so ``lse`` holds the sink, and nothing in the walk
+changes.  The backward kernels do not change at all: ``p = exp(s -
+lse)`` is already the sinked probability and ``delta = rowsum(dO out)``
+already ``sum_j p_j dP_j``; the sink's gradient is ``- sum_i exp(sink -
+lse_i) delta_i``, formed in XLA under the device scope ``attn_sink``.
+``sink=None`` is the program this file always traced to.
+
 What a rematted caller keeps.  The backward kernels read the five
 arrays the forward rule hands them: ``q``, ``k``, ``v`` as folded,
 ``out`` and ``lse``.  The rule names all five (``RESIDUAL_NAMES``),
@@ -88,6 +98,9 @@ _LANES = 128
 RESIDUAL_NAMES = (
     "flash_q", "flash_k", "flash_v", "flash_out", "flash_lse"
 )
+# the device scope of what a sink costs outside the kernels (its
+# gradient's reduction here, a caller's counter)
+SINK_SCOPE = "attn_sink"
 # What a grid step may keep of the operands its loop walks (K and V in
 # forward and dq, Q and dO in dkv: one of each pair is d_qk wide, the
 # other d_v), as the pipeline holds them: two buffers x rows x (d_qk +
@@ -502,7 +515,7 @@ def _fwd_kernel(
     lse_ref,                  # [1, 1, block_q]
     m_scr, l_scr, acc_scr,    # [block_q, 128] x2, [block_q, d_v]
     *, scale: float, block_q: int, block_k: int, causal: bool,
-    num_major: int, window: int | None = None,
+    num_major: int, window: int | None = None, sink_ref=None,
 ):
     q_start = pl.program_id(1) * block_q
     major = _major(num_major, window)
@@ -586,7 +599,35 @@ def _fwd_kernel(
         o_ref[0] = (acc_scr[...] / _lanes(safe_l, d)).astype(o_ref.dtype)
         lse_ref[0] = _rows_to_lanes(m_scr[...] + jnp.log(safe_l))
 
-    _bracket(major, num_major, init, walk, final)
+    def final_with_sink():
+        # the sink is one more column of the scores whose value is
+        # dropped: it joins the maximum and the denominator where the
+        # statistics are closed, and nothing before
+        m, sink = m_scr[...], sink_ref[0]
+        m_new = jnp.maximum(m, sink)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l_scr[...] + jnp.exp(sink - m_new)
+        o_ref[0] = (
+            acc_scr[...] * _lanes(alpha / l, d)
+        ).astype(o_ref.dtype)
+        lse_ref[0] = _rows_to_lanes(m_new + jnp.log(l))
+
+    _bracket(
+        major, num_major, init, walk,
+        final if sink_ref is None else final_with_sink,
+    )
+
+
+def _fwd_kernel_with_sink(
+    q_ref, k_ref, v_ref, sink_ref, o_ref, lse_ref, m_scr, l_scr,
+    acc_scr, **kw,
+):
+    """:func:`_fwd_kernel` with a fourth operand, the head's sink
+    along the lanes ``[1, 1, 128]``."""
+    _fwd_kernel(
+        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+        sink_ref=sink_ref, **kw,
+    )
 
 
 def _kv_walked(seq, block_q, block_k, d, d_v, itemsize, causal, group,
@@ -622,8 +663,10 @@ def _kv_walked(seq, block_q, block_k, d, d_v, itemsize, causal, group,
 
 def _fwd(
     q, k, v, scale: float, causal: bool, block_q: int, block_k: int,
-    group: int = 1, window: int | None = None,
+    group: int = 1, window: int | None = None, sink=None,
 ):
+    """``sink`` [bh] float32 (None: no sink) goes in lane-dense, one
+    ``[1, 1, 128]`` block a head."""
     bh, seq, d = q.shape
     d_v = v.shape[2]
     rows, num_major, kv_block = _kv_walked(
@@ -634,9 +677,18 @@ def _fwd(
     def q_block(b, i, j):
         return (b, i, 0)
 
+    kernel, operands, sink_specs = _fwd_kernel, (q, k, v), []
+    if sink is not None:
+        kernel = _fwd_kernel_with_sink
+        operands += (jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (bh, 1, _LANES)
+        ),)
+        sink_specs = [
+            pl.BlockSpec((1, 1, _LANES), lambda b, i, j: (b, 0, 0))
+        ]
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, block_q=block_q,
+            kernel, scale=scale, block_q=block_q,
             block_k=block_k, causal=causal, num_major=num_major,
             window=window,
         ),
@@ -645,6 +697,7 @@ def _fwd(
             pl.BlockSpec((1, block_q, d), q_block),
             pl.BlockSpec((1, rows, d), kv_block),
             pl.BlockSpec((1, rows, d_v), kv_block),
+            *sink_specs,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d_v), q_block),
@@ -662,7 +715,7 @@ def _fwd(
             _scratch((block_q, d_v), jnp.float32),
         ],
         interpret=_interpret(),
-    )(q, k, v)
+    )(*operands)
     return out, lse
 
 
@@ -1015,12 +1068,12 @@ def _named(x, name):
 
 
 def _flash_mha_fwd(q, k, v, scale, causal, block_q, block_k,
-                   group=1, window=None):
+                   group=1, window=None, sink=None):
     # q, k and v are named on arrays only the residuals hold: no
     # ``reduce_precision``, so as the numbers they are (``_named``;
     # ``test_a_saved_residual_costs_no_pass_over_it`` holds jax to it)
     out, lse = _fwd(
-        q, k, v, scale, causal, block_q, block_k, group, window
+        q, k, v, scale, causal, block_q, block_k, group, window, sink
     )
     q_name, k_name, v_name, out_name, lse_name = RESIDUAL_NAMES
     q, k, v = (
@@ -1043,6 +1096,53 @@ def _flash_mha_bwd(scale, causal, block_q, block_k, group, window,
 
 
 _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
+
+
+@functools.partial(
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9)
+)
+def _flash_mha_sink(q, k, v, sink, scale, causal, block_q, block_k,
+                    group=1, window=None):
+    """:func:`_flash_mha` with a learned sink a head (``sink`` [b h]
+    float32): ``(out, lse)``, both with the sink in the softmax's
+    denominator.  ``lse`` is handed out for counters only: its
+    cotangent is not read."""
+    return _fwd(
+        q, k, v, scale, causal, block_q, block_k, group, window, sink
+    )
+
+
+def _flash_mha_sink_fwd(q, k, v, sink, scale, causal, block_q, block_k,
+                        group=1, window=None):
+    out, residuals = _flash_mha_fwd(
+        q, k, v, scale, causal, block_q, block_k, group, window, sink
+    )
+    return (out, residuals[-1]), (*residuals, sink)
+
+
+def _flash_mha_sink_bwd(scale, causal, block_q, block_k, group, window,
+                        residuals, cotangents):
+    """The three kernels as they are: ``lse`` holds the sink, so ``p =
+    exp(s - lse)`` is the sinked probability and ``delta = rowsum(dO
+    out) = sum_j p_j dP_j`` already (the sink's ``dP`` is 0).  The
+    sink's own gradient is its column's ``ds = p (dP - delta)`` summed
+    over the rows: ``- sum_i exp(sink - lse_i) delta_i``, in XLA from
+    two ``[b h, s]`` rows."""
+    *residuals, sink = residuals
+    dout, _ = cotangents
+    dq, dk, dv = _bwd(
+        scale, causal, block_q, block_k, group, window, residuals, dout
+    )
+    with jax.named_scope(SINK_SCOPE):
+        out, lse = residuals[3:]
+        dsink = -jnp.sum(
+            jnp.exp(sink[:, None, None] - lse) * _delta(out, dout),
+            axis=(1, 2),
+        )
+    return dq, dk, dv, dsink
+
+
+_flash_mha_sink.defvjp(_flash_mha_sink_fwd, _flash_mha_sink_bwd)
 
 
 def default_blocks(seq: int, itemsize: int):
@@ -1082,6 +1182,8 @@ def flash_attention(
     block_k: int | None = None,
     dtype: Any = None,  # accepted for model-pluggability; output dtype
     window: int | None = None,
+    sink: jax.Array | None = None,
+    return_lse: bool = False,
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] tensors.
 
@@ -1116,6 +1218,17 @@ def flash_attention(
     materializes there.  The dkv backward still emits per-q-head
     gradients (a transient group-x temporary) before the group
     reduction.
+
+    ``sink`` (``[heads]``, float32; with ``causal``): a learned score
+    a head that joins every row's softmax as one more column whose
+    value is dropped: ``p_ij = exp(s_ij - m_i) / (exp(sink_h - m_i) +
+    sum_j' exp(s_ij' - m_i))`` with ``m_i = max(max_j s_ij, sink_h)``.
+    The forward differs only where the statistics are closed, the
+    backward kernels not at all; ``d sink`` comes back in float32.
+    ``None`` is the program this always was.  ``return_lse`` (with a
+    sink) also hands out the rows' log-sum-exp ``[b, heads, s]``
+    float32, the sink in it, for a counter: no gradient flows
+    through it.
     """
     b, s, h, d = q.shape
     kvh = k.shape[2]
@@ -1162,11 +1275,27 @@ def flash_attention(
         hh, width = x.shape[2:]
         return x.transpose(0, 2, 1, 3).reshape(b * hh, s, width)
 
-    out = _flash_mha(
-        fold(q), fold(k), fold(v), scale, causal, block_q, block_k,
-        group, window,
-    )
+    if sink is None:
+        if return_lse:
+            raise ValueError("lse is handed out with a sink only")
+        out = _flash_mha(
+            fold(q), fold(k), fold(v), scale, causal, block_q, block_k,
+            group, window,
+        )
+    else:
+        if not causal or sink.shape != (h,):
+            raise ValueError(
+                f"a sink is [heads] = [{h}] and needs causal, not "
+                f"{sink.shape}"
+            )
+        out, lse = _flash_mha_sink(
+            fold(q), fold(k), fold(v),
+            jnp.tile(sink.astype(jnp.float32), b), scale, causal,
+            block_q, block_k, group, window,
+        )
     out = out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
     if dtype is not None:
         out = out.astype(dtype)
+    if return_lse:
+        return out, jax.lax.stop_gradient(lse).reshape(b, h, s)
     return out

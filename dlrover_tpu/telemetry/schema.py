@@ -106,6 +106,10 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # attn.window_tiles_share: the sub-blocks the sliding layers'
         # flash kernels walk over those a causal walk would
         # (models/laguna.py)
+        # attn.sink_*: a softmax with a learned sink a head
+        # (models/mimo_v2.py): the share of a row's softmax the sink
+        # takes, mean over the sinked layers, heads and rows, and the
+        # largest |sink|
         # loop.*: a looped model's exits (models/ouro.py): the mean
         # exit a token is expected to leave at, the exit
         # distribution's mean entropy, and the first and the last
@@ -118,7 +122,8 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
             "moe.held_tiles_share", "moe.bias_abs_max",
-            "attn.window_tiles_share", "loop.expected_exit",
+            "attn.window_tiles_share", "attn.sink_mass_mean",
+            "attn.sink_abs_max", "loop.expected_exit",
             "loop.exit_entropy", "loop.nll_first", "loop.nll_last",
             "ssm.state_rms_max", "ssm.decay_mean"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
