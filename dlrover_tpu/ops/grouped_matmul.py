@@ -32,6 +32,18 @@ per group, rows^T x cotangent summed over the group's tiles in a
 float32 accumulator).  Interpreter mode off the TPU, as
 ``flash_attention.py``.
 
+A whole expert, :func:`grouped_expert`, is three more (PR 52), so
+that nothing between an expert's matmuls is a pass of XLA's over the
+rows at their static size: ``gmm_up_fwd`` (a row tile, read once,
+against its group's gate AND up blocks, and the activation from their
+float32 accumulators; one block and ``relu ** 2`` for an expert
+without a gate), ``gmm_down_dlhs`` (``gmm_dlhs`` of the down
+projection with the activation's derivative as its epilogue: it
+writes the pre-activations' gradients, not the hidden rows', and a
+gate's over the two products the forward kept) and
+``gmm_up_dlhs`` (``gmm_dlhs`` over two pairs of operands summed in
+one accumulator: ONE gradient to the rows that fed two products).
+
 Beside them, for a layer most of whose tiles hold no row (a chip that
 holds a range of the experts, PR 38): ``gmm_tokens_from_rows``
 (:func:`tokens_from_rows`: the rows of the used tiles added back to
@@ -42,6 +54,7 @@ used tiles to fill, which nothing has zeroed).
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -139,40 +152,67 @@ def unwritten(shape, dtype, after: jax.Array) -> jax.Array:
 
 def _gmm_kernel(
     tile_group, tiles_used,     # scalar prefetch
-    lhs_ref,                    # [row_tile, tk]
-    rhs_ref,                    # [1, tk, tn] ([1, tn, tk] transposed)
-    out_ref,                    # [row_tile, tn]
-    *acc,                       # [row_tile, tn] f32 where k is split
-    k_steps: int, transpose_rhs: bool,
+    *refs,                      # lhs x [row_tile, tk],
+                                # rhs x [1, tk, tn] ([1, tn, tk] transposed),
+                                # beside x [row_tile, tn],
+                                # outs x [row_tile, tn],
+                                # acc [products, row_tile, tn] f32 where k
+                                # is split
+    lhs: int, rhs: int, beside: int, k_steps: int, transpose_rhs: bool,
+    finish,
 ):
     row, step = pl.program_id(1), pl.program_id(2)
     contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+    lhs_refs, refs = refs[:lhs], refs[lhs:]
+    rhs_refs, refs = refs[:rhs], refs[rhs:]
+    beside_refs, out_refs = refs[:beside], refs[beside:]
+    if k_steps > 1:
+        out_refs, acc_ref = out_refs[:-1], out_refs[-1]
 
     # a tile of no group holds the last used tile's blocks (_gmm):
-    # ``out_ref`` is that tile's result, not yet written back, and
+    # an ``out_ref`` is that tile's result, not yet written back, and
     # must not be touched
     @pl.when(row < tiles_used[0])
     def _compute():
-        part = jax.lax.dot_general(
-            lhs_ref[...], rhs_ref[0], contract,
-            preferred_element_type=jnp.float32,
-        )
+        # one lhs: its product with each rhs.  Several: the SUM of the
+        # pairs' products, one accumulator
+        parts = [
+            jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[0], contract,
+                preferred_element_type=jnp.float32,
+            )
+            for lhs_ref, rhs_ref in zip(
+                lhs_refs * rhs if lhs == 1 else lhs_refs, rhs_refs,
+                strict=True,
+            )
+        ]
+        if lhs > 1:
+            parts = [functools.reduce(operator.add, parts)]
+
+        def store(sums):
+            values = finish(
+                sums, [ref[...].astype(jnp.float32) for ref in beside_refs]
+            )
+            for ref, value in zip(out_refs, values, strict=True):
+                ref[...] = value.astype(ref.dtype)
+
         if k_steps == 1:
-            out_ref[...] = part.astype(out_ref.dtype)
+            store(parts)
             return
-        acc_ref = acc[0]
 
         @pl.when(step == 0)
         def _first():
-            acc_ref[...] = part
+            for at, part in enumerate(parts):
+                acc_ref[at] = part
 
         @pl.when(step > 0)
         def _rest():
-            acc_ref[...] += part
+            for at, part in enumerate(parts):
+                acc_ref[at] += part
 
         @pl.when(step == k_steps - 1)
         def _store():
-            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+            store([acc_ref[at] for at in range(len(parts))])
 
 
 def _fit_tile(dim: int, tile: int) -> int:
@@ -189,23 +229,43 @@ def _fit_tile(dim: int, tile: int) -> int:
     return tile
 
 
-def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
+def _gmm(
+    lhs, rhs, tile_group, tiles_used, *, name, tiles, transpose_rhs=False,
+    beside=(), outs=1, over=0, finish=lambda sums, beside: sums,
+):
+    """One walk over the row tiles: ``lhs`` (each ``[m, k]``) against
+    their groups' blocks of ``rhs`` (each ``[groups, k, n]``,
+    ``[groups, n, k]`` transposed) -> a list of ``outs`` arrays ``[m,
+    n]``.
+    One lhs is multiplied with every rhs, a product each; several are
+    paired with as many rhs and the products SUMMED in one float32
+    accumulator.  ``finish(products, beside)`` (float32 in, the
+    ``beside [m, n]`` arrays' own tiles among them) makes what is
+    written; the first ``over`` outs are written over the ``beside``
+    of their index (a tile's block of each is read before it is
+    written, and the caller reads that ``beside`` nowhere later)."""
     row_tile, k_tile, n_tile = tiles
-    m, k = rows.shape
-    n = weights.shape[1] if transpose_rhs else weights.shape[2]
+    m, k = lhs[0].shape
+    n = rhs[0].shape[1] if transpose_rhs else rhs[0].shape[2]
     tk, tn = _fit_tile(k, k_tile), _fit_tile(n, n_tile)
     if m % row_tile or k % tk or n % tn:
         raise ValueError(
-            f"rows {rows.shape} x weights {weights.shape} do not "
+            f"rows {lhs[0].shape} x weights {rhs[0].shape} do not "
             f"divide into tiles {(row_tile, tk, tn)}"
         )
     k_steps = k // tk
+    products = len(rhs) if len(lhs) == 1 else 1
+    dtype = lhs[0].dtype
 
     def held_step(i, s, nu):
         # where the contraction is split, a tile of no group also
         # stays on the last used tile's last step, rows and weights
         return jnp.where(i < nu[0], s, k_steps - 1)
 
+    lhs_spec = pl.BlockSpec(
+        (row_tile, tk),
+        lambda j, i, s, tg, nu: (_held_tile(i, nu), held_step(i, s, nu)),
+    )
     if transpose_rhs:
         rhs_spec = pl.BlockSpec(
             (1, tn, tk),
@@ -216,36 +276,36 @@ def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
             (1, tk, tn),
             lambda j, i, s, tg, nu: (tg[i], held_step(i, s, nu), j),
         )
+    # held over the tiles of no group, the last used tile's result
+    # stays in VMEM and is written back once, when the sweep over the
+    # rows ends
+    out_spec = pl.BlockSpec(
+        (row_tile, tn), lambda j, i, s, tg, nu: (_held_tile(i, nu), j)
+    )
     return pl.pallas_call(
         functools.partial(
-            _gmm_kernel, k_steps=k_steps, transpose_rhs=transpose_rhs
+            _gmm_kernel, lhs=len(lhs), rhs=len(rhs), beside=len(beside),
+            k_steps=k_steps, transpose_rhs=transpose_rhs, finish=finish,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             # rows innermost of the two: a group's tiles follow each
             # other and keep its weight block
             grid=(n // tn, m // row_tile, k_steps),
-            in_specs=[
-                pl.BlockSpec(
-                    (row_tile, tk),
-                    lambda j, i, s, tg, nu: (
-                        _held_tile(i, nu), held_step(i, s, nu)
-                    ),
-                ),
-                rhs_spec,
-            ],
-            # held over the tiles of no group, the last used tile's
-            # result stays in VMEM and is written back once, when the
-            # sweep over the rows ends
-            out_specs=pl.BlockSpec(
-                (row_tile, tn),
-                lambda j, i, s, tg, nu: (_held_tile(i, nu), j),
+            in_specs=(
+                [lhs_spec] * len(lhs) + [rhs_spec] * len(rhs)
+                + [out_spec] * len(beside)
             ),
+            out_specs=[out_spec] * outs,
             scratch_shapes=[
-                pltpu.VMEM((row_tile, tn), jnp.float32)
+                pltpu.VMEM((products, row_tile, tn), jnp.float32)
             ] if k_steps > 1 else [],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
+        out_shape=[jax.ShapeDtypeStruct((m, n), dtype)] * outs,
+        # (the scalars are operands 0 and 1)
+        input_output_aliases={
+            2 + len(lhs) + len(rhs) + at: at for at in range(over)
+        },
         # the rows are NOT "parallel": several grid steps share one
         # output block, which is right only on one walk in order.  A
         # chip that splits a parallel dimension over two cores would
@@ -254,14 +314,15 @@ def _gmm(rows, weights, tile_group, tiles_used, *, transpose_rhs, tiles):
         # one core a chip: the order costs nothing there)
         compiler_params=_params(
             ("parallel", "arbitrary", "arbitrary"),
-            _nbytes((row_tile, tk), rows.dtype),
-            _nbytes((tk, tn), weights.dtype),
-            _nbytes((row_tile, tn), rows.dtype),
-            _nbytes((row_tile, tn), jnp.float32),
+            len(lhs) * _nbytes((row_tile, tk), dtype),
+            len(rhs) * _nbytes((tk, tn), rhs[0].dtype),
+            (len(beside) + outs) * _nbytes((row_tile, tn), dtype),
+            # the accumulators and an epilogue's own values
+            (products + len(beside)) * _nbytes((row_tile, tn), jnp.float32),
         ),
         interpret=_interpret(),
-        name="gmm_dlhs" if transpose_rhs else "gmm_fwd",
-    )(tile_group, tiles_used, rows, weights)
+        name=name,
+    )(tile_group, tiles_used, *lhs, *rhs, *beside)
 
 
 # -- the gradient to the weights ----------------------------------------------------
@@ -356,10 +417,11 @@ def _tgmm(rows, cotangent, tile_group, tiles_used, *, groups, tiles):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _grouped_matmul(rows, weights, tile_group, tiles_used, tiles):
-    return _gmm(
-        rows, weights, tile_group, tiles_used, transpose_rhs=False,
+    (out,) = _gmm(
+        [rows], [weights], tile_group, tiles_used, name="gmm_fwd",
         tiles=tiles,
     )
+    return out
 
 
 def _fwd(rows, weights, tile_group, tiles_used, tiles):
@@ -370,9 +432,9 @@ def _fwd(rows, weights, tile_group, tiles_used, tiles):
 def _bwd(tiles, residuals, cotangent):
     rows, weights, tile_group, tiles_used = residuals
     cotangent = cotangent.astype(rows.dtype)
-    d_rows = _gmm(
-        cotangent, weights, tile_group, tiles_used, transpose_rhs=True,
-        tiles=tiles,
+    (d_rows,) = _gmm(
+        [cotangent], [weights], tile_group, tiles_used, name="gmm_dlhs",
+        transpose_rhs=True, tiles=tiles,
     )
     d_weights = _tgmm(
         rows, cotangent, tile_group, tiles_used,
@@ -400,6 +462,145 @@ def grouped_matmul(
     through indices that name only rows of a group;
     ``tests/test_sarvam_mla.py`` overwrites the others with NaN)."""
     return _grouped_matmul(rows, weights, tile_group, tiles_used, tiles)
+
+
+# -- a whole expert: up, activation, down ------------------------------------
+
+
+def _hidden(pre):
+    """``silu(gate) * up`` of ``pre = [gate, up]``, ``relu(up) ** 2``
+    of ``[up]``: float32 in, float32 out."""
+    if len(pre) == 1:
+        return jnp.square(jnp.maximum(pre[0], 0.0))
+    gate, up = pre
+    return gate * jax.nn.sigmoid(gate) * up
+
+
+def _d_activation(d_hidden, kept):
+    """The pre-activations' gradients from the hidden rows' and what
+    the forward kept: ``[gate, up]``, or without a gate the hidden
+    rows, ``relu(up) ** 2``, whose root is ``relu(up)`` (half the
+    rounding of a kept ``up``)."""
+    (d_hidden,) = d_hidden
+    if len(kept) == 1:
+        return [2.0 * jnp.sqrt(kept[0]) * d_hidden]
+    gate, up = kept
+    sig = jax.nn.sigmoid(gate)
+    return [
+        d_hidden * up * (sig * (1.0 + gate * (1.0 - sig))),
+        d_hidden * (gate * sig),
+    ]
+
+
+def _up(tiles, tile_group, tiles_used, rows, *weights, keep):
+    """``[hidden, *a gate's two products with keep]`` of ``rows``
+    through ``weights = (gate, up) | (up,)``.  The blocks of one grid
+    step are together as wide as one product's: at hidden 4096 x
+    width 2048 two double-buffered blocks of 16 MB would leave VMEM no
+    room beside them."""
+    row_tile, k_tile, n_tile = tiles
+    return _gmm(
+        [rows], weights, tile_group, tiles_used, name="gmm_up_fwd",
+        tiles=(row_tile, k_tile, n_tile // len(weights)),
+        outs=3 if keep else 1,
+        finish=lambda pre, _: [_hidden(pre)] + (pre if keep else []),
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _grouped_expert(
+    rows, w_gate, w_up, w_down, tile_group, tiles_used, tiles
+):
+    # (a program that asks for no gradient: nothing is kept)
+    weights = [w_up] if w_gate is None else [w_gate, w_up]
+    (hidden,) = _up(tiles, tile_group, tiles_used, rows, *weights, keep=False)
+    return _grouped_matmul(hidden, w_down, tile_group, tiles_used, tiles)
+
+
+def _expert_fwd(rows, w_gate, w_up, w_down, tile_group, tiles_used, tiles):
+    weights = [w_up] if w_gate is None else [w_gate, w_up]
+    # kept: what the derivative reads.  A gate's two products, or
+    # without a gate the hidden rows themselves
+    hidden, *kept = _up(
+        tiles, tile_group, tiles_used, rows, *weights,
+        keep=w_gate is not None,
+    )
+    out = _grouped_matmul(hidden, w_down, tile_group, tiles_used, tiles)
+    return out, (
+        rows, weights, w_down, hidden, kept or [hidden], tile_group,
+        tiles_used,
+    )
+
+
+def _expert_bwd(tiles, residuals, d_out):
+    rows, weights, w_down, hidden, kept, tile_group, tiles_used = residuals
+    d_out = d_out.astype(rows.dtype)
+    gated = len(weights) > 1
+
+    def d_weights(lhs, cotangent, like):
+        return _tgmm(
+            lhs, cotangent, tile_group, tiles_used, groups=like.shape[0],
+            tiles=tiles,
+        ).astype(like.dtype)
+
+    # the hidden rows' gradient never leaves its kernel: the
+    # derivative is its epilogue.  A gate's two gradients are written
+    # over its two products, which nothing else reads (a step of the
+    # backward holds two arrays of the padded rows fewer); the hidden
+    # rows are still the down matrix's gradient's to read
+    d_pre = _gmm(
+        [d_out], [w_down], tile_group, tiles_used, name="gmm_down_dlhs",
+        transpose_rhs=True, tiles=tiles, beside=kept, outs=len(weights),
+        over=2 if gated else 0, finish=_d_activation,
+    )
+    # ONE gradient to the rows: both products in one accumulator
+    (d_rows,) = _gmm(
+        d_pre, weights, tile_group, tiles_used,
+        name="gmm_up_dlhs" if gated else "gmm_dlhs",
+        transpose_rhs=True, tiles=tiles,
+    )
+    return (
+        d_rows,
+        # (no gate matrix, no gradient to one)
+        *([None] * (2 - len(weights))),
+        *(d_weights(rows, d, w) for d, w in zip(d_pre, weights)),
+        d_weights(hidden, d_out, w_down),
+        None, None,
+    )
+
+
+_grouped_expert.defvjp(_expert_fwd, _expert_bwd)
+
+
+def grouped_expert(
+    rows: jax.Array,         # [tiles * row_tile, k], tile-aligned groups
+    w_gate,                  # [groups, k, n], or None: no gate
+    w_up: jax.Array,         # [groups, k, n]
+    w_down: jax.Array,       # [groups, n, k]
+    tile_group: jax.Array,   # [tiles] int32   } of group_layout
+    tiles_used: jax.Array,   # [1] int32       }
+    tiles=(ROW_TILE, K_TILE, N_TILE),
+) -> jax.Array:
+    """Each row through its group's expert -> ``[rows, k]``: ``(silu(x
+    @ w_gate[g]) * (x @ w_up[g])) @ w_down[g]`` or, with
+    ``w_gate=None``, ``relu(x @ w_up[g]) ** 2 @ w_down[g]``, ``g`` a
+    row tile's group; differentiable in the rows and every matrix.
+    The activation is taken from the products' float32 accumulators
+    inside the kernel that makes them (``gmm_up_fwd``), its
+    derivative inside the kernel that makes the hidden rows' gradient
+    (``gmm_down_dlhs``: from a gate's two products as the forward
+    rule kept them, in the rows' type; without a gate from the
+    hidden rows, whose root is ``relu(up)``), and the rows get ONE
+    gradient (``gmm_up_dlhs``);
+    the down projection and the matrices' gradients are
+    :func:`grouped_matmul`'s kernels.  As there: a
+    group's padding rows are zero in and zero out (``silu(0) * 0 =
+    relu(0) ** 2 = 0``), and the rows of the tiles from
+    ``tiles_used`` on are NOT READ and NOT WRITTEN, in the result, in
+    what the forward keeps and in every gradient."""
+    return _grouped_expert(
+        rows, w_gate, w_up, w_down, tile_group, tiles_used, tiles
+    )
 
 
 # -- the rows back to their tokens --------------------------------------------

@@ -301,9 +301,10 @@ def dropless_moe(
     bias`` and weighted by the score alone (the bias only steers the
     load and takes no gradient); ``renormalise`` divides the k weights
     by their sum, ``scale`` multiplies them.  An expert computes
-    ``down(silu(gate(x)) * up(x))``, three grouped matmuls, or with
-    ``w_gate=None`` ``down(relu(up(x)) ** 2)``, two: an expert that
-    has no gate has no gate matrix.
+    ``down(silu(gate(x)) * up(x))`` or, with ``w_gate=None``,
+    ``down(relu(up(x)) ** 2)``: an expert that has no gate has no
+    gate matrix.  Either way it is one call, ``gmm.grouped_expert``:
+    the matmuls AND the activation between them, in the kernels.
 
     **Held experts.**  ``held=(lo, count)`` says that this chip holds
     experts ``[lo, lo + count)`` of the layer's ``e`` (the weights are
@@ -326,10 +327,11 @@ def dropless_moe(
     of zero rows, and the kernels skip the tiles past the last used
     one: no product, no fetch, no store, so those rows of each
     matmul's result are NOT WRITTEN, forward or backward.  Nothing
-    here reads them: the activation works row by row, the gathers back
-    go through ``slot``, which names only rows of an expert, and the
-    next matmul skips the same tiles (a reduction over the padded rows
-    would: ``tests/test_sarvam_mla.py`` fills them with NaN).
+    here reads them: the activation and its derivative run inside the
+    kernels, over the used tiles, and the gathers back go through
+    ``slot``, which names only rows of an expert (a reduction over
+    the padded rows would: ``tests/test_sarvam_mla.py`` fills them
+    with NaN).
 
     **What runs at which size.**  The router and the index work (the
     sort, ``slot``, ``source``: ``[t * k]`` and ``[padded rows]``
@@ -437,16 +439,14 @@ def dropless_moe(
                 tokens.astype(dtype), source, slot, tiles_used
             )
     with jax.named_scope("moe_experts"):
-        def expert(x, w):
-            return gmm.grouped_matmul(
-                x, w.astype(dtype), tile_group, tiles_used
-            )
-
-        if w_gate is None:
-            hidden = relu2(expert(rows, w_up))
-        else:
-            hidden = nn.silu(expert(rows, w_gate)) * expert(rows, w_up)
-        rows = expert(hidden, w_down)
+        # the kernels' walks over the used tiles, the activation and
+        # its derivative inside them: nothing here passes over the
+        # padded rows
+        rows = gmm.grouped_expert(
+            rows, None if w_gate is None else w_gate.astype(dtype),
+            w_up.astype(dtype), w_down.astype(dtype), tile_group,
+            tiles_used,
+        )
     with jax.named_scope("moe_combine"):
         if held is None:
             out = jnp.einsum(
@@ -590,10 +590,11 @@ def _rows_of(x, index):
     )
 
 
-def _rows_from_tokens(x, token_of_row, tiles_used, weight=None):
+def _rows_from_tokens(x, token_of_row, tiles_used, weight=None, after=None):
     """``weight[p] * x[token_of_row[p]]`` for the rows of the used
     tiles, ``[padded rows, d]``: a gather of one tile's rows a trip;
-    the product in float32."""
+    the product in float32.  The result exists no sooner than
+    ``after`` (``x`` where none is given)."""
 
     def move(i, rows):
         tile = _rows_of(x, _tile(token_of_row, i))
@@ -605,7 +606,10 @@ def _rows_from_tokens(x, token_of_row, tiles_used, weight=None):
 
     return jax.lax.fori_loop(
         0, tiles_used[0], move,
-        gmm.unwritten((token_of_row.shape[0], x.shape[1]), x.dtype, x),
+        gmm.unwritten(
+            (token_of_row.shape[0], x.shape[1]), x.dtype,
+            x if after is None else after,
+        ),
     )
 
 
@@ -686,15 +690,19 @@ def _held_combine_bwd(res, g):
         token_of_row = _token_of_row(source, slot)
         # a row's gradient is its gate x its token's; the gate's is
         # the row's dot product with it, back in ``[t, k]`` through
-        # ``slot`` (0 for a choice held elsewhere).  Two walks, so
-        # that the first does not wait for ``rows``, which the
-        # backward pass has to make again
+        # ``slot`` (0 for a choice held elsewhere).  Two walks.  The
+        # rows' gradient reads nothing of ``rows``, which the
+        # backward pass has to make again, and still waits for the
+        # dots that do: made before the experts run again it is a
+        # third array of the padded rows beside their hidden rows,
+        # and made beside ``rows`` a second where it can take their
+        # place (the step's most bytes live at once: PERF.md, PR 52)
+        dots = _row_dots(rows, g, token_of_row, tiles_used)
+        d_gate = dots.at[slot].get(mode="fill", fill_value=0)
         d_rows = _rows_from_tokens(
-            g, token_of_row, tiles_used, _gate_of_row(gate, source)
+            g, token_of_row, tiles_used, _gate_of_row(gate, source),
+            after=dots,
         )
-        d_gate = _row_dots(rows, g, token_of_row, tiles_used).at[
-            slot
-        ].get(mode="fill", fill_value=0)
         return d_rows, d_gate.astype(gate.dtype), None, None, None
 
 

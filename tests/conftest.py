@@ -26,7 +26,9 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+import functools  # noqa: E402
 
+import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -108,6 +110,56 @@ def pallas_calls(jaxpr):
         (under, eqn) for under, eqn in equations(jaxpr)
         if eqn.primitive.name == "pallas_call"
     )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def fill_past(x, tiles_used, fill):
+    """``x`` with ``fill`` in the rows of the tiles from
+    ``tiles_used`` on (``ops/grouped_matmul.py``'s layout), and the
+    same done to its cotangent."""
+    from dlrover_tpu.ops.grouped_matmul import ROW_TILE
+
+    past = jnp.arange(x.shape[0]) >= tiles_used[0] * ROW_TILE
+    return jnp.where(past[:, None], jnp.asarray(fill, x.dtype), x)
+
+
+fill_past.defvjp(
+    lambda x, tiles_used, fill: (fill_past(x, tiles_used, fill), tiles_used),
+    lambda fill, tiles_used, g: (fill_past(g, tiles_used, fill), None),
+)
+
+
+def fill_inside_an_expert(monkeypatch, fill, names):
+    """What ``grouped_expert`` keeps to itself, overwritten past
+    ``tiles_used`` too: every row operand and every result of each of
+    its kernels' calls (the hidden rows, the pre-activations the
+    forward rule keeps, their gradients, the cotangents the matrices'
+    gradients read).  ``names`` gets each call's kernel."""
+    from dlrover_tpu.ops import grouped_matmul as gmm
+
+    walk, to_weights = gmm._gmm, gmm._tgmm
+
+    def filled(x, tiles_used):
+        return x if fill is None else fill_past(x, tiles_used, fill)
+
+    def _gmm(lhs, rhs, tile_group, tiles_used, *, beside=(), **how):
+        names.append(how["name"])
+        results = walk(
+            [filled(x, tiles_used) for x in lhs], rhs, tile_group,
+            tiles_used, beside=[filled(x, tiles_used) for x in beside],
+            **how,
+        )
+        return [filled(x, tiles_used) for x in results]
+
+    def _tgmm(rows, cotangent, tile_group, tiles_used, **how):
+        names.append("gmm_drhs")
+        return to_weights(
+            filled(rows, tiles_used), filled(cotangent, tiles_used),
+            tile_group, tiles_used, **how,
+        )
+
+    monkeypatch.setattr(gmm, "_gmm", _gmm)
+    monkeypatch.setattr(gmm, "_tgmm", _tgmm)
 
 
 def flash_forwards(jaxpr):

@@ -2,8 +2,13 @@
 mode here) against a per-group loop and against ``jax.lax.ragged_dot``:
 ragged, empty and single-group sizes, forward and both gradients, the
 tile-aligned layout itself, and what a tile of no group costs: no
-block moves for it, and nothing it holds reaches a result."""
+block moves for it, and nothing it holds reaches a result.  Three
+forms go through every such check: the plain ``product``
+(``grouped_matmul``) and a whole expert with and without a gate
+matrix (``grouped_expert``: the activation and its derivative inside
+the kernels, one gradient to the rows)."""
 
+import functools
 import itertools
 
 import pytest
@@ -26,6 +31,8 @@ SIZES = {
     "one_group_takes_all": [0, 0, 64, 0],
     "whole_tiles": [32, 64, 32, 32],
     "most_tiles_empty": [40, 0, 24],
+    # groups of no row, one row, a row tile and a row tile and one
+    "tile_edges": [0, 1, 32, 33],
 }
 # the arrays' static row count where it is not the sum of the sizes: a
 # layer that holds a range of the experts sizes its rows for every
@@ -40,6 +47,62 @@ def by_loop(rows, weights, sizes):
         out.append(rows[start:start + size] @ weights[group])
         start += size
     return jnp.concatenate(out, axis=0)
+
+
+# what goes through the kernels: ``product`` is ``grouped_matmul`` of
+# one matrix; ``gated`` and ``ungated`` are ``grouped_expert`` of
+# (gate, up, down) and of (up, down) with the activation inside
+FORMS = ("product", "gated", "ungated")
+EXPERTS = FORMS[1:]
+# the product goes through every case it went through; an expert,
+# whose every kernel shares the product's walk, through those that
+# differ for it
+PRODUCT_CASES = [
+    ("product", case) for case in sorted(SIZES) if case != "tile_edges"
+]
+
+
+def cases(*experts_cases):
+    return pytest.mark.parametrize("form, case", PRODUCT_CASES + [
+        (form, case) for form in EXPERTS for case in experts_cases
+    ])
+
+
+KERNELS = {
+    "product": ["gmm_fwd", "gmm_dlhs", "gmm_drhs"],
+    "gated": [
+        "gmm_up_fwd", "gmm_fwd", "gmm_down_dlhs", "gmm_up_dlhs",
+        "gmm_drhs", "gmm_drhs", "gmm_drhs",
+    ],
+    "ungated": [
+        "gmm_up_fwd", "gmm_fwd", "gmm_down_dlhs", "gmm_dlhs", "gmm_drhs",
+        "gmm_drhs",
+    ],
+}
+
+
+def form_by_loop(form, rows, weights, sizes):
+    """The form's plain reference: the per-group loop and jax's own
+    activation between two of them."""
+    if form == "product":
+        return by_loop(rows, weights[0], sizes)
+    up = by_loop(rows, weights[-2], sizes)
+    if form == "ungated":
+        hidden = jnp.square(jax.nn.relu(up))
+    else:
+        hidden = jax.nn.silu(by_loop(rows, weights[0], sizes)) * up
+    return by_loop(hidden, weights[-1], sizes)
+
+
+def form_kernels(form, padded_rows, weights, layout, tiles=TILES):
+    if form == "product":
+        return gmm.grouped_matmul(
+            padded_rows, weights[0], layout[0], layout[1], tiles
+        )
+    return gmm.grouped_expert(
+        padded_rows, weights[0] if form == "gated" else None, *weights[-2:],
+        layout[0], layout[1], tiles,
+    )
 
 
 class Aligned:
@@ -61,18 +124,103 @@ class Aligned:
         self.x = jax.random.normal(keys[0], (self.rows, K), dtype)
         self.w = jax.random.normal(keys[1], (len(sizes), K, N), dtype)
         self.cot = jax.random.normal(keys[2], (self.rows, N), dtype)
+        self.w_gate = jax.random.normal(
+            jax.random.fold_in(keys[1], 1), self.w.shape, dtype
+        )
+        self.w_down = jax.random.normal(
+            jax.random.fold_in(keys[1], 2), (len(sizes), N, K), dtype
+        )
+
+    def weights(self, form):
+        """The form's matrices; an expert's are scaled so that an
+        activation's argument and the result are of order one."""
+        if form == "product":
+            return (self.w,)
+        scale = jnp.asarray(K ** -0.5, self.w.dtype)
+        all_three = (
+            self.w_gate * scale, self.w * scale,
+            self.w_down * jnp.asarray(N ** -0.5, self.w.dtype),
+        )
+        return all_three if form == "gated" else all_three[1:]
+
+    def cotangent(self, form):
+        """A cotangent of the form's result: an expert's is as wide
+        as its rows."""
+        return self.cot if form == "product" else self.x[::-1]
+
+    def through(self, form, x, weights):
+        """The form's kernels on sorted rows, through the layout:
+        ``(padded result, sorted result)``."""
+        out = form_kernels(form, self.pad(x), weights, self.layout)
+        return out, out[self.index]
 
     def pad(self, sorted_rows):
         return jnp.zeros(
             (self.padded, sorted_rows.shape[1]), sorted_rows.dtype
         ).at[self.index].set(sorted_rows)
 
-    def product(self, x, w):
-        """The kernels' product on sorted rows, through the layout."""
-        out = gmm.grouped_matmul(
-            self.pad(x), w, self.layout[0], self.layout[1], TILES
+
+@functools.lru_cache(maxsize=None)
+def there_and_back(form, case, dtype=jnp.float32):
+    """``(aligned, padded result, sorted result, gradients)`` of the
+    form's kernels on a case, the gradients to the sorted rows and to
+    every matrix under the form's cotangent.  ONE compile of the
+    interpreted kernels a (form, case, type), for every test that
+    looks at a part of it."""
+    a = Aligned(case, seed=1, dtype=dtype)
+    weights = a.weights(form)
+
+    def loss(x, *w):
+        padded, got = a.through(form, x, w)
+        cot = a.cotangent(form)
+        return jnp.sum((got * cot).astype(jnp.float32)), (padded, got)
+
+    (_, (padded, got)), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(1 + len(weights))), has_aux=True
+    ))(a.x, *weights)
+    return a, padded, got, grads
+
+
+def by_loop_and_back(form, a, dtype=jnp.float32):
+    """The same of the form's plain reference, computed in ``dtype``
+    from the aligned case's own (already rounded) operands."""
+    weights = [w.astype(dtype) for w in a.weights(form)]
+
+    def loss(x, *w):
+        want = form_by_loop(form, x, w, a.sizes)
+        return jnp.sum(want * a.cotangent(form).astype(dtype)), want
+
+    (_, want), grads = jax.value_and_grad(
+        loss, argnums=tuple(range(1 + len(weights))), has_aux=True
+    )(a.x.astype(dtype), *weights)
+    return want, grads
+
+
+@functools.lru_cache(maxsize=None)
+def past_tiles_used(form, case):
+    """``(aligned, rows used, results with NaN, results with zeros)``
+    in the rows past ``tiles_used`` of the operand and of the
+    cotangent: the result on the used tiles, the gradient to their
+    rows and the gradient to every matrix."""
+    a = Aligned(case, seed=2)
+    used = int(a.layout[1][0]) * TILES[0]
+    assert used < a.padded
+
+    @jax.jit
+    def results(fill):
+        def past(sorted_rows):
+            return a.pad(sorted_rows).at[used:].set(fill)
+
+        out, vjp = jax.vjp(
+            lambda x, *w: form_kernels(form, x, w, a.layout),
+            past(a.x), *a.weights(form),
         )
-        return out, out[self.index]
+        d_rows, *d_weights = vjp(past(a.cotangent(form)))
+        return out[:used], d_rows[:used], *d_weights
+
+    return a, used, *(
+        [np.asarray(r) for r in results(fill)] for fill in (jnp.nan, 0.0)
+    )
 
 
 @pytest.mark.parametrize("case", sorted(SIZES))
@@ -98,25 +246,32 @@ def test_layout_gives_every_group_whole_tiles_of_its_own(case):
     ]
 
 
-@pytest.mark.parametrize("case", sorted(SIZES))
-def test_forward_equals_the_per_group_loop(case):
-    a = Aligned(case)
-    padded, got = a.product(a.x, a.w)
-    want = by_loop(a.x, a.w, a.sizes)
+@cases("tile_edges", "most_tiles_empty")
+def test_forward_equals_the_per_group_loop(form, case):
+    if case == "most_tiles_empty":
+        # (what the rows past ``tiles_used`` hold is another test's)
+        a, _, _, (padded, *_) = past_tiles_used(form, case)
+        got = padded[a.index]
+    else:
+        a, _, got, _ = there_and_back(form, case)
+    weights = a.weights(form)
+    want = form_by_loop(form, a.x, weights, a.sizes)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
-    np.testing.assert_allclose(
-        got, jax.lax.ragged_dot(a.x, a.w, jnp.asarray(a.sizes, jnp.int32)),
-        rtol=1e-5, atol=1e-4,
-    )
+    if form == "product":
+        np.testing.assert_allclose(
+            got,
+            jax.lax.ragged_dot(a.x, a.w, jnp.asarray(a.sizes, jnp.int32)),
+            rtol=1e-5, atol=1e-4,
+        )
 
 
-@pytest.mark.parametrize("case", sorted(SIZES))
-def test_padding_rows_inside_a_used_tile_come_out_zero(case):
+@cases("tile_edges")
+def test_padding_rows_inside_a_used_tile_come_out_zero(form, case):
     """Zero in, zero out for a group's own padding (``parallel/moe.py
-    ::_collect_bwd`` rests on it).  The tiles past ``tiles_used`` are
+    ::_collect_bwd`` rests on it), through an activation too (``silu(0)
+    * 0 = relu(0) ** 2 = 0``).  The tiles past ``tiles_used`` are
     another matter: their rows are not written."""
-    a = Aligned(case)
-    padded, _ = a.product(a.x, a.w)
+    a, padded, _, _ = there_and_back(form, case)
     padding = np.ones(a.padded, bool)
     padding[a.index] = False
     padding[int(a.layout[1][0]) * TILES[0]:] = False
@@ -126,37 +281,24 @@ def test_padding_rows_inside_a_used_tile_come_out_zero(case):
     assert not np.asarray(padded)[padding].any()
 
 
-@pytest.mark.parametrize("case", sorted(SIZES))
-def test_nothing_past_tiles_used_reaches_a_result(case):
+@cases("most_tiles_empty")
+def test_nothing_past_tiles_used_reaches_a_result(form, case):
     """The contract from the reading side: with the rows of the
     operand and of the cotangent past ``tiles_used`` set to NaN, the
-    product on the used tiles, the gradient to their rows and the
-    gradient to the weights (an empty group's zeros among them) are
-    finite and bit-equal to what zeros there give."""
-    a = Aligned(case, seed=2)
-    used = int(a.layout[1][0]) * TILES[0]
-    assert used < a.padded
-
-    def results(fill):
-        def past(sorted_rows):
-            return a.pad(sorted_rows).at[used:].set(fill)
-
-        out, vjp = jax.vjp(
-            lambda x, w: gmm.grouped_matmul(
-                x, w, a.layout[0], a.layout[1], TILES
-            ),
-            past(a.x), a.w,
-        )
-        d_rows, d_weights = vjp(past(a.cot))
-        return [np.asarray(r) for r in (out[:used], d_rows[:used], d_weights)]
-
-    got, want = results(jnp.nan), results(0.0)
+    result on the used tiles, the gradient to their rows and the
+    gradient to every matrix (an empty group's zeros among them) are
+    finite and bit-equal to what zeros there give.  (What the forward
+    rule keeps for the backward, a gate's two products or the hidden
+    rows, is not written there either, and the derivative's kernel
+    reads none of it.)"""
+    a, _, got, want = past_tiles_used(form, case)
+    assert len(got) == 2 + len(a.weights(form))
     for g, w in zip(got, want):
         assert np.isfinite(g).all()
         np.testing.assert_array_equal(g, w)
     for group, size in enumerate(a.sizes):
         if size == 0:
-            assert not got[2][group].any()
+            assert not any(d[group].any() for d in got[2:])
 
 
 def _pallas_calls(jaxpr):
@@ -190,13 +332,18 @@ INDEX_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(INDEX_CASES))
-def test_a_tile_of_no_group_moves_no_block(case):
+@pytest.mark.parametrize("form, case", [
+    *(("product", case) for case in sorted(INDEX_CASES)),
+    ("gated", "sarvam_264_tiles_20_used"), ("gated", "split_contraction"),
+    ("ungated", "olmoe_320_tiles_288_used"),
+])
+def test_a_tile_of_no_group_moves_no_block(form, case):
     """The index rule itself, read from the traced kernels: walking
     each grid in its order, a used tile names its own rows (operand,
-    cotangent, output) and its group's weights; on a tile past
-    ``tiles_used`` NO block's index differs from the grid step before,
-    so the pipeline neither fetches nor writes back for it."""
+    cotangent, output, an activation's kept arguments and their
+    gradients) and its group's weights; on a tile past ``tiles_used``
+    NO block's index differs from the grid step before, so the
+    pipeline neither fetches nor writes back for it."""
     c = INDEX_CASES[case]
     tile_sizes = c["tiles"]
     layout = gmm.group_layout(
@@ -207,40 +354,64 @@ def test_a_tile_of_no_group_moves_no_block(case):
     assert (tiles, used) == c["want"]
     dtype = jnp.bfloat16
 
-    def loss(r, w):
-        return gmm.grouped_matmul(
-            r, w, layout[0], layout[1], tile_sizes
-        ).astype(jnp.float32).sum()
+    shapes = {
+        "product": ["kn"], "gated": ["kn", "kn", "nk"],
+        "ungated": ["kn", "nk"],
+    }[form]
 
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+    def loss(r, *w):
+        return form_kernels(form, r, w, layout, tile_sizes).astype(
+            jnp.float32
+        ).sum()
+
+    jaxpr = jax.make_jaxpr(
+        jax.value_and_grad(loss, argnums=tuple(range(1 + len(shapes))))
+    )(
         jax.ShapeDtypeStruct((tiles * tile_sizes[0], c["k"]), dtype),
-        jax.ShapeDtypeStruct((len(c["sizes"]), c["k"], c["n"]), dtype),
+        *[
+            jax.ShapeDtypeStruct(
+                (len(c["sizes"]), c[shape[0]], c[shape[1]]), dtype
+            )
+            for shape in shapes
+        ],
     )
     calls = list(_pallas_calls(jaxpr.jaxpr))
-    assert [e.params["name"] for e in calls] == [
-        "gmm_fwd", "gmm_dlhs", "gmm_drhs"
-    ]
+    assert [e.params["name"] for e in calls] == KERNELS[form]
+    # a call's blocks: its operands and results (with a gate the
+    # forward rule's up call also writes the two products, the down
+    # projection's gradient reads them and writes their gradients,
+    # and the rows' ONE gradient takes a pair a matrix; without a
+    # gate the hidden rows are what the derivative reads)
+    gated = form == "gated"
+    blocks = {
+        "gmm_fwd": 3, "gmm_dlhs": 3, "gmm_drhs": 3,
+        "gmm_up_fwd": 6 if gated else 3,
+        "gmm_down_dlhs": 6 if gated else 4, "gmm_up_dlhs": 5,
+    }
     for call in calls:
+        name = call.params["name"]
         mapping = call.params["grid_mapping"]
-        row_axis = 2 if call.params["name"] == "gmm_drhs" else 1
+        row_axis = 2 if name == "gmm_drhs" else 1
         assert mapping.grid[row_axis] == tiles
         steps = np.array(
             list(itertools.product(*map(range, mapping.grid))), np.int32
         )
         row = steps[:, row_axis]
-        assert len(mapping.block_mappings) == 3
-        for block in mapping.block_mappings:
-            index_map = block.index_map_jaxpr
+        assert len(mapping.block_mappings) == blocks[name]
+        index_maps = [b.index_map_jaxpr for b in mapping.block_mappings]
 
-            def at(step, index_map=index_map):
-                return jnp.stack(jax.core.eval_jaxpr(
+        def at(step, index_maps=index_maps):
+            # every block's index at one grid step
+            return [
+                jnp.stack(jax.core.eval_jaxpr(
                     index_map.jaxpr, index_map.consts, *step,
                     jax.new_ref(layout[0]), jax.new_ref(layout[1]),
                 ))
+                for index_map in index_maps
+            ]
 
-            index = np.asarray(
-                jax.jit(lambda s, at=at: jax.lax.map(at, s))(steps)
-            )
+        for index in jax.jit(lambda s, at=at: jax.lax.map(at, s))(steps):
+            index = np.asarray(index)
             own = row if index.shape[1] == 2 else tile_group[row]
             np.testing.assert_array_equal(
                 index[row < used, 0], own[row < used]
@@ -250,37 +421,49 @@ def test_a_tile_of_no_group_moves_no_block(case):
             np.testing.assert_array_equal(index[empty], index[empty - 1])
 
 
-@pytest.mark.parametrize("case", sorted(SIZES))
-def test_both_gradients_equal_the_per_group_loop(case):
-    a = Aligned(case, seed=1)
-
-    def through(fn):
-        return jax.grad(
-            lambda x, w: jnp.sum(fn(x, w) * a.cot), argnums=(0, 1)
-        )(a.x, a.w)
-
-    got = through(lambda x, w: a.product(x, w)[1])
-    want = through(lambda x, w: by_loop(x, w, a.sizes))
-    for g, w in zip(got, want):
+@cases("tile_edges")
+def test_both_gradients_equal_the_per_group_loop(form, case):
+    """The gradient to the rows (ONE, where they fed two products)
+    and to every matrix against ``jax.grad`` of the loop."""
+    a, _, _, got = there_and_back(form, case)
+    _, want = by_loop_and_back(form, a)
+    assert len(got) == 1 + len(a.weights(form))
+    for g, w in zip(got, want, strict=True):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-4)
     # a group without rows gets a zero gradient, not garbage
     for group, size in enumerate(a.sizes):
         if size == 0:
-            assert not np.asarray(got[1][group]).any()
+            assert not any(np.asarray(d[group]).any() for d in got[1:])
 
 
-def test_bf16_operands_accumulate_in_float32():
+@pytest.mark.parametrize("form", FORMS)
+def test_bf16_operands_accumulate_in_float32(form, case="ragged"):
     """bf16 in, bf16 out, but the sum over k (two tiles of it here) is
-    kept in float32: against the float32 product of the same (already
+    kept in float32, and an activation is taken from that sum, not
+    from its rounding: against the float32 form of the same (already
     rounded) operands the result is within one bf16 rounding."""
-    a = Aligned("ragged", dtype=jnp.bfloat16)
-    _, got = a.product(a.x, a.w)
+    a, _, got, _ = there_and_back(form, case, jnp.bfloat16)
     assert got.dtype == jnp.bfloat16
-    want = by_loop(
-        a.x.astype(jnp.float32), a.w.astype(jnp.float32), a.sizes
-    )
+    want, _ = by_loop_and_back(form, a)
     err = np.abs(np.asarray(got, np.float32) - np.asarray(want))
     assert err.max() <= 2.0 ** -8 * np.abs(np.asarray(want)).max()
+
+
+@pytest.mark.parametrize("form", EXPERTS)
+def test_an_experts_bf16_gradients_are_the_float32_forms_within_rounding(
+    form, case="tile_edges"
+):
+    """The derivative is taken in float32 from what the forward kept
+    in bf16 (a gate's two products; without a gate the hidden rows,
+    whose root is ``relu(up)``) and every product accumulates in
+    float32: each gradient is within a few bf16 roundings of
+    ``jax.grad`` of the float32 form of the same operands."""
+    a, _, _, got = there_and_back(form, case, jnp.bfloat16)
+    _, want = by_loop_and_back(form, a)
+    for g, w in zip(got, want, strict=True):
+        assert g.dtype == jnp.bfloat16
+        err = np.abs(np.asarray(g, np.float32) - np.asarray(w))
+        assert err.max() <= 2.0 ** -6 * np.abs(np.asarray(w)).max()
 
 
 def test_sizes_that_do_not_divide_into_tiles_are_refused():

@@ -27,7 +27,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import loader  # noqa: E402  (the benchmark's own)
-from conftest import jax_internal, primitives_under  # noqa: E402
+from conftest import (  # noqa: E402
+    fill_inside_an_expert, fill_past, jax_internal, primitives_under,
+)
 
 from dlrover_tpu.models.gpt import count_params  # noqa: E402
 from dlrover_tpu.models.nemotron_h import (  # noqa: E402
@@ -422,83 +424,77 @@ def test_the_sixteen_shares_add_up_to_the_whole_layer():
     np.testing.assert_allclose(out[0], routed + shared, atol=1e-5)
 
 
-def test_an_ungated_expert_is_two_grouped_matmuls_round_relu2():
+def test_an_ungated_expert_is_one_call_of_the_kernels_with_relu2_inside():
     """The forward of the training loss under ``moe_experts`` and
-    ``moe_shared``, an expert layer: two grouped matmuls (each a
-    ``custom_vjp_call``) and two plain ones, ``relu(.) ** 2`` between;
-    no third matmul, no ``silu``.  (The gated form's count is pinned
-    where its families are tested: ``tests/test_olmoe.py``,
-    ``tests/test_sarvam_mla.py``.)"""
+    ``moe_shared``, an expert layer: ``grouped_expert`` (ONE
+    ``custom_vjp_call``: ``relu(.) ** 2`` is inside the up
+    projection's kernel since PR 52, no ``max`` and no ``square``
+    over the padded rows) and the shared expert's two plain matmuls
+    with ``relu(.) ** 2`` between; no gate matrix, no ``silu``.
+    (The gated form's count is pinned where its families are tested:
+    ``tests/test_olmoe.py``, ``tests/test_sarvam_mla.py``; that the
+    call holds two products and not three,
+    ``test_no_row_past_tiles_used_reaches_the_ungated_layer``.)"""
     model, params, batch = toy()
     jaxpr = jax.make_jaxpr(make_nemotron_h_loss(model, num_chunks=4))(
         params, batch
     ).jaxpr
     layers = PATTERN.count("E")
-    # (``relu`` is a ``custom_jvp_call``; float32 weights need no cast)
+    # (float32 weights need no cast)
     assert primitives_under(jaxpr, "moe_experts") == {
-        "custom_vjp_call": 2 * layers, "custom_jvp_call": layers,
-        "square": layers,
+        "custom_vjp_call": layers,
     }
+    # (``relu`` is a ``custom_jvp_call``)
     assert primitives_under(jaxpr, "moe_shared") == {
         "dot_general": 2 * layers, "custom_jvp_call": layers,
         "square": layers, "add": layers,
     }
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _fill_past(x, tiles_used, fill):
-    """``x`` with ``fill`` in the rows of the tiles from
-    ``tiles_used`` on, and the same done to its cotangent."""
-    past = jnp.arange(x.shape[0]) >= tiles_used[0] * gmm.ROW_TILE
-    return jnp.where(past[:, None], jnp.asarray(fill, x.dtype), x)
-
-
-_fill_past.defvjp(
-    lambda x, tiles_used, fill: (_fill_past(x, tiles_used, fill), tiles_used),
-    lambda fill, tiles_used, g: (_fill_past(g, tiles_used, fill), None),
-)
-
-
 def test_no_row_past_tiles_used_reaches_the_ungated_layer(monkeypatch):
     """The rows of the tiles past ``tiles_used`` are not written, and
-    ``relu(.) ** 2`` of what the memory held there may be anything:
-    with every such row of both grouped matmuls' results AND of their
-    gradients to the rows, of the dispatch's output and of the
-    combine's gradient overwritten with NaN, the output and all four
-    gradients are finite and bit-equal to the run with zeros there
-    and to the run as it is (``tests/test_sarvam_mla.py`` for the
-    gated form)."""
+    the memory may hold anything there: with every such row of the
+    experts' result AND of its gradient to the rows, of every array
+    between the kernels of ``grouped_expert`` (the hidden rows with
+    ``relu(.) ** 2`` taken inside, which are all the forward keeps,
+    the gradient of up's product that the derivative makes of their
+    root, every row operand of the matrices' gradients), of
+    the dispatch's output and of the combine's gradient overwritten
+    with NaN, the output and all four gradients are finite and
+    bit-equal to the run with zeros there and to the run as it is
+    (``tests/test_sarvam_mla.py`` for the gated form)."""
     operands = layer_operands(t=512, e=64, seed=2)
-    real = gmm.grouped_matmul
+    real = gmm.grouped_expert
     held_dispatch, held_combine = moe._held_dispatch, moe._held_combine
-    products = []
+    calls, kernels = [], []
 
     def results(fill):
-        def product(rows, weights, tile_group, tiles_used, *tiles):
-            products.append(int(tiles_used[0]))
+        def experts(rows, w_gate, w_up, w_down, tile_group, tiles_used):
+            calls.append((w_gate, int(tiles_used[0])))
             if fill is None:
-                return real(rows, weights, tile_group, tiles_used, *tiles)
-            return _fill_past(
+                return real(rows, w_gate, w_up, w_down, tile_group, tiles_used)
+            return fill_past(
                 real(
-                    _fill_past(rows, tiles_used, fill), weights,
-                    tile_group, tiles_used, *tiles,
+                    fill_past(rows, tiles_used, fill), w_gate, w_up, w_down,
+                    tile_group, tiles_used,
                 ),
                 tiles_used, fill,
             )
 
         def dispatch(tokens, source, slot, tiles_used):
-            return _fill_past(
+            return fill_past(
                 held_dispatch(tokens, source, slot, tiles_used),
                 tiles_used, fill,
             )
 
         def combine(rows, gate, source, slot, tiles_used):
             return held_combine(
-                _fill_past(rows, tiles_used, fill), gate, source, slot,
+                fill_past(rows, tiles_used, fill), gate, source, slot,
                 tiles_used,
             )
 
-        monkeypatch.setattr(moe.gmm, "grouped_matmul", product)
+        monkeypatch.setattr(moe.gmm, "grouped_expert", experts)
+        fill_inside_an_expert(monkeypatch, fill, kernels)
         if fill is not None:
             monkeypatch.setattr(moe, "_held_dispatch", dispatch)
             monkeypatch.setattr(moe, "_held_combine", combine)
@@ -511,8 +507,13 @@ def test_no_row_past_tiles_used_reaches_the_ungated_layer(monkeypatch):
         return [np.asarray(a) for a in (out, *grads)]
 
     as_it_is = results(None)
-    # two products forward, not three; 4 of the layout's 12 tiles used
-    assert products[:2] == [4, 4] and len(products) == 2
+    # one call, with no gate matrix; 4 of the layout's 12 tiles used
+    assert calls == [(None, 4)]
+    # two products forward, not three, and ``gmm_dlhs`` to the rows
+    assert kernels == [
+        "gmm_up_fwd", "gmm_fwd", "gmm_down_dlhs", "gmm_dlhs", "gmm_drhs",
+        "gmm_drhs",
+    ]
     with_nan, with_zeros = results(jnp.nan), results(0.0)
     for got, zeros, plain in zip(with_nan, with_zeros, as_it_is):
         assert np.isfinite(got).all() and got.any()

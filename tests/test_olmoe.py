@@ -302,13 +302,14 @@ def test_no_tokens_by_experts_by_capacity_tensor_in_the_step():
     assert (t, cfg.num_experts, capacity) in shapes_in(text)
 
 
-def test_a_gated_expert_is_three_grouped_matmuls_round_one_silu():
+def test_a_gated_expert_is_one_call_of_the_kernels_and_no_pass_beside():
     """The forward of the training loss under ``moe_experts``, a
-    layer: the three grouped matmuls (each a ``custom_vjp_call``)
-    with their weights' casts, one ``silu`` and one product; what it
-    was before ``dropless_moe`` got its ungated form (PR 47: recorded
-    on that PR's parent).  Three cells run this path: a change that
-    moves the count has to be measured in them."""
+    layer: ``grouped_expert`` (ONE ``custom_vjp_call``) with the
+    three weights' casts, and NOTHING else: the ``silu`` and the
+    product are inside the up projections' kernel since PR 52 (before
+    it: three grouped matmuls, a ``jit`` and a ``mul`` over the
+    padded rows).  Five cells run this path: a change that moves the
+    count has to be measured in them."""
     model = Olmoe(OlmoeConfig.tiny())
     params = jax.eval_shape(
         lambda: model.init_params(jax.random.PRNGKey(0), seq_len=64)
@@ -319,8 +320,7 @@ def test_a_gated_expert_is_three_grouped_matmuls_round_one_silu():
     ).jaxpr
     layers = model.config.num_layers
     assert primitives_under(jaxpr, "moe_experts") == {
-        "custom_vjp_call": 3 * layers, "convert_element_type": 3 * layers,
-        "jit": layers, "mul": layers,
+        "custom_vjp_call": layers, "convert_element_type": 3 * layers,
     }
     assert "experts_w_gate" in params["block_0"]["moe"]
 

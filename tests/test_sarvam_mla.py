@@ -25,7 +25,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import loader  # noqa: E402  (the benchmark's own)
-from conftest import primitives_under  # noqa: E402
+from conftest import (  # noqa: E402
+    fill_inside_an_expert, fill_past, primitives_under,
+)
 
 from dlrover_tpu.checkpoint.checkpointer import (  # noqa: E402
     Checkpointer,
@@ -455,19 +457,6 @@ def test_a_share_that_no_token_reaches_and_one_that_all_reach():
         dropless_moe(*operands, 4, held=(0, 4))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _fill_past(x, tiles_used, fill):
-    """``x`` with ``fill`` in the rows of the tiles from
-    ``tiles_used`` on, and the same done to its cotangent."""
-    past = jnp.arange(x.shape[0]) >= tiles_used[0] * gmm.ROW_TILE
-    return jnp.where(past[:, None], jnp.asarray(fill, x.dtype), x)
-
-
-_fill_past.defvjp(
-    lambda x, tiles_used, fill: (_fill_past(x, tiles_used, fill), tiles_used),
-    lambda fill, tiles_used, g: (_fill_past(g, tiles_used, fill), None),
-)
-
 UNWRITTEN = {
     # OLMoE's tiny case: softmax, not renormalised, every expert held
     "every_expert_held": dict(
@@ -489,13 +478,16 @@ UNWRITTEN = {
 
 @pytest.mark.parametrize("case", sorted(UNWRITTEN))
 def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
-    """A grouped matmul does not write the rows of the tiles past
-    ``tiles_used``, forward or backward.  That is safe because the
-    layer reads none of them: with every such row of every grouped
-    matmul's result AND of its gradient to the rows overwritten with
-    NaN, the output and all five gradients are finite and bit-equal
-    to the run with zeros there (the kernels' contract until PR 36)
-    and to the run as it is.  Where the chip holds a range, the
+    """The experts' kernels do not write the rows of the tiles past
+    ``tiles_used``, forward or backward.  That is safe because
+    nothing reads one: with every such row of the experts' result
+    and of its gradient to the rows, AND of every array between the
+    kernels of ``grouped_expert`` (the hidden rows, the kept
+    pre-activations of gate and up, their gradients, every row
+    operand of the matrices' gradients) overwritten with NaN, the
+    output and all five gradients are finite and bit-equal to the
+    run with zeros there (the kernels' contract until PR 36) and to
+    the run as it is.  Where the chip holds a range, the
     dispatch's output and the combine's gradient are not written
     there either (PR 38) and are overwritten alike.  A reduction over
     the padded rows, or a gather that names one, fails here."""
@@ -504,9 +496,9 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
     bias = None
     if "avoid" in c:
         bias = jnp.zeros(operands[1].shape[1:]).at[c["avoid"]].set(-9.0)
-    real = gmm.grouped_matmul
+    real = gmm.grouped_expert
     held_dispatch, held_combine = moe._held_dispatch, moe._held_combine
-    seen = []
+    seen, kernels = [], []
 
     def layer(*ops):
         if c["held"] is None:
@@ -514,15 +506,15 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
         return share(ops, c["held"], c["top_k"], bias=bias)
 
     def results(fill):
-        def product(rows, weights, tile_group, tiles_used, *tiles):
+        def experts(rows, w_gate, w_up, w_down, tile_group, tiles_used):
             seen.append((tiles_used, tile_group.shape[0]))
             if fill is None:
-                return real(rows, weights, tile_group, tiles_used, *tiles)
+                return real(rows, w_gate, w_up, w_down, tile_group, tiles_used)
             # the cotangent's fill first (d_rows), the result's last
-            return _fill_past(
+            return fill_past(
                 real(
-                    _fill_past(rows, tiles_used, fill), weights,
-                    tile_group, tiles_used, *tiles,
+                    fill_past(rows, tiles_used, fill), w_gate, w_up, w_down,
+                    tile_group, tiles_used,
                 ),
                 tiles_used, fill,
             )
@@ -532,7 +524,7 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
             return jnp.sum(out * cot), (out, stats)
 
         def dispatch(tokens, source, slot, tiles_used):
-            return _fill_past(
+            return fill_past(
                 held_dispatch(tokens, source, slot, tiles_used),
                 tiles_used, fill,
             )
@@ -540,11 +532,12 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
         def combine(rows, gate, source, slot, tiles_used):
             # the fill of ``rows`` is the fill of their gradient
             return held_combine(
-                _fill_past(rows, tiles_used, fill), gate, source, slot,
+                fill_past(rows, tiles_used, fill), gate, source, slot,
                 tiles_used,
             )
 
-        monkeypatch.setattr(moe.gmm, "grouped_matmul", product)
+        monkeypatch.setattr(moe.gmm, "grouped_expert", experts)
+        fill_inside_an_expert(monkeypatch, fill, kernels)
         if fill is not None:
             monkeypatch.setattr(moe, "_held_dispatch", dispatch)
             monkeypatch.setattr(moe, "_held_combine", combine)
@@ -556,7 +549,12 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
 
     as_it_is, stats = results(None)
     used, tiles = c["tiles"]
-    assert {(int(u[0]), n) for u, n in seen} == {(used, tiles)}
+    assert [(int(u[0]), n) for u, n in seen] == [(used, tiles)]
+    # ONE call, and these its kernels, forward rule and backward
+    assert kernels == [
+        "gmm_up_fwd", "gmm_fwd", "gmm_down_dlhs", "gmm_up_dlhs",
+        "gmm_drhs", "gmm_drhs", "gmm_drhs",
+    ]
     if "avoid" in c:
         assert float(stats["counts"][c["avoid"]]) == 0
     with_nan, _ = results(jnp.nan)
@@ -833,40 +831,63 @@ def dropless_moe_at_pr_33(
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_the_defaults_are_the_layer_olmoe_has_always_run(dtype):
     """OLMoE's tiny case (8 experts, top-2, softmax, not renormalised,
-    every expert held): output and all five gradients BIT-equal to the
-    function as the parent commit had it."""
+    every expert held): output and all five gradients against the
+    function as PR 33 had it, whose activation was XLA's between
+    three grouped matmuls.  BIT-equal until PR 52; since then the
+    activation is taken inside the kernel from the products' float32
+    sums (not from their rounding to ``dtype``), its derivative is
+    the down projection's kernel's epilogue and the rows' two
+    gradients are summed in float32.  In float32 the two agree to a
+    few roundings.  In bf16 the new path is held to being NO LESS
+    EXACT than PR 33's: against PR 33's function run in float32 on
+    the same operands, the output and every gradient lie closer (by
+    their errors' root mean square: 15 to 45% closer here) and none
+    of their elements further than 2 ** -6 of the largest."""
     operands = layer_operands(t=128, d=64, m=32, e=8, seed=4)
 
-    def new(*ops):
-        return dropless_moe(*ops, 2, dtype)[0]
+    def results(fn):
+        loss = lambda *ops: fn(*ops).astype(jnp.float32).sum()  # noqa: E731
+        return [
+            np.asarray(leaf, np.float32) for leaf in jax.tree.leaves((
+                jax.jit(fn)(*operands),
+                jax.jit(jax.grad(loss, range(5)))(*operands),
+            ))
+        ]
 
-    def old(*ops):
-        return dropless_moe_at_pr_33(*ops, 2, dtype)
+    new = results(lambda *ops: dropless_moe(*ops, 2, dtype)[0])
+    old = results(lambda *ops: dropless_moe_at_pr_33(*ops, 2, dtype))
+    assert len(new) == len(old) == 6
+    if dtype == jnp.float32:
+        for a, b in zip(new, old):
+            assert a.shape == b.shape and np.abs(b).max() > 0
+            assert np.abs(a - b).max() <= 2.0 ** -20 * np.abs(b).max()
+        return
+    truth = results(
+        lambda *ops: dropless_moe_at_pr_33(*ops, 2, jnp.float32)
+    )
+    for a, b, true in zip(new, old, truth, strict=True):
+        assert a.shape == b.shape == true.shape
 
-    for fn_new, fn_old in (
-        (new, old),
-        (jax.grad(lambda *o: new(*o).astype(jnp.float32).sum(), range(5)),
-         jax.grad(lambda *o: old(*o).astype(jnp.float32).sum(), range(5))),
-    ):
-        for a, b in zip(
-            jax.tree.leaves(jax.jit(fn_new)(*operands)),
-            jax.tree.leaves(jax.jit(fn_old)(*operands)),
-        ):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+        def rms(x, true=true):
+            return np.sqrt(np.mean(np.square(x - true)))
+
+        assert 0 < rms(a) <= rms(b)
+        assert np.abs(a - true).max() <= 2.0 ** -6 * np.abs(true).max()
 
 
 # -- the step: scopes, counters, the leaf no gradient reaches -----------------
 
 
-def test_a_gated_expert_is_three_grouped_matmuls_round_one_silu():
+def test_a_gated_expert_is_one_call_of_the_kernels_and_no_pass_beside():
     """The forward of the training loss under ``moe_experts`` and
-    ``moe_shared``, an expert layer: three grouped matmuls (each a
-    ``custom_vjp_call``) with their weights' casts, one ``silu`` and
-    one product; the shared expert three plain matmuls, one ``silu``,
-    one product and the sum onto the routed output; what both were
-    before the layer got its ungated form (PR 47: recorded on that
-    PR's parent).  Two cells run this path: a change that moves the
-    count has to be measured in them."""
+    ``moe_shared``, an expert layer: ``grouped_expert`` (ONE
+    ``custom_vjp_call``) with the three weights' casts and NOTHING
+    else: the ``silu`` and the product are inside the up projections'
+    kernel since PR 52 (before it: three grouped matmuls, a ``jit``
+    and a ``mul`` over the padded rows); the shared expert three
+    plain matmuls, one ``silu``, one product and the sum onto the
+    routed output, as it was.  Five cells run this path: a change
+    that moves the count has to be measured in them."""
     model = SarvamMla(SarvamMlaConfig.tiny(remat=True))
     params = jax.eval_shape(
         lambda: model.init_params(jax.random.PRNGKey(0), seq_len=64)
@@ -877,8 +898,7 @@ def test_a_gated_expert_is_three_grouped_matmuls_round_one_silu():
     ).jaxpr
     layers = model.config.num_layers - model.config.first_dense
     assert primitives_under(jaxpr, "moe_experts") == {
-        "custom_vjp_call": 3 * layers, "convert_element_type": 3 * layers,
-        "jit": layers, "mul": layers,
+        "custom_vjp_call": layers, "convert_element_type": 3 * layers,
     }
     assert primitives_under(jaxpr, "moe_shared") == {
         "dot_general": 3 * layers, "convert_element_type": 3 * layers,
