@@ -267,25 +267,268 @@ def op_names_path(key: str, cache_dir: Optional[str] = None) -> str:
     return os.path.join(cache_dir, key + OP_NAMES_SUFFIX)
 
 
+# -- the op-name map -----------------------------------------------------------
+#
+# What the COMPILER made (a layout copy, a pad, the halves of an
+# asynchronous copy or slice, a fusion of such) carries no metadata.
+# Each such instruction is work some layer of the program asked for:
+# the map says whose, by four rules over the optimized text.
+
+_COMPUTATION = re.compile(r"^(ENTRY )?(%[\w\-.]+) \(.*\{$")
+_OPERAND = re.compile(r"%[\w\-.]+")
+_CALLED = re.compile(
+    r"\b(?:body|condition|to_apply|calls|true_computation|"
+    r"false_computation)=(%[\w\-.]+)|branch_computations=\{([^}]*)\}"
+)
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+# instructions that hold others and run none of their own
+CONTAINER_OPCODES = ("while", "conditional", "call")
+# instructions a value passes through untouched: a user or a producer
+# is looked for beyond them
+_VIEWS = ("get-tuple-element", "bitcast", "tuple")
+# what is no operation of the device's (a container's time is its
+# body's)
+_NOT_RUN = _VIEWS + CONTAINER_OPCODES + ("parameter", "constant")
+
+
+def _closing(text: str, at: int) -> int:
+    """Index of the parenthesis that closes the one at ``at``."""
+    depth = 0
+    for i in range(at, len(text)):
+        c = text[i]
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+            if not depth:
+                return i
+    return len(text) - 1
+
+
+def _parse_instruction(line: str, at: int):
+    """``(opcode, shape, [operand], [called computation])`` of an
+    instruction's line, read from the end of ``%name = `` on."""
+    end = _closing(line, at) + 1 if line[at] == "(" else line.index(
+        " ", at
+    )
+    paren = line.index("(", end)
+    close = _closing(line, paren)
+    opcode = line[end + 1:paren]
+    called = []
+    if opcode == "fusion" or opcode in CONTAINER_OPCODES:
+        for one, many in _CALLED.findall(line, close):
+            called += [one] if one else _OPERAND.findall(many)
+    return (
+        opcode, line[at:end], _OPERAND.findall(line, paren, close),
+        called,
+    )
+
+
+def _common_stack(stacks) -> Optional[str]:
+    """The deepest prefix, by ``/`` component, that ``stacks`` share,
+    where it holds a component below the jit's root or is the one
+    stack all of them are (a parameter's own name has no root); else
+    None."""
+    if len(set(stacks)) == 1:
+        return stacks[0]
+    shared = os.path.commonprefix([s.split("/") for s in stacks])
+    return "/".join(shared) if len(shared) >= 2 else None
+
+
+def _inherit(computations, entry, names):
+    """``(inherited, containers, unnamed)`` of :func:`op_names`.
+    ``computations`` is ``{name: [(instruction, line, where its text
+    starts)]}``, a computation's root last."""
+    parsed, containers, queue = {}, [], [entry]
+    # a computation's root -> the container whose result it is
+    returns_to = {}
+    while queue:
+        computation = queue.pop()
+        if computation in parsed or computation not in computations:
+            continue
+        rows = parsed[computation] = {}
+        for name, line, at in computations[computation]:
+            rows[name] = _parse_instruction(line, at)
+            if rows[name][0] in CONTAINER_OPCODES:
+                containers.append(name)
+                queue += rows[name][3]
+                for called in rows[name][3]:
+                    if computations.get(called):
+                        returns_to[computations[called][-1][0]] = name
+    users_in = {}
+    for computation, rows in parsed.items():
+        users = users_in[computation] = {}
+        for name, (_, _, operands, _) in rows.items():
+            for operand in operands:
+                users.setdefault(operand, []).append(name)
+    inherited: Dict[str, List[str]] = {}
+
+    def stack_of(name):
+        return names.get(name) or inherited.get(name, (None,))[0]
+
+    def beyond(rows, users, name, forward, seen):
+        """The stacks of ``name``'s users (``forward``) or of its
+        operands' producers, past the instructions that only pass a
+        value on; a computation's root is used by its container."""
+        found = []
+        if forward and stack_of(returns_to.get(name)):
+            found.append(stack_of(returns_to[name]))
+        for other in users.get(name, ()) if forward else rows[name][2]:
+            if other in seen or other not in rows:
+                continue
+            seen.add(other)
+            if rows[other][0] in _VIEWS:
+                found += beyond(rows, users, other, forward, seen)
+            elif stack_of(other):
+                found.append(stack_of(other))
+        return found
+
+    body_stacks: Dict[str, Optional[str]] = {}
+
+    def body_stack(fused):
+        """The stack of a fused computation's root or, where the root
+        is the compiler's own (a convert, a copy of the result), what
+        the nearest named instructions it is made from share; else
+        what the whole body's stacks share.  (The same in every
+        round: it reads the text's own stacks alone.)"""
+        if fused not in body_stacks:
+            body_stacks[fused] = fused_stack(computations.get(fused))
+        return body_stacks[fused]
+
+    def fused_stack(body):
+        if not body:
+            return None
+        lines = {name: (line, at) for name, line, at in body}
+        level, seen = [body[-1][0]], set()
+        while level:
+            stacks = [names[n] for n in level if n in names]
+            if stacks:
+                found = _common_stack(stacks)
+                if found:
+                    return found
+                break
+            fresh = [n for n in level if n in lines and n not in seen]
+            seen.update(fresh)
+            level = [
+                operand for n in fresh
+                for operand in _parse_instruction(*lines[n])[2]
+            ]
+        stacks = [names[n] for n in lines if n in names]
+        return _common_stack(stacks) if stacks else None
+
+    # (computation, instruction) of what still has no stack
+    pending = [
+        (computation, name) for computation, rows in parsed.items()
+        for name, row in rows.items()
+        if row[0] not in _NOT_RUN and name not in names
+    ]
+
+    def one_round(rules):
+        left = []
+        for computation, name in pending:
+            rows, users = parsed[computation], users_in[computation]
+            opcode, _, operands, called = rows[name]
+            for rule in rules:
+                stack = None
+                if rule == "start" and opcode.endswith("-done"):
+                    stack = operands and stack_of(operands[0])
+                elif rule == "start" and opcode.endswith("-start"):
+                    stack = next((
+                        stack_of(u) for u in users.get(name, ())
+                        if rows[u][0].endswith("-done") and stack_of(u)
+                    ), None)
+                elif rule == "body" and opcode == "fusion":
+                    stack = called and body_stack(called[0])
+                elif rule in ("user", "operand"):
+                    stacks = beyond(
+                        rows, users, name, rule == "user", {name}
+                    )
+                    stack = stacks and _common_stack(stacks)
+                if stack:
+                    inherited[name] = [stack, rule]
+                    break
+            else:
+                left.append((computation, name))
+        took = len(pending) - len(left)
+        pending[:] = left
+        return took
+
+    # the users' word counts before the operands': a copy made to
+    # feed a kernel belongs to that kernel's layer
+    while one_round(("start", "body", "user")) or one_round(
+        ("operand",)
+    ):
+        pass
+    unnamed = {}
+    for computation, name in pending:
+        opcode, shape = parsed[computation][name][:2]
+        unnamed[name] = f"{opcode} {_LAYOUT.sub('', shape)}"
+    return inherited, containers, unnamed
+
+
 def op_names(hlo_text: str) -> Dict[str, Any]:
-    """``{"module": name, "op_names": {instruction: jax name
-    stack}}`` of an optimized HLO module's text: each instruction's
-    ``op_name`` metadata, which holds the ``jax.named_scope`` names
-    it was lowered under (``jit(step_fn)/optimizer/mul``)."""
+    """What a device trace, which names an operation by its HLO
+    instruction alone, is joined with.  Of an optimized module's text:
+
+    ``module``      its name;
+    ``op_names``    ``{instruction: jax name stack}``: each
+                    instruction's ``op_name`` metadata, which holds
+                    the device scopes it was lowered under
+                    (``jit(step_fn)/optimizer/mul``);
+    ``inherited``   ``{instruction: [stack, rule]}`` for the
+                    instructions WITHOUT metadata that run as
+                    operations of their own (the entry computation's
+                    and those of every computation a ``while``,
+                    ``conditional`` or ``call`` runs; not a fusion's
+                    body, not a reducer).  Rules, in this order, to a
+                    fixed point: ``start``, the two halves of a
+                    ``*-start`` / ``*-done`` pair are one operation
+                    and share what either has; ``body``, a fusion
+                    takes its fused computation's root's stack, else
+                    that of what the root is made from, else what its
+                    body's stacks share; ``user``, what the
+                    stacks of its users share, past
+                    ``get-tuple-element``, ``bitcast`` and ``tuple``
+                    (a computation's root is used by its container),
+                    where that holds a component below the jit's
+                    root; ``operand``, where a round of those three
+                    names nothing more, the same over its operands'
+                    producers;
+    ``containers``  the ``while`` / ``conditional`` / ``call``
+                    instructions: a reader counts their bodies, not
+                    them a second time;
+    ``unnamed``     ``{instruction: "<opcode> <shape>"}``: what no
+                    rule reached.
+    """
     module = _MODULE.match(hlo_text)
     names = {}
+    computations: Dict[str, List] = {}
+    entry = body = None
     for line in hlo_text.splitlines():
         found = _INSTRUCTION.match(line)
         if not found:
+            header = _COMPUTATION.match(line)
+            if header:
+                body = computations[header.group(2)] = []
+                if header.group(1):
+                    entry = header.group(2)
             continue
         # the metadata follows the operands; a custom call's
         # backend_config (a kernel's whole body) comes after it
         stack = _OP_NAME.search(line)
         if stack:
             names[found.group(1)] = stack.group(1)
+        if body is not None:
+            body.append((found.group(1), line, found.end()))
+    inherited, containers, unnamed = _inherit(
+        computations, entry, names
+    )
     return {
         "module": module.group(1) if module else None,
         "op_names": names,
+        "inherited": inherited,
+        "containers": containers,
+        "unnamed": unnamed,
     }
 
 
@@ -296,11 +539,16 @@ def save_op_names(
     its entry.  A device trace names an operation by its instruction
     (``%fusion.13``) and, taken without the HLO proto, carries no
     name stack: with this map a reader can sum device time by the
-    program's own scope names.  Written once, with the entry, on the
-    cold path; optional like the entry itself."""
+    program's own scope names, which it lists under ``scopes`` (the
+    trace that registered them has just run in this process).
+    Written once, with the entry, on the cold path; optional like
+    the entry itself."""
+    from dlrover_tpu.telemetry.tracing import DEVICE_SCOPES
+
     path = op_names_path(key, cache_dir)
     try:
         names = op_names(compiled.as_text())
+        names["scopes"] = sorted(DEVICE_SCOPES)
         tmp = f"{path}.{os.getpid()}.tmp"
         with open(tmp, "w") as f:
             json.dump(names, f)

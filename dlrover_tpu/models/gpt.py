@@ -36,6 +36,7 @@ from dlrover_tpu.parallel.pipeline import (
     pipeline_train_step_1f1b,
 )
 from dlrover_tpu.parallel.sharding import constrain_activation
+from dlrover_tpu.telemetry.tracing import device_scope
 
 
 @dataclass(frozen=True)
@@ -291,7 +292,7 @@ class GPT(nn.Module):
         # device scope "loss_head": the logits projection here and
         # the reduction in cross_entropy_loss, forward, backward and
         # remat copies alike
-        with jax.named_scope("loss_head"):
+        with device_scope("loss_head"):
             if cfg.tie_embeddings:
                 logits = wte.attend(x.astype(cfg.dtype))
             else:
@@ -306,7 +307,7 @@ class GPT(nn.Module):
 
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Mean next-token cross entropy; fp32 for the reduction."""
-    with jax.named_scope("loss_head"):
+    with device_scope("loss_head"):
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
         return nll.mean()

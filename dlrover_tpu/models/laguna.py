@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.parallel.moe import DroplessMoE
+from dlrover_tpu.telemetry.tracing import device_scope
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 SCOPE_OF = {FULL: "full_attn", SLIDING: "swa"}
@@ -143,7 +144,7 @@ class LagunaAttention(nn.Module):
         q = proj(heads * d, "q_proj")(x)
         k = proj(kv * d, "k_proj")(x)
         v = proj(kv * d, "v_proj")(x)
-        with jax.named_scope("attn_rope"):
+        with device_scope("attn_rope"):
             cos, sin = self.rope.tables(s, d)
             q = layers.rotate_partial(q.reshape(b, s, heads, d), cos, sin)
             k = layers.rotate_partial(k.reshape(b, s, kv, d), cos, sin)
@@ -152,7 +153,7 @@ class LagunaAttention(nn.Module):
             cfg.attention_impl, q, k, v, window=self.window,
             dtype=cfg.dtype,
         )
-        with jax.named_scope("attn_gate"):
+        with device_scope("attn_gate"):
             gate = jax.nn.sigmoid(
                 proj(heads, "g_proj")(x).astype(jnp.float32)
             )
@@ -176,7 +177,7 @@ class LagunaBlock(nn.Module):
     def __call__(self, x: jax.Array):
         cfg = self.config
         sliding = self.kind == SLIDING
-        with jax.named_scope(SCOPE_OF[self.kind]):
+        with device_scope(SCOPE_OF[self.kind]):
             x = x + LagunaAttention(
                 cfg, self.heads,
                 cfg.sliding_window if sliding else None,

@@ -41,6 +41,7 @@ from dlrover_tpu.parallel.sequence import (
     shard_local_attention,
     ulysses_attention,
 )
+from dlrover_tpu.telemetry.tracing import device_scope
 
 REMAT_POLICIES = ("full", "offload")
 
@@ -250,7 +251,7 @@ def _with_sink(impl, q, k, v, sink, kw):
         )
     form = flash_attention if impl == "flash" else _xla
     out, lse = form(q, k, v, sink=sink, return_lse=True, **kw)
-    with jax.named_scope(SINK_SCOPE):
+    with device_scope(SINK_SCOPE):
         mass = jnp.mean(
             jnp.exp(jax.lax.stop_gradient(
                 sink.astype(jnp.float32)

@@ -45,6 +45,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.telemetry.tracing import device_scope
+
 
 def _split(a, num_chunks):
     """``[n, seq, ...]`` with the scan axis leading and a chunk's rows
@@ -96,7 +98,7 @@ def _chunk_nll(h_chunk, kernel, t_chunk, transpose):
 def _head(hidden, head_kernel, targets, num_chunks, transpose):
     """Value only: one matmul a chunk, no gradient formed."""
     b, s, _ = hidden.shape
-    with jax.named_scope("loss_head"):
+    with device_scope("loss_head"):
         kernel = head_kernel.astype(hidden.dtype)
 
         def body(total, xs):
@@ -117,7 +119,7 @@ def _head_fwd(hidden, head_kernel, targets, num_chunks, transpose):
     b, s, h = hidden.shape
     d_hidden_spec = "tv,vh->th" if transpose else "tv,hv->th"
     d_kernel_spec = "th,tv->vh" if transpose else "th,tv->hv"
-    with jax.named_scope("loss_head"):
+    with device_scope("loss_head"):
         # head matmuls in the activation dtype (bf16 on TPU) like the
         # models' own head paths; only the softmax is float32
         kernel = head_kernel.astype(hidden.dtype)
@@ -164,7 +166,7 @@ def _head_fwd(hidden, head_kernel, targets, num_chunks, transpose):
 
 def _head_bwd(num_chunks, transpose, residuals, ct):
     d_hidden, d_kernel = residuals
-    with jax.named_scope("loss_head"):
+    with device_scope("loss_head"):
         return (
             d_hidden * ct.astype(d_hidden.dtype),
             d_kernel * ct.astype(d_kernel.dtype),
@@ -180,7 +182,7 @@ def _weighted_head(hidden, head_kernel, weights, targets, num_chunks):
     """Value only: ``(sum of weight x nll, the per-row nll [n, seq])``,
     one matmul a chunk, no gradient formed."""
     n = hidden.shape[0]
-    with jax.named_scope("loss_head"):
+    with device_scope("loss_head"):
         kernel = head_kernel.astype(hidden.dtype)
         h_chunks, t_chunks, w_chunks = (
             _split(a, num_chunks) for a in (hidden, targets, weights)
@@ -203,7 +205,7 @@ def _weighted_head_fwd(hidden, head_kernel, weights, targets, num_chunks):
     cotangent of 1) formed where each chunk's logits already are:
     :func:`_head_fwd` with ``d_logits`` scaled row by row."""
     n = hidden.shape[0]
-    with jax.named_scope("loss_head"):
+    with device_scope("loss_head"):
         kernel = head_kernel.astype(hidden.dtype)
         h_chunks, t_chunks, w_chunks = (
             _split(a, num_chunks) for a in (hidden, targets, weights)
@@ -248,7 +250,7 @@ def _weighted_head_bwd(num_chunks, residuals, cts):
     ``stop_gradient``."""
     d_hidden, d_kernel, nll = residuals
     ct, _ = cts
-    with jax.named_scope("loss_head"):
+    with device_scope("loss_head"):
         return (
             d_hidden * ct.astype(d_hidden.dtype),
             d_kernel * ct.astype(d_kernel.dtype),

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.parallel.moe import DroplessMoE, bias_deltas
+from dlrover_tpu.telemetry.tracing import device_scope
 
 FULL, WINDOW = 0, 1
 SCOPE_OF = {FULL: "full_attn", WINDOW: "swa"}
@@ -153,13 +154,13 @@ class MiMoV2Attention(nn.Module):
             layers.dense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             init_std=cfg.init_std,
         )
-        with jax.named_scope("attn_qkv"):
+        with device_scope("attn_qkv"):
             qkv = proj((heads + kv) * d + kv * dv, "qkv_proj")(x)
             q, k, v = jnp.split(
                 qkv, (heads * d, (heads + kv) * d), axis=-1
             )
             v = (v * cfg.value_scale).reshape(b, s, kv, dv)
-        with jax.named_scope("attn_rope"):
+        with device_scope("attn_rope"):
             cos, sin = self.rope.tables(s, d)
             q = layers.rotate_partial(q.reshape(b, s, heads, d), cos, sin)
             k = layers.rotate_partial(k.reshape(b, s, kv, d), cos, sin)
@@ -175,7 +176,7 @@ class MiMoV2Attention(nn.Module):
         )
         if self.sinked:
             out, mass = out
-        with jax.named_scope("attn_out"):
+        with device_scope("attn_out"):
             return proj(cfg.hidden_dim, "o_proj")(
                 out.reshape(b, s, heads * dv)
             ), mass
@@ -194,7 +195,7 @@ class MiMoV2Block(nn.Module):
     def __call__(self, x: jax.Array):
         cfg = self.config
         window = self.kind == WINDOW
-        with jax.named_scope(SCOPE_OF[self.kind]):
+        with device_scope(SCOPE_OF[self.kind]):
             out, mass = MiMoV2Attention(
                 cfg,
                 cfg.swa_num_heads if window else cfg.num_heads,
@@ -300,7 +301,7 @@ def make_mimo_v2_loss(model: MiMoV2, num_chunks: int = 8):
             hidden, params["lm_head"]["kernel"], batch["y"],
             num_chunks=num_chunks,
         )
-        with jax.named_scope("moe_router"):
+        with device_scope("moe_router"):
             counts = jax.lax.stop_gradient(stats["counts"])
             deltas = bias_deltas(counts, cfg.bias_update_rate)
             biases = jnp.stack([
@@ -321,7 +322,7 @@ def make_mimo_v2_loss(model: MiMoV2, num_chunks: int = 8):
             },
         }
         if sink_mass is not None:
-            with jax.named_scope(layers.SINK_SCOPE):
+            with device_scope(layers.SINK_SCOPE):
                 aux["attn.sink_mass_mean"] = jnp.mean(sink_mass)
                 aux["attn.sink_abs_max"] = jnp.max(jnp.abs(jnp.stack([
                     params[f"block_{i}"]["attn"]["sink"]

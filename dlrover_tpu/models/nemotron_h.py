@@ -58,6 +58,7 @@ from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.ops.causal_conv import causal_conv
 from dlrover_tpu.ops.ssd import ssd_scan
 from dlrover_tpu.parallel.moe import DroplessMoE, bias_deltas
+from dlrover_tpu.telemetry.tracing import device_scope
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -151,11 +152,11 @@ class Mamba2Mixer(nn.Module):
             layers.dense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             init_std=cfg.init_std,
         )
-        with jax.named_scope("ssm_in_proj"):
+        with device_scope("ssm_in_proj"):
             zxbcdt = proj(2 * inner + 2 * bc + heads, "in_proj")(u)
             z = zxbcdt[..., :inner]
             dt = zxbcdt[..., 2 * inner + 2 * bc:]
-        with jax.named_scope("ssm_conv"):
+        with device_scope("ssm_conv"):
             taps = self.param(
                 "conv", layers.conv_init, (cfg.conv_kernel, inner + 2 * bc),
                 cfg.param_dtype,
@@ -184,18 +185,18 @@ class Mamba2Mixer(nn.Module):
                 cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor
             ), (heads,), jnp.float32,
         )
-        with jax.named_scope("ssm_gates"):
+        with device_scope("ssm_gates"):
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
             A = -jnp.exp(a_log)
             decay_mean = jnp.mean(jnp.exp(dt * A))
-        with jax.named_scope("ssm_scan"):
+        with device_scope("ssm_scan"):
             # (the block's remat keeps nothing of it for the backward)
             y, state = ssd_scan(x, dt, A, B, C, chunk=cfg.chunk_size)
-        with jax.named_scope("ssm_gates"):
+        with device_scope("ssm_gates"):
             y = y.astype(jnp.float32) + skip[:, None] * x.astype(
                 jnp.float32
             )
-        with jax.named_scope("ssm_norm"):
+        with device_scope("ssm_norm"):
             # the gate first, then one RMS a group of channels
             scale = self.param(
                 "norm", nn.initializers.ones, (inner,), jnp.float32
@@ -207,7 +208,7 @@ class Mamba2Mixer(nn.Module):
             )
             y = (y.reshape(b, s, inner) * scale).astype(cfg.dtype)
             state_rms = jnp.sqrt(jnp.mean(state * state))
-        with jax.named_scope("ssm_out_proj"):
+        with device_scope("ssm_out_proj"):
             out = proj(cfg.hidden_dim, "out_proj")(y)
         return out, {"state_rms": state_rms, "decay_mean": decay_mean}
 
@@ -261,7 +262,7 @@ class NemotronHBlock(nn.Module):
                 name="moe",
             )(h)
         elif self.kind == ATTENTION:
-            with jax.named_scope("full_attn"):
+            with device_scope("full_attn"):
                 out, stats = Attention(cfg, name="attn")(h), None
         else:
             raise ValueError(f"unknown layer kind {self.kind!r}")
@@ -335,7 +336,7 @@ def make_nemotron_h_loss(model: NemotronH, num_chunks: int = 8):
             hidden, params["lm_head"]["kernel"], batch["y"],
             num_chunks=num_chunks,
         )
-        with jax.named_scope("moe_router"):
+        with device_scope("moe_router"):
             counts = jax.lax.stop_gradient(moe["counts"])
             deltas = bias_deltas(counts, cfg.bias_update_rate)
             biases = jnp.stack([

@@ -45,6 +45,7 @@ from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.ops.causal_conv import causal_conv
 from dlrover_tpu.ops.gated_delta_rule import gated_delta_rule
+from dlrover_tpu.telemetry.tracing import device_scope
 
 LINEAR, FULL = "linear_attention", "full_attention"
 PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
@@ -166,7 +167,7 @@ class GatedDeltaNet(nn.Module):
         # [b, s, heads x d] layout (a head's sum is a matmul with the
         # heads' indicator): a [.., heads, d] view of 96 or 192 lanes
         # costs a relayout each way
-        with jax.named_scope("gdn_conv"):
+        with device_scope("gdn_conv"):
             def conv(name, y, dtype):
                 taps = self.param(
                     name, layers.conv_init,
@@ -179,7 +180,7 @@ class GatedDeltaNet(nn.Module):
             q = conv("q_conv", q, jnp.float32)
             k = conv("k_conv", k, jnp.float32)
             v = conv("v_conv", v, cfg.dtype)
-        with jax.named_scope("gdn_gates"):
+        with device_scope("gdn_gates"):
             q = _head_rsqrt(q, heads, 1e-6) * dk ** -0.5
             k = _head_rsqrt(k, heads, 1e-6)
             q, k = q.astype(cfg.dtype), k.astype(cfg.dtype)
@@ -187,13 +188,13 @@ class GatedDeltaNet(nn.Module):
             if cfg.allow_neg_eigval:
                 beta = 2.0 * beta
             g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
-        with jax.named_scope("gdn_rule"):
+        with device_scope("gdn_rule"):
             # (the kernels' custom_vjp says what the backward keeps)
             o, state = gated_delta_rule(
                 q.reshape(b, s, heads, dk), k.reshape(b, s, heads, dk),
                 v.reshape(b, s, heads, dv), g, beta,
             )
-        with jax.named_scope("gdn_norm"):
+        with device_scope("gdn_norm"):
             # per head, one learned scale of size d_v, gated by z
             scale = self.param(
                 "o_norm", nn.initializers.ones, (dv,), jnp.float32

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.parallel.moe import DroplessMoE
+from dlrover_tpu.telemetry.tracing import device_scope
 
 
 @dataclass(frozen=True)
@@ -191,7 +192,7 @@ def make_olmoe_loss(
             hidden, params["lm_head"]["kernel"], batch["y"],
             num_chunks=num_chunks,
         )
-        with jax.named_scope("moe_router"):
+        with device_scope("moe_router"):
             lb, z, load = router_losses(stats, model.config.top_k)
         loss = ce + lb_weight * lb + z_weight * z
         return loss, {

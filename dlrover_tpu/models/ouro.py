@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import weighted_chunked_cross_entropy
+from dlrover_tpu.telemetry.tracing import device_scope
 
 
 @dataclass(frozen=True)
@@ -182,14 +183,14 @@ class Ouro(nn.Module):
         """``(x_t, its gate logit [b, s] float32 or None)``: the stack
         and the final norm that closes the pass."""
         cfg = self.config
-        with jax.named_scope("ut"):
+        with device_scope("ut"):
             for i in range(cfg.num_layers):
                 x = getattr(self, f"block_{i}")(x)
             x = self.ln_f(x)
         if cfg.ut_steps == 1:
             # one pass: no gate, and none in the tree
             return x, None
-        with jax.named_scope("exit_gate"):
+        with device_scope("exit_gate"):
             return x, self.exit_gate(x)
 
     def __call__(self, tokens: jax.Array, return_hidden: bool = False):
@@ -245,7 +246,7 @@ def make_ouro_loss(model: Ouro, num_chunks: int = 16):
             {"params": params}, batch["x"], return_hidden=True
         )
         steps, b, s, h = exits.shape
-        with jax.named_scope("exit_gate"):
+        with device_scope("exit_gate"):
             p, log_p = exit_distribution(gate_logits)
             entropy = -jnp.sum(p * log_p, axis=0)           # [b, s]
         mixed, nll = weighted_chunked_cross_entropy(
@@ -253,7 +254,7 @@ def make_ouro_loss(model: Ouro, num_chunks: int = 16):
             jnp.tile(batch["y"], (steps, 1)),
             (p / (b * s)).reshape(steps * b, s), num_chunks=num_chunks,
         )
-        with jax.named_scope("exit_gate"):
+        with device_scope("exit_gate"):
             loss = mixed - cfg.entropy_weight * entropy.mean()
             nll = nll.reshape(steps, b, s)
             exit_at = jnp.arange(1, steps + 1, dtype=jnp.float32)

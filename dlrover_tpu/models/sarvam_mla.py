@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.parallel.moe import DroplessMoE, bias_deltas
+from dlrover_tpu.telemetry.tracing import device_scope
 
 
 @dataclass(frozen=True)
@@ -129,16 +130,16 @@ class LatentAttention(nn.Module):
             layers.dense, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             init_std=cfg.init_std,
         )
-        with jax.named_scope("mla_q"):
+        with device_scope("mla_q"):
             q = proj(heads * (nope + rope), "q_proj")(x)
-        with jax.named_scope("mla_kv_down"):
+        with device_scope("mla_kv_down"):
             down = proj(cfg.kv_lora_rank + rope, "kv_down")(x)
             latent = layers.RMSNorm(cfg.rms_eps, name="kv_norm")(
                 down[..., :cfg.kv_lora_rank]
             )
-        with jax.named_scope("mla_kv_up"):
+        with device_scope("mla_kv_up"):
             up = proj(heads * (nope + dv), "kv_up")(latent)
-        with jax.named_scope("mla_rope"):
+        with device_scope("mla_rope"):
             angles = (
                 jnp.arange(s, dtype=jnp.float32)[:, None]
                 * jnp.asarray(layers.yarn_inv_freq(
@@ -171,7 +172,7 @@ class LatentAttention(nn.Module):
             cfg.attention_impl, q, k, v, scale=softmax_scale(cfg),
             dtype=cfg.dtype,
         )
-        with jax.named_scope("mla_out"):
+        with device_scope("mla_out"):
             return proj(cfg.hidden_dim, "o_proj")(
                 out.reshape(b, s, heads * dv)
             )
@@ -272,7 +273,7 @@ def make_sarvam_mla_loss(model: SarvamMla, num_chunks: int = 8):
             hidden, params["lm_head"]["kernel"], batch["y"],
             num_chunks=num_chunks,
         )
-        with jax.named_scope("moe_router"):
+        with device_scope("moe_router"):
             counts = jax.lax.stop_gradient(stats["counts"])
             deltas = bias_deltas(counts, cfg.bias_update_rate)
             biases = jnp.stack([

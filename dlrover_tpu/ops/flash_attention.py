@@ -87,6 +87,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.telemetry.tracing import device_scope
+
 NEG_INF = -1e30
 _LANES = 128
 # the five arrays the backward kernels read, under the names a remat
@@ -1133,7 +1135,7 @@ def _flash_mha_sink_bwd(scale, causal, block_q, block_k, group, window,
     dq, dk, dv = _bwd(
         scale, causal, block_q, block_k, group, window, residuals, dout
     )
-    with jax.named_scope(SINK_SCOPE):
+    with device_scope(SINK_SCOPE):
         out, lse = residuals[3:]
         dsink = -jnp.sum(
             jnp.exp(sink[:, None, None] - lse) * _delta(out, dout),

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.ops import grouped_matmul as gmm
+from dlrover_tpu.telemetry.tracing import device_scope
 
 
 def top_k_gating(
@@ -239,7 +240,7 @@ def _dispatch_fwd(tokens, source, slot):
 
 
 def _dispatch_bwd(slot, g):
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         return g[slot].sum(axis=1).astype(g.dtype), None, None
 
 
@@ -264,7 +265,7 @@ def _collect_fwd(rows, source, slot):
 
 
 def _collect_bwd(source, g):
-    with jax.named_scope("moe_combine"):
+    with device_scope("moe_combine"):
         flat = g.reshape((-1, g.shape[-1]))
         return flat.at[source].get(mode="clip"), None, None
 
@@ -359,7 +360,7 @@ def dropless_moe(
             f"{w_up.shape[0]} experts' weights for {count} held"
         )
     assignments = t * top_k
-    with jax.named_scope("moe_router"):
+    with device_scope("moe_router"):
         logits = jnp.dot(
             tokens.astype(jnp.float32),
             router_kernel.astype(jnp.float32),
@@ -393,7 +394,7 @@ def dropless_moe(
                 jax.nn.logsumexp(logits, axis=-1) ** 2
             ),
         }
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         tile_group, tiles_used, padded_starts = gmm.group_layout(
             group_sizes, assignments
         )
@@ -438,7 +439,7 @@ def dropless_moe(
             rows = _held_dispatch(
                 tokens.astype(dtype), source, slot, tiles_used
             )
-    with jax.named_scope("moe_experts"):
+    with device_scope("moe_experts"):
         # the kernels' walks over the used tiles, the activation and
         # its derivative inside them: nothing here passes over the
         # padded rows
@@ -447,7 +448,7 @@ def dropless_moe(
             w_up.astype(dtype), w_down.astype(dtype), tile_group,
             tiles_used,
         )
-    with jax.named_scope("moe_combine"):
+    with device_scope("moe_combine"):
         if held is None:
             out = jnp.einsum(
                 "tkd,tk->td", _collect_rows(rows, source, slot), gate,
@@ -531,7 +532,7 @@ class DroplessMoE(nn.Module):
                     kernel_init=self.kernel_init, name=name,
                 )
 
-            with jax.named_scope("moe_shared"):
+            with device_scope("moe_shared"):
                 if gated:
                     hidden = nn.silu(
                         dense(self.shared_dim, "shared_gate")(x)
@@ -651,7 +652,7 @@ def _held_dispatch_fwd(tokens, source, slot, tiles_used):
 
 def _held_dispatch_bwd(res, g):
     source, slot, tiles_used = res
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         d_tokens = gmm.tokens_from_rows(
             g, _token_of_row(source, slot), tiles_used, slot.shape[0]
         )
@@ -686,7 +687,7 @@ def _held_combine_fwd(rows, gate, source, slot, tiles_used):
 
 def _held_combine_bwd(res, g):
     rows, gate, source, slot, tiles_used = res
-    with jax.named_scope("moe_combine"):
+    with device_scope("moe_combine"):
         token_of_row = _token_of_row(source, slot)
         # a row's gradient is its gate x its token's; the gate's is
         # the row's dot product with it, back in ``[t, k]`` through

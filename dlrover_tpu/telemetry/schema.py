@@ -464,6 +464,11 @@ SPAN_SCHEMAS: Dict[str, SpanSchema] = {
             "save is skipped), or this engine's first snapshot before "
             "the call returns (waited_s)"),
         SpanSchema(
+            "ckpt.save.route", "trainer",
+            "choosing the save's route: does the state hold device "
+            "arrays, and does every device report room for a "
+            "snapshot beside the step's scratch"),
+        SpanSchema(
             "ckpt.save.snapshot", "trainer",
             "on-device copy of the state"),
         SpanSchema(
@@ -472,7 +477,8 @@ SPAN_SCHEMAS: Dict[str, SpanSchema] = {
             "beneath ckpt.save.write"),
         SpanSchema(
             "ckpt.save.enqueue", "trainer",
-            "handing the snapshot to the writer thread"),
+            "counting the snapshot's bytes and handing it to the "
+            "writer thread"),
         SpanSchema(
             "ckpt.save.write", "trainer (writer thread)",
             "the snapshot's shm write (lock_wait, fetch, memcpy ... "

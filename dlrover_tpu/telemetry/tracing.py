@@ -15,6 +15,10 @@ and (3) emitted as a ``span`` training event when an event log is
 configured — which is how cross-process parent/child linkage is
 verified end to end.
 
+One name system: a host span (:func:`span`), its profiler annotation
+(:func:`annotation`) and a device scope (:func:`device_scope`, the
+name a device operation is summed under).
+
 One clock: in a process that has imported jax, every span also enters
 a ``jax.profiler.TraceAnnotation`` named ``dlrover.<span name>``
 (:func:`annotation`).  It reaches a device trace only while a
@@ -30,9 +34,9 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Set
 
 from dlrover_tpu.telemetry import events as _events
 from dlrover_tpu.telemetry import metrics as _metrics
@@ -98,6 +102,26 @@ def annotation(name: str, wall_ns: int, **stats):
         return ann
     except Exception:  # noqa: BLE001 - a half-imported jax, an
         return None  # older profiler: the event log still has it
+
+
+# every name :func:`device_scope` was entered under in this process
+DEVICE_SCOPES: Set[str] = set()
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)``, with ``name`` kept in
+    :data:`DEVICE_SCOPES`: the one way the package opens a device
+    scope.  The name becomes a component of the ``op_name`` of every
+    instruction lowered inside (metadata alone: the program is the
+    same), which is how a device trace's operations are summed by the
+    program's own layers; the set goes into the op-name map beside
+    the step's AOT entry (``common/aot_cache.py::save_op_names``) and
+    tells a scope (``ssm_norm``) from a flax module's name
+    (``block_3``).  Runs while a function is TRACED, never in a step.
+    jax is looked up as :func:`annotation` does."""
+    DEVICE_SCOPES.add(name)
+    jax = sys.modules.get("jax")
+    return jax.named_scope(name) if jax else nullcontext()
 
 
 @dataclass

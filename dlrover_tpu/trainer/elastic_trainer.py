@@ -39,7 +39,7 @@ from dlrover_tpu.parallel.sharding import (
 from dlrover_tpu.telemetry import tracing as trace
 from dlrover_tpu.telemetry.events import emit_event
 from dlrover_tpu.telemetry.metrics import get_registry
-from dlrover_tpu.telemetry.tracing import annotation
+from dlrover_tpu.telemetry.tracing import annotation, device_scope
 
 _REG = get_registry()
 _REPORTED_STEP = _REG.gauge(
@@ -335,7 +335,7 @@ def make_train_step(
     def grads_of(params, batch):
         # (with has_aux, ``loss`` is the pair (loss, aux) down to
         # where step_fn splits it)
-        with jax.named_scope("forward_backward"):
+        with device_scope("forward_backward"):
             loss, grads = jax.value_and_grad(
                 loss_fn, has_aux=has_aux
             )(params, batch)
@@ -382,7 +382,7 @@ def make_train_step(
 
         # device scope: every op of the optimizer pass carries
         # "optimizer" in its name stack, so a trace can sum them
-        with jax.named_scope("optimizer"):
+        with device_scope("optimizer"):
             updates, new_opt = optimizer.update(
                 grads, state.opt_state, state.params
             )

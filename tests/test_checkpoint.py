@@ -521,7 +521,9 @@ def test_first_snapshot_on_a_reporting_device_commits_in_the_call(
     lock = saver._shm_locks[0]
     try:
         assert lock.acquire(note="test")
-        _release_later(lock, 0.3)
+        # (held well past what comes before the wait: the first
+        # save's set-up is 0.05-0.2 s of the host's own)
+        _release_later(lock, 0.6)
         t0 = time.perf_counter()
         assert ckpt.save_checkpoint(
             1, _state_dict(), storage_type=StorageType.MEMORY

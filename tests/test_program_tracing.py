@@ -277,8 +277,8 @@ def test_ckpt_save_children_cover_the_save(tmp_path, event_log):
             and e["name"] != "ckpt.save.write"
         ]
         assert {e["name"] for e in called} == {
-            "ckpt.save.writer_wait", "ckpt.save.snapshot",
-            "ckpt.save.enqueue",
+            "ckpt.save.writer_wait", "ckpt.save.route",
+            "ckpt.save.snapshot", "ckpt.save.enqueue",
         }
         assert uncovered(root, called) <= slack(root)
         # on the writer thread: the write and everything beneath it
