@@ -4,6 +4,7 @@ strategy engine's dry-runner, and the benchmarks."""
 from dlrover_tpu.models.gpt import GPT, GPTConfig
 from dlrover_tpu.models.llama import Llama, LlamaConfig
 from dlrover_tpu.models.mimo_v2 import MiMoV2, MiMoV2Config
+from dlrover_tpu.models.motif import Motif, MotifConfig
 from dlrover_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from dlrover_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from dlrover_tpu.models.ouro import Ouro, OuroConfig
@@ -20,6 +21,8 @@ __all__ = [
     "LlamaConfig",
     "MiMoV2",
     "MiMoV2Config",
+    "Motif",
+    "MotifConfig",
     "NemotronH",
     "NemotronHConfig",
     "OlmoHybrid",

@@ -1,9 +1,12 @@
 """What the decoder families share: the norm, rope (also by a layer
 kind's :class:`RopeRule`, rotating part of a head), the dense helper,
-the SwiGLU, the causal depthwise convolution of the recurrent mixers,
-the one call that maps ``attention_impl`` to a function, and the
-remat rule.  A family file imports this module, ``losses``,
-``ops`` and ``parallel``, and no sibling; nothing here knows a family
+the SwiGLU and its PolyNorm form, the residual wrapper of a block
+whose token carries several streams (:class:`StreamCoefficients`,
+:func:`read_streams`, :func:`write_streams`), the causal depthwise
+convolution of the recurrent mixers, the one call that maps
+``attention_impl`` to a function, and the remat rule.  A family file
+imports this module, ``losses``, ``ops`` and ``parallel``, and no
+sibling; nothing here knows a family
 (layers take widths and dtypes, never a config object).
 
 Two SwiGLUs stay apart because their parameter names are a
@@ -32,6 +35,7 @@ from dlrover_tpu.ops.flash_attention import (
     SINK_SCOPE,
     flash_attention,
 )
+from dlrover_tpu.parallel.moe import polynorm_glu, polynorm_params
 from dlrover_tpu.parallel.mesh import (
     get_activation_constraint_mesh,
     get_global_mesh,
@@ -84,6 +88,218 @@ class SwiGLU(nn.Module):
         gate = proj(self.mlp_dim, "gate_proj")(x)
         up = proj(self.mlp_dim, "up_proj")(x)
         return proj(self.hidden_dim, "down_proj")(nn.silu(gate) * up)
+
+
+class PolyNormGLU(nn.Module):
+    """:class:`SwiGLU` with PolyNorm (arXiv:2411.03884) in silu's
+    place: ``down(P(gate(x)) * up(x))``, ``P``
+    ``ops/grouped_matmul.py::poly_norm`` over the module's whole width
+    with ONE learned ``polynorm_w [3]`` (1/3 each) and ``polynorm_b
+    []`` (0), float32 (``parallel/moe.py::polynorm_params``)."""
+
+    mlp_dim: int
+    hidden_dim: int
+    dtype: Any
+    param_dtype: Any
+    init_std: float
+    output_scale: float = 0.5
+    bias_clamp: float = 0.5
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        proj = partial(
+            dense, dtype=self.dtype, param_dtype=self.param_dtype,
+            init_std=self.init_std,
+        )
+        coeffs = polynorm_params(
+            self, "", self.output_scale, self.bias_clamp
+        )
+        hidden = polynorm_glu(
+            proj(self.mlp_dim, "gate_proj")(x),
+            proj(self.mlp_dim, "up_proj")(x), coeffs,
+        )
+        return proj(self.hidden_dim, "down_proj")(hidden)
+
+
+# -- a token of several residual streams --------------------------------------
+#
+# Manifold-constrained hyper-connections (arXiv:2512.24880 over
+# arXiv:2409.19606): a token's state is ``X [n, C]``, kept here as
+# ``[b, s, n * C]`` (stream ``i`` in lanes ``[i C, (i + 1) C)``: a
+# stream axis of 4 before the lanes would pad every tile four times).
+# Round ONE sub-layer ``F``: ``u = H_pre X``, ``y = F(norm(u))``, ``X'
+# = H_res X + H_post^T y``, the three mixes functions of the token's
+# own ``n C`` numbers, ``H_res`` doubly stochastic by Sinkhorn's
+# iterations.  The mixing is memory traffic and no matmul: a read of
+# ``X`` for ``u``, a read and a write for ``X'``.
+
+
+class StreamCoefficients(nn.Module):
+    """``(H_pre [n, b, s], H_post [n, b, s], H_res [n, n, b, s],
+    err)`` of ``x [b, s, n * C]``, float32, the token axes LAST (an
+    ``[.., n, n]`` array would be 16 lanes of 128 wide)::
+
+        [p | q | r] = RMSNorm(x) Phi        (one norm of all n C numbers,
+                                             no learned scale)
+        H_pre  = sigmoid(alpha_pre p + b_pre)
+        H_post = 2 sigmoid(alpha_post q + b_post)
+        M_0    = exp(alpha_res mat(r) + b_res); ``iters`` times: rows
+                 divided by their sums, then columns; H_res = M_iters
+
+    The norm's factor is a scalar a token, so it multiplies the
+    product (bf16 operands, float32 sums) and ``x`` is read once.
+    ``err`` is the worst ``|row or column sum - 1|`` of ``H_res`` (no
+    gradient).  Parameters: ``phi [n C, 2 n + n^2]``, ``alpha [3]``
+    (float32, 0.01 each: the mixes start near their biases) and
+    ``bias [2 n + n^2]`` (float32, zeros: ``H_pre`` 1/2, ``H_post``
+    1, ``H_res`` uniform ``1 / n``).  Device scopes ``mhc_coeff`` and
+    ``mhc_sinkhorn``."""
+
+    streams: int
+    iters: int
+    eps: float
+    dtype: Any
+    param_dtype: Any
+    init_std: float
+    alpha_init: float = 0.01
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        n = self.streams
+        b, s, _ = x.shape
+        phi = self.param(
+            "phi", nn.initializers.normal(self.init_std),
+            (x.shape[-1], 2 * n + n * n), self.param_dtype,
+        )
+        alpha = self.param(
+            "alpha", nn.initializers.constant(self.alpha_init), (3,),
+            jnp.float32,
+        )
+        bias = self.param(
+            "bias", nn.initializers.zeros, (2 * n + n * n,), jnp.float32
+        )
+        with device_scope("mhc_coeff"):
+            x32 = x.astype(jnp.float32)
+            inv_rms = jax.lax.rsqrt(
+                jnp.mean(x32 * x32, axis=-1) + self.eps
+            )
+            raw = jnp.moveaxis(jnp.einsum(
+                "bsk,kc->bsc", x.astype(self.dtype), phi.astype(self.dtype),
+                preferred_element_type=jnp.float32,
+            ), -1, 0) * inv_rms
+            # alpha_pre | alpha_post | alpha_res, a column of phi each
+            scale = alpha[np.repeat(np.arange(3), (n, n, n * n))]
+            raw = raw * scale[:, None, None] + bias[:, None, None]
+            h_pre = jax.nn.sigmoid(raw[:n])
+            h_post = 2.0 * jax.nn.sigmoid(raw[n:2 * n])
+        with device_scope("mhc_sinkhorn"):
+            m = jnp.exp(raw[2 * n:]).reshape(n, n, b, s)
+            for _ in range(self.iters):
+                m = m / m.sum(axis=1, keepdims=True)   # rows
+                m = m / m.sum(axis=0, keepdims=True)   # columns
+            done = jax.lax.stop_gradient(m)
+            err = jnp.maximum(
+                jnp.max(jnp.abs(done.sum(axis=1) - 1.0)),
+                jnp.max(jnp.abs(done.sum(axis=0) - 1.0)),
+            )
+        return h_pre, h_post, m, err
+
+
+def _streams(x, n):
+    # (split first: each consumer converts the lanes it reads, and no
+    # float32 copy of all n streams is asked for)
+    return [s.astype(jnp.float32) for s in jnp.split(x, n, axis=-1)]
+
+
+def _dots(a, streams):
+    """``[<a, stream>]`` over the lanes, float32 ``[len, b, s]``."""
+    return jnp.stack([jnp.sum(a * stream, axis=-1) for stream in streams])
+
+
+def sum_streams(x: jax.Array, n: int) -> jax.Array:
+    """The ``n`` streams of ``x [b, s, n * C]`` added up in float32:
+    ``[b, s, C]`` in ``x``'s type."""
+    return sum(_streams(x, n)).astype(x.dtype)
+
+
+@jax.custom_vjp
+def read_streams(x: jax.Array, h_pre: jax.Array) -> jax.Array:
+    """``u = H_pre X``: ``[b, s, C]`` from ``x [b, s, n * C]`` and
+    ``h_pre [n, b, s]`` float32, summed in float32, ``x``'s type out.
+    The gradient is written out (``dX_i = H_pre_i du``, ``dH_pre_i =
+    <du, X_i>``) so that each pass is one read of what it needs and
+    its results leave in ``x``'s type: left to autodiff the streams'
+    cotangents stand in float32, 0.5 GB each at 8192 x 16384.  Device
+    scope ``mhc_mix``, both ways."""
+    with device_scope("mhc_mix"):
+        return sum(
+            h[..., None] * stream
+            for h, stream in zip(h_pre, _streams(x, h_pre.shape[0]))
+        ).astype(x.dtype)
+
+
+def _read_fwd(x, h_pre):
+    return read_streams(x, h_pre), (x, h_pre)
+
+
+def _read_bwd(residuals, du):
+    x, h_pre = residuals
+    with device_scope("mhc_mix"):
+        du = du.astype(jnp.float32)
+        d_x = jnp.concatenate(
+            [(h[..., None] * du).astype(x.dtype) for h in h_pre], axis=-1
+        )
+        return d_x, _dots(du, _streams(x, h_pre.shape[0]))
+
+
+read_streams.defvjp(_read_fwd, _read_bwd)
+
+
+@jax.custom_vjp
+def write_streams(x, y, h_post, h_res) -> jax.Array:
+    """``X' = H_res X + H_post^T y``: ``[b, s, n * C]`` from the
+    streams ``x``, the sub-layer's output ``y [b, s, C]``, ``h_post
+    [n, b, s]`` and ``h_res [n, n, b, s]`` (float32); float32 inside,
+    ``x``'s type out.  The gradient is written out as
+    :func:`read_streams`': ``dX_j = sum_i H_res_ij dX'_i``, ``dy =
+    sum_i H_post_i dX'_i``, ``dH_res_ij = <dX'_i, X_j>``, ``dH_post_i
+    = <dX'_i, y>``.  Device scope ``mhc_mix``, both ways."""
+    with device_scope("mhc_mix"):
+        streams = _streams(x, h_post.shape[0])
+        y32 = y.astype(jnp.float32)
+        return jnp.concatenate([
+            (
+                sum(h[..., None] * stream for h, stream in zip(row, streams))
+                + post[..., None] * y32
+            ).astype(x.dtype)
+            for row, post in zip(h_res, h_post)
+        ], axis=-1)
+
+
+def _write_fwd(x, y, h_post, h_res):
+    return write_streams(x, y, h_post, h_res), (x, y, h_post, h_res)
+
+
+def _write_bwd(residuals, d_out):
+    x, y, h_post, h_res = residuals
+    n = h_post.shape[0]
+    with device_scope("mhc_mix"):
+        d_streams = _streams(d_out, n)
+        streams = _streams(x, n)
+        d_x = jnp.concatenate([
+            sum(
+                h_res[i, j][..., None] * d_streams[i] for i in range(n)
+            ).astype(x.dtype) for j in range(n)
+        ], axis=-1)
+        d_y = sum(
+            post[..., None] * d for post, d in zip(h_post, d_streams)
+        ).astype(y.dtype)
+        d_post = _dots(y.astype(jnp.float32), d_streams)
+        d_res = jnp.stack([_dots(d, streams) for d in d_streams])
+        return d_x, d_y, d_post, d_res
+
+
+write_streams.defvjp(_write_fwd, _write_bwd)
 
 
 def conv_init(key, shape, dtype):
@@ -320,7 +536,10 @@ def window_tiles_share(seq: int, window: int, itemsize: int = 2) -> float:
 
 
 def remat_policy(name: str):
-    """What a rematted block keeps, for every decoder family: the
+    """What a rematted block keeps, for every decoder family: its
+    input (``jax.checkpoint``'s own: ``[b, s, h]``, or ``[b, s, n h]``
+    where a token carries ``n`` residual streams, so ``n`` times the
+    bytes a block boundary) and the
     five arrays its flash kernel's backward kernels read (``q``, ``k``
     and ``v`` as the kernel took them, ``out`` and ``lse`` as it wrote
     them: all in HBM for the forward already), by the names the
@@ -353,7 +572,10 @@ def remat_policy(name: str):
 
 def rematted(block, prevent_cse: bool, policy: str = "full"):
     """``block`` (a module class) under ``jax.checkpoint`` with the one
-    rule above."""
+    rule above.  Kept a block: its input, ``b x s x n x h`` values
+    for ``n`` residual streams (1 in every family but ``motif``'s 4:
+    268 MB a boundary at 8192 x 4096 in bf16 against 67), and the
+    flash kernel's five arrays."""
     return nn.remat(
         block, prevent_cse=prevent_cse, policy=remat_policy(policy)
     )

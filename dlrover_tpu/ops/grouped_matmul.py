@@ -77,6 +77,8 @@ from dlrover_tpu.ops import flash_attention as _flash
 ROW_TILE = 256
 K_TILE = 4096
 N_TILE = 2048
+# a row tile's sums leave their kernel as one float32 tile
+_SUMS_BLOCK = (8, 128)
 
 
 def _interpret() -> bool:
@@ -158,16 +160,20 @@ def _gmm_kernel(
                                 # outs x [row_tile, tn],
                                 # acc [products, row_tile, tn] f32 where k
                                 # is split
-    lhs: int, rhs: int, beside: int, k_steps: int, transpose_rhs: bool,
-    finish,
+    lhs: int, rhs: int, beside: int, coeffs: int, tile_sums: int,
+    k_steps: int, transpose_rhs: bool, finish,
 ):
     row, step = pl.program_id(1), pl.program_id(2)
     contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
     lhs_refs, refs = refs[:lhs], refs[lhs:]
     rhs_refs, refs = refs[:rhs], refs[rhs:]
-    beside_refs, out_refs = refs[:beside], refs[beside:]
+    beside_refs, refs = refs[:beside], refs[beside:]
+    # an epilogue's learned scalars, whole in SMEM
+    coeff_refs, out_refs = refs[:bool(coeffs)], refs[bool(coeffs):]
     if k_steps > 1:
         out_refs, acc_ref = out_refs[:-1], out_refs[-1]
+    if tile_sums:
+        out_refs, sums_ref = out_refs[:-1], out_refs[-1]
 
     # a tile of no group holds the last used tile's blocks (_gmm):
     # an ``out_ref`` is that tile's result, not yet written back, and
@@ -191,10 +197,21 @@ def _gmm_kernel(
 
         def store(sums):
             values = finish(
-                sums, [ref[...].astype(jnp.float32) for ref in beside_refs]
+                sums, [ref[...].astype(jnp.float32) for ref in beside_refs],
+                *([ref[at] for at in range(coeffs)] for ref in coeff_refs),
             )
+            values, totals = values[:len(out_refs)], values[len(out_refs):]
             for ref, value in zip(out_refs, values, strict=True):
                 ref[...] = value.astype(ref.dtype)
+            if tile_sums:
+                # this tile's ``[1, 1]`` sums, one a lane of its block
+                lane = jax.lax.broadcasted_iota(
+                    jnp.int32, sums_ref.shape[1:], 1
+                )
+                block = jnp.zeros(sums_ref.shape[1:], jnp.float32)
+                for at, total in zip(range(tile_sums), totals, strict=True):
+                    block = jnp.where(lane == at, total, block)
+                sums_ref[0] = block
 
         if k_steps == 1:
             store(parts)
@@ -215,14 +232,21 @@ def _gmm_kernel(
             store([acc_ref[at] for at in range(len(parts))])
 
 
-def _fit_tile(dim: int, tile: int) -> int:
+def _fit_tile(dim: int, tile: int, whole: bool = False) -> int:
     """A dim's tile: the dim whole up to ``tile``, else its largest
     divisor that is a whole number of lanes and at most ``tile`` (a
     hidden size of 3072 under a tile of 2048 goes in halves of 1536;
     2048 and 4096 take the tile itself).  Where there is none the
-    tile, which the caller's check then refuses."""
+    tile, which the caller's check then refuses.  ``whole``: the
+    epilogue reduces over a row's whole width, so the dim is ONE block
+    or the call is refused (a norm over a part is another function)."""
     if dim <= tile:
         return dim
+    if whole:
+        raise ValueError(
+            f"a width of {dim} does not fit one block of {tile}: the "
+            "epilogue needs a row's whole width"
+        )
     for parts in range(-(-dim // tile), dim // 128 + 1):
         if dim % parts == 0 and (dim // parts) % 128 == 0:
             return dim // parts
@@ -232,6 +256,7 @@ def _fit_tile(dim: int, tile: int) -> int:
 def _gmm(
     lhs, rhs, tile_group, tiles_used, *, name, tiles, transpose_rhs=False,
     beside=(), outs=1, over=0, finish=lambda sums, beside: sums,
+    whole_n=False, coeffs=None, tile_sums=0,
 ):
     """One walk over the row tiles: ``lhs`` (each ``[m, k]``) against
     their groups' blocks of ``rhs`` (each ``[groups, k, n]``,
@@ -243,11 +268,20 @@ def _gmm(
     ``beside [m, n]`` arrays' own tiles among them) makes what is
     written; the first ``over`` outs are written over the ``beside``
     of their index (a tile's block of each is read before it is
-    written, and the caller reads that ``beside`` nowhere later)."""
+    written, and the caller reads that ``beside`` nowhere later).
+
+    An epilogue that is not element-wise says so: ``whole_n`` (it
+    reduces over a row's whole width: ``n`` is one block, or the call
+    is refused), ``coeffs`` (a float32 vector of learned scalars,
+    handed to ``finish`` as a third argument) and ``tile_sums`` (that
+    many ``[1, 1]`` sums over a tile's rows follow the ``outs`` values
+    in what ``finish`` returns; they come back as one more result,
+    ``[tiles, 8, 128]`` float32, a sum a lane of sublane 0, NOT
+    WRITTEN for the tiles of no group as every result)."""
     row_tile, k_tile, n_tile = tiles
     m, k = lhs[0].shape
     n = rhs[0].shape[1] if transpose_rhs else rhs[0].shape[2]
-    tk, tn = _fit_tile(k, k_tile), _fit_tile(n, n_tile)
+    tk, tn = _fit_tile(k, k_tile), _fit_tile(n, n_tile, whole=whole_n)
     if m % row_tile or k % tk or n % tn:
         raise ValueError(
             f"rows {lhs[0].shape} x weights {rhs[0].shape} do not "
@@ -282,10 +316,19 @@ def _gmm(
     out_spec = pl.BlockSpec(
         (row_tile, tn), lambda j, i, s, tg, nu: (_held_tile(i, nu), j)
     )
+    # (only with ``whole_n``: one column block, so one writer a tile)
+    sums_spec = pl.BlockSpec(
+        (1,) + _SUMS_BLOCK,
+        lambda j, i, s, tg, nu: (_held_tile(i, nu), 0, 0),
+    )
+    if tile_sums and not whole_n:
+        raise ValueError("a tile's sums need the row's whole width")
     return pl.pallas_call(
         functools.partial(
             _gmm_kernel, lhs=len(lhs), rhs=len(rhs), beside=len(beside),
-            k_steps=k_steps, transpose_rhs=transpose_rhs, finish=finish,
+            coeffs=0 if coeffs is None else coeffs.shape[0],
+            tile_sums=tile_sums, k_steps=k_steps,
+            transpose_rhs=transpose_rhs, finish=finish,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -295,13 +338,17 @@ def _gmm(
             in_specs=(
                 [lhs_spec] * len(lhs) + [rhs_spec] * len(rhs)
                 + [out_spec] * len(beside)
+                + [pl.BlockSpec(memory_space=pltpu.SMEM)]
+                * (coeffs is not None)
             ),
-            out_specs=[out_spec] * outs,
+            out_specs=[out_spec] * outs + [sums_spec] * bool(tile_sums),
             scratch_shapes=[
                 pltpu.VMEM((products, row_tile, tn), jnp.float32)
             ] if k_steps > 1 else [],
         ),
-        out_shape=[jax.ShapeDtypeStruct((m, n), dtype)] * outs,
+        out_shape=[jax.ShapeDtypeStruct((m, n), dtype)] * outs + [
+            jax.ShapeDtypeStruct((m // row_tile,) + _SUMS_BLOCK, jnp.float32)
+        ] * bool(tile_sums),
         # (the scalars are operands 0 and 1)
         input_output_aliases={
             2 + len(lhs) + len(rhs) + at: at for at in range(over)
@@ -322,7 +369,10 @@ def _gmm(
         ),
         interpret=_interpret(),
         name=name,
-    )(tile_group, tiles_used, *lhs, *rhs, *beside)
+    )(
+        tile_group, tiles_used, *lhs, *rhs, *beside,
+        *(() if coeffs is None else (coeffs,)),
+    )
 
 
 # -- the gradient to the weights ----------------------------------------------------
@@ -492,48 +542,128 @@ def _d_activation(d_hidden, kept):
     ]
 
 
-def _up(tiles, tile_group, tiles_used, rows, *weights, keep):
+POLYNORM_EPS = 1e-6
+
+
+def _poly_parts(z, eps=POLYNORM_EPS):
+    """``[(N(z^3), r_3), (N(z^2), r_2), (N(z), r_1)]`` of ``z [rows,
+    width]`` float32: ``N(a) = a r``, ``r = (mean over the WIDTH of
+    a^2 + eps)^-1/2``."""
+    z2 = z * z
+    parts = []
+    for power in (z2 * z, z2, z):
+        r = jax.lax.rsqrt(
+            jnp.mean(power * power, axis=-1, keepdims=True) + eps
+        )
+        parts.append((power * r, r))
+    return parts
+
+
+def poly_norm(z, coeffs, eps=POLYNORM_EPS):
+    """PolyNorm (arXiv:2411.03884) of ``z [rows, width]`` float32:
+    ``c_3 N(z^3) + c_2 N(z^2) + c_1 N(z) + bias`` for ``coeffs = (c_3,
+    c_2, c_1, bias)``, four scalars (the caller's: a learned weight
+    times its output scale, a clamped bias).  Each norm is over a
+    row's WHOLE width.  A row of zeros gives ``bias``."""
+    return _poly_value(_poly_parts(z, eps), coeffs)
+
+
+def _poly_value(parts, coeffs):
+    (n3, _), (n2, _), (n1, _) = parts
+    c3, c2, c1, bias = coeffs
+    return c3 * n3 + c2 * n2 + c1 * n1 + bias
+
+
+def _tile_sum(x):
+    return jnp.sum(
+        jnp.sum(x, axis=0, keepdims=True), axis=1, keepdims=True
+    )
+
+
+def _d_poly(d_hidden, kept, coeffs):
+    """``[d gate, d up]`` from the hidden rows' gradient and the kept
+    ``[gate, up]``, then the four sums over this tile's rows that are
+    the coefficients' gradients: ``sum(g N(z^3)), sum(g N(z^2)),
+    sum(g N(z)), sum(g)``, ``g = d hidden x up`` the gradient to
+    ``poly_norm``'s value.  Through a norm: ``d/dz sum(g N_i) = i
+    z^(i-1) r_i (g - N_i mean(g N_i))``."""
+    (d_hidden,) = d_hidden
+    gate, up = kept
+    c3, c2, c1, _ = coeffs
+    parts = _poly_parts(gate)
+    (n3, r3), (n2, r2), (n1, r1) = parts
+    g = d_hidden * up
+
+    def through(n, r):
+        return r * (g - n * jnp.mean(g * n, axis=-1, keepdims=True))
+
+    d_gate = (
+        c3 * 3.0 * gate * gate * through(n3, r3)
+        + c2 * 2.0 * gate * through(n2, r2) + c1 * through(n1, r1)
+    )
+    d_up = d_hidden * _poly_value(parts, coeffs)
+    return [d_gate, d_up] + [_tile_sum(g * n) for n in (n3, n2, n1)] + [
+        _tile_sum(g)
+    ]
+
+
+def _up(tiles, tile_group, tiles_used, rows, *weights, keep, coeffs=None):
     """``[hidden, *a gate's two products with keep]`` of ``rows``
     through ``weights = (gate, up) | (up,)``.  The blocks of one grid
     step are together as wide as one product's: at hidden 4096 x
     width 2048 two double-buffered blocks of 16 MB would leave VMEM no
-    room beside them."""
+    room beside them.  With ``coeffs`` the activation is
+    :func:`poly_norm`, which needs a row's whole width: the two blocks
+    are as wide as the products, and a width past one tile is
+    refused."""
     row_tile, k_tile, n_tile = tiles
+    poly = coeffs is not None
+
+    def finish(pre, _, *c):
+        hidden = poly_norm(pre[0], *c) * pre[1] if poly else _hidden(pre)
+        return [hidden] + (pre if keep else [])
+
     return _gmm(
         [rows], weights, tile_group, tiles_used, name="gmm_up_fwd",
-        tiles=(row_tile, k_tile, n_tile // len(weights)),
-        outs=3 if keep else 1,
-        finish=lambda pre, _: [_hidden(pre)] + (pre if keep else []),
+        tiles=tiles if poly else (row_tile, k_tile, n_tile // len(weights)),
+        outs=3 if keep else 1, finish=finish, whole_n=poly, coeffs=coeffs,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _grouped_expert(
-    rows, w_gate, w_up, w_down, tile_group, tiles_used, tiles
+    rows, w_gate, w_up, w_down, coeffs, tile_group, tiles_used, tiles
 ):
     # (a program that asks for no gradient: nothing is kept)
     weights = [w_up] if w_gate is None else [w_gate, w_up]
-    (hidden,) = _up(tiles, tile_group, tiles_used, rows, *weights, keep=False)
+    (hidden,) = _up(
+        tiles, tile_group, tiles_used, rows, *weights, keep=False,
+        coeffs=coeffs,
+    )
     return _grouped_matmul(hidden, w_down, tile_group, tiles_used, tiles)
 
 
-def _expert_fwd(rows, w_gate, w_up, w_down, tile_group, tiles_used, tiles):
+def _expert_fwd(
+    rows, w_gate, w_up, w_down, coeffs, tile_group, tiles_used, tiles
+):
     weights = [w_up] if w_gate is None else [w_gate, w_up]
     # kept: what the derivative reads.  A gate's two products, or
     # without a gate the hidden rows themselves
     hidden, *kept = _up(
         tiles, tile_group, tiles_used, rows, *weights,
-        keep=w_gate is not None,
+        keep=w_gate is not None, coeffs=coeffs,
     )
     out = _grouped_matmul(hidden, w_down, tile_group, tiles_used, tiles)
     return out, (
-        rows, weights, w_down, hidden, kept or [hidden], tile_group,
-        tiles_used,
+        rows, weights, w_down, hidden, kept or [hidden], coeffs,
+        tile_group, tiles_used,
     )
 
 
 def _expert_bwd(tiles, residuals, d_out):
-    rows, weights, w_down, hidden, kept, tile_group, tiles_used = residuals
+    (
+        rows, weights, w_down, hidden, kept, coeffs, tile_group, tiles_used,
+    ) = residuals
     d_out = d_out.astype(rows.dtype)
     gated = len(weights) > 1
 
@@ -548,11 +678,25 @@ def _expert_bwd(tiles, residuals, d_out):
     # over its two products, which nothing else reads (a step of the
     # backward holds two arrays of the padded rows fewer); the hidden
     # rows are still the down matrix's gradient's to read
+    poly = coeffs is not None
     d_pre = _gmm(
         [d_out], [w_down], tile_group, tiles_used, name="gmm_down_dlhs",
         transpose_rhs=True, tiles=tiles, beside=kept, outs=len(weights),
-        over=2 if gated else 0, finish=_d_activation,
+        over=2 if gated else 0, finish=_d_poly if poly else _d_activation,
+        whole_n=poly, coeffs=coeffs,
+        tile_sums=coeffs.shape[0] if poly else 0,
     )
+    d_coeffs = None
+    if poly:
+        # poly_norm's derivative needs the row means again, and the
+        # coefficients' gradients are sums over EVERY row of the
+        # layer: a tile hands back its own, and the tiles of a group
+        # (the tiles of no group are not written) add up here
+        *d_pre, sums = d_pre
+        used = jnp.arange(sums.shape[0]) < tiles_used[0]
+        d_coeffs = jnp.sum(jnp.where(
+            used[:, None], sums[:, 0, :coeffs.shape[0]], 0.0
+        ), axis=0)
     # ONE gradient to the rows: both products in one accumulator
     (d_rows,) = _gmm(
         d_pre, weights, tile_group, tiles_used,
@@ -565,7 +709,7 @@ def _expert_bwd(tiles, residuals, d_out):
         *([None] * (2 - len(weights))),
         *(d_weights(rows, d, w) for d, w in zip(d_pre, weights)),
         d_weights(hidden, d_out, w_down),
-        None, None,
+        d_coeffs, None, None,
     )
 
 
@@ -580,6 +724,7 @@ def grouped_expert(
     tile_group: jax.Array,   # [tiles] int32   } of group_layout
     tiles_used: jax.Array,   # [1] int32       }
     tiles=(ROW_TILE, K_TILE, N_TILE),
+    coeffs=None,             # [4] float32: poly_norm's, for silu
 ) -> jax.Array:
     """Each row through its group's expert -> ``[rows, k]``: ``(silu(x
     @ w_gate[g]) * (x @ w_up[g])) @ w_down[g]`` or, with
@@ -597,9 +742,20 @@ def grouped_expert(
     group's padding rows are zero in and zero out (``silu(0) * 0 =
     relu(0) ** 2 = 0``), and the rows of the tiles from
     ``tiles_used`` on are NOT READ and NOT WRITTEN, in the result, in
-    what the forward keeps and in every gradient."""
+    what the forward keeps and in every gradient.
+
+    With ``coeffs`` (differentiable) the gate's activation is
+    :func:`poly_norm` in silu's place, the third form and the first
+    that is not element-wise: each norm is over the expert's whole
+    width, so ``n`` is ONE column block of ``gmm_up_fwd`` and
+    ``gmm_down_dlhs`` (a wider expert is refused), the derivative
+    takes the row means again, and the coefficients' gradients, sums
+    over every row of the layer, leave ``gmm_down_dlhs`` a tile at a
+    time.  A padding row is still zero out: ``poly_norm(0) * 0``."""
+    if coeffs is not None and w_gate is None:
+        raise ValueError("poly_norm is a gate's activation: no gate")
     return _grouped_expert(
-        rows, w_gate, w_up, w_down, tile_group, tiles_used, tiles
+        rows, w_gate, w_up, w_down, coeffs, tile_group, tiles_used, tiles
     )
 
 

@@ -277,6 +277,46 @@ def relu2(x: jax.Array) -> jax.Array:
     return jnp.square(nn.relu(x))
 
 
+EXPERT_FORMS = ("swiglu", "relu2", "polynorm")
+
+
+def polynorm_coeffs(weight, bias, scale: float, clamp: float):
+    """``gmm.poly_norm``'s four scalars from a module's learned
+    ``weight [3]`` (cubic, square, linear) and ``bias []``: ``scale x
+    weight`` and the bias held to ``+-clamp``, float32."""
+    return jnp.concatenate([
+        scale * weight.astype(jnp.float32),
+        jnp.clip(bias.astype(jnp.float32), -clamp, clamp).reshape(1),
+    ])
+
+
+def polynorm_params(module, prefix: str, scale: float, clamp: float):
+    """:func:`polynorm_coeffs` of a flax module's own learned pair,
+    created here: ``<prefix>polynorm_w [3]`` (1/3 each) and
+    ``<prefix>polynorm_b []`` (0), float32."""
+    return polynorm_coeffs(
+        module.param(
+            prefix + "polynorm_w", nn.initializers.constant(1.0 / 3.0),
+            (3,), jnp.float32,
+        ),
+        module.param(
+            prefix + "polynorm_b", nn.initializers.zeros, (), jnp.float32
+        ),
+        scale, clamp,
+    )
+
+
+def polynorm_glu(gate, up, coeffs):
+    """``poly_norm(gate) * up`` outside the kernels (a dense
+    feed-forward, a shared expert), ``[..., width]``: float32 inside,
+    ``up``'s type out, under the device scope ``polynorm``."""
+    with device_scope("polynorm"):
+        return (
+            gmm.poly_norm(gate.astype(jnp.float32), coeffs)
+            * up.astype(jnp.float32)
+        ).astype(up.dtype)
+
+
 def dropless_moe(
     tokens: jax.Array,         # [t, d]
     router_kernel: jax.Array,  # [d, e]
@@ -291,6 +331,7 @@ def dropless_moe(
     select_bias: Optional[jax.Array] = None,  # [e], no gradient
     renormalise: bool = False,
     scale: float = 1.0,
+    polynorm: Optional[jax.Array] = None,  # [4] float32
 ):
     """Top-k routing without capacity: ``(out [t, d], stats)``.
 
@@ -304,8 +345,11 @@ def dropless_moe(
     by their sum, ``scale`` multiplies them.  An expert computes
     ``down(silu(gate(x)) * up(x))`` or, with ``w_gate=None``,
     ``down(relu(up(x)) ** 2)``: an expert that has no gate has no
-    gate matrix.  Either way it is one call, ``gmm.grouped_expert``:
-    the matmuls AND the activation between them, in the kernels.
+    gate matrix; with ``polynorm`` (:func:`polynorm_coeffs`) the
+    gate's activation is ``gmm.poly_norm`` in silu's place, each norm
+    over the expert's own width.  Every form is one call,
+    ``gmm.grouped_expert``: the matmuls AND the activation between
+    them, in the kernels.
 
     **Held experts.**  ``held=(lo, count)`` says that this chip holds
     experts ``[lo, lo + count)`` of the layer's ``e`` (the weights are
@@ -443,10 +487,11 @@ def dropless_moe(
         # the kernels' walks over the used tiles, the activation and
         # its derivative inside them: nothing here passes over the
         # padded rows
+        # (the two older forms call it as they always did)
         rows = gmm.grouped_expert(
             rows, None if w_gate is None else w_gate.astype(dtype),
             w_up.astype(dtype), w_down.astype(dtype), tile_group,
-            tiles_used,
+            tiles_used, **({} if polynorm is None else {"coeffs": polynorm}),
         )
     with device_scope("moe_combine"):
         if held is None:
@@ -473,7 +518,14 @@ class DroplessMoE(nn.Module):
     device scope ``moe_shared``.  ``expert_form="relu2"`` is a layer
     of experts WITHOUT a gate matrix, the shared one too
     (``down(relu(up(x)) ** 2)``): the tree then has no
-    ``experts_w_gate`` and no ``shared_gate``."""
+    ``experts_w_gate`` and no ``shared_gate``.
+    ``expert_form="polynorm"`` keeps the gate and puts PolyNorm in
+    silu's place (:func:`polynorm_coeffs` with ``polynorm_scale`` and
+    ``polynorm_clamp``): ONE learned ``experts_polynorm_w [3]`` /
+    ``experts_polynorm_b []`` (float32) for the layer's routed experts,
+    whose gradients sum over every row of the layer, and one more,
+    ``shared_polynorm_w`` / ``shared_polynorm_b``, for the shared
+    expert."""
 
     num_experts: int
     mlp_dim: int
@@ -487,16 +539,29 @@ class DroplessMoE(nn.Module):
     renormalise: bool = False
     scale: float = 1.0
     shared_dim: int = 0
-    expert_form: str = "swiglu"  # | "relu2"
+    expert_form: str = "swiglu"  # | "relu2" | "polynorm"
+    polynorm_scale: float = 0.5
+    polynorm_clamp: float = 0.5
 
     @nn.compact
     def __call__(self, x: jax.Array):
         b, s, d = x.shape
         e, m = self.num_experts, self.mlp_dim
         count = e if self.held is None else self.held[1]
-        if self.expert_form not in ("swiglu", "relu2"):
-            raise ValueError(f"no expert form {self.expert_form!r}")
-        gated = self.expert_form == "swiglu"
+        if self.expert_form not in EXPERT_FORMS:
+            raise ValueError(
+                f"no expert form {self.expert_form!r} "
+                f"({' | '.join(EXPERT_FORMS)})"
+            )
+        gated = self.expert_form != "relu2"
+
+        def polynorm(prefix):
+            if self.expert_form != "polynorm":
+                return None
+            return polynorm_params(
+                self, prefix, self.polynorm_scale, self.polynorm_clamp
+            )
+
         router = self.param(
             "router", self.kernel_init, (d, e), self.param_dtype
         )
@@ -521,7 +586,7 @@ class DroplessMoE(nn.Module):
             x.reshape(b * s, d), router, w_gate, w_up, w_down,
             self.top_k, self.dtype, held=self.held, score=self.score,
             select_bias=bias, renormalise=self.renormalise,
-            scale=self.scale,
+            scale=self.scale, polynorm=polynorm("experts_"),
         )
         out = out.reshape(b, s, d)
         if self.shared_dim:
@@ -533,7 +598,13 @@ class DroplessMoE(nn.Module):
                 )
 
             with device_scope("moe_shared"):
-                if gated:
+                if self.expert_form == "polynorm":
+                    hidden = polynorm_glu(
+                        dense(self.shared_dim, "shared_gate")(x),
+                        dense(self.shared_dim, "shared_up")(x),
+                        polynorm("shared_"),
+                    )
+                elif gated:
                     hidden = nn.silu(
                         dense(self.shared_dim, "shared_gate")(x)
                     ) * dense(self.shared_dim, "shared_up")(x)

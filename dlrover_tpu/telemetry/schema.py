@@ -110,6 +110,12 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # (models/mimo_v2.py): the share of a row's softmax the sink
         # takes, mean over the sinked layers, heads and rows, and the
         # largest |sink|
+        # mhc.* / gdla.* / mtp.*: a model of several residual streams
+        # and differential attention (models/motif.py): the worst
+        # |row or column sum - 1| of the streams' mixing matrices
+        # after Sinkhorn's iterations; the mean lambda and the mean
+        # |lambda A_noise| over the mean |A_signal|; the prediction
+        # layer's own cross entropy
         # loop.*: a looped model's exits (models/ouro.py): the mean
         # exit a token is expected to leave at, the exit
         # distribution's mean entropy, and the first and the last
@@ -125,7 +131,9 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
             "attn.window_tiles_share", "attn.sink_mass_mean",
             "attn.sink_abs_max", "loop.expected_exit",
             "loop.exit_entropy", "loop.nll_first", "loop.nll_last",
-            "ssm.state_rms_max", "ssm.decay_mean"]),
+            "ssm.state_rms_max", "ssm.decay_mean",
+            "mhc.res_sum_err_max", "gdla.lambda_mean",
+            "gdla.noise_share", "mtp.loss"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

@@ -645,12 +645,15 @@ class ElasticTrainer:
             # a model's own counters (``has_aux`` of make_train_step:
             # a sparse model's routing, a linear-attention model's
             # state, a windowed model's walk, a looped model's exits,
-            # a state-space model's states and decays) ride on this
+            # a state-space model's states and decays, a
+            # hyper-connected model's streams, differential attention
+            # and prediction layer) ride on this
             # event: one more event would cost the loop
             # 0.65 ms a step (PERF.md, PR 25)
-            if name.startswith(
-                ("moe.", "gdn.", "attn.", "loop.", "ssm.")
-            ):
+            if name.startswith((
+                "moe.", "gdn.", "attn.", "loop.", "ssm.", "mhc.", "gdla.",
+                "mtp.",
+            )):
                 step_event[name] = float(value)
         emit_event("train_step", **step_event)
         # chaos hook AFTER the event: a kill rule at step N must leave
