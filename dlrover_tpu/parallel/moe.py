@@ -26,6 +26,7 @@ ragged all-to-all (ROADMAP R3), which is not here; the
 ``expert``-mesh path stays with :class:`MoEMLP`.
 """
 
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -273,6 +274,66 @@ def _collect_bwd(source, g):
 _collect_rows.defvjp(_collect_fwd, _collect_bwd)
 
 
+def _layout_by_sorting(expert_ids, group_sizes, padded_starts, padded_rows):
+    """``(source [padded rows], slot [t, k])`` of :func:`_dispatch_rows`
+    where every expert is held: the ``t * k`` assignments
+    stable-sorted by expert, each expert's rows from its padded
+    start."""
+    flat_ids = expert_ids.reshape(-1)
+    assignments = flat_ids.shape[0]
+    order = jnp.argsort(flat_ids, stable=True).astype(jnp.int32)
+    sorted_ids = flat_ids[order]
+    starts = jnp.cumsum(group_sizes) - group_sizes
+    # the padded row of the assignment at sorted position i
+    row = (
+        padded_starts[sorted_ids] - starts[sorted_ids]
+        + jnp.arange(assignments, dtype=jnp.int32)
+    )
+    slot = jnp.zeros_like(order).at[order].set(
+        row, unique_indices=True
+    ).reshape(expert_ids.shape)
+    source = jnp.full((padded_rows,), assignments, jnp.int32).at[row].set(
+        order, unique_indices=True
+    )
+    return source, slot
+
+
+def _is_expert(expert_ids, experts):
+    # ``[t, k, len(experts)]``: choice c of token t is that expert.
+    # Never an array: a compare that fuses into the sum that reads it
+    return expert_ids[..., None] == experts.astype(expert_ids.dtype)
+
+
+def _assignments_of(expert_ids, e: int):
+    """``[e]`` int32, how many of the ``t x k`` assignments each expert
+    got: ``bincount``'s numbers as a compare with every expert and an
+    integer sum (a scatter-add takes a TPU 7-10 ns an assignment)."""
+    return jnp.sum(
+        _is_expert(expert_ids, jnp.arange(e)), axis=(0, 1), dtype=jnp.int32
+    )
+
+
+def _scores_of(probs, expert_ids):
+    """``probs[t, expert_ids[t, c]]``, ``[t, k]``, as a sum over the
+    experts with ONE term that is not zero, so exact; its gradient is
+    a sum over a token's k choices, distinct experts, with at most one
+    (where ``take_along_axis`` gathers ``t x k`` scalars one by one
+    and its transpose fills ``[t, e]`` with zeros and scatters)."""
+    scores = jnp.sum(
+        jnp.where(
+            _is_expert(expert_ids, jnp.arange(probs.shape[-1])),
+            probs[:, None, :], 0.0,
+        ),
+        axis=-1,
+    )
+    # an array of its own, as a gather's result is: a sum over the k
+    # choices that reads it (``renormalise``) adds them in their order,
+    # where the compiler would merge the two sums into one over ``[k,
+    # e]`` that adds them in the experts' (the last bits of every
+    # weight, and with them of the step's loss, would move)
+    return jax.lax.optimization_barrier(scores)
+
+
 def relu2(x: jax.Array) -> jax.Array:
     return jnp.square(nn.relu(x))
 
@@ -361,33 +422,45 @@ def dropless_moe(
     all k choices, held or not, so the shares of all the chips that
     hold a layer add up to the whole layer.
 
-    The ``t * k`` assignments are stable-sorted by expert (those to
-    experts held elsewhere behind the rest), the rows gathered in that
-    order with each expert's rows starting on a row tile of the
-    grouped-matmul kernel (``ops/grouped_matmul.py``), and each expert
-    computes its own rows as grouped matmuls.  Every shape is static
-    (``t * k`` rows and one tile of padding an expert held, whatever
-    the routing: a batch may send every assignment here); an expert
-    without a token is one tile
-    of zero rows, and the kernels skip the tiles past the last used
-    one: no product, no fetch, no store, so those rows of each
-    matmul's result are NOT WRITTEN, forward or backward.  Nothing
-    here reads them: the activation and its derivative run inside the
-    kernels, over the used tiles, and the gathers back go through
-    ``slot``, which names only rows of an expert (a reduction over
-    the padded rows would: ``tests/test_sarvam_mla.py`` fills them
-    with NaN).
+    The ``t * k`` assignments lie in the order of a stable sort by
+    expert, an expert's rows in token order, each expert's rows
+    starting on a row tile of the grouped-matmul kernel
+    (``ops/grouped_matmul.py``), and each expert computes its own
+    rows as grouped matmuls.  Every shape is static (``t * k`` rows
+    and one tile of padding an expert held, whatever the routing: a
+    batch may send every assignment here); an expert without a token
+    is one tile of zero rows, and the kernels skip the tiles past the
+    last used one: no product, no fetch, no store, so those rows of
+    each matmul's result are NOT WRITTEN, forward or backward.
+    Nothing here reads them: the activation and its derivative run
+    inside the kernels, over the used tiles, and the ways back go
+    through ``slot`` or ``token_of_row``, which name only rows of an
+    expert (a reduction over the padded rows would:
+    ``tests/test_sarvam_mla.py`` fills them with NaN).
 
-    **What runs at which size.**  The router and the index work (the
-    sort, ``slot``, ``source``: ``[t * k]`` and ``[padded rows]``
-    int32) run at the static size.  With every expert held, so do the
-    two row gathers and the weighting, over ``[t, k, d]``: nearly
-    every tile has rows.  With a held range most tiles have none, and
-    the rows move from the row side (below the layer in this file):
-    dispatch and combine walk the ``tiles_used`` tiles that hold a
-    row, forward and backward; the token side keeps ``[t, d]`` arrays
-    and nothing of ``[t, k, d]`` is made.  Their ``[padded rows, d]``
-    results are, as the kernels', NOT WRITTEN past ``tiles_used``.
+    **What runs at which size.**  The router runs at the static size:
+    the scores, the top-k, the chosen scores as a masked sum over the
+    experts (:func:`_scores_of`: no gather, and no scatter-add for its
+    gradient) and ``counts`` as a compare with every expert and an
+    integer sum (:func:`_assignments_of`: no ``bincount``); neither
+    makes an array of ``[t, k, e]``.  With every expert held, so do
+    the index work (the sort, ``slot [t, k]`` and ``source [padded
+    rows]`` by two scatters), the two row gathers and the weighting,
+    over ``[t, k, d]``: nearly every tile has rows.  With a held range
+    most tiles have none.  At the static size there are then only
+    vector passes over ``[count, k, t]`` masks and ``[count, t]``
+    prefix sums and weights (:func:`_held_choices`: no sort, no
+    scatter, no gather); everything on the row side walks the
+    ``tiles_used`` tiles that hold a row, forward and backward (below
+    the layer in this file): which token a row holds and at what
+    weight, that weight's gradient back to its token, dispatch and
+    combine; the token side keeps ``[t, d]`` arrays and nothing of
+    ``[t, k, d]`` is made.  The ``[padded rows, d]`` results are, as
+    the kernels', NOT WRITTEN past ``tiles_used``.  The arrays are
+    the sort's to the bit at every routing
+    (``tests/test_moe_held_index.py``), so the two sides differ in
+    cost alone: which one runs is ``held is not None``, a fact of the
+    input.
 
     ``stats`` carries what the auxiliary losses and the counters
     need: ``counts [e]`` (assignments per expert over ALL experts, no
@@ -416,19 +489,17 @@ def dropless_moe(
             probs = jax.nn.sigmoid(logits)
         else:
             raise ValueError(f"unknown router score {score!r}")
-        if select_bias is None:
-            gate, expert_ids = jax.lax.top_k(probs, top_k)  # [t, k]
-        else:
-            _, expert_ids = jax.lax.top_k(
-                probs + jax.lax.stop_gradient(select_bias), top_k
-            )
-            gate = jnp.take_along_axis(probs, expert_ids, axis=-1)
+        _, expert_ids = jax.lax.top_k(  # [t, k]
+            probs if select_bias is None
+            else probs + jax.lax.stop_gradient(select_bias),
+            top_k,
+        )
+        gate = _scores_of(probs, expert_ids)
         if renormalise:
             gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
         if scale != 1.0:
             gate = gate * scale
-        flat_ids = expert_ids.reshape(-1)
-        counts = jnp.bincount(flat_ids, length=e).astype(jnp.int32)
+        counts = _assignments_of(expert_ids, e)
         group_sizes = counts if held is None else counts[lo:lo + count]
         stats = {
             "counts": counts.astype(jnp.float32),
@@ -442,46 +513,21 @@ def dropless_moe(
         tile_group, tiles_used, padded_starts = gmm.group_layout(
             group_sizes, assignments
         )
-        padded_rows = tile_group.shape[0] * gmm.ROW_TILE
-        if held is not None:
-            stats["tiles_used"] = tiles_used[0].astype(jnp.float32)
-            stats["tiles"] = jnp.float32(tile_group.shape[0])
         if held is None:
-            order = jnp.argsort(flat_ids, stable=True).astype(jnp.int32)
-            sorted_ids = flat_ids[order]
-        else:
-            # an expert held elsewhere sorts as group ``count``
-            local = flat_ids - lo
-            local = jnp.where((local >= 0) & (local < count), local, count)
-            order = jnp.argsort(local, stable=True).astype(jnp.int32)
-            here = local[order] < count
-            sorted_ids = jnp.minimum(local[order], count - 1)
-        starts = jnp.cumsum(group_sizes) - group_sizes
-        # the padded row of the assignment at sorted position i
-        row = (
-            padded_starts[sorted_ids] - starts[sorted_ids]
-            + jnp.arange(assignments, dtype=jnp.int32)
-        )
-        if held is not None:
-            # no row: a slot past the last one, each its own
-            row = jnp.where(
-                here, row,
-                padded_rows + jnp.arange(assignments, dtype=jnp.int32),
+            source, slot = _layout_by_sorting(
+                expert_ids, group_sizes, padded_starts,
+                tile_group.shape[0] * gmm.ROW_TILE,
             )
-        slot = jnp.zeros_like(order).at[order].set(
-            row, unique_indices=True
-        ).reshape(t, top_k)
-        source = jnp.full(
-            (padded_rows,), assignments, jnp.int32
-        ).at[row].set(
-            order, unique_indices=True,
-            mode=None if held is None else "drop",
-        )
-        if held is None:
             rows = _dispatch_rows(tokens.astype(dtype), source, slot)
         else:
+            stats["tiles_used"] = tiles_used[0].astype(jnp.float32)
+            stats["tiles"] = jnp.float32(tile_group.shape[0])
+            token_of_row, gate_of_row = _held_layout(
+                expert_ids, gate, lo, count, tile_group, tiles_used,
+                padded_starts,
+            )
             rows = _held_dispatch(
-                tokens.astype(dtype), source, slot, tiles_used
+                tokens.astype(dtype), token_of_row, tiles_used, t
             )
     with device_scope("moe_experts"):
         # the kernels' walks over the used tiles, the activation and
@@ -500,7 +546,9 @@ def dropless_moe(
                 preferred_element_type=jnp.float32,
             )
         else:
-            out = _held_combine(rows, gate, source, slot, tiles_used)
+            out = _held_combine(
+                rows, gate_of_row, token_of_row, tiles_used, t
+            )
     return out.astype(dtype), stats
 
 
@@ -627,25 +675,21 @@ def bias_deltas(counts, rate: float):
 #
 # A chip that holds ``count`` of a layer's ``e`` experts gives a row to
 # ``count / e`` of the assignments, so most of the static layout's row
-# tiles hold none.  The movements below walk the used tiles, up to
-# ``tiles_used`` (a trip count read on the device), and keep only ``[t,
-# d]`` arrays on the token side: no ``[t, k, d]`` array is made and the
-# rows past ``tiles_used`` are neither read nor written.  Rows from
-# tokens is a loop of one tile's gather a trip; its transpose, the
-# rows back to their tokens, is ``gmm.tokens_from_rows`` (a kernel:
-# XLA's scatter-add walks its rows one by one).  Both live inside
+# tiles hold none.  What has the static size is vector work on the
+# token side: masks over the ``count`` held experts and prefix sums over
+# the tokens (:func:`_held_layout`).  Everything on the row side walks
+# the used tiles, up to ``tiles_used`` (a trip count read on the
+# device): which token a row holds and at what weight, the rows from
+# their tokens (a loop of one tile's gather a trip) and the rows back
+# to their tokens (``gmm.tokens_from_rows``, a kernel: XLA's
+# scatter-add walks its rows one by one).  Only ``[t, d]`` arrays stand
+# on the token side, no ``[t, k, d]`` array is made and the ``[padded
+# rows, d]`` rows past ``tiles_used`` are neither read nor written.
+# No sort and no scatter: a stable sort of the assignments by expert
+# keeps an expert's rows in token order, and a token's k choices are
+# distinct experts, so an assignment's rank inside its expert is the
+# number of earlier tokens that chose it.  The walks live inside
 # ``custom_vjp`` rules, so autodiff never meets a loop.
-
-
-def _token_of_row(source, slot):
-    """``[padded rows]``: the token whose assignment lives at each
-    padded row.  A row of padding names a token past the last one,
-    each its own and ascending, so that a gather fills it with zeros
-    and a scatter drops it, and the indices of one tile (one expert's
-    rows in token order, then its padding) are sorted and distinct."""
-    t, k = slot.shape
-    padding = t + jnp.arange(source.shape[0], dtype=jnp.int32)
-    return jnp.where(source < t * k, source // k, padding)
 
 
 def _tile(x, i):
@@ -654,12 +698,148 @@ def _tile(x, i):
     )
 
 
+def _put_tile(x, tile, i):
+    return jax.lax.dynamic_update_slice_in_dim(
+        x, tile, i * gmm.ROW_TILE, axis=0
+    )
+
+
+def _line(x, j):
+    # ``x[j]`` of ``[count, t]``: one held expert's numbers, a token each
+    return jax.lax.dynamic_index_in_dim(x, j, axis=0, keepdims=False)
+
+
 def _rows_of(x, index):
     # ``x[index]`` for one tile's tokens; a row of padding reads zeros
     return x.at[index].get(
         mode="fill", fill_value=0, indices_are_sorted=True,
         unique_indices=True,
     )
+
+
+def _held_choices(expert_ids, gate, lo: int, count: int):
+    """``(reached [count, t] int32, gate_of_choice [count, t])``:
+    how many of the tokens up to and with t chose held expert ``lo +
+    j``, and the weight of token t's choice of it (0 where it chose
+    another).  A stable sort of the assignments by expert keeps an
+    expert's rows in token order, so the assignment of token t to
+    expert j lives at padded row ``padded_starts[j] + reached[j, t] -
+    1``.  Masks and sums over ``[count, k, t]`` with the tokens along
+    the lanes, at the static size."""
+    # [count, k, t]: choice c of token t is held expert j
+    hit = _is_expert(expert_ids.T, lo + jnp.arange(count)).transpose(2, 0, 1)
+    reached = jnp.cumsum(hit.any(axis=1), axis=1, dtype=jnp.int32)
+    # a token's k choices are distinct experts: one term is not zero
+    return reached, jnp.sum(jnp.where(hit, gate.T, 0.0), axis=1)
+
+
+def _held_layout(
+    expert_ids, gate, lo: int, count: int, tile_group, tiles_used,
+    padded_starts,
+):
+    """``(token_of_row [padded rows] int32, gate_of_row [padded rows])``
+    of the layout ``gmm.group_layout`` gives the held experts' counts:
+    the token whose assignment lives at each padded row, and that
+    assignment's weight (its gradient flows back to ``gate``).  EQUAL,
+    on every tile and at every routing, to what a stable sort of the
+    assignments by expert and two scatters give
+    (``tests/test_moe_held_index.py`` keeps that form as the
+    reference): what has the static size is :func:`_held_choices`, and
+    the way back from a row to its token is made for the used tiles."""
+    reached, gate_of_choice = _held_choices(expert_ids, gate, lo, count)
+    token_of_row = _tokens_of_rows(
+        reached, tile_group, tiles_used, padded_starts
+    )
+    return token_of_row, _gates_of_rows(
+        gate_of_choice, token_of_row, tile_group, tiles_used
+    )
+
+
+def _tokens_of_rows(reached, tile_group, tiles_used, padded_starts):
+    """``[padded rows]`` int32: the token at rank ``r`` of held expert
+    ``j`` is the first whose ``reached[j]`` is ``r + 1``, which is the
+    NUMBER of tokens with ``reached[j] <= r``: a compare of the
+    expert's line with a tile's 256 ranks and a sum, a used tile a
+    trip.  A row of padding names a token past the last one, each its
+    own and ascending, so that a gather fills it with zeros and a
+    scatter drops it, and the indices of one tile (one expert's rows
+    in token order, then its padding) are sorted and distinct; so do
+    the rows of the tiles that are not walked."""
+    t = reached.shape[1]
+    lane = jnp.arange(gmm.ROW_TILE, dtype=jnp.int32)
+
+    def find(i, token_of_row):
+        group = tile_group[i]
+        upto = _line(reached, group)
+        rank = i * gmm.ROW_TILE - padded_starts[group] + lane
+        before = jnp.sum(
+            upto[:, None] <= rank[None, :], axis=0, dtype=jnp.int32
+        )
+        # past the expert's last row every token is counted
+        padding = t + i * gmm.ROW_TILE + lane
+        return _put_tile(
+            token_of_row, jnp.where(before < t, before, padding), i
+        )
+
+    return jax.lax.fori_loop(
+        0, tiles_used[0], find,
+        t + jnp.arange(
+            tile_group.shape[0] * gmm.ROW_TILE, dtype=jnp.int32
+        ),
+    )
+
+
+@jax.custom_vjp
+def _gates_of_rows(gate_of_choice, token_of_row, tile_group, tiles_used):
+    """``[padded rows]``: ``gate_of_choice[expert of the row, token of
+    the row]`` for the rows of the used tiles (a tile's 256 out of its
+    expert's line), 0 for a row of padding and past ``tiles_used``."""
+
+    def find(i, gates):
+        line = _line(gate_of_choice, tile_group[i])
+        return _put_tile(gates, _rows_of(line, _tile(token_of_row, i)), i)
+
+    return jax.lax.fori_loop(
+        0, tiles_used[0], find,
+        jnp.zeros(token_of_row.shape, gate_of_choice.dtype),
+    )
+
+
+def _gates_of_rows_fwd(gate_of_choice, token_of_row, tile_group, tiles_used):
+    return (
+        _gates_of_rows(gate_of_choice, token_of_row, tile_group, tiles_used),
+        (gate_of_choice.shape, token_of_row, tile_group, tiles_used),
+    )
+
+
+def _gates_of_rows_bwd(res, g):
+    """A used tile's 256 numbers back at their tokens in the expert's
+    line, ``[count, t]``: a compare of the tile's tokens with all of
+    them and a sum with at most one term that is not zero (a tile's
+    tokens are distinct, and so are one expert's over its tiles)."""
+    shape, token_of_row, tile_group, tiles_used = res
+    every_token = jnp.arange(shape[1], dtype=jnp.int32)
+
+    def back(i, lines):
+        at_tokens = jnp.sum(
+            jnp.where(
+                _tile(token_of_row, i)[:, None] == every_token[None, :],
+                _tile(g, i)[:, None], 0.0,
+            ),
+            axis=0,
+        )
+        group = tile_group[i]
+        return jax.lax.dynamic_update_index_in_dim(
+            lines, _line(lines, group) + at_tokens, group, axis=0
+        )
+
+    with device_scope("moe_dispatch"):
+        return jax.lax.fori_loop(
+            0, tiles_used[0], back, jnp.zeros(shape, g.dtype)
+        ), None, None, None
+
+
+_gates_of_rows.defvjp(_gates_of_rows_fwd, _gates_of_rows_bwd)
 
 
 def _rows_from_tokens(x, token_of_row, tiles_used, weight=None, after=None):
@@ -672,9 +852,7 @@ def _rows_from_tokens(x, token_of_row, tiles_used, weight=None, after=None):
         tile = _rows_of(x, _tile(token_of_row, i))
         if weight is not None:
             tile = tile.astype(jnp.float32) * _tile(weight, i)[:, None]
-        return jax.lax.dynamic_update_slice_in_dim(
-            rows, tile.astype(x.dtype), i * gmm.ROW_TILE, axis=0
-        )
+        return _put_tile(rows, tile.astype(x.dtype), i)
 
     return jax.lax.fori_loop(
         0, tiles_used[0], move,
@@ -695,9 +873,7 @@ def _row_dots(rows, x, token_of_row, tiles_used):
             * _rows_of(x, _tile(token_of_row, i)).astype(jnp.float32),
             axis=-1,
         )
-        return jax.lax.dynamic_update_slice_in_dim(
-            dots, tile, i * gmm.ROW_TILE, axis=0
-        )
+        return _put_tile(dots, tile, i)
 
     return jax.lax.fori_loop(
         0, tiles_used[0], move,
@@ -705,77 +881,63 @@ def _row_dots(rows, x, token_of_row, tiles_used):
     )
 
 
-@jax.custom_vjp
-def _held_dispatch(tokens, source, slot, tiles_used):
-    """:func:`_dispatch_rows` where only some assignments have a row
-    (``slot`` names a row past the last one for the others)."""
-    return _rows_from_tokens(
-        tokens, _token_of_row(source, slot), tiles_used
-    )
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _held_dispatch(tokens, token_of_row, tiles_used, t: int):
+    """:func:`_dispatch_rows` where only some assignments have a row:
+    ``tokens[token_of_row]`` over the used tiles, zeros for a row of
+    padding (``t``: how many tokens there are, for the way back)."""
+    return _rows_from_tokens(tokens, token_of_row, tiles_used)
 
 
-def _held_dispatch_fwd(tokens, source, slot, tiles_used):
+def _held_dispatch_fwd(tokens, token_of_row, tiles_used, t):
     return (
-        _held_dispatch(tokens, source, slot, tiles_used),
-        (source, slot, tiles_used),
+        _held_dispatch(tokens, token_of_row, tiles_used, t),
+        (token_of_row, tiles_used),
     )
 
 
-def _held_dispatch_bwd(res, g):
-    source, slot, tiles_used = res
+def _held_dispatch_bwd(t, res, g):
     with device_scope("moe_dispatch"):
-        d_tokens = gmm.tokens_from_rows(
-            g, _token_of_row(source, slot), tiles_used, slot.shape[0]
-        )
-        return d_tokens, None, None, None
+        return gmm.tokens_from_rows(g, *res, t), None, None
 
 
 _held_dispatch.defvjp(_held_dispatch_fwd, _held_dispatch_bwd)
 
 
-def _gate_of_row(gate, source):
-    return gate.reshape(-1).at[source].get(mode="fill", fill_value=0)
-
-
-@jax.custom_vjp
-def _held_combine(rows, gate, source, slot, tiles_used):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _held_combine(rows, gate_of_row, token_of_row, tiles_used, t: int):
     """``sum over a token's held choices of gate x row``, accumulated
     in float32 and cast once, ``[t, d]``: :func:`_collect_rows` and
     the weighting in one, from the row side.  Choices held elsewhere
     add nothing."""
     return gmm.tokens_from_rows(
-        rows, _token_of_row(source, slot), tiles_used, slot.shape[0],
-        _gate_of_row(gate, source),
+        rows, token_of_row, tiles_used, t, gate_of_row
     )
 
 
-def _held_combine_fwd(rows, gate, source, slot, tiles_used):
+def _held_combine_fwd(rows, gate_of_row, token_of_row, tiles_used, t):
     return (
-        _held_combine(rows, gate, source, slot, tiles_used),
-        (rows, gate, source, slot, tiles_used),
+        _held_combine(rows, gate_of_row, token_of_row, tiles_used, t),
+        (rows, gate_of_row, token_of_row, tiles_used),
     )
 
 
-def _held_combine_bwd(res, g):
-    rows, gate, source, slot, tiles_used = res
+def _held_combine_bwd(t, res, g):
+    rows, gate_of_row, token_of_row, tiles_used = res
     with device_scope("moe_combine"):
-        token_of_row = _token_of_row(source, slot)
         # a row's gradient is its gate x its token's; the gate's is
-        # the row's dot product with it, back in ``[t, k]`` through
-        # ``slot`` (0 for a choice held elsewhere).  Two walks.  The
-        # rows' gradient reads nothing of ``rows``, which the
-        # backward pass has to make again, and still waits for the
+        # the row's dot product with it (0 for a row of padding).  Two
+        # walks.  The rows' gradient reads nothing of ``rows``, which
+        # the backward pass has to make again, and still waits for the
         # dots that do: made before the experts run again it is a
         # third array of the padded rows beside their hidden rows,
         # and made beside ``rows`` a second where it can take their
         # place (the step's most bytes live at once: PERF.md, PR 52)
         dots = _row_dots(rows, g, token_of_row, tiles_used)
-        d_gate = dots.at[slot].get(mode="fill", fill_value=0)
         d_rows = _rows_from_tokens(
-            g, token_of_row, tiles_used, _gate_of_row(gate, source),
-            after=dots,
+            g, token_of_row, tiles_used, gate_of_row, after=dots
         )
-        return d_rows, d_gate.astype(gate.dtype), None, None, None
+        return d_rows, dots.astype(gate_of_row.dtype), None, None
 
 
 _held_combine.defvjp(_held_combine_fwd, _held_combine_bwd)

@@ -481,16 +481,16 @@ def test_no_row_past_tiles_used_reaches_the_ungated_layer(monkeypatch):
                 tiles_used, fill,
             )
 
-        def dispatch(tokens, source, slot, tiles_used):
+        def dispatch(tokens, token_of_row, tiles_used, t):
             return fill_past(
-                held_dispatch(tokens, source, slot, tiles_used),
+                held_dispatch(tokens, token_of_row, tiles_used, t),
                 tiles_used, fill,
             )
 
-        def combine(rows, gate, source, slot, tiles_used):
+        def combine(rows, gate_of_row, token_of_row, tiles_used, t):
             return held_combine(
-                fill_past(rows, tiles_used, fill), gate, source, slot,
-                tiles_used,
+                fill_past(rows, tiles_used, fill), gate_of_row,
+                token_of_row, tiles_used, t,
             )
 
         monkeypatch.setattr(moe.gmm, "grouped_expert", experts)

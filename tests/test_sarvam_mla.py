@@ -28,6 +28,7 @@ import loader  # noqa: E402  (the benchmark's own)
 from conftest import (  # noqa: E402
     fill_inside_an_expert, fill_past, primitives_under,
 )
+from test_moe_held_index import layout_by_sorting  # noqa: E402
 
 from dlrover_tpu.checkpoint.checkpointer import (  # noqa: E402
     Checkpointer,
@@ -523,17 +524,17 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
             out, stats = layer(*ops)
             return jnp.sum(out * cot), (out, stats)
 
-        def dispatch(tokens, source, slot, tiles_used):
+        def dispatch(tokens, token_of_row, tiles_used, t):
             return fill_past(
-                held_dispatch(tokens, source, slot, tiles_used),
+                held_dispatch(tokens, token_of_row, tiles_used, t),
                 tiles_used, fill,
             )
 
-        def combine(rows, gate, source, slot, tiles_used):
+        def combine(rows, gate_of_row, token_of_row, tiles_used, t):
             # the fill of ``rows`` is the fill of their gradient
             return held_combine(
-                fill_past(rows, tiles_used, fill), gate, source, slot,
-                tiles_used,
+                fill_past(rows, tiles_used, fill), gate_of_row,
+                token_of_row, tiles_used, t,
             )
 
         monkeypatch.setattr(moe.gmm, "grouped_expert", experts)
@@ -569,7 +570,9 @@ def test_no_row_past_tiles_used_reaches_the_layer(case, monkeypatch):
 # plain reference of the row-side movements.  Every array has the
 # static size: the dispatch gathers ``[padded rows, d]``, the combine
 # gathers ``[t, k, d]`` (a choice held elsewhere reads zeros) and
-# weights it.
+# weights it.  Its ``source`` and ``slot`` come from the sort of the
+# assignments (``test_moe_held_index.py::layout_by_sorting``: the index
+# work as it stood until PR 58).
 
 
 def _rows_at(rows, slot, some_absent: bool):
@@ -615,15 +618,34 @@ def _collect_bwd(some_absent, source, g):
 _collect_rows_at_pr_37.defvjp(_collect_fwd, _collect_bwd)
 
 
-def plain_dispatch(tokens, source, slot, tiles_used):
-    return _dispatch_rows_at_pr_37(tokens, source, slot, True)
+def plain_layout(
+    expert_ids, gate, lo, count, tile_group, tiles_used, padded_starts
+):
+    # in ``moe._held_layout``'s place: the sort's ``source`` and
+    # ``slot`` where the row side's ``token_of_row`` goes, the weights
+    # ``[t, k]`` as they are where ``gate_of_row`` does
+    *_, source, slot = layout_by_sorting(
+        expert_ids, lo + count + 1, lo, count
+    )
+    return (source, slot), gate
 
 
-def plain_combine(rows, gate, source, slot, tiles_used):
+def plain_dispatch(tokens, source_and_slot, tiles_used, t):
+    return _dispatch_rows_at_pr_37(tokens, *source_and_slot, True)
+
+
+def plain_combine(rows, gate, source_and_slot, tiles_used, t):
     return jnp.einsum(
-        "tkd,tk->td", _collect_rows_at_pr_37(rows, source, slot, True),
+        "tkd,tk->td",
+        _collect_rows_at_pr_37(rows, *source_and_slot, True),
         gate, preferred_element_type=jnp.float32,
     ).astype(rows.dtype)
+
+
+def plain_routing(patch):
+    patch.setattr(moe, "_held_layout", plain_layout)
+    patch.setattr(moe, "_held_dispatch", plain_dispatch)
+    patch.setattr(moe, "_held_combine", plain_combine)
 
 
 HELD = {
@@ -677,8 +699,7 @@ def test_the_row_side_is_the_plain_routing(case, monkeypatch):
 
     got, stats = results()
     assert (int(stats["tiles_used"]), int(stats["tiles"])) == c["tiles"]
-    monkeypatch.setattr(moe, "_held_dispatch", plain_dispatch)
-    monkeypatch.setattr(moe, "_held_combine", plain_combine)
+    plain_routing(monkeypatch)
     want, _ = results()
     for name, a, b in zip(
         ("out", "tokens", "router", "w_gate", "w_up", "w_down"), got, want
@@ -774,8 +795,7 @@ def test_no_tokens_by_choices_by_width_array_in_the_step():
 
     assert not every_assignment(lowered())
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(moe, "_held_dispatch", plain_dispatch)
-        patch.setattr(moe, "_held_combine", plain_combine)
+        plain_routing(patch)
         assert (t, k, d) in every_assignment(lowered())
 
 

@@ -550,6 +550,104 @@ HYBRID_ATTN = (1, 8192, 30, 128)
 HYBRID_RULE = dict(batch=1, seq=8192, heads=30, dk=96, dv=192)
 
 
+# a cell's router: its outputs, the choices a token makes, the score,
+# whether a bias picks them
+ROUTERS = {
+    "olmoe_steady_4k": (64, 8, "softmax", False),
+    "sarvam_steady_8k": (128, 8, "sigmoid", True),
+    "laguna_steady_8k": (256, 10, "softmax", False),
+    "nemotron_steady_8k": (128, 6, "sigmoid", True),
+    "mimo_v2_5_steady": (256, 8, "sigmoid", True),
+}
+
+
+def _index_passes(text, sizes):
+    """``(kind, shape)`` of every ``sort``, ``scatter`` and ``gather``
+    of a compiled program (fused ones too) that makes an array of one
+    of ``sizes`` elements: the operations that take a TPU 7-10 ns an
+    element where a vector pass takes bytes."""
+    found = []
+    for kind, made in re.findall(
+        r"^\s*(?:ROOT )?%[\w\-.]+ = (\(?[^=\n]*?\)?) "
+        r"(sort|scatter|gather)\(", text, re.M,
+    ):
+        found += [
+            (made, shape) for shape in re.findall(r"\w+\[([\d,]+)\]", kind)
+            if int(np.prod([int(n) for n in shape.split(",")])) in sizes
+        ]
+    return found
+
+
+def _arrays_of(text, elements: int):
+    """The results of ``elements`` elements that a compiled program's
+    own instructions make (what stands in memory), a fusion's inner
+    values left out."""
+    found, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and " -> " in line:
+            fused = "fused_computation" in line.split("(", 1)[0]
+        elif not fused:
+            found += [
+                shape for shape in re.findall(
+                    r"^\s*(?:ROOT )?%[\w\-.]+ = \w+\[([\d,]+)\]", line
+                )
+                if int(np.prod([int(n) for n in shape.split(",")]))
+                == elements
+            ]
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_LAYERS))
+def test_the_held_layers_index_work_is_no_sort_and_no_scatter(
+    one_chip, on_tpu, cell
+):
+    """The layer at each cell's tokens, choices, router outputs and
+    held experts (the widths small: the index work does not see
+    them), value and all gradients through a rematted layer, compiled:
+    where a chip holds a range, NO ``sort``, ``scatter`` or ``gather``
+    makes an array of ``tokens x k`` or of the padded rows' size (the
+    router's top-k sorts ``[tokens, e]``; the row side gathers a
+    tile's 256), and no array of ``tokens x k x e`` stands in memory
+    (100 MB of int32 at 384 outputs): masks and prefix sums are
+    fused vector passes.  Where every expert is held
+    (``olmoe_steady_4k``) the sort and its scatters stay, and the
+    search finds them."""
+    from dlrover_tpu.ops import grouped_matmul as gmm
+    from dlrover_tpu.parallel.moe import dropless_moe
+
+    assignments, groups, _, _, gated = EXPERT_LAYERS[cell]
+    e, k, score, bias = ROUTERS[cell]
+    t, d, m = assignments // k, 384, 128
+    held = None if groups == e else (0, groups)
+    padded_rows = assignments + groups * gmm.ROW_TILE
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, router, w_gate, w_up, w_down, select_bias):
+        out, stats = dropless_moe(
+            x, router, w_gate if gated else None, w_up, w_down, k,
+            held=held, score=score, renormalise=True, scale=2.5,
+            select_bias=select_bias if bias else None,
+        )
+        return out.astype(jnp.float32).sum() + jnp.vdot(
+            stats["prob_sum"], stats["counts"]
+        )
+
+    text = jax.jit(jax.value_and_grad(
+        jax.checkpoint(loss), argnums=(0, 1, 3, 4)
+    )).lower(
+        s((t, d)), s((d, e), jnp.float32), s((groups, d, m)),
+        s((groups, d, m)), s((groups, m, d)), s((e,), jnp.float32),
+    ).compile().as_text()
+    found = _index_passes(text, {assignments, padded_rows})
+    if held is None:
+        assert {kind for kind, _ in found} == {"sort", "scatter", "gather"}
+    else:
+        assert not found
+    assert not _arrays_of(text, assignments * e)
+
+
 def _rule_operands(one_chip, dtype=jnp.bfloat16):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
