@@ -1,6 +1,10 @@
 """Model zoo: TPU-native reference models used by the trainer, the
 strategy engine's dry-runner, and the benchmarks."""
 
+from dlrover_tpu.models.bailing_hybrid import (
+    BailingHybrid,
+    BailingHybridConfig,
+)
 from dlrover_tpu.models.gpt import GPT, GPTConfig
 from dlrover_tpu.models.llama import Llama, LlamaConfig
 from dlrover_tpu.models.mimo_v2 import MiMoV2, MiMoV2Config
@@ -15,6 +19,8 @@ from dlrover_tpu.models.losses import (
 )
 
 __all__ = [
+    "BailingHybrid",
+    "BailingHybridConfig",
     "GPT",
     "GPTConfig",
     "Llama",

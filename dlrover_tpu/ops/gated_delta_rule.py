@@ -8,8 +8,11 @@ Per head, with a state ``S`` in ``R^{d_k x d_v}`` that starts at 0::
     S_t = exp(g_t) S_{t-1} + beta_t k_t (v_t - exp(g_t) S_{t-1}^T k_t)^T
     o_t = S_t^T q_t
 
-``g_t <= 0`` is the log of the decay and ``beta_t`` the write strength
-(up to 2 where the layer allows negative eigenvalues).  Token by token
+``g_t <= 0`` is the log of the decay, ONE number a head and token
+(the rule whose decay is a vector, a number a key channel, lives in
+``ops/kda.py`` and imports this file's inverse, its gradient, the
+matmul helpers, the layouts and the barriers), and ``beta_t`` the write
+strength (up to 2 where the layer allows negative eigenvalues).  Token by token
 this is a scan of ``seq`` steps of rank-one updates: no training path.
 Over a chunk of ``CHUNK`` tokens, with ``gamma`` the running sum of
 ``g`` inside the chunk and ``S`` the state the chunk starts from::

@@ -393,6 +393,8 @@ def dropless_moe(
     renormalise: bool = False,
     scale: float = 1.0,
     polynorm: Optional[jax.Array] = None,  # [4] float32
+    n_group: int = 1,
+    topk_group: int = 1,
 ):
     """Top-k routing without capacity: ``(out [t, d], stats)``.
 
@@ -406,7 +408,15 @@ def dropless_moe(
     by their sum, ``scale`` multiplies them.  An expert computes
     ``down(silu(gate(x)) * up(x))`` or, with ``w_gate=None``,
     ``down(relu(up(x)) ** 2)``: an expert that has no gate has no
-    gate matrix; with ``polynorm`` (:func:`polynorm_coeffs`) the
+    gate matrix.  ``n_group > 1`` limits the choice to groups
+    (DeepSeek-V3's ``noaux_tc``, arXiv:2412.19437): the ``e`` outputs
+    are ``n_group`` groups of consecutive experts, a group's score is
+    the sum of its two largest ``score + bias``, only the
+    ``topk_group`` best groups' experts stand for the top-k
+    (:func:`_within_best_groups`, under the device scope
+    ``moe_group_select``), and ``stats`` gains ``groups_per_token``,
+    the mean number of distinct groups among a token's k choices (at
+    most ``topk_group``).  With ``polynorm`` (:func:`polynorm_coeffs`) the
     gate's activation is ``gmm.poly_norm`` in silu's place, each norm
     over the expert's own width.  Every form is one call,
     ``gmm.grouped_expert``: the matmuls AND the activation between
@@ -477,6 +487,7 @@ def dropless_moe(
             f"{w_up.shape[0]} experts' weights for {count} held"
         )
     assignments = t * top_k
+    check_groups(e, top_k, n_group, topk_group)
     with device_scope("moe_router"):
         logits = jnp.dot(
             tokens.astype(jnp.float32),
@@ -489,11 +500,15 @@ def dropless_moe(
             probs = jax.nn.sigmoid(logits)
         else:
             raise ValueError(f"unknown router score {score!r}")
-        _, expert_ids = jax.lax.top_k(  # [t, k]
+        standing = (
             probs if select_bias is None
-            else probs + jax.lax.stop_gradient(select_bias),
-            top_k,
+            else probs + jax.lax.stop_gradient(select_bias)
         )
+    if n_group > 1:
+        with device_scope("moe_group_select"):
+            standing = _within_best_groups(standing, n_group, topk_group)
+    with device_scope("moe_router"):
+        _, expert_ids = jax.lax.top_k(standing, top_k)  # [t, k]
         gate = _scores_of(probs, expert_ids)
         if renormalise:
             gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
@@ -509,6 +524,11 @@ def dropless_moe(
                 jax.nn.logsumexp(logits, axis=-1) ** 2
             ),
         }
+    if n_group > 1:
+        with device_scope("moe_group_select"):
+            stats["groups_per_token"] = _groups_chosen(
+                expert_ids, e // n_group, n_group
+            )
     with device_scope("moe_dispatch"):
         tile_group, tiles_used, padded_starts = gmm.group_layout(
             group_sizes, assignments
@@ -552,6 +572,46 @@ def dropless_moe(
     return out.astype(dtype), stats
 
 
+def check_groups(e: int, top_k: int, n_group: int, topk_group: int):
+    """Raises where ``n_group`` groups do not divide the ``e`` outputs
+    or the ``topk_group`` kept groups hold fewer than ``top_k``
+    experts."""
+    if n_group < 1 or e % n_group:
+        raise ValueError(f"{e} experts do not divide into {n_group} groups")
+    if not 1 <= topk_group <= n_group:
+        raise ValueError(f"{topk_group} of {n_group} groups kept")
+    if top_k > topk_group * (e // n_group):
+        raise ValueError(
+            f"top-{top_k} of {topk_group} groups of {e // n_group} experts"
+        )
+
+
+def _within_best_groups(standing, n_group: int, topk_group: int):
+    """``standing [t, e]`` with every expert outside its token's
+    ``topk_group`` best groups at ``-inf``: a group (``e / n_group``
+    consecutive experts) scores the sum of its two largest entries."""
+    t, e = standing.shape
+    grouped = standing.reshape(t, n_group, e // n_group)
+    best_two, _ = jax.lax.top_k(grouped, 2)
+    _, best = jax.lax.top_k(best_two.sum(axis=-1), topk_group)
+    # [t, n_group]: the group is one of the token's best
+    kept = jnp.any(
+        best[:, :, None] == jnp.arange(n_group, dtype=best.dtype), axis=1
+    )
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, e)
+
+
+def _groups_chosen(expert_ids, group_size: int, n_group: int):
+    """The mean over tokens of how many distinct groups a token's k
+    choices lie in, float32."""
+    of_choice = expert_ids // group_size                  # [t, k]
+    hit = jnp.any(
+        of_choice[:, :, None] == jnp.arange(n_group, dtype=of_choice.dtype),
+        axis=1,
+    )
+    return jnp.mean(jnp.sum(hit, axis=-1, dtype=jnp.float32))
+
+
 class DroplessMoE(nn.Module):
     """:func:`dropless_moe` as a layer: ``x [b, s, d] -> (out, stats)``.
     Parameter names as :class:`MoEMLP`'s gated experts (``router``,
@@ -573,7 +633,8 @@ class DroplessMoE(nn.Module):
     ``experts_polynorm_b []`` (float32) for the layer's routed experts,
     whose gradients sum over every row of the layer, and one more,
     ``shared_polynorm_w`` / ``shared_polynorm_b``, for the shared
-    expert."""
+    expert.  ``n_group`` / ``topk_group`` limit the choice to groups
+    (:func:`dropless_moe`); the defaults are no grouping."""
 
     num_experts: int
     mlp_dim: int
@@ -590,6 +651,8 @@ class DroplessMoE(nn.Module):
     expert_form: str = "swiglu"  # | "relu2" | "polynorm"
     polynorm_scale: float = 0.5
     polynorm_clamp: float = 0.5
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x: jax.Array):
@@ -635,6 +698,7 @@ class DroplessMoE(nn.Module):
             self.top_k, self.dtype, held=self.held, score=self.score,
             select_bias=bias, renormalise=self.renormalise,
             scale=self.scale, polynorm=polynorm("experts_"),
+            n_group=self.n_group, topk_group=self.topk_group,
         )
         out = out.reshape(b, s, d)
         if self.shared_dim:

@@ -124,6 +124,12 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # the largest rms of a layer's final state, and the mean of
         # the decay exp(dt A) over tokens, heads and layers (1 / (1 -
         # a) tokens is how far back a layer remembers)
+        # kda.* / moe.groups_per_token_mean: channel-gated linear
+        # attention over grouped experts (models/bailing_hybrid.py):
+        # the least log-decay a step of any channel (the safe gate
+        # holds it at or above kda_lower_bound), the largest rms of a
+        # layer's final state, and the mean number of distinct groups
+        # among a token's k choices (at most topk_group)
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
@@ -133,7 +139,8 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
             "loop.exit_entropy", "loop.nll_first", "loop.nll_last",
             "ssm.state_rms_max", "ssm.decay_mean",
             "mhc.res_sum_err_max", "gdla.lambda_mean",
-            "gdla.noise_share", "mtp.loss"]),
+            "gdla.noise_share", "mtp.loss", "kda.log_decay_min",
+            "kda.state_rms_max", "moe.groups_per_token_mean"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

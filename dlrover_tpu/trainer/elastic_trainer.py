@@ -647,12 +647,13 @@ class ElasticTrainer:
             # state, a windowed model's walk, a looped model's exits,
             # a state-space model's states and decays, a
             # hyper-connected model's streams, differential attention
-            # and prediction layer) ride on this
+            # and prediction layer, a channel-gated model's decays)
+            # ride on this
             # event: one more event would cost the loop
             # 0.65 ms a step (PERF.md, PR 25)
             if name.startswith((
                 "moe.", "gdn.", "attn.", "loop.", "ssm.", "mhc.", "gdla.",
-                "mtp.",
+                "mtp.", "kda.",
             )):
                 step_event[name] = float(value)
         emit_event("train_step", **step_event)

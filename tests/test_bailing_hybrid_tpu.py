@@ -1,0 +1,193 @@
+"""Ling-3.0-flash's kernels and the cell's step, COMPILED for a
+described TPU v5e (no chip attached, nothing runs): the fixtures and
+helpers are ``test_tpu_compile.py``'s.  In a file of its own (PR 50's
+departure (1): under ``--dist loadfile`` a file is one worker's, and a
+long file ends the run)."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from test_tpu_compile import (  # noqa: F401  (fixtures by name)
+    _calls,
+    _compile_and_reserved_hbm,
+    _kernels,
+    _shapes,
+    on_tpu,
+    one_chip,
+    topo,
+)
+
+from dlrover_tpu.ops import flash_attention as fa
+from dlrover_tpu.ops import kda
+from dlrover_tpu.optim import adamw_bf16
+from dlrover_tpu.trainer.elastic_trainer import (
+    TrainState,
+    make_train_step,
+)
+
+RULE = dict(batch=1, seq=8192, heads=32, d=128)
+
+
+def _rule_operands(one_chip, dtype):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, t, h, d = (RULE[k] for k in ("batch", "seq", "heads", "d"))
+    tokens = s((b, t, h, d), dtype)
+    return (
+        tokens, tokens, tokens, s((b, t, h, d), jnp.float32),
+        s((b, t, h), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize(
+    "dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"]
+)
+def test_the_channel_wise_rule_compiles_at_published_sizes(
+    one_chip, on_tpu, dtype
+):
+    """The rule at (1, 8192, 32, 128 | 128) with a log-decay a
+    channel, forward and backward, for the described chip: the forward
+    is the ``kda_fwd`` kernel and no ``while``, the gradient adds
+    ``kda_bwd``, the row-of-a-block gathers of the levels' reference
+    rows are legal Mosaic reshapes, and both stay inside the scoped
+    VMEM (no ``vmem_limit_bytes`` is asked for).  bf16 is the cell's;
+    float32 operands (every matmul at ``HIGHEST``) are the tests'
+    exact path."""
+    operands = _rule_operands(one_chip, dtype)
+    forward = jax.jit(kda.kda_rule).lower(*operands).compile()
+    out, state = forward.out_info
+    assert out.shape == (1, 8192, 32, 128) and out.dtype == dtype
+    assert state.shape == (1, 32, 128, 128) and state.dtype == jnp.float32
+    assert _calls(forward, "kda_fwd") == _kernels(forward) == 1
+    text = forward.as_text()
+    assert " while(" not in text
+    hlo = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    chunks = f"{hlo}[32,{8192 // kda.CHUNK}"
+    # the states each chunk starts from and its inverse, for the
+    # backward, in the operands' type
+    assert f"{chunks},128,128]" in text
+
+    def loss(*a):
+        return kda.kda_rule(*a)[0].astype(jnp.float32).sum()
+
+    backward = jax.jit(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    ).lower(*operands).compile()
+    assert _calls(backward, "kda_fwd") == 1
+    assert _calls(backward, "kda_bwd") == 1
+    assert _kernels(backward) == 2
+    assert " while(" not in backward.as_text()
+    # the decay's gradient leaves the program a float32 a channel
+    assert backward.out_info[3].shape == (1, 8192, 32, 128)
+    assert backward.out_info[3].dtype == jnp.float32
+    temp = backward.memory_analysis().temp_size_in_bytes
+    print(f"kda backward temporaries {hlo}: {temp / 2**30:.3f} GiB")
+    # 0.63 GiB in bf16, 0.88 in float32 (offline compile, PR 59)
+    assert temp < 1.5 * 2**30
+
+
+def test_flash_attention_compiles_at_32_heads_of_192_and_128(
+    one_chip, on_tpu
+):
+    """The latent layer's attention: 8192 tokens, 32 query and 32 key
+    heads of 192 | 128 (the other latent cells run 16 and 20 query
+    heads): forward, dq and dkv inside the v5e's scoped VMEM."""
+    q = jax.ShapeDtypeStruct(
+        (1, 8192, 32, 192), jnp.bfloat16, sharding=one_chip
+    )
+    v = jax.ShapeDtypeStruct(
+        (1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip
+    )
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(
+        jax.grad(loss, argnums=(0, 1, 2))
+    ).lower(q, q, v).compile()
+    assert _kernels(compiled) == 3
+
+
+def test_ling_step_fits_the_chip(one_chip, on_tpu, tmp_path):
+    """The cell's step (``ling_3_flash_cut``: a dense KDA block, five
+    sparse KDA blocks and a sparse latent-attention block at the
+    published widths, 16 of 512 experts held under the group mask, a
+    quarter of the vocabulary, bf16 state, flash attention, per-block
+    remat, 1 x 8192 tokens): state + temporaries under the chip's
+    15.75 GiB, the rule's kernels a KDA layer (forward, its remat
+    copy, backward), the flash kernels under the module ``attn``, and
+    every scope the benchmark's readers join on in the op-name map."""
+    from dlrover_tpu.common.aot_cache import op_names
+    from dlrover_tpu.models.bailing_hybrid import (
+        BailingHybrid,
+        BailingHybridConfig,
+        make_bailing_hybrid_loss,
+    )
+
+    model = BailingHybrid(BailingHybridConfig(
+        vocab_size=39296, num_layers=7, layer_ids=(1, 6, 7, 8, 9, 10, 11),
+        first_dense=1, experts_held=(0, 16), attention_impl="flash",
+        remat=True, param_dtype=jnp.bfloat16,
+    ))
+    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
+    abs_state = jax.eval_shape(
+        lambda: TrainState.create(
+            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
+            optimizer,
+        )
+    )
+    tokens = np.zeros((1, 8192), np.int32)
+    compiled, reserved = _compile_and_reserved_hbm(make_train_step(
+        make_bailing_hybrid_loss(model, num_chunks=8), optimizer
+    ).lower(
+        _shapes(abs_state, one_chip),
+        _shapes({"x": tokens, "y": tokens}, one_chip),
+    ), tmp_path)
+    mem = compiled.memory_analysis()
+    # 1.268 B parameters x 6 bytes (the norms' scales, A_log, dt_bias
+    # and the select bias are float32)
+    assert round(mem.argument_size_in_bytes / 1e9, 2) == 7.61
+    print(
+        f"ling step temporaries: {reserved / 1e9:.3f} GB reserved, "
+        f"{(2 * reserved - mem.temp_size_in_bytes) / 1e9:.3f} live at "
+        f"once, {mem.temp_size_in_bytes / 1e9:.3f} reported"
+    )
+    # offline compile, PR 59: 4.44 GB reported
+    assert mem.temp_size_in_bytes < 4.8e9
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        < 15.75 * 2**30
+    )
+    text = compiled.as_text()
+    assert _calls(compiled, "kda_fwd") == 12
+    assert _calls(compiled, "kda_bwd") == 6
+    calls = re.findall(
+        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', text, re.M,
+    )
+    found = op_names(text)
+    stacks = found["op_names"]
+    flash = [c for c in calls if re.match(r"^%?attn(\.|$)", c)]
+    # forward, dq, dkv in the one latent block; it does not run its
+    # forward again
+    assert len(flash) == 3
+    assert all("/block_6/attn/" in stacks[c] for c in flash)
+    for scope in (
+        "kda_proj", "kda_conv", "kda_gates", "kda_rule", "kda_norm",
+        "kda_out", "mla_q", "mla_kv_down", "mla_kv_up", "mla_rope",
+        "attn_gate", "mla_out", "moe_router", "moe_group_select",
+        "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
+        "loss_head",
+    ):
+        # (bare or inside jax's wrappers: ``jvp(loss_head)``)
+        assert any(
+            re.search(rf"[/(]{scope}[/)]|/{scope}$", s)
+            for s in stacks.values()
+        ), scope
+    # nothing of the step is left without a name of the program
+    assert not found["unnamed"]
