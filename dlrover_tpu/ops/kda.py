@@ -59,14 +59,26 @@ five gradients; ``d Gamma [C, d_k]`` needs no reference token: a pair
 operand on either side, whatever it was scaled about.  A
 ``jax.custom_vjp`` joins them.
 
-Outside the kernels, in XLA: the layout into heads-leading ``[b h, s,
-d]``, the tail's padding and ``Gamma`` (a cumulative sum of ``[b h, n,
-C, d_k]`` float32; its transpose, a reverse cumulative sum a channel,
-turns ``d Gamma`` into ``d g``), between ``optimization_barrier``s.
+The kernels take the caller's arrays as the caller holds them, viewed
+``[b, s, h d]`` (free: a head is ``d`` whole lanes of a row), in
+blocks ``(1, CHUNK, heads a step x d)`` whose index map picks batch,
+chunk and the heads' column; head ``h`` of a block is the lane slice
+``[:, h d:(h + 1) d]``, and ``o`` and the gradients of ``q``, ``k``,
+``v`` and ``g`` are written the same way.  ``Gamma`` is made in VMEM a
+head and chunk (:func:`_running_sum`: seven shifted, masked float32
+adds down the tokens, the same on both precisions), and ``kda_bwd``
+ends with its transpose, ``d g = L^T d Gamma`` (the reverse running
+sum; ``d Gamma_C`` reaches every token of the chunk).  So no
+heads-leading copy of ``q``, ``k``, ``v``, ``g`` or ``o`` exists, no
+running sum runs in XLA and the backward recomputes nothing outside
+its kernel.  Outside the kernels, in XLA: ``beta``'s rows (``[b h, n,
+1, C]`` float32, 1 MB a layer; ``d beta`` comes back the same way)
+and, where ``s`` is no multiple of ``CHUNK``, the tail's padding.
 What this file shares with the scalar rule (the inverse and its
-gradient, the matmul helpers, the layouts and barriers) is imported
-from ``ops/gated_delta_rule.py``.  The scalar rule is the case in
-which a head's channels share one ``g``
+gradient, the matmul helpers, ``beta``'s layout) is imported from
+``ops/gated_delta_rule.py``; the scalar rule's heads of 96 | 192 lanes
+are no whole columns, so it keeps its own entry path.  The scalar rule
+is the case in which a head's channels share one ``g``
 (``tests/test_bailing_hybrid.py``).
 
 Precision: decays, running sums, the exponentials, the state, ``dS``,
@@ -88,7 +100,6 @@ from dlrover_tpu.ops.gated_delta_rule import (
     NT,
     TN,
     _as_row,
-    _barrier,
     _dot,
     _heads_lead,
     _interpret,
@@ -211,28 +222,53 @@ def _chunk(q, k, v, gamma, beta, state, exact, t=None):
     )
 
 
+def _running_sum(x, reverse=False):
+    """The running sum of ``x [C, d]`` float32 down the tokens, ``L x``
+    with ``L`` the 0/1 lower triangle, diagonal included (from the
+    last token up with ``reverse``: ``L^T x``): ``log2 C`` shifted,
+    masked adds, each a float32 add of two partial sums."""
+    c = x.shape[0]
+    at = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    k = 1
+    while k < c:
+        if reverse:
+            x = x + jnp.where(at < c - k, pltpu.roll(x, c - k, 0), 0.0)
+        else:
+            x = x + jnp.where(at >= k, pltpu.roll(x, k, 0), 0.0)
+        k *= 2
+    return x
+
+
+def _head(h, d):
+    """Where head ``h``'s ``[C, d]`` lies in a ``(1, C, heads x d)``
+    block: whole lanes of its rows."""
+    return 0, slice(None), slice(h * d, (h + 1) * d)
+
+
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, gamma_ref, beta_ref,
+    q_ref, k_ref, v_ref, g_ref, beta_ref,
     o_ref, final_ref, start_ref, t_ref, state, *, exact,
 ):
     n = pl.program_id(1)
+    heads, dk, dv = state.shape
 
     @pl.when(n == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
-    for h in range(q_ref.shape[0]):
+    for h in range(heads):
         s = state[h]
         start_ref[h, 0] = s.astype(start_ref.dtype)
+        at_k, at_v = _head(h, dk), _head(h, dv)
         x = _chunk(
-            q_ref[h], k_ref[h], v_ref[h], gamma_ref[h], beta_ref[h, 0],
-            s, exact,
+            q_ref[at_k], k_ref[at_k], v_ref[at_v],
+            _running_sum(g_ref[at_k]), beta_ref[h, 0], s, exact,
         )
         t_ref[h, 0] = x["t"]
         o = _dot(x["q_in"], x["sb"], NN, exact) + _dot(
             x["p"], x["vn"], NN, exact
         )
-        o_ref[h] = o.astype(o_ref.dtype)
+        o_ref[at_v] = o.astype(o_ref.dtype)
         state[h] = x["end_rows"] * s + _dot(x["k_end"], x["vn"], TN, exact)
 
     @pl.when(n == pl.num_programs(1) - 1)
@@ -241,25 +277,26 @@ def _fwd_kernel(
 
 
 def _bwd_kernel(
-    q_ref, k_ref, v_ref, gamma_ref, beta_ref, start_ref, t_ref,
+    q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, t_ref,
     do_ref, dfinal_ref,
-    dq_ref, dk_ref, dv_ref, dgamma_ref, dbeta_ref, dstate, *, exact,
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *, exact,
 ):
     @pl.when(pl.program_id(1) == 0)
     def _():
         dstate[...] = dfinal_ref[...]
 
-    for h in range(q_ref.shape[0]):
-        q, k, v = q_ref[h], k_ref[h], v_ref[h]
+    heads, dk, dv = dstate.shape
+    for h in range(heads):
+        at_k, at_v = _head(h, dk), _head(h, dv)
+        q, k, v = q_ref[at_k], k_ref[at_k], v_ref[at_v]
         dtype = q.dtype
-        c, dk = q.shape
-        dv = v.shape[1]
+        c = q.shape[0]
         s = start_ref[h, 0].astype(F32)
         x = _chunk(
-            q, k, v, gamma_ref[h], beta_ref[h, 0], s, exact,
+            q, k, v, _running_sum(g_ref[at_k]), beta_ref[h, 0], s, exact,
             t=t_ref[h, 0],
         )
-        do = do_ref[h]
+        do = do_ref[at_v]
         ds = dstate[h]
         dsb = ds.astype(dtype)
         row, col = _iotas(c)
@@ -314,9 +351,9 @@ def _bwd_kernel(
         dq = dq + x["d_row"] * dqr
         dk_total = dk_total + x["d_row"] * dkr + x["d_col"] * dkc
         dgamma = dgamma + dkr * x["kr"] + dqr * x["qr"] - dkc * x["kc"]
-        dq_ref[h] = dq.astype(dq_ref.dtype)
-        dk_ref[h] = dk_total.astype(dk_ref.dtype)
-        dv_ref[h] = (_lanes(b_row, dv) * dvb).astype(dv_ref.dtype)
+        dq_ref[at_k] = dq.astype(dq_ref.dtype)
+        dk_ref[at_k] = dk_total.astype(dk_ref.dtype)
+        dv_ref[at_v] = (_lanes(b_row, dv) * dvb).astype(dv_ref.dtype)
 
         def rows(y):
             return jnp.sum(y, axis=1, keepdims=True)
@@ -330,114 +367,135 @@ def _bwd_kernel(
         d_end = jnp.sum(at_end, axis=0, keepdims=True) + x["end"] * (
             _as_row(rows(s * ds))
         )
-        at = jax.lax.broadcasted_iota(jnp.int32, dgamma.shape, 0)
-        dgamma_ref[h] = dgamma + jnp.where(at == c - 1, d_end, 0.0)
+        # g_t is in Gamma_i for every i >= t of the chunk, Gamma_C too
+        dg_ref[at_k] = _running_sum(dgamma, reverse=True) + d_end
         dbeta_ref[h, 0] = _as_row(dbeta)
 
 
-def _heads_a_step(bh: int) -> int:
-    return max(n for n in range(1, HEADS + 1) if bh % n == 0)
+def _heads_a_step(heads: int, dk: int, dv: int) -> int:
+    """Heads a grid step holds, from the shapes: up to ``HEADS`` where
+    a head is whole 128-lane columns of ``[b, s, h d]``; else the
+    fewest heads that are, or the whole head axis (a block as wide as
+    the array always lowers)."""
+    whole = [
+        n for n in range(1, heads + 1)
+        if heads % n == 0 and n * dk % 128 == 0 and n * dv % 128 == 0
+    ]
+    few = [n for n in whole if n <= HEADS]
+    return max(few) if few else min(whole, default=heads)
+
+
+def _specs(b, h, hb, dk, dv, chunk_of):
+    """The block specs of both kernels over a grid of ``(b x h / hb,
+    chunks)``; ``chunk_of`` maps the grid's second index to the chunk
+    it works on.  ``tokens``: the caller's ``[b, s, h d]``, a column of
+    ``hb`` heads; the others are the kernels' own ``[b h, n, ..]``."""
+    columns = h // hb
+
+    def tokens(d):
+        return pl.BlockSpec(
+            (1, CHUNK, hb * d),
+            lambda i, j: (i // columns, chunk_of(j), i % columns),
+        )
+
+    def chunks(*tile):
+        return pl.BlockSpec(
+            (hb, 1) + tile, lambda i, j: (i, chunk_of(j), 0, 0)
+        )
+
+    return dict(
+        k=tokens(dk), v=tokens(dv), gate=chunks(1, CHUNK),
+        starts=chunks(dk, dv), t=chunks(CHUNK, CHUNK),
+        state=pl.BlockSpec((hb, dk, dv), lambda i, j: (i, 0, 0)),
+    )
+
+
+def _sizes(q, v, beta):
+    """``(b, h, heads a step, d_k, d_v)`` of the kernels' operands."""
+    b = q.shape[0]
+    h = beta.shape[0] // b
+    dk, dv = q.shape[2] // h, v.shape[2] // h
+    return b, h, _heads_a_step(h, dk, dv), dk, dv
 
 
 # (jitted: traced once for all of a model's layers and call sites)
 @jax.jit
-def _forward(q, k, v, gamma, beta):
-    """``(o, final state, chunk-start states [bh, n, d_k, d_v], T [bh,
-    n, C, C])`` of heads-leading operands: ``q, k [bh, s, d_k]``, ``v
-    [bh, s, d_v]``, ``gamma [bh, s, d_k]`` and ``beta [bh, n, 1, C]``
-    float32."""
-    bh, s, dk = q.shape
-    dv = v.shape[-1]
-    n = s // CHUNK
-    hb = _heads_a_step(bh)
-    exact = q.dtype == F32
-
-    def tokens(d):
-        return pl.BlockSpec((hb, CHUNK, d), lambda i, j: (i, j, 0))
-
-    gate = pl.BlockSpec((hb, 1, 1, CHUNK), lambda i, j: (i, j, 0, 0))
+def _forward(q, k, v, g, beta):
+    """``(o [b, s, h d_v], final state [b h, d_k, d_v], chunk-start
+    states [b h, n, d_k, d_v], T [b h, n, C, C])`` of ``q, k [b, s, h
+    d_k]``, ``v [b, s, h d_v]``, ``g [b, s, h d_k]`` and ``beta [b h,
+    n, 1, C]`` float32, ``s`` whole chunks."""
+    b, h, hb, dk, dv = sizes = _sizes(q, v, beta)
+    n = q.shape[1] // CHUNK
+    spec = _specs(*sizes, lambda j: j)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, exact=exact),
-        grid=(bh // hb, n),
-        in_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk), gate],
-        out_specs=[
-            tokens(dv),
-            pl.BlockSpec((hb, dk, dv), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((hb, 1, dk, dv), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((hb, 1, CHUNK, CHUNK), lambda i, j: (i, j, 0, 0)),
-        ],
+        functools.partial(_fwd_kernel, exact=q.dtype == F32),
+        grid=(b * h // hb, n),
+        in_specs=[spec["k"], spec["k"], spec["v"], spec["k"], spec["gate"]],
+        out_specs=[spec["v"], spec["state"], spec["starts"], spec["t"]],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, dk, dv), F32),
-            jax.ShapeDtypeStruct((bh, n, dk, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, n, CHUNK, CHUNK), q.dtype),
+            jax.ShapeDtypeStruct(v.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * h, dk, dv), F32),
+            jax.ShapeDtypeStruct((b * h, n, dk, dv), q.dtype),
+            jax.ShapeDtypeStruct((b * h, n, CHUNK, CHUNK), q.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), F32)],
         compiler_params=_params(),
         interpret=_interpret(),
         name="kda_fwd",
-    )(q, k, v, gamma, beta)
+    )(q, k, v, g, beta)
 
 
 @jax.jit
-def _backward(q, k, v, gamma, beta, starts, t, do, dfinal):
-    bh, s, dk = q.shape
-    dv = v.shape[-1]
-    n = s // CHUNK
-    hb = _heads_a_step(bh)
-    exact = q.dtype == F32
-
+def _backward(q, k, v, g, beta, starts, t, do, dfinal):
+    """-> ``(dq, dk, dv, dg, dbeta)`` in the layouts of
+    :func:`_forward`."""
+    b, h, hb, dk, dv = sizes = _sizes(q, v, beta)
+    n = q.shape[1] // CHUNK
     # the chunks in reverse
-    def tokens(d):
-        return pl.BlockSpec(
-            (hb, CHUNK, d), lambda i, j: (i, n - 1 - j, 0)
-        )
-
-    def chunks(*tile):
-        return pl.BlockSpec(
-            (hb, 1) + tile, lambda i, j: (i, n - 1 - j, 0, 0)
-        )
-
-    gate = chunks(1, CHUNK)
-    whole = pl.BlockSpec((hb, dk, dv), lambda i, j: (i, 0, 0))
+    spec = _specs(*sizes, lambda j: n - 1 - j)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, exact=exact),
-        grid=(bh // hb, n),
+        functools.partial(_bwd_kernel, exact=q.dtype == F32),
+        grid=(b * h // hb, n),
         in_specs=[
-            tokens(dk), tokens(dk), tokens(dv), tokens(dk), gate,
-            chunks(dk, dv), chunks(CHUNK, CHUNK), tokens(dv), whole,
+            spec["k"], spec["k"], spec["v"], spec["k"], spec["gate"],
+            spec["starts"], spec["t"], spec["v"], spec["state"],
         ],
-        out_specs=[tokens(dk), tokens(dk), tokens(dv), tokens(dk), gate],
+        out_specs=[
+            spec["k"], spec["k"], spec["v"], spec["k"], spec["gate"]
+        ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct(gamma.shape, F32),
+            jax.ShapeDtypeStruct(g.shape, F32),
             jax.ShapeDtypeStruct(beta.shape, F32),
         ],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), F32)],
         compiler_params=_params(),
         interpret=_interpret(),
         name="kda_bwd",
-    )(q, k, v, gamma, beta, starts, t, do, dfinal)
+    )(q, k, v, g, beta, starts, t, do, dfinal)
+
+
+def _tokens(x, pad):
+    """``[b, s, h, d]`` as the kernels take it, ``[b, s + pad, h d]``:
+    the tail of the last chunk padded with tokens that neither decay
+    nor write (``g = 0``, ``beta = 0``)."""
+    b, s = x.shape[:2]
+    return jnp.pad(x.reshape(b, s, -1), ((0, 0), (0, pad), (0, 0)))
 
 
 def _operands(q, k, v, g, beta):
-    """``[b, s, h, ..]`` as the kernels take them: heads lead the
-    sequence, the tail of the last chunk padded with tokens that
-    neither decay nor write (``g = 0``, ``beta = 0``), ``g`` summed up
-    inside each chunk a channel (``[b h, s, d_k]`` float32), ``beta``
-    as ``[b h, n, 1, C]`` float32 rows."""
+    """The caller's arrays as the kernels take them: ``q``, ``k``,
+    ``v`` and ``g`` (float32) a row a token, ``beta [b, s, h]`` as
+    ``[b h, n, 1, C]`` float32 rows."""
     pad = -g.shape[1] % CHUNK
-    gamma = _heads_lead(g.astype(F32), pad)
-    bh, s, dk = gamma.shape
-    gamma = jnp.cumsum(
-        gamma.reshape(bh, s // CHUNK, CHUNK, dk), axis=2
-    ).reshape(bh, s, dk)
-    beta = _heads_lead(beta.astype(F32), pad)
+    rows = _heads_lead(beta.astype(F32), pad)
     return (
-        _heads_lead(q, pad), _heads_lead(k, pad), _heads_lead(v, pad),
-        gamma, beta.reshape(bh, -1, 1, CHUNK),
+        _tokens(q, pad), _tokens(k, pad), _tokens(v, pad),
+        _tokens(g.astype(F32), pad),
+        rows.reshape(rows.shape[0], -1, 1, CHUNK),
     )
 
 
@@ -455,23 +513,24 @@ def kda_rule(q, k, v, g, beta):
 
 def _rule_fwd(q, k, v, g, beta):
     b, s, h, dk = q.shape
-    given = _barrier(q, k, v, g, beta)
-    o, final, starts, t = _forward(*_operands(*given))
-    o = jnp.moveaxis(o.reshape(b, h, -1, o.shape[-1]), 1, 2)[:, :s]
-    return (*_barrier(o), final.reshape(b, h, dk, -1)), (given, starts, t)
+    o, final, starts, t = _forward(*_operands(q, k, v, g, beta))
+    return (
+        o[:, :s].reshape(v.shape), final.reshape(b, h, dk, -1)
+    ), (q, k, v, g, beta, starts, t)
 
 
 def _rule_bwd(kept, cotangents):
-    given, starts, t = kept
+    *given, starts, t = kept
     do, dfinal = cotangents
-    # behind a barrier with the cotangent, or the compiler shares the
-    # forward's heads-leading copies and they live until here
-    *given, do = _barrier(*given, do)
-    operands, back = jax.vjp(_operands, *given)
-    do = _heads_lead(do, operands[0].shape[1] - do.shape[1])
-    dfinal = dfinal.reshape((-1,) + dfinal.shape[2:])
-    return _barrier(
-        *back(tuple(_backward(*operands, starts, t, do, dfinal)))
+    b, s, h = given[-1].shape
+    dq, dk, dv, dg, dbeta = _backward(
+        *_operands(*given), starts, t, _tokens(do, -s % CHUNK),
+        dfinal.reshape((-1,) + dfinal.shape[2:]),
+    )
+    dbeta = jnp.moveaxis(dbeta.reshape(b, h, -1), 1, 2)
+    return tuple(
+        d[:, :s].reshape(x.shape).astype(x.dtype)
+        for d, x in zip((dq, dk, dv, dg, dbeta), given)
     )
 
 

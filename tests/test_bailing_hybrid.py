@@ -21,6 +21,7 @@ jax = pytest.importorskip("jax")
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+from jax.experimental import pallas as pl  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -181,12 +182,62 @@ def test_one_decay_a_head_is_the_scalar_rule():
         assert relative(a, w) < 5e-5, name
 
 
+def tile_sums(g, reverse):
+    """``kda._running_sum``, the kernels' own sum down a chunk's
+    tokens, on each ``[CHUNK, d]`` tile of ``g [s, d]`` float32."""
+    def kernel(g_ref, out_ref):
+        out_ref[...] = kda._running_sum(g_ref[...], reverse=reverse)
+
+    block = pl.BlockSpec((kda.CHUNK, g.shape[1]), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel, grid=(g.shape[0] // kda.CHUNK,), in_specs=[block],
+        out_specs=block, out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
+        interpret=True,
+    )(g)
+
+
+@pytest.mark.parametrize("reverse", [False, True], ids=["Gamma", "dg"])
+@pytest.mark.parametrize("s", [200, 256], ids=["tail", "whole"])
+@pytest.mark.parametrize("at_bound", [False, True], ids=["random", "at_-5"])
+def test_the_kernels_running_sum_is_float64s(at_bound, s, reverse):
+    """``Gamma = L g`` and ``d g = L^T d Gamma`` as the kernels make
+    them (one code for bf16 and float32 callers: float32 adds) against
+    numpy's float64 sums a chunk: no further off than twice float32
+    ``jnp.cumsum`` (PR 59's program), and exact where every ``g`` is
+    -5.  The tail is padded as ``kda._tokens`` pads it, with zeros."""
+    d = 32
+    rng = np.random.default_rng(60 + s)
+    g = np.full((s, d), kda.LOWER) if at_bound else rng.uniform(
+        kda.LOWER, 0.0, (s, d)
+    )
+    g = np.pad(g.astype(np.float32), ((0, -s % kda.CHUNK), (0, 0)))
+    chunks = g.reshape(-1, kda.CHUNK, d)
+    if reverse:
+        chunks = chunks[:, ::-1]
+
+    def back(sums):
+        return np.asarray(sums[:, ::-1] if reverse else sums).reshape(g.shape)
+
+    want = back(np.cumsum(chunks.astype(np.float64), axis=1))
+    xla = back(jnp.cumsum(jnp.asarray(chunks), axis=1))
+    got = np.asarray(tile_sums(jnp.asarray(g), reverse))
+    assert got.dtype == np.float32
+    error = np.abs(got - want).max()
+    if at_bound:
+        assert error == 0.0 and np.abs(want).max() == 640.0
+    else:
+        assert error <= max(
+            2 * np.abs(xla - want).max(),
+            np.spacing(np.float32(np.abs(want).max())),
+        )
+
+
 def test_what_the_rule_shares_with_the_scalar_rule_is_imported():
     from dlrover_tpu.ops import gated_delta_rule as gdr
 
     for name in (
-        "_inverse_unit_lower", "_solve_bwd", "_heads_lead", "_barrier",
-        "_dot", "_lanes", "_as_row",
+        "_inverse_unit_lower", "_solve_bwd", "_heads_lead", "_dot",
+        "_lanes", "_as_row",
     ):
         assert getattr(kda, name) is getattr(gdr, name), name
     assert kda.CHUNK == gdr.CHUNK and kda.CHUNK % kda.SUB == 0

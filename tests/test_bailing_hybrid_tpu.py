@@ -33,15 +33,48 @@ RULE = dict(batch=1, seq=8192, heads=32, d=128)
 
 
 def _rule_operands(one_chip, dtype):
+    """As ``KdaAttention`` holds them: its projections' ``[b, s, h d]``
+    rows.  (A ``[b, s, h, d]`` array AT a jit boundary lies tiled over
+    ``(h, d)``, and the view of it a row a token is a relayout that
+    is the boundary's, not the rule's.)"""
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     b, t, h, d = (RULE[k] for k in ("batch", "seq", "heads", "d"))
-    tokens = s((b, t, h, d), dtype)
+    tokens = s((b, t, h * d), dtype)
     return (
-        tokens, tokens, tokens, s((b, t, h, d), jnp.float32),
+        tokens, tokens, tokens, s((b, t, h * d), jnp.float32),
         s((b, t, h), jnp.float32),
     )
+
+
+def _rule(q, k, v, g, beta):
+    """The rule as ``KdaAttention`` calls it: a head a 128-lane column
+    of the rows, ``o`` back into rows for the norm and ``o_proj``."""
+    def heads(x):
+        return x.reshape(x.shape[:2] + (RULE["heads"], RULE["d"]))
+
+    o, state = kda.kda_rule(heads(q), heads(k), heads(v), heads(g), beta)
+    return o.reshape(q.shape), state
+
+
+def _moved_outside_the_kernels(compiled):
+    """The compiled program's instructions that move or sum a whole
+    token array in XLA, whatever its view: a copy, a transpose, a
+    reshape that is no bitcast, a fusion or a ``reduce-window`` of
+    ``s x h x d`` elements or more."""
+    whole = RULE["batch"] * RULE["seq"] * RULE["heads"] * RULE["d"]
+    found = []
+    for line in compiled.as_text().splitlines():
+        if " reduce-window(" in line:
+            found.append(line.strip()[:120])
+        hit = re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+            r"(copy|copy-start|transpose|reshape|fusion)\(", line,
+        )
+        if hit and np.prod([int(n) for n in hit[1].split(",")]) >= whole:
+            found.append(line.strip()[:120])
+    return found
 
 
 @pytest.mark.parametrize(
@@ -53,19 +86,25 @@ def test_the_channel_wise_rule_compiles_at_published_sizes(
     """The rule at (1, 8192, 32, 128 | 128) with a log-decay a
     channel, forward and backward, for the described chip: the forward
     is the ``kda_fwd`` kernel and no ``while``, the gradient adds
-    ``kda_bwd``, the row-of-a-block gathers of the levels' reference
-    rows are legal Mosaic reshapes, and both stay inside the scoped
-    VMEM (no ``vmem_limit_bytes`` is asked for).  bf16 is the cell's;
+    ``kda_bwd``, the column blocks of ``[b, s, h d]``, the running
+    sum's sublane rolls and the row-of-a-block gathers of the levels'
+    reference rows are legal Mosaic, and both stay inside the scoped
+    VMEM (no ``vmem_limit_bytes`` is asked for).  Since PR 60 the
+    kernels make their own operands: NOTHING of a token array's size
+    is copied, transposed or summed outside them, forward or backward
+    (PR 59's program: 11 copies and a ``reduce-window`` fusion in the
+    forward, 24 and three in the gradient).  bf16 is the cell's;
     float32 operands (every matmul at ``HIGHEST``) are the tests'
     exact path."""
     operands = _rule_operands(one_chip, dtype)
-    forward = jax.jit(kda.kda_rule).lower(*operands).compile()
+    forward = jax.jit(_rule).lower(*operands).compile()
     out, state = forward.out_info
-    assert out.shape == (1, 8192, 32, 128) and out.dtype == dtype
+    assert out.shape == (1, 8192, 32 * 128) and out.dtype == dtype
     assert state.shape == (1, 32, 128, 128) and state.dtype == jnp.float32
     assert _calls(forward, "kda_fwd") == _kernels(forward) == 1
     text = forward.as_text()
     assert " while(" not in text
+    assert _moved_outside_the_kernels(forward) == []
     hlo = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
     chunks = f"{hlo}[32,{8192 // kda.CHUNK}"
     # the states each chunk starts from and its inverse, for the
@@ -73,7 +112,8 @@ def test_the_channel_wise_rule_compiles_at_published_sizes(
     assert f"{chunks},128,128]" in text
 
     def loss(*a):
-        return kda.kda_rule(*a)[0].astype(jnp.float32).sum()
+        # (a cotangent that is one scalar: no array of the test's own)
+        return _rule(*a)[0][0, -1, -1].astype(jnp.float32)
 
     backward = jax.jit(
         jax.grad(loss, argnums=(0, 1, 2, 3, 4))
@@ -82,8 +122,9 @@ def test_the_channel_wise_rule_compiles_at_published_sizes(
     assert _calls(backward, "kda_bwd") == 1
     assert _kernels(backward) == 2
     assert " while(" not in backward.as_text()
+    assert _moved_outside_the_kernels(backward) == []
     # the decay's gradient leaves the program a float32 a channel
-    assert backward.out_info[3].shape == (1, 8192, 32, 128)
+    assert backward.out_info[3].shape == (1, 8192, 32 * 128)
     assert backward.out_info[3].dtype == jnp.float32
     temp = backward.memory_analysis().temp_size_in_bytes
     print(f"kda backward temporaries {hlo}: {temp / 2**30:.3f} GiB")
