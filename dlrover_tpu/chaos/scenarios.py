@@ -107,7 +107,7 @@ def place_batch():
 # WRITES the entry + index the next incarnation hits.  Deliberately
 # on the MAIN thread: a second XLA-heavy thread fighting the
 # restore/state build measurably inflates the deserialize on small
-# hosts (resolve_step_async exists for wide ones).
+# hosts.
 def _abstract_examples():
     abs_params = jax.eval_shape(
         model.init_params, jax.random.PRNGKey(0)

@@ -463,29 +463,6 @@ def resolve_train_step(
     return aot_cache.resolve_step(step_fn, args, label=label).fn
 
 
-def resolve_train_step_async(
-    step_fn,
-    example_builder: Callable,
-    profiler,
-    label: str = "train_step",
-    restore_busy=None,
-) -> Callable:
-    """:func:`resolve_train_step` on a daemon thread — the recovery
-    posture.  ``example_builder`` is a zero-arg callable returning
-    ``(abstract_state, abstract_batch)`` (so even the ``eval_shape``
-    cost overlaps); the returned ``join()`` yields the step and books
-    the ``aot`` phase as the join wait — the seconds the critical
-    path actually stalled, which on a warm cache rounds to zero
-    because the deserialize hid behind the restore read and the
-    model/state build."""
-    return profiler.resolve_step_async(
-        step_fn,
-        example_builder,
-        label=label,
-        restore_busy=restore_busy,
-    )
-
-
 def _chip_metrics() -> str:
     """Memory stats of the devices THIS process owns, one line each —
     written into the metrics file so the agent's diagnosis collector

@@ -38,9 +38,8 @@ launch).
 """
 
 import os
-import threading
 import time
-from typing import Callable, Dict, Optional
+from typing import Dict, Optional
 
 from dlrover_tpu.common import env_utils
 from dlrover_tpu.common.compile_cache import (
@@ -204,87 +203,16 @@ class RecoveryProfiler:
             res, wall, entries_before, restore_busy
         )
 
-    def resolve_step_async(
-        self,
-        fn,
-        args_builder: Callable,
-        label: str = "train_step",
-        cache_dir: Optional[str] = None,
-        restore_busy=None,
-    ) -> Callable:
-        """:meth:`resolve_step` on a daemon thread, so the
-        deserialize (HIT) or trace+compile (MISS) — and the abstract
-        example build itself — overlap the async restore read AND the
-        caller's own model/optimizer/state construction::
-
-            join = prof.resolve_step_async(
-                step_fn, lambda: (abstract_state, abstract_batch),
-                restore_busy=lambda: not load_handle.done())
-            ... build model, join the restore, build the state ...
-            step = join()   # waits only for what did not overlap
-
-        The ``aot`` budget phase books the JOIN WAIT — the seconds
-        the critical path actually stalled, which is what the
-        sub-second cycle is made of — while the ``aot_cache`` event
-        keeps the thread-measured ``load_s``/``trace_s``/``save_s``
-        so the true deserialize cost stays visible."""
-        from dlrover_tpu.common import aot_cache as _aot
-
-        entries_before = cache_entries(self.cache_dir)
-        holder: Dict[str, object] = {}
-        t0 = time.perf_counter()
-
-        def run():
-            try:
-                # the builder is passed THROUGH (not called): on the
-                # warm fast path the label index resolves without
-                # ever building the abstract examples
-                holder["res"] = _aot.resolve_step(
-                    fn, args_builder, label=label, cache_dir=cache_dir
-                )
-            except Exception as e:  # noqa: BLE001 - never crash
-                holder["res"] = _aot.Resolution(
-                    fn=fn, source="off", deferred=True,
-                    reason=f"async resolve failed: {e}",
-                )
-
-        thread = threading.Thread(
-            target=run, daemon=True, name="aot-resolve"
-        )
-        thread.start()
-
-        def join(timeout: Optional[float] = None):
-            w0 = time.perf_counter()
-            thread.join(timeout=timeout)
-            wait = time.perf_counter() - w0
-            res = holder.get("res")
-            if res is None:  # timeout: trace inline, never wedge
-                res = _aot.Resolution(
-                    fn=fn, source="off", deferred=True,
-                    reason="async resolve timed out",
-                )
-            wall = time.perf_counter() - t0
-            return self._book_resolution(
-                res, wall, entries_before, restore_busy,
-                aot_phase_s=wait,
-            )
-
-        return join
-
     def _book_resolution(
         self,
         res,
         wall: float,
         entries_before: int,
         restore_busy=None,
-        aot_phase_s: Optional[float] = None,
     ):
         """Book an :class:`aot_cache.Resolution` into the budget
         phases and emit the ``aot_cache`` + ``compile_cache``
-        witnesses; returns the callable the training loop should use.
-        ``aot_phase_s`` overrides the booked ``aot`` phase (the async
-        path passes the join wait — the critical-path cost — while
-        the event keeps the thread-measured times)."""
+        witnesses; returns the callable the training loop should use."""
         from dlrover_tpu.common import aot_cache as _aot
 
         aot_n = _aot.aot_entries(res.dir) if res.dir else 0
@@ -305,8 +233,6 @@ class RecoveryProfiler:
             "restart_count": self.restart_count,
             "node_rank": self.node_rank,
         }
-        if aot_phase_s is not None:
-            event["wait_s"] = round(aot_phase_s, 4)
         for k, v in res.extra.items():
             event[k] = round(v, 4) if isinstance(v, float) else v
         if res.reason:
@@ -319,10 +245,7 @@ class RecoveryProfiler:
         if res.source == "aot":
             self.aot_hit = True
             self.cache_hit = True
-            self.record(
-                "aot",
-                res.load_s if aot_phase_s is None else aot_phase_s,
-            )
+            self.record("aot", res.load_s)
             # no tracing happened anywhere: the retrace phase the
             # invariants/budget sum over is genuinely zero
             self.record("retrace", 0.0)
@@ -356,10 +279,7 @@ class RecoveryProfiler:
         # off / failed resolve: keep today's semantics — the first
         # call traces under the measured_retrace bracket (still books
         # the failed load attempt so the budget stays complete)
-        self.record(
-            "aot",
-            res.load_s if aot_phase_s is None else aot_phase_s,
-        )
+        self.record("aot", res.load_s)
         emit_event("aot_cache", **event)
         inner = res.fn
         done = [False]

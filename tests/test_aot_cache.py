@@ -484,36 +484,6 @@ def test_resolve_train_step_helper_without_profiler(tmp_path):
         os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 
-def test_resolve_step_async_join(tmp_path, monkeypatch):
-    """The async resolve (wide-host posture): the join books the
-    wait as the aot phase and returns a callable equal to the sync
-    result."""
-    from dlrover_tpu.trainer.recovery import RecoveryProfiler
-
-    monkeypatch.setenv(
-        "JAX_COMPILATION_CACHE_DIR", str(tmp_path)
-    )
-    monkeypatch.setenv(
-        "DLROVER_EVENT_LOG", str(tmp_path / "ev.jsonl")
-    )
-    step_fn, state, batch = _fresh()
-    p0 = RecoveryProfiler(restart_count=0, node_rank=0)
-    join = p0.resolve_step_async(
-        step_fn, lambda: (state, batch)
-    )
-    step0 = join()
-    s, m = step0(state, batch)
-    step_fn1, state1, batch1 = _fresh()
-    p1 = RecoveryProfiler(restart_count=1, node_rank=0)
-    join = p1.resolve_step_async(
-        step_fn1, lambda: (state1, batch1)
-    )
-    step1 = join()
-    assert p1.aot_hit is True
-    s1, m1 = step1(state1, batch1)
-    assert float(m1["loss"]) == float(m["loss"])
-
-
 def test_code_change_invalidates_entry(tmp_path):
     """Same label, same avals, DIFFERENT code: the fingerprint half
     of the key must refuse the stale executable — a persistent cache

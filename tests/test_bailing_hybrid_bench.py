@@ -2,13 +2,10 @@
 file is one worker's): group-limited routing in ``parallel/moe.py``
 (no token's choices pass ``topk_group`` groups; one group is the
 ungrouped router; the shares of all the chips, a group a host, add up
-to the uncut layer, the shared expert counted once); the other
-families' programs are what they were; the counters on the
+to the uncut layer, the shared expert counted once); the counters on the
 ``train_step`` event; the cut configuration's arithmetic, the
 benchmark's entries and their readers; the harness's rehearsal."""
 
-import hashlib
-import importlib
 import json
 import os
 import subprocess
@@ -76,19 +73,21 @@ def test_the_shares_of_all_the_chips_add_up_to_the_uncut_layer():
     each computes of the routed sum, with the shared expert counted
     once, is the layer that holds all sixteen."""
     x, params = layer_params()
-    whole, stats = layer().apply({"params": params}, x)
+    whole, stats = jax.jit(layer().apply)({"params": params}, x)
     parts = []
     for lo in range(0, 16, 4):
         share = {**params, **{
             k: params[k][lo:lo + 4]
             for k in ("experts_w_gate", "experts_w_in", "experts_w_out")
         }}
-        out, said = layer(held=(lo, 4)).apply({"params": share}, x)
+        out, said = jax.jit(layer(held=(lo, 4)).apply)(
+            {"params": share}, x
+        )
         np.testing.assert_array_equal(said["counts"], stats["counts"])
         parts.append(out)
     # the shared expert alone: a layer without it, taken from one with
     routed = {k: v for k, v in params.items() if not k.startswith("shared_")}
-    without, _ = layer(shared=0).apply({"params": routed}, x)
+    without, _ = jax.jit(layer(shared=0).apply)({"params": routed}, x)
     shared = whole - without
     total = sum(parts) - 3 * shared
     np.testing.assert_allclose(total, whole, rtol=2e-5, atol=2e-6)
@@ -165,44 +164,6 @@ def test_groups_that_cannot_hold_the_top_k_are_refused(groups, match):
     x, _ = layer_params()
     with pytest.raises(ValueError, match=match):
         layer(**groups).init(jax.random.PRNGKey(0), x)
-
-
-# -- the families that share the rule's helpers, the convolution, the held
-# -- layer and the kernels ------------------------------------------------------
-
-# sha256 (16 hex digits) of the lowered text of value and gradient of
-# five toy losses (tiny configurations, flash attention, remat on, 2 x
-# 64 tokens) on THE PARENT OF PR 59 (f68364b): the scalar rule's walk
-# and the convolutions (olmo_hybrid), the held layer and its router
-# (sarvam_mla, nemotron_h, motif) and the layer that holds all its
-# experts (olmoe).  PR 59 put the group mask between the router's
-# scores and its top-k and left every other path's program alone
-PINNED_AT_PR_58 = {
-    "olmo_hybrid": ("OlmoHybrid", "05248f4221689fa7"),
-    "sarvam_mla": ("SarvamMla", "1f4eb1e29d808c63"),
-    "nemotron_h": ("NemotronH", "fc50950923070677"),
-    "motif": ("Motif", "9a4b1e6bb3da9798"),
-    "olmoe": ("Olmoe", "6ca9f44ed37f52b2"),
-}
-
-
-@pytest.mark.parametrize("name", list(PINNED_AT_PR_58))
-def test_the_other_families_lower_to_the_text_they_did(name):
-    cls, pinned = PINNED_AT_PR_58[name]
-    module = importlib.import_module(f"dlrover_tpu.models.{name}")
-    model = getattr(module, cls)(getattr(module, cls + "Config").tiny(
-        attention_impl="flash", remat=True
-    ))
-    params = jax.eval_shape(
-        lambda key: model.init_params(key, seq_len=64),
-        jax.random.PRNGKey(0),
-    )
-    batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32) for k in "xy"}
-    text = jax.jit(jax.value_and_grad(
-        getattr(module, f"make_{name}_loss")(model, num_chunks=4),
-        has_aux=True,
-    )).lower(params, batch).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == pinned
 
 
 # -- counters, the cut, the benchmark's entries -------------------------------

@@ -2,13 +2,11 @@
 the repo's blocks against the plain float32 reference
 (``benchmarks/models/mimo_v2_reference.py``): loss, logits and every
 leaf's gradient, the sinks' included; the flash kernels with a sink
-against the plain form; ``sink=None`` leaves the other families'
-programs what they were; the expert shares (no shared expert) and the
+against the plain form; the expert shares (no shared expert) and the
 head shares each add up to the whole; a token with no held expert gets
 exactly nothing; the bias's rule in the step; the counters, the cut
 configuration's arithmetic and the harness's rehearsal."""
 
-import hashlib
 import os
 import re
 import subprocess
@@ -28,16 +26,6 @@ import loader  # noqa: E402  (the benchmark's own)
 
 from dlrover_tpu.common.aot_cache import SOURCE_PACKAGES  # noqa: E402
 from dlrover_tpu.models import layers  # noqa: E402
-from dlrover_tpu.models.laguna import (  # noqa: E402
-    Laguna,
-    LagunaConfig,
-    make_laguna_loss,
-)
-from dlrover_tpu.models.sarvam_mla import (  # noqa: E402
-    SarvamMla,
-    SarvamMlaConfig,
-    make_sarvam_mla_loss,
-)
 from dlrover_tpu.ops.attention import xla_window_attention  # noqa: E402
 from dlrover_tpu.ops.flash_attention import (  # noqa: E402
     block_schedule,
@@ -259,49 +247,6 @@ def test_a_sink_is_refused_where_no_form_takes_it():
         flash_attention(q, k, v, return_lse=True)
     out, mass = layers.attention("xla", q, k, v, window=32, sink=sink)
     assert out.shape == (2, 128, 2, 16) and mass.shape == (2,)
-
-
-# sha256 (16 hex digits) of the lowered text of value and gradient of
-# the toy ``laguna`` and ``sarvam_mla`` losses (tiny configurations,
-# remat on, 2 x 64 tokens) AS PR 58 LEFT THEM.  Until PR 52 the values
-# were those of the commit BEFORE the kernels, the plain forms and
-# ``layers.attention`` knew of a sink and before ``RopeRule`` moved to
-# ``layers.py`` (1566dc3: 809c18b186360c4e and 3d1a220435285726, which
-# PR 52's parent still lowered to).  Both texts hold the held experts'
-# layer, which PR 52 changed (the activation inside the grouped-matmul
-# kernels, the order of the combine's backward) while it touched
-# nothing of attention: so the values were PR 52's tree's
-# (4a1941cd0f8f9283 and d5e4f7a670f8b842), no longer 1566dc3; and PR 58
-# changed that layer's index work alone (``parallel/moe.py``: no sort,
-# scatter or scalar gather where a chip holds a range), so they are PR
-# 58's tree's now
-WITHOUT_A_SINK_AT_PR_58 = {
-    ("laguna", "xla"): "4b80b17fe2120d48",
-    ("sarvam_mla", "flash"): "1f4eb1e29d808c63",
-}
-FAMILIES = {
-    "laguna": (Laguna, LagunaConfig, make_laguna_loss),
-    "sarvam_mla": (SarvamMla, SarvamMlaConfig, make_sarvam_mla_loss),
-}
-
-
-@pytest.mark.parametrize("family, attention", list(WITHOUT_A_SINK_AT_PR_58))
-def test_without_a_sink_the_other_families_lower_to_the_text_they_did(
-    family, attention
-):
-    module, config, make = FAMILIES[family]
-    model = module(config.tiny(attention_impl=attention, remat=True))
-    params = jax.eval_shape(
-        lambda key: model.init_params(key, seq_len=64),
-        jax.random.PRNGKey(0),
-    )
-    batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32) for k in "xy"}
-    text = jax.jit(jax.value_and_grad(
-        make(model, num_chunks=4), has_aux=True
-    )).lower(params, batch).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
-        WITHOUT_A_SINK_AT_PR_58[family, attention]
-    )
 
 
 # -- the shares ---------------------------------------------------------------

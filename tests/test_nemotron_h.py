@@ -8,12 +8,12 @@ shares of all chips add up to the whole layer, no gate matrix in the
 tree, no row past the used tiles read) and against the gated layer's
 lowering, which is what it was; an expert width that is no whole
 number of lane tiles through the grouped-matmul kernels; the router's
-bias and the ``ssm.*`` counters through the train step."""
+bias and the ``ssm.*`` counters through the train step.  What the
+benchmark has of the family is ``test_nemotron_h_bench.py``, the cell's
+offline compile ``test_nemotron_h_tpu.py``."""
 
 import functools
-import json
 import os
-import subprocess
 import sys
 
 import pytest
@@ -31,7 +31,6 @@ from conftest import (  # noqa: E402
     fill_inside_an_expert, fill_past, jax_internal, primitives_under,
 )
 
-from dlrover_tpu.models.gpt import count_params  # noqa: E402
 from dlrover_tpu.models.nemotron_h import (  # noqa: E402
     NemotronH,
     NemotronHConfig,
@@ -42,11 +41,8 @@ from dlrover_tpu.ops.ssd import ssd_scan  # noqa: E402
 from dlrover_tpu.optim import adamw_bf16  # noqa: E402
 from dlrover_tpu.parallel import moe  # noqa: E402
 from dlrover_tpu.parallel.moe import DroplessMoE, dropless_moe  # noqa: E402
-from dlrover_tpu.telemetry.events import read_events  # noqa: E402
-from dlrover_tpu.telemetry.schema import validate_event  # noqa: E402
 from dlrover_tpu.trainer.elastic_trainer import (  # noqa: E402
     STATE_UPDATES,
-    ElasticTrainer,
     TrainState,
     make_train_step,
 )
@@ -69,11 +65,16 @@ COUNTERS = {
 }
 
 
-def toy(dtype=jnp.float32, seq=48, **kw):
-    """Three chunks of 16 tokens through ``M E * E M``; 4 of 16
-    experts held."""
-    model = NemotronH(NemotronHConfig.tiny(dtype=dtype, **kw))
-    params = model.init_params(jax.random.PRNGKey(7), seq_len=seq)
+@functools.cache
+def toy_weights(seq):
+    """The toy's weights, made ONCE a module: the initialisation reads
+    neither the attention, nor remat, nor the scan's chunk, nor the
+    compute dtype."""
+    model = NemotronH(NemotronHConfig.tiny())
+    # (jitted: an eager init runs the whole model op by op)
+    params = jax.jit(lambda key: model.init_params(key, seq_len=seq))(
+        jax.random.PRNGKey(7)
+    )
     # weights at 0.02 leave every router near 0.5 and every state near
     # 0: scale the matrices up so that routing is decided and the
     # recurrence matters; the biases apart, so that score + bias picks
@@ -85,6 +86,15 @@ def toy(dtype=jnp.float32, seq=48, **kw):
         params[f"block_{i}"]["moe"]["select_bias"] = 0.2 * jax.random.normal(
             jax.random.PRNGKey(20 + n), (16,)
         )
+    return params
+
+
+def toy(dtype=jnp.float32, seq=48, **kw):
+    """Three chunks of 16 tokens through ``M E * E M``; 4 of 16
+    experts held."""
+    model = NemotronH(NemotronHConfig.tiny(dtype=dtype, **kw))
+    # (buffers of its own: a step donates its state)
+    params = jax.tree.map(jnp.copy, toy_weights(seq))
     tokens = jax.random.randint(jax.random.PRNGKey(8), (2, seq + 1), 0, 256)
     return model, params, {"x": tokens[:, :-1], "y": tokens[:, 1:]}
 
@@ -110,12 +120,16 @@ def relative(got, want):
 @pytest.mark.parametrize("attention", ["xla", "flash"])
 def test_float32_loss_logits_and_counters_equal_the_reference(attention):
     model, params, batch = toy(attention_impl=attention)
-    loss, aux = make_nemotron_h_loss(model, num_chunks=4)(params, batch)
+    loss, aux = jax.jit(make_nemotron_h_loss(model, num_chunks=4))(
+        params, batch
+    )
     want, said = reference.loss_and_said(
         params, batch["x"], batch["y"], CFG
     )
     assert abs(float(loss) - float(want)) < 1e-5
-    logits = model.apply({"params": params}, batch["x"])
+    logits = jax.jit(lambda p, x: model.apply({"params": p}, x))(
+        params, batch["x"]
+    )
     np.testing.assert_allclose(
         logits, jnp.stack(reference.forward(params, batch["x"], CFG)),
         rtol=0, atol=2e-4,
@@ -141,10 +155,10 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
     bias takes no gradient on either side."""
     model, params, batch = toy(remat=True, attention_impl="flash")
     loss_fn = make_nemotron_h_loss(model, num_chunks=4)
-    got = jax.grad(lambda p: loss_fn(p, batch)[0])(params)
-    want = jax.grad(lambda p: reference.loss_and_said(
+    got = jax.jit(jax.grad(lambda p: loss_fn(p, batch)[0]))(params)
+    want = jax.jit(jax.grad(lambda p: reference.loss_and_said(
         p, batch["x"], batch["y"], CFG
-    )[0])(params)
+    )[0]))(params)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
     # a norm a layer, 8 leaves a state-space mixer, 6 an expert
@@ -161,7 +175,9 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
 
 def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
     model, params, batch = toy(dtype=jnp.bfloat16)
-    loss, _ = make_nemotron_h_loss(model, num_chunks=4)(params, batch)
+    loss, _ = jax.jit(make_nemotron_h_loss(model, num_chunks=4))(
+        params, batch
+    )
     want = reference.loss(params, batch["x"], batch["y"], CFG)
     assert abs(float(loss) - want) < 0.05
 
@@ -171,38 +187,11 @@ def test_the_whole_model_is_causal():
     scan across three chunks, attention and the routing."""
     model, params, batch = toy()
     x = batch["x"]
-    base = model.apply({"params": params}, x)
-    moved = model.apply(
-        {"params": params}, x.at[:, 30].set((x[:, 30] + 1) % 256)
-    )
+    apply = jax.jit(lambda p, x: model.apply({"params": p}, x))
+    base = apply(params, x)
+    moved = apply(params, x.at[:, 30].set((x[:, 30] + 1) % 256))
     np.testing.assert_array_equal(base[:, :30], moved[:, :30])
     assert np.abs(np.asarray(base[:, 30:] - moved[:, 30:])).max() > 1e-3
-
-
-def test_published_sizes_give_the_issues_parameter_counts():
-    """The cut configuration's tree, by shape alone: 38.74 M a
-    state-space layer, 23.40 M an attention layer, 9.978 M an expert
-    (two matrices: NO gate), 1.246 B in all."""
-    cfg = loader.load_json(os.path.join(
-        REPO, "benchmarks", "configs", "nemotron_3_nano_30b_cut.json"
-    ))
-    model, _, _ = family.build(cfg)
-    params = jax.eval_shape(
-        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=128)
-    )
-    assert cfg["hybrid_override_pattern"] == "MEMEM*EMEMEM*EMEME"
-    assert count_params(params["block_0"]["ssm"]) == 38_742_208
-    assert count_params(params["block_5"]["attn"]) == 23_396_352
-    experts = params["block_1"]["moe"]
-    assert sorted(experts) == [
-        "experts_w_in", "experts_w_out", "router", "select_bias",
-        "shared_down", "shared_up",
-    ]
-    assert experts["experts_w_in"].shape == (8, 2688, 1856)
-    assert experts["experts_w_out"].shape == (8, 1856, 2688)
-    assert experts["router"].shape == (2688, 128)
-    assert count_params(params) == 1_245_843_840
-    assert params["block_0"]["ssm"]["A_log"].dtype == jnp.float32
 
 
 # -- the chunked scan against the recurrence ----------------------------------
@@ -390,11 +379,16 @@ def test_the_sixteen_shares_add_up_to_the_whole_layer():
     e = shares * held
     operands = layer_operands(t=80, e=e, seed=2)
     bias = 0.3 * jax.random.normal(jax.random.PRNGKey(5), (e,))
-    parts = [
-        share(operands, (lo, held), top_k, bias)
-        for lo in range(0, e, held)
-    ]
-    want = whole_layer(operands, top_k, bias)
+    # one share's function, compiled (a range's start is static) and
+    # called for each share
+    one_share = jax.jit(
+        lambda operands, bias, lo: share(
+            operands, (lo, held), top_k, bias
+        ),
+        static_argnums=2,
+    )
+    parts = [one_share(operands, bias, lo) for lo in range(0, e, held)]
+    want = jax.jit(whole_layer, static_argnums=1)(operands, top_k, bias)
     np.testing.assert_allclose(
         sum(out for out, _ in parts), want, atol=2e-5
     )
@@ -406,18 +400,18 @@ def test_the_sixteen_shares_add_up_to_the_whole_layer():
         expert_form="relu2",
     )
     x = operands[0][None]
-    variables = layer.init(jax.random.PRNGKey(0), x)
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
     p = variables["params"]
     assert sorted(p) == [
         "experts_w_in", "experts_w_out", "router", "select_bias",
         "shared_down", "shared_up",
     ]
-    out, _ = layer.apply(variables, x)
-    routed, _ = dropless_moe(
-        x[0], p["router"], None, p["experts_w_in"], p["experts_w_out"],
+    out, _ = jax.jit(layer.apply)(variables, x)
+    routed, _ = jax.jit(lambda x, p: dropless_moe(
+        x, p["router"], None, p["experts_w_in"], p["experts_w_out"],
         top_k, jnp.float32, held=(held, held), score="sigmoid",
         select_bias=p["select_bias"], renormalise=True, scale=2.5,
-    )
+    ))(x[0], p)
     shared = jnp.square(jax.nn.relu(
         x[0] @ p["shared_up"]["kernel"]
     )) @ p["shared_down"]["kernel"]
@@ -432,7 +426,7 @@ def test_an_ungated_expert_is_one_call_of_the_kernels_with_relu2_inside():
     over the padded rows) and the shared expert's two plain matmuls
     with ``relu(.) ** 2`` between; no gate matrix, no ``silu``.
     (The gated form's count is pinned where its families are tested:
-    ``tests/test_olmoe.py``, ``tests/test_sarvam_mla.py``; that the
+    ``tests/test_olmoe.py``, ``tests/test_moe_held.py``; that the
     call holds two products and not three,
     ``test_no_row_past_tiles_used_reaches_the_ungated_layer``.)"""
     model, params, batch = toy()
@@ -624,154 +618,3 @@ def test_the_layers_scopes_are_in_the_compiled_step(compiled_step):
         assert named, scope
         assert any("transpose(" in s for s in named), scope
     assert any("full_attn" in s and "/attn/" in s for s in stacks)
-
-
-def test_the_counters_ride_on_the_train_step_event(tmp_path, monkeypatch):
-    path = str(tmp_path / "events.jsonl")
-    monkeypatch.setenv("DLROVER_EVENT_LOG", path)
-    monkeypatch.setenv(
-        "DLROVER_METRICS_FILE", str(tmp_path / "metrics.json")
-    )
-    trainer = ElasticTrainer(4, 4, dp_size=1)
-    trainer.report_step({
-        "loss": jnp.float32(1.5), "grad_norm": jnp.float32(0.1),
-        "ssm.state_rms_max": jnp.float32(0.25),
-        "ssm.decay_mean": jnp.float32(0.875),
-        "moe.held_rows_share": jnp.float32(0.0625),
-    })
-    (event,) = [e for e in read_events(path) if e["type"] == "train_step"]
-    assert event["ssm.state_rms_max"] == 0.25
-    assert event["ssm.decay_mean"] == 0.875
-    assert event["moe.held_rows_share"] == 0.0625
-    assert not validate_event(event)
-
-
-# -- the benchmark's family and harness ---------------------------------------
-
-
-def test_the_compared_leaves_and_their_limits():
-    """Every leaf of the first and the last state-space layer, both
-    attention layers, every norm and router, the last expert layer's
-    held experts; each judged by its own kind's limit."""
-    cfg = loader.load_json(os.path.join(
-        REPO, "benchmarks", "configs", "nemotron_3_nano_30b_cut.json"
-    ))
-    model, _, _ = family.build(cfg)
-    params = jax.eval_shape(
-        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=128)
-    )
-    pick = family.compared(cfg)
-    names = [
-        jax.tree_util.keystr(path)
-        for path, _ in jax.tree_util.tree_leaves_with_path(params)
-    ]
-    picked = [name for name in names if pick(name)]
-    kinds = {}
-    for name in picked:
-        kinds.setdefault(family.kind_of(name), []).append(name)
-    assert len(kinds["decay_gradient_tolerance"]) == 2 * 3
-    assert len(kinds["routed_gradient_tolerance"]) == 8 + 2
-    # 2 x 5 further state-space leaves, 2 x 4 attention, 18 + 1 norms
-    assert len(kinds["gradient_tolerance"]) == 10 + 8 + 19
-    assert "['block_16']['ssm']['conv_bias']" in picked
-    assert "['block_2']['ssm']['A_log']" not in picked
-    assert "['block_17']['moe']['experts_w_out']" in picked
-    assert "['block_15']['moe']['experts_w_out']" not in picked
-    assert set(cfg["reference"]) >= set(kinds) | {
-        "loss_tolerance", "router_rms_tolerance", "bias_update_tolerance",
-        "state_rms_tolerance",
-    }
-
-
-@pytest.mark.parametrize("moved, inside", [
-    ({}, True),
-    ({"gradients": {"['block_0']['ssm']['D']": 0.9}}, False),
-    ({"gradients": {"['block_5']['attn']['q_proj']['kernel']": 0.3}}, False),
-    ({"gradients": {"['block_1']['moe']['router']": 0.3}}, True),
-    ({"gradients": {"['block_1']['moe']['router']": float("nan")}}, False),
-    ({"routers_rms": 0.46}, False),
-    ({"bias": 0.5}, False),
-    ({"state_rms": 0.2}, False),
-])
-def test_every_comparison_is_judged_by_its_own_limit(
-    monkeypatch, moved, inside
-):
-    found = {
-        "loss": 9.5, "bias": 0.01, "state_rms": 0.001, "routers_rms": 0.2,
-        "gradients": {
-            "['block_0']['ssm']['D']": 0.1,
-            "['block_5']['attn']['q_proj']['kernel']": 0.05,
-            "['block_1']['moe']['router']": 0.2,
-        },
-    }
-    found = {**found, **moved, "gradients": {
-        **found["gradients"], **moved.get("gradients", {})
-    }}
-    monkeypatch.setattr(family, "comparisons", lambda *a: found)
-    limits = {"reference": {
-        "gradient_tolerance": 0.2, "routed_gradient_tolerance": 0.5,
-        "decay_gradient_tolerance": 0.5, "router_rms_tolerance": 0.45,
-        "bias_update_tolerance": 0.15, "state_rms_tolerance": 0.05,
-    }}
-    loss = family.reference_loss(None, None, None, limits)
-    assert loss == (9.5 if inside else float("inf"))
-
-
-def test_the_flops_keys_count_what_the_family_requires():
-    """``flops.py`` reads GPT-2's key names: on the cut configuration
-    they give the FLOPs a token that ``nemotron_flops.py`` counts
-    layer by layer, to the FLOP."""
-    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-    import flops
-    import nemotron_flops
-
-    cfg = loader.load_json(os.path.join(
-        REPO, "benchmarks", "configs", "nemotron_3_nano_30b_cut.json"
-    ))
-    assert flops.train_flops_per_token(cfg, 8192) == (
-        nemotron_flops.train_flops_per_token(cfg, 8192)
-    ) == 4_022_501_376
-
-
-def test_the_harness_rehearses_the_family_on_the_cpu(tmp_path, checkout):
-    """``benchmarks/run.py`` end to end on the toy configuration:
-    ``tpurun`` -> the worker -> the ``has_aux`` step over three kinds
-    of mixer with its ``state_updates`` -> the reference's loss and
-    the family's own comparisons -> the readers; exit code 3 (a
-    rehearsal, never a result), ``correct`` true."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path))
-    env.pop("XLA_FLAGS", None)
-    done = subprocess.run(
-        # (from a checkout of its own: conftest.py, ROADMAP B7)
-        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
-         "--cells", os.path.join(
-             REPO, "benchmarks", "rehearsal_nemotron_h.json"),
-         "--workload", "toy_nemotron_h_steady", "--seed", "4700000007",
-         "--seconds", "1", "--trace", "1"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert done.returncode == 3, done.stdout[-3000:] + done.stderr[-3000:]
-    assert '"correct": true' in done.stdout
-    assert "ssm.state_rms_max" in done.stdout
-
-
-def test_the_benchmark_lists_the_cell_and_its_readers():
-    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    (cell,) = [
-        w for w in spec["workloads"] if w["name"] == "nemotron_steady_8k"
-    ]
-    assert cell == {**cell, "config": "nemotron_3_nano_30b_cut",
-                    "traffic": "steady_8k", "chips": 1}
-    mine = [
-        m["name"] for m in spec["per_layer"]
-        if m.get("workloads") == ["nemotron_steady_8k"]
-    ]
-    assert mine == [
-        "ssm.scan_ms_per_step", "ssm.scan_roofline_pct",
-        "ssm.mix_ms_per_step", "ssm.proj_ms_per_step",
-        "ssm.state_rms_max", "moe.relu2_expert_roofline_pct",
-        "ssm.kernel_ms_per_step",
-    ]
-    for name in mine:
-        reader = loader.load_module("layer_metrics", name)
-        assert reader.NAME == name and reader.MOVES == "tokens_per_s"

@@ -3,6 +3,7 @@
 against its own claims, and the ``has_aux`` step that carries the
 routing counters to the ``train_step`` event."""
 
+import functools
 import os
 import sys
 
@@ -53,15 +54,27 @@ CFG = {
 }
 
 
-def toy(dtype=jnp.float32, **kw):
-    model = Olmoe(OlmoeConfig.tiny(dtype=dtype, **kw))
-    params = model.init_params(jax.random.PRNGKey(7), seq_len=64)
+@functools.cache
+def toy_weights():
+    """The toy's weights, made ONCE a module: the initialisation reads
+    neither remat nor the compute dtype."""
+    model = Olmoe(OlmoeConfig.tiny())
+    # (jitted: an eager init runs the whole model op by op)
+    params = jax.jit(lambda key: model.init_params(key, seq_len=64))(
+        jax.random.PRNGKey(7)
+    )
     # weights at 0.02 leave every router near uniform: scale them up
     # so that routing is decided and the experts' outputs matter
-    params = jax.tree_util.tree_map_with_path(
+    return jax.tree_util.tree_map_with_path(
         lambda path, x: x * (1.0 if "scale" in str(path[-1]) else 6.0),
         params,
     )
+
+
+def toy(dtype=jnp.float32, **kw):
+    model = Olmoe(OlmoeConfig.tiny(dtype=dtype, **kw))
+    # (buffers of its own: a step donates its state)
+    params = jax.tree.map(jnp.copy, toy_weights())
     tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 65), 0, 256)
     return model, params, {"x": tokens[:, :-1], "y": tokens[:, 1:]}
 
@@ -84,10 +97,14 @@ def relative(got, want):
 
 def test_float32_loss_and_logits_equal_the_reference():
     model, params, batch = toy()
-    loss, aux = make_olmoe_loss(model, num_chunks=4)(params, batch)
+    loss, aux = jax.jit(make_olmoe_loss(model, num_chunks=4))(
+        params, batch
+    )
     want = reference.loss(params, batch["x"], batch["y"], CFG)
     assert abs(float(loss) - want) < 1e-5
-    logits = model.apply({"params": params}, batch["x"])
+    logits = jax.jit(lambda p, x: model.apply({"params": p}, x))(
+        params, batch["x"]
+    )
     ref_logits, _ = reference.forward(params, batch["x"], CFG)
     np.testing.assert_allclose(
         logits, jnp.stack(ref_logits), rtol=0, atol=1e-4
@@ -103,10 +120,10 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
     largest entry."""
     model, params, batch = toy(remat=True)
     loss_fn = make_olmoe_loss(model, num_chunks=4)
-    got = jax.grad(lambda p: loss_fn(p, batch)[0])(params)
-    want = jax.grad(
+    got = jax.jit(jax.grad(lambda p: loss_fn(p, batch)[0]))(params)
+    want = jax.jit(jax.grad(
         lambda p: reference.loss_of(p, batch["x"], batch["y"], CFG)
-    )(params)
+    ))(params)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
     assert len(flat_got) == len(flat_want) == 2 * 12 + 3
@@ -125,7 +142,7 @@ def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
     load-balancing loss alone moves the loss by 2e-2)."""
     model, params, batch = toy(dtype=jnp.bfloat16)
     params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
-    loss, _ = make_olmoe_loss(model, num_chunks=4)(params, batch)
+    loss, _ = jax.jit(make_olmoe_loss(model, num_chunks=4))(params, batch)
     want = reference.loss(params, batch["x"], batch["y"], CFG)
     assert abs(float(loss) - want) < 1e-2
     assert 0.01 * 2.0 > 1e-2  # the lb term at its floor, E * k / E
@@ -386,7 +403,8 @@ def test_has_aux_puts_the_counters_into_the_metrics(grad_accum):
     loss_fn = make_olmoe_loss(model, num_chunks=4)
     assert loss_fn.has_aux  # read by make_train_step: no argument
     step = make_train_step(loss_fn, optimizer, grad_accum=grad_accum)
-    loss, aux = loss_fn(params, batch)  # (the step donates its state)
+    # (the step donates its state)
+    loss, aux = jax.jit(loss_fn)(params, batch)
     _, metrics = step(TrainState.create(params, optimizer), batch)
     assert set(metrics) == {
         "loss", "grad_norm", "moe.lb_loss", "moe.z_loss",

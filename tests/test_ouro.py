@@ -10,6 +10,7 @@ head against the unweighted one, whose lowering is pinned; the cut
 configuration's arithmetic; counters, scopes and the benchmark's
 readers; the harness's rehearsal."""
 
+import functools
 import hashlib
 import json
 import os
@@ -61,6 +62,25 @@ def toy_cfg(passes=3, **recipe):
     return cfg
 
 
+@functools.cache
+def toy_weights(seq, passes, param_dtype):
+    """The toy's weights, made ONCE a module: the initialisation reads
+    neither the attention, nor remat, nor the compute dtype."""
+    family = loader.load_module("models", "ouro")
+    model, _, _ = family.build(toy_cfg(passes, param_dtype=param_dtype))
+    # (jitted: an eager init runs the whole model op by op)
+    params = jax.jit(lambda key: model.init_params(key, seq_len=seq))(
+        jax.random.PRNGKey(7)
+    )
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * (1.0 if "scale" in str(path[-1]) else 4.0),
+        params,
+    )
+    if passes > 1:
+        params["exit_gate"]["bias"] = jnp.asarray([0.3])
+    return params
+
+
 def toy(seq=128, passes=3, **recipe):
     """``(family, cfg, model, loss_fn, params, batch)``: weights
     scaled up and the gate's bias off 0, so that the exits differ, the
@@ -68,13 +88,10 @@ def toy(seq=128, passes=3, **recipe):
     family = loader.load_module("models", "ouro")
     cfg = toy_cfg(passes, **recipe)
     model, _, loss_fn = family.build(cfg)
-    params = model.init_params(jax.random.PRNGKey(7), seq_len=seq)
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x * (1.0 if "scale" in str(path[-1]) else 4.0),
-        params,
+    # (buffers of its own: a step donates its state)
+    params = jax.tree.map(
+        jnp.copy, toy_weights(seq, passes, cfg["recipe"]["param_dtype"])
     )
-    if passes > 1:
-        params["exit_gate"]["bias"] = jnp.asarray([0.3])
     tokens = jax.random.randint(jax.random.PRNGKey(8), (2, seq + 1), 0, 512)
     return family, cfg, model, loss_fn, params, {
         "x": tokens[:, :-1], "y": tokens[:, 1:],
@@ -87,9 +104,9 @@ def relative(got, want):
 
 
 def reference_grads(params, batch, cfg):
-    return jax.grad(lambda p: reference.loss_and_aux(
+    return jax.jit(jax.grad(lambda p: reference.loss_and_aux(
         p, batch["x"], batch["y"], cfg
-    )[0])(params)
+    )[0]))(params)
 
 
 # -- (i) the family against the reference ---------------------------------------
@@ -98,7 +115,7 @@ def reference_grads(params, batch, cfg):
 @pytest.mark.parametrize("attention", ["xla", "flash"])
 def test_float32_loss_logits_and_counters_equal_the_reference(attention):
     _, cfg, model, loss_fn, params, batch = toy(attention=attention)
-    loss, aux = loss_fn(params, batch)
+    loss, aux = jax.jit(loss_fn)(params, batch)
     want, want_aux = reference.loss_and_aux(
         params, batch["x"], batch["y"], cfg
     )
@@ -108,7 +125,9 @@ def test_float32_loss_logits_and_counters_equal_the_reference(attention):
         assert float(aux[name]) == pytest.approx(
             float(want_aux[name]), abs=1e-5
         ), name
-    logits, gate_logits = model.apply({"params": params}, batch["x"])
+    logits, gate_logits = jax.jit(
+        lambda p, x: model.apply({"params": p}, x)
+    )(params, batch["x"])
     ref_logits, ref_p = reference.exit_logits(params, batch["x"], cfg)
     assert logits.shape == (3, 2, 128, 512) and gate_logits.shape == (
         2, 2, 128
@@ -129,7 +148,7 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
     leaf of ``jax.grad`` of the training loss, to 1e-4 of the leaf's
     largest entry."""
     _, cfg, _, loss_fn, params, batch = toy(attention="flash", remat=True)
-    got = jax.grad(lambda p: loss_fn(p, batch)[0])(params)
+    got = jax.jit(jax.grad(lambda p: loss_fn(p, batch)[0]))(params)
     want = reference_grads(params, batch, cfg)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
@@ -143,36 +162,12 @@ def test_float32_gradients_equal_the_reference_leaf_by_leaf():
         assert relative(g, w) < 1e-4, name
 
 
-@pytest.mark.parametrize("attention", ["flash", "xla"])
-def test_a_rematted_block_keeps_what_its_flash_backward_reads(
-    attention, remat_keeps_what_flash_reads,
-    remat_with_xla_attention_is_the_parents,
-):
-    """One forward kernel an application (two in the body of the scan
-    over the passes), none of them run again for the backward; loss
-    and gradients the parent policy's bit for bit.  With XLA attention
-    nothing is named and the program is the parent's.  The block takes
-    its policy where the other families take theirs."""
-
-    _, cfg, _, loss_fn, params, batch = toy(attention=attention, remat=True)
-
-    def loss(p):
-        return loss_fn(p, batch)[0]
-
-    if attention == "xla":
-        remat_with_xla_attention_is_the_parents(loss, params)
-    else:
-        remat_keeps_what_flash_reads(
-            loss, params, cfg["num_hidden_layers"]
-        )
-
-
 def test_bfloat16_loss_is_within_bf16_rounding_of_the_reference():
     _, cfg, _, loss_fn, params, batch = toy(
         param_dtype="bfloat16", compute_dtype="bfloat16",
     )
     params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
-    loss, _ = loss_fn(params, batch)
+    loss, _ = jax.jit(loss_fn)(params, batch)
     want = reference.loss(params, batch["x"], batch["y"], cfg)
     assert abs(float(loss) - want) < 2e-2
 
@@ -184,9 +179,9 @@ def untied_block_gradients(params, batch, cfg):
     """The reference with a copy of its own for every application:
     a list of ``L`` blocks' gradients, ``[R, ...]`` a leaf, pass
     ``t``'s at ``[t]``."""
-    return jax.grad(lambda copies: reference.loss_and_aux(
+    return jax.jit(jax.grad(lambda copies: reference.loss_and_aux(
         params, batch["x"], batch["y"], cfg, copies=copies
-    )[0])(reference.copies_of(params, cfg))
+    )[0]))(reference.copies_of(params, cfg))
 
 
 def drops_pass_one(loss_fn):
@@ -229,13 +224,15 @@ def test_a_blocks_gradient_is_the_sum_of_its_applications_gradients():
     same program with pass 1's output under ``stop_gradient`` reads
     the same loss and FAILS the sum by what pass 1 gave."""
     _, cfg, _, loss_fn, params, batch = toy(attention="flash", remat=True)
-    system = jax.grad(lambda p: loss_fn(p, batch)[0])(params)
+    value, system = jax.jit(
+        jax.value_and_grad(lambda p: loss_fn(p, batch)[0])
+    )(params)
     copies = untied_block_gradients(params, batch, cfg)
     faulty_fn = drops_pass_one(loss_fn)
-    assert float(faulty_fn(params, batch)[0]) == float(
-        loss_fn(params, batch)[0]
-    )
-    faulty = jax.grad(lambda p: faulty_fn(p, batch)[0])(params)
+    faulty_value, faulty = jax.jit(
+        jax.value_and_grad(lambda p: faulty_fn(p, batch)[0])
+    )(params)
+    assert float(faulty_value) == float(value)
     for layer, block in enumerate(("block_0", "block_1")):
         per_pass = [
             jax.tree.map(lambda g: g[t], copies[layer]) for t in range(3)
@@ -268,13 +265,13 @@ def test_one_pass_is_the_plain_sandwich_norm_model():
     head."""
     _, cfg, model, loss_fn, params, batch = toy(passes=1)
     assert "exit_gate" not in params
-    loss, aux = loss_fn(params, batch)
+    loss, aux = jax.jit(loss_fn)(params, batch)
     assert float(aux["loop.expected_exit"]) == 1.0
     assert float(aux["loop.exit_entropy"]) == 0.0
     assert float(aux["loop.nll_first"]) == float(aux["loop.nll_last"])
-    exits, gate_logits = model.apply(
-        {"params": params}, batch["x"], return_hidden=True
-    )
+    exits, gate_logits = jax.jit(lambda p, x: model.apply(
+        {"params": p}, x, return_hidden=True
+    ))(params, batch["x"])
     assert exits.shape == (1, 2, 128, 128) and gate_logits.shape == (
         0, 2, 128
     )
@@ -285,7 +282,7 @@ def test_one_pass_is_the_plain_sandwich_norm_model():
     assert float(loss) == pytest.approx(
         reference.loss(params, batch["x"], batch["y"], cfg), abs=1e-5
     )
-    got = jax.grad(lambda p: loss_fn(p, batch)[0])(params)
+    got = jax.jit(jax.grad(lambda p: loss_fn(p, batch)[0]))(params)
     want = reference_grads(params, batch, cfg)
     for (path, g), w in zip(
         jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)

@@ -1,8 +1,12 @@
-"""What a rematted block runs again for its backward, in the four
-families whose blocks sit behind ``prevent_cse=True``: with flash
-attention the one remat policy keeps the kernel's five residuals
-(``ops/flash_attention.py::RESIDUAL_NAMES``), so nothing that stands
-before the kernel only to feed it is in the rematted computation."""
+"""What a rematted block keeps and what it runs again for its
+backward, in the four families whose blocks sit behind
+``prevent_cse=True``: with flash attention the one remat policy keeps
+the kernel's five residuals
+(``ops/flash_attention.py::RESIDUAL_NAMES``), so the forward kernel is
+not run again and nothing that stands before it only to feed it is in
+the rematted computation; with XLA attention nothing is named and the
+program is the parent policy's.  A family's toy is built, traced under
+both policies and run ONCE for the tests that read it."""
 
 import os
 import sys
@@ -20,23 +24,72 @@ import loader  # noqa: E402  (the benchmark's own)
 from dlrover_tpu.models import layers  # noqa: E402
 
 # family -> (attention's matmuls that feed only the kernel, those whose
-# results something else's gradient reads and that are run again)
+# results something else's gradient reads and that are run again, the
+# flash forward kernels of the toy's gradient)
 FAMILIES = {
     "laguna": (
         {"q_proj", "k_proj", "v_proj"},
         # the gate and the output projection read the normed input
         # and the kernel's output
         {"g_proj", "o_proj"},
+        # one a layer: a full one, two sliding
+        3,
     ),
     "sarvam_mla": (
         {"q_proj", "kv_up"},
         # ``kv_up``'s gradient reads the normed latent
         {"kv_down", "o_proj"},
+        # one a layer (heads of 24 | 16)
+        3,
     ),
-    "ouro": ({"q_proj", "k_proj", "v_proj"}, {"o_proj"}),
-    # QK-norm's gradient reads the un-normed q and k
-    "olmo_hybrid": ({"v_proj"}, {"q_proj", "k_proj", "o_proj"}),
+    # one an application: two in the body of the scan over the passes
+    "ouro": ({"q_proj", "k_proj", "v_proj"}, {"o_proj"}, 2),
+    # QK-norm's gradient reads the un-normed q and k; one kernel in the
+    # period's one full-attention layer
+    "olmo_hybrid": ({"v_proj"}, {"q_proj", "k_proj", "o_proj"}, 1),
 }
+
+
+def toy_loss(family, attention):
+    """``(loss of the parameters alone, params)`` of the family's toy
+    configuration in float32 with remat, at 2 x 128 tokens."""
+    cfg = loader.load_json(
+        os.path.join(REPO, "benchmarks", "configs", f"toy_{family}.json")
+    )
+    cfg["recipe"].update(
+        param_dtype="float32", compute_dtype="float32",
+        attention=attention, remat=True,
+    )
+    model, _, loss_fn = loader.load_module("models", family).build(cfg)
+    # (jitted: an eager init runs the whole model op by op)
+    params = jax.jit(lambda key: model.init_params(key, seq_len=128))(
+        jax.random.PRNGKey(7)
+    )
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 129), 0, 256)
+    batch = {"x": tokens[:, :-1], "y": tokens[:, 1:]}
+    return lambda p: loss_fn(p, batch)[0], params
+
+
+@pytest.fixture(scope="module", params=list(FAMILIES))
+def traced(request):
+    """``(family, ours, parents)``: the family's toy with flash
+    attention; ``ours`` is the jaxpr of ``jax.value_and_grad`` of its
+    loss and the values of its jitted call under
+    ``models/layers.py::remat_policy``, ``parents`` the same under the
+    parent's policy (``None``: keep nothing)."""
+    loss, params = toy_loss(request.param, "flash")
+
+    def trace():
+        # a function of its own: a trace is cached by its function; ONE
+        # trace gives the jaxpr and the program that is run
+        traced = jax.jit(jax.value_and_grad(lambda p: loss(p))).trace(params)
+        return traced.jaxpr.jaxpr, traced.lower().compile()(params)
+
+    ours = trace()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(layers, "remat_policy", lambda name: None)
+        parents = trace()
+    return request.param, ours, parents
 
 
 def recomputed_attention_matmuls(jaxpr):
@@ -56,50 +109,48 @@ def recomputed_attention_matmuls(jaxpr):
     return found
 
 
-@pytest.mark.parametrize("family", list(FAMILIES))
 def test_a_rematted_block_runs_nothing_again_only_to_feed_its_flash_kernel(
-    family, monkeypatch
+    traced
 ):
-    """The tiny configuration with ``remat`` and flash attention: the
+    """The toy configuration with ``remat`` and flash attention: the
     rematted computation of the gradient's jaxpr holds no matmul of
     the projections that only the kernel reads (it holds them under
     the parent's ``policy=None``, the control), it still holds the
     ones a gradient reads, and loss and every gradient leaf are the
     parent policy's numbers bit for bit."""
-    dead, alive = FAMILIES[family]
-    cfg = loader.load_json(
-        os.path.join(REPO, "benchmarks", "configs", f"toy_{family}.json")
-    )
-    cfg["recipe"].update(
-        param_dtype="float32", compute_dtype="float32",
-        attention="flash", remat=True,
-    )
-    model, _, loss_fn = loader.load_module("models", family).build(cfg)
-    params = model.init_params(jax.random.PRNGKey(7), seq_len=128)
-    tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 129), 0, 256)
-    batch = {"x": tokens[:, :-1], "y": tokens[:, 1:]}
-
-    def loss(p):
-        return loss_fn(p, batch)[0]
-
-    grad = jax.value_and_grad(loss)
-    again = recomputed_attention_matmuls(jax.make_jaxpr(grad)(params).jaxpr)
+    family, (jaxpr, kept), (parents_jaxpr, parents) = traced
+    dead, alive, _ = FAMILIES[family]
+    again = recomputed_attention_matmuls(jaxpr)
     assert again, (
         "no recomputed attention matmul found: does jax "
         f"{jax.__version__} still head a rematted equation's name "
         "stack with 'rematted_computation'?"
     )
     assert not again & dead and alive <= again, again
-    kept = jax.jit(grad)(params)
-
-    monkeypatch.setattr(layers, "remat_policy", lambda name: None)
-    # another function: a trace is cached by its function
-    grad = jax.value_and_grad(lambda p: loss(p))
-    again = recomputed_attention_matmuls(jax.make_jaxpr(grad)(params).jaxpr)
+    again = recomputed_attention_matmuls(parents_jaxpr)
     assert dead | alive <= again, again
-    for ours, parents in zip(
-        jax.tree.leaves(kept), jax.tree.leaves(jax.jit(grad)(params))
+    for ours, theirs in zip(
+        jax.tree.leaves(kept), jax.tree.leaves(parents), strict=True
     ):
         np.testing.assert_array_equal(
-            np.asarray(ours), np.asarray(parents)
+            np.asarray(ours), np.asarray(theirs)
         )
+
+
+@pytest.mark.parametrize("attention", ["flash", "xla"])
+def test_a_rematted_block_keeps_what_its_flash_backward_reads(
+    attention, traced, remat_keeps_what_flash_reads,
+    remat_with_xla_attention_is_the_parents,
+):
+    """One forward kernel a layer (an application in ``ouro``, whose
+    block takes its policy where the other families take theirs), none
+    of them run again for the backward; loss and gradients the parent
+    policy's bit for bit (``olmo_hybrid``'s linear layers' rule keeps
+    what it kept).  With XLA attention nothing is named and the
+    program is the parent's."""
+    family, ours, parents = traced
+    _, _, forwards = FAMILIES[family]
+    if attention == "xla":
+        remat_with_xla_attention_is_the_parents(*toy_loss(family, "xla"))
+    else:
+        remat_keeps_what_flash_reads(ours, parents, forwards)

@@ -386,6 +386,23 @@ def test_delta_exports_are_o_rows_touched(tmp_path):
 # -- memory guard (CI) ----------------------------------------------------
 
 
+def _trim_heap():
+    """Give the free pages of glibc's heap back to the kernel, so that
+    a leg of a guard starts from the heap state its other leg starts
+    from: every page it touches is one its resident set grows by.  A
+    worker's earlier files leave freed blocks in the heap; untrimmed,
+    a leg's arrays come out of them, its resident set does not grow,
+    the bounded path passes blind and its control fails at 0.0 MB.
+    Called before EACH leg: before the control alone it would leave
+    the bounded path blind."""
+    import ctypes
+
+    trim = getattr(ctypes.CDLL(None), "malloc_trim", None)
+    if trim is not None:  # glibc's; another allocator keeps no such heap
+        trim.argtypes, trim.restype = [ctypes.c_size_t], ctypes.c_int
+        trim(0)
+
+
 def test_windowed_reshard_memory_guard():
     """THE bounded-memory claim, measured: peak extra RSS during a
     windowed reshard of a ~20 MB 2-shard split stays ≤ 2x the
@@ -416,12 +433,14 @@ def test_windowed_reshard_memory_guard():
         return t, SparseStateAdapter(digest=False).register_table(t)
 
     t_s, a_s = fresh()
+    _trim_heap()
     with PeakRssSampler() as rss_stream:
         info = a_s.import_shards_streaming(
             shards, world_size=16, rank=0, window_rows=window_rows,
         )
     assert info["kv_chunks"] > 1
     t_o, a_o = fresh()
+    _trim_heap()
     with PeakRssSampler() as rss_oneshot:
         a_o.import_shards(shards, world_size=16, rank=0)
     _assert_tables_bit_equal(t_s, t_o)
@@ -467,6 +486,7 @@ def test_streamed_base_publish_memory_guard(tmp_path, monkeypatch):
     # windowed zip writer; peak extra RSS bounded by the window
     t_s, a_s = fresh()
     pub = EmbeddingPublisher(a_s, str(tmp_path / "s_stream"))
+    _trim_heap()
     with PeakRssSampler() as rss_stream:
         gen = pub.publish(step=1)
     bound = 2 * window_mb * 2**20
@@ -494,6 +514,7 @@ def test_streamed_base_publish_memory_guard(tmp_path, monkeypatch):
         a_f, str(tmp_path / "s_fallback"),
         storage=BufferedStorage(),
     )
+    _trim_heap()
     with PeakRssSampler() as rss_fallback:
         pub_f.publish(step=1)
     assert rss_fallback.peak_extra_bytes > bound, (

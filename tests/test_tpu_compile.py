@@ -478,174 +478,10 @@ def test_olmoe_block_at_published_widths_compiles(one_chip, on_tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
 
 
-# a cell's expert layer: assignments, experts held, hidden size, expert
-# width, whether an expert has a gate matrix
-EXPERT_LAYERS = {
-    "olmoe_steady_4k": (65536, 64, 2048, 1024, True),
-    "sarvam_steady_8k": (65536, 8, 4096, 2048, True),
-    "laguna_steady_8k": (81920, 16, 3072, 1024, True),
-    "nemotron_steady_8k": (49152, 8, 2688, 1856, False),
-    "mimo_v2_5_steady": (65536, 8, 4096, 2048, True),
-}
-
-
-@pytest.mark.parametrize("cell", sorted(EXPERT_LAYERS))
-def test_a_whole_expert_compiles_at_the_cells_widths(one_chip, on_tpu, cell):
-    """``grouped_expert`` at each cell's widths, bf16, the result
-    alone (a program that asks for no gradient: the up kernel writes
-    ONE result) and value with all gradients: the up projection(s)
-    with the activation in ONE kernel whose weight blocks (two of
-    ``[4096, 1024]`` where a gate and an up matrix of ``[4096,
-    2048]`` go through one grid step) fit the chip's fast memory, the
-    derivative in the epilogue of the down projection's gradient and
-    ONE gradient to the rows; a width of 1856 = 14.5 lane tiles
-    whole, a hidden size of 3072 in halves and of 2688 in thirds."""
-    from dlrover_tpu.ops import grouped_matmul as gmm
-
-    assignments, groups, d, m, gated = EXPERT_LAYERS[cell]
-    tiles = assignments // gmm.ROW_TILE + groups
-
-    def s(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    operands = (
-        s((tiles * gmm.ROW_TILE, d)), s((groups, d, m)), s((groups, d, m)),
-        s((groups, m, d)), s((tiles,), jnp.int32), s((1,), jnp.int32),
-    )
-
-    def expert(rows, w_gate, *rest):
-        return gmm.grouped_expert(rows, w_gate if gated else None, *rest)
-
-    def loss(*operands):
-        return expert(*operands).astype(jnp.float32).sum()
-
-    def kinds(compiled):
-        calls = re.findall(
-            r"^\s*(?:ROOT )?%([\w\-.]+) = [^\n]*custom_call_target="
-            r'"tpu_custom_call"', compiled.as_text(), re.M,
-        )
-        return sorted(re.search(r"gmm_[a-z_]*[a-z]", c)[0] for c in calls)
-
-    primal = jax.jit(expert).lower(*operands).compile()
-    assert kinds(primal) == ["gmm_fwd", "gmm_up_fwd"]
-    # one result: a gate's two products are the forward rule's to keep
-    assert re.search(
-        rf"%gmm_up_fwd[\w.]* = bf16\[{tiles * gmm.ROW_TILE},{m}\]",
-        primal.as_text(),
-    )
-    both = jax.jit(
-        jax.value_and_grad(loss, argnums=(0, 1, 2, 3))
-    ).lower(*operands).compile()
-    wanted = _expert_kernels(1, gated)
-    wanted.update(gmm_up_fwd=1, gmm_fwd=1)  # no remat here
-    assert kinds(both) == sorted(
-        kind for kind, count in wanted.items() for _ in range(count)
-    )
-    assert "add_any" not in both.as_text()
-
-
 # -- Olmo-Hybrid: the rule, flash attention at its shapes, the one-period step ----
 
 HYBRID_ATTN = (1, 8192, 30, 128)
 HYBRID_RULE = dict(batch=1, seq=8192, heads=30, dk=96, dv=192)
-
-
-# a cell's router: its outputs, the choices a token makes, the score,
-# whether a bias picks them
-ROUTERS = {
-    "olmoe_steady_4k": (64, 8, "softmax", False),
-    "sarvam_steady_8k": (128, 8, "sigmoid", True),
-    "laguna_steady_8k": (256, 10, "softmax", False),
-    "nemotron_steady_8k": (128, 6, "sigmoid", True),
-    "mimo_v2_5_steady": (256, 8, "sigmoid", True),
-}
-
-
-def _index_passes(text, sizes):
-    """``(kind, shape)`` of every ``sort``, ``scatter`` and ``gather``
-    of a compiled program (fused ones too) that makes an array of one
-    of ``sizes`` elements: the operations that take a TPU 7-10 ns an
-    element where a vector pass takes bytes."""
-    found = []
-    for kind, made in re.findall(
-        r"^\s*(?:ROOT )?%[\w\-.]+ = (\(?[^=\n]*?\)?) "
-        r"(sort|scatter|gather)\(", text, re.M,
-    ):
-        found += [
-            (made, shape) for shape in re.findall(r"\w+\[([\d,]+)\]", kind)
-            if int(np.prod([int(n) for n in shape.split(",")])) in sizes
-        ]
-    return found
-
-
-def _arrays_of(text, elements: int):
-    """The results of ``elements`` elements that a compiled program's
-    own instructions make (what stands in memory), a fusion's inner
-    values left out."""
-    found, fused = [], False
-    for line in text.splitlines():
-        if line.endswith("{") and " -> " in line:
-            fused = "fused_computation" in line.split("(", 1)[0]
-        elif not fused:
-            found += [
-                shape for shape in re.findall(
-                    r"^\s*(?:ROOT )?%[\w\-.]+ = \w+\[([\d,]+)\]", line
-                )
-                if int(np.prod([int(n) for n in shape.split(",")]))
-                == elements
-            ]
-    return found
-
-
-@pytest.mark.parametrize("cell", sorted(EXPERT_LAYERS))
-def test_the_held_layers_index_work_is_no_sort_and_no_scatter(
-    one_chip, on_tpu, cell
-):
-    """The layer at each cell's tokens, choices, router outputs and
-    held experts (the widths small: the index work does not see
-    them), value and all gradients through a rematted layer, compiled:
-    where a chip holds a range, NO ``sort``, ``scatter`` or ``gather``
-    makes an array of ``tokens x k`` or of the padded rows' size (the
-    router's top-k sorts ``[tokens, e]``; the row side gathers a
-    tile's 256), and no array of ``tokens x k x e`` stands in memory
-    (100 MB of int32 at 384 outputs): masks and prefix sums are
-    fused vector passes.  Where every expert is held
-    (``olmoe_steady_4k``) the sort and its scatters stay, and the
-    search finds them."""
-    from dlrover_tpu.ops import grouped_matmul as gmm
-    from dlrover_tpu.parallel.moe import dropless_moe
-
-    assignments, groups, _, _, gated = EXPERT_LAYERS[cell]
-    e, k, score, bias = ROUTERS[cell]
-    t, d, m = assignments // k, 384, 128
-    held = None if groups == e else (0, groups)
-    padded_rows = assignments + groups * gmm.ROW_TILE
-
-    def s(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def loss(x, router, w_gate, w_up, w_down, select_bias):
-        out, stats = dropless_moe(
-            x, router, w_gate if gated else None, w_up, w_down, k,
-            held=held, score=score, renormalise=True, scale=2.5,
-            select_bias=select_bias if bias else None,
-        )
-        return out.astype(jnp.float32).sum() + jnp.vdot(
-            stats["prob_sum"], stats["counts"]
-        )
-
-    text = jax.jit(jax.value_and_grad(
-        jax.checkpoint(loss), argnums=(0, 1, 3, 4)
-    )).lower(
-        s((t, d)), s((d, e), jnp.float32), s((groups, d, m)),
-        s((groups, d, m)), s((groups, m, d)), s((e,), jnp.float32),
-    ).compile().as_text()
-    found = _index_passes(text, {assignments, padded_rows})
-    if held is None:
-        assert {kind for kind, _ in found} == {"sort", "scatter", "gather"}
-    else:
-        assert not found
-    assert not _arrays_of(text, assignments * e)
 
 
 def _rule_operands(one_chip, dtype=jnp.bfloat16):
@@ -732,108 +568,6 @@ def test_gated_delta_rule_compiles_at_published_sizes(
     assert temp < 2.0 * 2**30
 
 
-def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
-    """The cell's step (``olmo_hybrid_7b_cut``: one period at the
-    published widths, the whole vocabulary, bf16 state, flash
-    attention, per-block remat, 1 x 8192 tokens): state + temporaries
-    under the chip's 15.75 GB, the loss head's three matmuls a chunk,
-    the three flash kernels under the module ``attn`` (the block keeps
-    the five arrays the kernel's backward reads: no second forward,
-    PR 44 and PR 45), and
-    under each linear layer's ``gdn_rule`` scope two ``gdn_fwd``
-    (forward, the block's remat copy) and one ``gdn_bwd``."""
-    from dlrover_tpu.common.aot_cache import op_names
-    from dlrover_tpu.models.olmo_hybrid import (
-        PERIOD,
-        OlmoHybrid,
-        OlmoHybridConfig,
-        make_olmo_hybrid_loss,
-    )
-
-    model = OlmoHybrid(OlmoHybridConfig(
-        layer_types=PERIOD, attention_impl="flash", remat=True,
-        param_dtype=jnp.bfloat16,
-    ))
-    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
-    abs_state = jax.eval_shape(
-        lambda: TrainState.create(
-            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
-            optimizer,
-        )
-    )
-    tokens = np.zeros((1, 8192), np.int32)
-    compiled = compile_lowered(make_train_step(
-        make_olmo_hybrid_loss(model, num_chunks=8), optimizer
-    ).lower(
-        _shapes(abs_state, one_chip),
-        _shapes({"x": tokens, "y": tokens}, one_chip),
-    ))
-    mem = compiled.memory_analysis()
-    # 1.603 B parameters x 6 bytes
-    assert round(mem.argument_size_in_bytes / 1e9, 1) == 9.6
-    assert (
-        mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        < 15.75 * 2**30
-    )
-    # no more scratch than the checkpointed head of PR 32 asked for
-    # (offline compile of 2da395f, this very program).  4.44 GB now
-    # (4,441,295,360 B; 4,442,198,528 before the one full-attention
-    # layer kept its q, k and v, 3 x 62.9 MB: the peak is not in that
-    # layer's backward, so 0.19 GB kept shows as nothing); 4.07 GB
-    # with the convolutions as kernels (4,069,591,040 B, PR 49: the
-    # padded float32 copies of q, k and v are gone): the limit is
-    # what stood before them
-    assert mem.temp_size_in_bytes <= 4_441_295_360
-    text = compiled.as_text()
-    # the head: 3 vocabulary-sized matmuls a chunk of 8192 / 8 tokens
-    # (logits, d_hidden, d_kernel: 3 x 8 a step, where the
-    # checkpointed head made 4 x 8), none of them a recomputation
-    assert _head_matmul_shapes(text) == [
-        "bf16[1024,3840]", "f32[1024,100352]", "f32[3840,100352]",
-    ]
-    calls = re.findall(
-        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
-        r'"tpu_custom_call"', text, re.M,
-    )
-    stacks = op_names(text)["op_names"]
-    rule = [c for c in calls if "gdn_" in c]
-    conv = [c for c in calls if "conv_" in c]
-    calls = [c for c in calls if c not in rule + conv]
-    # the convolutions of q, k and v in each linear layer: forward,
-    # the block's remat copy and one backward each, under the scope
-    # the mix's reader sums
-    assert sorted(
-        (re.search(r"block_(\d)/gdn/", stacks[c]).group(1),
-         re.search(r"conv_(fwd|bwd)", c).group(1))
-        for c in conv
-    ) == sorted(
-        (str(i), kind) for i in range(3)
-        for kind in ("fwd", "fwd", "bwd") * 3
-    )
-    assert all(
-        re.search(r"(?:^|[/(])gdn_conv(?:[/)]|$)", stacks[c]) for c in conv
-    )
-    # forward, dq, dkv: one layer of four
-    assert len(calls) == 3
-    assert all(re.match(r"^%?attn(\.|$)", name) for name in calls)
-    assert all("/block_3/attn/" in stacks[c] for c in calls)
-    # the other three: the rule's kernels, the backward's too under
-    # the scope the benchmark's readers look for
-    assert sorted(
-        (re.search(r"block_(\d)/gdn/", stacks[c]).group(1),
-         re.search(r"gdn_(fwd|bwd)", c).group(1))
-        for c in rule
-    ) == sorted(
-        (str(i), kind) for i in range(3) for kind in ("fwd", "fwd", "bwd")
-    )
-    # (bare forward, ``transpose(jvp(gdn_rule))`` backward)
-    assert all(
-        re.search(r"(?:^|[/(])gdn_rule(?:[/)]|$)", stacks[c]) for c in rule
-    )
-    for scope in ("gdn_conv", "gdn_gates", "gdn_rule", "gdn_norm"):
-        assert any(f"/gdn/{scope}/" in s for s in stacks.values()), scope
-
-
 def test_flash_attention_compiles_at_two_head_sizes(one_chip, on_tpu):
     """Latent attention's shape in the cell: 16 heads x 8192 tokens, q
     and k of 192 (one and a half lane tiles), v of 128, bf16, a scale
@@ -856,121 +590,6 @@ def test_flash_attention_compiles_at_two_head_sizes(one_chip, on_tpu):
     ).lower(q, q, v).compile()
     assert _kernels(compiled) == 3
     assert fa.resident_rows(8192, 1024, 192, 2, 128) == 2048
-
-
-def test_sarvam_one_dense_four_expert_step_fits_the_chip(
-    one_chip, on_tpu, tmp_path
-):
-    """The cell's step (``sarvam_105b_cut``: the leading dense block
-    and four expert blocks at the published widths, 16 heads and 8 of
-    128 experts held, an eighth of the vocabulary, bf16 state, flash
-    attention at 192 | 128, per-block remat, 1 x 8192 tokens): state +
-    temporaries under the chip's 15.75 GB, the flash kernels under the
-    module ``attn``, the grouped matmuls under ``moe_experts``, and
-    every scope the benchmark's readers join on in the op-name map."""
-    from dlrover_tpu.common.aot_cache import op_names
-    from dlrover_tpu.models.sarvam_mla import (
-        SarvamMla,
-        SarvamMlaConfig,
-        make_sarvam_mla_loss,
-    )
-
-    model = SarvamMla(SarvamMlaConfig(
-        vocab_size=32768, num_layers=5, num_heads_held=16,
-        experts_held=(0, 8), attention_impl="flash", remat=True,
-        param_dtype=jnp.bfloat16,
-    ))
-    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
-    abs_state = jax.eval_shape(
-        lambda: TrainState.create(
-            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
-            optimizer,
-        )
-    )
-    tokens = np.zeros((1, 8192), np.int32)
-    compiled, reserved = _compile_and_reserved_hbm(make_train_step(
-        make_sarvam_mla_loss(model, num_chunks=8), optimizer
-    ).lower(
-        _shapes(abs_state, one_chip),
-        _shapes({"x": tokens, "y": tokens}, one_chip),
-    ), tmp_path)
-    mem = compiled.memory_analysis()
-    # 1.505 B parameters x 6 bytes
-    assert round(mem.argument_size_in_bytes / 1e9, 2) == 9.03
-    assert (
-        mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        < 15.75 * 2**30
-    )
-    # the block's remat holds (left to the compiler's CSE the step
-    # asked for 7.8 GB and did not fit: offline compile, PR 35).
-    # 4.24 GB (4,236,185,088 B) until PR 52: 3.57 with ``out`` and
-    # ``lse`` kept (PR 44) + q and k at 16 x 8192 x 192 and v at 128,
-    # bf16: 134 MB a layer x 5 = 0.67 GB, all of it (3,573,515,776 B
-    # before).  That figure is the reserved block PLUS its
-    # fragmentation (``_compile_and_reserved_hbm``): the block was
-    # 3.927 GB (3,927,294,464 B) with 3.618 live in it at once.
-    # Since PR 52: 3.779 reserved, 3.074 live (4.485 reported: a
-    # block that holds less at its fullest reads as more
-    # fragmentation).  The fullest moment was the backward's second
-    # run of the last block's experts with the combine's gradient to
-    # their rows ALREADY made beside them; it is made after them now
-    # (``parallel/moe.py::_held_combine_bwd``), in the place of the
-    # rows it is the gradient of.  Both held under what the parent
-    # read (offline compile, PR 52; PERF.md section 7)
-    live = 2 * reserved - mem.temp_size_in_bytes
-    print(
-        f"sarvam step temporaries: {reserved / 1e9:.3f} GB reserved, "
-        f"{live / 1e9:.3f} live at once, "
-        f"{mem.temp_size_in_bytes / 1e9:.3f} reported"
-    )
-    assert mem.temp_size_in_bytes < 5 * 2**30
-    assert reserved < 3.927e9, f"{reserved / 1e9:.3f} GB where 3.779 was read"
-    assert live < 3.618e9, f"{live / 1e9:.3f} GB live where 3.074 was read"
-    text = compiled.as_text()
-    calls = re.findall(
-        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
-        r'"tpu_custom_call"', text, re.M,
-    )
-    flash = [c for c in calls if re.match(r"^%?attn(\.|$)", c)]
-    # forward, dq, dkv in each of five blocks: a block keeps what the
-    # backward kernels read of the forward (5 x 33.8 MB: the
-    # temporaries are 3.57 GB where they were 3.44, PR 44)
-    assert len(flash) == 3 * 5
-    stacks = op_names(text)["op_names"]
-    grouped = [c for c in calls if c not in flash]
-    # four layers' experts (``_expert_kernels``) under the names the
-    # benchmark's readers join on; the rows back to their tokens in
-    # the combine's forward and the dispatch's backward; a buffer for
-    # the walk of the used tiles to fill in the dispatch's forward,
-    # its remat copy and the combine's backward
-    kinds = [re.sub(r"^%|\.\d+$", "", c) for c in grouped]
-    assert {kind: kinds.count(kind) for kind in kinds} == {
-        **_expert_kernels(4),
-        "gmm_tokens_from_rows": 2 * 4, "gmm_unwritten": 3 * 4,
-    }
-    for call, kind in zip(grouped, kinds):
-        if kind in _expert_kernels(4):
-            assert "/moe_experts/" in stacks[call]
-        else:
-            assert re.search("/moe_(dispatch|combine)/", stacks[call]), call
-    # both passes of a rematted block run the forward RULE: all eight
-    # up calls write the gate's two products beside the hidden rows,
-    # and the first pass's are dropped unread (0.09 ms a call here)
-    assert set(re.findall(
-        r"^\s*%gmm_up_fwd[.\d]* = (\(?)bf16\[67584,2048\]", text, re.M
-    )) == {"("}
-    # 65536 assignments + a tile a held expert: no ``add_any`` and no
-    # elementwise pass over them between the kernels
-    assert not _passes_at_the_static_size(text, stacks, 67584)
-    # no array of every assignment's row, forward or backward
-    assert not re.search(r"\[8192,8,4096\]|\[65536,4096\]", text)
-    assert not any("/block_0/moe" in s for s in stacks.values())
-    for scope in (
-        "mla_q", "mla_kv_down", "mla_kv_up", "mla_rope", "mla_out",
-        "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
-        "moe_shared",
-    ):
-        assert any(f"/{scope}/" in s for s in stacks.values()), scope
 
 
 @pytest.mark.parametrize("heads, window", [(72, 512), (48, None)])
@@ -1003,121 +622,6 @@ def test_flash_attention_compiles_at_lagunas_two_kinds_of_layer(
     )
     if window is not None:
         assert fa._tiles_back(1024, window) == 1
-
-
-def test_laguna_one_dense_four_sparse_step_fits_the_chip(
-    one_chip, on_tpu, tmp_path
-):
-    """The cell's step (``laguna_s_2_1_cut``: a full dense block, three
-    sliding sparse blocks and a full sparse one at the published
-    widths, 16 of 256 experts held, an eighth of the vocabulary, bf16
-    state, flash attention, per-block remat, 1 x 8192 tokens): state +
-    temporaries under the chip's 15.75 GB, the flash kernels under the
-    module ``attn`` inside ``swa`` or ``full_attn``, the grouped
-    matmuls (hidden 3072 in tiles of 1536) under ``moe_experts``, and
-    every scope the benchmark's readers join on in the op-name map."""
-    from dlrover_tpu.common.aot_cache import op_names
-    from dlrover_tpu.models.laguna import (
-        FULL,
-        SLIDING,
-        Laguna,
-        LagunaConfig,
-        make_laguna_loss,
-    )
-
-    model = Laguna(LagunaConfig(
-        vocab_size=12544,
-        layer_types=(FULL, SLIDING, SLIDING, SLIDING, FULL),
-        heads_per_layer=(48, 72, 72, 72, 48),
-        mlp_layer_types=("dense",) + ("sparse",) * 4,
-        experts_held=(0, 16), attention_impl="flash", remat=True,
-        param_dtype=jnp.bfloat16,
-    ))
-    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
-    abs_state = jax.eval_shape(
-        lambda: TrainState.create(
-            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
-            optimizer,
-        )
-    )
-    tokens = np.zeros((1, 8192), np.int32)
-    compiled, reserved = _compile_and_reserved_hbm(make_train_step(
-        make_laguna_loss(model, num_chunks=8), optimizer
-    ).lower(
-        _shapes(abs_state, one_chip),
-        _shapes({"x": tokens, "y": tokens}, one_chip),
-    ), tmp_path)
-    mem = compiled.memory_analysis()
-    # 1.113 B parameters x 6 bytes
-    assert round(mem.argument_size_in_bytes / 1e9, 2) == 6.68
-    # 4.11 GB (4,108,032,000 B).  2.96 before a block kept its
-    # kernel's ``out`` and ``lse`` (3 x 151 + 2 x 101 + 10 MB = 0.67
-    # GB: 3.63, PR 44); since PR 45 it keeps q, k and v too: q as
-    # ``out`` (0.65 GB), k and v at the 8 kv heads (5 x 2 x 16.8 MB =
-    # 0.17 GB), 0.82 GB kept for 0.48 GB more, because the backward
-    # of the block at the peak held its remat copy's q, k and v there
-    # before.  That figure is the reserved block PLUS its
-    # fragmentation (``_compile_and_reserved_hbm``): 3.901 GB reserved
-    # (3,901,096,448 B) with 3.694 live at once.  Since PR 52:
-    # 3.881 reserved, 3.141 live at once (the combine's gradient to
-    # the experts' rows is made after the backward ran the experts
-    # again, not beside their hidden rows: the sarvam step's test
-    # above), and the FIGURE reads 4.62 GB, because a block that
-    # holds less at its fullest reads as more fragmentation.  So the
-    # limit that stood on the figure (4 GiB) is held on the two it is
-    # made of, each under what the parent read (offline compile, PR
-    # 52; PERF.md section 7)
-    live = 2 * reserved - mem.temp_size_in_bytes
-    print(
-        f"laguna step temporaries: {reserved / 1e9:.3f} GB reserved, "
-        f"{live / 1e9:.3f} live at once, "
-        f"{mem.temp_size_in_bytes / 1e9:.3f} reported"
-    )
-    assert reserved < 3.901e9, f"{reserved / 1e9:.3f} GB where 3.881 was read"
-    assert live < 3.694e9, f"{live / 1e9:.3f} GB live where 3.141 was read"
-    assert (
-        mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        < 15.75 * 2**30
-    )
-    text = compiled.as_text()
-    calls = re.findall(
-        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
-        r'"tpu_custom_call"', text, re.M,
-    )
-    stacks = op_names(text)["op_names"]
-    flash = [c for c in calls if re.match(r"^%?attn(\.|$)", c)]
-    # forward, dq, dkv in each of five blocks: 9 in the sliding layers,
-    # 6 in the full ones; no block runs its forward again
-    assert len(flash) == 3 * 5
-    assert sum("/swa/attn/" in stacks[c] for c in flash) == 3 * 3
-    assert sum("/full_attn/attn/" in stacks[c] for c in flash) == 3 * 2
-    for block, scope in enumerate(
-        ("full_attn", "swa", "swa", "swa", "full_attn")
-    ):
-        assert sum(
-            f"/block_{block}/{scope}/attn/" in stacks[c] for c in flash
-        ) == 3
-    kinds = [
-        re.sub(r"^%|\.\d+$", "", c) for c in calls if c not in flash
-    ]
-    assert {kind: kinds.count(kind) for kind in kinds} == {
-        **_expert_kernels(4),
-        "gmm_tokens_from_rows": 2 * 4, "gmm_unwritten": 3 * 4,
-    }
-    assert all(
-        "/moe_experts/" in stacks[c] for c in calls
-        if re.sub(r"^%|\.\d+$", "", c) in _expert_kernels(4)
-    )
-    # no array of every assignment's row, forward or backward
-    assert not re.search(r"\[8192,10,3072\]|\[81920,3072\]", text)
-    # ... and, of the 81920 + 16 tiles of padded rows, no ``add_any``
-    # and no elementwise pass between the experts' kernels
-    assert not _passes_at_the_static_size(text, stacks, 86016)
-    for scope in (
-        "attn_rope", "attn_gate", "moe_router", "moe_dispatch",
-        "moe_experts", "moe_combine", "moe_shared",
-    ):
-        assert any(f"/{scope}/" in s for s in stacks.values()), scope
 
 
 @pytest.mark.parametrize(
@@ -1227,205 +731,6 @@ def test_causal_conv_compiles_at_both_cells_shapes(one_chip, on_tpu, shape):
         out == jnp.float32
     )
     assert not re.search(r"\[1,8195,", backward.as_text())
-
-
-def test_nemotron_eighteen_layer_step_fits_the_chip(
-    one_chip, on_tpu, tmp_path
-):
-    """The cell's step (``nemotron_3_nano_30b_cut``: ``MEMEM*EMEMEM*EMEME``
-    at the published widths, 8 of 128 experts held, an eighth of the
-    vocabulary, bf16 state, flash attention at 32 heads over 2, per-layer
-    remat, 1 x 8192 tokens): state + temporaries under the chip's 15.75
-    GB, the expert width of 1856 (14.5 lane tiles) WHOLE through the
-    grouped-matmul kernels and the hidden size of 2688 in thirds of 896,
-    two grouped matmuls an expert layer forward, the flash kernels
-    under ``full_attn``, and every scope the benchmark's readers join
-    on in the op-name map."""
-    from dlrover_tpu.common.aot_cache import op_names
-    from dlrover_tpu.models.nemotron_h import (
-        NemotronH,
-        NemotronHConfig,
-        make_nemotron_h_loss,
-    )
-
-    model = NemotronH(NemotronHConfig(
-        vocab_size=16384, pattern="MEMEM*EMEMEM*EMEME",
-        experts_held=(0, 8), attention_impl="flash", remat=True,
-        param_dtype=jnp.bfloat16,
-    ))
-    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
-    abs_state = jax.eval_shape(
-        lambda: TrainState.create(
-            model.init_params(jax.random.PRNGKey(0), seq_len=8192),
-            optimizer,
-        )
-    )
-    tokens = np.zeros((1, 8192), np.int32)
-    compiled, reserved = _compile_and_reserved_hbm(make_train_step(
-        make_nemotron_h_loss(model, num_chunks=8), optimizer
-    ).lower(
-        _shapes(abs_state, one_chip),
-        _shapes({"x": tokens, "y": tokens}, one_chip),
-    ), tmp_path)
-    mem = compiled.memory_analysis()
-    # 1.2458 B parameters x 6 bytes (the three per-head vectors of a
-    # state-space layer are float32)
-    assert round(mem.argument_size_in_bytes / 1e9, 2) == 7.48
-    # What the chip reserves for the step's temporaries: 3.718 GiB
-    # (3,991,798,272 B, offline compile, PR 49; 3.866 GiB at PR 48,
-    # 4,150,641,152 B: the padded float32 xBC and its transposes are
-    # gone), and the most that is live in it at once 3.312 GiB (3.759
-    # at PR 48): both DOWN.  ``temp_size_in_bytes`` is the block plus
-    # its fragmentation (``_compile_and_reserved_hbm``): 3.718 + 0.405
-    # = 4.123 GiB where PR 48 read 3.866 + 0.106 = 3.972, a smaller
-    # block packed looser, so the limit that stood on that figure
-    # (4.0 GiB) is held on the two it is made of, each under PR 48's.
-    # Nothing chunk-square is among them (4.45 GB with the scan as
-    # XLA einsums, PR 47).  Since PR 52 (``relu(.) ** 2`` inside the
-    # up projection's kernel, which writes the hidden rows and keeps
-    # nothing else: the derivative takes ``relu(u)`` as their root):
-    # 3.644 GiB reserved, 3.292 live at once, 3.996 reported, each
-    # under PR 49's
-    temp = mem.temp_size_in_bytes
-    live = 2 * reserved - temp
-    print(
-        f"nemotron step temporaries: {reserved / 2**30:.3f} GiB reserved, "
-        f"{live / 2**30:.3f} live at once, {temp / 2**30:.3f} reported"
-    )
-    assert reserved < 3.75 * 2**30, (
-        f"{reserved / 2**30:.3f} GiB reserved where 3.644 was read"
-    )
-    assert live < 3.4 * 2**30, (
-        f"{live / 2**30:.3f} GiB live at once where 3.292 was read"
-    )
-    assert (
-        mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        < 15.75 * 2**30
-    )
-    text = compiled.as_text()
-    calls = re.findall(
-        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
-        r'"tpu_custom_call"', text, re.M,
-    )
-    stacks = op_names(text)["op_names"]
-    flash = [c for c in calls if re.match(r"^%?attn(\.|$)", c)]
-    # forward, dq, dkv in each of the two attention layers
-    assert len(flash) == 3 * 2
-    assert all("/full_attn/attn/" in stacks[c] for c in flash)
-    # the state-space scan: eight layers' forward and the block's
-    # remat copy, one backward each, all under the scan's scope
-    scan = [c for c in calls if "ssd_" in c]
-    assert all("/ssm_scan/" in stacks[c] for c in scan)
-    kinds = [
-        re.sub(r"^%|\.\d+$", "", c) for c in calls if c not in flash
-    ]
-    # an expert layer: up (with ``relu(.) ** 2`` inside) and down
-    # forward and in the remat copy, each with its two gradients: no
-    # third matrix, so the rows' gradient is the plain ``gmm_dlhs``
-    assert {kind: kinds.count(kind) for kind in kinds} == {
-        **_expert_kernels(8, gated=False),
-        "gmm_tokens_from_rows": 2 * 8, "gmm_unwritten": 3 * 8,
-        "ssd_fwd": 2 * 8, "ssd_bwd": 8,
-        # x, B and C, each a window of the projection's lanes
-        "conv_fwd": 3 * 2 * 8, "conv_bwd": 3 * 8,
-    }
-    conv = [c for c in calls if "conv_" in c]
-    assert all(
-        re.search(r"(?:^|[/(])ssm_conv(?:[/)]|$)", stacks[c]) for c in conv
-    )
-    # read where the projection wrote them: no copy of its lanes
-    assert not re.search(r"bf16\[1,8192,6144\]", text)
-    # no array of every assignment's row, forward or backward
-    assert not re.search(r"\[8192,6,2688\]|\[49152,2688\]", text)
-    assert all(
-        "/moe_experts/" in stacks[c] for c in calls
-        if re.sub(r"^%|\.\d+$", "", c) in _expert_kernels(8, gated=False)
-    )
-    # ... and, of the 49152 + 8 tiles of padded rows, no elementwise
-    # pass between the experts' kernels
-    assert not _passes_at_the_static_size(text, stacks, 51200)
-    for scope in (
-        "ssm_in_proj", "ssm_conv", "ssm_gates", "ssm_scan", "ssm_norm",
-        "ssm_out_proj", "moe_router", "moe_dispatch", "moe_experts",
-        "moe_combine", "moe_shared",
-    ):
-        named = [s for s in stacks.values() if f"/{scope}/" in s]
-        assert any("transpose(" in s for s in named), scope
-
-
-def test_ouro_twelve_layers_four_passes_step_fits_the_chip(one_chip, on_tpu):
-    """The cell's step (``ouro_2_6b_cut``: twelve blocks at the
-    published widths run four times over the same weights, the whole
-    vocabulary, bf16 state, flash attention, per-block remat, 1 x 4096
-    tokens, the four exits through one weighted head of 16 chunks):
-    state + temporaries under the chip's 15.75 GB, compiled as the
-    engine compiles it (``aot_cache.compile_lowered``); the tree holds
-    12 blocks and the program ONE pass's instructions in the bodies of
-    two scans (twelve applications' flash kernels under ``ut``, run
-    four times); the head's three vocabulary-sized matmuls a chunk,
-    none recomputed."""
-    from dlrover_tpu.common.aot_cache import op_names
-    from dlrover_tpu.models.ouro import Ouro, OuroConfig, make_ouro_loss
-
-    model = Ouro(OuroConfig(
-        num_layers=12, attention_impl="flash", remat=True,
-        param_dtype=jnp.bfloat16,
-    ))
-    optimizer = adamw_bf16(learning_rate=3e-4, weight_decay=0.1)
-    abs_state = jax.eval_shape(
-        lambda: TrainState.create(
-            model.init_params(jax.random.PRNGKey(0), 1, seq_len=4096),
-            optimizer,
-        )
-    )
-    assert sum(k.startswith("block_") for k in abs_state.params) == 12
-    tokens = np.zeros((1, 4096), np.int32)
-    compiled = compile_lowered(make_train_step(
-        make_ouro_loss(model, num_chunks=16), optimizer
-    ).lower(
-        _shapes(abs_state, one_chip),
-        _shapes({"x": tokens, "y": tokens}, one_chip),
-    ))
-    mem = compiled.memory_analysis()
-    # 818.0 M parameters x 6 bytes
-    assert round(mem.argument_size_in_bytes / 1e9, 2) == 4.91
-    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes - (
-        2 * 4096 * 4
-    )
-    # 7.80 GB (7,803,492,864 B): 4.88 at PR 44 (3.69 + the ``out`` and
-    # ``lse`` of 48 applications) + 36 stacks of ``bf16[4,16,4096,
-    # 128]``, the q, k and v of twelve applications over the four
-    # passes (2.42 GB: 7.30) + 0.5 round them; the dumped buffer
-    # assignment's one preallocated temporary, which is what the chip
-    # reserves, is 6.99 GB (4.32 at PR 44).  Under the compiler's OWN
-    # choice of order this step asked 14.59 GB and reserved 9.63
-    # (``aot_cache.COMPILER_OPTIONS``; PERF.md section 6, PR 45), and
-    # the sum below did not hold
-    assert mem.temp_size_in_bytes < 7.9e9
-    assert (
-        mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        < 15.75 * 2**30
-    )
-    text = compiled.as_text()
-    calls = re.findall(
-        r"^\s*(?:ROOT )?(%[\w\-.]+) = [^\n]*custom_call_target="
-        r'"tpu_custom_call"', text, re.M,
-    )
-    stacks = op_names(text)["op_names"]
-    # forward, dq, dkv of a pass's 12 applications
-    assert len(calls) == 3 * 12
-    assert all(re.match(r"^%?attn(\.|$)", c) for c in calls)
-    assert all("/while/body/" in stacks[c] for c in calls)
-    assert all("/ut/" in stacks[c] for c in calls)
-    for block in range(12):
-        assert sum(
-            f"block_{block}/attn/" in stacks[c] for c in calls
-        ) == 3, block
-    for scope in ("exit_gate", "loss_head", "optimizer"):
-        assert any(scope in s for s in stacks.values()), scope
-    assert _head_matmul_shapes(text) == [
-        "bf16[1024,2048]", "f32[1024,49152]", "f32[2048,49152]",
-    ]
 
 
 def test_chunked_head_compiles_at_olmoes_shapes(one_chip):
