@@ -20,10 +20,18 @@ and ``d`` values, per token ``x``::
     g   = lower x sigmoid(exp(A_log_h) (x W_f + dt_bias))   [H, d]
           (the safe gate: every channel's log-decay in [lower, 0],
           ``lower`` = ``kda_lower_bound`` = -5; ``W_f`` full rank,
-          float32 out)
+          float32 out; these three one pass over the rows,
+          :func:`dlrover_tpu.ops.kda_rows.kda_gates`)
     beta_h = sigmoid(x W_b)_h
     o = kda_rule(q, k, v, g, beta)      (:mod:`dlrover_tpu.ops.kda`)
     y = (RMSNorm_d(o_h) * sigmoid(x W_g)) W_o
+          (norm and gate one pass,
+          :func:`dlrover_tpu.ops.kda_rows.kda_norm`)
+
+Between the projections every array of the mixer stays the ``[b, s, H
+d]`` rows its matmul wrote: the convolution, the gates, the rule and
+the norm are Pallas kernels that take a head as ``d`` lanes of a row,
+and XLA is left the matmuls, ``beta`` and the per-channel sums.
 
 Latent-attention mixer (DeepSeek-V2, arXiv:2405.04434; no query
 latent; the head-wise output gate of arXiv:2505.06708)::
@@ -50,7 +58,9 @@ and a non-zero SwiGLU clamp, raise.
 
 Flax names: ``kda`` and ``attn`` (the benchmark finds flash kernels
 by the second).  Device scopes: ``kda_proj``, ``kda_conv``,
-``kda_gates``, ``kda_rule``, ``kda_norm``, ``kda_out``; ``mla_q``,
+``kda_gates`` (the kernels ``kda_gates_fwd`` / ``kda_gates_bwd``, and
+``beta``), ``kda_rule``, ``kda_norm`` (``kda_norm_fwd`` /
+``kda_norm_bwd``), ``kda_out``; ``mla_q``,
 ``mla_kv_down``, ``mla_kv_up``, ``mla_rope``, ``attn_gate``,
 ``mla_out``; the expert layer's ``moe_*``.
 """
@@ -67,6 +77,7 @@ from dlrover_tpu.models import layers
 from dlrover_tpu.models.losses import chunked_cross_entropy
 from dlrover_tpu.ops.causal_conv import causal_conv
 from dlrover_tpu.ops.kda import kda_rule
+from dlrover_tpu.ops.kda_rows import kda_gates, kda_norm
 from dlrover_tpu.parallel.moe import DroplessMoE, bias_deltas
 from dlrover_tpu.telemetry.tracing import device_scope
 
@@ -175,11 +186,6 @@ def _dt_bias_init(key, shape, dtype):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def _l2_normalised(x, eps=1e-6):
-    """``x [.., d] / |x|`` in float32."""
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
-
-
 def rotate_pairs(x, cos, sin):
     """``x [b, s, heads, rope]``: lanes ``2i`` and ``2i + 1`` rotate
     together (``rope_interleave``), float32 inside; ``cos, sin [1, s,
@@ -241,32 +247,28 @@ class KdaAttention(nn.Module):
             k = conv("k_conv", k, jnp.float32)
             v = conv("v_conv", v, cfg.dtype)
         with device_scope("kda_gates"):
-            q = _l2_normalised(q.reshape(b, s, heads, d)) * d ** -0.5
-            k = _l2_normalised(k.reshape(b, s, heads, d))
-            q, k = q.astype(cfg.dtype), k.astype(cfg.dtype)
-            beta = jax.nn.sigmoid(bb)
-            g = cfg.kda_lower_bound * jax.nn.sigmoid(
-                jnp.exp(a_log)[:, None]
-                * (f.astype(jnp.float32) + dt_bias).reshape(b, s, heads, d)
+            # (rows in, rows out: a head is d lanes of a row)
+            q, k, g, least = kda_gates(
+                q, k, f, a_log, dt_bias, lower=cfg.kda_lower_bound,
+                dtype=cfg.dtype,
             )
+            beta = jax.nn.sigmoid(bb)
         with device_scope("kda_rule"):
             # (the kernels' custom_vjp says what the backward keeps)
-            o, state = kda_rule(q, k, v.reshape(b, s, heads, d), g, beta)
+            o, state = kda_rule(*(
+                y.reshape(b, s, heads, d) for y in (q, k, v, g)
+            ), beta)
         with device_scope("kda_norm"):
             # per head, one learned scale of size d, gated by sigmoid(z)
             scale = self.param(
                 "o_norm", nn.initializers.ones, (d,), jnp.float32
             )
-            o32 = o.astype(jnp.float32)
-            o32 = o32 * jax.lax.rsqrt(
-                jnp.mean(o32 * o32, axis=-1, keepdims=True) + cfg.rms_eps
-            ) * scale
-            o = (
-                o32.reshape(b, s, heads * d)
-                * jax.nn.sigmoid(z.astype(jnp.float32))
-            ).astype(cfg.dtype)
+            o = kda_norm(
+                o.reshape(b, s, heads * d), z, scale, eps=cfg.rms_eps,
+                dtype=cfg.dtype,
+            )
             stats = {
-                "log_decay_min": jax.lax.stop_gradient(jnp.min(g)),
+                "log_decay_min": jax.lax.stop_gradient(least),
                 "state_rms": jax.lax.stop_gradient(
                     jnp.sqrt(jnp.mean(state * state))
                 ),

@@ -373,7 +373,8 @@ def test_the_family_imports_no_sibling():
     assert sorted(line.split()[1] for line in imported) == [
         "dlrover_tpu.models", "dlrover_tpu.models.losses",
         "dlrover_tpu.ops.causal_conv", "dlrover_tpu.ops.kda",
-        "dlrover_tpu.parallel.moe", "dlrover_tpu.telemetry.tracing",
+        "dlrover_tpu.ops.kda_rows", "dlrover_tpu.parallel.moe",
+        "dlrover_tpu.telemetry.tracing",
     ]
 
 

@@ -31,7 +31,7 @@ from dlrover_tpu.models.gpt import (  # noqa: E402
 # nothing; ``gpt`` is the loss its two cells run
 # (``benchmarks/models/gpt2.py::build``).
 PINS = {
-    ("bailing_hybrid", "flash"): "73d3f23e1dd8a16c",  # PR 61's tree
+    ("bailing_hybrid", "flash"): "b1a372cd028d185f",  # PR 62's tree
     ("gpt", "flash"): "d1d2b7b0b9ff02ee",  # PR 61's tree
     ("laguna", "flash"): "322e01c44b37bb48",  # PR 61's tree
     ("laguna", "xla"): "4b80b17fe2120d48",  # PR 58's tree
