@@ -395,6 +395,7 @@ def dropless_moe(
     polynorm: Optional[jax.Array] = None,  # [4] float32
     n_group: int = 1,
     topk_group: int = 1,
+    renormalise_eps: float = 1e-20,
 ):
     """Top-k routing without capacity: ``(out [t, d], stats)``.
 
@@ -405,7 +406,8 @@ def dropless_moe(
     own; with ``select_bias`` the k experts are chosen by ``score +
     bias`` and weighted by the score alone (the bias only steers the
     load and takes no gradient); ``renormalise`` divides the k weights
-    by their sum, ``scale`` multiplies them.  An expert computes
+    by their sum (+ ``renormalise_eps``, a family's own guard),
+    ``scale`` multiplies them.  An expert computes
     ``down(silu(gate(x)) * up(x))`` or, with ``w_gate=None``,
     ``down(relu(up(x)) ** 2)``: an expert that has no gate has no
     gate matrix.  ``n_group > 1`` limits the choice to groups
@@ -511,7 +513,9 @@ def dropless_moe(
         _, expert_ids = jax.lax.top_k(standing, top_k)  # [t, k]
         gate = _scores_of(probs, expert_ids)
         if renormalise:
-            gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
+            gate = gate / (
+                gate.sum(axis=-1, keepdims=True) + renormalise_eps
+            )
         if scale != 1.0:
             gate = gate * scale
         counts = _assignments_of(expert_ids, e)
@@ -634,7 +638,9 @@ class DroplessMoE(nn.Module):
     whose gradients sum over every row of the layer, and one more,
     ``shared_polynorm_w`` / ``shared_polynorm_b``, for the shared
     expert.  ``n_group`` / ``topk_group`` limit the choice to groups
-    (:func:`dropless_moe`); the defaults are no grouping."""
+    (:func:`dropless_moe`); the defaults are no grouping.
+    ``renormalise_eps`` is the guard in ``renormalise``'s denominator
+    (a family's own: 1e-6 in ``models/lfm2_moe.py``)."""
 
     num_experts: int
     mlp_dim: int
@@ -653,6 +659,7 @@ class DroplessMoE(nn.Module):
     polynorm_clamp: float = 0.5
     n_group: int = 1
     topk_group: int = 1
+    renormalise_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x: jax.Array):
@@ -699,6 +706,7 @@ class DroplessMoE(nn.Module):
             select_bias=bias, renormalise=self.renormalise,
             scale=self.scale, polynorm=polynorm("experts_"),
             n_group=self.n_group, topk_group=self.topk_group,
+            renormalise_eps=self.renormalise_eps,
         )
         out = out.reshape(b, s, d)
         if self.shared_dim:

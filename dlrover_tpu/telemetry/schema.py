@@ -130,6 +130,10 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # holds it at or above kda_lower_bound), the largest rms of a
         # layer's final state, and the mean number of distinct groups
         # among a token's k choices (at most topk_group)
+        # sconv.out_rms_max: the largest rms of a gated short
+        # convolution's output y = C * conv(B * u) over the conv
+        # layers (models/lfm2_moe.py): a product of three projections
+        # of one input, what grows first if the mixer's scale drifts
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
@@ -140,7 +144,8 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
             "ssm.state_rms_max", "ssm.decay_mean",
             "mhc.res_sum_err_max", "gdla.lambda_mean",
             "gdla.noise_share", "mtp.loss", "kda.log_decay_min",
-            "kda.state_rms_max", "moe.groups_per_token_mean"]),
+            "kda.state_rms_max", "moe.groups_per_token_mean",
+            "sconv.out_rms_max"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

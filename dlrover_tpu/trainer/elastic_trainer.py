@@ -630,7 +630,7 @@ class ElasticTrainer:
             # 0.65 ms a step (PERF.md, PR 25)
             if name.startswith((
                 "moe.", "gdn.", "attn.", "loop.", "ssm.", "mhc.", "gdla.",
-                "mtp.", "kda.",
+                "mtp.", "kda.", "sconv.",
             )):
                 step_event[name] = float(value)
         emit_event("train_step", **step_event)

@@ -35,6 +35,7 @@ PINS = {
     ("gpt", "flash"): "d1d2b7b0b9ff02ee",  # PR 61's tree
     ("laguna", "flash"): "322e01c44b37bb48",  # PR 61's tree
     ("laguna", "xla"): "4b80b17fe2120d48",  # PR 58's tree
+    ("lfm2_moe", "flash"): "6554d5ab6a5a2c6b",  # PR 63's tree
     ("mimo_v2", "flash"): "899ec9a23ed03093",  # PR 58's tree
     ("motif", "flash"): "9a4b1e6bb3da9798",  # PR 58's tree
     ("nemotron_h", "flash"): "fc50950923070677",  # PR 58's tree
