@@ -30,6 +30,7 @@ from dlrover_tpu.ops.attention import (
     xla_window_attention,
 )
 from dlrover_tpu.ops import flash_attention as fa
+from dlrover_tpu.ops import gated_delta_rule, kda, ssd
 from dlrover_tpu.ops.flash_attention import (
     RESIDUAL_NAMES,
     SINK_SCOPE,
@@ -544,10 +545,17 @@ def remat_policy(name: str):
     and ``v`` as the kernel took them, ``out`` and ``lse`` as it wrote
     them: all in HBM for the forward already), by the names the
     kernel's forward rule gives them
-    (``ops/flash_attention.py::RESIDUAL_NAMES``), and nothing else: the
-    backward runs neither the forward kernel again nor the
-    projections, RoPE and layouts that only feed it.  With XLA
-    attention the names do not occur and everything is recomputed.
+    (``ops/flash_attention.py::RESIDUAL_NAMES``), and what a
+    recurrent rule's forward kernel wrote that anything reads after
+    it (``RESIDUAL_NAMES`` of ``ops/gated_delta_rule.py``,
+    ``ops/kda.py`` and ``ops/ssd.py``: ``o`` | ``y``, the final
+    state, the chunk-start states and, of the two delta rules, ``T``;
+    their operands are NOT kept: gradients of their own read what
+    produces them), and nothing else: the
+    backward runs neither a forward kernel again nor the
+    projections, RoPE and layouts that only feed it.  A name occurs
+    only in a program that calls its kernel: with XLA attention and
+    no recurrent rule none does and everything is recomputed.
     "offload" keeps nothing on the device, the kernel's five arrays
     neither (a layer's ``out`` alone is as many bytes as the
     ``block_in`` it moves off the device), and parks the per-block
@@ -556,7 +564,8 @@ def remat_policy(name: str):
     checkpoint)."""
     if name in ("full", "", None):
         return jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES
+            *RESIDUAL_NAMES, *gated_delta_rule.RESIDUAL_NAMES,
+            *kda.RESIDUAL_NAMES, *ssd.RESIDUAL_NAMES,
         )
     if name == "offload":
         return jax.checkpoint_policies.save_and_offload_only_these_names(
@@ -574,8 +583,13 @@ def rematted(block, prevent_cse: bool, policy: str = "full"):
     """``block`` (a module class) under ``jax.checkpoint`` with the one
     rule above.  Kept a block: its input, ``b x s x n x h`` values
     for ``n`` residual streams (1 in every family but ``motif``'s 4:
-    268 MB a boundary at 8192 x 4096 in bf16 against 67), and the
-    flash kernel's five arrays."""
+    268 MB a boundary at 8192 x 4096 in bf16 against 67), the
+    flash kernel's five arrays, and a recurrent layer's results: 3 x
+    67 MB of a KDA layer at 1 x 8192 x 32 x 128 (``o``, start states,
+    ``T``), 67 + 134 MB of a state-space layer at 64 heads of 64 x
+    128 (``y``, float32 start states), 94 + 71 + 63 MB of a gated
+    delta layer at 30 heads of 96 | 192, and about 2 MB of float32
+    final state each."""
     return nn.remat(
         block, prevent_cse=prevent_cse, policy=remat_policy(policy)
     )

@@ -190,7 +190,7 @@ class Mamba2Mixer(nn.Module):
             A = -jnp.exp(a_log)
             decay_mean = jnp.mean(jnp.exp(dt * A))
         with device_scope("ssm_scan"):
-            # (the block's remat keeps nothing of it for the backward)
+            # (the block's remat keeps what the forward kernel wrote)
             y, state = ssd_scan(x, dt, A, B, C, chunk=cfg.chunk_size)
         with device_scope("ssm_gates"):
             y = y.astype(jnp.float32) + skip[:, None] * x.astype(

@@ -59,6 +59,15 @@ five gradients; ``d Gamma [C, d_k]`` needs no reference token: a pair
 operand on either side, whatever it was scaled about.  A
 ``jax.custom_vjp`` joins them.
 
+What a rematted caller keeps.  The forward rule names what ``kda_fwd``
+wrote and anything reads after it (``RESIDUAL_NAMES``: ``o``, the
+final state, the chunk-start states, ``T``), and a ``jax.checkpoint``
+whose policy saves those names (``models/layers.py::remat_policy``)
+does not run the forward kernel again in its backward, for 3 x 67 MB
+a layer kept from forward to backward.  The five operands are not
+named: their producers (projections, convolutions, the gates' row
+kernel) run again, read by gradients of their own.
+
 The kernels take the caller's arrays as the caller holds them, viewed
 ``[b, s, h d]`` (free: a head is ``d`` whole lanes of a row), in
 blocks ``(1, CHUNK, heads a step x d)`` whose index map picks batch,
@@ -90,9 +99,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.flash_attention import _named
 from dlrover_tpu.ops.gated_delta_rule import (
     CHUNK,
     F32,
@@ -119,6 +130,10 @@ EXP_MAX = 85.0
 # Heads a grid step holds (32 a layer; the unrolled body is lowered at
 # every launch and twice the scalar rule's size).
 HEADS = 2
+# what the forward kernel writes, under the names a remat policy keeps
+# it by: ``o`` (``[b, s, h d_v]``), the final state, and the two arrays
+# only the backward kernel reads, the chunk-start states and ``T``
+RESIDUAL_NAMES = ("kda_o", "kda_final", "kda_starts", "kda_t")
 
 
 def _dot32(a, b, contract, exact):
@@ -514,9 +529,16 @@ def kda_rule(q, k, v, g, beta):
 def _rule_fwd(q, k, v, g, beta):
     b, s, h, dk = q.shape
     o, final, starts, t = _forward(*_operands(q, k, v, g, beta))
+    o_name, final_name, starts_name, t_name = RESIDUAL_NAMES
+    # ``o`` and the final state go on into the block, so they are
+    # named as bits; the other two are the residuals' alone
+    o, final = _named(o, o_name), _named(final, final_name)
     return (
         o[:, :s].reshape(v.shape), final.reshape(b, h, dk, -1)
-    ), (q, k, v, g, beta, starts, t)
+    ), (
+        q, k, v, g, beta, checkpoint_name(starts, starts_name),
+        checkpoint_name(t, t_name),
+    )
 
 
 def _rule_bwd(kept, cotangents):

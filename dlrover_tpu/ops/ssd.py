@@ -43,9 +43,18 @@ their rounding behind); and the chunk's last token takes ``<dS', S'>``
 besides.  A ``jax.custom_vjp`` joins the
 two, so what is kept for the backward is said here (the caller's five
 operands and the chunk-start states) and nothing ``chunk x chunk`` is
-a residual; a model of many such layers still calls it inside a
-rematted block (``models/layers.py::rematted``), so one layer's start
-states live at a time.
+a residual.
+
+What a rematted caller keeps.  The forward rule names what
+``ssd_fwd`` wrote and anything reads after it (``RESIDUAL_NAMES``:
+``y``, the final state, the chunk-start states), and a
+``jax.checkpoint`` whose policy saves those names
+(``models/layers.py::remat_policy``) does not run the forward kernel
+again in its backward: EVERY layer's ``y`` and start states then live
+from its forward to its backward (67 + 134 MB a layer at the sizes
+above, where one layer's lived at a time before PR 65), which is the
+price of the kernel's second run.  The operands are not named: their
+producers run again, read by gradients of their own.
 
 Layout: the operands as the model holds them.  A group's ``H / G``
 heads are ``H / G x P`` contiguous lanes of ``x [b, s, H P]`` and the
@@ -86,12 +95,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.flash_attention import _named
 from dlrover_tpu.ops.gated_delta_rule import (
     F32, NN, NT, TN, _dot, _interpret, _iotas, _lanes, _params, _split,
 )
+
+# what the forward kernel writes, under the names a remat policy keeps
+# it by: ``y`` (``[b, s, H P]``), the final state, and the array only
+# the backward kernel reads, the chunk-start states
+RESIDUAL_NAMES = ("ssd_y", "ssd_final", "ssd_starts")
 
 
 def _running_sum(x, reverse=False):
@@ -505,10 +521,14 @@ def _scan_fwd(x, dt, A, B, C, chunk):
     y, final, starts = _forward(
         *_operands(x, dt, A, B, C, chunk), chunk=chunk, p=p
     )
+    y_name, final_name, starts_name = RESIDUAL_NAMES
+    # ``y`` and the final state go on into the block, so they are
+    # named as bits; the start states are the residuals' alone
+    y, final = _named(y, y_name), _named(final, final_name)
     final = final.reshape(b, groups, n, -1, p).transpose(0, 1, 3, 4, 2)
     return (
         y[:, :s].reshape(x.shape), final.reshape(b, heads, p, n)
-    ), (x, dt, A, B, C, starts)
+    ), (x, dt, A, B, C, checkpoint_name(starts, starts_name))
 
 
 def _scan_bwd(chunk, kept, cotangents):

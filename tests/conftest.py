@@ -174,25 +174,36 @@ def flash_forwards(jaxpr):
     ]
 
 
+def kernel_calls(jaxpr, name):
+    """Where a jaxpr calls the Pallas kernel ``name``: the primitives
+    round each call."""
+    return [
+        under for under, eqn in pallas_calls(jaxpr)
+        if eqn.params["name"] == name
+    ]
+
+
 @pytest.fixture
 def remat_keeps_what_flash_reads():
-    """``check(ours, parents, forwards)``: ``jax.value_and_grad`` of
-    the loss of a rematted model with flash attention, whose blocks
-    take their policy from ``models/layers.py::remat_policy``
-    (``ours``: its jaxpr and the values of its jitted call), calls the
-    forward kernel ``forwards`` times, never under a ``checkpoint``;
-    with the parent's policy (``parents``; ``None``: keep nothing)
-    each runs a second time there, and loss and every gradient leaf
-    are the same numbers bit for bit."""
+    """``check(ours, parents, forwards, calls=flash_forwards)``:
+    ``jax.value_and_grad`` of the loss of a rematted model with flash
+    attention, whose blocks take their policy from
+    ``models/layers.py::remat_policy`` (``ours``: its jaxpr and the
+    values of its jitted call), calls the forward kernel (the calls
+    ``calls`` finds in a jaxpr: a recurrent rule's forward by its
+    name) ``forwards`` times, never under a ``checkpoint``; with the
+    parent's policy (``parents``; ``None``: keep nothing) each runs a
+    second time there, and loss and every gradient leaf are the same
+    numbers bit for bit."""
     import numpy as np
 
-    def check(ours, parents, forwards):
+    def check(ours, parents, forwards, calls=flash_forwards):
         jaxpr, kept = ours
-        where = flash_forwards(jaxpr)
+        where = calls(jaxpr)
         assert len(where) == forwards, where
         assert not any(REMAT_PRIMITIVE in under for under in where), where
         jaxpr, again = parents
-        where = flash_forwards(jaxpr)
+        where = calls(jaxpr)
         assert len(where) == 2 * forwards, where
         assert sum(
             REMAT_PRIMITIVE in under for under in where

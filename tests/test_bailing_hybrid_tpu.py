@@ -265,9 +265,11 @@ def test_ling_step_fits_the_chip(one_chip, on_tpu, tmp_path):
     published widths, 16 of 512 experts held under the group mask, a
     quarter of the vocabulary, bf16 state, flash attention, per-block
     remat, 1 x 8192 tokens): state + temporaries under the chip's
-    15.75 GiB, the rule's kernels a KDA layer (forward, its remat
-    copy, backward), the flash kernels under the module ``attn``, and
-    every scope the benchmark's readers join on in the op-name map."""
+    15.75 GiB, the rule's two kernels a KDA layer (the block keeps
+    what ``kda_fwd`` wrote: no second forward, PR 65) between the row
+    kernels' three (forward, their remat copy, backward), the flash
+    kernels under the module ``attn``, and every scope the benchmark's
+    readers join on in the op-name map."""
     from dlrover_tpu.common.aot_cache import op_names
     from dlrover_tpu.models.bailing_hybrid import (
         BailingHybrid,
@@ -303,17 +305,24 @@ def test_ling_step_fits_the_chip(one_chip, on_tpu, tmp_path):
         f"{(2 * reserved - mem.temp_size_in_bytes) / 1e9:.3f} live at "
         f"once, {mem.temp_size_in_bytes / 1e9:.3f} reported"
     )
-    # offline compile: 3.90 GB reported, 3.17 reserved since PR 62
-    # (the mixer's float32 passes hold no copies); 4.44 | 3.72 before
-    assert mem.temp_size_in_bytes < 4.8e9
+    # offline compile: 4.85 GB reported, 4.17 reserved since PR 65
+    # (six KDA blocks keep ``o``, the chunk-start states and ``T``,
+    # 3 x 67 MB each and 1.2 GB in all, for the forward kernel's
+    # second run); 3.90 | 3.17 since PR 62 (the mixer's float32 passes
+    # hold no copies); 4.44 | 3.72 before.  The limit is PR 62's 4.8
+    # GB and what is kept
+    assert mem.temp_size_in_bytes < 4.8e9 + 6 * 3 * 67.2e6
     assert (
         mem.argument_size_in_bytes + mem.temp_size_in_bytes
         < 15.75 * 2**30
     )
     text = compiled.as_text()
-    assert _calls(compiled, "kda_fwd") == 12
+    # the rule once forward and once backward a layer: its forward is
+    # in no block's remat copy (12 before PR 65)
+    assert _calls(compiled, "kda_fwd") == 6
     assert _calls(compiled, "kda_bwd") == 6
-    # the gates and the head norm round the rule, the same three times
+    # the gates and the head norm round the rule three times: gradients
+    # of their own read what they wrote, so they stay in the copy
     for rows in ("kda_gates", "kda_norm"):
         assert _calls(compiled, f"{rows}_fwd") == 12
         assert _calls(compiled, f"{rows}_bwd") == 6

@@ -160,9 +160,9 @@ def test_state_handed_over_equals_the_recurrences_at_every_boundary(
 @pytest.mark.parametrize("regime", ["plain", "beta2-strong"])
 def test_gradients_under_an_enclosing_remat_are_the_same(regime):
     """``jax.grad`` of the rule inside a ``jax.checkpoint`` of the
-    function around it (the model's per-block remat: the forward runs
-    again, then the ``custom_vjp``'s backward) equals the same
-    without."""
+    function around it (a remat that keeps nothing, as the model's
+    per-block remat did before PR 65: the forward runs again, then the
+    ``custom_vjp``'s backward) equals the same without."""
     x = operands(gdr.CHUNK + 9, *REGIMES[regime])
     weights = jax.random.normal(jax.random.PRNGKey(9), x[2].shape)
 

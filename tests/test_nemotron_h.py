@@ -292,14 +292,17 @@ def test_the_chunked_scan_is_the_recurrence(dtype, seq, chunk, shape, limit):
 
 
 @pytest.mark.parametrize("remat", [True, False])
-def test_a_rematted_block_keeps_nothing_of_the_scan(remat):
+def test_a_rematted_block_keeps_what_the_scan_wrote(remat):
     """What the scan's backward reads is said by its ``custom_vjp``:
     the caller's five operands and the float32 state each chunk starts
-    from.  Under the block's remat nothing of the scan is a residual
-    of the model's loss (the block's backward runs ``ssd_fwd`` again);
-    without it the chunk-start states of both state-space layers are,
-    beside the operands, and nothing ``chunk x chunk`` either way (six
-    chunks of 8 here, so that no other array has that shape)."""
+    from.  Under the block's remat the residuals of the model's loss
+    that the scan's file names are what ``ssd_fwd`` WROTE and the
+    backward reads, ``y`` (as bits: ``flash_attention._named``) and
+    the chunk-start states of both state-space layers, so the block's
+    backward does not run ``ssd_fwd`` again, and none of the operands
+    (their producers run again); without it the start states and the
+    operands are, and nothing ``chunk x chunk`` either way (six chunks
+    of 8 here, so that no other array has that shape)."""
     model, params, batch = toy(remat=remat, chunk_size=8)
     saved = jax_internal("ad_checkpoint", "saved_residuals")(
         lambda p: make_nemotron_h_loss(model, num_chunks=4)(p, batch)[0],
@@ -311,8 +314,10 @@ def test_a_rematted_block_keeps_nothing_of_the_scan(remat):
         cfg.ssm_inner // cfg.ssm_groups,
     )
     of_the_scan = [a for a, said in saved if "ops/ssd.py" in said]
-    assert [(a.shape, a.dtype) for a in of_the_scan] == (
-        [] if remat else [(starts, jnp.float32)] * 2
+    y = ((2, 48, cfg.ssm_inner), jnp.uint32)
+    assert sorted((a.shape, str(a.dtype)) for a in of_the_scan) == sorted(
+        (shape, str(jnp.dtype(dtype)))
+        for shape, dtype in [(starts, jnp.float32)] * 2 + [y] * 2 * remat
     )
     shapes = [a.shape for a, _ in saved]
     assert not [s for s in shapes if s[-2:] == (8, 8)]

@@ -84,18 +84,23 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(
     # up projection's kernel, which writes the hidden rows and keeps
     # nothing else: the derivative takes ``relu(u)`` as their root):
     # 3.644 GiB reserved, 3.292 live at once, 3.996 reported, each
-    # under PR 49's
+    # under PR 49's.  Since PR 65 the eight state-space blocks keep
+    # what ``ssd_fwd`` wrote (``y`` 67 MB and the float32 start states
+    # 134 MB a layer, 1.5 GiB in all) so that it runs once: 5.060 GiB
+    # reserved, 4.420 live at once, 5.699 reported; the limits are
+    # those that stood and what is kept
+    kept = 8 * (1 * 8192 * 4096 * 2 + 64 * 8 * 128 * 512 * 4)
     temp = mem.temp_size_in_bytes
     live = 2 * reserved - temp
     print(
         f"nemotron step temporaries: {reserved / 2**30:.3f} GiB reserved, "
         f"{live / 2**30:.3f} live at once, {temp / 2**30:.3f} reported"
     )
-    assert reserved < 3.75 * 2**30, (
-        f"{reserved / 2**30:.3f} GiB reserved where 3.644 was read"
+    assert reserved < 3.75 * 2**30 + kept, (
+        f"{reserved / 2**30:.3f} GiB reserved where 5.060 was read"
     )
-    assert live < 3.4 * 2**30, (
-        f"{live / 2**30:.3f} GiB live at once where 3.292 was read"
+    assert live < 3.4 * 2**30 + kept, (
+        f"{live / 2**30:.3f} GiB live at once where 4.420 was read"
     )
     assert (
         mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -111,8 +116,9 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(
     # forward, dq, dkv in each of the two attention layers
     assert len(flash) == 3 * 2
     assert all("/full_attn/attn/" in stacks[c] for c in flash)
-    # the state-space scan: eight layers' forward and the block's
-    # remat copy, one backward each, all under the scan's scope
+    # the state-space scan: eight layers' forward and one backward
+    # each, all under the scan's scope; the block keeps what the
+    # forward wrote, so its remat copy holds none (16 before PR 65)
     scan = [c for c in calls if "ssd_" in c]
     assert all("/ssm_scan/" in stacks[c] for c in scan)
     kinds = [
@@ -124,7 +130,7 @@ def test_nemotron_eighteen_layer_step_fits_the_chip(
     assert {kind: kinds.count(kind) for kind in kinds} == {
         **_expert_kernels(8, gated=False),
         "gmm_tokens_from_rows": 2 * 8, "gmm_unwritten": 3 * 8,
-        "ssd_fwd": 2 * 8, "ssd_bwd": 8,
+        "ssd_fwd": 8, "ssd_bwd": 8,
         # x, B and C, each a window of the projection's lanes
         "conv_fwd": 3 * 2 * 8, "conv_bwd": 3 * 8,
     }

@@ -35,8 +35,9 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
     the three flash kernels under the module ``attn`` (the block keeps
     the five arrays the kernel's backward reads: no second forward,
     PR 44 and PR 45), and
-    under each linear layer's ``gdn_rule`` scope two ``gdn_fwd``
-    (forward, the block's remat copy) and one ``gdn_bwd``."""
+    under each linear layer's ``gdn_rule`` scope one ``gdn_fwd`` and
+    one ``gdn_bwd`` (the block keeps ``o``, the chunk-start states and
+    ``T`` as the kernel wrote them: no second forward, PR 65)."""
     from dlrover_tpu.common.aot_cache import op_names
     from dlrover_tpu.models.olmo_hybrid import (
         PERIOD,
@@ -76,9 +77,13 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
     # layer kept its q, k and v, 3 x 62.9 MB: the peak is not in that
     # layer's backward, so 0.19 GB kept shows as nothing); 4.07 GB
     # with the convolutions as kernels (4,069,591,040 B, PR 49: the
-    # padded float32 copies of q, k and v are gone): the limit is
-    # what stood before them
-    assert mem.temp_size_in_bytes <= 4_441_295_360
+    # padded float32 copies of q, k and v are gone); 4.52 GB since
+    # PR 65 (4,522,255,872 B: the three linear blocks keep ``o``, the
+    # chunk-start states and ``T`` as ``gdn_fwd`` wrote them, 94.4 +
+    # 70.8 + 62.9 MB a layer, for the kernel's second run): the limit
+    # is PR 49's reading and what is kept
+    kept = 3 * 8192 * 30 * (192 + 96 * 192 // 128 + 128) * 2
+    assert mem.temp_size_in_bytes <= 4_069_591_040 + kept
     text = compiled.as_text()
     # the head: 3 vocabulary-sized matmuls a chunk of 8192 / 8 tokens
     # (logits, d_hidden, d_kernel: 3 x 8 a step, where the
@@ -119,7 +124,7 @@ def test_olmo_hybrid_one_period_step_fits_the_chip(one_chip, on_tpu):
          re.search(r"gdn_(fwd|bwd)", c).group(1))
         for c in rule
     ) == sorted(
-        (str(i), kind) for i in range(3) for kind in ("fwd", "fwd", "bwd")
+        (str(i), kind) for i in range(3) for kind in ("fwd", "bwd")
     )
     # (bare forward, ``transpose(jvp(gdn_rule))`` backward)
     assert all(

@@ -1,20 +1,24 @@
 """What a rematted block keeps and what it runs again for its
-backward, in the four families whose blocks sit behind
+backward, in the six families whose blocks sit behind
 ``prevent_cse=True``: with flash attention the one remat policy keeps
 the kernel's five residuals
 (``ops/flash_attention.py::RESIDUAL_NAMES``), so the forward kernel is
 not run again and nothing that stands before it only to feed it is in
-the rematted computation; with XLA attention nothing is named and the
-program is the parent policy's.  A family's toy is built, traced under
-both policies and run ONCE for the tests that read it."""
+the rematted computation; a recurrent rule's forward kernel
+(``gdn_fwd``, ``kda_fwd``, ``ssd_fwd``) names its own results and the
+policy keeps those, so it runs once a layer; with XLA attention and no
+such rule nothing is named and the program is the parent policy's.  A
+family's toy is built, traced under both policies and run ONCE for the
+tests that read it."""
 
+import functools
 import os
 import sys
 
 import jax
 import numpy as np
 import pytest
-from conftest import REMAT_PRIMITIVE, equations
+from conftest import REMAT_PRIMITIVE, equations, kernel_calls
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -25,7 +29,8 @@ from dlrover_tpu.models import layers  # noqa: E402
 
 # family -> (attention's matmuls that feed only the kernel, those whose
 # results something else's gradient reads and that are run again, the
-# flash forward kernels of the toy's gradient)
+# flash forward kernels of the toy's gradient, the forward kernel of
+# the family's recurrent rule and the toy's layers that call it)
 FAMILIES = {
     "laguna": (
         {"q_proj", "k_proj", "v_proj"},
@@ -33,29 +38,46 @@ FAMILIES = {
         # and the kernel's output
         {"g_proj", "o_proj"},
         # one a layer: a full one, two sliding
-        3,
+        3, None,
     ),
     "sarvam_mla": (
         {"q_proj", "kv_up"},
         # ``kv_up``'s gradient reads the normed latent
         {"kv_down", "o_proj"},
         # one a layer (heads of 24 | 16)
-        3,
+        3, None,
     ),
     # one an application: two in the body of the scan over the passes
-    "ouro": ({"q_proj", "k_proj", "v_proj"}, {"o_proj"}, 2),
+    "ouro": ({"q_proj", "k_proj", "v_proj"}, {"o_proj"}, 2, None),
     # QK-norm's gradient reads the un-normed q and k; one kernel in the
-    # period's one full-attention layer
-    "olmo_hybrid": ({"v_proj"}, {"q_proj", "k_proj", "o_proj"}, 1),
+    # period's one full-attention layer, the rule in the three before
+    "olmo_hybrid": (
+        {"v_proj"}, {"q_proj", "k_proj", "o_proj"}, 1, ("gdn_fwd", 3)
+    ),
+    # (KDA, KDA, latent: as ``sarvam_mla`` plus the head-wise gate,
+    # which reads the block's normed input)
+    "bailing_hybrid": (
+        {"q_proj", "kv_up"}, {"kv_down", "g_proj", "o_proj"}, 1,
+        ("kda_fwd", 2),
+    ),
+    # (MEM*EM: nothing reads ``o_proj``'s result but the residual sum,
+    # so no attention matmul is run again)
+    "nemotron_h": (
+        {"q_proj", "k_proj", "v_proj"}, set(), 1, ("ssd_fwd", 3)
+    ),
 }
+# (a family's toy is ``configs/toy_<family>.json`` but for)
+TOYS = {"bailing_hybrid": "toy_ling"}
+RECURRENT_FORWARDS = ("gdn_fwd", "kda_fwd", "ssd_fwd")
 
 
 def toy_loss(family, attention):
     """``(loss of the parameters alone, params)`` of the family's toy
     configuration in float32 with remat, at 2 x 128 tokens."""
-    cfg = loader.load_json(
-        os.path.join(REPO, "benchmarks", "configs", f"toy_{family}.json")
-    )
+    cfg = loader.load_json(os.path.join(
+        REPO, "benchmarks", "configs",
+        TOYS.get(family, f"toy_{family}") + ".json",
+    ))
     cfg["recipe"].update(
         param_dtype="float32", compute_dtype="float32",
         attention=attention, remat=True,
@@ -119,16 +141,16 @@ def test_a_rematted_block_runs_nothing_again_only_to_feed_its_flash_kernel(
     ones a gradient reads, and loss and every gradient leaf are the
     parent policy's numbers bit for bit."""
     family, (jaxpr, kept), (parents_jaxpr, parents) = traced
-    dead, alive, _ = FAMILIES[family]
-    again = recomputed_attention_matmuls(jaxpr)
+    dead, alive, _, _ = FAMILIES[family]
+    again = recomputed_attention_matmuls(parents_jaxpr)
     assert again, (
         "no recomputed attention matmul found: does jax "
         f"{jax.__version__} still head a rematted equation's name "
         "stack with 'rematted_computation'?"
     )
-    assert not again & dead and alive <= again, again
-    again = recomputed_attention_matmuls(parents_jaxpr)
     assert dead | alive <= again, again
+    again = recomputed_attention_matmuls(jaxpr)
+    assert not again & dead and alive <= again, again
     for ours, theirs in zip(
         jax.tree.leaves(kept), jax.tree.leaves(parents), strict=True
     ):
@@ -137,20 +159,42 @@ def test_a_rematted_block_runs_nothing_again_only_to_feed_its_flash_kernel(
         )
 
 
-@pytest.mark.parametrize("attention", ["flash", "xla"])
 def test_a_rematted_block_keeps_what_its_flash_backward_reads(
-    attention, traced, remat_keeps_what_flash_reads,
-    remat_with_xla_attention_is_the_parents,
+    traced, remat_keeps_what_flash_reads
 ):
     """One forward kernel a layer (an application in ``ouro``, whose
     block takes its policy where the other families take theirs), none
     of them run again for the backward; loss and gradients the parent
-    policy's bit for bit (``olmo_hybrid``'s linear layers' rule keeps
-    what it kept).  With XLA attention nothing is named and the
-    program is the parent's."""
+    policy's bit for bit."""
     family, ours, parents = traced
-    _, _, forwards = FAMILIES[family]
-    if attention == "xla":
-        remat_with_xla_attention_is_the_parents(*toy_loss(family, "xla"))
-    else:
-        remat_keeps_what_flash_reads(ours, parents, forwards)
+    remat_keeps_what_flash_reads(ours, parents, FAMILIES[family][2])
+
+
+def test_a_rematted_block_runs_no_recurrent_forward_again(
+    traced, remat_keeps_what_flash_reads
+):
+    """A recurrent rule's forward kernel runs once a layer that calls
+    it and never in the rematted computation: the rule names what it
+    writes (``o``, the final state, the chunk-start states, ``T``) and
+    the policy keeps the names, all of one ``pallas_call``'s live
+    results or the call stays.  Under the parent's policy each runs a
+    second time there; the numbers are the same bit for bit.  A family
+    with no such rule calls none of the three."""
+    family, ours, parents = traced
+    kernel, layers_with = FAMILIES[family][3] or (None, 0)
+    for name in RECURRENT_FORWARDS:
+        remat_keeps_what_flash_reads(
+            ours, parents, layers_with if name == kernel else 0,
+            calls=functools.partial(kernel_calls, name=name),
+        )
+
+
+@pytest.mark.parametrize(
+    "family", [name for name, kept in FAMILIES.items() if not kept[3]]
+)
+def test_with_xla_attention_and_no_recurrent_rule_nothing_is_named(
+    family, remat_with_xla_attention_is_the_parents
+):
+    """With XLA attention, in a family whose layers call no recurrent
+    rule, no name occurs and the program is the parent's."""
+    remat_with_xla_attention_is_the_parents(*toy_loss(family, "xla"))

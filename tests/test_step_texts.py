@@ -31,15 +31,15 @@ from dlrover_tpu.models.gpt import (  # noqa: E402
 # nothing; ``gpt`` is the loss its two cells run
 # (``benchmarks/models/gpt2.py::build``).
 PINS = {
-    ("bailing_hybrid", "flash"): "b1a372cd028d185f",  # PR 62's tree
+    ("bailing_hybrid", "flash"): "aff0de98befe6952",  # PR 65's tree
     ("gpt", "flash"): "d1d2b7b0b9ff02ee",  # PR 61's tree
     ("laguna", "flash"): "322e01c44b37bb48",  # PR 61's tree
     ("laguna", "xla"): "4b80b17fe2120d48",  # PR 58's tree
     ("lfm2_moe", "flash"): "6554d5ab6a5a2c6b",  # PR 63's tree
     ("mimo_v2", "flash"): "899ec9a23ed03093",  # PR 58's tree
     ("motif", "flash"): "9a4b1e6bb3da9798",  # PR 58's tree
-    ("nemotron_h", "flash"): "fc50950923070677",  # PR 58's tree
-    ("olmo_hybrid", "flash"): "05248f4221689fa7",  # PR 58's tree
+    ("nemotron_h", "flash"): "b0da0cfa3f14116c",  # PR 65's tree
+    ("olmo_hybrid", "flash"): "0a5c5afab24c6619",  # PR 65's tree
     ("olmoe", "flash"): "6ca9f44ed37f52b2",  # PR 58's tree
     ("ouro", "flash"): "f1eaf19b556d3257",  # PR 61's tree
     ("sarvam_mla", "flash"): "1f4eb1e29d808c63",  # PR 58's tree
