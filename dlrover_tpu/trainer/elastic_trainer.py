@@ -480,6 +480,48 @@ def _chip_metrics() -> str:
     return "\n".join(lines)
 
 
+@dataclass
+class _StepReport:
+    """One completed step as ``report_step`` hands it over, written
+    at once or inside the next step's ``compute`` phase.  ``metrics``
+    are the device arrays they were: nothing is fetched before the
+    write.  ``phases`` and ``ts`` are set where the write comes AFTER
+    the step closed: its breakdown, and when it completed (the ``ts``
+    of its two events and the metrics file's ``timestamp``)."""
+
+    step: int
+    epoch: int
+    metrics: Dict[str, Any]
+    phases: Optional[Dict[str, float]] = None
+    ts: Optional[float] = None
+
+    def stamp(self) -> Dict[str, float]:
+        """The events' own ``ts`` (``emit_many``: an event written
+        after it happened); none where they are written as it does."""
+        return {} if self.ts is None else {"ts": self.ts}
+
+    def scalars(self) -> Dict[str, float]:
+        """The metrics' scalars on the host after ONE device-to-host
+        fetch (a ``float()`` each is a round trip each: 10-30 of
+        them a step in the newer families)."""
+        fetched = jax.device_get({
+            k: v for k, v in self.metrics.items() if np.ndim(v) == 0
+        })
+        scalars = {}
+        for name, value in fetched.items():
+            try:
+                scalars[name] = float(value)
+            except (TypeError, ValueError):
+                pass
+        return scalars
+
+
+def _flush_at_exit(trainer_ref):
+    trainer = trainer_ref()
+    if trainer is not None:
+        trainer.flush_reports()
+
+
 class ElasticTrainer:
     """Step/epoch accounting with a fixed global batch across resizes
     (reference: trainer.py GradientState + _ElasticOptimizer)."""
@@ -515,6 +557,17 @@ class ElasticTrainer:
         # metrics-file record for the agent's collectors)
         self.profiler = StepPhaseProfiler()
         self.last_step_phases: Dict[str, float] = {}
+        # the report of the last step, where it waits for the next
+        # step's ``compute`` phase, and whether the step in progress
+        # bracketed its compute with ``block``
+        self._pending: Optional[_StepReport] = None
+        self._compute_blocked = False
+        # (imported here: no line above ``make_train_step`` may move,
+        # tests/test_step_report.py)
+        import atexit
+        import weakref
+
+        atexit.register(_flush_at_exit, weakref.ref(self))
         _GRAD_ACCUM_GAUGE.set(self.grad_accum)
         devices = jax.local_devices()
         emit_event(
@@ -547,78 +600,122 @@ class ElasticTrainer:
         """Samples this data-parallel rank consumes per step."""
         return self.micro_batch_size * self.grad_accum
 
+    @contextmanager
     def profile(self, name: str):
         """``with trainer.profile("data_wait"): batch = next(it)`` —
         see :class:`StepPhaseProfiler`.  For the compute phase,
         ``with trainer.profile("compute") as p: state, m = step(...);
         p.block(m)`` brackets the device work with
-        ``block_until_ready``."""
-        return self.profiler.phase(name)
+        ``block_until_ready``; the report of the step BEFORE is
+        written there, after the dispatch and before the block, while
+        the device computes (see :meth:`report_step`)."""
+        with self.profiler.phase(name) as handle:
+            yield handle
+            if name == "compute" and handle.pending is not None:
+                self._compute_blocked = True
+                if self._pending is not None:
+                    with self.profiler.phase("compute.report"):
+                        self._write_pending("compute.report")
 
     def report_step(self, metrics: Optional[Dict[str, float]] = None):
-        """Advance the step counter and write the metrics file the
-        agent monitor tails (reference: trainer.py report to file +
-        monitor/training.py).  All of it is the step's ``report``
-        phase, in three sub-phases: ``report.events``,
-        ``report.chip_metrics`` and ``report.metrics_file``."""
+        """Advance the step counter and close the step's phases; the
+        step's report (the ``train_step`` event, the chip's memory
+        line, the metrics file the agent monitor tails, the
+        ``step_phases`` event; reference: trainer.py report to file +
+        monitor/training.py) is written beside the NEXT step and not
+        between two steps, where the device would wait for it.
+
+        A step whose ``compute`` phase was bracketed with ``block``
+        hands its report over: it is written inside the next such
+        phase, booked there as ``compute.report`` (sub-phases
+        ``.events``, ``.chip_metrics``, ``.metrics_file``), under its
+        own step number and with the time it completed as ``ts``, and
+        this call's ``report`` phase is the hand-over alone.  The
+        events and the file so lag the chip by one step; whatever is
+        still pending is written by the next ``report_step``, by
+        :meth:`flush_reports` and at interpreter exit, never on entry
+        to ``checkpoint`` (a save's stall is the save's).  A loop
+        without such a phase, and every process with a fault injector
+        armed (its rules are placed against the log as it is written
+        step by step: a kill at step N leaves step N's event and
+        fires before the save that follows), write at once, all of it
+        in ``report`` (``report.events``, ``report.chip_metrics``,
+        ``report.metrics_file``)."""
         prof = self.profiler
+        deferred = self._compute_blocked and not _chaos.chaos_enabled()
+        self._compute_blocked = False
         with prof.phase("report"):
-            with prof.phase("report.events"):
-                self._emit_train_step(metrics)
-            with prof.phase("report.chip_metrics"):
-                chip_metrics = _chip_metrics()
-            with prof.phase("report.metrics_file"):
-                # the file's ``phases`` are the step's so far: its
-                # own write is still open, so counted up to here
-                self._write_metrics_file(
-                    metrics, chip_metrics, prof.peek()
-                )
+            self._write_pending("report")
+            # (a copy: the loop may reuse its dict before the write;
+            # made before the counter moves, so metrics that are no
+            # dict raise with nothing advanced)
+            report = _StepReport(
+                self.global_step + 1, self._epoch, dict(metrics or {})
+            )
+            self.global_step = report.step
+            _REPORTED_STEP.set(self.global_step)
+            if not deferred:
+                self._write_step(report, "report")
         # close the step's phase breakdown: everything since the last
         # report (minus profiled phases) is "other"
-        phases = prof.finish_step()
-        self.last_step_phases = phases
-        for name, seconds in phases.items():
-            if name == "total_s":
-                continue
-            _STEP_PHASE_SECONDS.observe(
-                seconds,
-                phase="other" if name == "other_s" else name,
-            )
+        report.phases = self.last_step_phases = prof.finish_step()
         prof.step = self.global_step + 1
+        if deferred:
+            report.ts = time.time()
+            self._pending = report
+            return
         # the breakdown's own event is the first thing the next step
         # pays for: booked to its ``report``, not left in ``other``
         with prof.phase("report"), prof.phase("report.events"):
-            # dict-build instead of kwargs so a user phase named
-            # "step" can never collide with the envelope fields
-            emit_event("step_phases", **{
-                **phases,
-                "step": self.global_step,
-                "node_rank": env_utils.get_node_rank(),
-            })
+            self._write_phases(report)
 
-    def _emit_train_step(self, metrics):
-        self.global_step += 1
-        _REPORTED_STEP.set(self.global_step)
+    def flush_reports(self):
+        """Write the report that waits for a next step which may not
+        come: a loop's own last line, and a test's before it reads
+        the log or the metrics file."""
+        if self._pending is not None:
+            with self.profiler.phase("report"):
+                self._write_pending("report")
+
+    def _write_pending(self, phase: str):
+        """The pending report, if any, in sub-phases of the open
+        ``phase``."""
+        report, self._pending = self._pending, None
+        if report is not None:
+            self._write_step(report, phase)
+            with self.profiler.phase(phase + ".events"):
+                self._write_phases(report)
+
+    def _write_step(self, report: _StepReport, phase: str):
+        """The step's event and the metrics file, in three sub-phases
+        of ``phase``."""
+        prof = self.profiler
+        with prof.phase(phase + ".events"):
+            scalars = report.scalars()
+            self._emit_train_step(report, scalars)
+        with prof.phase(phase + ".chip_metrics"):
+            chip_metrics = _chip_metrics()
+        with prof.phase(phase + ".metrics_file"):
+            self._write_metrics_file(report, scalars, chip_metrics)
+
+    def _emit_train_step(self, report, scalars):
         # per-step training event: this is what lets the chaos
         # invariant checkers compute "steps lost across a fault" from
         # the event log alone (no-op unless an event log is configured)
         step_event = {
-            "step": self.global_step,
+            "step": report.step,
             "restart_count": self._restart_count,
             # which node stepped: multi-agent chaos invariants decide
             # per-node progress from the event log alone
             "node_rank": env_utils.get_node_rank(),
         }
-        if metrics and "loss" in metrics:
+        if "loss" in scalars:
             # the elastic-resize loss-trajectory invariant compares
             # same-step losses across incarnations and world sizes —
             # a resharded restore that mangled the params shows up
             # as a divergence here, decided from the log alone
-            try:
-                step_event["loss"] = float(metrics["loss"])
-            except (TypeError, ValueError):
-                pass
-        for name, value in (metrics or {}).items():
+            step_event["loss"] = scalars["loss"]
+        for name, value in scalars.items():
             # a model's own counters (``has_aux`` of make_train_step:
             # a sparse model's routing, a linear-attention model's
             # state, a windowed model's walk, a looped model's exits,
@@ -632,32 +729,27 @@ class ElasticTrainer:
                 "moe.", "gdn.", "attn.", "loop.", "ssm.", "mhc.", "gdla.",
                 "mtp.", "kda.", "sconv.",
             )):
-                step_event[name] = float(value)
-        emit_event("train_step", **step_event)
+                step_event[name] = value
+        emit_event("train_step", **step_event, **report.stamp())
         # chaos hook AFTER the event: a kill rule at step N must leave
         # step N's completion in the log before the process dies; a
         # slow rule stretches the observable step time (straggler)
-        _chaos.fire("trainer.step", step=self.global_step)
+        _chaos.fire("trainer.step", step=report.step)
 
-    def _write_metrics_file(self, metrics, chip_metrics, phases):
+    def _write_metrics_file(self, report, scalars, chip_metrics):
         record = {
-            "global_step": self.global_step,
-            "timestamp": time.time(),
-            "epoch": self._epoch,
+            "global_step": report.step,
+            "timestamp": report.ts or time.time(),
+            "epoch": report.epoch,
             # the agent's StepPhaseCollector ships these to the
-            # master's diagnosis chain (data-starved detection)
-            "phases": phases,
+            # master's diagnosis chain (data-starved detection): the
+            # closed step's or, written at once, the step's so far
+            # (its own write is still open, so counted up to here)
+            "phases": report.phases or self.profiler.peek(),
         }
         if chip_metrics:
             record["chip_metrics"] = chip_metrics
-        if metrics:
-            record.update(
-                {
-                    k: float(v)
-                    for k, v in metrics.items()
-                    if jnp.isscalar(v) or getattr(v, "ndim", 1) == 0
-                }
-            )
+        record.update(scalars)
         tmp = self._metrics_path + ".tmp"
         try:
             with open(tmp, "w") as f:
@@ -665,6 +757,24 @@ class ElasticTrainer:
             os.replace(tmp, self._metrics_path)
         except OSError as e:
             logger.debug("metrics file write failed: %s", e)
+
+    def _write_phases(self, report):
+        """The closed step's breakdown: histograms and its event."""
+        for name, seconds in report.phases.items():
+            if name == "total_s":
+                continue
+            _STEP_PHASE_SECONDS.observe(
+                seconds,
+                phase="other" if name == "other_s" else name,
+            )
+        # dict-build instead of kwargs so a user phase named
+        # "step" can never collide with the envelope fields
+        emit_event("step_phases", **{
+            **report.phases,
+            "step": report.step,
+            "node_rank": env_utils.get_node_rank(),
+            **report.stamp(),
+        })
 
     def set_epoch(self, epoch: int):
         self._epoch = epoch

@@ -223,6 +223,8 @@ class Trainer:
                 except StopIteration:
                     data_iter = iter(self.train_data)
                     batch = next(data_iter)
+        # the last step's report has no next step to be written in
+        self._elastic.flush_reports()
         # final storage save (it waits for an in-flight snapshot
         # itself); then its commit, so a process exit right after
         # train() cannot lose it

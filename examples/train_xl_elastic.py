@@ -290,6 +290,8 @@ def run_elastic(args):
             with trainer.profile("checkpoint"):
                 flash_save()
 
+    # the last step's report has no next step to be written in
+    trainer.flush_reports()
     # the last state goes to disk through the agent before exit
     ckpt.wait()
     flash_save()

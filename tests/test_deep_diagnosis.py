@@ -67,6 +67,8 @@ def test_profiler_phase_breakdown_and_event(event_log, tmp_path):
         x = jnp.ones(8) * 2
         p.block(x)
     trainer.report_step({"loss": 0.5})
+    # (a blocked compute phase: the report waits for the next step)
+    trainer.flush_reports()
 
     phases = trainer.last_step_phases
     assert phases["data_wait"] >= 0.015
