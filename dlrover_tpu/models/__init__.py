@@ -6,6 +6,7 @@ from dlrover_tpu.models.bailing_hybrid import (
     BailingHybridConfig,
 )
 from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models.jamba import Jamba, JambaConfig
 from dlrover_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from dlrover_tpu.models.llama import Llama, LlamaConfig
 from dlrover_tpu.models.mimo_v2 import MiMoV2, MiMoV2Config
@@ -24,6 +25,8 @@ __all__ = [
     "BailingHybridConfig",
     "GPT",
     "GPTConfig",
+    "Jamba",
+    "JambaConfig",
     "Lfm2Moe",
     "Lfm2MoeConfig",
     "Llama",

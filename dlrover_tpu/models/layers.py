@@ -30,7 +30,7 @@ from dlrover_tpu.ops.attention import (
     xla_window_attention,
 )
 from dlrover_tpu.ops import flash_attention as fa
-from dlrover_tpu.ops import gated_delta_rule, kda, ssd
+from dlrover_tpu.ops import gated_delta_rule, kda, selective_scan, ssd
 from dlrover_tpu.ops.flash_attention import (
     RESIDUAL_NAMES,
     SINK_SCOPE,
@@ -548,7 +548,8 @@ def remat_policy(name: str):
     (``ops/flash_attention.py::RESIDUAL_NAMES``), and what a
     recurrent rule's forward kernel wrote that anything reads after
     it (``RESIDUAL_NAMES`` of ``ops/gated_delta_rule.py``,
-    ``ops/kda.py`` and ``ops/ssd.py``: ``o`` | ``y``, the final
+    ``ops/kda.py``, ``ops/ssd.py`` and ``ops/selective_scan.py``:
+    ``o`` | ``y``, the final
     state, the chunk-start states and, of the two delta rules, ``T``;
     their operands are NOT kept: gradients of their own read what
     produces them), and nothing else: the
@@ -566,6 +567,7 @@ def remat_policy(name: str):
         return jax.checkpoint_policies.save_only_these_names(
             *RESIDUAL_NAMES, *gated_delta_rule.RESIDUAL_NAMES,
             *kda.RESIDUAL_NAMES, *ssd.RESIDUAL_NAMES,
+            *selective_scan.RESIDUAL_NAMES,
         )
     if name == "offload":
         return jax.checkpoint_policies.save_and_offload_only_these_names(
@@ -588,7 +590,8 @@ def rematted(block, prevent_cse: bool, policy: str = "full"):
     67 MB of a KDA layer at 1 x 8192 x 32 x 128 (``o``, start states,
     ``T``), 67 + 134 MB of a state-space layer at 64 heads of 64 x
     128 (``y``, float32 start states), 94 + 71 + 63 MB of a gated
-    delta layer at 30 heads of 96 | 192, and about 2 MB of float32
+    delta layer at 30 heads of 96 | 192, 84 + 21 MB of a selective
+    scan at 5120 channels x 16 lanes, and about 2 MB of float32
     final state each."""
     return nn.remat(
         block, prevent_cse=prevent_cse, policy=remat_policy(policy)

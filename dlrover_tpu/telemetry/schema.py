@@ -134,6 +134,10 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         # convolution's output y = C * conv(B * u) over the conv
         # layers (models/lfm2_moe.py): a product of three projections
         # of one input, what grows first if the mixer's scale drifts
+        # s6.*: the selective scans (models/jamba.py): the largest rms
+        # of a layer's final state, the mean of exp(dt A) over every
+        # 64th row, the channels, the lanes and the layers, and the
+        # mean step dt
         _s("train_step", ["step", "restart_count", "node_rank"],
            ["loss", "moe.load_max_over_mean", "moe.lb_loss",
             "moe.z_loss", "gdn.state_rms_max", "moe.held_rows_share",
@@ -145,7 +149,8 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
             "mhc.res_sum_err_max", "gdla.lambda_mean",
             "gdla.noise_share", "mtp.loss", "kda.log_decay_min",
             "kda.state_rms_max", "moe.groups_per_token_mean",
-            "sconv.out_rms_max"]),
+            "sconv.out_rms_max", "s6.state_rms_max", "s6.decay_mean",
+            "s6.dt_mean"]),
         _s("loss_spike", ["step", "loss", "ema", "factor"]),
         # which devices the trainer process owns (its own
         # jax.local_devices()): the agent never opens the chip, so

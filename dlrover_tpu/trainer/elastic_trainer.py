@@ -727,7 +727,7 @@ class ElasticTrainer:
             # 0.65 ms a step (PERF.md, PR 25)
             if name.startswith((
                 "moe.", "gdn.", "attn.", "loop.", "ssm.", "mhc.", "gdla.",
-                "mtp.", "kda.", "sconv.",
+                "mtp.", "kda.", "sconv.", "s6.",
             )):
                 step_event[name] = value
         emit_event("train_step", **step_event, **report.stamp())

@@ -206,20 +206,22 @@ def test_the_benchmark_gains_one_configuration_one_cell_five_readers():
     (config,) = [
         c for c in bench["configs"] if c["name"] == "lfm2_24b_a2b_cut"
     ]
-    assert config == bench["configs"][-1]
     assert config["reduced"] == CUT["reduced"]
     assert config["source"] == CUT["source"]
     assert config["file"] == "benchmarks/configs/lfm2_24b_a2b_cut.json"
     cells = [
         w for w in bench["workloads"] if w["config"] == "lfm2_24b_a2b_cut"
     ]
-    assert cells == [bench["workloads"][-1]] == [{
+    assert cells == [{
         "name": "lfm2_moe_steady_8k", "config": "lfm2_24b_a2b_cut",
         "traffic": "steady_8k", "chips": 1, "why": cells[0]["why"],
     }]
     assert len(cells[0]["why"]) <= 200 and len(config["why"]) <= 200
     assert all(w["chips"] == 1 for w in bench["workloads"])
-    assert [m["name"] for m in bench["per_layer"][-5:]] == list(NEW_READERS)
+    names = [m["name"] for m in bench["per_layer"]]
+    # (five entries in a row; later PRs append after them)
+    first = names.index(NEW_READERS[0])
+    assert names[first:first + 5] == list(NEW_READERS)
     listed = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_READERS:
         metric = listed[name]
@@ -232,8 +234,9 @@ def test_the_benchmark_gains_one_configuration_one_cell_five_readers():
             metric[k] for k in ("name", "unit", "layer", "moves", "source")
         )
     # nothing that stood is edited: no accepted reader's list is widened
-    for metric in bench["per_layer"][:-5]:
-        assert "lfm2_moe_steady_8k" not in metric.get("workloads", [])
+    for metric in bench["per_layer"]:
+        if metric["name"] not in NEW_READERS:
+            assert "lfm2_moe_steady_8k" not in metric.get("workloads", [])
 
 
 @pytest.mark.parametrize("leaf, limit", [

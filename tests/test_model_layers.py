@@ -24,7 +24,7 @@ PACKAGE = os.path.join(
 )
 FAMILIES = [
     "gpt", "llama", "olmoe", "olmo_hybrid", "sarvam_mla", "laguna", "ouro",
-    "nemotron_h", "mimo_v2", "motif", "bailing_hybrid",
+    "nemotron_h", "mimo_v2", "motif", "bailing_hybrid", "jamba",
 ]
 # the one sideways import left (ROADMAP D22): the pipeline's adapter
 # calls ``gpt.py::cross_entropy_loss``, which the benchmark imports by

@@ -1,11 +1,11 @@
 """What a rematted block keeps and what it runs again for its
-backward, in the six families whose blocks sit behind
+backward, in the seven families whose blocks sit behind
 ``prevent_cse=True``: with flash attention the one remat policy keeps
 the kernel's five residuals
 (``ops/flash_attention.py::RESIDUAL_NAMES``), so the forward kernel is
 not run again and nothing that stands before it only to feed it is in
 the rematted computation; a recurrent rule's forward kernel
-(``gdn_fwd``, ``kda_fwd``, ``ssd_fwd``) names its own results and the
+(``gdn_fwd``, ``kda_fwd``, ``ssd_fwd``, ``s6_fwd``) names its own results and the
 policy keeps those, so it runs once a layer; with XLA attention and no
 such rule nothing is named and the program is the parent policy's.  A
 family's toy is built, traced under both policies and run ONCE for the
@@ -65,10 +65,15 @@ FAMILIES = {
     "nemotron_h": (
         {"q_proj", "k_proj", "v_proj"}, set(), 1, ("ssd_fwd", 3)
     ),
+    # (mamba, attention, mamba; the block's SwiGLU reads what
+    # ``o_proj`` adds to the residual)
+    "jamba": (
+        {"q_proj", "k_proj", "v_proj"}, {"o_proj"}, 1, ("s6_fwd", 2)
+    ),
 }
 # (a family's toy is ``configs/toy_<family>.json`` but for)
 TOYS = {"bailing_hybrid": "toy_ling"}
-RECURRENT_FORWARDS = ("gdn_fwd", "kda_fwd", "ssd_fwd")
+RECURRENT_FORWARDS = ("gdn_fwd", "kda_fwd", "ssd_fwd", "s6_fwd")
 
 
 def toy_loss(family, attention):
@@ -179,7 +184,7 @@ def test_a_rematted_block_runs_no_recurrent_forward_again(
     the policy keeps the names, all of one ``pallas_call``'s live
     results or the call stays.  Under the parent's policy each runs a
     second time there; the numbers are the same bit for bit.  A family
-    with no such rule calls none of the three."""
+    with no such rule calls none of the four."""
     family, ours, parents = traced
     kernel, layers_with = FAMILIES[family][3] or (None, 0)
     for name in RECURRENT_FORWARDS:

@@ -33,6 +33,7 @@ from dlrover_tpu.models.gpt import (  # noqa: E402
 PINS = {
     ("bailing_hybrid", "flash"): "aff0de98befe6952",  # PR 65's tree
     ("gpt", "flash"): "d1d2b7b0b9ff02ee",  # PR 61's tree
+    ("jamba", "flash"): "de7e5764941d4967",  # PR 68's tree
     ("laguna", "flash"): "322e01c44b37bb48",  # PR 61's tree
     ("laguna", "xla"): "4b80b17fe2120d48",  # PR 58's tree
     ("lfm2_moe", "flash"): "6554d5ab6a5a2c6b",  # PR 63's tree
